@@ -1,9 +1,9 @@
 """ctypes loader for the fused quantization kernels (quant.cc).
 
-Same build discipline as ``_native/store.py``: compile the bundled
-source on first use when the .so is missing or stale (flock-guarded so
-concurrent workers don't race), force-rebuild when dlopen rejects a
-binary from a foreign toolchain.  ``lib()`` returns None when no
+Same build discipline as ``_native/store.py`` (``_native/build.py``):
+compile the bundled source on first use when the .so is missing or was
+built from another source, force-rebuild when dlopen rejects a binary
+from a foreign toolchain.  ``lib()`` returns None when no
 compiler is available — the numpy reference path in
 ``util/collective/quantize.py`` is always there as the fallback, and
 both produce bit-identical wire bytes (quant.cc builds with
@@ -14,8 +14,9 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
+
+from ray_tpu._native.build import ensure_built
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "quant.cc")
@@ -26,36 +27,16 @@ _lib_lock = threading.Lock()
 
 
 def _build(force: bool = False) -> None:
-    def fresh():
-        return (
-            not force
-            and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        )
-
-    if fresh():
-        return
-    with open(_SO + ".lock", "w") as lf:
-        import fcntl
-
-        fcntl.flock(lf, fcntl.LOCK_EX)
-        if fresh():
-            return
-        tmp = _SO + ".tmp"
-        # -march=native is safe here: the .so is always compiled on the
-        # host that dlopens it (build-at-first-use, foreign binaries are
-        # rebuilt), and it unlocks the wide-SIMD quant loops.  Retry
-        # without it for exotic toolchains that reject the flag.
-        base = ["g++", "-O3", "-ffp-contract=off", "-fno-math-errno",
-                "-fPIC", "-shared", "-std=c++17", _SRC, "-o", tmp]
-        try:
-            subprocess.run(
-                base[:1] + ["-march=native"] + base[1:],
-                check=True, capture_output=True,
-            )
-        except subprocess.CalledProcessError:
-            subprocess.run(base, check=True, capture_output=True)
-        os.replace(tmp, _SO)
+    # -march=native is safe here: the .so is always compiled on the
+    # host that dlopens it (build-at-first-use, foreign binaries are
+    # rebuilt), and it unlocks the wide-SIMD quant loops.  Retry
+    # without it for exotic toolchains that reject the flag.
+    base = ["g++", "-O3", "-ffp-contract=off", "-fno-math-errno",
+            "-fPIC", "-shared", "-std=c++17"]
+    ensure_built(
+        _SRC, _SO, [base[:1] + ["-march=native"] + base[1:], base],
+        force=force,
+    )
 
 
 def _bind(lib) -> None:

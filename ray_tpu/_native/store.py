@@ -5,6 +5,10 @@ header comment).  The C library owns allocation and the object index; the
 data plane is a plain ``mmap`` of the same arena file, giving zero-copy
 ``memoryview`` reads of sealed objects (ray: plasma client.cc mmap-and-read
 analogue, minus the socket protocol).
+
+No binary is committed: the library is compiled from the bundled source
+at first use and rebuilt whenever that source changes
+(``_native/build.py``).
 """
 
 from __future__ import annotations
@@ -12,10 +16,10 @@ from __future__ import annotations
 import ctypes
 import mmap
 import os
-import subprocess
 import threading
 from typing import Optional
 
+from ray_tpu._native.build import ensure_built
 from ray_tpu.common import faults
 
 _SRC = os.path.join(os.path.dirname(__file__), "shm_store.cc")
@@ -65,48 +69,15 @@ class StoreFullError(StoreError):
 
 
 def _build_library(force: bool = False) -> None:
-    """Compile the .so if missing or older than the source (flock-guarded so
-    concurrent workers don't race).  ``force`` rebuilds even when the
-    binary looks fresh — used when dlopen rejects a prebuilt .so from a
-    different toolchain (e.g. a newer-glibc build shipped into an older
-    container)."""
-    def _stat_sig():
-        try:
-            st = os.stat(_SO)
-            return (st.st_mtime_ns, st.st_size, st.st_ino)
-        except OSError:
-            return None
-
-    def fresh():
-        return (
-            not force
-            and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        )
-
-    if fresh():
-        return
-    pre_lock_sig = _stat_sig()
-    lock_path = _SO + ".lock"
-    with open(lock_path, "w") as lf:
-        import fcntl
-
-        fcntl.flock(lf, fcntl.LOCK_EX)
-        if fresh():
-            return
-        if force and _stat_sig() != pre_lock_sig:
-            # a peer that held the flock first already replaced the
-            # binary — N workers failing dlopen together must not each
-            # run a full recompile back-to-back
-            return
-        tmp = _SO + ".tmp"
-        subprocess.run(
-            ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
-             _SRC, "-o", tmp],
-            check=True,
-            capture_output=True,
-        )
-        os.replace(tmp, _SO)
+    """Compile the .so unless it was built from this very source (see
+    ``_native/build.py``: a content hash recorded next to the binary,
+    not mtimes).  ``force`` rebuilds even then — used when dlopen
+    rejects a binary built by a different toolchain."""
+    ensure_built(
+        _SRC, _SO,
+        [["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread"]],
+        force=force,
+    )
 
 
 _lib = None
@@ -122,9 +93,9 @@ def _get_lib():
                 try:
                     lib = ctypes.CDLL(_SO)
                 except OSError:
-                    # prebuilt binary from an incompatible toolchain
-                    # (GLIBC version mismatch): rebuild from the bundled
-                    # source with the local compiler and retry
+                    # a binary carried over from an incompatible
+                    # toolchain (GLIBC version mismatch): rebuild from
+                    # the bundled source with the local compiler
                     _build_library(force=True)
                     lib = ctypes.CDLL(_SO)
                 lib.rt_store_create.restype = ctypes.c_void_p

@@ -11,6 +11,7 @@ gang scheduling uses the slice-name resource + STRICT_PACK placement groups.
 from __future__ import annotations
 
 import glob
+import logging
 import os
 import subprocess
 import sys
@@ -18,7 +19,17 @@ from typing import Dict, Optional
 
 from ray_tpu.common.config import cfg
 
+logger = logging.getLogger(__name__)
+
 TPU_RESOURCE = "TPU"
+
+
+#: Run by the detection probe in a child process, so that the caller —
+#: a control process — never opens the chips itself.
+_PROBE_SRC = (
+    "import jax; ds=[d for d in jax.devices() if d.platform != 'cpu']; "
+    "print(len(ds)); print(ds[0].device_kind if ds else '')"
+)
 
 
 class TPUAcceleratorManager:
@@ -27,6 +38,8 @@ class TPUAcceleratorManager:
     def __init__(self):
         self._num_chips: Optional[int] = None
         self._generation: Optional[str] = None
+        #: which detection step answered (set by num_chips())
+        self.detected_by: Optional[str] = None
 
     def num_chips(self) -> int:
         if self._num_chips is None:
@@ -35,43 +48,49 @@ class TPUAcceleratorManager:
 
     def _detect(self) -> int:
         if cfg.tpu_chips_override >= 0:
+            self.detected_by = "RT_TPU_CHIPS_OVERRIDE"
             return cfg.tpu_chips_override
-        # 1) device files (real TPU VM: /dev/accel* or /dev/vfio/*)
+        # 1) and 2) device files of a TPU VM: /dev/accel* or /dev/vfio/*
         n = len(glob.glob("/dev/accel*"))
-        if n == 0:
-            vfio = [p for p in glob.glob("/dev/vfio/*") if p != "/dev/vfio/vfio"]
-            n = len(vfio)
         if n > 0:
+            self.detected_by = "/dev/accel*"
             return n
-        # 2) ask jax in a subprocess (covers tunnelled/experimental platforms;
-        #    a subprocess so this control process never claims the chips)
+        n = len([p for p in glob.glob("/dev/vfio/*") if p != "/dev/vfio/vfio"])
+        if n > 0:
+            self.detected_by = "/dev/vfio/*"
+            return n
+        # 3) ask jax, in a child process so that this one never claims
+        #    the chips.  The child fails if this process already holds
+        #    them (a caller of init() that has touched jax): that is
+        #    logged, and the node then has no chips.
+        n = self._probe_jax()
+        self.detected_by = "jax probe" if n else "none"
+        return n
+
+    def _probe_jax(self) -> int:
+        env = {
+            k: v
+            for k, v in os.environ.items()
+            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")
+        }
         try:
             out = subprocess.run(
-                [
-                    sys.executable,
-                    "-c",
-                    "import jax; ds=[d for d in jax.devices() if d.platform"
-                    " not in ('cpu',)]; print(len(ds)); "
-                    "print(ds[0].device_kind if ds else '')",
-                ],
-                env={
-                    k: v
-                    for k, v in os.environ.items()
-                    if k not in ("JAX_PLATFORMS", "XLA_FLAGS")
-                },
-                capture_output=True,
-                timeout=60,
-                text=True,
+                [sys.executable, "-c", _PROBE_SRC],
+                env=env, capture_output=True, timeout=60, text=True,
             )
-            if out.returncode == 0:
-                lines = out.stdout.strip().splitlines()
-                if lines and lines[0].isdigit():
-                    if len(lines) > 1 and lines[1]:
-                        self._generation = _kind_to_generation(lines[1])
-                    return int(lines[0])
-        except Exception:
-            pass
-        return 0
+        except (OSError, subprocess.TimeoutExpired) as e:
+            logger.warning("TPU detection probe did not run: %r", e)
+            return 0
+        lines = out.stdout.strip().splitlines()
+        if out.returncode != 0 or not lines or not lines[0].isdigit():
+            logger.warning(
+                "TPU detection probe failed (exit %s): %s",
+                out.returncode, out.stderr.strip()[-2000:],
+            )
+            return 0
+        if len(lines) > 1 and lines[1]:
+            self._generation = _kind_to_generation(lines[1])
+        return int(lines[0])
 
     def generation(self) -> Optional[str]:
         if self._generation is None:

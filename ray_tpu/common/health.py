@@ -24,7 +24,10 @@ Two wall-clock guards bound the adaptive band (see gcs.py):
 regardless of history — detection latency never regresses vs the fixed
 detector), and ``health_death_floor_frac`` of it is the floor (a CI
 box stalling the whole process for a second must not mass-kill nodes
-whose learned interval was 100 ms).
+whose learned interval was 100 ms).  Neither covers a long stall of the
+observer itself — a worker opening a TPU chip freezes every process of
+a v5e host for 5-7 s — so the health loop measures how late it woke
+and takes that time off every node's silence (``excuse``).
 
 The distribution model is a normal tail with a floored standard
 deviation (``min_std_frac`` x mean): a floor is what keeps a
@@ -88,6 +91,14 @@ class PhiAccrualDetector:
             old = self._intervals.popleft()
             self._sum -= old
             self._sumsq -= old * old
+
+    def excuse(self, seconds: float, now: float) -> None:
+        """The OBSERVER was not running for ``seconds`` (its host
+        stalled, its loop was blocked): that time is not the node's
+        silence.  Moves the last arrival forward by it, never past
+        ``now``."""
+        if self._last is not None:
+            self._last = min(self._last + seconds, now)
 
     # ---- queries -------------------------------------------------------
     @property

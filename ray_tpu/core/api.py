@@ -56,7 +56,15 @@ def init(
 
     if address is None:
         sdir = session_dir or node_mod.default_session_dir()
-        res = node_mod.detect_resources(num_cpus, num_tpus, resources)
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+        tpu_manager = TPUAcceleratorManager()
+        res = node_mod.detect_resources(
+            num_cpus, num_tpus, resources, tpu_manager=tpu_manager
+        )
+        tpu_detected_by = (
+            tpu_manager.detected_by if num_tpus is None else "num_tpus"
+        )
         gcs_proc, gcs_addr = node_mod.start_gcs(sdir)
         try:
             raylet_proc, raylet_addr, node_id, store_path = node_mod.start_raylet(
@@ -79,6 +87,7 @@ def init(
     else:
         gcs_addr = address
         raylet_addr, node_id, store_path = _find_local_raylet(gcs_addr)
+        tpu_detected_by = None
 
     rt = Runtime(
         gcs_address=gcs_addr,
@@ -108,6 +117,9 @@ def init(
         "gcs_address": gcs_addr,
         "node_id": node_id,
         "session_dir": _node_group.session_dir if _node_group else None,
+        # how the head node's TPU chip count was arrived at: a detection
+        # step of accelerators/tpu.py, or "num_tpus" when the caller gave it
+        "tpu_detected_by": tpu_detected_by,
     }
 
 

@@ -989,7 +989,18 @@ class GcsServer:
     # fixed-timeout behavior.
     async def _health_loop(self):
         while True:
+            slept_at = time.monotonic()
             await asyncio.sleep(cfg.heartbeat_interval_s)
+            # How late did this loop wake?  Time in which the GCS itself
+            # was not running is not the nodes' silence: the whole host
+            # stalls for 5-7 s whenever a worker opens a TPU chip (seen
+            # on v5e: every process on the machine freezes), and a
+            # raylet frozen with us would be declared dead the moment
+            # we both wake.  Anything past one interval of lateness is
+            # taken off every node's silence.
+            stall = time.monotonic() - slept_at - cfg.heartbeat_interval_s
+            if stall > cfg.heartbeat_interval_s:
+                self._excuse_own_stall(stall)
             # reap finished driver subprocesses even when nobody polls
             # (zombies otherwise; and the checkpoint must not persist a
             # finished job as RUNNING)
@@ -1049,6 +1060,18 @@ class GcsServer:
                         continue
                     keep.append(e)
                 self.scheduler.pending = keep
+
+    def _excuse_own_stall(self, stall: float) -> None:
+        now = time.monotonic()
+        logger.warning(
+            "health loop woke %.2fs late; not counting it as node silence",
+            stall,
+        )
+        for node in self.nodes.values():
+            node.last_heartbeat = min(node.last_heartbeat + stall, now)
+            det = self.node_health.get(node.node_id)
+            if det is not None:
+                det.excuse(stall, now)
 
     async def _on_node_death(self, node_id: NodeID, reason: str):
         self._mark_dirty()

@@ -141,9 +141,11 @@ def _control_plane_env() -> dict:
     """Control-plane processes must never touch the TPU (one process owns the
     chips); pin them to CPU-only jax in case anything imports it."""
     env = dict(os.environ)
-    # remember the accelerator platform so TPU-leased workers can be
-    # pointed back at it (raylet _accel_env_for); control-plane processes
-    # themselves must never touch the TPU
+    # What the raylet re-points TPU-leased workers at (_accel_env_for).
+    # It is anything but "tpu" only under tier-1's fake-chip tests: they
+    # run with JAX_PLATFORMS=cpu and RT_TPU_CHIPS_OVERRIDE chips, and
+    # their TPU leases must come up on the host (tests/test_chip_smoke.py
+    # proves that chip_smoke.py refuses such a worker).
     env.setdefault(
         "RT_TPU_JAX_PLATFORM", os.environ.get("JAX_PLATFORMS") or "tpu"
     )
@@ -152,13 +154,18 @@ def _control_plane_env() -> dict:
     return env
 
 
-def detect_resources(num_cpus=None, num_tpus=None, extra=None) -> Dict[str, float]:
+def detect_resources(
+    num_cpus=None, num_tpus=None, extra=None, tpu_manager=None
+) -> Dict[str, float]:
+    """This node's resources.  With ``num_tpus`` unset the chips are
+    detected; pass a ``TPUAcceleratorManager`` as ``tpu_manager`` to read
+    afterwards which detection step answered (``detected_by``)."""
     res: Dict[str, float] = dict(extra or {})
     res["CPU"] = num_cpus if num_cpus is not None else float(os.cpu_count() or 1)
     if num_tpus is None:
         from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 
-        mgr = TPUAcceleratorManager()
+        mgr = tpu_manager or TPUAcceleratorManager()
         num_tpus = mgr.num_chips()
         if num_tpus:
             res.update(mgr.extra_resources())

@@ -9,12 +9,15 @@ accelerator-aware reuse, (2) the owner of the node's shm object store, and
 analogue, pull-based).
 
 TPU ownership model: libtpu allows one process per chip set, so TPU leases
-carry an explicit chip assignment (TPU_VISIBLE_CHIPS) decided here.  A worker
-is forever bound to the first accelerator env it receives (jax initializes
-once); idle workers are reused only on exact-match bindings, and idle workers
-whose chips conflict with a new allocation are killed (ray's env-var dance at
-python/ray/_private/accelerators/tpu.py:174-196 is per-task; here it is a
-lease-time contract).
+carry an explicit chip assignment (TPU_VISIBLE_CHIPS plus, for a subset of
+the host's chips, the process bounds libtpu needs beside it) decided here.
+A worker is forever bound to the first accelerator env it receives (jax
+initializes once); idle workers are reused only on exact-match bindings, and
+idle workers whose chips conflict with a new allocation are killed (ray's
+env-var dance at python/ray/_private/accelerators/tpu.py:174-196 is per-task;
+here it is a lease-time contract).  A chip goes back to the free set only
+once the process that held it is gone: the next holder could not open it
+before.
 """
 
 from __future__ import annotations
@@ -49,6 +52,24 @@ _PULL_SHUFFLE_RNG = random.Random()
 _PLAN_DELAY_DEFAULT = faults.FaultPlan.__dataclass_fields__[
     "delay_s"
 ].default
+
+
+#: What libtpu needs beside TPU_VISIBLE_CHIPS to open a SUBSET of a
+#: host's chips: the shape of the subset.  Without it a second process
+#: is refused at libtpu's host-wide lock, and a lone one sizes itself
+#: for the whole host.  (ray: accelerators/tpu.py:174-196 sets the same
+#: two shapes; both were opened on a 2x2 v5e host, PR 21.)
+_TPU_SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+#: libtpu reads each bound under two names, *_PROCESS_* and the older
+#: *_HOST_* (either alone works); a TPU host's own environment carries
+#: the older ones sized for all its chips, so a subset lease sets both.
+_TPU_CHIP_BOUNDS_VARS = (
+    "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_CHIPS_PER_HOST_BOUNDS",
+)
+_TPU_PROCESS_BOUNDS_VARS = ("TPU_PROCESS_BOUNDS", "TPU_HOST_BOUNDS")
+
+#: SIGTERM-to-SIGKILL grace for a chip-holding worker being retired
+_CHIP_RECLAIM_GRACE_S = 5.0
 
 
 @dataclass
@@ -102,6 +123,8 @@ class Raylet:
         self._tpu_chips_free: Set[int] = set(
             range(int(self.resources.get("TPU", 0)))
         )
+        # killed chip holders that have not exited yet (_reclaim_chips)
+        self._chip_reclaims: Dict[asyncio.Task, WorkerEntry] = {}
         self._peer_conns: Dict[str, rpc.Connection] = {}
         self._inflight_pulls: Dict[bytes, asyncio.Future] = {}
         self._tasks: List[asyncio.Task] = []
@@ -271,6 +294,9 @@ class Raylet:
         self._closing = True
         for t in self._tasks:
             t.cancel()
+        for t, w in list(self._chip_reclaims.items()):
+            t.cancel()
+            self._hard_kill_worker(w)
         for w in list(self.workers.values()):
             try:
                 w.proc.terminate()
@@ -1026,26 +1052,42 @@ class Raylet:
 
     def _accel_env_for(self, resources: Dict[str, float]) -> Dict[str, str]:
         """Accelerator visibility env for a lease (TPU chips or CPU-only)."""
-        n_tpu = int(resources.get("TPU", 0))
-        if n_tpu <= 0 and resources.get("TPU", 0) > 0:
-            n_tpu = 1  # fractional chip -> whole chip visibility
-        if n_tpu > 0:
-            if len(self._tpu_chips_free) < n_tpu:
-                raise rpc.RpcError(
-                    f"TPU chips exhausted: want {n_tpu}, free {len(self._tpu_chips_free)}"
-                )
-            chips = sorted(self._tpu_chips_free)[:n_tpu]
-            for c in chips:
-                self._tpu_chips_free.discard(c)
-            return {
-                "TPU_VISIBLE_CHIPS": ",".join(map(str, chips)),
-                "_RT_TPU_CHIPS": ",".join(map(str, chips)),
-                # undo the control-plane cpu pin for chip-holding workers
-                "JAX_PLATFORMS": os.environ.get(
-                    "RT_TPU_JAX_PLATFORM", "tpu"
-                ),
-            }
-        return {"JAX_PLATFORMS": "cpu"}
+        n_tpu = _lease_chip_count(resources)
+        if n_tpu <= 0:
+            return {"JAX_PLATFORMS": "cpu"}
+        if len(self._tpu_chips_free) < n_tpu:
+            raise rpc.RpcError(
+                f"TPU chips exhausted: want {n_tpu}, free {len(self._tpu_chips_free)}"
+            )
+        n_host = int(self.resources.get("TPU", 0))
+        if n_tpu < n_host and n_tpu not in _TPU_SUBSET_BOUNDS:
+            raise rpc.RpcError(
+                f"a lease of {n_tpu} of this host's {n_host} TPU chips is "
+                f"not a shape libtpu can open; ask for "
+                f"{sorted(_TPU_SUBSET_BOUNDS)} or all {n_host}"
+            )
+        chips = _pick_chips(self._tpu_chips_free, n_tpu)
+        if chips is None:
+            raise rpc.RpcError(
+                f"TPU chips fragmented: no aligned block of {n_tpu} among "
+                f"free chips {sorted(self._tpu_chips_free)}"
+            )
+        for c in chips:
+            self._tpu_chips_free.discard(c)
+        env = {
+            "TPU_VISIBLE_CHIPS": ",".join(map(str, chips)),
+            "_RT_TPU_CHIPS": ",".join(map(str, chips)),
+            # undo the control-plane cpu pin for chip-holding workers.
+            # RT_TPU_JAX_PLATFORM (core/node.py) is "cpu" only under
+            # tier-1's fake-chip tests, whose TPU leases run on the host.
+            "JAX_PLATFORMS": os.environ.get("RT_TPU_JAX_PLATFORM", "tpu"),
+        }
+        if n_tpu < n_host:
+            for var in _TPU_CHIP_BOUNDS_VARS:
+                env[var] = _TPU_SUBSET_BOUNDS[n_tpu]
+            for var in _TPU_PROCESS_BOUNDS_VARS:
+                env[var] = "1,1,1"
+        return env
 
     def _release_accel_env(self, env: Dict[str, str]):
         chips = env.get("_RT_TPU_CHIPS")
@@ -1076,14 +1118,49 @@ class Raylet:
         return None
 
     async def _evict_idle_chip_holders(self, n_tpu_needed: int):
-        """Kill idle workers holding chips until n_tpu_needed are free."""
+        """Kill idle workers holding chips until n_tpu_needed are free
+        or on their way back (_reclaim_chips)."""
         for pool in list(self._idle_by_env.values()):
             for cand in list(pool):
-                if len(self._tpu_chips_free) >= n_tpu_needed:
+                coming = sum(
+                    len(w.tpu_chips) for w in self._chip_reclaims.values()
+                )
+                if len(self._tpu_chips_free) + coming >= n_tpu_needed:
                     return
                 if cand.tpu_chips and cand.idle:
                     pool.remove(cand)
                     await self._on_worker_exit(cand, kill=True)
+
+    async def _reclaim_chips(self, w: WorkerEntry):
+        """Hand a retired worker's chips back once its process is gone.
+        A chip belongs to one process at a time: freed any earlier, the
+        next lease would bind a worker that cannot open it."""
+        deadline = time.monotonic() + _CHIP_RECLAIM_GRACE_S
+        while w.proc.poll() is None:
+            if time.monotonic() > deadline:
+                self._hard_kill_worker(w)
+                deadline = float("inf")
+            await asyncio.sleep(0.01)
+        self._release_accel_env(w.bound_env)
+
+    async def _await_reclaimed_chips(self, n_tpu: int):
+        """Park a lease until the chips it needs have come back from
+        workers that are still exiting (bounded; _accel_env_for then
+        reports exhaustion if they never did)."""
+        deadline = time.monotonic() + cfg.worker_start_timeout_s
+        # "an aligned block is free", not "n chips are free": chips come
+        # back one at a time, and two odd ones are no pair
+        while (
+            _pick_chips(self._tpu_chips_free, n_tpu) is None
+            and self._chip_reclaims
+        ):
+            left = deadline - time.monotonic()
+            if left <= 0:
+                return
+            await asyncio.wait(
+                list(self._chip_reclaims), timeout=left,
+                return_when=asyncio.FIRST_COMPLETED,
+            )
 
     async def rpc_lease_worker(self, conn: rpc.Connection, p):
         """GCS asks for a worker bound to `resources` (+ runtime env).
@@ -1115,9 +1192,7 @@ class Raylet:
         elif rtenv and rtenv.get("container"):
             container = self._container_spawn_prefix(rtenv)
             venv_key = rtenv_key  # containerized workers never mix pools
-        n_tpu = int(resources.get("TPU", 0))
-        if n_tpu <= 0 and resources.get("TPU", 0) > 0:
-            n_tpu = 1
+        n_tpu = _lease_chip_count(resources)
         if n_tpu > 0:
             # chip-bound reuse must come BEFORE allocation: the free set
             # may be empty precisely because idle workers hold the chips
@@ -1127,6 +1202,8 @@ class Raylet:
                 # evict idle chip holders bound to other envs (the
                 # docstring contract: conflicting idle workers are killed)
                 await self._evict_idle_chip_holders(n_tpu)
+            if w is None:
+                await self._await_reclaimed_chips(n_tpu)
             if w is not None:
                 w.lease_id = p["lease_id"]
                 w.leased_at = time.monotonic()
@@ -1239,7 +1316,11 @@ class Raylet:
         for pool in self._idle_by_env.values():
             if w in pool:
                 pool.remove(w)
-        if w.bound_env:
+        if w.tpu_chips and w.proc.poll() is None:
+            task = asyncio.ensure_future(self._reclaim_chips(w))
+            self._chip_reclaims[task] = w
+            task.add_done_callback(self._chip_reclaims.pop)
+        elif w.bound_env:
             self._release_accel_env(w.bound_env)
         if kill and w.proc.poll() is None:
             try:
@@ -1603,6 +1684,34 @@ def _inject_parent_site(root: str) -> None:
     ):
         with open(os.path.join(vs, "_rt_parent_env.pth"), "w") as f:
             f.write("\n".join(parents) + "\n")
+
+
+def _pick_chips(free: Set[int], n: int) -> Optional[List[int]]:
+    """``n`` free chips one process can open together: an aligned block
+    (ids k*n .. k*n+n-1), whose chips are ICI neighbours — two chips
+    picked at random from a 2x2 host may sit on a diagonal.  Best fit:
+    the block whose enclosing 2n-block has the fewest free chips, so
+    single-chip leases fill broken pairs before they break whole ones."""
+    blocks = [
+        list(range(start, start + n))
+        for start in range(0, max(free, default=-1) + 1, n)
+    ]
+    whole = [b for b in blocks if free.issuperset(b)]
+    if not whole:
+        return None
+
+    def free_around(block):
+        start = block[0] // (2 * n) * 2 * n
+        return len(free.intersection(range(start, start + 2 * n)))
+
+    return min(whole, key=lambda b: (free_around(b), b[0]))
+
+
+def _lease_chip_count(resources: Dict[str, float]) -> int:
+    """Whole chips a lease binds: a fractional chip still needs the
+    whole chip visible."""
+    want = resources.get("TPU", 0)
+    return max(int(want), 1) if want > 0 else 0
 
 
 def _env_key(env: Optional[Dict[str, str]], rtenv_key: str = "") -> tuple:

@@ -19,8 +19,9 @@ lse) on the fly.
 
 Role-equivalent to the reference's fused GPU attention paths (the
 reference delegates to torch/cutlass; here the MXU/VMEM design is
-original).  Falls back to the dense einsum on non-TPU backends so tests
-run on CPU.
+original).  Off the chip the same kernels run in Pallas interpret mode
+(`_interpret`), so tests exercise them on CPU; nothing here switches to
+the dense einsum.
 """
 
 from __future__ import annotations
@@ -204,7 +205,17 @@ def _block_sizes(S):
 
 
 def _interpret():
+    """Interpret the kernels when there is no TPU to compile them for —
+    the CPU-test convenience.  On a chip they compile (a
+    ``tpu_custom_call`` in the step's text is the proof)."""
     return jax.devices()[0].platform != "tpu"
+
+
+def _out_struct(shape, dtype, like):
+    """A kernel output that varies over the mesh axes ``like`` varies
+    over: under ``sharded_flash_attention_bhsd``'s shard_map every
+    pallas_call output must say so (jax's check_vma)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _fwd(q, k, v, scale):
@@ -225,8 +236,8 @@ def _fwd(q, k, v, scale):
             pl.BlockSpec((1, 1, 2, blk_q), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, 2, S), jnp.float32),
+            _out_struct((B, H, S, D), q.dtype, q),
+            _out_struct((B, H, 2, S), jnp.float32, q),
         ],
         interpret=_interpret(),
     )(q, k, v)
@@ -253,7 +264,7 @@ def _bwd(q, k, v, o, lse, do, scale):
         out_specs=pl.BlockSpec(
             (1, 1, blk_q, D), lambda b, h, i, j: (b, h, i, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_shape=_out_struct((B, H, S, D), q.dtype, q),
         interpret=_interpret(),
     )(q, k, v, do, lse4, delta4)
     # For the dkv pass the grid iterates (kv, q): index maps swap i/j roles.
@@ -269,8 +280,8 @@ def _bwd(q, k, v, o, lse, do, scale):
             pl.BlockSpec((1, 1, blk_k, D), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, S, D), v.dtype),
+            _out_struct((B, H, S, D), k.dtype, k),
+            _out_struct((B, H, S, D), v.dtype, v),
         ],
         interpret=_interpret(),
     )(q, k, v, do, lse4, delta4)

@@ -123,22 +123,20 @@ def make_mesh(
 ) -> Mesh:
     """Build the 6-axis mesh over ``devices`` (default: all local devices).
 
-    Uses `jax.experimental.mesh_utils` device ordering when available so
-    the innermost axes land on physically adjacent chips (ICI neighbors);
-    falls back to a plain reshape on CPU meshes where topology is flat.
+    Uses `jax.experimental.mesh_utils` device ordering so the innermost
+    axes land on physically adjacent chips (ICI neighbors); on CPU
+    meshes, where topology is flat, that is a plain reshape.
     """
     if devices is None:
         devices = jax.devices()
     devices = list(devices)
     config = (config or MeshConfig()).resolve(len(devices))
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
+    try:
         dev_array = mesh_utils.create_device_mesh(
             config.shape, devices=devices
         )
-    except ImportError:
-        dev_array = np.asarray(devices).reshape(config.shape)
     except Exception as e:
         # A failed topology-aware layout on real hardware means sp/tp
         # neighbors may not be ICI-adjacent — degraded, not incorrect,

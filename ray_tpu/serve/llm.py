@@ -259,7 +259,9 @@ class LlamaDeployment:
         import jax
 
         from ray_tpu.models import llama
+        from ray_tpu.util.compile_cache import CompileLog
 
+        self._compiles = CompileLog()
         self.config = config or llama.LlamaConfig.tiny()
         if weights_ref is not None:
             import ray_tpu
@@ -273,6 +275,33 @@ class LlamaDeployment:
             params, self.config, max_slots=max_slots, max_len=max_len,
             max_prompt_len=max_prompt_len,
         )
+
+    def stats(self) -> dict:
+        """What this replica runs on and what it has cost so far: the
+        jax device it holds, its XLA compiles (all of them, and how
+        many versions of the two engine programs — prefill compiles
+        once per distinct prompt length), peak device memory where the
+        backend reports it, and the admitter's counters."""
+        import jax
+
+        from ray_tpu.models import llama
+
+        devices = jax.devices()
+        dev = devices[0]
+        mem = dev.memory_stats() or {}
+        return {
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": len(devices),
+            "compiles": self._compiles.snapshot(),
+            "programs": {
+                "prefill_into_slot": llama.prefill_into_slot._cache_size(),
+                "decode_step_rowwise": llama.decode_step_rowwise._cache_size(),
+            },
+            "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
+            "admitted_total": self.engine.admitted_total,
+            "shed_total": self.engine.shed_total,
+        }
 
     def update_weights(self, params) -> bool:
         """Swap the decode params in place — the serve weight-push path

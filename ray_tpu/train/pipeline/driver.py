@@ -135,15 +135,25 @@ def synthetic_batches(config: PipelineConfig, steps: int,
     return out
 
 
-def init_pp_params(config: PipelineConfig):
-    """Driver-side model init + stage cut (numpy trees, ready to ship).
-    All family knowledge comes from the partition registry, so a new
-    family registered in models.pp.PARTITIONS just works here."""
+def _init_pp_params_here(config: PipelineConfig):
+    """Model init + stage cut in THIS process (numpy trees, ready to
+    ship).  All family knowledge comes from the partition registry, so a
+    new family registered in models.pp.PARTITIONS just works here."""
     import jax
 
     part = get_partition(config.model, config.model_config)
     params = part.init(jax.random.key(config.seed))
     return to_numpy(part.to_pp(params, config.n_stages))
+
+
+def init_pp_params(config: PipelineConfig):
+    """Model init + stage cut for a cluster run, computed in a CPU-leased
+    task and fetched as numpy trees.  Never in the calling process: a
+    driver that opened a jax backend here would be the first process on
+    the accelerator, and the stage actors it starts next could not open
+    their chips."""
+    task = ray_tpu.remote(num_cpus=0)(_init_pp_params_here)
+    return ray_tpu.get(task.remote(config), timeout=config.get_timeout_s)
 
 
 class PipelineTrainer:
@@ -167,6 +177,9 @@ class PipelineTrainer:
         self.worker_groups: List[Any] = []
         self.step = 0
         self.losses: List[float] = []
+        # each stage actor's configure() reply, [stage][lane] flattened:
+        # pid, host, jax platform, leased chips
+        self.stage_info: List[dict] = []
 
     # -- lifecycle -------------------------------------------------------
     def start(self) -> None:
@@ -215,7 +228,7 @@ class PipelineTrainer:
                     cfg.stage_spec(s, r), blocks, tail
                 ))
         try:
-            ray_tpu.get(refs, timeout=cfg.get_timeout_s)
+            self.stage_info = ray_tpu.get(refs, timeout=cfg.get_timeout_s)
         except Exception as e:
             raise TrainWorkerGroupError(
                 f"pipeline stage configure failed: {e}"
@@ -392,7 +405,7 @@ class LocalPipelineRunner:
                           config.scale)
             for s in range(config.n_stages)
         ]
-        pp = init_pp_params(config)
+        pp = _init_pp_params_here(config)
         import jax
 
         self.blocks = [

@@ -138,7 +138,17 @@ class PipelineStageActor:
                 group_name=spec["group_name"],
                 options=spec.get("collective_options"),
             )
-        return {"pid": os.getpid(), "host": socket.gethostname()}
+        import jax
+
+        return {
+            "pid": os.getpid(), "host": socket.gethostname(),
+            "stage": spec["stage_idx"], "lane": spec["lane"],
+            # what this stage's programs run on, and which chips the
+            # raylet leased it ("" on a CPU lease)
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "tpu_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+        }
 
     def _build(self, spec: dict) -> None:
         part = get_partition(spec["model"], spec["model_config"])

@@ -188,6 +188,33 @@ class TestPhiAccrualDetector:
         after = d.phi(t + 0.4)
         assert after < before
 
+    def test_observer_stall_is_not_the_nodes_silence(self):
+        """A worker opening a TPU chip freezes every process of the host
+        for seconds (seen on v5e: 5-7 s).  The GCS and the raylet both
+        lose that time; when they wake, the health loop sees ~8 s of
+        silence — past the floor, phi through the roof — unless the
+        time the observer itself lost is taken off (`excuse`)."""
+        from ray_tpu.common.config import cfg
+        from ray_tpu.common.health import death_confirmed
+
+        floor = cfg.node_death_timeout_s * cfg.health_death_floor_frac
+        d, t = self._warm(interval=1.0, jitter=0.02)
+        woke = t + 1.0 + 7.0  # one interval, then a 7 s freeze
+        assert death_confirmed(
+            d.phi(woke), woke - d.last_heartbeat, cfg.health_phi_death,
+            floor, cfg.node_death_timeout_s,
+        )
+        d.excuse(7.0, woke)
+        assert d.phi(woke) < cfg.health_phi_suspect
+        assert not death_confirmed(
+            d.phi(woke), woke - d.last_heartbeat, cfg.health_phi_death,
+            floor, cfg.node_death_timeout_s,
+        )
+        # never moved past the present: a beat that was already
+        # processed before the loop woke stays in the past
+        d.excuse(100.0, woke)
+        assert d.last_heartbeat == woke
+
     def test_death_verdict_floor_and_cap(self):
         from ray_tpu.common.health import death_confirmed
 

@@ -39,7 +39,7 @@ def _distributed_psum_loop(config):
 
     # 1) pure collective: psum of (axis_index + 1) over every device in
     # the gang — crosses the process boundary via Gloo
-    from jax.experimental.shard_map import shard_map
+    shard_map = jax.shard_map
 
     def contrib():
         return jax.lax.psum(

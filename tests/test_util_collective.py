@@ -504,10 +504,7 @@ class TestXlaRegistryAdapter:
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
 
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # newer jax: promoted to the top level
-            shard_map = jax.shard_map
+        shard_map = jax.shard_map
 
         xla = col.get_backend("xla")
         assert xla.kind == "in_program"
