@@ -1,0 +1,295 @@
+"""One command, one cell, one run::
+
+    python3 -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+Finds everything by the names in ``BENCHMARK.json``: the cell's
+configuration (``chipbench/configs/<config>.json``), its traffic mix or
+job (``chipbench/traffic/<traffic>.json``, whose ``job`` names
+``chipbench/jobs/<job>.py``) and, in a traced run, one reader per
+per-layer metric (``chipbench/layer_metrics/<metric>.py``, or the file
+of the name without its last ``.suffix``).  A new cell,
+mix, job kind or metric is new files and new entries; nothing here is
+edited.
+
+This process never opens a jax backend: the chip belongs to the worker
+or replica the raylet leases it to.  Its standard output carries
+exactly one line, written last (see ``_end``).
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS_START = time.time()
+
+import argparse  # noqa: E402
+import importlib  # noqa: E402
+import importlib.util  # noqa: E402
+import json  # noqa: E402
+import logging  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+import threading  # noqa: E402
+import traceback  # noqa: E402
+
+from chipbench import contract, trace_reduce  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+#: a run must end inside the driver's 360 s; the first in a checkout,
+#: which compiles, inside 1200 s.  Whether programs are cached is not
+#: known before the run, so the watchdog takes the longer limit.
+DEADLINE_S = 1150.0
+
+
+def _say(msg: str) -> None:
+    print(f"[chipbench] {msg}", file=sys.stderr, flush=True)
+
+
+def _load_json(*parts: str) -> dict:
+    with open(os.path.join(HERE, *parts)) as f:
+        return json.load(f)
+
+
+def _descendants(root: int) -> list:
+    children: dict = {}
+    for pid in os.listdir("/proc"):
+        if not pid.isdigit():
+            continue
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                ppid = int(f.read().rsplit(")", 1)[1].split()[1])
+        except (OSError, ValueError, IndexError):
+            continue
+        children.setdefault(ppid, []).append(int(pid))
+    out, todo = [], [root]
+    while todo:
+        for kid in children.get(todo.pop(), []):
+            out.append(kid)
+            todo.append(kid)
+    return out
+
+
+def _stop_cluster() -> None:
+    """Shut the cluster down and leave no process behind."""
+    started = _descendants(os.getpid())
+    try:
+        import ray_tpu
+
+        if ray_tpu.is_initialized():
+            t = threading.Thread(target=ray_tpu.shutdown, daemon=True)
+            t.start()
+            t.join(20)
+    except Exception:  # noqa: BLE001 — the kill below is the backstop
+        traceback.print_exc()
+    for pid in started:
+        try:
+            os.kill(pid, signal.SIGKILL)
+        except OSError:
+            pass
+    for pid in started:  # wait until each has ended
+        for _ in range(100):
+            if not os.path.exists(f"/proc/{pid}"):
+                break
+            try:
+                os.waitpid(pid, os.WNOHANG)
+            except OSError:
+                pass
+            time.sleep(0.05)
+
+
+_ENDING = threading.Lock()
+
+
+def _end(real_stdout: int, line, code: int, scratch) -> None:
+    """Stop everything, then write the line (if any) as the process's
+    last act and leave without running atexit hooks: nothing can print
+    after it.  Runs once: the watchdog and the main thread may both
+    arrive."""
+    _ENDING.acquire()
+    _stop_cluster()
+    if scratch:
+        shutil.rmtree(scratch, ignore_errors=True)
+    sys.stderr.flush()
+    if line is not None:
+        os.write(real_stdout, (line + "\n").encode())
+    os._exit(code)
+
+
+def _reader(metric: str):
+    path = contract.reader_path(metric)
+    if path is None:
+        raise RuntimeError(f"no reader for per-layer metric {metric!r} under "
+                           "chipbench/layer_metrics/")
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_layer_metric_" + metric.replace(".", "_").replace("-", "_"), path
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def build_line(bench: dict, workload: str, trace: int, job: dict,
+               traced: dict | None, peaks: dict, rehearse: bool = False) -> dict:
+    """The line's object from the cell's DECLARED metrics: a metric the
+    run could not compute is an error, never a null or a missing key."""
+    device = dict(job["device"])
+    if device.get("memory_peak_bytes") is None and rehearse:
+        device["memory_peak_bytes"] = 1  # the CPU backend reports none
+    if device.get("memory_peak_bytes") is None:
+        raise RuntimeError("the device reported no peak_bytes_in_use")
+    want = contract.declared_metrics(bench, workload, trace)
+    metrics = {}
+    if not trace:
+        values = dict(job["end_to_end"], setup_s=job["setup_s"])
+        for name, unit in want.items():
+            if values.get(name) is None:
+                raise RuntimeError(f"end-to-end metric {name!r} was not measured")
+            metrics[name] = {"value": values[name], "unit": unit}
+    else:
+        peak = peaks.get(device["kind"])
+        if peak is None and rehearse:
+            peak = next(iter(peaks.values()))
+        if peak is None:
+            raise RuntimeError(
+                f"device kind {device['kind']!r} is not in chipbench/peaks.json; "
+                "add it with its source, there is no default"
+            )
+        ctx = dict(traced, facts=job["facts"], peak=peak, device=device,
+                   cell=contract.cell(bench, workload))
+        for name, unit in want.items():
+            value = _reader(name)(ctx)
+            if value is None and rehearse:
+                _say(f"rehearsal: {name} found nothing to read; 0 stands in")
+                value = 0.0
+            if value is None:
+                raise RuntimeError(
+                    f"per-layer metric {name!r}: its reader found nothing to read"
+                )
+            metrics[name] = {"value": value, "unit": unit}
+        device["busy_s"], device["window_s"] = traced["busy_s"], traced["window_s"]
+    line = {
+        "correct": bool(job["correct"]) and job["failed"] == 0,
+        "attempted": int(job["attempted"]), "failed": int(job["failed"]),
+        "metrics": metrics, "device": device,
+    }
+    if trace:
+        line["breakdown"] = {
+            "device_ops": trace_reduce.top_ops(traced["planes"]),
+            "idle_gaps": trace_reduce.idle_gaps(traced["planes"]),
+        }
+    return line
+
+
+def reduce_trace(trace_dir: str, rehearse: bool, host_s=None) -> dict:
+    trace = trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir))
+    planes = trace_reduce.device_planes(trace)
+    if not planes and rehearse:
+        planes = trace_reduce.rehearsal_device_planes(trace)
+    if not planes:
+        raise trace_reduce.TraceError(
+            "the trace holds no device plane (planes: "
+            f"{[p['name'] for p in trace['planes']]})"
+        )
+    busy_s, window_s = trace_reduce.busy(planes, host_s)
+    return {"planes": planes, "busy_s": busy_s, "window_s": window_s}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="walk the control flow on the CPU at toy shapes with "
+                         "fake chips; the line says platform cpu and its "
+                         "numbers go nowhere (never in BENCHMARK.json's command)")
+    args = ap.parse_args()
+
+    # Everything that anyone prints from here on - forwarded worker and
+    # replica logs, the profiler, shutdown, children that inherit fd 1 -
+    # goes to stderr.  The real stdout is kept for the one line.
+    sys.stdout.flush()
+    real_stdout = os.dup(1)
+    os.dup2(2, 1)
+    sys.stdout = sys.stderr
+    logging.basicConfig(stream=sys.stderr, level=logging.WARNING)
+
+    scratch = None
+    watchdog = threading.Timer(
+        DEADLINE_S, lambda: (_say(f"deadline of {DEADLINE_S:.0f} s passed"),
+                             _end(real_stdout, None, 1, scratch)))
+    watchdog.daemon = True
+    watchdog.start()
+    try:
+        bench = contract.load_benchmark()
+        cell = contract.cell(bench, args.workload)
+        cfg_file = contract.config_entry(bench, cell["config"])["file"]
+        with open(os.path.join(contract.ROOT, cfg_file)) as f:
+            config = json.load(f)
+        traffic = _load_json("traffic", cell["traffic"] + ".json")
+        peaks = _load_json("peaks.json")
+        job_mod = importlib.import_module("chipbench.jobs." + traffic["job"])
+
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            os.environ["RT_TPU_CHIPS_OVERRIDE"] = str(cell["chips"])
+            os.environ["XLA_FLAGS"] = (
+                f"--xla_force_host_platform_device_count={cell['chips']}"
+            )
+        scratch = tempfile.mkdtemp(prefix="chipbench_")  # under $TMPDIR
+        trace_dir = os.path.join(contract.ROOT, ".chipbench_trace", args.workload)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        if args.trace:
+            os.makedirs(trace_dir)
+
+        import ray_tpu
+
+        info = ray_tpu.init(session_dir=os.path.join(scratch, "session"))
+        n_tpu = int(ray_tpu.cluster_resources().get("TPU", 0))
+        _say(f"{args.workload}: {n_tpu} chip(s) found by {info['tpu_detected_by']}")
+        if n_tpu < cell["chips"]:
+            raise RuntimeError(
+                f"the cell asks for {cell['chips']} TPU chip(s), ray_tpu.init() "
+                f"found {n_tpu}; there is no CPU fallback"
+            )
+        job = job_mod.run({
+            "cell": cell, "config": config, "traffic": traffic,
+            "seed": args.seed, "seconds": args.seconds, "trace": args.trace,
+            "rehearse": args.rehearse, "trace_dir": trace_dir,
+            "storage_dir": os.path.join(scratch, "results"),
+            "t_process_start": T_PROCESS_START,
+        })
+        if not args.rehearse and (
+            job["device"]["platform"] != "tpu" or job["device"]["count"] != cell["chips"]
+        ):
+            raise RuntimeError(f"the job ran on {job['device']}, not on "
+                               f"{cell['chips']} TPU chip(s)")
+        traced = reduce_trace(
+            trace_dir, args.rehearse, job["facts"].get("trace_host_s")
+        ) if args.trace else None
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        obj = build_line(bench, args.workload, args.trace, job, traced, peaks,
+                         args.rehearse)
+        facts = {k: v for k, v in job["facts"].items()
+                 if not isinstance(v, (list, dict))}
+        _say(f"facts: {json.dumps(facts)}")
+        line = json.dumps(obj)
+        contract.validate(line, args.workload, args.trace, bench)
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError("the parent process opened a jax backend")
+    except BaseException:  # noqa: BLE001 — every failure ends in _end
+        traceback.print_exc()
+        watchdog.cancel()
+        _end(real_stdout, None, 1, scratch)
+    watchdog.cancel()
+    _end(real_stdout, line, 0, scratch)
+
+
+if __name__ == "__main__":
+    main()
