@@ -125,6 +125,9 @@ class LeaseEntry:
     pg_ref: Optional[Tuple[PlacementGroupID, int]] = None
 
 
+#: spans the GCS keeps for the whole cluster (newest; util.tracing)
+SPAN_TABLE_SIZE = 65536
+
 RUNNING_JOB = "RUNNING"
 SUCCEEDED_JOB = "SUCCEEDED"
 FAILED_JOB = "FAILED"
@@ -531,7 +534,8 @@ _READONLY_RPCS = frozenset({
     "wait_placement_group_ready", "ping", "subscribe", "unsubscribe",
     "get_drain_status",
     "get_autoscaler_state", "list_tasks", "list_objects",
-    "metrics_push", "get_metrics", "get_job_info", "get_job_logs",
+    "metrics_push", "get_metrics", "list_spans", "get_job_info",
+    "get_job_logs",
     "list_jobs", "list_events", "report_event", "get_worker_death_info",
     "cluster_store_stats", "dump_worker_stacks", "cancel_lease_requests",
     "dump_tasks", "publish", "chaos_partition", "chaos_heal",
@@ -623,6 +627,10 @@ class GcsServer:
         self._start_time = time.time()
         # observability: reporter id -> latest metric snapshot
         self.metrics_by_reporter: Dict[str, dict] = {}
+        # newest spans of the whole cluster, (pid, row) as util.tracing
+        # records them; a table of its own so that spans never push
+        # node-death and lease events out of _events
+        self.spans: deque = deque(maxlen=SPAN_TABLE_SIZE)
         # submitted driver jobs (job_submission.py): sub_id -> info
         self.submitted_jobs: Dict[str, dict] = {}
         self.session_dir = session_dir
@@ -2167,7 +2175,25 @@ class GcsServer:
             "ts": time.time(),
             "metrics": p["metrics"],
         }
+        pid = p.get("pid")
+        self.spans.extend((pid, row) for row in p.get("spans", ()))
         return True
+
+    async def rpc_list_spans(self, conn, p):
+        """Spans the cluster's processes pushed (util.tracing), oldest
+        first, as dicts; every filter is optional.  The table outlives
+        its reporters, as metrics_by_reporter does."""
+        from ray_tpu.util import tracing
+
+        trace_id, prefix = p.get("trace_id"), p.get("name_prefix")
+        since, until = p.get("since_ns"), p.get("until_ns")
+        return [
+            tracing.as_dict(row, pid) for pid, row in list(self.spans)
+            if (trace_id is None or row[1] == trace_id)
+            and (prefix is None or row[0].startswith(prefix))
+            and (since is None or row[5] >= since)
+            and (until is None or row[4] <= until)
+        ]
 
     async def rpc_get_metrics(self, conn, p):
         """Aggregated metrics: counters/histogram buckets sum across

@@ -567,24 +567,73 @@ class Runtime:
             reply = await self.gcs.call("register_job", {"pid": os.getpid()})
             self.job_id = JobID(reply["job_id"])
         self._metrics_task = self._loop.create_task(self._metrics_push_loop())
+        self._stall_task = self._loop.create_task(self._stall_watch_loop())
 
     async def _metrics_push_loop(self):
-        """Ship this process's util.metrics registry to the GCS
-        periodically (ray: stats exporter role)."""
-        from ray_tpu.util import metrics as metrics_mod
-
         while not self._closed:
             await asyncio.sleep(cfg.metrics_push_interval_s)
-            snap = metrics_mod.registry_snapshot()
-            if not snap:
-                continue
-            try:
-                await self.gcs.notify(
-                    "metrics_push",
-                    {"reporter": self.worker_id.hex(), "metrics": snap},
+            await self.push_telemetry()
+
+    async def _stall_watch_loop(self):
+        """One line when this process stands still: a 100 ms ticker on
+        the io loop that, woken more than 1 s late, logs the wall time
+        lost and the CPU time the process and the loop thread used
+        meanwhile.  CPU time that did not advance means the process was
+        stopped from outside (another process opening a chip, a frozen
+        host); CPU time that did means Python held the loop."""
+        from ray_tpu.util import metrics as metrics_mod
+
+        lost = metrics_mod.Counter(
+            "loop_stall_seconds_total",
+            "wall time by which the io loop's 100 ms ticker woke late, "
+            "counted from 1 s", tag_keys=("role",),
+        )
+        tick_s = 0.1
+        was = (time.monotonic_ns(), time.process_time_ns(),
+               time.thread_time_ns())
+        while not self._closed:
+            await asyncio.sleep(tick_s)
+            now = (time.monotonic_ns(), time.process_time_ns(),
+                   time.thread_time_ns())
+            late_s = (now[0] - was[0]) / 1e9 - tick_s
+            if late_s > 1.0:
+                lost.inc(late_s, {"role": self.mode})
+                logger.warning(
+                    "%s pid %d stood still: its io loop woke %.2f s late; "
+                    "meanwhile the process used %.2f s of CPU and the loop "
+                    "thread %.2f s; open span: %s",
+                    self.mode, os.getpid(), late_s,
+                    (now[1] - was[1]) / 1e9, (now[2] - was[2]) / 1e9,
+                    tracing.open_span() or "none",
                 )
-            except Exception:
-                pass
+            was = now
+
+    async def push_telemetry(self):
+        """Ship this process's util.metrics registry and the spans it
+        finished since the last push to the GCS, in one RPC (ray: stats
+        exporter role).  Nothing is sent when there is neither."""
+        from ray_tpu.util import metrics as metrics_mod
+
+        snap = metrics_mod.registry_snapshot()
+        spans = tracing.drain()
+        if not snap and not spans:
+            return
+        payload = {"reporter": self.worker_id.hex(), "metrics": snap}
+        if spans:
+            payload["spans"] = spans
+            payload["pid"] = os.getpid()
+        try:
+            await self.gcs.notify("metrics_push", payload)
+        except Exception:
+            pass  # best effort: the next push carries the metrics again
+
+    async def push_last_telemetry(self):
+        """On a graceful exit path: what the push loop has not sent yet,
+        without letting a dead GCS connection hold the exit up."""
+        try:
+            await asyncio.wait_for(self.push_telemetry(), timeout=1.0)
+        except Exception:
+            pass
 
     async def _reattach_gcs(self, conn):
         await conn.call(
@@ -669,6 +718,7 @@ class Runtime:
         if method == "exit_worker":
             logger.info("worker told to exit: %s", payload.get("reason"))
             threading.Thread(target=_delayed_exit, daemon=True).start()
+            await self.push_last_telemetry()  # has the 0.1 s before the exit
             return True
         if method == "create_actor" and self._worker_server is not None:
             return await self._worker_server.handle_create_actor(payload)
@@ -695,6 +745,8 @@ class Runtime:
             t = getattr(self, "_metrics_task", None)
             if t is not None:
                 t.cancel()
+                self._stall_task.cancel()
+            await self.push_last_telemetry()
             # resident actor pumps park on their wake events; release
             # them cleanly instead of tearing the loop down under them
             for st in self._actor_states.values():

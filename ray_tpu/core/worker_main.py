@@ -141,6 +141,7 @@ class WorkerServer:
         if method == "exit_worker":
             logger.info("exit requested: %s", p.get("reason"))
             threading.Thread(target=_exit_soon, daemon=True).start()
+            await self.rt.push_last_telemetry()  # has the 0.1 s before the exit
             return True
         if method == "ping":
             return {"pid": os.getpid(), "actor": bool(self.actor_instance)}
@@ -397,8 +398,9 @@ class WorkerServer:
                 # non-streaming async path (sync generators are bounded
                 # by the pool they occupy below).
                 async with sem if sem is not None else contextlib.nullcontext():
-                    async for item in fn(*args, **kwargs):
-                        await self._stream_send(conn, spec, state, item)
+                    with _maybe_execute_span(spec):
+                        async for item in fn(*args, **kwargs):
+                            await self._stream_send(conn, spec, state, item)
             else:
                 def pump():
                     # sync generator on the executor thread; each item ships

@@ -567,48 +567,50 @@ def _block_decode_rowwise(x, p, cache_k, cache_v, pos, config: LlamaConfig):
     absolute position of the new token in each row."""
     c = config
     B = x.shape[0]
-    h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-    positions = pos[:, None]  # (B, 1)
-    q = _rope(
-        jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype)),
-        positions, c.rope_theta,
-    )
-    kk = _rope(
-        jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype)),
-        positions, c.rope_theta,
-    )
-    vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
-    rows = jnp.arange(B)
-    T = cache_k.shape[1]
-    slot = pos % T if c.sliding_window else pos  # rolling buffer slots
-    cache_k = cache_k.at[rows, slot].set(kk[:, 0].astype(c.dtype))
-    cache_v = cache_v.at[rows, slot].set(vv[:, 0].astype(c.dtype))
-    # attention over each row's own prefix [0, pos[b]]
-    k_all, v_all = cache_k, cache_v
-    if c.q_per_kv > 1:
-        k_all = jnp.repeat(k_all, c.q_per_kv, axis=2)
-        v_all = jnp.repeat(v_all, c.q_per_kv, axis=2)
-    scores = jnp.einsum(
-        "bqhd,bthd->bhqt", q, k_all, preferred_element_type=jnp.float32
-    ) / math.sqrt(c.head_dim)
-    t_idx = jnp.arange(T)
-    if c.sliding_window:
-        # rolling buffer: reconstruct each slot's position per row
-        mask = _rolling_mask(
-            pos[:, None], t_idx[None, :], T, c.sliding_window
+    with jax.named_scope("decode_attn"):
+        h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
+        positions = pos[:, None]  # (B, 1)
+        q = _rope(
+            jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype)),
+            positions, c.rope_theta,
         )
-    else:
-        mask = t_idx[None, :] <= pos[:, None]  # (B, T)
-    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-    attn = jnp.einsum("bhqt,bthd->bqhd", probs, v_all)
-    x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
-    h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
-    x = x + jnp.einsum(
-        "bsm,me->bse", jax.nn.silu(gate) * up, p["w_down"].astype(c.dtype)
-    )
+        kk = _rope(
+            jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype)),
+            positions, c.rope_theta,
+        )
+        vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
+        rows = jnp.arange(B)
+        T = cache_k.shape[1]
+        slot = pos % T if c.sliding_window else pos  # rolling buffer slots
+        cache_k = cache_k.at[rows, slot].set(kk[:, 0].astype(c.dtype))
+        cache_v = cache_v.at[rows, slot].set(vv[:, 0].astype(c.dtype))
+        # attention over each row's own prefix [0, pos[b]]
+        k_all, v_all = cache_k, cache_v
+        if c.q_per_kv > 1:
+            k_all = jnp.repeat(k_all, c.q_per_kv, axis=2)
+            v_all = jnp.repeat(v_all, c.q_per_kv, axis=2)
+        scores = jnp.einsum(
+            "bqhd,bthd->bhqt", q, k_all, preferred_element_type=jnp.float32
+        ) / math.sqrt(c.head_dim)
+        t_idx = jnp.arange(T)
+        if c.sliding_window:
+            # rolling buffer: reconstruct each slot's position per row
+            mask = _rolling_mask(
+                pos[:, None], t_idx[None, :], T, c.sliding_window
+            )
+        else:
+            mask = t_idx[None, :] <= pos[:, None]  # (B, T)
+        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+        attn = jnp.einsum("bhqt,bthd->bqhd", probs, v_all)
+        x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
+    with jax.named_scope("decode_mlp"):
+        h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
+        gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
+        up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
+        x = x + jnp.einsum(
+            "bsm,me->bse", jax.nn.silu(gate) * up, p["w_down"].astype(c.dtype)
+        )
     return x, cache_k, cache_v
 
 

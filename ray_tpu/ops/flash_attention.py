@@ -240,6 +240,7 @@ def _fwd(q, k, v, scale):
             _out_struct((B, H, 2, S), jnp.float32, q),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse2[:, :, 0, :]
 
@@ -266,6 +267,7 @@ def _bwd(q, k, v, o, lse, do, scale):
         ),
         out_shape=_out_struct((B, H, S, D), q.dtype, q),
         interpret=_interpret(),
+        name="flash_dq",
     )(q, k, v, do, lse4, delta4)
     # For the dkv pass the grid iterates (kv, q): index maps swap i/j roles.
     qspec2 = pl.BlockSpec((1, 1, blk_q, D), lambda b, h, i, j: (b, h, j, 0))
@@ -284,6 +286,7 @@ def _bwd(q, k, v, o, lse, do, scale):
             _out_struct((B, H, S, D), v.dtype, v),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(q, k, v, do, lse4, delta4)
     return dq, dk, dv
 

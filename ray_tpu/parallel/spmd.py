@@ -198,11 +198,14 @@ def compile_train_step(
     """
 
     def step(state: TrainState, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        # scope names are metadata for the profiler's op line
+        with jax.named_scope("fwd_bwd"):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
         return (
             TrainState(state.step + 1, params, opt_state),

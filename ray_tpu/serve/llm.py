@@ -28,12 +28,47 @@ Wire-up::
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import heapq
 import itertools
 import time
 from typing import Any, List, Optional
 
 from ray_tpu import serve
+from ray_tpu.util import metrics, tracing
+
+#: histogram boundaries in ms, 1 ms .. 62 s in steps of 1.15x: a median
+#: read from the buckets by interpolation is within 5% of the samples'
+_MS_LADDER = tuple(round(1.15 ** k, 4) for k in range(80))
+#: always on, two observations per request, none per step
+_QUEUE_WAIT_MS = metrics.Histogram(
+    "llm_queue_wait_ms",
+    "stream() pushed the request -> the slot admitter popped it",
+    boundaries=_MS_LADDER, tag_keys=("outcome",),
+)
+_ENGINE_TTFT_MS = metrics.Histogram(
+    "llm_engine_ttft_ms",
+    "stream() pushed the request -> its first token was put on its queue",
+    boundaries=_MS_LADDER,
+)
+_OFF = contextlib.nullcontext()
+
+
+def _span(on: bool, name: str):
+    """A child span of the ambient one while tracing is on (asked once
+    per engine iteration, so an iteration is recorded whole or not)."""
+    return tracing.span(name) if on else _OFF
+
+
+class _Request:
+    __slots__ = ("prompt", "max_new", "queue", "pushed", "trace_id")
+
+    def __init__(self, prompt, max_new, queue, trace_id):
+        self.prompt = prompt
+        self.max_new = max_new
+        self.queue = queue          # per-request token queue
+        self.pushed = time.monotonic()
+        self.trace_id = trace_id    # of its llm.request span, if traced
 
 
 class _Slot:
@@ -76,10 +111,10 @@ class LLMEngine:
             self.cache_len = max_len
         self.cache = llama.init_cache(config, max_slots, self.cache_len)
         self.slots: List[Optional[_Slot]] = [None] * max_slots
-        # slot admitter queue: EDF heap of
-        # (deadline, seq, prompt, max_new, out_queue) — requests with a
-        # traffic-plane SLO overtake deadline-less ones (deadline=inf)
-        # at the free slot, and expired waiters are shed before prefill
+        # slot admitter queue: EDF heap of (deadline, seq, _Request) —
+        # requests with a traffic-plane SLO overtake deadline-less ones
+        # (deadline=inf) at the free slot, and expired waiters are shed
+        # before prefill
         self._pending: List[tuple] = []
         self._admit_seq = itertools.count()
         self._runner: Optional[asyncio.Task] = None
@@ -87,6 +122,7 @@ class LLMEngine:
         # admitter counters (bench / tests)
         self.admitted_total = 0
         self.shed_total = 0
+        self._steps = 0  # engine iterations begun (llm.step's `step`)
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -106,18 +142,31 @@ class LLMEngine:
             )
         q: asyncio.Queue = asyncio.Queue()
         deadline = get_request_deadline()
+        # the request's span is finished by hand: a `with` around the
+        # yields below would write the context variable of whoever
+        # drives this generator
+        request = tracing.span(
+            "llm.request", prompt_len=len(prompt),
+            max_new_tokens=int(max_new_tokens),
+        ) if tracing.enabled() else None
         heapq.heappush(self._pending, (
             deadline if deadline is not None else float("inf"),
-            next(self._admit_seq), list(prompt), int(max_new_tokens), q,
+            next(self._admit_seq),
+            _Request(list(prompt), int(max_new_tokens), q,
+                     request.trace_id if request else None),
         ))
         self._wake.set()
-        while True:
-            tok = await q.get()
-            if tok is _END:
-                return
-            if isinstance(tok, Exception):
-                raise tok
-            yield tok
+        try:
+            while True:
+                tok = await q.get()
+                if tok is _END:
+                    return
+                if isinstance(tok, Exception):
+                    raise tok
+                yield tok
+        finally:
+            if request is not None:
+                request.finish()
 
     # -- engine loop -----------------------------------------------------
     async def _run(self):
@@ -139,59 +188,66 @@ class LLMEngine:
                         await s.queue.put(_END)
                         self.slots[i] = None
                 while self._pending:
-                    _, _, _, _, q = heapq.heappop(self._pending)
+                    q = heapq.heappop(self._pending)[2].queue
                     await q.put(e)
                     await q.put(_END)
                 self.cache = self._llama.init_cache(
                     self.config, self.max_slots, self.cache_len
                 )
 
-    async def _run_inner(self):
+    async def _admit(self, on: bool) -> int:
+        """Admit pending requests into free slots (prefill), EDF: the
+        earliest-deadline waiter takes the free cache row, and a waiter
+        whose deadline lapsed in this queue is shed — prefill compute
+        for a response the client already gave up on would only delay
+        every live slot's next token.  Returns the requests prefilled."""
         import jax.numpy as jnp
-        import numpy as np
 
         llama = self._llama
         cfg = self.config
-        while True:
-            # admit pending requests into free slots (prefill), EDF:
-            # the earliest-deadline waiter takes the free cache row, and
-            # a waiter whose deadline lapsed in this queue is shed —
-            # prefill compute for a response the client already gave up
-            # on would only delay every live slot's next token
-            while self._pending and None in self.slots:
-                deadline, _, prompt, max_new, q = heapq.heappop(
-                    self._pending
+        prefilled = 0
+        while self._pending and None in self.slots:
+            deadline, _, req = heapq.heappop(self._pending)
+            q, prompt, max_new = req.queue, req.prompt, req.max_new
+            waited_ms = (time.monotonic() - req.pushed) * 1e3
+            if deadline <= time.monotonic():
+                from ray_tpu.serve.traffic.config import (
+                    RequestShedError,
                 )
-                if deadline <= time.monotonic():
-                    from ray_tpu.serve.traffic.config import (
-                        RequestShedError,
-                    )
 
-                    self.shed_total += 1
-                    await q.put(RequestShedError(
-                        "SLO budget exhausted before a decode slot "
-                        "freed up"
-                    ))
-                    await q.put(_END)
-                    continue
-                self.admitted_total += 1
-                if max_new <= 0:  # exact budget: zero tokens requested
-                    await q.put(_END)
-                    continue
-                slot = self.slots.index(None)
-                S0 = len(prompt)
-                if (
-                    S0 + max_new > self.max_len
-                    or S0 > self.max_prompt_len
-                    or S0 == 0
-                ):
-                    await q.put(ValueError(
-                        f"prompt of {S0} tokens + {max_new} new exceeds "
-                        f"max_len {self.max_len} (or prompt cap "
-                        f"{self.max_prompt_len})"
-                    ))
-                    await q.put(_END)
-                    continue
+                self.shed_total += 1
+                _QUEUE_WAIT_MS.observe(waited_ms, {"outcome": "shed"})
+                await q.put(RequestShedError(
+                    "SLO budget exhausted before a decode slot "
+                    "freed up"
+                ))
+                await q.put(_END)
+                continue
+            self.admitted_total += 1
+            S0 = len(prompt)
+            if max_new > 0 and (
+                S0 + max_new > self.max_len
+                or S0 > self.max_prompt_len
+                or S0 == 0
+            ):
+                _QUEUE_WAIT_MS.observe(waited_ms, {"outcome": "rejected"})
+                await q.put(ValueError(
+                    f"prompt of {S0} tokens + {max_new} new exceeds "
+                    f"max_len {self.max_len} (or prompt cap "
+                    f"{self.max_prompt_len})"
+                ))
+                await q.put(_END)
+                continue
+            _QUEUE_WAIT_MS.observe(waited_ms, {"outcome": "admitted"})
+            if max_new <= 0:  # exact budget: zero tokens requested
+                await q.put(_END)
+                continue
+            slot = self.slots.index(None)
+            with tracing.Span(
+                "llm.prefill", (req.trace_id, tracing.current()[1]),
+                {"slot": slot, "prompt_len": S0,
+                 "rows_stalled": self.max_slots - self.slots.count(None)},
+            ) if on else _OFF:
                 toks = jnp.asarray([prompt], jnp.int32)
 
                 def _prefill():
@@ -203,49 +259,85 @@ class LLMEngine:
                 logits, self.cache = await asyncio.to_thread(_prefill)
                 first = int(jnp.argmax(logits[0]))
                 await q.put(first)
-                if max_new <= 1:
-                    await q.put(_END)
-                    continue
-                self.slots[slot] = _Slot(
-                    queue=q, pos=S0, remaining=max_new - 1,
-                    last_token=first, max_pos=self.max_len - 1,
-                )
-            active = [i for i, s in enumerate(self.slots) if s is not None]
-            if not active:
+            _ENGINE_TTFT_MS.observe((time.monotonic() - req.pushed) * 1e3)
+            prefilled += 1
+            if max_new <= 1:
+                await q.put(_END)
+                continue
+            self.slots[slot] = _Slot(
+                queue=q, pos=S0, remaining=max_new - 1,
+                last_token=first, max_pos=self.max_len - 1,
+            )
+        return prefilled
+
+    async def _run_inner(self):
+        import jax.numpy as jnp
+        import numpy as np
+
+        llama = self._llama
+        cfg = self.config
+        while True:
+            if not self._pending and self.slots.count(None) == self.max_slots:
                 # idle: park until a request arrives
                 self._wake.clear()
-                if not self._pending:
+                with tracing.root("llm.idle") if tracing.enabled() else _OFF:
                     await self._wake.wait()
                 continue
-            # one fused decode step over ALL slots (inactive rows decode
-            # into their own rows harmlessly; shape stays constant)
-            tokens = np.zeros((self.max_slots,), np.int32)
-            pos = np.zeros((self.max_slots,), np.int32)
-            for i, s in enumerate(self.slots):
-                if s is not None:
-                    tokens[i] = s.last_token
-                    pos[i] = s.pos
+            # the spans of one iteration (util/tracing.py; names and the
+            # metric each is read by: PERF.md section 3)
+            on = tracing.enabled()
+            self._steps += 1
+            with (tracing.root("llm.step", step=self._steps)
+                  if on else _OFF) as step:
+                admitted = 0
+                if self._pending and None in self.slots:
+                    with _span(on, "llm.step.admit"):
+                        admitted = await self._admit(on)
+                active = [
+                    i for i, s in enumerate(self.slots) if s is not None
+                ]
+                if on:
+                    step.attrs.update(active=len(active), admitted=admitted)
+                if not active:
+                    continue
+                # one fused decode step over ALL slots (inactive rows
+                # decode into their own rows harmlessly; shape stays
+                # constant)
+                with _span(on, "llm.step.build"):
+                    tokens = np.zeros((self.max_slots,), np.int32)
+                    pos = np.zeros((self.max_slots,), np.int32)
+                    for i in active:
+                        tokens[i] = self.slots[i].last_token
+                        pos[i] = self.slots[i].pos
 
-            def _step(t=tokens, p=pos):
-                return llama.decode_step_rowwise(
-                    self.params, jnp.asarray(t), self.cache,
-                    jnp.asarray(p), cfg,
-                )
+                    def _step(t=tokens, p=pos):
+                        t, p = jnp.asarray(t), jnp.asarray(p)
+                        # the thread runs in a copy of this context, so
+                        # the span is llm.step.dispatch's child: what is
+                        # left of it after the hop and the two copies
+                        with _span(on, "llm.step.launch"):
+                            return llama.decode_step_rowwise(
+                                self.params, t, self.cache, p, cfg,
+                            )
 
-            logits, self.cache = await asyncio.to_thread(_step)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            for i in active:
-                s = self.slots[i]
-                tok = int(nxt[i])
-                await s.queue.put(tok)
-                s.last_token = tok
-                s.pos += 1
-                s.remaining -= 1
-                if s.remaining <= 0 or s.pos >= s.max_pos:
-                    await s.queue.put(_END)
-                    self.slots[i] = None
-            # let admissions/consumers run between steps
-            await asyncio.sleep(0)
+                with _span(on, "llm.step.dispatch"):
+                    logits, self.cache = await asyncio.to_thread(_step)
+                with _span(on, "llm.step.sync"):
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                with _span(on, "llm.step.deliver"):
+                    for i in active:
+                        s = self.slots[i]
+                        tok = int(nxt[i])
+                        await s.queue.put(tok)
+                        s.last_token = tok
+                        s.pos += 1
+                        s.remaining -= 1
+                        if s.remaining <= 0 or s.pos >= s.max_pos:
+                            await s.queue.put(_END)
+                            self.slots[i] = None
+                # let admissions/consumers run between steps
+                with _span(on, "llm.step.yield"):
+                    await asyncio.sleep(0)
 
 
 @serve.deployment

@@ -12,6 +12,15 @@ import inspect
 from typing import Any
 
 import ray_tpu
+from ray_tpu.util import tracing
+
+
+def _item_span():
+    """``serve.stream_item`` while tracing is on: an item taken from the
+    user's generator → the streaming transport came back for the next
+    one.  Finished by hand: the caller is a generator, and the context
+    variable belongs to the transport's task."""
+    return tracing.span("serve.stream_item") if tracing.enabled() else None
 
 
 def _resolve_handle_refs(value, app_name: str):
@@ -158,12 +167,18 @@ class ReplicaActor:
                 result = await result
             if _inspect.isasyncgen(result):
                 async for item in result:
+                    sp = _item_span()
                     yield item
+                    if sp is not None:
+                        sp.finish()
             elif hasattr(result, "__iter__") and not isinstance(
                 result, (str, bytes, dict)
             ):
                 for item in result:
+                    sp = _item_span()
                     yield item
+                    if sp is not None:
+                        sp.finish()
             else:
                 raise TypeError(
                     f"streaming call to {method!r} returned "

@@ -142,3 +142,6 @@ def worker_stacks(worker_id: str) -> Dict[str, Any]:
 # single implementation lives in util.events; re-exported here so the
 # state API surface is complete (ray: list_cluster_events)
 from ray_tpu.util.events import list_events  # noqa: E402,F401
+# likewise the cluster's spans: util.tracing.collect(trace_id=None,
+# since_ns=None, until_ns=None, name_prefix=None)
+from ray_tpu.util.tracing import collect as list_spans  # noqa: E402,F401

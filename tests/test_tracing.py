@@ -1,5 +1,6 @@
-"""Distributed tracing: spans around submit/execute, W3C context in the
-TaskSpec, cluster-wide aggregation via GCS events.
+"""Distributed tracing: spans around submit/execute and inside the
+decode engine, W3C context in the TaskSpec, cluster-wide aggregation in
+the GCS's span table (one batched push per process per interval).
 
 (reference: python/ray/util/tracing/tracing_helper.py — _ray_trace_ctx
 propagation + submit/execute span wrappers; here the OpenTelemetry API
@@ -7,31 +8,90 @@ is bridged when an SDK provider exists and a built-in recorder serves
 otherwise, since the image ships no OTel SDK.)
 """
 
+import asyncio
 import os
 import time
+import tracemalloc
 
 import pytest
 
 import ray_tpu
-from ray_tpu.util import events, tracing
+from ray_tpu.common.config import cfg
+from ray_tpu.util import events, metrics, state, tracing
+
+PUSH_INTERVAL_S = 0.5
 
 
 @pytest.fixture(scope="module")
 def traced_cluster():
     os.environ["RT_TRACING_ENABLED"] = "1"  # workers inherit
+    os.environ["RT_METRICS_PUSH_INTERVAL_S"] = str(PUSH_INTERVAL_S)
+    cfg.override("metrics_push_interval_s", PUSH_INTERVAL_S)
     tracing.enable()
     ray_tpu.init(num_cpus=4, num_tpus=0)
     yield
     ray_tpu.shutdown()
     tracing.disable()
+    cfg.reset()
     os.environ.pop("RT_TRACING_ENABLED", None)
+    os.environ.pop("RT_METRICS_PUSH_INTERVAL_S", None)
 
 
-def _span_events():
-    return [
-        e for e in events.list_events()
-        if e.get("source") == "tracing"
-    ]
+@pytest.fixture
+def tracing_off():
+    was = tracing._enabled
+    tracing.disable()
+    tracing.clear()
+    yield
+    if was:
+        tracing.enable()
+
+
+@pytest.fixture
+def pushes(traced_cluster, monkeypatch):
+    """Every metrics_push this process sends while the test runs."""
+    from ray_tpu.core.runtime import get_runtime
+
+    rt = get_runtime()
+    sent = []
+    notify = rt.gcs.notify
+
+    async def counting(method, payload):
+        if method == "metrics_push":
+            sent.append((time.monotonic(), payload))
+        return await notify(method, payload)
+
+    monkeypatch.setattr(rt.gcs, "notify", counting)
+    return sent
+
+
+def _run_engine(requests=((4, 8), (5, 8), (3, 8))):
+    """Tokens of a few concurrent requests through a tiny LLMEngine on
+    two slots, so that one request waits for a slot."""
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import LLMEngine
+
+    async def main():
+        config = llama.LlamaConfig.tiny()
+        engine = LLMEngine(llama.init(jax.random.key(0), config), config,
+                           max_slots=2, max_len=64)
+
+        async def one(new, prompt_len):
+            prompt = list(range(1, prompt_len + 1))
+            return [t async for t in engine.stream(prompt, new)]
+
+        return await asyncio.gather(*(one(*r) for r in requests))
+
+    return asyncio.run(main())
+
+
+def _histogram_count(name: str, tags_key: str = "[]") -> float:
+    for m in metrics.registry_snapshot():
+        if m["name"] == name:
+            return m["series"].get(f"{tags_key}|le=+Inf", 0.0)
+    return 0.0
 
 
 class TestTracing:
@@ -53,21 +113,24 @@ class TestTracing:
         submit = [s for s in local if s["name"].startswith("submit")]
         assert submit, local
         trace_id = submit[-1]["trace_id"]
-        # the worker-side execute span lands in the GCS event ring with
+        # the worker-side execute span lands in the GCS span table with
         # the SAME trace id, parented under the submit span
         deadline = time.monotonic() + 30
         execs = []
         while time.monotonic() < deadline and not execs:
             execs = [
-                e for e in _span_events()
-                if e.get("trace_id") == trace_id
-                and e.get("name", "").startswith("execute")
+                e for e in state.list_spans(trace_id=trace_id)
+                if e["name"].startswith("execute")
             ]
             time.sleep(0.2)
         assert execs, "no execute span exported"
         f = execs[0]
         assert f["parent_id"] == submit[-1]["span_id"]
         assert f["pid"] != os.getpid()  # actually ran in the worker
+        assert isinstance(f["start_ns"], int) and f["end_ns"] >= f["start_ns"]
+        # spans have a table of their own: none among the cluster events
+        assert not [e for e in events.list_events(limit=2000)
+                    if e.get("source") == "tracing"]
 
     def test_actor_call_chain_keeps_one_trace(self, traced_cluster):
         tracing.clear()
@@ -93,9 +156,7 @@ class TestTracing:
         names = set()
         while time.monotonic() < deadline:
             names = {
-                e.get("name", "")
-                for e in _span_events()
-                if e.get("trace_id") == trace_id
+                e["name"] for e in tracing.collect(trace_id=trace_id)
             }
             if any(
                 n.startswith("execute") and n.endswith("inner")
@@ -113,19 +174,40 @@ class TestTracing:
             for n in names
         ), names
 
-    def test_disabled_tracing_adds_nothing(self, traced_cluster):
-        tracing.disable()
+    def test_disabled_tracing_adds_nothing(self, pushes, tracing_off):
+        from ray_tpu.core.runtime import get_runtime
+        from ray_tpu.serve import llm
+
+        @ray_tpu.remote
+        def untraced():
+            return 1
+
+        ray_tpu.get(untraced.remote(), timeout=60)
+        assert tracing.spans() == [] and tracing.drain() == []
+        assert tracing.current() is None  # no context-variable write
+        # a span site that is off allocates nothing in the recorder
+        tracemalloc.start()
         try:
-            tracing.clear()
-
-            @ray_tpu.remote
-            def untraced():
-                return 1
-
-            ray_tpu.get(untraced.remote(), timeout=60)
-            assert tracing.spans() == []
+            before = tracemalloc.take_snapshot()
+            for _ in range(1000):
+                if tracing.enabled():
+                    tracing.span("never")
+                with llm._span(tracing.enabled(), "never"):
+                    pass
+            after = tracemalloc.take_snapshot()
         finally:
-            tracing.enable()
+            tracemalloc.stop()
+        grown = [
+            d for d in after.compare_to(before, "filename")
+            if d.size_diff > 0 and d.traceback[0].filename in (
+                tracing.__file__, llm.__file__)
+        ]
+        assert not grown, grown
+        # and nothing is sent: a push now carries no spans
+        rt = get_runtime()
+        rt._run(rt.push_telemetry())
+        time.sleep(2 * PUSH_INTERVAL_S)
+        assert not [p for _t, p in pushes if p.get("spans")]
 
     def test_span_records_error_attribute(self):
         with pytest.raises(ValueError):
@@ -133,3 +215,162 @@ class TestTracing:
                 raise ValueError("x")
         s = tracing.spans()[-1]
         assert s["name"] == "boom" and s["attributes"]["error"] == "ValueError"
+
+    def test_spans_are_exported_in_batches(self, pushes):
+        """N spans cost at most one RPC per push interval, and each is
+        sent exactly once."""
+        from ray_tpu.core.runtime import get_runtime
+
+        rt = get_runtime()
+        rt._run(rt.push_telemetry())  # what earlier tests left
+        del pushes[:]
+        t0 = time.monotonic()
+        made = []
+        while time.monotonic() - t0 < 2.5 * PUSH_INTERVAL_S:
+            with tracing.span("batched") as sp:
+                made.append(sp.span_id)
+            time.sleep(0.002)
+        time.sleep(1.5 * PUSH_INTERVAL_S)
+        elapsed = time.monotonic() - t0
+        with_spans = [p for _t, p in pushes if p.get("spans")]
+        assert 1 <= len(with_spans) <= elapsed / PUSH_INTERVAL_S + 1
+        assert len(pushes) <= elapsed / PUSH_INTERVAL_S + 1
+        sent = [row[2] for p in with_spans for row in p["spans"]
+                if row[0] == "batched"]
+        assert sorted(sent) == sorted(made) and len(made) > 100
+        got = tracing.collect(name_prefix="batched")
+        assert sorted(s["span_id"] for s in got) == sorted(made)
+        assert {s["pid"] for s in got} == {os.getpid()}
+
+    def test_ring_is_drained_exactly_once(self):
+        tracing.clear()
+        for i in range(5):
+            with tracing.span("drained", i=i):
+                pass
+        first = tracing.drain()
+        assert [r[0] for r in first] == ["drained"] * 5
+        assert [r[6]["i"] for r in first] == list(range(5))
+        assert tracing.drain() == []
+        assert len(tracing.spans()) == 5  # the ring keeps them for reading
+        with tracing.span("drained", i=5):
+            pass
+        assert [r[6]["i"] for r in tracing.drain()] == [5]
+        # ids: a per-process prefix and a counter, W3C sizes
+        a, b = first[0], first[1]
+        assert len(a[1]) == 32 and len(a[2]) == 16
+        assert a[2][:8] == b[2][:8] and a[2] != b[2]
+
+    def test_profiler_session_turns_spans_on(self, tracing_off, tmp_path):
+        """Between start_trace and stop_trace spans are recorded without
+        the operator's switch, and show in the trace's host plane."""
+        import jax
+        from jax.profiler import ProfileData
+
+        from chipbench import trace_reduce
+
+        jax.numpy.zeros(1).block_until_ready()
+        assert not tracing.enabled()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert tracing.enabled()
+            with tracing.span("probe.in_session", k=1) as sp:
+                time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        assert not tracing.enabled()
+        with tracing.span("probe.after"):  # a site would not get here
+            pass
+        assert [s["name"] for s in tracing.spans()][0] == "probe.in_session"
+        data = ProfileData.from_file(trace_reduce.find_xplane(str(tmp_path)))
+        found = [
+            (plane.name, dict(e.stats))
+            for plane in data.planes for line in plane.lines
+            for e in line.events if e.name == "probe.in_session"
+        ]
+        assert found, [p.name for p in data.planes]
+        plane, stats = found[0]
+        assert plane.startswith("/host:")
+        assert stats.get("span_id") == sp.span_id
+
+    def test_engine_spans_and_histograms(self):
+        ttft0 = _histogram_count("llm_engine_ttft_ms")
+        wait0 = _histogram_count("llm_queue_wait_ms",
+                                 '[["outcome", "admitted"]]')
+        was = tracing._enabled
+        tracing.enable()
+        tracing.clear()
+        try:
+            out = _run_engine()
+        finally:
+            if not was:
+                tracing.disable()
+        assert [len(o) for o in out] == [4, 5, 3]
+        rows = tracing.spans()
+        steps = [s for s in rows if s["name"] == "llm.step"]
+        assert steps and [s["attributes"]["step"] for s in steps] == sorted(
+            s["attributes"]["step"] for s in steps)
+        decoding = [s for s in steps if s["attributes"]["active"]]
+        assert decoding
+        for step in decoding:
+            kids = sorted(
+                (s for s in rows if s["parent_id"] == step["span_id"]),
+                key=lambda s: s["start_ns"],
+            )
+            names = [k["name"] for k in kids]
+            if step["attributes"]["admitted"]:
+                assert names[0] == "llm.step.admit"
+                names = names[1:]
+            assert names == [
+                "llm.step.build", "llm.step.dispatch", "llm.step.sync",
+                "llm.step.deliver", "llm.step.yield",
+            ]
+            assert all(k["trace_id"] == step["trace_id"] for k in kids)
+            assert kids[0]["start_ns"] >= step["start_ns"]
+            assert kids[-1]["end_ns"] <= step["end_ns"]
+            for a, b in zip(kids, kids[1:]):
+                assert a["end_ns"] <= b["start_ns"]
+        requests = [s for s in rows if s["name"] == "llm.request"]
+        prefills = [s for s in rows if s["name"] == "llm.prefill"]
+        assert len(requests) == len(prefills) == 3
+        assert ({p["trace_id"] for p in prefills}
+                == {r["trace_id"] for r in requests})
+        admits = {s["span_id"] for s in rows if s["name"] == "llm.step.admit"}
+        assert all(p["parent_id"] in admits for p in prefills)
+        # the third request found both slots taken
+        assert sorted(p["attributes"]["rows_stalled"] for p in prefills) == [0, 1, 1]
+        assert sum(s["attributes"]["admitted"] for s in steps) == 3
+        # the two histograms: one observation each per request
+        assert _histogram_count("llm_engine_ttft_ms") == ttft0 + 3
+        assert _histogram_count(
+            "llm_queue_wait_ms", '[["outcome", "admitted"]]') == wait0 + 3
+
+    def test_engine_records_nothing_when_off(self, tracing_off):
+        ttft0 = _histogram_count("llm_engine_ttft_ms")
+        out = _run_engine()
+        assert [len(o) for o in out] == [4, 5, 3]
+        assert tracing.spans() == [] and tracing.drain() == []
+        assert tracing.open_span() is None
+        # the counters are always on
+        assert _histogram_count("llm_engine_ttft_ms") == ttft0 + 3
+
+    def test_a_stalled_loop_says_so_once(self, traced_cluster, caplog):
+        """The io loop held for over a second: one log line with the CPU
+        time used meanwhile and the open span, and the counter moves."""
+        from ray_tpu.core.runtime import get_runtime
+
+        rt = get_runtime()
+
+        def hold():
+            with tracing.span("holds.the.loop"):
+                time.sleep(1.4)
+
+        with caplog.at_level("WARNING", logger="ray_tpu.core.runtime"):
+            rt._loop.call_soon_threadsafe(hold)
+            time.sleep(2.0)
+        lines = [r.getMessage() for r in caplog.records
+                 if "stood still" in r.getMessage()]
+        assert len(lines) == 1, lines
+        assert f"driver pid {os.getpid()}" in lines[0]
+        lost = [m for m in metrics.registry_snapshot()
+                if m["name"] == "loop_stall_seconds_total"]
+        assert lost and lost[0]["series"]['[["role", "driver"]]'] >= 1.0
