@@ -350,7 +350,11 @@ def _gen_step(params, padded, length, key, *, config, temperature):
 def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     """Fixed-bucket KV cache: (L, B, max_len, KV, D) per tensor, bf16.
     Static shapes — one compiled prefill + one compiled decode step
-    serve any request up to max_len.
+    serve any request up to max_len.  The steps take it donated and
+    hand it back: ``decode_step_rowwise`` carries it whole through its
+    layer loop and writes only each row's new K/V in place, and
+    attention reads it as stored — KV heads are never expanded to the
+    query heads (``_grouped_attention``).
 
     With ``sliding_window`` the cache is a ROLLING buffer (slot =
     position mod max_len), so ``max_len`` can be as small as
@@ -381,9 +385,30 @@ def _rolling_mask(q_pos, t_idx, T: int, window: int):
     position q holds position q - ((q - s) mod T) — the newest position
     <= q congruent to s.  Valid iff non-negative and inside the window.
     q_pos: (..., 1)-broadcastable positions; t_idx: (T,) slot indices.
-    The ONE implementation both cached-attention paths share."""
+    The ONE implementation both callers of ``_grouped_attention`` share."""
     t_pos = q_pos - ((q_pos - t_idx) % T)
     return (t_pos >= 0) & (t_pos > q_pos - window)
+
+
+def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
+    """The ONE cached-attention body.  q: (B, Sq, H, D) attends over the
+    cache AS STORED, (B, T, KV, D): the H = KV * G query heads fold to
+    (KV, G) and contract against their KV head directly, so K/V are
+    never expanded (no ``jnp.repeat``) and every cached byte is read
+    once, in the cache's dtype.  MHA is G = 1, the same code.  mask:
+    (B or 1, Sq, T), True where query q may see slot t.  Scores and
+    softmax in f32, probabilities and values in ``config.dtype``."""
+    c = config
+    B, Sq, H, D = q.shape
+    KV = k_cache.shape[2]
+    q = q.reshape(B, Sq, KV, H // KV, D)
+    scores = jnp.einsum(
+        "bqkgd,btkd->bkgqt", q, k_cache, preferred_element_type=jnp.float32
+    ) / math.sqrt(D)
+    scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    out = jnp.einsum("bkgqt,btkd->bqkgd", probs, v_cache)
+    return out.reshape(B, Sq, H, D)
 
 
 def _cached_attention(q, k_cache, v_cache, pos, config: LlamaConfig):
@@ -391,14 +416,8 @@ def _cached_attention(q, k_cache, v_cache, pos, config: LlamaConfig):
     masked.  Works for prefill (Sq = prompt len, pos = len-1) and decode
     (Sq = 1)."""
     c = config
-    B, Sq, H, D = q.shape
+    Sq = q.shape[1]
     T = k_cache.shape[1]
-    if c.q_per_kv > 1:
-        k_cache = jnp.repeat(k_cache, c.q_per_kv, axis=2)
-        v_cache = jnp.repeat(v_cache, c.q_per_kv, axis=2)
-    scores = jnp.einsum(
-        "bqhd,bthd->bhqt", q, k_cache, preferred_element_type=jnp.float32
-    ) / math.sqrt(D)
     # causal within the query block + bounded by pos overall
     q_pos = pos - (Sq - 1) + jnp.arange(Sq)  # absolute position per query
     t_idx = jnp.arange(T)
@@ -410,9 +429,7 @@ def _cached_attention(q, k_cache, v_cache, pos, config: LlamaConfig):
         )
     else:
         mask = t_idx[None, :] <= q_pos[:, None]  # (Sq, T)
-    scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-    return jnp.einsum("bhqt,bthd->bqhd", probs, v_cache)
+    return _grouped_attention(q, k_cache, v_cache, mask[None], c)
 
 
 def _block_cached(x, p, cache_k, cache_v, start, config: LlamaConfig):
@@ -527,9 +544,12 @@ def generate_kv(params: Params, prompt, config: LlamaConfig, *,
 # module-level jits: caches keyed by (config, shapes, temperature) so
 # repeated generate_kv calls — e.g. per serve request — reuse ONE
 # compiled prefill and ONE compiled decode step.  The cache buffers are
-# DONATED: the (L, B, max_len, KV, D) k/v arrays update in place instead
-# of being copied every token (the copy would dominate decode bandwidth
-# on a real config).
+# DONATED, so the (L, B, max_len, KV, D) k/v arrays are returned in the
+# buffers they came in.  Donation alone does not stop copies INSIDE the
+# step: forward_cached hands the slabs to its layer scan as xs -> ys,
+# which copies each slab once a call — nothing beside a prefill's
+# compute, but 9% of the serving decode step on the chip (PERF.md, PR
+# 25), which is why decode_step_rowwise carries the cache instead.
 _prefill_jit = jax.jit(
     forward_cached, static_argnames="config", donate_argnames=("cache",)
 )
@@ -562,9 +582,12 @@ def _decode_step(params, tok, cache, start, key, *, config, temperature):
 # one fused XLA step for the whole slot batch).
 
 
-def _block_decode_rowwise(x, p, cache_k, cache_v, pos, config: LlamaConfig):
-    """One block for ONE new token per row.  x: (B, 1, E); pos: (B,)
-    absolute position of the new token in each row."""
+def _block_decode_rowwise(x, p, cache_k, cache_v, layer, pos,
+                          config: LlamaConfig):
+    """Block ``layer`` for ONE new token per row.  x: (B, 1, E); pos:
+    (B,) absolute position of the new token in each row; cache_k/v: the
+    WHOLE (L, B, T, KV, D) cache, of which only the new row at
+    [layer, b, slot[b]] is written."""
     c = config
     B = x.shape[0]
     with jax.named_scope("decode_attn"):
@@ -580,18 +603,11 @@ def _block_decode_rowwise(x, p, cache_k, cache_v, pos, config: LlamaConfig):
         )
         vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
         rows = jnp.arange(B)
-        T = cache_k.shape[1]
+        T = cache_k.shape[2]
         slot = pos % T if c.sliding_window else pos  # rolling buffer slots
-        cache_k = cache_k.at[rows, slot].set(kk[:, 0].astype(c.dtype))
-        cache_v = cache_v.at[rows, slot].set(vv[:, 0].astype(c.dtype))
+        cache_k = cache_k.at[layer, rows, slot].set(kk[:, 0].astype(c.dtype))
+        cache_v = cache_v.at[layer, rows, slot].set(vv[:, 0].astype(c.dtype))
         # attention over each row's own prefix [0, pos[b]]
-        k_all, v_all = cache_k, cache_v
-        if c.q_per_kv > 1:
-            k_all = jnp.repeat(k_all, c.q_per_kv, axis=2)
-            v_all = jnp.repeat(v_all, c.q_per_kv, axis=2)
-        scores = jnp.einsum(
-            "bqhd,bthd->bhqt", q, k_all, preferred_element_type=jnp.float32
-        ) / math.sqrt(c.head_dim)
         t_idx = jnp.arange(T)
         if c.sliding_window:
             # rolling buffer: reconstruct each slot's position per row
@@ -600,9 +616,12 @@ def _block_decode_rowwise(x, p, cache_k, cache_v, pos, config: LlamaConfig):
             )
         else:
             mask = t_idx[None, :] <= pos[:, None]  # (B, T)
-        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-        attn = jnp.einsum("bhqt,bthd->bqhd", probs, v_all)
+        attn = _grouped_attention(
+            q,
+            lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False),
+            lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False),
+            mask[:, None, :], c,
+        )
         x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     with jax.named_scope("decode_mlp"):
         h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
@@ -621,18 +640,22 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
     tokens: (B,) int32 last token per row; pos: (B,) its absolute
     position.  Returns (logits (B, V) f32, new cache).  Inactive rows
     simply keep decoding garbage into their own slots — the engine masks
-    them out — so the compiled shape never changes."""
+    them out — so the compiled shape never changes.
+
+    The (donated) cache rides the layer loop whole, as its carry: each
+    layer writes one new K/V row per sequence in place and reads its own
+    slab once, unexpanded; no slab is copied."""
     c = config
     x = params["tok_embed"].astype(c.dtype)[tokens][:, None, :]
 
     def body(carry, layer):
-        xx, _ = carry
-        p, ck, cv = layer
-        xx, ck, cv = _block_decode_rowwise(xx, p, ck, cv, pos, c)
-        return (xx, None), (ck, cv)
+        xx, ck, cv = carry
+        p, l = layer
+        return _block_decode_rowwise(xx, p, ck, cv, l, pos, c), None
 
-    (x, _), (new_k, new_v) = lax.scan(
-        body, (x, None), (params["blocks"], cache["k"], cache["v"])
+    (x, new_k, new_v), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(c.num_layers)),
     )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = jnp.einsum(

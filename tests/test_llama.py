@@ -334,3 +334,70 @@ class TestKVCacheDecode:
         cfg = llama.LlamaConfig.tiny(num_heads=4, num_kv_heads=2)
         cache = llama.init_cache(cfg, 3, 32)
         assert cache["k"].shape == (2, 3, 32, 2, 16)
+
+
+class TestRowwiseDecode:
+    """The continuous batcher's step: rows at different positions in one
+    cache, against the dense forward of each row's own sequence."""
+
+    T, STEPS = 12, 4
+    # row -> prompt length; row 0 starts decoding at pos 0 with nothing
+    # prefilled, row 2 ends at pos T - 1, row 3 idles (token 0, pos 0)
+    PROMPTS = {1: 3, 2: 8}
+
+    def _setup(self, kv_heads, window):
+        cfg = llama.LlamaConfig.tiny(
+            num_heads=4, num_kv_heads=kv_heads, sliding_window=window
+        )
+        params = llama.init(jax.random.key(0), cfg)
+        seqs = jax.random.randint(jax.random.key(11), (3, self.T), 0, 256)
+        # causal: position p of the padded row is the row's own prefix
+        dense = np.asarray(llama.forward(params, seqs, cfg))
+        cache = llama.init_cache(cfg, 4, self.T)
+        for row, n in self.PROMPTS.items():
+            logits, cache = llama.prefill_into_slot(
+                params, seqs[row:row + 1, :n], cache, jnp.int32(row), cfg
+            )
+            np.testing.assert_allclose(
+                np.asarray(logits[0]), dense[row, n - 1], atol=3e-4, rtol=3e-4
+            )
+        return cfg, params, np.asarray(seqs), dense, cache
+
+    @pytest.mark.parametrize("window", [0, 5])
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1])  # G = 1, 2, 4
+    def test_rows_match_dense_forward(self, kv_heads, window):
+        cfg, params, seqs, dense, cache = self._setup(kv_heads, window)
+        start = np.array([0, 3, 8, 0])
+        for step in range(self.STEPS):
+            pos = start + np.array([step, step, step, 0])
+            tokens = np.append(seqs[np.arange(3), pos[:3]], 0)
+            logits, cache = llama.decode_step_rowwise(
+                params, jnp.asarray(tokens, jnp.int32), cache,
+                jnp.asarray(pos, jnp.int32), cfg,
+            )
+            for row in range(3):
+                np.testing.assert_allclose(
+                    np.asarray(logits[row]), dense[row, pos[row]],
+                    atol=3e-4, rtol=3e-4,
+                    err_msg=f"row {row} at pos {pos[row]} (step {step})",
+                )
+        assert pos[2] == self.T - 1
+
+    def test_rows_do_not_leak(self):
+        """Whatever another row's cache holds, a row's logits are the
+        same to the bit."""
+        cfg, params, seqs, _, cache = self._setup(2, 0)
+        noise = {
+            n: a.at[:, jnp.array([0, 2, 3])].set(
+                jax.random.normal(jax.random.key(i), a[:, :3].shape, a.dtype)
+            )
+            for i, (n, a) in enumerate(cache.items())
+        }
+        tokens = jnp.asarray([5, seqs[1, 3], 7, 0], jnp.int32)
+        pos = jnp.asarray([6, 3, 11, 0], jnp.int32)
+        clean, _ = llama.decode_step_rowwise(params, tokens, cache, pos, cfg)
+        noisy, _ = llama.decode_step_rowwise(params, tokens, noise, pos, cfg)
+        np.testing.assert_array_equal(
+            np.asarray(clean[1]), np.asarray(noisy[1])
+        )
+        assert not np.array_equal(np.asarray(clean[0]), np.asarray(noisy[0]))

@@ -1,0 +1,94 @@
+"""The serving decode step at real widths, compiled for the chip without
+the chip (see tests/test_chip_compile.py for the kernels' version).
+
+What is asserted is what PR 25 removed and a later change to
+``models/llama.py`` could bring back unseen by any CPU test: K/V slabs
+expanded across their query group (`jnp.repeat`, or an einsum the
+compiler lowers to a broadcast) and whole-slab copies through the layer
+loop.  Both show as temporaries of the compiled program — 3.36 GB
+before, under 1 MB after — and as arrays of the expanded shape in its
+text.  A compile that passes is not a chip run: nothing executes here.
+"""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import llama
+
+# the compiler otherwise logs under /tmp
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+CONFIG_FILE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "chipbench", "configs", "internlm2-7b-l16.json",
+)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a described v5e 2x2, or skip."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """An entry written for a described chip cannot be read back without
+    one, and the next run would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_decode_step_reads_the_cache_once(v5e_chip, no_compile_cache):
+    # the configuration as the benchmark's serving cells run it
+    from chipbench.jobs.serve_llm import llama_config
+
+    with open(CONFIG_FILE) as f:
+        served = json.load(f)
+    config = llama_config(served)
+    slots, max_len = served["serving"]["max_slots"], served["serving"]["max_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    compiled = llama.decode_step_rowwise.lower(
+        params, rows, cache, rows, config
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 * 2**20, mem
+    # the donated cache is updated in place, not returned as a fresh one
+    assert mem.alias_size_in_bytes >= 2 * 2**30, mem
+    text = compiled.as_text()
+    kv, g, d = config.num_kv_heads, config.q_per_kv, config.head_dim
+    assert (slots, max_len, kv, g, d) == (32, 1024, 8, 4, 128)
+    for expanded in (f"[{slots},{max_len},{kv},{g},{d}]",
+                     f"[{slots},{max_len},{kv * g},{d}]"):
+        assert expanded not in text, f"a K/V slab is expanded to {expanded}"
