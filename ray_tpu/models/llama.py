@@ -12,6 +12,19 @@ sequence parallelism, and a chunked cross-entropy for HBM-sized logits.
 Grouped-query attention: num_kv_heads < num_heads shares each KV head
 across num_heads // num_kv_heads query heads (Llama-2-70B/Llama-3
 layout; num_kv_heads == num_heads gives classic MHA).
+
+The feed-forward is ONE function, ``_ffn``, called by the three block
+bodies (training ``_block``, ``_block_cached`` for prefill,
+``_block_decode_rowwise``).  With ``num_experts > 0`` it is a sparse
+expert layer as OLMoE has it: softmax router over all experts in
+float32, top-k, weights NOT renormalised, every routed (token, expert)
+pair computed through ``ops/grouped_matmul.py`` — no capacity, no
+dropped token, no expert applied to a token that did not choose it.
+``qk_norm`` adds OLMoE's RMSNorm over the whole projected query and
+key vectors before they are split into heads.  ``forward`` / ``loss_fn``
+run the same layer, which is what the CPU comparison with the float32
+reference needs; there is no auxiliary load-balancing loss and no
+training cell for it (ROADMAP R1, training half).
 """
 
 from __future__ import annotations
@@ -52,6 +65,14 @@ class LlamaConfig:
     xent_chunk: int = 0
     scan_unroll: int = 1
     tie_embeddings: bool = False
+    # sparse experts (OLMoE): 0 = dense SwiGLU of width mlp_dim.  With
+    # num_experts > 0 every block's feed-forward is num_experts SwiGLU
+    # experts of width expert_dim, experts_per_token of them per token.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    # RMSNorm over the whole projected q and k vectors (before heads)
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -103,6 +124,15 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if config.num_experts:
+        blk.update({
+            "w_router": ("layers", "embed", None),
+            "w_gate": ("layers", "expert", "embed", "mlp"),
+            "w_up": ("layers", "expert", "embed", "mlp"),
+            "w_down": ("layers", "expert", "mlp", "embed"),
+        })
+    if config.qk_norm:
+        blk.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
     out = {
         "tok_embed": ("vocab", "embed"),
         "blocks": blk,
@@ -116,10 +146,13 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
 def init(rng, config: LlamaConfig) -> Params:
     c = config
     dt = c.param_dtype
-    L, E, H, KV, D, M = (
-        c.num_layers, c.embed_dim, c.num_heads, c.num_kv_heads,
-        c.head_dim, c.mlp_dim,
+    L, E, H, KV, D = (
+        c.num_layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim,
     )
+    # dense: one SwiGLU of width mlp_dim; experts: the same three
+    # matrices behind a leading expert axis, of width expert_dim
+    X = (c.num_experts,) if c.num_experts else ()
+    M = c.expert_dim if c.num_experts else c.mlp_dim
     k = jax.random.split(rng, 8)
     std = 0.02
     resid_std = std / math.sqrt(2 * L)
@@ -136,12 +169,21 @@ def init(rng, config: LlamaConfig) -> Params:
             "wv": norm(k[3], (L, E, KV, D), std),
             "wo": norm(k[4], (L, H, D, E), resid_std),
             "mlp_norm": jnp.ones((L, E), dt),
-            "w_gate": norm(k[5], (L, E, M), std),
-            "w_up": norm(k[6], (L, E, M), std),
-            "w_down": norm(k[7], (L, M, E), resid_std),
+            "w_gate": norm(k[5], (L, *X, E, M), std),
+            "w_up": norm(k[6], (L, *X, E, M), std),
+            "w_down": norm(k[7], (L, *X, M, E), resid_std),
         },
         "final_norm": jnp.ones((E,), dt),
     }
+    if c.num_experts:
+        params["blocks"]["w_router"] = norm(
+            jax.random.fold_in(k[5], 1), (L, E, c.num_experts), std
+        )
+    if c.qk_norm:
+        params["blocks"].update({
+            "q_norm": jnp.ones((L, H * D), dt),
+            "k_norm": jnp.ones((L, KV * D), dt),
+        })
     if not c.tie_embeddings:
         params["lm_head"] = norm(
             jax.random.fold_in(k[0], 1), (c.vocab_size, E), std
@@ -187,14 +229,116 @@ def _attention(q, k, v, config: LlamaConfig):
     return dense_attention(q, k, v, window=config.sliding_window)
 
 
+_EXPERT_TENSORS = ("w_gate", "w_up", "w_down")
+
+
+def _layer_params(blocks: Params, config: LlamaConfig):
+    """``(xs, whole)`` for a layer loop: ``xs`` is what it scans over
+    (every stacked leaf, and the layer's index), ``whole`` what its body
+    adds unsliced to the layer's parameters: an expert config's three
+    expert tensors in the compute dtype (see ``_ffn``; cast here, once
+    a forward and not once a layer), nothing for a dense config."""
+    layers = jnp.arange(config.num_layers)
+    if not config.num_experts:
+        return (blocks, layers), {}
+    whole = {k: blocks[k].astype(config.dtype) for k in _EXPERT_TENSORS}
+    rest = {k: v for k, v in blocks.items() if k not in whole}
+    return (rest, layers), whole
+
+
+def _qkv(h, p, positions, config: LlamaConfig):
+    """Projections of the normed input: q (B, S, H, D) and k (B, S, KV,
+    D) with rotary positions applied, v (B, S, KV, D).  With ``qk_norm``
+    q and k are RMS-normed over their WHOLE projected width (all heads
+    together, scales ``q_norm`` / ``k_norm``) before the rotation."""
+    c = config
+    B, S = h.shape[:2]
+
+    def normed(x, scale):
+        if not c.qk_norm:
+            return x
+        return _rmsnorm(x.reshape(B, S, -1), p[scale], c.rms_eps).reshape(x.shape)
+
+    q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype))
+    q = _rope(normed(q, "q_norm"), positions, c.rope_theta)
+    kk = jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype))
+    kk = _rope(normed(kk, "k_norm"), positions, c.rope_theta)
+    vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
+    return q, kk, vv
+
+
+def _ffn(h, p, config: LlamaConfig):
+    """The ONE feed-forward body.  h: (B, S, E), already normed.
+    Returns ``(y, routing)``: y (B, S, E) to add to the residual, and
+    for an expert config ``{"rows": (num_experts,) int32 rows each
+    expert computed in this call, "experts": (B, S, k) the experts each
+    token chose}`` (None for a dense config).
+
+    Dense: SwiGLU, ``w_down(silu(w_gate h) * w_up h)``.
+
+    Experts (``num_experts`` > 0): router logits ``h @ w_router`` and a
+    softmax over ALL experts in float32; ``lax.top_k`` picks
+    ``experts_per_token`` of them and their probabilities are the
+    weights as they are (not renormalised: OLMoE's ``norm_topk_prob``
+    false).  The B*S*k (token, choice) rows are sorted by expert, the
+    three matrices are applied as grouped matmuls over the sorted rows,
+    and the results are put back in token order, weighted and summed
+    over the k choices.  Every routed pair is computed: no capacity, no
+    dropped token, no dense (rows, experts, width) intermediate.
+
+    The three expert tensors arrive STACKED over the layers, (L, X, ..),
+    with ``p["layer"]`` saying which layer this is (``_layer_params``):
+    a layer loop that handed the kernel one layer's slice would copy
+    that slice first (805 MB a layer at OLMoE's widths), so the kernel
+    gets all L * X matrices as its groups and sizes that are zero
+    outside this layer's X."""
+    c = config
+    if not c.num_experts:
+        gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
+        up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
+        act = constrain(jax.nn.silu(gate) * up, ("batch", "seq", "mlp"))
+        return jnp.einsum("bsm,me->bse", act, p["w_down"].astype(c.dtype)), None
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    B, S, E = h.shape
+    X, K = c.num_experts, c.experts_per_token
+    x = h.reshape(B * S, E)
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum(
+            "ne,ex->nx", x, p["w_router"].astype(c.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        weight, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        flat = expert.reshape(-1)                      # (N*K,) row -> expert
+        order = jnp.argsort(flat, stable=True)         # sorted row -> row
+        rows = (flat[:, None] == jnp.arange(X)[None, :]).sum(
+            0, dtype=jnp.int32
+        )                                              # bincount, (X,)
+        xs = x[order // K]                             # (N*K, E) by expert
+    with jax.named_scope("moe_experts"):
+        L = p["w_gate"].shape[0]
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros((L * X,), jnp.int32), rows, (p["layer"] * X,)
+        )
+        w_gate, w_up, w_down = (
+            p[k].reshape(L * X, *p[k].shape[2:]) for k in _EXPERT_TENSORS
+        )
+        gate = grouped_matmul(xs, w_gate, sizes)
+        up = grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        back = jnp.argsort(order)                      # row -> sorted row
+        y = ys[back].reshape(B * S, K, E).astype(jnp.float32)
+        y = (y * weight[:, :, None]).sum(1).astype(c.dtype)
+    return y.reshape(B, S, E), {
+        "rows": rows, "experts": expert.reshape(B, S, K),
+    }
+
+
 def _block(x, p, positions, config: LlamaConfig):
     c = config
     h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype))
-    kk = jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype))
-    vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
-    q = _rope(q, positions, c.rope_theta)
-    kk = _rope(kk, positions, c.rope_theta)
+    q, kk, vv = _qkv(h, p, positions, c)
     # GQA: repeat each KV head across its query group
     if c.q_per_kv > 1:
         kk = jnp.repeat(kk, c.q_per_kv, axis=2)
@@ -205,17 +349,12 @@ def _block(x, p, positions, config: LlamaConfig):
     attn = _attention(q, kk, vv, c)
     x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     x = constrain(x, ("batch", "seq", "embed"))
-    h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
-    h = jax.nn.silu(gate) * up
-    h = constrain(h, ("batch", "seq", "mlp"))
-    x = x + jnp.einsum("bsm,me->bse", h, p["w_down"].astype(c.dtype))
-    return constrain(x, ("batch", "seq", "embed"))
+    y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
+    x = constrain(x + y, ("batch", "seq", "embed"))
+    return x, routing and routing["experts"]
 
 
-def features(params: Params, tokens, config: LlamaConfig):
-    """tokens (B, S) int32 → final-RMSNorm features (B, S, E)."""
+def _features_and_choices(params: Params, tokens, config: LlamaConfig):
     c = config
     B, S = tokens.shape
     emb = constrain(params["tok_embed"], (None, None)).astype(c.dtype)
@@ -223,16 +362,30 @@ def features(params: Params, tokens, config: LlamaConfig):
     x = constrain(x, ("batch", "seq", "embed"))
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
-    def body(carry, layer_params):
+    xs, whole = _layer_params(params["blocks"], c)
+
+    def body(carry, layer):
         fn = _block
         if c.remat:
             fn = jax.checkpoint(_block, static_argnums=(3,))
-        return fn(carry, layer_params, positions, c), None
+        p, l = layer
+        return fn(carry, dict(p, layer=l, **whole), positions, c)
 
-    x, _ = lax.scan(
-        body, x, params["blocks"], unroll=max(1, c.scan_unroll)
-    )
-    return _rmsnorm(x, params["final_norm"], c.rms_eps)
+    x, experts = lax.scan(body, x, xs, unroll=max(1, c.scan_unroll))
+    return _rmsnorm(x, params["final_norm"], c.rms_eps), experts
+
+
+def features(params: Params, tokens, config: LlamaConfig):
+    """tokens (B, S) int32 → final-RMSNorm features (B, S, E)."""
+    return _features_and_choices(params, tokens, config)[0]
+
+
+def expert_choices(params: Params, tokens, config: LlamaConfig):
+    """tokens (B, S) int32 → (L, B, S, k) int32: the experts every token
+    chose in every layer of the no-cache forward, in order of falling
+    router probability.  For comparisons with a reference's routing
+    (which pairs swap under rounding); no serving path calls it."""
+    return _features_and_choices(params, tokens, config)[1]
 
 
 def _head_weight(params: Params, config: LlamaConfig):
@@ -361,13 +514,42 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     ``window + max_prefill_chunk - 1`` regardless of how long decoding
     runs — the Mistral memory win (8x at 32k context / 4k window).
     Positions older than the window are overwritten in place; the
-    attention mask reconstructs each slot's position implicitly."""
+    attention mask reconstructs each slot's position implicitly.
+
+    An expert config adds int32 running totals that ride the donated
+    cache like K and V, so no step pays a device-to-host copy for them
+    (``serve/llm.py`` reads them in ``stats()``): ``moe_expert_tokens``
+    (L, X) rows each expert of each layer computed, ``moe_experts_touched``
+    (L,) experts with at least one row, summed over the calls, and
+    ``moe_layer_steps`` (L,) calls.  They count what the kernel did:
+    every row of a decode step routes, also the rows the engine treats
+    as inactive."""
     c = config
     shape = (c.num_layers, batch_size, max_len, c.num_kv_heads, c.head_dim)
-    return {
+    cache = {
         "k": jnp.zeros(shape, c.dtype),
         "v": jnp.zeros(shape, c.dtype),
     }
+    if c.num_experts:
+        cache["moe_expert_tokens"] = jnp.zeros(
+            (c.num_layers, c.num_experts), jnp.int32
+        )
+        cache["moe_experts_touched"] = jnp.zeros((c.num_layers,), jnp.int32)
+        cache["moe_layer_steps"] = jnp.zeros((c.num_layers,), jnp.int32)
+    return cache
+
+
+def _with_expert_counts(cache: Params, k, v, expert_rows) -> Params:
+    """The cache after one call: new K/V and, for an expert config, the
+    running totals plus this call's (L, X) rows per expert."""
+    out = dict(cache, k=k, v=v)
+    if expert_rows is not None:
+        out["moe_expert_tokens"] = cache["moe_expert_tokens"] + expert_rows
+        out["moe_experts_touched"] = cache["moe_experts_touched"] + (
+            expert_rows > 0
+        ).sum(-1, dtype=jnp.int32)
+        out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
+    return out
 
 
 def rolling_cache_len(config: LlamaConfig, prefill_chunk: int) -> int:
@@ -434,20 +616,12 @@ def _cached_attention(q, k_cache, v_cache, pos, config: LlamaConfig):
 
 def _block_cached(x, p, cache_k, cache_v, start, config: LlamaConfig):
     """One block over Sq new tokens starting at absolute `start`;
-    returns (x_out, new_cache_k, new_cache_v)."""
+    returns (x_out, new_cache_k, new_cache_v, expert_rows)."""
     c = config
     B, Sq, _ = x.shape
     h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
     positions = (start + jnp.arange(Sq))[None, :].repeat(B, 0)
-    q = _rope(
-        jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype)),
-        positions, c.rope_theta,
-    )
-    kk = _rope(
-        jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype)),
-        positions, c.rope_theta,
-    )
-    vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
+    q, kk, vv = _qkv(h, p, positions, c)
     if c.sliding_window:
         # rolling buffer: position t lives in slot t mod T
         slots = (start + jnp.arange(Sq)) % cache_k.shape[1]
@@ -462,13 +636,8 @@ def _block_cached(x, p, cache_k, cache_v, start, config: LlamaConfig):
         )
     attn = _cached_attention(q, cache_k, cache_v, start + Sq - 1, c)
     x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
-    h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
-    x = x + jnp.einsum(
-        "bsm,me->bse", jax.nn.silu(gate) * up, p["w_down"].astype(c.dtype)
-    )
-    return x, cache_k, cache_v
+    y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
+    return x + y, cache_k, cache_v, routing and routing["rows"]
 
 
 def forward_cached(params: Params, tokens, cache: Params, start,
@@ -493,14 +662,18 @@ def forward_cached(params: Params, tokens, cache: Params, start,
         )
     x = params["tok_embed"].astype(c.dtype)[tokens]
 
+    (blocks, layers), whole = _layer_params(params["blocks"], c)
+
     def body(carry, layer):
         xx, _ = carry
-        p, ck, cv = layer
-        xx, ck, cv = _block_cached(xx, p, ck, cv, start, c)
-        return (xx, None), (ck, cv)
+        p, l, ck, cv = layer
+        xx, ck, cv, expert_rows = _block_cached(
+            xx, dict(p, layer=l, **whole), ck, cv, start, c
+        )
+        return (xx, None), (ck, cv, expert_rows)
 
-    (x, _), (new_k, new_v) = lax.scan(
-        body, (x, None), (params["blocks"], cache["k"], cache["v"])
+    (x, _), (new_k, new_v, expert_rows) = lax.scan(
+        body, (x, None), (blocks, layers, cache["k"], cache["v"])
     )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = jnp.einsum(
@@ -509,7 +682,7 @@ def forward_cached(params: Params, tokens, cache: Params, start,
         _head_weight(params, c).astype(c.dtype),
         preferred_element_type=jnp.float32,
     )
-    return logits, {"k": new_k, "v": new_v}
+    return logits, _with_expert_counts(cache, new_k, new_v, expert_rows)
 
 
 def generate_kv(params: Params, prompt, config: LlamaConfig, *,
@@ -592,16 +765,7 @@ def _block_decode_rowwise(x, p, cache_k, cache_v, layer, pos,
     B = x.shape[0]
     with jax.named_scope("decode_attn"):
         h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-        positions = pos[:, None]  # (B, 1)
-        q = _rope(
-            jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype)),
-            positions, c.rope_theta,
-        )
-        kk = _rope(
-            jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype)),
-            positions, c.rope_theta,
-        )
-        vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
+        q, kk, vv = _qkv(h, p, pos[:, None], c)
         rows = jnp.arange(B)
         T = cache_k.shape[2]
         slot = pos % T if c.sliding_window else pos  # rolling buffer slots
@@ -624,13 +788,8 @@ def _block_decode_rowwise(x, p, cache_k, cache_v, layer, pos,
         )
         x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     with jax.named_scope("decode_mlp"):
-        h = _rmsnorm(x, p["mlp_norm"], c.rms_eps)
-        gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
-        x = x + jnp.einsum(
-            "bsm,me->bse", jax.nn.silu(gate) * up, p["w_down"].astype(c.dtype)
-        )
-    return x, cache_k, cache_v
+        y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
+    return x + y, cache_k, cache_v, routing and routing["rows"]
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
@@ -648,14 +807,18 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
     c = config
     x = params["tok_embed"].astype(c.dtype)[tokens][:, None, :]
 
+    xs, whole = _layer_params(params["blocks"], c)
+
     def body(carry, layer):
         xx, ck, cv = carry
         p, l = layer
-        return _block_decode_rowwise(xx, p, ck, cv, l, pos, c), None
+        *carry, expert_rows = _block_decode_rowwise(
+            xx, dict(p, layer=l, **whole), ck, cv, l, pos, c
+        )
+        return tuple(carry), expert_rows
 
-    (x, new_k, new_v), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(c.num_layers)),
+    (x, new_k, new_v), expert_rows = lax.scan(
+        body, (x, cache["k"], cache["v"]), xs
     )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = jnp.einsum(
@@ -664,7 +827,7 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
         _head_weight(params, c).astype(c.dtype),
         preferred_element_type=jnp.float32,
     )
-    return logits, {"k": new_k, "v": new_v}
+    return logits, _with_expert_counts(cache, new_k, new_v, expert_rows)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
@@ -675,15 +838,16 @@ def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
     cache.  Returns (last-token logits (1, V), updated cache).  One
     compile per prompt-bucket length serves every slot (slot is traced).
     """
-    sub = {
-        "k": lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1),
-        "v": lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1),
-    }
+    sub = dict(
+        cache,
+        k=lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1),
+        v=lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1),
+    )
     logits, sub = forward_cached(params, tokens, sub, jnp.int32(0), config)
-    cache = {
-        "k": lax.dynamic_update_slice_in_dim(cache["k"], sub["k"], slot,
-                                             axis=1),
-        "v": lax.dynamic_update_slice_in_dim(cache["v"], sub["v"], slot,
-                                             axis=1),
-    }
+    # an expert config's counters come back in ``sub``, already advanced
+    cache = dict(
+        sub,
+        k=lax.dynamic_update_slice_in_dim(cache["k"], sub["k"], slot, axis=1),
+        v=lax.dynamic_update_slice_in_dim(cache["v"], sub["v"], slot, axis=1),
+    )
     return logits, cache

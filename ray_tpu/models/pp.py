@@ -219,7 +219,9 @@ def llama_partition(config) -> ModelPartition:
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
         def body(x, layer_params):
-            return llama_mod._block(x, layer_params, positions, c), None
+            # (x, expert choices): the choices are None for the dense
+            # configs this cut supports
+            return llama_mod._block(x, layer_params, positions, c)
 
         h2, _ = lax.scan(body, h, stage_blocks)
         return h2

@@ -51,6 +51,15 @@ _ENGINE_TTFT_MS = metrics.Histogram(
     "stream() pushed the request -> its first token was put on its queue",
     boundaries=_MS_LADDER,
 )
+#: expert configs only, set where ``moe_counters`` reads the counters
+_MOE_TOUCHED_MEAN = metrics.Gauge(
+    "llm_moe_experts_touched_mean",
+    "experts with at least one row, mean over every layer-step so far",
+)
+_MOE_LOAD_MAX_OVER_MEAN = metrics.Gauge(
+    "llm_moe_expert_load_max_over_mean",
+    "rows of the busiest (layer, expert) over the mean of all, so far",
+)
 _OFF = contextlib.nullcontext()
 
 
@@ -110,6 +119,9 @@ class LLMEngine:
         else:
             self.cache_len = max_len
         self.cache = llama.init_cache(config, max_slots, self.cache_len)
+        # held while a prefill or a decode step has the (donated) cache:
+        # whoever else wants to read it (moe_counters) waits its turn
+        self._cache_lock = asyncio.Lock()
         self.slots: List[Optional[_Slot]] = [None] * max_slots
         # slot admitter queue: EDF heap of (deadline, seq, _Request) —
         # requests with a traffic-plane SLO overtake deadline-less ones
@@ -123,6 +135,9 @@ class LLMEngine:
         self.admitted_total = 0
         self.shed_total = 0
         self._steps = 0  # engine iterations begun (llm.step's `step`)
+        # token rows the model was given: a prompt's length per prefill,
+        # max_slots per decode step (inactive rows are computed too)
+        self.rows_stepped_total = 0
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -167,6 +182,34 @@ class LLMEngine:
         finally:
             if request is not None:
                 request.finish()
+
+    async def moe_counters(self) -> Optional[dict]:
+        """The expert layer's running totals, pulled from the device
+        between two steps (None for a dense config): ``expert_tokens``
+        (L, X) rows each expert of each layer computed,
+        ``experts_touched_total`` and ``layer_steps_total`` summed over
+        the layers.  Every row of a decode step routes, so rows of
+        slots the engine holds no request in are counted too: these
+        count what the kernel did, not what clients received."""
+        import numpy as np
+
+        from ray_tpu.ops import grouped_matmul
+
+        if "moe_expert_tokens" not in self.cache:
+            return None
+        async with self._cache_lock:
+            tokens = np.asarray(self.cache["moe_expert_tokens"])
+            touched = int(np.asarray(self.cache["moe_experts_touched"]).sum())
+            steps = int(np.asarray(self.cache["moe_layer_steps"]).sum())
+        if steps:
+            _MOE_TOUCHED_MEAN.set(touched / steps)
+            _MOE_LOAD_MAX_OVER_MEAN.set(float(tokens.max() / tokens.mean()))
+        return {
+            "grouped_matmul": grouped_matmul.implementation(),
+            "moe_expert_tokens": tokens.tolist(),
+            "moe_experts_touched_total": touched,
+            "moe_layer_steps_total": steps,
+        }
 
     # -- engine loop -----------------------------------------------------
     async def _run(self):
@@ -256,7 +299,9 @@ class LLMEngine:
                         cfg,
                     )
 
-                logits, self.cache = await asyncio.to_thread(_prefill)
+                async with self._cache_lock:
+                    logits, self.cache = await asyncio.to_thread(_prefill)
+                    self.rows_stepped_total += S0
                 first = int(jnp.argmax(logits[0]))
                 await q.put(first)
             _ENGINE_TTFT_MS.observe((time.monotonic() - req.pushed) * 1e3)
@@ -321,7 +366,9 @@ class LLMEngine:
                             )
 
                 with _span(on, "llm.step.dispatch"):
-                    logits, self.cache = await asyncio.to_thread(_step)
+                    async with self._cache_lock:
+                        logits, self.cache = await asyncio.to_thread(_step)
+                        self.rows_stepped_total += self.max_slots
                 with _span(on, "llm.step.sync"):
                     nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
                 with _span(on, "llm.step.deliver"):
@@ -368,12 +415,22 @@ class LlamaDeployment:
             max_prompt_len=max_prompt_len,
         )
 
-    def stats(self) -> dict:
+    async def stats(self) -> dict:
         """What this replica runs on and what it has cost so far: the
         jax device it holds, its XLA compiles (all of them, and how
         many versions of the two engine programs — prefill compiles
         once per distinct prompt length), peak device memory where the
-        backend reports it, and the admitter's counters."""
+        backend reports it, and the admitter's counters.
+
+        An expert config adds which grouped-matmul body its programs
+        were traced with (``grouped_matmul``) and the routing counters
+        the cache carries (``LLMEngine.moe_counters``; one device-to-
+        host copy here, none in any step): ``moe_expert_tokens``,
+        ``moe_experts_touched_total``, ``moe_layer_steps_total``.  The
+        two gauges ``llm_moe_experts_touched_mean`` and
+        ``llm_moe_expert_load_max_over_mean`` are set from them there
+        (this class travels to its replica by value, so it names no
+        metric object itself)."""
         import jax
 
         from ray_tpu.models import llama
@@ -381,7 +438,9 @@ class LlamaDeployment:
         devices = jax.devices()
         dev = devices[0]
         mem = dev.memory_stats() or {}
+        moe = await self.engine.moe_counters()
         return {
+            **(moe or {}),
             "platform": dev.platform,
             "device_kind": dev.device_kind,
             "device_count": len(devices),
@@ -393,6 +452,7 @@ class LlamaDeployment:
             "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
             "admitted_total": self.engine.admitted_total,
             "shed_total": self.engine.shed_total,
+            "rows_stepped_total": self.engine.rows_stepped_total,
         }
 
     def update_weights(self, params) -> bool:

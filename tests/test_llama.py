@@ -401,3 +401,183 @@ class TestRowwiseDecode:
             np.asarray(clean[1]), np.asarray(noisy[1])
         )
         assert not np.array_equal(np.asarray(clean[0]), np.asarray(noisy[0]))
+
+
+def _expert_config(**kw):
+    """Tiny OLMoE-shaped config: MHA with QK-norm, 8 experts, top-2."""
+    defaults = dict(
+        num_kv_heads=4, mlp_dim=0, num_experts=8, experts_per_token=2,
+        expert_dim=48, qk_norm=True,
+    )
+    defaults.update(kw)
+    return llama.LlamaConfig.tiny(**defaults)
+
+
+class TestGroupedMatmul:
+    """ops/grouped_matmul.py against a loop over rows."""
+
+    @pytest.mark.parametrize("sizes", [
+        [3, 0, 5, 1, 0, 7],      # ragged, with empty groups
+        [0, 0, 16, 0, 0, 0],     # every row in one group
+        [1, 1, 1, 1, 1, 1],      # single-row groups; rows past the sum
+        [0, 0, 0, 0, 0, 0],      # nothing routed at all
+    ])
+    def test_rows_times_their_groups_matrix(self, sizes):
+        from ray_tpu.ops.grouped_matmul import grouped_matmul, implementation
+
+        assert implementation() == "ragged_dot"  # no TPU here
+        rows, k, n = 16, 12, 20
+        lhs = jax.random.normal(jax.random.key(0), (rows, k))
+        rhs = jax.random.normal(jax.random.key(1), (len(sizes), k, n))
+        got = np.asarray(grouped_matmul(lhs, rhs, jnp.asarray(sizes)))
+        group = np.repeat(np.arange(len(sizes)), sizes)
+        for r, g in enumerate(group):  # rows past sum(sizes) are undefined
+            np.testing.assert_allclose(
+                got[r], np.asarray(lhs[r]) @ np.asarray(rhs[g]),
+                atol=1e-5, rtol=1e-5,
+            )
+        assert got.shape == (rows, n)
+
+    def test_tiles_fit_the_two_shapes_the_replica_runs(self):
+        from ray_tpu.ops import grouped_matmul as gm
+
+        for k, n in ((2048, 1024), (1024, 2048), (64, 48), (4096, 14336)):
+            tm, tk, tn = gm.tile_for(k, n)
+            assert (tm, tk) == (128, k) and (tn == n or tn % 128 == 0)
+            # two bf16 tiles of rhs in flight: half the kernel's 16 MiB
+            assert 2 * tk * tn * 2 <= 8 * 2**20
+        assert gm.tile_for(2048, 1024) == (128, 2048, 1024)  # a whole expert
+        assert gm.tile_for(1024, 2048) == (128, 1024, 2048)
+
+
+class TestExpertLayer:
+    def test_dense_config_is_todays_program(self):
+        """num_experts = 0 and qk_norm False: the same parameters, the
+        same cache and no routing operation in any traced program."""
+        cfg = llama.LlamaConfig.tiny()
+        params = llama.init(jax.random.key(0), cfg)
+        assert set(params["blocks"]) == {
+            "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
+            "w_gate", "w_up", "w_down",
+        }
+        assert params["blocks"]["w_gate"].shape == (2, 64, 160)
+        assert set(llama.param_logical_axes(cfg)["blocks"]) == set(params["blocks"])
+        cache = llama.init_cache(cfg, 2, 16)
+        assert set(cache) == {"k", "v"}
+        rows = jnp.zeros((2,), jnp.int32)
+        for text in (
+            str(jax.make_jaxpr(
+                lambda p, c: llama.decode_step_rowwise(p, rows, c, rows, cfg)
+            )(params, cache)),
+            str(jax.make_jaxpr(
+                lambda p, c: llama.prefill_into_slot(
+                    p, jnp.zeros((1, 8), jnp.int32), c, jnp.int32(0), cfg)
+            )(params, cache)),
+            str(jax.make_jaxpr(
+                lambda p: llama.loss_fn(p, {"tokens": jnp.zeros((1, 9), jnp.int32)}, cfg)
+            )(params)),
+        ):
+            for op in ("top_k", " sort[", "argsort", "ragged_dot", "moe_"):
+                assert op not in text, op
+        logits, new = llama.decode_step_rowwise(params, rows, cache, rows, cfg)
+        assert set(new) == {"k", "v"}
+
+    def test_expert_parameters_and_axes(self):
+        cfg = _expert_config()
+        params = llama.init(jax.random.key(0), cfg)
+        b = params["blocks"]
+        assert b["w_router"].shape == (2, 64, 8)
+        assert b["w_gate"].shape == b["w_up"].shape == (2, 8, 64, 48)
+        assert b["w_down"].shape == (2, 8, 48, 64)
+        assert b["q_norm"].shape == b["k_norm"].shape == (2, 64)
+        axes = llama.param_logical_axes(cfg)["blocks"]
+        assert set(axes) == set(b)
+        for name in ("w_gate", "w_up", "w_down"):
+            assert axes[name][1] == "expert" and len(axes[name]) == b[name].ndim
+        assert llama.num_params(cfg) == sum(
+            a.size for a in jax.tree.leaves(params))
+
+    def test_no_token_is_dropped_when_every_token_picks_the_same_expert(self):
+        """A router that sends every token to experts 5 and 2: a layer
+        with a capacity would drop most of them.  Here expert 5 computes
+        every token, and the output is the plain weighted sum."""
+        cfg = _expert_config(num_layers=1)
+        params = llama.init(jax.random.key(0), cfg)
+        router = np.zeros((1, 64, 8), np.float32)
+        router[0, 0, 5], router[0, 0, 2] = 3.0, 2.0
+        params["blocks"]["w_router"] = jnp.asarray(router)
+        h = jax.random.normal(jax.random.key(3), (2, 6, 64))
+        h = h.at[..., 0].set(jnp.abs(h[..., 0]) + 0.5)  # logit 5 > logit 2 > 0
+        # as a layer loop hands them over: the layer's own slice of every
+        # leaf but the three expert tensors, which stay stacked
+        p = {k: v if k in llama._EXPERT_TENSORS else v[0]
+             for k, v in params["blocks"].items()}
+        y, routing = llama._ffn(h, dict(p, layer=jnp.int32(0)), cfg)
+        np.testing.assert_array_equal(
+            np.asarray(routing["rows"]), [0, 0, 12, 0, 0, 12, 0, 0])
+        assert np.asarray(routing["experts"]).tolist() == [[[5, 2]] * 6] * 2
+        probs = np.asarray(jax.nn.softmax(h.reshape(12, 64) @ router[0], -1))
+        want = np.zeros((12, 64), np.float32)
+        x = np.asarray(h.reshape(12, 64))
+        for e in (5, 2):
+            g = x @ np.asarray(p["w_gate"][0, e])
+            act = g / (1 + np.exp(-g)) * (x @ np.asarray(p["w_up"][0, e]))
+            want += probs[:, e:e + 1] * (act @ np.asarray(p["w_down"][0, e]))
+        np.testing.assert_allclose(
+            np.asarray(y.reshape(12, 64)), want, atol=1e-5, rtol=1e-4)
+
+    def test_counters_after_n_steps_equal_a_numpy_recount(self):
+        """The cache's running totals against the choices the no-cache
+        forward makes for the same tokens, counted with numpy.  Rows the
+        caller treats as idle (token 0 at position 0) are counted too."""
+        cfg = _expert_config()
+        params = llama.init(jax.random.key(0), cfg)
+        params["blocks"]["w_router"] = params["blocks"]["w_router"] * 20
+        seq = np.asarray(jax.random.randint(jax.random.key(4), (9,), 0, 256))
+        slots, steps = 3, 4
+        cache = llama.init_cache(cfg, slots, 16)
+        assert cache["moe_expert_tokens"].shape == (2, 8)
+        _, cache = llama.prefill_into_slot(
+            params, jnp.asarray(seq[None, :5]), cache, jnp.int32(1), cfg)
+        for i in range(steps):
+            tokens = np.zeros((slots,), np.int32)
+            pos = np.zeros((slots,), np.int32)
+            tokens[1], pos[1] = seq[5 + i], 5 + i
+            _, cache = llama.decode_step_rowwise(
+                params, jnp.asarray(tokens), cache, jnp.asarray(pos), cfg)
+        # row 1 saw seq[:9]; rows 0 and 2 saw token 0 at position 0, 4 times
+        chose = np.asarray(llama.expert_choices(params, jnp.asarray(seq[None]), cfg))
+        idle = np.asarray(llama.expert_choices(params, jnp.zeros((1, 1), jnp.int32), cfg))
+        want = np.zeros((2, 8), np.int64)
+        touched = np.zeros((2,), np.int64)
+        for layer in range(2):
+            calls = [chose[layer, 0, :5].ravel()] + [
+                np.concatenate([chose[layer, 0, 5 + i], idle[layer, 0, 0], idle[layer, 0, 0]])
+                for i in range(steps)
+            ]
+            for call in calls:
+                count = np.bincount(call, minlength=8)
+                want[layer] += count
+                touched[layer] += (count > 0).sum()
+        np.testing.assert_array_equal(np.asarray(cache["moe_expert_tokens"]), want)
+        np.testing.assert_array_equal(np.asarray(cache["moe_experts_touched"]), touched)
+        np.testing.assert_array_equal(np.asarray(cache["moe_layer_steps"]), [1 + steps] * 2)
+        # nothing dropped: k rows per token per layer
+        assert want.sum() == 2 * 2 * (5 + steps * slots)
+
+    def test_generate_kv_runs_an_expert_config(self):
+        cfg = _expert_config()
+        params = llama.init(jax.random.key(0), cfg)
+        prompt = jnp.asarray([[3, 7, 11, 2]], jnp.int32)
+        kv = llama.generate_kv(params, prompt, cfg, max_new_tokens=5)
+        full = llama.generate(params, prompt, cfg, max_new_tokens=5)
+        np.testing.assert_array_equal(np.asarray(kv), np.asarray(full))
+
+    def test_expert_loss_has_gradients_for_every_expert_tensor(self):
+        cfg = _expert_config()
+        params = llama.init(jax.random.key(0), cfg)
+        toks = jax.random.randint(jax.random.key(1), (2, 17), 0, cfg.vocab_size)
+        loss, grads = jax.value_and_grad(llama.loss_fn)(params, {"tokens": toks}, cfg)
+        assert abs(float(loss) - np.log(cfg.vocab_size)) < 0.5
+        for name in ("w_router", "w_gate", "w_up", "w_down", "q_norm", "k_norm"):
+            assert float(jnp.abs(grads["blocks"][name]).max()) > 0, name

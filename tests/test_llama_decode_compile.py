@@ -92,3 +92,64 @@ def test_decode_step_reads_the_cache_once(v5e_chip, no_compile_cache):
     for expanded in (f"[{slots},{max_len},{kv},{g},{d}]",
                      f"[{slots},{max_len},{kv * g},{d}]"):
         assert expanded not in text, f"a K/V slab is expanded to {expanded}"
+
+
+OLMOE_FILE = os.path.join(os.path.dirname(CONFIG_FILE), "olmoe-1b-7b-l12.json")
+
+
+def test_expert_decode_step_routes_without_a_dense_intermediate(
+        v5e_chip, no_compile_cache, monkeypatch):
+    """OLMoE's decode step at the published widths (12 layers), compiled
+    for the chip with the kernel the chip runs.  What a later edit of
+    ``_ffn`` could bring back unseen by any CPU test: every expert
+    applied to every row and masked ("dense" routing: an array of rows x
+    experts x width), or one layer's expert tensors copied out of the
+    stacked weights before the kernel reads them (805 MB a layer; the
+    program had 270 MB of temporaries while it sliced them, 1.9 MB
+    since)."""
+    from chipbench.jobs.serve_moe import moe_config
+    from ray_tpu.ops import grouped_matmul
+
+    # jax's default backend is the CPU here; the chip's body is what the
+    # replica traces on the chip and what is compiled for it
+    monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    with open(OLMOE_FILE) as f:
+        served = json.load(f)
+    config = moe_config(served)
+    slots, max_len = served["serving"]["max_slots"], served["serving"]["max_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert 10.4e9 < weights < 10.6e9  # 5.24 B parameters in bf16
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    compiled = llama.decode_step_rowwise.lower(
+        params, rows, cache, rows, config
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 * 2**20, mem
+    # cache and counters are updated in place
+    assert mem.alias_size_in_bytes >= 3 * 2**30, mem
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%gmm." in text
+    x, k, e, m = (config.num_experts, config.experts_per_token,
+                  config.embed_dim, config.expert_dim)
+    assert (slots * k, x, e, m) == (256, 64, 2048, 1024)
+    for n in (slots, slots * k):
+        for width in (e, m, 2 * m):
+            for dense in (f"[{n},{x},{width}]", f"[{x},{n},{width}]"):
+                assert dense not in text, f"every expert on every row: {dense}"
+    # no copy of a layer's experts out of the stacked tensors
+    for sliced in (f"bf16[{x},{e},{m}]", f"bf16[{x},{m},{e}]",
+                   f"bf16[1,{x},{e},{m}]", f"bf16[1,{x},{m},{e}]"):
+        assert sliced not in text, f"one layer's experts are copied: {sliced}"

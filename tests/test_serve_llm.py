@@ -296,3 +296,78 @@ class TestRollingCacheEngine:
                     pass
 
         asyncio.run(run())
+
+
+class TestExpertEngine:
+    def test_streams_through_an_expert_config_and_stats_reads_the_counters(self):
+        """A tiny OLMoE-shaped config (8 experts, top-2, QK-norm) through
+        the same LLMEngine: the greedy tokens are the full recompute's,
+        and ``stats()`` reports the routing counters the cache carries —
+        every row of every decode step is counted, also the idle slot's."""
+        import asyncio
+
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu.models import llama
+        from ray_tpu.serve.llm import LlamaDeployment
+        from ray_tpu.util import metrics
+
+        cfg = llama.LlamaConfig.tiny(
+            num_kv_heads=4, mlp_dim=0, num_experts=8, experts_per_token=2,
+            expert_dim=48, qk_norm=True,
+        )
+        replica = LlamaDeployment.func_or_class(
+            config=cfg, max_slots=3, max_len=48, seed=0
+        )
+        engine = replica.engine
+        prompts = [[3, 7, 11, 2], [5, 1, 9, 13, 17, 8]]
+
+        async def one(prompt):
+            return [t async for t in engine.stream(prompt, max_new_tokens=10)]
+
+        async def run():
+            before = await replica.stats()
+            got = await asyncio.gather(*(one(p) for p in prompts))
+            return before, got, await replica.stats()
+
+        before, got, stats = asyncio.run(run())
+        for prompt, toks in zip(prompts, got):
+            ref = llama.generate(
+                engine.params, jnp.asarray([prompt], jnp.int32), cfg,
+                max_new_tokens=10,
+            )
+            np.testing.assert_array_equal(
+                np.asarray(toks), np.asarray(ref[0, len(prompt):])
+            )
+        assert before["moe_layer_steps_total"] == 0
+        assert before["grouped_matmul"] == stats["grouped_matmul"] == "ragged_dot"
+        tokens = np.asarray(stats["moe_expert_tokens"])
+        assert tokens.shape == (cfg.num_layers, cfg.num_experts)
+        # two prefills and the decode steps, each over ALL three slots
+        rows = stats["rows_stepped_total"]
+        assert rows == 4 + 6 + 3 * ((rows - 10) // 3) and rows >= 10 + 3 * 9
+        assert tokens.sum() == rows * cfg.num_layers * cfg.experts_per_token
+        layer_steps = stats["moe_layer_steps_total"]
+        assert layer_steps == cfg.num_layers * (2 + (rows - 10) // 3)
+        assert 2 * layer_steps <= stats["moe_experts_touched_total"] <= 8 * layer_steps
+        gauges = {
+            m["name"]: list(m["series"].values())[0]
+            for m in metrics.registry_snapshot()
+            if m["name"].startswith("llm_moe_")
+        }
+        assert gauges["llm_moe_experts_touched_mean"] == pytest.approx(
+            stats["moe_experts_touched_total"] / layer_steps)
+        assert gauges["llm_moe_expert_load_max_over_mean"] == pytest.approx(
+            tokens.max() / tokens.mean())
+
+    def test_a_dense_config_reports_no_expert_keys(self):
+        import asyncio
+
+        from ray_tpu.serve.llm import LlamaDeployment
+
+        replica = LlamaDeployment.func_or_class(max_slots=2, max_len=32)
+        stats = asyncio.run(replica.stats())
+        assert not [k for k in stats if k.startswith("moe_")]
+        assert "grouped_matmul" not in stats and stats["rows_stepped_total"] == 0
