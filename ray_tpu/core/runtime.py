@@ -342,6 +342,10 @@ class Runtime:
         self._sync_waiters: Dict[bytes, Any] = {}
         self._sync_reg_lock = threading.Lock()
         self._sync_get_tls = threading.local()  # reusable wait Event
+        # per-thread mark left by every call that parks its thread until
+        # the io loop has acted for it (_parks_on_loop); the worker's
+        # executor reads it to keep such methods off the loop itself
+        self._caller_tls = threading.local()
         self._shared: set = set()  # oids known to be in shm + registered
         self._escaped: set = set()  # refs passed on before their task finished
 
@@ -501,7 +505,17 @@ class Runtime:
         return rtenv_mod.normalize(env, kv_put, scope=self.gcs_address)
 
     # ---- loop bridging -------------------------------------------------
+    def _parks_on_loop(self):
+        """Called where a caller thread is about to wait for the io
+        loop (_run, a sync get that is not there yet, the next item of a
+        stream).  A sync method seen here while it runs on the worker's
+        executor is never promoted to run inline ON that loop
+        (worker_main._note_method_time): however fast it returned, there
+        it would wait for itself for good."""
+        self._caller_tls.parked = True
+
     def _run(self, coro, timeout: Optional[float] = None):
+        self._parks_on_loop()
         fut = asyncio.run_coroutine_threadsafe(coro, self._loop)
         try:
             return fut.result(timeout)
@@ -1076,6 +1090,7 @@ class Runtime:
             if remaining is not None and remaining <= 0:
                 ok = False
             else:
+                self._parks_on_loop()
                 ok = ev.wait(remaining)
             self._drop_sync_waiter(oid, ev)
             if not ok:
@@ -1217,6 +1232,7 @@ class Runtime:
                     raise GetTimeoutError(
                         f"timed out waiting for stream item {idx}"
                     )
+                self._parks_on_loop()
                 buf.cond.wait(remaining)
         oid = ObjectID.for_task_return(TaskID(tid), idx)
         if conn is not None and not conn.closed:

@@ -347,6 +347,8 @@ class WorkerServer:
         }
         try:
             t0 = time.time()
+            caller = self.rt._caller_tls
+            caller.parked = False
             t0p = time.perf_counter()
             try:
                 with _maybe_execute_span(spec):
@@ -355,7 +357,7 @@ class WorkerServer:
                 # finally: slow raising runs must demote/ban too
                 self._note_method_time(
                     "task:" + spec["fn_hash"].hex(),
-                    time.perf_counter() - t0p,
+                    time.perf_counter() - t0p, caller.parked,
                 )
             reply = self._exec_pack(spec, result)
             if type(reply) is tuple:  # compact ("i", payload) fast shape
@@ -1096,12 +1098,13 @@ class WorkerServer:
         is running on the executor (so executions can't overlap), the args
         are ref-free (resolving a ref needs the loop), and the method's
         recent-execution-time EMA is under _INLINE_EMA_S.  First calls
-        always go through the pool, so a blocking method never runs
-        inline.  The tail risk — a promoted method whose NEXT run turns
-        slow blocks the loop for that one run, and cancellation cannot
-        interrupt it — is bounded by demotion: any run past
-        _INLINE_DEMOTE_S (50 ms) bans the method from inline permanently,
-        and a sustained slowdown drags the EMA over the bar.
+        always go through the pool, and a method that waited for the io
+        loop there (however briefly) is banned, so a method that blocks
+        every time never runs inline.  The tail risk — a promoted method
+        whose NEXT run turns slow blocks the loop for that one run, and
+        cancellation cannot interrupt it — is bounded by demotion: any
+        run past _INLINE_DEMOTE_S (50 ms) bans the method from inline
+        permanently, and a sustained slowdown drags the EMA over the bar.
         Returns None when the pool must be used."""
         if (
             self._actor_thread_pool is not None
@@ -1151,20 +1154,25 @@ class WorkerServer:
             self._cancelled.discard(tid)
         return reply
 
-    def _note_method_time(self, mname: str, dt: float):
+    def _note_method_time(self, mname: str, dt: float,
+                          parked: bool = False):
         # [samples, banned, ema].  An EMA (not a consecutive-fast streak)
         # so one OS-preemption spike — routine on a loaded host, and the
         # r4 regression: a single >2ms measurement de-promoted the method
         # and locked pipelined windows onto the pool — cannot flip a
         # genuinely fast method back to the executor.  A single run past
-        # the demote bound still bans inline outright.
+        # the demote bound still bans inline outright, and so does one
+        # that parked its thread on the io loop (Runtime._parks_on_loop:
+        # a get, a kill, any _run — the serve controller's
+        # delete_application kills replicas): inline, on that loop, it
+        # would wait for itself for good.
         st = self._method_stats.get(mname)
         if st is None:
             st = self._method_stats[mname] = [1, False, dt]
         else:
             st[0] += 1
             st[2] += 0.125 * (dt - st[2])
-        if dt > self._INLINE_DEMOTE_S:
+        if dt > self._INLINE_DEMOTE_S or parked:
             st[1] = True
 
     def _execute_sync_method(self, method, spec) -> dict:
@@ -1183,6 +1191,8 @@ class WorkerServer:
             if unpacked is None:  # ObjectRef args: resolve on the io loop
                 unpacked = self.rt._run(self.rt.unpack_args(spec["args"]))
             args, kwargs = unpacked
+            caller = self.rt._caller_tls
+            caller.parked = False  # after the arg resolution above
             t0p = time.perf_counter()
             try:
                 with _maybe_execute_span(spec):
@@ -1190,7 +1200,8 @@ class WorkerServer:
             finally:
                 # finally: slow raising runs must demote/ban too
                 self._note_method_time(
-                    spec["method"], time.perf_counter() - t0p
+                    spec["method"], time.perf_counter() - t0p,
+                    caller.parked,
                 )
             return self._exec_pack(spec, result)
         except TaskCancelledError as e:

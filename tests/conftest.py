@@ -31,7 +31,20 @@ except RuntimeError:
     # actually too small when tests run.
     pass
 
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
+import hashlib  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+
 import pytest  # noqa: E402
+
+#: Seconds every test gets (set-up of its function-scoped fixtures, the
+#: call, their tear-down).  About three times the slowest honest test
+#: of the tier-1 run on six workers; a test that needs more says so
+#: with ``@pytest.mark.limit(seconds)``.
+TEST_LIMIT_S = 180
 
 
 def pytest_configure(config):
@@ -40,16 +53,85 @@ def pytest_configure(config):
         "slow: long-running scale/stress tests excluded from the "
         "tier-1 `-m 'not slow'` run",
     )
+    config.addinivalue_line(
+        "markers",
+        "limit(seconds): this test's own time limit, in place of the "
+        f"{TEST_LIMIT_S} s every test gets (tests/conftest.py)",
+    )
+
+
+@contextlib.contextmanager
+def time_limit(seconds: float, name: str):
+    """Fail the body, by name, once it has run ``seconds``: a hang
+    costs one named failure and not the run.  SIGALRM's handler runs in
+    the main thread between two bytecodes (a blocking lock or sleep is
+    interrupted for it): it dumps every thread's stack into the failure
+    message and raises ``pytest.fail``.  A hang inside a C call never
+    gets that far, so ``faulthandler`` ends the process 30 s later (an
+    xdist worker, which xdist replaces).  The handler and timer found on
+    entry are put back on exit, whatever the outcome."""
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile() as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read().decode(errors="replace")
+        pytest.fail(
+            f"{name} ran past its limit of {seconds:g} s; the threads "
+            f"were at:\n{stacks}",
+            pytrace=False,
+        )
+
+    old_handler = signal.signal(signal.SIGALRM, on_alarm)
+    old_timer = signal.setitimer(signal.ITIMER_REAL, seconds)
+    faulthandler.dump_traceback_later(
+        seconds + 30, exit=True, file=sys.__stderr__
+    )
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, *old_timer)
+        signal.signal(signal.SIGALRM, old_handler)
+
+
+@pytest.fixture(autouse=True)
+def _test_limit(request):
+    nodeid = request.node.nodeid
+    marker = request.node.get_closest_marker("limit")
+    seconds = marker.args[0] if marker else TEST_LIMIT_S
+    # Under ``--dist loadfile`` xdist hands a file whose worker died
+    # back to the next worker WITH the test that killed it, up to nine
+    # times over.  A file per running test, in the run's own name, lets
+    # the second worker fail that test at once and go on with the rest.
+    run = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+    running = run and os.path.join(
+        tempfile.gettempdir(),
+        f"rt-test-{run}-{hashlib.sha1(nodeid.encode()).hexdigest()[:16]}",
+    )
+    if running:
+        if os.path.exists(running):
+            os.unlink(running)
+            pytest.fail(
+                f"{nodeid} took its xdist worker down earlier in this "
+                "run (a hang no signal reached, or a crash): not run again",
+                pytrace=False,
+            )
+        open(running, "w").close()
+    try:
+        with time_limit(seconds, nodeid):
+            yield
+    finally:
+        if running:
+            os.unlink(running)
 
 
 def pytest_collection_modifyitems(config, items):
-    """Tier-1 hygiene guard: the CI tier-1 run executes files in name
-    order under a hard wall-clock truncation window, so long-running
-    suites must sort PAST the fast ones — any test file carrying the
-    ``slow`` marker (the flag for suites sized beyond the window) must
-    be named ``test_zz_*``.  Enforced at collection: a misnamed file
-    would silently eat the tier-1 budget from the middle of the
-    alphabet."""
+    """A ``slow`` test outside a ``test_zz_*`` file is a naming error,
+    refused at collection: ``slow`` marks what is sized beyond the
+    tier-1 run (``-m 'not slow'``), and the file's name is how a reader
+    and a ``tests/test_zz_*`` glob find those suites without opening
+    every file."""
     bad = sorted({
         os.path.basename(str(item.fspath))
         for item in items
@@ -58,8 +140,7 @@ def pytest_collection_modifyitems(config, items):
     })
     if bad:
         raise pytest.UsageError(
-            "slow-marked tests outside test_zz_* files (they would run "
-            "inside the tier-1 truncation window): " + ", ".join(bad)
+            "slow-marked tests outside test_zz_* files: " + ", ".join(bad)
         )
 
 

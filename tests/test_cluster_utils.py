@@ -28,13 +28,33 @@ class TestMultiNode:
         assert ray_tpu.cluster_resources()["CPU"] == 4.0
 
     def test_tasks_use_both_nodes(self, cluster):
+        @ray_tpu.remote(num_cpus=0)
+        class Arrivals:
+            def __init__(self):
+                self.n = 0
+
+            def arrive(self):
+                self.n += 1
+
+            def count(self):
+                return self.n
+
         @ray_tpu.remote
-        def where(t):
-            time.sleep(t)
+        def where(arrivals):
+            # hold the CPU until all four run at once: with a sleep in
+            # its place, a host loaded enough to start one node's
+            # workers 2 s after the other's runs all four on one node,
+            # two after two
+            ray_tpu.get(arrivals.arrive.remote(), timeout=60)
+            deadline = time.monotonic() + 60
+            while ray_tpu.get(arrivals.count.remote(), timeout=60) < 4:
+                assert time.monotonic() < deadline, "never four at once"
+                time.sleep(0.05)
             return ray_tpu.get_runtime_context().node_id
 
         # 4 concurrent 1-CPU tasks need both 2-CPU nodes
-        refs = [where.remote(1.0) for _ in range(4)]
+        arrivals = Arrivals.remote()
+        refs = [where.remote(arrivals) for _ in range(4)]
         nodes = set(ray_tpu.get(refs, timeout=120))
         assert len(nodes) == 2
 

@@ -2,6 +2,9 @@
 
 import os
 import pickle
+import signal
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -226,3 +229,47 @@ class TestPhiAccrualDetector:
         assert death_confirmed(0.0, 2.1, 8.0, 1.0, 2.0)
         # neither: alive
         assert not death_confirmed(3.0, 1.2, 8.0, 1.0, 2.0)
+
+
+class TestTimeLimit:
+    """tests/conftest.py:time_limit, the clock every test runs under
+    (this one too: the fixture's handler and timer are what is found
+    on entry and must be back on exit)."""
+
+    def _state(self):
+        return (
+            signal.getsignal(signal.SIGALRM),
+            signal.getitimer(signal.ITIMER_REAL)[0],
+        )
+
+    def test_body_past_its_limit_fails_by_name(self):
+        from conftest import time_limit
+
+        handler, remaining = self._state()
+        t0 = time.monotonic()
+        with pytest.raises(pytest.fail.Exception) as err:
+            with time_limit(1, "tests/test_x.py::test_that_hangs"):
+                threading.Event().wait(30)
+        assert time.monotonic() - t0 < 5
+        msg = str(err.value)
+        assert msg.startswith(
+            "tests/test_x.py::test_that_hangs ran past its limit of 1 s"
+        ), msg[:200]
+        # every thread's stack, this one's among them, parked in wait()
+        assert "test_body_past_its_limit_fails_by_name" in msg
+        assert "threading.py" in msg and "in wait" in msg
+        handler_after, remaining_after = self._state()
+        assert handler_after is handler
+        assert remaining - 5 < remaining_after <= remaining
+
+    def test_body_inside_its_limit_leaves_no_trace(self):
+        from conftest import time_limit
+
+        handler, remaining = self._state()
+        with time_limit(30, "tests/test_x.py::test_that_returns"):
+            assert signal.getsignal(signal.SIGALRM) is not handler
+            assert 29 < signal.getitimer(signal.ITIMER_REAL)[0] <= 30
+        handler_after, remaining_after = self._state()
+        assert handler_after is handler
+        assert remaining - 5 < remaining_after <= remaining
+        time.sleep(0.05)  # nothing of the inner clock is left to fire

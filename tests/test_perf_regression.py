@@ -64,6 +64,46 @@ def test_windowed_actor_calls_promote_inline(cluster):
     ray_tpu.kill(a)
 
 
+@ray_tpu.remote
+class Caller:
+    """Sync methods that wait for the io loop, each in under a
+    millisecond: a get of a result that is not there yet, a kill."""
+
+    def __init__(self, echo):
+        self.echo = echo
+
+    def get_through(self):
+        return ray_tpu.get(self.echo.ping.remote(), timeout=10)
+
+    def kill_it(self, victim):
+        ray_tpu.kill(victim)
+        return b"ok"
+
+
+@pytest.mark.parametrize("method", ["get_through", "kill_it"])
+def test_method_that_waits_for_the_loop_never_promotes(cluster, method):
+    """A fast sync method is promoted onto the worker's io loop after
+    ten runs; one that parks its thread until that loop has acted for
+    it must not be, or its eleventh call waits for itself for good
+    (the serve controller's delete_application did: PR 27)."""
+    echo = Echo.remote()
+    c = Caller.remote(echo)
+    before = None
+    for i in range(16):
+        arg = () if method == "get_through" else (
+            Echo.options(num_cpus=0).remote(),
+        )
+        out = ray_tpu.get(getattr(c, method).remote(*arg), timeout=30)
+        assert out == b"ok", (i, out)
+        if before is None:
+            before = _worker_status(c)["exec_counts"]
+    after = _worker_status(c)["exec_counts"]
+    assert after["inline"] == before["inline"], (before, after)
+    assert after["pool"] - before["pool"] == 15
+    ray_tpu.kill(c)
+    ray_tpu.kill(echo)
+
+
 def test_driver_allocations_per_actor_call_bounded(cluster):
     """Allocated-block delta per submitted call on the driver, measured
     with gc frozen — deterministic, unlike wall clock.  The budget is

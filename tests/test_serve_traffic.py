@@ -70,7 +70,13 @@ class TestAdmission:
             results = await asyncio.gather(
                 *(r.result_async() for r in admitted)
             )
-            return results, sheds, h._router._traffic_scheduler.stats()
+            # the scheduler counts a completion in a task of its own,
+            # woken beside the caller's: give the last one its turn
+            sched = h._router._traffic_scheduler
+            deadline = time.monotonic() + 5
+            while sched.stats()["inflight"] and time.monotonic() < deadline:
+                await asyncio.sleep(0.01)
+            return results, sheds, sched.stats()
 
         results, sheds, stats = asyncio.run(drive())
         # depth cap 4: only a handful admitted, the burst's tail shed

@@ -6,6 +6,10 @@ bad-hyperparameter trial must adopt a good trial's checkpoint+config and
 catch up.
 """
 
+import os
+import time
+import uuid
+
 import numpy as np
 import pytest
 
@@ -114,13 +118,30 @@ def _trainable(config):
     ck = sess.get_checkpoint()
     if ck is not None:
         score = float(ck.to_dict()["score"])
-    for _ in range(32):
+    for i in range(32):
         score += float(config["rate"])
         from ray_tpu.train.checkpoint import Checkpoint
 
         sess.report(
             {"score": score}, checkpoint=Checkpoint.from_dict({"score": score})
         )
+        if i == 0:
+            _wait_for_population(config["reported"])
+
+
+def _wait_for_population(reported: str, size: int = 4):
+    """After its first report (report() returns once the driver has taken
+    it) a trial waits until every trial of the population has made one.
+    PBT ranks a trial only against a population that has ALL reported,
+    and 32 unpaced iterations are over in a fraction of the second or
+    two by which one trial's worker starts after another's: without the
+    wait a bad trial whose worker comes up first is through before it
+    can be ranked.  A restarted trial adds a file and goes straight
+    through."""
+    open(os.path.join(reported, uuid.uuid4().hex), "w").close()
+    deadline = time.monotonic() + 60
+    while len(os.listdir(reported)) < size and time.monotonic() < deadline:
+        time.sleep(0.01)
 
 
 class TestPBTEndToEnd:
@@ -134,9 +155,14 @@ class TestPBTEndToEnd:
             hyperparam_mutations={"rate": [1.0, 5.0]},
             seed=7,
         )
+        reported = tmp_path / "reported"
+        reported.mkdir()
         tuner = tune.Tuner(
             _trainable,
-            param_space={"rate": tune.grid_search([5.0, 4.0, 3.0, 0.01])},
+            param_space={
+                "rate": tune.grid_search([5.0, 4.0, 3.0, 0.01]),
+                "reported": str(reported),
+            },
             tune_config=tune.TuneConfig(
                 metric="score", mode="max", scheduler=pbt
             ),

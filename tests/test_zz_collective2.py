@@ -781,29 +781,35 @@ class TestProgressEngine:
             ]
             expected = xs[0] + xs[1]
             compute_ms = 300.0
-            outs = ray_tpu.get(
-                [
-                    m.launch_overlap.remote(x, group, compute_ms)
-                    for m, x in zip(ms, xs)
-                ],
-                timeout=120,
-            )
-            for out, total_s, compute_s in outs:
-                assert np.array_equal(out, expected)
-                assert compute_s >= 0.9 * compute_ms / 1000.0
-            # serialized reference on the same group
-            ser = ray_tpu.get(
-                [
-                    m.blocking_then_compute.remote(x, group, compute_ms)
-                    for m, x in zip(ms, xs)
-                ],
-                timeout=120,
-            )
-            ser_total = max(t for _, t in ser)
-            ov_total = max(t for _, t, _c in outs)
+            ov_totals, ser_totals = [], []
+            for _ in range(3):
+                outs = ray_tpu.get(
+                    [
+                        m.launch_overlap.remote(x, group, compute_ms)
+                        for m, x in zip(ms, xs)
+                    ],
+                    timeout=120,
+                )
+                for out, total_s, compute_s in outs:
+                    assert np.array_equal(out, expected)
+                    assert compute_s >= 0.9 * compute_ms / 1000.0
+                ov_totals.append(max(t for _, t, _c in outs))
+                # serialized reference on the same group
+                ser = ray_tpu.get(
+                    [
+                        m.blocking_then_compute.remote(x, group, compute_ms)
+                        for m, x in zip(ms, xs)
+                    ],
+                    timeout=120,
+                )
+                ser_totals.append(max(t for _, t in ser))
             # overlap must beat strict serialization by a real margin
-            # (the op alone takes >> 30 ms at 4 MB on this plane)
-            assert ov_total < ser_total, (ov_total, ser_total)
+            # (the op alone takes >> 30 ms at 4 MB on this plane).  The
+            # best of three of each: one reading is 300 ms of compute,
+            # the op, and whatever a loaded host adds, which can be as
+            # much as the op
+            ov_total, ser_total = min(ov_totals), min(ser_totals)
+            assert ov_total < ser_total, (ov_totals, ser_totals)
         finally:
             _teardown(ms, group)
 

@@ -601,12 +601,13 @@ class TestChaosLogDeterminism:
                     dur = round(chaos.rng.uniform(0.05, 0.2), 3)
                     chaos.partition(victim, "gcs", duration_s=dur)
                     chaos.heal(victim, "gcs")
-                events = [
-                    {k: v for k, v in e.items() if k != "ts"}
-                    for e in chaos.log
-                ]
-                links = [dict(e) for e in faults.link_log()]
-                return events, links
+                def without_ts(log):
+                    return [
+                        {k: v for k, v in e.items() if k != "ts"}
+                        for e in log
+                    ]
+
+                return without_ts(chaos.log), without_ts(faults.link_log())
 
             e1, l1 = run_schedule(1234)
             e2, l2 = run_schedule(1234)
