@@ -82,6 +82,9 @@ class WorkerEntry:
     rtenv_key: str = ""  # runtime-env binding (core/runtime_env.py)
     venv_key: str = ""   # pip-env interpreter this worker was spawned with
     lease_id: Optional[int] = None
+    # spawned for a lease that is waiting for it (rpc_lease_worker):
+    # never pooled, so no other lease can take it meanwhile
+    spoken_for: bool = False
     tpu_chips: tuple = ()
     started_at: float = field(default_factory=time.monotonic)
     leased_at: float = 0.0  # monotonic time of the CURRENT lease grant
@@ -1034,8 +1037,12 @@ class Raylet:
         w.conn = conn
         w.addr = p["address"]
         conn.peer_info["worker_id"] = wid
-        key = _env_key(w.bound_env, w.rtenv_key) if w.bound_env else ()
-        self._idle_by_env.setdefault(key, []).append(w)
+        if not w.spoken_for:
+            # pooled until the lease that spawned it woke from its poll,
+            # a fresh worker was taken by a lease arriving in between as
+            # well, and two leases' tasks ran one behind the other on it
+            key = _env_key(w.bound_env, w.rtenv_key) if w.bound_env else ()
+            self._idle_by_env.setdefault(key, []).append(w)
         return True
 
     async def _wait_for_worker(self, w: WorkerEntry):
@@ -1252,12 +1259,13 @@ class Raylet:
             w = self._spawn_worker(python_exe=venv_python,
                                    venv_key=venv_key,
                                    container=container)
-            await self._wait_for_worker(w)
-            # worker_ready put the fresh worker in the idle pool; it is being
-            # handed out right now, so pull it back out
-            for pool in self._idle_by_env.values():
-                if w in pool:
-                    pool.remove(w)
+            w.spoken_for = True
+            try:
+                await self._wait_for_worker(w)
+            finally:
+                # handed out below; or, after a failed start, anyone's
+                # should it still come up
+                w.spoken_for = False
         if w.bound_env is None:
             try:
                 await w.conn.call(

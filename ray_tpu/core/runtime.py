@@ -2070,7 +2070,14 @@ class Runtime:
             # no ramp (reference: lease request pipelining,
             # direct_task_transport.cc).
             want = (len(st.queue) + cap - 1) // cap
-            have = len(st.leases) + st.requests_inflight
+            # a lease that is full is not capacity: counted as such, a
+            # task queued behind leases that are all busy asked for no
+            # lease and waited for one of THEIR tasks to end, with CPUs
+            # free in the cluster
+            have = st.requests_inflight + sum(
+                1 for lease in st.leases
+                if not lease.broken and lease.inflight < cap
+            )
             ceiling = cfg.sched_max_lease_requests_per_class
             if want > have and st.requests_inflight < ceiling:
                 st.cancel_sent = False

@@ -13,9 +13,9 @@ Grouped-query attention: num_kv_heads < num_heads shares each KV head
 across num_heads // num_kv_heads query heads (Llama-2-70B/Llama-3
 layout; num_kv_heads == num_heads gives classic MHA).
 
-The feed-forward is ONE function, ``_ffn``, called by the three block
-bodies (training ``_block``, ``_block_cached`` for prefill,
-``_block_decode_rowwise``).  With ``num_experts > 0`` it is a sparse
+The feed-forward is ONE function, ``_ffn``, called by the two block
+bodies (training ``_block``, and ``_block_step`` for every path that
+keeps a KV cache).  With ``num_experts > 0`` it is a sparse
 expert layer as OLMoE has it: softmax router over all experts in
 float32, top-k, weights NOT renormalised, every routed (token, expert)
 pair computed through ``ops/grouped_matmul.py`` — no capacity, no
@@ -458,10 +458,11 @@ def generate(params: Params, prompt, config: LlamaConfig, *,
     The context is padded once to the fixed bucket S + max_new_tokens
     and the step function takes the current length as a traced index —
     ONE compiled executable serves every decode step (no per-token
-    recompile).  Each step still recomputes the full context (O(S²)
-    total; the KV-cache incremental decode is the planned serving fast
-    path, see ops/attention.py dense_attention(start_pos=...)).
-    temperature 0 is argmax; otherwise categorical sampling."""
+    recompile).  Each step recomputes the full context (O(S²) total)
+    through the no-cache ``forward``: this is the plain reference the
+    tests hold the cached paths and the serving engine to, not a path
+    to serve from.  temperature 0 is argmax; otherwise categorical
+    sampling."""
     tokens = jnp.asarray(prompt, jnp.int32)
     B, S0 = tokens.shape
     if max_new_tokens <= 0:
@@ -496,18 +497,17 @@ def _gen_step(params, padded, length, key, *, config, temperature):
 
 
 # ---------------------------------------------------------------------------
-# KV-cache incremental decoding (the serving fast path)
+# KV-cache decoding: ONE cached step behind prefill, decode and generate_kv
 # ---------------------------------------------------------------------------
 
 
 def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     """Fixed-bucket KV cache: (L, B, max_len, KV, D) per tensor, bf16.
-    Static shapes — one compiled prefill + one compiled decode step
-    serve any request up to max_len.  The steps take it donated and
-    hand it back: ``decode_step_rowwise`` carries it whole through its
-    layer loop and writes only each row's new K/V in place, and
-    attention reads it as stored — KV heads are never expanded to the
-    query heads (``_grouped_attention``).
+    Static shapes — one compiled prefill per prompt length + one compiled
+    decode step serve any request up to max_len.  The jitted entry
+    points take it donated and hand it back: the one step behind them
+    (``_cached_step``) carries it whole through its layer loop, writes
+    only the new tokens' K/V in place, and attention reads it as stored.
 
     With ``sliding_window`` the cache is a ROLLING buffer (slot =
     position mod max_len), so ``max_len`` can be as small as
@@ -562,12 +562,17 @@ def rolling_cache_len(config: LlamaConfig, prefill_chunk: int) -> int:
     return config.sliding_window + max(1, prefill_chunk) - 1
 
 
-def _rolling_mask(q_pos, t_idx, T: int, window: int):
-    """Validity mask for rolling-buffer slots: slot s as seen by query
-    position q holds position q - ((q - s) mod T) — the newest position
-    <= q congruent to s.  Valid iff non-negative and inside the window.
-    q_pos: (..., 1)-broadcastable positions; t_idx: (T,) slot indices.
-    The ONE implementation both callers of ``_grouped_attention`` share."""
+def _cache_mask(positions, T: int, window: int):
+    """(R, Sq, T), True where the query at ``positions[r, s]`` may see
+    cache slot t.  Full causal: slot t holds position t.  With a window
+    the cache is a rolling buffer: slot t as seen by query position q
+    holds position q - ((q - t) mod T) — the newest position <= q
+    congruent to t — valid iff non-negative and inside the window (slot
+    correctness needs T >= window + Sq - 1: ``rolling_cache_len``)."""
+    q_pos = positions[:, :, None]
+    t_idx = jnp.arange(T)
+    if not window:
+        return t_idx <= q_pos
     t_pos = q_pos - ((q_pos - t_idx) % T)
     return (t_pos >= 0) & (t_pos > q_pos - window)
 
@@ -578,8 +583,8 @@ def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
     (KV, G) and contract against their KV head directly, so K/V are
     never expanded (no ``jnp.repeat``) and every cached byte is read
     once, in the cache's dtype.  MHA is G = 1, the same code.  mask:
-    (B or 1, Sq, T), True where query q may see slot t.  Scores and
-    softmax in f32, probabilities and values in ``config.dtype``."""
+    (B, Sq, T), True where query q may see slot t.  Scores and softmax
+    in f32, probabilities and values in ``config.dtype``."""
     c = config
     B, Sq, H, D = q.shape
     KV = k_cache.shape[2]
@@ -593,227 +598,98 @@ def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
     return out.reshape(B, Sq, H, D)
 
 
-def _cached_attention(q, k_cache, v_cache, pos, config: LlamaConfig):
-    """q: (B, Sq, H, D) attends over cache[:, :T]; positions > pos are
-    masked.  Works for prefill (Sq = prompt len, pos = len-1) and decode
-    (Sq = 1)."""
+def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
+    """The one place K/V enter a cache.  Writes ``new`` (R, Sq, KV, D)
+    into the WHOLE carried ``cache`` (L, B, T, KV, D) at ``layer``, rows
+    ``slot`` (None: all B rows) and ``positions``; returns the cache and
+    the (R, T, KV, D) slab of those rows, new tokens included, for
+    attention.  Which of the two comes first is chosen from static
+    shapes, because each order copies where the other is in place:
+
+    - one token for every row (the decode step): write the B rows into
+      the carried cache, THEN index the layer's slab out of it.  XLA
+      reads that slab where it lies; taken first, with the rows put
+      into the copy, 67 MB of slab would move a layer.
+    - a run of tokens (a prefill, a chunk): take the addressed rows'
+      slab FIRST, put the run into that copy for attention, and write
+      the run into the cache separately.  Written first and then
+      sliced, XLA copies the whole K and V cache every call (2 GiB at
+      the serving widths; tests/test_llama_decode_compile.py)."""
+    L, B, T, KV, D = cache.shape
+    R, Sq = positions.shape
+    rolling = config.sliding_window > 0
+
+    def write(buf, *lead):
+        # buf[*lead[r], slot of positions[r, s]] = new[r, s]
+        if rolling or Sq == 1:
+            # one row write per token: a decode step's tokens lie in R
+            # different rows; in a rolling buffer position t lives in
+            # slot t mod T, so a run may wrap
+            slots = positions % T if rolling else positions
+            return buf.at[(*(i[:, None] for i in lead), slots)].set(new)
+        # full causal: a run is contiguous, ONE block write per row (R
+        # is 1 for a prefill).  As a scatter of R windows XLA carries
+        # the cache through the layer loop in another layout and copies
+        # it whole twice a call; token by token it is Sq row writes.
+        for r in range(R):
+            at = (*(i[r] for i in lead), positions[r, 0], 0, 0)
+            buf = lax.dynamic_update_slice(buf, new[r][(None,) * len(lead)], at)
+        return buf
+
+    rows, row0 = jnp.arange(R), 0 if slot is None else slot
+    lead = (jnp.full((R,), layer), rows + row0)
+    if slot is None and Sq == 1:
+        cache = write(cache, *lead)
+        return cache, lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    slab = lax.dynamic_slice(cache, (layer, row0, 0, 0, 0), (1, R, T, KV, D))[0]
+    return write(cache, *lead), write(slab, rows)
+
+
+def _block_step(x, p, cache_k, cache_v, slot, positions, config: LlamaConfig):
+    """Block ``p["layer"]`` over each row's run of new tokens.  x: (R,
+    Sq, E); positions: (R, Sq) absolute position of every new token;
+    cache_k/v: the WHOLE (L, B, T, KV, D) cache, of which only the new
+    tokens' entries are written; slot: the one cache row addressed, or
+    None for all B.  Returns (x, cache_k, cache_v, expert_rows)."""
     c = config
-    Sq = q.shape[1]
-    T = k_cache.shape[1]
-    # causal within the query block + bounded by pos overall
-    q_pos = pos - (Sq - 1) + jnp.arange(Sq)  # absolute position per query
-    t_idx = jnp.arange(T)
-    if c.sliding_window:
-        # rolling buffer (slot correctness needs T >= window + Sq - 1:
-        # see rolling_cache_len / forward_cached)
-        mask = _rolling_mask(
-            q_pos[:, None], t_idx[None, :], T, c.sliding_window
-        )
-    else:
-        mask = t_idx[None, :] <= q_pos[:, None]  # (Sq, T)
-    return _grouped_attention(q, k_cache, v_cache, mask[None], c)
-
-
-def _block_cached(x, p, cache_k, cache_v, start, config: LlamaConfig):
-    """One block over Sq new tokens starting at absolute `start`;
-    returns (x_out, new_cache_k, new_cache_v, expert_rows)."""
-    c = config
-    B, Sq, _ = x.shape
-    h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-    positions = (start + jnp.arange(Sq))[None, :].repeat(B, 0)
-    q, kk, vv = _qkv(h, p, positions, c)
-    if c.sliding_window:
-        # rolling buffer: position t lives in slot t mod T
-        slots = (start + jnp.arange(Sq)) % cache_k.shape[1]
-        cache_k = cache_k.at[:, slots].set(kk.astype(c.dtype))
-        cache_v = cache_v.at[:, slots].set(vv.astype(c.dtype))
-    else:
-        cache_k = lax.dynamic_update_slice(
-            cache_k, kk.astype(c.dtype), (0, start, 0, 0)
-        )
-        cache_v = lax.dynamic_update_slice(
-            cache_v, vv.astype(c.dtype), (0, start, 0, 0)
-        )
-    attn = _cached_attention(q, cache_k, cache_v, start + Sq - 1, c)
-    x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
-    y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
-    return x + y, cache_k, cache_v, routing and routing["rows"]
-
-
-def forward_cached(params: Params, tokens, cache: Params, start,
-                   config: LlamaConfig):
-    """Run Sq new tokens through all layers, updating the cache.
-
-    Returns (last_logits (B, V), new_cache).  `start` is the absolute
-    position of tokens[:, 0] (0 for prefill; prompt_len + i in decode) —
-    a traced scalar, so one compile covers every step."""
-    c = config
-    if c.sliding_window:
-        T, Sq = cache["k"].shape[2], tokens.shape[1]
-        # structural bound only: a chunk longer than the cache would
-        # self-overwrite within one write-set.  Whether WRAPPING (a
-        # position overwriting position-minus-T) is safe depends on how
-        # far the caller decodes: positions < T never wrap (generate_kv
-        # sizes exactly so), and truly rolling callers size via
-        # rolling_cache_len() so wrapped slots are always out-of-window.
-        assert Sq <= T, (
-            f"prefill chunk {Sq} exceeds cache length {T}; prefill long "
-            "prompts in chunks"
-        )
-    x = params["tok_embed"].astype(c.dtype)[tokens]
-
-    (blocks, layers), whole = _layer_params(params["blocks"], c)
-
-    def body(carry, layer):
-        xx, _ = carry
-        p, l, ck, cv = layer
-        xx, ck, cv, expert_rows = _block_cached(
-            xx, dict(p, layer=l, **whole), ck, cv, start, c
-        )
-        return (xx, None), (ck, cv, expert_rows)
-
-    (x, _), (new_k, new_v, expert_rows) = lax.scan(
-        body, (x, None), (blocks, layers, cache["k"], cache["v"])
-    )
-    x = _rmsnorm(x, params["final_norm"], c.rms_eps)
-    logits = jnp.einsum(
-        "be,ve->bv",
-        x[:, -1, :],
-        _head_weight(params, c).astype(c.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    return logits, _with_expert_counts(cache, new_k, new_v, expert_rows)
-
-
-def generate_kv(params: Params, prompt, config: LlamaConfig, *,
-                max_new_tokens: int = 32, temperature: float = 0.0,
-                rng=None):
-    """KV-cache decode: prefill once, then one O(1)-per-token compiled
-    step — the serving fast path (vs generate()'s full recompute)."""
-    tokens = jnp.asarray(prompt, jnp.int32)
-    B, S0 = tokens.shape
-    if max_new_tokens <= 0:
-        return tokens
-    total = S0 + max_new_tokens
-    cache = init_cache(config, B, total)
-    temperature = float(temperature or 0.0)  # None == greedy
-
-    logits, cache = _prefill_jit(params, tokens, cache, jnp.int32(0),
-                                 config=config)
-    key = rng if rng is not None else jax.random.key(0)
-    key, sub = jax.random.split(key)
-    nxt = _pick_token(logits, sub, temperature=temperature)
-    out = [tokens, nxt[:, None]]
-    for i in range(1, max_new_tokens):
-        key, sub = jax.random.split(key)
-        nxt, cache = _decode_step(
-            params, nxt[:, None], cache, jnp.int32(S0 + i - 1), sub,
-            config=config, temperature=temperature,
-        )
-        out.append(nxt[:, None])
-    return jnp.concatenate(out, axis=1)
-
-
-# module-level jits: caches keyed by (config, shapes, temperature) so
-# repeated generate_kv calls — e.g. per serve request — reuse ONE
-# compiled prefill and ONE compiled decode step.  The cache buffers are
-# DONATED, so the (L, B, max_len, KV, D) k/v arrays are returned in the
-# buffers they came in.  Donation alone does not stop copies INSIDE the
-# step: forward_cached hands the slabs to its layer scan as xs -> ys,
-# which copies each slab once a call — nothing beside a prefill's
-# compute, but 9% of the serving decode step on the chip (PERF.md, PR
-# 25), which is why decode_step_rowwise carries the cache instead.
-_prefill_jit = jax.jit(
-    forward_cached, static_argnames="config", donate_argnames=("cache",)
-)
-
-
-@partial(jax.jit, static_argnames=("temperature",))
-def _pick_token(logits, key, *, temperature):
-    if temperature > 0.0:
-        return jax.random.categorical(key, logits / temperature).astype(
-            jnp.int32
-        )
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-@partial(
-    jax.jit,
-    static_argnames=("config", "temperature"),
-    donate_argnames=("cache",),
-)
-def _decode_step(params, tok, cache, start, key, *, config, temperature):
-    logits, cache = forward_cached(params, tok, cache, start, config)
-    return _pick_token(logits, key, temperature=temperature), cache
-
-
-# -- continuous batching (row-wise positions) --------------------------------
-# Serving batches sequences at DIFFERENT positions: each cache row b has
-# its own length pos[b].  The decode step scatters the new K/V at
-# [b, pos[b]] and masks attention per row — the primitive a continuous
-# batcher needs (reference role: vLLM-on-ray / serve LLM replicas; here
-# one fused XLA step for the whole slot batch).
-
-
-def _block_decode_rowwise(x, p, cache_k, cache_v, layer, pos,
-                          config: LlamaConfig):
-    """Block ``layer`` for ONE new token per row.  x: (B, 1, E); pos:
-    (B,) absolute position of the new token in each row; cache_k/v: the
-    WHOLE (L, B, T, KV, D) cache, of which only the new row at
-    [layer, b, slot[b]] is written."""
-    c = config
-    B = x.shape[0]
     with jax.named_scope("decode_attn"):
         h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-        q, kk, vv = _qkv(h, p, pos[:, None], c)
-        rows = jnp.arange(B)
-        T = cache_k.shape[2]
-        slot = pos % T if c.sliding_window else pos  # rolling buffer slots
-        cache_k = cache_k.at[layer, rows, slot].set(kk[:, 0].astype(c.dtype))
-        cache_v = cache_v.at[layer, rows, slot].set(vv[:, 0].astype(c.dtype))
-        # attention over each row's own prefix [0, pos[b]]
-        t_idx = jnp.arange(T)
-        if c.sliding_window:
-            # rolling buffer: reconstruct each slot's position per row
-            mask = _rolling_mask(
-                pos[:, None], t_idx[None, :], T, c.sliding_window
-            )
-        else:
-            mask = t_idx[None, :] <= pos[:, None]  # (B, T)
-        attn = _grouped_attention(
-            q,
-            lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False),
-            lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False),
-            mask[:, None, :], c,
+        q, kk, vv = _qkv(h, p, positions, c)
+        cache_k, slab_k = _write_and_read(
+            cache_k, kk.astype(c.dtype), p["layer"], slot, positions, c
         )
+        cache_v, slab_v = _write_and_read(
+            cache_v, vv.astype(c.dtype), p["layer"], slot, positions, c
+        )
+        mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window)
+        attn = _grouped_attention(q, slab_k, slab_v, mask, c)
         x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     with jax.named_scope("decode_mlp"):
         y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
     return x + y, cache_k, cache_v, routing and routing["rows"]
 
 
-@partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
-def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
-    """One token for every row at per-row positions.
+def _cached_step(params: Params, tokens, cache: Params, slot, start,
+                 config: LlamaConfig):
+    """The ONE cached step: a contiguous run of Sq new tokens per row,
+    each row's starting at its own offset, through all layers.
 
-    tokens: (B,) int32 last token per row; pos: (B,) its absolute
-    position.  Returns (logits (B, V) f32, new cache).  Inactive rows
-    simply keep decoding garbage into their own slots — the engine masks
-    them out — so the compiled shape never changes.
-
-    The (donated) cache rides the layer loop whole, as its carry: each
-    layer writes one new K/V row per sequence in place and reads its own
-    slab once, unexpanded; no slab is copied."""
+    tokens: (R, Sq); start: (R,) absolute position of tokens[:, 0];
+    slot: None when the R rows are all B rows of the cache, else the
+    (traced) index of the one row addressed (R = 1).  Returns
+    (last-token logits (R, V) f32, new cache).  The cache rides the
+    layer loop whole, as its carry: under a jit that donates it every
+    layer writes the new tokens' K/V in place and no slab is copied."""
     c = config
-    x = params["tok_embed"].astype(c.dtype)[tokens][:, None, :]
-
+    positions = start[:, None] + jnp.arange(tokens.shape[1])
+    x = params["tok_embed"].astype(c.dtype)[tokens]
     xs, whole = _layer_params(params["blocks"], c)
 
     def body(carry, layer):
         xx, ck, cv = carry
         p, l = layer
-        *carry, expert_rows = _block_decode_rowwise(
-            xx, dict(p, layer=l, **whole), ck, cv, l, pos, c
+        *carry, expert_rows = _block_step(
+            xx, dict(p, layer=l, **whole), ck, cv, slot, positions, c
         )
         return tuple(carry), expert_rows
 
@@ -830,24 +706,93 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
     return logits, _with_expert_counts(cache, new_k, new_v, expert_rows)
 
 
+def forward_cached(params: Params, tokens, cache: Params, start,
+                   config: LlamaConfig):
+    """Run Sq new tokens of every row through all layers, updating the
+    cache.  Returns (last_logits (B, V), new_cache).  `start` is the
+    absolute position of tokens[:, 0], the same for all rows (0 for
+    prefill) — a traced scalar, so one compile covers every chunk."""
+    if config.sliding_window:
+        T, Sq = cache["k"].shape[2], tokens.shape[1]
+        # structural bound only: a chunk longer than the cache would
+        # self-overwrite within one write-set.  Whether WRAPPING (a
+        # position overwriting position-minus-T) is safe depends on how
+        # far the caller decodes: positions < T never wrap (generate_kv
+        # sizes exactly so), and truly rolling callers size via
+        # rolling_cache_len() so wrapped slots are always out-of-window.
+        assert Sq <= T, (
+            f"prefill chunk {Sq} exceeds cache length {T}; prefill long "
+            "prompts in chunks"
+        )
+    start = jnp.full((tokens.shape[0],), start, jnp.int32)
+    return _cached_step(params, tokens, cache, None, start, config)
+
+
+@partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
+def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
+    """One token for every row at per-row positions — the primitive a
+    continuous batcher needs: each cache row b has its own length.
+
+    tokens: (B,) int32 last token per row; pos: (B,) its absolute
+    position.  Returns (logits (B, V) f32, new cache).  Inactive rows
+    simply keep decoding garbage into their own slots — the engine masks
+    them out — so the compiled shape never changes.  In place: one new
+    K/V row per sequence and layer, each slab read once, unexpanded."""
+    return _cached_step(params, tokens[:, None], cache, None, pos, config)
+
+
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
 def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
     """Prefill ONE sequence into batched-cache row ``slot``.
 
     tokens: (1, S) prompt; cache: the engine's (L, B, T, KV, D) batch
-    cache.  Returns (last-token logits (1, V), updated cache).  One
-    compile per prompt-bucket length serves every slot (slot is traced).
-    """
-    sub = dict(
-        cache,
-        k=lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1),
-        v=lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1),
+    cache, donated and written in place at that row only.  Returns
+    (last-token logits (1, V), updated cache).  One compile per
+    prompt-bucket length serves every slot (slot is traced)."""
+    return _cached_step(
+        params, tokens, cache, slot, jnp.zeros((1,), jnp.int32), config
     )
-    logits, sub = forward_cached(params, tokens, sub, jnp.int32(0), config)
-    # an expert config's counters come back in ``sub``, already advanced
-    cache = dict(
-        sub,
-        k=lax.dynamic_update_slice_in_dim(cache["k"], sub["k"], slot, axis=1),
-        v=lax.dynamic_update_slice_in_dim(cache["v"], sub["v"], slot, axis=1),
-    )
-    return logits, cache
+
+
+@partial(jax.jit, static_argnames=("temperature",))
+def _pick_token(logits, key, *, temperature):
+    if temperature > 0.0:
+        return jax.random.categorical(key, logits / temperature).astype(
+            jnp.int32
+        )
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def generate_kv(params: Params, prompt, config: LlamaConfig, *,
+                max_new_tokens: int = 32, temperature: float = 0.0,
+                rng=None):
+    """KV-cache decode (B, S) → (B, S + max_new_tokens) with the serving
+    engine's two programs: ``prefill_into_slot`` once per row, then one
+    ``decode_step_rowwise`` per token with every row at the same
+    position.  Same tokens as ``generate``'s full recompute; a
+    convenience for scripts and tests — ``serve.llm.LlamaDeployment``
+    is what batches requests at different positions."""
+    tokens = jnp.asarray(prompt, jnp.int32)
+    B, S0 = tokens.shape
+    if max_new_tokens <= 0:
+        return tokens
+    cache = init_cache(config, B, S0 + max_new_tokens)
+    temperature = float(temperature or 0.0)  # None == greedy
+    rows = []
+    for b in range(B):
+        row, cache = prefill_into_slot(
+            params, tokens[b:b + 1], cache, jnp.int32(b), config
+        )
+        rows.append(row)
+    logits = jnp.concatenate(rows, axis=0)
+    key = rng if rng is not None else jax.random.key(0)
+    out = [tokens]
+    for i in range(max_new_tokens):
+        if i:  # the first token comes from the prefills' logits
+            pos = jnp.full((B,), S0 + i - 1, jnp.int32)
+            logits, cache = decode_step_rowwise(
+                params, out[-1][:, 0], cache, pos, config
+            )
+        key, sub = jax.random.split(key)
+        out.append(_pick_token(logits, sub, temperature=temperature)[:, None])
+    return jnp.concatenate(out, axis=1)

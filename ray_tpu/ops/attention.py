@@ -12,21 +12,19 @@ import jax
 import jax.numpy as jnp
 
 
-def dense_attention(q, k, v, *, start_pos: int = 0, window: int = 0):
+def dense_attention(q, k, v, *, window: int = 0):
     """Causal attention, f32 softmax.  q,k,v: (B, S, H, D).
 
-    ``start_pos`` offsets query positions for decode-time use (queries
-    are a suffix of the key sequence).  ``window`` > 0 limits each
-    query to the last ``window`` keys (Mistral-style sliding-window
-    attention: position t attends to (t-window, t]; memory-for-range
-    tradeoff long-context models use).
+    ``window`` > 0 limits each query to the last ``window`` keys
+    (Mistral-style sliding-window attention: position t attends to
+    (t-window, t]; memory-for-range tradeoff long-context models use).
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) / math.sqrt(D)
-    q_pos = jnp.arange(Sq)[:, None] + start_pos
+    q_pos = jnp.arange(Sq)[:, None]
     k_pos = jnp.arange(Sk)[None, :]
     mask = q_pos >= k_pos
     if window > 0:

@@ -58,6 +58,102 @@ class TestMultiNode:
         nodes = set(ray_tpu.get(refs, timeout=120))
         assert len(nodes) == 2
 
+    def test_a_task_queued_behind_busy_leases_asks_for_its_own(self, cluster):
+        """What failed the test above in whole runs (`never four at
+        once`): on a loaded host the driver's fourth ``.remote()`` can
+        come after the first three leases were granted, and the lease
+        pump counted those three, all busy, as capacity — so the fourth
+        task asked for no lease and waited for one of theirs, with a CPU
+        free.  Here the straggler is made, not hoped for."""
+        @ray_tpu.remote(num_cpus=0)
+        class Arrivals:
+            def __init__(self):
+                self.n = 0
+
+            def arrive(self):
+                self.n += 1
+
+            def count(self):
+                return self.n
+
+        @ray_tpu.remote
+        def hold(arrivals):
+            ray_tpu.get(arrivals.arrive.remote(), timeout=60)
+            deadline = time.monotonic() + 30
+            while ray_tpu.get(arrivals.count.remote(), timeout=60) < 4:
+                assert time.monotonic() < deadline, "the fourth never ran"
+                time.sleep(0.05)
+            return True
+
+        arrivals = Arrivals.remote()
+        refs = [hold.remote(arrivals) for _ in range(3)]
+        deadline = time.monotonic() + 60
+        while ray_tpu.get(arrivals.count.remote(), timeout=60) < 3:
+            assert time.monotonic() < deadline, "three never ran"
+            time.sleep(0.05)
+        refs.append(hold.remote(arrivals))  # three leases busy, one CPU free
+        assert ray_tpu.get(refs, timeout=120) == [True] * 4
+
+    def test_a_lease_cannot_take_a_worker_spawned_for_another(self):
+        """A fresh worker sat in the idle pool from its ``worker_ready``
+        until the lease that spawned it woke from its 10 ms poll, and a
+        lease arriving in between was handed the same worker, whose two
+        leases' tasks then ran one behind the other."""
+        import asyncio
+
+        from ray_tpu.common.ids import WorkerID
+        from ray_tpu.core import raylet as raylet_mod
+
+        class Proc:
+            poll = staticmethod(lambda: None)
+
+        class Conn:
+            closed = False
+
+            def __init__(self):
+                self.peer_info = {}
+
+            async def call(self, method, payload):
+                return True
+
+        r = raylet_mod.Raylet.__new__(raylet_mod.Raylet)
+        r.draining = r._fencing = False
+        r._idle_by_env, r.workers, spawned = {}, {}, []
+
+        def spawn(**kw):
+            w = raylet_mod.WorkerEntry(worker_id=WorkerID.random(), proc=Proc())
+            r.workers[w.worker_id] = w
+            spawned.append(w)
+            return w
+
+        r._spawn_worker = spawn
+
+        def lease(n):
+            return asyncio.ensure_future(r.rpc_lease_worker(
+                None, {"lease_id": n, "resources": {"CPU": 1}}))
+
+        async def ready(w, addr):
+            await r.rpc_worker_ready(
+                Conn(), {"worker_id": w.worker_id.binary(), "address": addr})
+
+        async def scenario():
+            first = lease(1)
+            await asyncio.sleep(0)  # spawned its worker, now polls for it
+            await ready(spawned[0], "w:1")
+            second = lease(2)  # arrives before lease 1 wakes
+            await asyncio.sleep(0)
+            assert len(spawned) == 2, "lease 2 took lease 1's worker"
+            await ready(spawned[1], "w:2")
+            got = await asyncio.wait_for(asyncio.gather(first, second), 5)
+            assert [g["worker_addr"] for g in got] == ["w:1", "w:2"]
+            # returned, a worker is pooled for the next lease
+            await r.rpc_release_worker(
+                None, {"worker_id": spawned[0].worker_id.binary()})
+            third = await asyncio.wait_for(lease(3), 5)
+            assert third["worker_addr"] == "w:1" and len(spawned) == 2
+
+        asyncio.run(scenario())
+
     def test_object_transfer_across_nodes(self, cluster):
         @ray_tpu.remote(resources={"side": 1})
         def produce():

@@ -335,6 +335,22 @@ class TestKVCacheDecode:
         cache = llama.init_cache(cfg, 3, 32)
         assert cache["k"].shape == (2, 3, 32, 2, 16)
 
+    def test_generate_kv_decodes_with_the_engines_programs(self):
+        """generate_kv has no decode program of its own: on a shape no
+        other test uses it compiles one ``prefill_into_slot`` and one
+        ``decode_step_rowwise``, the two the serving engine runs."""
+        cfg = llama.LlamaConfig.tiny(vocab_size=300, num_layers=1)
+        params = llama.init(jax.random.key(0), cfg)
+        prompt = jax.random.randint(jax.random.key(2), (3, 5), 0, 300)
+        before = (llama.prefill_into_slot._cache_size(),
+                  llama.decode_step_rowwise._cache_size())
+        cached = llama.generate_kv(params, prompt, cfg, max_new_tokens=3)
+        assert (llama.prefill_into_slot._cache_size(),
+                llama.decode_step_rowwise._cache_size()) == (
+            before[0] + 1, before[1] + 1)
+        full = llama.generate(params, prompt, cfg, max_new_tokens=3)
+        np.testing.assert_array_equal(np.asarray(full), np.asarray(cached))
+
 
 class TestRowwiseDecode:
     """The continuous batcher's step: rows at different positions in one
@@ -401,6 +417,62 @@ class TestRowwiseDecode:
             np.asarray(clean[1]), np.asarray(noisy[1])
         )
         assert not np.array_equal(np.asarray(clean[0]), np.asarray(noisy[0]))
+
+    @pytest.mark.parametrize("window", [0, 5])
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1])
+    def test_entry_points_agree_and_touch_only_their_rows(self, kv_heads, window):
+        """One prompt through ``forward_cached`` into a 1-row cache and
+        through ``prefill_into_slot`` into row 2 of a 4-row cache full
+        of noise, then one ``decode_step_rowwise`` on each: the same
+        logits and K/V, and every other row as it was, to the bit."""
+        cfg = llama.LlamaConfig.tiny(
+            num_heads=4, num_kv_heads=kv_heads, sliding_window=window
+        )
+        params = llama.init(jax.random.key(0), cfg)
+        n, row = 6, 2
+        prompt = jax.random.randint(jax.random.key(12), (1, n), 0, 256)
+        one = llama.init_cache(cfg, 1, self.T)
+        noise = {
+            name: np.asarray(
+                jax.random.normal(jax.random.key(i), a.shape, a.dtype))
+            for i, (name, a) in enumerate(
+                llama.init_cache(cfg, 4, self.T).items())
+        }
+        others = [0, 1, 3]
+
+        def same_rows(four, one, upto, before, others_from):
+            for name in ("k", "v"):
+                got, want = np.asarray(four[name]), np.asarray(one[name])
+                np.testing.assert_allclose(
+                    got[:, row, :upto], want[:, 0, :upto], atol=1e-5, rtol=1e-5)
+                # the row's own tail and the other rows: never written
+                np.testing.assert_array_equal(
+                    got[:, row, upto:], before[name][:, row, upto:])
+                np.testing.assert_array_equal(
+                    got[:, others, others_from:],
+                    before[name][:, others, others_from:])
+
+        want, one = llama.forward_cached(params, prompt, one, jnp.int32(0), cfg)
+        got, four = llama.prefill_into_slot(
+            params, prompt, {k: jnp.asarray(v) for k, v in noise.items()},
+            jnp.int32(row), cfg,
+        )
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+        same_rows(four, one, n, noise, 0)
+
+        tok = jnp.argmax(want, axis=-1).astype(jnp.int32)
+        want, one = llama.decode_step_rowwise(
+            params, tok, one, jnp.full((1,), n, jnp.int32), cfg)
+        prefilled = {k: np.asarray(v) for k, v in four.items()}
+        # the other rows decode token 0 at position 0: into their slot 0
+        got, four = llama.decode_step_rowwise(
+            params, jnp.zeros((4,), jnp.int32).at[row].set(tok[0]), four,
+            jnp.zeros((4,), jnp.int32).at[row].set(n), cfg,
+        )
+        np.testing.assert_allclose(
+            np.asarray(got[row]), np.asarray(want[0]), atol=1e-5, rtol=1e-5)
+        same_rows(four, one, n + 1, prefilled, 1)
 
 
 def _expert_config(**kw):

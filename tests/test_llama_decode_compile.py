@@ -13,6 +13,7 @@ text.  A compile that passes is not a chip run: nothing executes here.
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,3 +154,62 @@ def test_expert_decode_step_routes_without_a_dense_intermediate(
     for sliced in (f"bf16[{x},{e},{m}]", f"bf16[{x},{m},{e}]",
                    f"bf16[1,{x},{e},{m}]", f"bf16[1,{x},{m},{e}]"):
         assert sliced not in text, f"one layer's experts are copied: {sliced}"
+
+
+@pytest.mark.parametrize("prompt_len", [256, 768])
+@pytest.mark.parametrize("served_file, aliased_gib", [
+    pytest.param(CONFIG_FILE, 2, id="internlm2"),
+    pytest.param(OLMOE_FILE, 3, id="olmoe"),
+])
+def test_prefill_writes_the_donated_cache_in_place(
+        v5e_chip, no_compile_cache, monkeypatch, served_file, aliased_gib,
+        prompt_len):
+    """``prefill_into_slot`` at the serving cells' widths carries the
+    engine's WHOLE cache through its layer loop (one cached step since
+    PR 28), so one stray copy of that cache is 2 GiB (InternLM2) or no
+    program at all (OLMoE: 13.7 GB live of 16).  Seen at compile time
+    while the step was merged: the run written before the slab is read,
+    or written as a scatter of windows, and XLA copies K and V whole
+    (2,050 MiB of temporaries).  The program that sliced a one-row
+    cache out and wrote it back had 128-268 MiB."""
+    from chipbench.jobs.serve_llm import llama_config
+    from chipbench.jobs.serve_moe import moe_config
+    from ray_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    with open(served_file) as f:
+        served = json.load(f)
+    config = (moe_config if served_file == OLMOE_FILE else llama_config)(served)
+    slots, max_len = served["serving"]["max_slots"], served["serving"]["max_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    compiled = llama.prefill_into_slot.lower(
+        params,
+        jax.ShapeDtypeStruct((1, prompt_len), jnp.int32, sharding=v5e_chip),
+        cache,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip),
+        config,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= aliased_gib * 2**30, mem
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem
+    # the prompt's K and V go in as one block each, not token by token
+    whole = "bf16[{}]".format(",".join(map(str, cache["k"].shape)))
+    text = compiled.as_text()
+
+    def making_a_cache(op):  # instructions whose result is a whole K or V
+        return re.findall(rf"{re.escape(whole)}\S* {op}\(", text)
+
+    assert len(making_a_cache("dynamic-update-slice")) == 2
+    assert not making_a_cache("scatter")

@@ -177,8 +177,8 @@ def test_tasks_async_single_client_throughput_floor(cluster):
     """Wall-clock floor for the `tasks_async_single_client` bench row
     (VERDICT weak #1: frozen at 0.27x baseline for two rounds with no
     guard).  The bound is deliberately ~5-10x below the bench-host
-    steady state (2,234/s in BENCH_r05) so a loaded 1-core CI host
-    passes with margin while a real regression on the windowed
+    steady state (2,234/s in round 5's driver record) so a loaded
+    1-core CI host passes with margin while a real regression on the windowed
     submission path — extra per-task GCS round trips, lease churn, lost
     pipelining — still fails loudly."""
 
