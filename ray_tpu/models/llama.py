@@ -741,6 +741,14 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
     return _cached_step(params, tokens[:, None], cache, None, pos, config)
 
 
+@jax.jit
+def set_row(tokens, row, token):
+    """``tokens`` (B,) with ``tokens[row] = token``, on the device: how a
+    batcher that feeds each decode step the argmax of the one before
+    hands it the first token of a row it has just prefilled."""
+    return tokens.at[row].set(token)
+
+
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
 def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
     """Prefill ONE sequence into batched-cache row ``slot``.

@@ -28,6 +28,7 @@ Wire-up::
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import heapq
 import itertools
@@ -63,10 +64,14 @@ _MOE_LOAD_MAX_OVER_MEAN = metrics.Gauge(
 _OFF = contextlib.nullcontext()
 
 
-def _span(on: bool, name: str):
-    """A child span of the ambient one while tracing is on (asked once
-    per engine iteration, so an iteration is recorded whole or not)."""
-    return tracing.span(name) if on else _OFF
+def _part(parent: Optional[tracing.Span], name: str):
+    """A child span of ``parent``, whatever the ambient context: a decode
+    step's parts are recorded an iteration apart.  ``parent`` is None
+    where tracing was off when the step began (asked once per step, so a
+    step is recorded whole or not at all)."""
+    if parent is None:
+        return _OFF
+    return tracing.Span(name, (parent.trace_id, parent.span_id), {})
 
 
 class _Request:
@@ -81,24 +86,53 @@ class _Request:
 
 
 class _Slot:
-    __slots__ = ("queue", "pos", "remaining", "last_token", "max_pos")
+    """What the host knows of a cache row when it launches a step.  The
+    row's token is not here: it is row i of ``LLMEngine._tokens``, the
+    last launched step's argmax, which stays on the device."""
 
-    def __init__(self, queue, pos, remaining, last_token, max_pos):
+    __slots__ = ("queue", "pos", "remaining", "max_pos")
+
+    def __init__(self, queue, pos, remaining, max_pos):
         self.queue = queue          # per-request token queue
-        self.pos = pos              # absolute position of last_token
-        self.remaining = remaining  # tokens still to generate
-        self.last_token = last_token
+        self.pos = pos              # position of the token the next step feeds
+        self.remaining = remaining  # decode steps still to launch
         self.max_pos = max_pos
+
+
+class _Step:
+    """A decode step from its launch to the delivery of its tokens."""
+
+    __slots__ = ("tokens", "rows", "span")
+
+    def __init__(self, tokens, rows, span):
+        self.tokens = tokens  # (max_slots,) int32 argmax, on the device
+        self.rows = rows      # [(row, queue, the request's last token?)]
+        self.span = span      # its llm.step span, if traced
 
 
 _END = object()
 
 
 class LLMEngine:
-    """Slot-based continuous batcher: admit-prefill + shared decode step."""
+    """Slot-based continuous batcher: admit-prefill + shared decode step,
+    with one decode step in flight ahead of the one being delivered.
+
+    The host never needs a token to schedule the next step: step k+1 is
+    fed step k's argmax as it is, a device array (``_tokens``; a prefill's
+    first token is merged into it on the device), a row's position is its
+    last plus one, and a row ends by counts the host holds (no stop
+    token).  So the loop launches step k+1, then waits for step k's
+    tokens, hands them out and yields to their consumers while the device
+    computes.  Only an admission drains the pipeline: the prefill is
+    launched behind the step in flight, that step's tokens are delivered,
+    the prefill's first token is waited for, and the next step starts on
+    an idle device — one long token gap per admission for every live
+    row, not two."""
 
     def __init__(self, params, config, *, max_slots: int = 4,
                  max_len: int = 256, max_prompt_len: Optional[int] = None):
+        import jax.numpy as jnp
+
         from ray_tpu.models import llama
 
         self._llama = llama
@@ -119,10 +153,18 @@ class LLMEngine:
         else:
             self.cache_len = max_len
         self.cache = llama.init_cache(config, max_slots, self.cache_len)
-        # held while a prefill or a decode step has the (donated) cache:
-        # whoever else wants to read it (moe_counters) waits its turn
+        # held while a prefill or a decode step is launched on the
+        # (donated) cache: whoever else wants to read it (moe_counters)
+        # waits its turn, and then for the steps launched so far
         self._cache_lock = asyncio.Lock()
+        # a slot is taken from a request's prefill to the LAUNCH of its
+        # last step; that step's _Step delivers the last token
         self.slots: List[Optional[_Slot]] = [None] * max_slots
+        # what the next decode step is fed, on the device
+        self._tokens = jnp.zeros((max_slots,), jnp.int32)
+        # launched and not yet delivered, oldest first: at most two, and
+        # two only between a launch and the delivery that follows it
+        self._flying: collections.deque = collections.deque()
         # slot admitter queue: EDF heap of (deadline, seq, _Request) —
         # requests with a traffic-plane SLO overtake deadline-less ones
         # (deadline=inf) at the free slot, and expired waiters are shed
@@ -134,10 +176,12 @@ class LLMEngine:
         # admitter counters (bench / tests)
         self.admitted_total = 0
         self.shed_total = 0
-        self._steps = 0  # engine iterations begun (llm.step's `step`)
         # token rows the model was given: a prompt's length per prefill,
         # max_slots per decode step (inactive rows are computed too)
         self.rows_stepped_total = 0
+        self.decode_steps_total = 0
+        # of those, launched while the one before had not been synced
+        self.steps_launched_ahead_total = 0
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -219,31 +263,42 @@ class LLMEngine:
             except Exception as e:  # noqa: BLE001 — delivered to clients
                 import logging
 
+                import jax.numpy as jnp
+
                 logging.getLogger(__name__).exception(
                     "LLM engine step failed; failing active requests"
                 )
-                # fail every active stream and drain pending admissions;
-                # reinitialize the cache (a donated buffer may be stale
-                # after a mid-step failure) and keep serving
-                for i, s in enumerate(self.slots):
-                    if s is not None:
-                        await s.queue.put(e)
-                        await s.queue.put(_END)
-                        self.slots[i] = None
+                # fail every active stream (a row is in its slot, in a
+                # step in flight, or both) and drain pending admissions;
+                # drop the steps in flight and their tokens with the
+                # cache (a donated buffer may be stale after a mid-step
+                # failure) and keep serving
+                live = {id(s.queue): s.queue for s in self.slots if s}
+                for step in self._flying:
+                    live.update((id(q), q) for _, q, _ in step.rows)
                 while self._pending:
                     q = heapq.heappop(self._pending)[2].queue
+                    live[id(q)] = q
+                for q in live.values():
                     await q.put(e)
                     await q.put(_END)
+                self.slots = [None] * self.max_slots
+                self._flying.clear()
+                self._tokens = jnp.zeros((self.max_slots,), jnp.int32)
                 self.cache = self._llama.init_cache(
                     self.config, self.max_slots, self.cache_len
                 )
 
-    async def _admit(self, on: bool) -> int:
+    async def _admit(self, life: Optional[tracing.Span]) -> int:
         """Admit pending requests into free slots (prefill), EDF: the
         earliest-deadline waiter takes the free cache row, and a waiter
         whose deadline lapsed in this queue is shed — prefill compute
         for a response the client already gave up on would only delay
-        every live slot's next token.  Returns the requests prefilled."""
+        every live slot's next token.  Returns the requests prefilled.
+
+        The first prefill is launched behind the decode step in flight,
+        whose tokens are delivered before this waits for the prefill's
+        own: the pipeline is empty from there on."""
         import jax.numpy as jnp
 
         llama = self._llama
@@ -290,20 +345,24 @@ class LLMEngine:
                 "llm.prefill", (req.trace_id, tracing.current()[1]),
                 {"slot": slot, "prompt_len": S0,
                  "rows_stalled": self.max_slots - self.slots.count(None)},
-            ) if on else _OFF:
+            ) if life is not None else _OFF:
                 toks = jnp.asarray([prompt], jnp.int32)
+                row = jnp.int32(slot)
 
                 def _prefill():
                     return llama.prefill_into_slot(
-                        self.params, toks, self.cache, jnp.int32(slot),
-                        cfg,
+                        self.params, toks, self.cache, row, cfg,
                     )
 
                 async with self._cache_lock:
                     logits, self.cache = await asyncio.to_thread(_prefill)
                     self.rows_stepped_total += S0
-                first = int(jnp.argmax(logits[0]))
-                await q.put(first)
+                first = jnp.argmax(logits[0])
+                if max_new > 1:
+                    self._tokens = llama.set_row(self._tokens, row, first)
+                if self._flying:
+                    await self._deliver()
+                await q.put(int(first))
             _ENGINE_TTFT_MS.observe((time.monotonic() - req.pushed) * 1e3)
             prefilled += 1
             if max_new <= 1:
@@ -311,80 +370,112 @@ class LLMEngine:
                 continue
             self.slots[slot] = _Slot(
                 queue=q, pos=S0, remaining=max_new - 1,
-                last_token=first, max_pos=self.max_len - 1,
+                max_pos=self.max_len - 1,
             )
         return prefilled
 
-    async def _run_inner(self):
+    async def _launch(self, life: Optional[tracing.Span],
+                      active: List[int]) -> None:
+        """Call one fused decode step over ALL slots (inactive rows decode
+        into their own rows harmlessly; the shape stays constant) on the
+        tokens the last one left on the device, and put it in flight.
+        Nothing here waits for the device."""
         import jax.numpy as jnp
         import numpy as np
 
         llama = self._llama
         cfg = self.config
+        with _part(life, "llm.step.build"):
+            pos = np.zeros((self.max_slots,), np.int32)
+            for i in active:
+                pos[i] = self.slots[i].pos
+        with _part(life, "llm.step.dispatch") as dispatch:
+
+            def _step(tokens=self._tokens):
+                p = jnp.asarray(pos)
+                # what is left of the dispatch after the hop to the
+                # thread and the copy
+                with _part(dispatch, "llm.step.launch"):
+                    logits, cache = llama.decode_step_rowwise(
+                        self.params, tokens, self.cache, p, cfg,
+                    )
+                    return jnp.argmax(logits, axis=-1), cache
+
+            async with self._cache_lock:
+                self._tokens, self.cache = await asyncio.to_thread(_step)
+                self.rows_stepped_total += self.max_slots
+            self.decode_steps_total += 1
+            if self._flying:
+                self.steps_launched_ahead_total += 1
+            # a row's last step is known now, by counts: its slot is
+            # free for the next prefill, which the device runs behind
+            # this step
+            rows = []
+            for i in active:
+                s = self.slots[i]
+                s.pos += 1
+                s.remaining -= 1
+                last = s.remaining <= 0 or s.pos >= s.max_pos
+                rows.append((i, s.queue, last))
+                if last:
+                    self.slots[i] = None
+            self._flying.append(_Step(self._tokens, rows, life))
+
+    async def _deliver(self) -> None:
+        """Wait for the oldest step in flight, put its tokens on their
+        queues and give their consumers one pass of the loop."""
+        import numpy as np
+
+        step = self._flying[0]  # in flight until synced: _run fails its rows
+        with _part(step.span, "llm.step.sync"):
+            nxt = np.asarray(step.tokens)
+        self._flying.popleft()
+        with _part(step.span, "llm.step.deliver"):
+            for i, q, last in step.rows:
+                await q.put(int(nxt[i]))
+                if last:
+                    await q.put(_END)
+        # consumers take their tokens and the transport sends them while
+        # the device computes the step launched before this one's sync
+        with _part(step.span, "llm.step.yield"):
+            await asyncio.sleep(0)
+        if step.span is not None:
+            step.span.finish()
+
+    async def _run_inner(self):
         while True:
-            if not self._pending and self.slots.count(None) == self.max_slots:
+            if not (self._pending or self._flying or any(self.slots)):
                 # idle: park until a request arrives
                 self._wake.clear()
                 with tracing.root("llm.idle") if tracing.enabled() else _OFF:
                     await self._wake.wait()
                 continue
-            # the spans of one iteration (util/tracing.py; names and the
+            # one llm.step span is the life of one decode step, from the
+            # admissions before its launch to the yield after its
+            # delivery an iteration later: consecutive ones overlap, so
+            # they are finished by hand (util/tracing.py; names and the
             # metric each is read by: PERF.md section 3)
-            on = tracing.enabled()
-            self._steps += 1
-            with (tracing.root("llm.step", step=self._steps)
-                  if on else _OFF) as step:
-                admitted = 0
-                if self._pending and None in self.slots:
-                    with _span(on, "llm.step.admit"):
-                        admitted = await self._admit(on)
-                active = [
-                    i for i, s in enumerate(self.slots) if s is not None
-                ]
-                if on:
-                    step.attrs.update(active=len(active), admitted=admitted)
-                if not active:
-                    continue
-                # one fused decode step over ALL slots (inactive rows
-                # decode into their own rows harmlessly; shape stays
-                # constant)
-                with _span(on, "llm.step.build"):
-                    tokens = np.zeros((self.max_slots,), np.int32)
-                    pos = np.zeros((self.max_slots,), np.int32)
-                    for i in active:
-                        tokens[i] = self.slots[i].last_token
-                        pos[i] = self.slots[i].pos
-
-                    def _step(t=tokens, p=pos):
-                        t, p = jnp.asarray(t), jnp.asarray(p)
-                        # the thread runs in a copy of this context, so
-                        # the span is llm.step.dispatch's child: what is
-                        # left of it after the hop and the two copies
-                        with _span(on, "llm.step.launch"):
-                            return llama.decode_step_rowwise(
-                                self.params, t, self.cache, p, cfg,
-                            )
-
-                with _span(on, "llm.step.dispatch"):
-                    async with self._cache_lock:
-                        logits, self.cache = await asyncio.to_thread(_step)
-                        self.rows_stepped_total += self.max_slots
-                with _span(on, "llm.step.sync"):
-                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-                with _span(on, "llm.step.deliver"):
-                    for i in active:
-                        s = self.slots[i]
-                        tok = int(nxt[i])
-                        await s.queue.put(tok)
-                        s.last_token = tok
-                        s.pos += 1
-                        s.remaining -= 1
-                        if s.remaining <= 0 or s.pos >= s.max_pos:
-                            await s.queue.put(_END)
-                            self.slots[i] = None
-                # let admissions/consumers run between steps
-                with _span(on, "llm.step.yield"):
-                    await asyncio.sleep(0)
+            life = tracing.root(
+                "llm.step", step=self.decode_steps_total + 1,
+            ) if tracing.enabled() else None
+            admitted = 0
+            if self._pending and None in self.slots:
+                with _part(life, "llm.step.admit"):
+                    admitted = await self._admit(life)
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            # the step launched by the last iteration, unless an
+            # admission has just delivered it
+            ahead = bool(self._flying)
+            if life is not None:
+                life.attrs.update(
+                    active=len(active), admitted=admitted, ahead=ahead,
+                )
+            if active:
+                await self._launch(life, active)
+            elif life is not None:
+                life.finish()  # it admitted at most; there is no step
+            if ahead:
+                await self._deliver()
 
 
 @serve.deployment
@@ -420,7 +511,10 @@ class LlamaDeployment:
         jax device it holds, its XLA compiles (all of them, and how
         many versions of the two engine programs — prefill compiles
         once per distinct prompt length), peak device memory where the
-        backend reports it, and the admitter's counters.
+        backend reports it, the admitter's counters, and the decode
+        steps launched (``decode_steps_total``; of those,
+        ``steps_launched_ahead_total`` while the one before was still
+        in flight: all but the first after each admission or idle time).
 
         An expert config adds which grouped-matmul body its programs
         were traced with (``grouped_matmul``) and the routing counters
@@ -453,13 +547,17 @@ class LlamaDeployment:
             "admitted_total": self.engine.admitted_total,
             "shed_total": self.engine.shed_total,
             "rows_stepped_total": self.engine.rows_stepped_total,
+            "decode_steps_total": self.engine.decode_steps_total,
+            "steps_launched_ahead_total":
+                self.engine.steps_launched_ahead_total,
         }
 
     def update_weights(self, params) -> bool:
         """Swap the decode params in place — the serve weight-push path
         (`serve.weights.push_weights` fans new weights to every replica
         via one collective broadcast, optionally block-quantized).
-        In-flight decodes pick the new params up at their next step;
+        In-flight decodes pick the new params up at the next step the
+        engine launches (one is in flight on the old ones meanwhile);
         the KV cache is content not weights, so it stays valid."""
         self.engine.params = params
         return True

@@ -65,9 +65,10 @@ def pushes(traced_cluster, monkeypatch):
     return sent
 
 
-def _run_engine(requests=((4, 8), (5, 8), (3, 8))):
+def _run_engine(requests=((4, 8), (5, 8), (3, 8)), later=()):
     """Tokens of a few concurrent requests through a tiny LLMEngine on
-    two slots, so that one request waits for a slot."""
+    two slots, so that one request waits for a slot; then those of
+    ``later``, sent after the engine has stood idle for 0.1 s."""
     import jax
 
     from ray_tpu.models import llama
@@ -82,9 +83,26 @@ def _run_engine(requests=((4, 8), (5, 8), (3, 8))):
             prompt = list(range(1, prompt_len + 1))
             return [t async for t in engine.stream(prompt, new)]
 
-        return await asyncio.gather(*(one(*r) for r in requests))
+        out = await asyncio.gather(*(one(*r) for r in requests))
+        if later:
+            await asyncio.sleep(0.1)
+            out += await asyncio.gather(*(one(*r) for r in later))
+        return out
 
     return asyncio.run(main())
+
+
+def _run_engine_traced(**kwargs):
+    """``_run_engine`` with the operator's switch on: (tokens, spans)."""
+    was = tracing._enabled
+    tracing.enable()
+    tracing.clear()
+    try:
+        out = _run_engine(**kwargs)
+    finally:
+        if not was:
+            tracing.disable()
+    return out, tracing.spans()
 
 
 def _histogram_count(name: str, tags_key: str = "[]") -> float:
@@ -192,7 +210,10 @@ class TestTracing:
             for _ in range(1000):
                 if tracing.enabled():
                     tracing.span("never")
-                with llm._span(tracing.enabled(), "never"):
+                # the engine asks once per decode step, then hands its
+                # span sites the step's span: None while tracing is off
+                life = tracing.root("never") if tracing.enabled() else None
+                with llm._part(life, "never"):
                     pass
             after = tracemalloc.take_snapshot()
         finally:
@@ -296,21 +317,18 @@ class TestTracing:
         ttft0 = _histogram_count("llm_engine_ttft_ms")
         wait0 = _histogram_count("llm_queue_wait_ms",
                                  '[["outcome", "admitted"]]')
-        was = tracing._enabled
-        tracing.enable()
-        tracing.clear()
-        try:
-            out = _run_engine()
-        finally:
-            if not was:
-                tracing.disable()
+        out, rows = _run_engine_traced()
         assert [len(o) for o in out] == [4, 5, 3]
-        rows = tracing.spans()
-        steps = [s for s in rows if s["name"] == "llm.step"]
+        # one llm.step is the life of one decode step; lives overlap and
+        # end out of order, so they are read in the order they began
+        steps = sorted((s for s in rows if s["name"] == "llm.step"),
+                       key=lambda s: s["start_ns"])
         assert steps and [s["attributes"]["step"] for s in steps] == sorted(
             s["attributes"]["step"] for s in steps)
         decoding = [s for s in steps if s["attributes"]["active"]]
-        assert decoding
+        assert [s["attributes"]["step"] for s in decoding] == list(
+            range(1, len(decoding) + 1))
+        launches, syncs = [], []
         for step in decoding:
             kids = sorted(
                 (s for s in rows if s["parent_id"] == step["span_id"]),
@@ -329,6 +347,23 @@ class TestTracing:
             assert kids[-1]["end_ns"] <= step["end_ns"]
             for a, b in zip(kids, kids[1:]):
                 assert a["end_ns"] <= b["start_ns"]
+            dispatch = kids[-4]
+            launch, = [s for s in rows if s["parent_id"] == dispatch["span_id"]]
+            assert launch["name"] == "llm.step.launch"
+            assert dispatch["start_ns"] <= launch["start_ns"]
+            assert launch["end_ns"] <= dispatch["end_ns"]
+            launches.append(launch)
+            syncs.append(kids[-3])
+        # launched ahead: step k's sync begins after step k+1's launch;
+        # else (the step after an admission) step k was delivered first
+        assert not decoding[0]["attributes"]["ahead"]
+        for k in range(len(decoding) - 1):
+            if decoding[k + 1]["attributes"]["ahead"]:
+                assert launches[k + 1]["end_ns"] <= syncs[k]["start_ns"]
+            else:
+                assert decoding[k + 1]["attributes"]["admitted"]
+                assert syncs[k]["end_ns"] <= launches[k + 1]["start_ns"]
+        assert sum(s["attributes"]["ahead"] for s in decoding) == len(decoding) - 2
         requests = [s for s in rows if s["name"] == "llm.request"]
         prefills = [s for s in rows if s["name"] == "llm.prefill"]
         assert len(requests) == len(prefills) == 3
@@ -343,6 +378,40 @@ class TestTracing:
         assert _histogram_count("llm_engine_ttft_ms") == ttft0 + 3
         assert _histogram_count(
             "llm_queue_wait_ms", '[["outcome", "admitted"]]') == wait0 + 3
+
+    def test_engine_spans_fit_the_benchmarks_reader(self):
+        """``chipbench/span_reduce.py`` pairs the k-th execution of the
+        decode program in a device trace with the k-th ``llm.step`` and
+        raises on the chip where they do not fit.  The engine's real
+        spans and a module line made for them — one execution a step,
+        begun after its launch (and after the one before it), done
+        before its sync returned — must align, to the few microseconds
+        the line was made with.  (The 0.1 s the engine stands idle is a
+        change of the step period that no pairing off by a step or two
+        survives, as a prefill's is on the chip.)"""
+        from chipbench import span_reduce
+
+        _, spans = _run_engine_traced(later=((6, 8), (4, 8)))
+        steps = span_reduce.steps_of(spans)
+        assert [s["span"]["attributes"]["step"] for s in steps] == list(
+            range(1, len(steps) + 1))
+        assert len(steps) == len(
+            [s for s in spans if s["name"] == "llm.step.launch"])
+        assert any(s["span"]["attributes"]["ahead"] for s in steps)
+        offset, slack = 1_700_000_000_000_000_000, 2_000  # ns
+        events, done = [], 0
+        for k, step in enumerate(steps):
+            start = max(step["launch_ns"] - offset + slack, done + slack)
+            done = step["parts"]["sync"]["end_ns"] - offset - slack
+            assert start + slack < done
+            events += [
+                [f"jit_decode_step_rowwise({k})", start, done - start - slack, {}],
+                ["jit__argmax(1)", done - slack // 2, slack // 2, {}],
+            ]
+        planes = [{"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": events}]}]
+        lo, hi = span_reduce.align(planes, spans)
+        assert lo <= offset <= hi and hi - lo <= 2 * slack
 
     def test_engine_records_nothing_when_off(self, tracing_off):
         ttft0 = _histogram_count("llm_engine_ttft_ms")
