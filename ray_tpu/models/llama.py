@@ -25,6 +25,20 @@ key vectors before they are split into heads.  ``forward`` / ``loss_fn``
 run the same layer, which is what the CPU comparison with the float32
 reference needs; there is no auxiliary load-balancing loss and no
 training cell for it (ROADMAP R1, training half).
+
+``kv_lora_rank`` > 0 makes the attention of every block GLM-5's
+(DeepSeek-V3.2's): multi-head LATENT attention, whose cache row is one
+normed latent and one rotary key shared by all heads, and a learned
+INDEXER beside it that scores every visible key and lets attention see
+the ``index_topk`` best of them (``_latent_attention``).  Such a config
+keeps two kinds of state per layer in the cache (``init_cache``), runs
+decode in the absorbed form over the gathered rows and prefill in the
+expanded form over query blocks, may lead with ``first_dense_layers``
+dense blocks before its expert blocks (two parameter stacks under the
+one block body), routes with DeepSeek-V3's sigmoid router beside a
+shared expert, and may hold only ``experts_held`` of the experts its
+router routes over (one chip's share of an expert-parallel layer).  It
+runs on the cached paths only: ``forward`` / ``loss_fn`` refuse it.
 """
 
 from __future__ import annotations
@@ -73,10 +87,54 @@ class LlamaConfig:
     expert_dim: int = 0
     # RMSNorm over the whole projected q and k vectors (before heads)
     qk_norm: bool = False
+    # latent attention (MLA, DeepSeek-V2/V3, GLM-5): kv_lora_rank > 0
+    # replaces wq/wk/wv by low-rank projections; the cache holds
+    # kv_lora_rank + qk_rope_head_dim numbers a token, for all heads
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the sparse-attention indexer beside it (DeepSeek-V3.2, GLM-5):
+    # index_n_heads heads of index_head_dim score every visible key and
+    # attention sees the index_topk best (all, while there are fewer)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # leading blocks with a dense SwiGLU of width mlp_dim before the
+    # expert blocks (DeepSeek's first_k_dense_replace); num_layers
+    # counts both
+    first_dense_layers: int = 0
+    # a SwiGLU of this width added for every token beside the routed
+    # experts (0: none)
+    shared_expert_dim: int = 0
+    # the router: "softmax" over all experts, the chosen probabilities
+    # as they are (OLMoE) | "sigmoid" scores, chosen by score + a
+    # selection-only bias, the chosen scores renormalised
+    # (router_norm_topk) and times router_scale (DeepSeek-V3, GLM-5)
+    router_scoring: str = "softmax"
+    router_norm_topk: bool = False
+    router_scale: float = 1.0
+    # one chip's share of an expert-parallel layer: the router keeps
+    # num_experts outputs, this program holds experts [expert_offset,
+    # expert_offset + experts_held) and computes their part only
+    # (0: all of them are held)
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.embed_dim // self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        """The attention kind: latent rows + indexer keys in the cache
+        (True) or K and V per KV head (False)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def q_per_kv(self) -> int:
@@ -111,82 +169,174 @@ class LlamaConfig:
         return LlamaConfig(**defaults)
 
 
+def _stacks(config: LlamaConfig):
+    """The parameter stacks of the one layer loop, in layer order:
+    ``[(name in the tree, layers, first layer's index, expert FFN?)]``.
+    One stack, ``blocks``, unless dense blocks lead the expert blocks."""
+    c = config
+    if not c.first_dense_layers:
+        return [("blocks", c.num_layers, 0, bool(c.num_experts))]
+    return [
+        ("dense_blocks", c.first_dense_layers, 0, False),
+        ("blocks", c.num_layers - c.first_dense_layers,
+         c.first_dense_layers, bool(c.num_experts)),
+    ]
+
+
 def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Per-parameter logical axis names (parallel/sharding.py specs)."""
-    blk = {
+    c = config
+    if c.latent:
+        attn = {
+            "w_qa": ("layers", "embed", None), "q_a_norm": ("layers", None),
+            "w_qb": ("layers", None, "heads", None),
+            "w_kva": ("layers", "embed", None), "kv_a_norm": ("layers", None),
+            "w_kb": ("layers", None, "heads", None),
+            "w_vb": ("layers", None, "heads", None),
+            "w_iq": ("layers", None, None, None),
+            "w_ik": ("layers", "embed", None),
+            "ik_norm": ("layers", None), "ik_bias": ("layers", None),
+            "w_iw": ("layers", "embed", None),
+        }
+    else:
+        attn = {
+            "wq": ("layers", "embed", "heads", None),
+            "wk": ("layers", "embed", "kv", None),
+            "wv": ("layers", "embed", "kv", None),
+        }
+        if c.qk_norm:
+            attn.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
+    dense = {
         "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads", None),
-        "wk": ("layers", "embed", "kv", None),
-        "wv": ("layers", "embed", "kv", None),
+        **attn,
         "wo": ("layers", "heads", None, "embed"),
         "mlp_norm": ("layers", "embed"),
         "w_gate": ("layers", "embed", "mlp"),
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
-    if config.num_experts:
-        blk.update({
-            "w_router": ("layers", "embed", None),
-            "w_gate": ("layers", "expert", "embed", "mlp"),
-            "w_up": ("layers", "expert", "embed", "mlp"),
-            "w_down": ("layers", "expert", "mlp", "embed"),
+    expert = dict(dense, **{
+        "w_router": ("layers", "embed", None),
+        "w_gate": ("layers", "expert", "embed", "mlp"),
+        "w_up": ("layers", "expert", "embed", "mlp"),
+        "w_down": ("layers", "expert", "mlp", "embed"),
+    })
+    if c.router_scoring == "sigmoid":
+        expert["router_bias"] = ("layers", None)
+    if c.shared_expert_dim:
+        expert.update({
+            "ws_gate": ("layers", "embed", "mlp"),
+            "ws_up": ("layers", "embed", "mlp"),
+            "ws_down": ("layers", "mlp", "embed"),
         })
-    if config.qk_norm:
-        blk.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
-    out = {
-        "tok_embed": ("vocab", "embed"),
-        "blocks": blk,
-        "final_norm": ("embed",),
-    }
-    if not config.tie_embeddings:
+    out = {"tok_embed": ("vocab", "embed"), "final_norm": ("embed",)}
+    for name, _n, _first, experts in _stacks(c):
+        out[name] = expert if experts else dense
+    if not c.tie_embeddings:
         out["lm_head"] = ("vocab", "embed")
     return out
+
+
+def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool) -> Params:
+    """One stack of ``layers`` blocks: a leading layer axis on every
+    leaf.  ``experts``: the feed-forward is the expert layer (router,
+    ``experts_here`` SwiGLUs of width ``expert_dim``, the shared expert
+    if any), else one SwiGLU of width ``mlp_dim``."""
+    c = config
+    dt = c.param_dtype
+    L, E, H, KV, D = layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim
+    X = (c.experts_here,) if experts else ()
+    M = c.expert_dim if experts else c.mlp_dim
+    k = jax.random.split(rng, 8)
+    std = 0.02
+    resid_std = std / math.sqrt(2 * c.num_layers)
+
+    def norm(key, shape, s):
+        return (jax.random.normal(key, shape, jnp.float32) * s).astype(dt)
+
+    def more(i):  # keys for tensors newer than the eight above
+        return jax.random.fold_in(k[1], i)
+
+    if c.latent:
+        Q, C = c.q_lora_rank, c.kv_lora_rank
+        Dn, Dr, Dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        J, Di = c.index_n_heads, c.index_head_dim
+        blk = {
+            "w_qa": norm(k[1], (L, E, Q), std),
+            "q_a_norm": jnp.ones((L, Q), dt),
+            "w_qb": norm(more(1), (L, Q, H, Dn + Dr), std),
+            "w_kva": norm(k[2], (L, E, C + Dr), std),
+            "kv_a_norm": jnp.ones((L, C), dt),
+            "w_kb": norm(more(2), (L, C, H, Dn), std),
+            "w_vb": norm(k[3], (L, C, H, Dv), std),
+            "wo": norm(k[4], (L, H, Dv, E), resid_std),
+            "w_iq": norm(more(3), (L, Q, J, Di), std),
+            "w_ik": norm(more(4), (L, E, Di), std),
+            "ik_norm": jnp.ones((L, Di), dt),
+            "ik_bias": jnp.zeros((L, Di), dt),
+            "w_iw": norm(more(5), (L, E, J), std),
+        }
+    else:
+        blk = {
+            "wq": norm(k[1], (L, E, H, D), std),
+            "wk": norm(k[2], (L, E, KV, D), std),
+            "wv": norm(k[3], (L, E, KV, D), std),
+            "wo": norm(k[4], (L, H, D, E), resid_std),
+        }
+        if c.qk_norm:
+            blk.update({
+                "q_norm": jnp.ones((L, H * D), dt),
+                "k_norm": jnp.ones((L, KV * D), dt),
+            })
+    blk.update({
+        "attn_norm": jnp.ones((L, E), dt),
+        "mlp_norm": jnp.ones((L, E), dt),
+        "w_gate": norm(k[5], (L, *X, E, M), std),
+        "w_up": norm(k[6], (L, *X, E, M), std),
+        "w_down": norm(k[7], (L, *X, M, E), resid_std),
+    })
+    if experts:
+        blk["w_router"] = norm(
+            jax.random.fold_in(k[5], 1), (L, E, c.num_experts), std
+        )
+        if c.router_scoring == "sigmoid":
+            # selection-only: it moves which experts are chosen, never
+            # their weights.  A hundredth of a sigmoid's range, the size
+            # of the gaps between the best scores: at a tenth the bias
+            # alone chose the experts and one took 5-10x the mean load
+            # (my chip run, PR 30)
+            blk["router_bias"] = norm(more(6), (L, c.num_experts), 0.01)
+        if c.shared_expert_dim:
+            Ms = c.shared_expert_dim
+            blk.update({
+                "ws_gate": norm(more(7), (L, E, Ms), std),
+                "ws_up": norm(more(8), (L, E, Ms), std),
+                "ws_down": norm(more(9), (L, Ms, E), resid_std),
+            })
+    return blk
 
 
 def init(rng, config: LlamaConfig) -> Params:
     c = config
     dt = c.param_dtype
-    L, E, H, KV, D = (
-        c.num_layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim,
-    )
-    # dense: one SwiGLU of width mlp_dim; experts: the same three
-    # matrices behind a leading expert axis, of width expert_dim
-    X = (c.num_experts,) if c.num_experts else ()
-    M = c.expert_dim if c.num_experts else c.mlp_dim
-    k = jax.random.split(rng, 8)
     std = 0.02
-    resid_std = std / math.sqrt(2 * L)
+    k0 = jax.random.split(rng, 8)[0]
 
     def norm(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(dt)
 
     params: Params = {
-        "tok_embed": norm(k[0], (c.vocab_size, E), std),
-        "blocks": {
-            "attn_norm": jnp.ones((L, E), dt),
-            "wq": norm(k[1], (L, E, H, D), std),
-            "wk": norm(k[2], (L, E, KV, D), std),
-            "wv": norm(k[3], (L, E, KV, D), std),
-            "wo": norm(k[4], (L, H, D, E), resid_std),
-            "mlp_norm": jnp.ones((L, E), dt),
-            "w_gate": norm(k[5], (L, *X, E, M), std),
-            "w_up": norm(k[6], (L, *X, E, M), std),
-            "w_down": norm(k[7], (L, *X, M, E), resid_std),
-        },
-        "final_norm": jnp.ones((E,), dt),
+        "tok_embed": norm(k0, (c.vocab_size, c.embed_dim), std),
+        "final_norm": jnp.ones((c.embed_dim,), dt),
     }
-    if c.num_experts:
-        params["blocks"]["w_router"] = norm(
-            jax.random.fold_in(k[5], 1), (L, E, c.num_experts), std
-        )
-    if c.qk_norm:
-        params["blocks"].update({
-            "q_norm": jnp.ones((L, H * D), dt),
-            "k_norm": jnp.ones((L, KV * D), dt),
-        })
+    for name, layers, first, experts in _stacks(c):
+        # the one stack of a config without leading dense blocks draws
+        # from ``rng`` itself, as it always did
+        key = jax.random.fold_in(rng, first) if first else rng
+        params[name] = _init_blocks(key, c, layers, experts)
     if not c.tie_embeddings:
         params["lm_head"] = norm(
-            jax.random.fold_in(k[0], 1), (c.vocab_size, E), std
+            jax.random.fold_in(k0, 1), (c.vocab_size, c.embed_dim), std
         )
     return params
 
@@ -233,13 +383,14 @@ _EXPERT_TENSORS = ("w_gate", "w_up", "w_down")
 
 
 def _layer_params(blocks: Params, config: LlamaConfig):
-    """``(xs, whole)`` for a layer loop: ``xs`` is what it scans over
-    (every stacked leaf, and the layer's index), ``whole`` what its body
-    adds unsliced to the layer's parameters: an expert config's three
-    expert tensors in the compute dtype (see ``_ffn``; cast here, once
-    a forward and not once a layer), nothing for a dense config."""
-    layers = jnp.arange(config.num_layers)
-    if not config.num_experts:
+    """``(xs, whole)`` for a layer loop over one stack: ``xs`` is what
+    it scans over (every stacked leaf, and the layer's index in the
+    stack), ``whole`` what its body adds unsliced to the layer's
+    parameters: an expert stack's three expert tensors in the compute
+    dtype (see ``_ffn``; cast here, once a forward and not once a
+    layer), nothing for a dense stack."""
+    layers = jnp.arange(blocks["attn_norm"].shape[0])
+    if "w_router" not in blocks:
         return (blocks, layers), {}
     whole = {k: blocks[k].astype(config.dtype) for k in _EXPERT_TENSORS}
     rest = {k: v for k, v in blocks.items() if k not in whole}
@@ -267,49 +418,89 @@ def _qkv(h, p, positions, config: LlamaConfig):
     return q, kk, vv
 
 
+def _swiglu(h, w_gate, w_up, w_down, config: LlamaConfig):
+    c = config
+    gate = jnp.einsum("bse,em->bsm", h, w_gate.astype(c.dtype))
+    up = jnp.einsum("bse,em->bsm", h, w_up.astype(c.dtype))
+    act = constrain(jax.nn.silu(gate) * up, ("batch", "seq", "mlp"))
+    return jnp.einsum("bsm,me->bse", act, w_down.astype(c.dtype))
+
+
+def _route(x, p, config: LlamaConfig):
+    """Router of the expert layer.  x: (N, E).  Returns ``(weight (N,
+    k) float32, expert (N, k) int32)`` in order of falling selection
+    score.  ``softmax``: probabilities over ALL experts in float32,
+    ``lax.top_k`` of them, the chosen probabilities as they are.
+    ``sigmoid`` (DeepSeek-V3's ``noaux_tc`` with one group): scores
+    ``sigmoid(logits)`` in float32; the top k of score + ``router_bias``
+    are chosen, the bias is in the choice only; the weights are the
+    chosen SCORES, divided by their sum if ``router_norm_topk``, times
+    ``router_scale``."""
+    c = config
+    logits = jnp.einsum(
+        "ne,ex->nx", x, p["w_router"].astype(c.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    if c.router_scoring == "softmax":
+        return lax.top_k(jax.nn.softmax(logits, axis=-1), c.experts_per_token)
+    score = jax.nn.sigmoid(logits)
+    _, expert = lax.top_k(
+        score + p["router_bias"].astype(jnp.float32), c.experts_per_token
+    )
+    weight = jnp.take_along_axis(score, expert, axis=-1)
+    if c.router_norm_topk:
+        weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+    return weight * c.router_scale, expert
+
+
 def _ffn(h, p, config: LlamaConfig):
     """The ONE feed-forward body.  h: (B, S, E), already normed.
     Returns ``(y, routing)``: y (B, S, E) to add to the residual, and
-    for an expert config ``{"rows": (num_experts,) int32 rows each
+    for an expert block ``{"rows": (experts held,) int32 rows each
     expert computed in this call, "experts": (B, S, k) the experts each
-    token chose}`` (None for a dense config).
+    token chose}`` (None for a dense block: one without ``w_router``).
 
     Dense: SwiGLU, ``w_down(silu(w_gate h) * w_up h)``.
 
-    Experts (``num_experts`` > 0): router logits ``h @ w_router`` and a
-    softmax over ALL experts in float32; ``lax.top_k`` picks
-    ``experts_per_token`` of them and their probabilities are the
-    weights as they are (not renormalised: OLMoE's ``norm_topk_prob``
-    false).  The B*S*k (token, choice) rows are sorted by expert, the
-    three matrices are applied as grouped matmuls over the sorted rows,
-    and the results are put back in token order, weighted and summed
-    over the k choices.  Every routed pair is computed: no capacity, no
-    dropped token, no dense (rows, experts, width) intermediate.
+    Experts: ``_route`` picks ``experts_per_token`` experts and their
+    weights for every token.  The B*S*k (token, choice) rows are sorted
+    by expert, the three matrices are applied as grouped matmuls over
+    the sorted rows, and the results are put back in token order,
+    weighted and summed over the k choices.  Every routed pair is
+    computed: no capacity, no dropped token, no dense (rows, experts,
+    width) intermediate.  ``shared_expert_dim`` adds one more SwiGLU of
+    every token, unweighted.
 
-    The three expert tensors arrive STACKED over the layers, (L, X, ..),
-    with ``p["layer"]`` saying which layer this is (``_layer_params``):
-    a layer loop that handed the kernel one layer's slice would copy
-    that slice first (805 MB a layer at OLMoE's widths), so the kernel
-    gets all L * X matrices as its groups and sizes that are zero
-    outside this layer's X."""
+    ``experts_held``: this program holds experts [``expert_offset``,
+    ``expert_offset + experts_held``) of the ``num_experts`` the router
+    routes over.  Rows that chose another expert sort behind the held
+    groups, belong to no group — the grouped matmul computes nothing
+    for them — and count as zero: y is this chip's part of the layer
+    (its experts' terms and the shared expert), and a token whose k
+    experts all live elsewhere gets the shared expert alone.
+
+    The three expert tensors arrive STACKED over the stack's layers,
+    (L, X, ..), with ``p["layer"]`` saying which layer this is
+    (``_layer_params``): a layer loop that handed the kernel one
+    layer's slice would copy that slice first (805 MB a layer at
+    OLMoE's widths), so the kernel gets all L * X matrices as its
+    groups and sizes that are zero outside this layer's X."""
     c = config
-    if not c.num_experts:
-        gate = jnp.einsum("bse,em->bsm", h, p["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bse,em->bsm", h, p["w_up"].astype(c.dtype))
-        act = constrain(jax.nn.silu(gate) * up, ("batch", "seq", "mlp"))
-        return jnp.einsum("bsm,me->bse", act, p["w_down"].astype(c.dtype)), None
+    if "w_router" not in p:
+        return _swiglu(h, p["w_gate"], p["w_up"], p["w_down"], c), None
     from ray_tpu.ops.grouped_matmul import grouped_matmul
 
     B, S, E = h.shape
-    X, K = c.num_experts, c.experts_per_token
+    X, K = c.experts_here, c.experts_per_token
     x = h.reshape(B * S, E)
     with jax.named_scope("moe_route"):
-        logits = jnp.einsum(
-            "ne,ex->nx", x, p["w_router"].astype(c.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        weight, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        weight, expert = _route(x, p, c)
         flat = expert.reshape(-1)                      # (N*K,) row -> expert
+        if c.experts_held:
+            flat = flat - c.expert_offset
+            here = (flat >= 0) & (flat < X)            # (N*K,) computed here?
+            flat = jnp.where(here, flat, X)            # the others sort last
+            weight = jnp.where(here.reshape(-1, K), weight, 0.0)
         order = jnp.argsort(flat, stable=True)         # sorted row -> row
         rows = (flat[:, None] == jnp.arange(X)[None, :]).sum(
             0, dtype=jnp.int32
@@ -329,10 +520,14 @@ def _ffn(h, p, config: LlamaConfig):
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)                      # row -> sorted row
         y = ys[back].reshape(B * S, K, E).astype(jnp.float32)
+        if c.experts_held:  # rows of no group come back undefined
+            y = jnp.where(here.reshape(-1, K, 1), y, 0.0)
         y = (y * weight[:, :, None]).sum(1).astype(c.dtype)
-    return y.reshape(B, S, E), {
-        "rows": rows, "experts": expert.reshape(B, S, K),
-    }
+    y = y.reshape(B, S, E)
+    if c.shared_expert_dim:
+        with jax.named_scope("moe_shared"):
+            y = y + _swiglu(h, p["ws_gate"], p["ws_up"], p["ws_down"], c)
+    return y, {"rows": rows, "experts": expert.reshape(B, S, K)}
 
 
 def _block(x, p, positions, config: LlamaConfig):
@@ -356,6 +551,11 @@ def _block(x, p, positions, config: LlamaConfig):
 
 def _features_and_choices(params: Params, tokens, config: LlamaConfig):
     c = config
+    if c.latent or c.first_dense_layers:
+        raise NotImplementedError(
+            "a latent-attention or dense-leading config runs on the cached "
+            "paths only (prefill_into_slot / decode_step_rowwise)"
+        )
     B, S = tokens.shape
     emb = constrain(params["tok_embed"], (None, None)).astype(c.dtype)
     x = emb[tokens]
@@ -440,13 +640,26 @@ def num_params(config: LlamaConfig) -> int:
 
 
 def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float:
-    """fwd+bwd FLOPs per token: 6N + attention quadratic term."""
+    """fwd+bwd FLOPs per token: 6N + the attention term.  N counts what
+    ``init`` makes (the experts HELD here, the shared expert, the latent
+    projections and the indexer) less the embedding.  K/V attention:
+    scores and mix over the whole context.  Latent attention: scores
+    (head size nope + rope) and mix (``v_head_dim``) over the
+    ``index_topk`` keys a query may see, and the indexer's ``index_n_heads
+    x index_head_dim`` over the whole context."""
     c = config
     S = seq_len or c.max_seq_len
     n = num_params(c) - c.vocab_size * c.embed_dim * (
         0 if c.tie_embeddings else 1
     )
-    attn = 12 * c.num_layers * c.embed_dim * S  # 2*2*3 * L * E * S
+    if c.latent:
+        seen = min(S, c.index_topk)
+        per_key = c.num_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim + c.v_head_dim)
+        attn = 6 * c.num_layers * (
+            per_key * seen + c.index_n_heads * c.index_head_dim * S
+        )
+    else:
+        attn = 12 * c.num_layers * c.embed_dim * S  # 2*2*3 * L * E * S
     return 6.0 * n + attn
 
 
@@ -516,39 +729,101 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     Positions older than the window are overwritten in place; the
     attention mask reconstructs each slot's position implicitly.
 
+    A latent config (``kv_lora_rank`` > 0) keeps TWO KINDS OF STATE per
+    layer instead of K and V, written together and read differently:
+    ``ckv`` (L, B, max_len, kv_lora_rank + qk_rope_head_dim, rounded up
+    to whole 128-lane tiles: 576 -> 640, zeros behind), the normed
+    latent and the one rotary key all heads share, of which a decode
+    step gathers only the rows the indexer chose; and ``ik`` (L, B,
+    max_len, index_head_dim), the indexer's keys, of which it reads
+    every row up to ``pos``.  Beside them ``dsa_keys`` (L, 2, 2, 2)
+    int32: keys visible / keys selected, summed over every (row, query)
+    of every call, for runs (prefills) / single-token steps apart, each
+    as two words (millions, rest: ``_add_wide``) because 32 rows at 10k
+    keys are 2 M a step and int32 would last 1,000 steps.
+
     An expert config adds int32 running totals that ride the donated
     cache like K and V, so no step pays a device-to-host copy for them
     (``serve/llm.py`` reads them in ``stats()``): ``moe_expert_tokens``
-    (L, X) rows each expert of each layer computed, ``moe_experts_touched``
-    (L,) experts with at least one row, summed over the calls, and
-    ``moe_layer_steps`` (L,) calls.  They count what the kernel did:
-    every row of a decode step routes, also the rows the engine treats
-    as inactive."""
+    (expert layers, experts held) rows each expert of each layer
+    computed, ``moe_experts_touched`` (expert layers,) experts with at
+    least one row, summed over the calls, and ``moe_layer_steps``
+    calls.  They count what the kernel did: every row of a decode step
+    routes, also the rows the engine treats as inactive."""
     c = config
-    shape = (c.num_layers, batch_size, max_len, c.num_kv_heads, c.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, c.dtype),
-        "v": jnp.zeros(shape, c.dtype),
-    }
+    if c.latent:
+        lead = (c.num_layers, batch_size, max_len)
+        cache = {
+            "ckv": jnp.zeros((*lead, _latent_row(c)), c.dtype),
+            "ik": jnp.zeros((*lead, c.index_head_dim), c.dtype),
+            "dsa_keys": jnp.zeros((c.num_layers, 2, 2, 2), jnp.int32),
+        }
+    else:
+        shape = (c.num_layers, batch_size, max_len, c.num_kv_heads, c.head_dim)
+        cache = {
+            "k": jnp.zeros(shape, c.dtype),
+            "v": jnp.zeros(shape, c.dtype),
+        }
     if c.num_experts:
+        layers = c.num_layers - c.first_dense_layers
         cache["moe_expert_tokens"] = jnp.zeros(
-            (c.num_layers, c.num_experts), jnp.int32
+            (layers, c.experts_here), jnp.int32
         )
-        cache["moe_experts_touched"] = jnp.zeros((c.num_layers,), jnp.int32)
-        cache["moe_layer_steps"] = jnp.zeros((c.num_layers,), jnp.int32)
+        cache["moe_experts_touched"] = jnp.zeros((layers,), jnp.int32)
+        cache["moe_layer_steps"] = jnp.zeros((layers,), jnp.int32)
     return cache
 
 
-def _with_expert_counts(cache: Params, k, v, expert_rows) -> Params:
-    """The cache after one call: new K/V and, for an expert config, the
-    running totals plus this call's (L, X) rows per expert."""
-    out = dict(cache, k=k, v=v)
-    if expert_rows is not None:
-        out["moe_expert_tokens"] = cache["moe_expert_tokens"] + expert_rows
+def _latent_row(config: LlamaConfig) -> int:
+    """Width of a latent cache row: the latent and the rotary key, in
+    whole 128-lane tiles.  At a width that is no multiple of 128 the
+    TPU's default layout puts the POSITIONS minor-most, and every step
+    copies the whole cache into a row-major layout and back (2 x 2.3 GB
+    at GLM-5's 576; compile-only, PR 30); row-major it would be padded
+    to the same 640 anyway."""
+    return -(-(config.kv_lora_rank + config.qk_rope_head_dim) // 128) * 128
+
+
+#: the cache's entries that hold tokens' state (the rest are counters)
+_STATE = ("k", "v", "ckv", "ik")
+_WIDE = 1 << 20
+
+
+def _add_wide(total, amount):
+    """``total`` (..., 2) int32 = (amount // 2**20, amount % 2**20) of a
+    count too large for one int32, plus ``amount`` (...,) int32 >= 0."""
+    low = total[..., 1] + amount % _WIDE
+    high = total[..., 0] + amount // _WIDE + low // _WIDE
+    return jnp.stack([high, low % _WIDE], axis=-1)
+
+
+def wide_total(total) -> int:
+    """A ``_add_wide`` total, summed over its leading axes, as an int
+    (host side: numpy in, Python int out)."""
+    import numpy as np
+
+    t = np.asarray(total).astype(np.int64).reshape(-1, 2)
+    return int(t[:, 0].sum()) * _WIDE + int(t[:, 1].sum())
+
+
+def _with_counts(cache: Params, state: Params, aux: Params, step: bool) -> Params:
+    """The cache after one call: the new state and the running totals
+    plus this call's ``aux`` (the layer loop's stacked outputs): an
+    expert config's (expert layers, experts held) rows per expert, a
+    latent config's (L, 2) keys visible and selected."""
+    out = dict(cache, **state)
+    if "expert_rows" in aux:
+        rows = aux["expert_rows"]
+        out["moe_expert_tokens"] = cache["moe_expert_tokens"] + rows
         out["moe_experts_touched"] = cache["moe_experts_touched"] + (
-            expert_rows > 0
+            rows > 0
         ).sum(-1, dtype=jnp.int32)
         out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
+    if "dsa_keys" in aux:
+        kind = int(step)  # runs at [:, :, 0], single-token steps at [:, :, 1]
+        out["dsa_keys"] = cache["dsa_keys"].at[:, :, kind].set(
+            _add_wide(cache["dsa_keys"][:, :, kind], aux["dsa_keys"])
+        )
     return out
 
 
@@ -645,32 +920,334 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
     return write(cache, *lead), write(slab, rows)
 
 
-def _block_step(x, p, cache_k, cache_v, slot, positions, config: LlamaConfig):
-    """Block ``p["layer"]`` over each row's run of new tokens.  x: (R,
-    Sq, E); positions: (R, Sq) absolute position of every new token;
-    cache_k/v: the WHOLE (L, B, T, KV, D) cache, of which only the new
-    tokens' entries are written; slot: the one cache row addressed, or
-    None for all B.  Returns (x, cache_k, cache_v, expert_rows)."""
+def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
+    """Attention over a K/V cache: projections, the new tokens' K/V
+    written, grouped attention over the rows' slabs.  Returns (the
+    heads' outputs (R, Sq, H, D), state, no counters)."""
+    c = config
+    q, kk, vv = _qkv(h, p, positions, c)
+    cache_k, slab_k = _write_and_read(
+        state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c
+    )
+    cache_v, slab_v = _write_and_read(
+        state["v"], vv.astype(c.dtype), p["cache_layer"], slot, positions, c
+    )
+    mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window)
+    attn = _grouped_attention(q, slab_k, slab_v, mask, c)
+    return attn, {"k": cache_k, "v": cache_v}, {}
+
+
+def _rope_pairs(x, positions, theta):
+    """Rotary embedding over INTERLEAVED pairs of the last dim: (x[2i],
+    x[2i+1]) turns by ``position * theta**(-2i/D)`` (DeepSeek's and
+    GLM-5's ``rope_interleave``; ``_rope`` pairs x[i] with x[i + D/2]).
+    x: (B, S, H, D)."""
+    half = x.shape[-1] // 2
+    freqs = jnp.exp(
+        -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
+    )
+    angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return rotated.reshape(x.shape).astype(x.dtype)
+
+
+def _layernorm(x, scale, bias, eps):
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - x32.mean(-1, keepdims=True)
+    y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (
+        y * scale.astype(jnp.float32) + bias.astype(jnp.float32)
+    ).astype(x.dtype)
+
+
+#: eps of the indexer's key LayerNorm (torch's default; ``assumed``)
+_INDEX_NORM_EPS = 1e-6
+#: queries per block of a latent prefill's attention, at most, and the
+#: (heads, block, keys) float32 scores that may be live at once: 2**25
+#: = 128 MiB, which is 128 queries at 64 heads x 4,096 keys and 64 at
+#: 8,192.  At 256 MiB the chip's compiler gives the softmax fusion a
+#: schedule it prices at 2**63 cycles, and an 8,192-token prefill took
+#: 10.1 s where 4,096 took 0.44 (my chip run, PR 30)
+_QUERY_BLOCK = 128
+_SCORE_ELEMENTS = 1 << 25
+#: runs of queries a prefill of a whole multiple is cut into, each
+#: attending only to the keys up to its own end
+_CAUSAL_GROUPS = 4
+
+
+def _index_scores(qi, wi, ki):
+    """The indexer's score of every (query, key) pair: ``sum_j w_j *
+    relu(q_j . k)``.  qi: (..., Q, J, Di), wi: (..., Q, J), ki: (..., T,
+    Di) -> (..., Q, T) float32."""
+    dots = jnp.einsum(
+        "...qjd,...td->...qjt", qi, ki, preferred_element_type=jnp.float32
+    )
+    return jnp.einsum("...qjt,...qj->...qt", jax.nn.relu(dots), wi)
+
+
+def _select_mask(scores, k: int):
+    """(Q, T) bool: for each query the ``k`` keys of largest score,
+    EXACTLY k of them where more than k are visible (ties at the k-th
+    value go to the lower index, as ``lax.top_k`` orders them), every
+    visible key where at most k are.  ``scores`` (Q, T) float32 holds
+    -inf at the keys a query may not see."""
+    visible = scores > -jnp.inf
+    if scores.shape[-1] <= k:
+        return visible
+    kth = lax.top_k(scores, k)[0][:, -1:]
+    above = scores > kth
+    tied = (scores == kth) & visible
+    need = k - above.sum(-1, keepdims=True, dtype=jnp.int32)
+    return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need))
+
+
+def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
+                      collect: bool = False):
+    """GLM-5's attention (DeepSeek-V3.2's): multi-head latent attention
+    over the keys a learned indexer selects.  h: (R, Sq, E) normed.
+
+    Per token: ``c_q = RMSNorm(h W_qa)``; ``q = c_q W_qb`` -> H heads of
+    [nope | rope], the rope part rotated; ``[c_kv | k_rope] = h W_kva``,
+    ``c_kv`` RMS-normed, ``k_rope`` rotated, ONE for all heads.  The
+    cache row (``ckv``) is ``[c_kv | k_rope]``.  Head h's key is ``[W_kb,h
+    c_kv | k_rope]`` and its value ``W_vb,h c_kv``; scores over head
+    size nope + rope.  Indexer: ``q^I = c_q W_iq`` (J heads of Di),
+    ``k^I = LayerNorm(h W_ik)`` (cached in ``ik``), the first
+    ``qk_rope_head_dim`` of both rotated, ``w = h W_iw``; ``I(t, s) =
+    sum_j w_j relu(q^I_j . k^I_s)``; a query attends to the
+    ``index_topk`` visible keys of largest I, exactly (all of them
+    while there are fewer).
+
+    One token for every row (decode): the rows' new state is written,
+    the indexer reads the layer's whole ``ik`` slab, ``lax.top_k``
+    picks ``index_topk`` rows, ONLY THOSE rows of ``ckv`` are gathered,
+    and attention runs in the absorbed form on them — ``W_kb`` carried
+    into the query, ``W_vb`` applied to the weighted sum of latents:
+    keys and values are never expanded over the cache.
+
+    A run (prefill; one row, from position 0: the run's own tokens are
+    all the keys there are): K and V are expanded from the run's latents
+    once, and attention runs in blocks of ``_QUERY_BLOCK`` queries, each
+    block's selection a mask over the run's keys, so nothing of size
+    heads x Sq x Sq is live.
+
+    Returns (the heads' outputs (R, Sq, H, v_head_dim), state,
+    {"dsa_keys": (2,) int32 keys visible and selected over all queries}
+    — with ``collect`` also ``"selected"``: (R, Sq, T') bool, the keys
+    each query attended to (T' = the cache's length for a step, Sq for
+    a run))."""
+    c = config
+    R, Sq, _ = h.shape
+    C, Dn, Dr = c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim
+    K = c.index_topk
+    layer = p["cache_layer"]
+    scale = 1.0 / math.sqrt(Dn + Dr)
+    dt = c.dtype
+    with jax.named_scope("mla_proj"):
+        c_q = _rmsnorm(
+            jnp.einsum("rse,eq->rsq", h, p["w_qa"].astype(dt)),
+            p["q_a_norm"], c.rms_eps,
+        )
+        q = jnp.einsum("rsq,qhd->rshd", c_q, p["w_qb"].astype(dt))
+        q_nope = q[..., :Dn]
+        q_rope = _rope_pairs(q[..., Dn:], positions, c.rope_theta)
+        kv = jnp.einsum("rse,ec->rsc", h, p["w_kva"].astype(dt))
+        c_kv = _rmsnorm(kv[..., :C], p["kv_a_norm"], c.rms_eps)
+        k_rope = _rope_pairs(kv[:, :, None, C:], positions, c.rope_theta)[:, :, 0]
+        fill = jnp.zeros((R, Sq, _latent_row(c) - C - Dr), dt)
+        new_ckv = jnp.concatenate([c_kv.astype(dt), k_rope.astype(dt), fill], axis=-1)
+    with jax.named_scope("dsa_index"):
+        def turned(x):  # the first Dr of the last dim rotated, (R, Sq, J, Di)
+            return jnp.concatenate(
+                [_rope_pairs(x[..., :Dr], positions, c.rope_theta), x[..., Dr:]],
+                axis=-1,
+            )
+
+        qi = turned(jnp.einsum("rsq,qjd->rsjd", c_q, p["w_iq"].astype(dt)))
+        ki = _layernorm(
+            jnp.einsum("rse,ed->rsd", h, p["w_ik"].astype(dt)),
+            p["ik_norm"], p["ik_bias"], _INDEX_NORM_EPS,
+        )
+        ki = turned(ki[:, :, None])[:, :, 0].astype(dt)
+        wi = jnp.einsum(
+            "rse,ej->rsj", h, p["w_iw"].astype(dt),
+            preferred_element_type=jnp.float32,
+        )
+    aux = {}
+
+    if slot is None and Sq == 1:  # ---- one token for every row
+        ckv, ik = state["ckv"], state["ik"]
+        T = ckv.shape[2]
+        rows, pos = jnp.arange(R), positions[:, 0]
+        with jax.named_scope("dsa_index"):
+            ckv = ckv.at[layer, rows, pos].set(new_ckv[:, 0])
+            ik = ik.at[layer, rows, pos].set(ki[:, 0])
+            slab = lax.dynamic_index_in_dim(ik, layer, 0, keepdims=False)
+            visible = jnp.arange(T)[None, :] <= pos[:, None]        # (R, T)
+            scores = jnp.where(
+                visible, _index_scores(qi, wi, slab)[:, 0], -jnp.inf
+            )
+        with jax.named_scope("dsa_select"):
+            _, chosen = lax.top_k(scores, min(K, T))                # (R, K)
+            valid = chosen <= pos[:, None]
+            picked = ckv[layer, rows[:, None], chosen]              # (R, K, row)
+        with jax.named_scope("mla_attn"):
+            q_lat = jnp.einsum("rhn,chn->rhc", q_nope[:, 0], p["w_kb"].astype(dt))
+            qq = jnp.concatenate(
+                [q_lat, q_rope[:, 0], fill[:, :1].repeat(c.num_heads, 1)], axis=-1
+            )                                                       # (R, H, row)
+            att = jnp.einsum(
+                "rhc,rkc->rhk", qq, picked, preferred_element_type=jnp.float32
+            ) * scale
+            att = jnp.where(valid[:, None, :], att, -1e30)
+            probs = jax.nn.softmax(att, axis=-1).astype(dt)
+            mix = jnp.einsum("rhk,rkc->rhc", probs, picked[..., :C])
+            out = jnp.einsum("rhc,chv->rhv", mix, p["w_vb"].astype(dt))[:, None]
+        aux["dsa_keys"] = jnp.stack([
+            visible.sum(dtype=jnp.int32), valid.sum(dtype=jnp.int32)
+        ])
+        if collect:
+            hit = jnp.zeros((R, T), bool).at[rows[:, None], chosen].set(valid)
+            aux["selected"] = hit[:, None, :]
+        return out, {"ckv": ckv, "ik": ik}, aux
+
+    # ---- a run of one row, from position 0
+    if slot is None or R != 1:
+        raise NotImplementedError(
+            "a latent config prefills whole prompts, one row at a time "
+            "(prefill_into_slot); chunks and batched runs are not written"
+        )
+    # the run's state is not written here: no layer of a run reads the
+    # cache, so ``_cached_step`` writes all layers' rows at once after
+    # the loop.  Written here, layer by layer, XLA carries the cache
+    # through the loop with the POSITIONS minor-most (the layout the
+    # projections' outputs have) and copies it whole, in and out, every
+    # call (3 GB; compile-only, PR 30).
+    aux["ckv_rows"], aux["ik_rows"] = new_ckv[0], ki[0]
+    with jax.named_scope("mla_proj"):
+        lat = new_ckv[0, :, :C]
+        k_nope = jnp.einsum("sc,chn->shn", lat, p["w_kb"].astype(dt))
+        keys = jnp.concatenate([
+            k_nope,
+            jnp.broadcast_to(new_ckv[0, :, None, C:C + Dr], (Sq, c.num_heads, Dr)),
+        ], axis=-1)                                                 # (Sq, H, Dn+Dr)
+        values = jnp.einsum("sc,chv->shv", lat, p["w_vb"].astype(dt))
+        qq = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)       # (Sq, H, Dn+Dr)
+    H = c.num_heads
+    blk = max(8, min(_QUERY_BLOCK, Sq, _SCORE_ELEMENTS // (H * Sq)))
+    groups = _CAUSAL_GROUPS if Sq % (_CAUSAL_GROUPS * blk) == 0 else 1
+    per = Sq // groups
+
+    def attend(lo, n_keys):
+        """Queries [lo, lo + per) over keys [0, n_keys), in blocks."""
+        pad = -per % blk  # only a run that is one group has one
+
+        def blocked(a):  # (per, ...) -> (blocks, blk, ...); the pad attends, is dropped
+            a = jnp.pad(a[lo:lo + per], ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            return a.reshape(-1, blk, *a.shape[1:])
+
+        real = blocked(jnp.ones((Sq,), bool))
+        # the pad's queries see every key
+        when = jnp.where(real, blocked(positions[0]), n_keys)
+        kb, vb, kib = keys[:n_keys], values[:n_keys], ki[0, :n_keys]
+
+        def block(args):
+            qb, qib, wib, tb, rb = args
+            with jax.named_scope("dsa_index"):
+                seen = jnp.arange(n_keys)[None, :] <= tb[:, None]   # (blk, keys)
+                scores = jnp.where(seen, _index_scores(qib, wib, kib), -jnp.inf)
+            with jax.named_scope("dsa_select"):
+                chosen = _select_mask(scores, K)
+            with jax.named_scope("mla_attn"):
+                att = jnp.einsum(
+                    "qhd,shd->hqs", qb, kb, preferred_element_type=jnp.float32
+                ) * scale
+                att = jnp.where(chosen[None], att, -1e30)
+                probs = jax.nn.softmax(att, axis=-1).astype(dt)
+                ob = jnp.einsum("hqs,shv->qhv", probs, vb)
+            count = jnp.stack([
+                (seen & rb[:, None]).sum(dtype=jnp.int32),
+                (chosen & rb[:, None]).sum(dtype=jnp.int32),
+            ])
+            return ob, count, (chosen if collect else None)
+
+        ob, count, chosen = lax.map(
+            block, (blocked(qq), blocked(qi[0]), blocked(wi[0]), when, real)
+        )
+        if collect:
+            chosen = jnp.pad(
+                chosen.reshape(-1, n_keys)[:per], ((0, 0), (0, Sq - n_keys))
+            )
+        return ob.reshape(-1, *ob.shape[2:])[:per], count.sum(0), chosen
+
+    # a query sees no key behind it: the g-th of ``groups`` runs of
+    # queries is given the first g + 1 runs of keys only, which leaves
+    # out 3/8 of a masked-everywhere attention's work at four groups
+    parts = [attend(g * per, (g + 1) * per) for g in range(groups)]
+    aux["dsa_keys"] = sum(part[1] for part in parts)
+    if collect:
+        aux["selected"] = jnp.concatenate([part[2] for part in parts])[None]
+    out = jnp.concatenate([part[0] for part in parts])[None]
+    return out, state, aux
+
+
+#: token rows of a run above which its feed-forward goes through the
+#: expert layer in chunks: the sorted (token, choice) rows of 8,192
+#: tokens x 8 are 805 MB a copy at 6,144 wide
+_FFN_CHUNK = 2048
+
+
+def _ffn_in_chunks(h, p, config: LlamaConfig):
+    """``_ffn`` of a long run, ``_FFN_CHUNK`` tokens at a time (the same
+    result: every token's feed-forward is its own)."""
+    B, S, E = h.shape
+    if "w_router" not in p or S <= _FFN_CHUNK or S % _FFN_CHUNK:
+        return _ffn(h, p, config)
+    chunks = h.reshape(B, S // _FFN_CHUNK, _FFN_CHUNK, E).swapaxes(0, 1)
+    y, routing = lax.map(lambda hc: _ffn(hc, p, config), chunks)
+    k = routing["experts"].shape[-1]
+    return y.swapaxes(0, 1).reshape(B, S, E), {
+        "rows": routing["rows"].sum(0),
+        "experts": routing["experts"].swapaxes(0, 1).reshape(B, S, k),
+    }
+
+
+def _block_step(x, p, state, slot, positions, config: LlamaConfig,
+                collect: bool = False):
+    """Block ``p["cache_layer"]`` over each row's run of new tokens.  x:
+    (R, Sq, E); positions: (R, Sq) absolute position of every new token;
+    state: the WHOLE cache's token state (``k``/``v``, or ``ckv``/``ik``
+    for a latent config: the attention and what the cache holds go by
+    the config's attention kind), of which only the new tokens' entries
+    are written; slot: the one cache row addressed, or None for all B.
+    Returns (x, state, aux): aux holds ``expert_rows`` for an expert
+    block and ``dsa_keys`` for a latent config (``_with_counts``), with
+    ``collect`` also the choices made (``selected``, ``experts``)."""
     c = config
     with jax.named_scope("decode_attn"):
         h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-        q, kk, vv = _qkv(h, p, positions, c)
-        cache_k, slab_k = _write_and_read(
-            cache_k, kk.astype(c.dtype), p["layer"], slot, positions, c
-        )
-        cache_v, slab_v = _write_and_read(
-            cache_v, vv.astype(c.dtype), p["layer"], slot, positions, c
-        )
-        mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window)
-        attn = _grouped_attention(q, slab_k, slab_v, mask, c)
+        if c.latent:
+            attn, state, aux = _latent_attention(
+                h, p, state, slot, positions, c, collect
+            )
+        else:
+            attn, state, aux = _kv_attention(h, p, state, slot, positions, c)
         x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     with jax.named_scope("decode_mlp"):
-        y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
-    return x + y, cache_k, cache_v, routing and routing["rows"]
+        y, routing = _ffn_in_chunks(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
+    if routing:
+        aux["expert_rows"] = routing["rows"]
+        if collect:
+            aux["experts"] = routing["experts"]
+    return x + y, state, aux
 
 
 def _cached_step(params: Params, tokens, cache: Params, slot, start,
-                 config: LlamaConfig):
+                 config: LlamaConfig, collect: bool = False):
     """The ONE cached step: a contiguous run of Sq new tokens per row,
     each row's starting at its own offset, through all layers.
 
@@ -679,23 +1256,40 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
     (traced) index of the one row addressed (R = 1).  Returns
     (last-token logits (R, V) f32, new cache).  The cache rides the
     layer loop whole, as its carry: under a jit that donates it every
-    layer writes the new tokens' K/V in place and no slab is copied."""
+    layer writes the new tokens' state in place and no slab is copied.
+    A config with leading dense blocks loops over its two parameter
+    stacks in turn, with the same body.  ``collect``: returns (logits,
+    cache, the choices every layer made) — for comparisons with a
+    reference, no serving path asks."""
     c = config
     positions = start[:, None] + jnp.arange(tokens.shape[1])
     x = params["tok_embed"].astype(c.dtype)[tokens]
-    xs, whole = _layer_params(params["blocks"], c)
+    step = slot is None and tokens.shape[1] == 1
+    state = {k: cache[k] for k in _STATE if k in cache}
+    # a latent config's run reads no cache: its state stays out of the
+    # loop and gets the run's rows after it (``_latent_attention``)
+    riding = {} if c.latent and not step else state
+    kept = {}
+    for name, _layers, first, _experts in _stacks(c):
+        xs, whole = _layer_params(params[name], c)
 
-    def body(carry, layer):
-        xx, ck, cv = carry
-        p, l = layer
-        *carry, expert_rows = _block_step(
-            xx, dict(p, layer=l, **whole), ck, cv, slot, positions, c
-        )
-        return tuple(carry), expert_rows
+        def body(carry, layer, first=first, whole=whole):
+            xx, st = carry
+            p, l = layer
+            p = dict(p, layer=l, cache_layer=l + first if first else l, **whole)
+            xx, st, aux = _block_step(xx, p, st, slot, positions, c, collect)
+            return (xx, st), aux
 
-    (x, new_k, new_v), expert_rows = lax.scan(
-        body, (x, cache["k"], cache["v"]), xs
-    )
+        (x, riding), aux = lax.scan(body, (x, riding), xs)
+        for k, v in aux.items():
+            kept.setdefault(k, []).append(v)
+    aux = {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
+    state = dict(state, **riding)
+    for k in ("ckv", "ik"):
+        if k + "_rows" in aux:  # (L, Sq, width) -> every layer, row ``slot``
+            state[k] = lax.dynamic_update_slice(
+                state[k], aux.pop(k + "_rows")[:, None], (0, slot, 0, 0)
+            )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = jnp.einsum(
         "be,ve->bv",
@@ -703,7 +1297,8 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
         _head_weight(params, c).astype(c.dtype),
         preferred_element_type=jnp.float32,
     )
-    return logits, _with_expert_counts(cache, new_k, new_v, expert_rows)
+    cache = _with_counts(cache, state, aux, step)
+    return (logits, cache, aux) if collect else (logits, cache)
 
 
 def forward_cached(params: Params, tokens, cache: Params, start,
@@ -726,6 +1321,22 @@ def forward_cached(params: Params, tokens, cache: Params, start,
         )
     start = jnp.full((tokens.shape[0],), start, jnp.int32)
     return _cached_step(params, tokens, cache, None, start, config)
+
+
+@partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
+def choices_cached(params, tokens, cache, slot, pos, config: LlamaConfig):
+    """``prefill_into_slot`` (``slot`` an index, tokens (1, S), ``pos``
+    ignored) or ``decode_step_rowwise`` (``slot`` None, tokens (B,), pos
+    (B,)) that also hands back what each layer chose: (logits, cache,
+    {"selected": (L, R, Sq, T') bool keys each query attended to (latent
+    configs), "experts": (expert layers, R, Sq, k) (expert configs)}).
+    The same ``_cached_step`` with its choices kept: for comparisons
+    with a reference's choices; no serving path calls it."""
+    if slot is None:
+        return _cached_step(params, tokens[:, None], cache, None, pos, config, True)
+    return _cached_step(
+        params, tokens, cache, slot, jnp.zeros((1,), jnp.int32), config, True
+    )
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))

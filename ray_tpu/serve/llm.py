@@ -52,7 +52,7 @@ _ENGINE_TTFT_MS = metrics.Histogram(
     "stream() pushed the request -> its first token was put on its queue",
     boundaries=_MS_LADDER,
 )
-#: expert configs only, set where ``moe_counters`` reads the counters
+#: expert configs only, set where ``cache_counters`` reads the counters
 _MOE_TOUCHED_MEAN = metrics.Gauge(
     "llm_moe_experts_touched_mean",
     "experts with at least one row, mean over every layer-step so far",
@@ -60,6 +60,15 @@ _MOE_TOUCHED_MEAN = metrics.Gauge(
 _MOE_LOAD_MAX_OVER_MEAN = metrics.Gauge(
     "llm_moe_expert_load_max_over_mean",
     "rows of the busiest (layer, expert) over the mean of all, so far",
+)
+#: latent-attention configs only, set where ``cache_counters`` reads them
+_DSA_SELECTED_SHARE = metrics.Gauge(
+    "llm_dsa_selected_share",
+    "keys attention was given over keys the indexer saw, every query so far",
+)
+_MOE_HELD_SHARE = metrics.Gauge(
+    "llm_moe_held_assignment_share",
+    "routed (token, expert) assignments that fell on an expert held here",
 )
 _OFF = contextlib.nullcontext()
 
@@ -154,7 +163,7 @@ class LLMEngine:
             self.cache_len = max_len
         self.cache = llama.init_cache(config, max_slots, self.cache_len)
         # held while a prefill or a decode step is launched on the
-        # (donated) cache: whoever else wants to read it (moe_counters)
+        # (donated) cache: whoever else wants to read it (cache_counters)
         # waits its turn, and then for the steps launched so far
         self._cache_lock = asyncio.Lock()
         # a slot is taken from a request's prefill to the LAUNCH of its
@@ -227,33 +236,62 @@ class LLMEngine:
             if request is not None:
                 request.finish()
 
-    async def moe_counters(self) -> Optional[dict]:
-        """The expert layer's running totals, pulled from the device
-        between two steps (None for a dense config): ``expert_tokens``
-        (L, X) rows each expert of each layer computed,
-        ``experts_touched_total`` and ``layer_steps_total`` summed over
-        the layers.  Every row of a decode step routes, so rows of
-        slots the engine holds no request in are counted too: these
-        count what the kernel did, not what clients received."""
+    async def cache_counters(self) -> dict:
+        """Every running total the donated cache carries, in ONE pass
+        under the cache's lock (one device-to-host copy per counter,
+        between two steps), as one flat dict.  An expert config:
+        ``moe_expert_tokens`` (expert layers, experts held) rows each
+        expert of each layer computed, ``moe_experts_touched_total`` and
+        ``moe_layer_steps_total`` summed over the layers; every row of a
+        decode step routes, so rows of slots the engine holds no request
+        in are counted too: these count what the kernel did, not what
+        clients received.  A latent-attention config: keys the indexer
+        saw (``dsa_visible_*``) and keys attention was given
+        (``dsa_selected_*``), summed over every (layer, row, query),
+        decode steps (``_step``) and prefills (``_run``) apart.  Sets
+        the gauges of both."""
         import numpy as np
 
+        from ray_tpu.models.llama import wide_total
         from ray_tpu.ops import grouped_matmul
 
-        if "moe_expert_tokens" not in self.cache:
-            return None
+        out = {}
+        names = [k for k in self.cache if k.startswith(("moe_", "dsa_"))]
+        if not names:
+            return out
         async with self._cache_lock:
-            tokens = np.asarray(self.cache["moe_expert_tokens"])
-            touched = int(np.asarray(self.cache["moe_experts_touched"]).sum())
-            steps = int(np.asarray(self.cache["moe_layer_steps"]).sum())
-        if steps:
-            _MOE_TOUCHED_MEAN.set(touched / steps)
-            _MOE_LOAD_MAX_OVER_MEAN.set(float(tokens.max() / tokens.mean()))
-        return {
-            "grouped_matmul": grouped_matmul.implementation(),
-            "moe_expert_tokens": tokens.tolist(),
-            "moe_experts_touched_total": touched,
-            "moe_layer_steps_total": steps,
-        }
+            host = {k: np.asarray(self.cache[k]) for k in names}
+        if "moe_expert_tokens" in host:
+            tokens = host["moe_expert_tokens"]
+            touched = int(host["moe_experts_touched"].sum())
+            steps = int(host["moe_layer_steps"].sum())
+            if steps:
+                _MOE_TOUCHED_MEAN.set(touched / steps)
+                _MOE_LOAD_MAX_OVER_MEAN.set(float(tokens.max() / tokens.mean()))
+            routed = (self.rows_stepped_total * tokens.shape[0]
+                      * self.config.experts_per_token)
+            if self.config.experts_held and routed:
+                _MOE_HELD_SHARE.set(float(tokens.sum()) / routed)
+            out.update({
+                "grouped_matmul": grouped_matmul.implementation(),
+                "moe_expert_tokens": tokens.tolist(),
+                "moe_experts_touched_total": touched,
+                "moe_layer_steps_total": steps,
+            })
+        if "dsa_keys" in host:
+            keys = host["dsa_keys"]            # (L, visible|selected, run|step, 2)
+            dsa = {
+                f"dsa_{what}_{kind}": wide_total(keys[:, i, j])
+                for i, what in enumerate(("visible", "selected"))
+                for j, kind in enumerate(("run", "step"))
+            }
+            seen = dsa["dsa_visible_run"] + dsa["dsa_visible_step"]
+            if seen:
+                _DSA_SELECTED_SHARE.set(
+                    (dsa["dsa_selected_run"] + dsa["dsa_selected_step"]) / seen
+                )
+            out.update(dsa)
+        return out
 
     # -- engine loop -----------------------------------------------------
     async def _run(self):
@@ -518,13 +556,20 @@ class LlamaDeployment:
 
         An expert config adds which grouped-matmul body its programs
         were traced with (``grouped_matmul``) and the routing counters
-        the cache carries (``LLMEngine.moe_counters``; one device-to-
+        the cache carries (``LLMEngine.cache_counters``; one device-to-
         host copy here, none in any step): ``moe_expert_tokens``,
         ``moe_experts_touched_total``, ``moe_layer_steps_total``.  The
         two gauges ``llm_moe_experts_touched_mean`` and
         ``llm_moe_expert_load_max_over_mean`` are set from them there
         (this class travels to its replica by value, so it names no
-        metric object itself)."""
+        metric object itself).  A latent-attention config adds
+        ``dsa_visible_step`` / ``dsa_selected_step`` (decode steps) and
+        ``dsa_visible_run`` / ``dsa_selected_run`` (prefills): keys its
+        indexer saw and keys attention was given, over every (layer,
+        row, query) of the replica's life, and the gauges
+        ``llm_dsa_selected_share`` and (experts held here)
+        ``llm_moe_held_assignment_share``.  ``cache_bytes`` is what the
+        cache holds, by entry."""
         import jax
 
         from ray_tpu.models import llama
@@ -532,9 +577,15 @@ class LlamaDeployment:
         devices = jax.devices()
         dev = devices[0]
         mem = dev.memory_stats() or {}
-        moe = await self.engine.moe_counters()
+        counters = await self.engine.cache_counters()
         return {
-            **(moe or {}),
+            **counters,
+            # what the cache holds, entry by entry (``k``/``v``, or a
+            # latent config's ``ckv``/``ik``; the counters beside them)
+            "cache_bytes": {
+                k: int(v.size) * v.dtype.itemsize
+                for k, v in self.engine.cache.items()
+            },
             "platform": dev.platform,
             "device_kind": dev.device_kind,
             "device_count": len(devices),

@@ -213,3 +213,73 @@ def test_prefill_writes_the_donated_cache_in_place(
 
     assert len(making_a_cache("dynamic-update-slice")) == 2
     assert not making_a_cache("scatter")
+
+
+GLM_FILE = os.path.join(os.path.dirname(CONFIG_FILE), "glm-5-ep16-l6.json")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill4096"])
+def test_latent_cache_is_never_copied_nor_expanded(
+        v5e_chip, no_compile_cache, monkeypatch, program):
+    """GLM-5's two programs at the published widths (``glm-5-ep16-l6``:
+    12.5 GB of weights and cache live of 16), compiled for the chip.
+    What was seen at compile time while they were written (PR 30) and a
+    later edit could bring back unseen by any CPU test: a latent row of
+    576 values (no multiple of the 128 lanes) made the chip's default
+    layout put the positions minor-most, and every decode step copied
+    the whole 2.3 GB cache into a row-major layout and back (2.52 GB of
+    temporaries; 88 MB since the row is 640); a prefill that wrote its
+    rows layer by layer inside the layer loop carried the cache through
+    it transposed and copied all 3 GB in and out (4.7 GB of temporaries
+    at 4,096 tokens; 1.3 GB since the rows are written once, after the
+    loop).  And what the path is for: the decode step gathers 2,048
+    latent rows a row and never makes keys or values of the cache."""
+    from chipbench.jobs.serve_dsa import dsa_config
+    from ray_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    with open(GLM_FILE) as f:
+        served = json.load(f)
+    config = dsa_config(served)
+    slots, max_len = served["serving"]["max_slots"], served["serving"]["max_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert 9.4e9 < weights < 9.5e9           # 4.73 B parameters in bf16
+    held = sum(a.size * a.dtype.itemsize for k, a in cache.items() if k in ("ckv", "ik"))
+    assert held == 32 * 10240 * 6 * (640 + 128) * 2   # 3.02 GB
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    if program == "decode":
+        compiled = llama.decode_step_rowwise.lower(
+            params, rows, cache, rows, config).compile()
+        limit = 256 * 2**20
+    else:
+        compiled = llama.prefill_into_slot.lower(
+            params, jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=v5e_chip),
+            cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip), config,
+        ).compile()
+        limit = 1536 * 2**20
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held, mem          # both written in place
+    assert mem.temp_size_in_bytes < limit, mem
+    text = compiled.as_text()
+    whole = "bf16[{}]".format(",".join(map(str, cache["ckv"].shape)))
+    assert not re.findall(rf"{re.escape(whole)}\S* copy\(", text), "the cache is copied"
+    assert "{2,3,1,0" not in "".join(re.findall(rf"{re.escape(whole)}\S*", text))
+    if program == "decode":
+        # 32 rows x 2,048 chosen latent rows, gathered; no key or value of
+        # the cache's length per head (64 heads x 192 / 256 over 10,240)
+        assert re.search(r"bf16\[65536,640\]\S* fusion\(", text)
+        for expanded in ("10240,64,192]", "10240,64,256]", "64,10240,192]", "64,10240,256]"):
+            assert expanded not in text, f"keys or values expanded over the cache: {expanded}"
