@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import latent_decode_attention
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -734,13 +735,17 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     ``ckv`` (L, B, max_len, kv_lora_rank + qk_rope_head_dim, rounded up
     to whole 128-lane tiles: 576 -> 640, zeros behind), the normed
     latent and the one rotary key all heads share, of which a decode
-    step gathers only the rows the indexer chose; and ``ik`` (L, B,
-    max_len, index_head_dim), the indexer's keys, of which it reads
-    every row up to ``pos``.  Beside them ``dsa_keys`` (L, 2, 2, 2)
-    int32: keys visible / keys selected, summed over every (row, query)
-    of every call, for runs (prefills) / single-token steps apart, each
-    as two words (millions, rest: ``_add_wide``) because 32 rows at 10k
-    keys are 2 M a step and int32 would last 1,000 steps.
+    step attends to the rows the indexer chose — streaming each row's
+    blocks up to ``pos`` through one kernel with the choice as its mask,
+    or, in a cache too long for that to pay, gathering the chosen rows
+    (``ops/latent_decode_attention.py``); and ``ik`` (L, B, max_len,
+    index_head_dim), the indexer's keys, of which it reads every row up
+    to ``pos``.  Beside them ``dsa_keys`` (L, 3, 2, 2) int32: keys
+    visible / keys selected / latent rows read from ``ckv`` (single-token
+    steps only: blocks streamed or rows gathered), summed over every
+    (row, query) of every call, for runs (prefills) / single-token steps
+    apart, each as two words (millions, rest: ``_add_wide``) because 32
+    rows at 10k keys are 2 M a step and int32 would last 1,000 steps.
 
     An expert config adds int32 running totals that ride the donated
     cache like K and V, so no step pays a device-to-host copy for them
@@ -756,7 +761,7 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
         cache = {
             "ckv": jnp.zeros((*lead, _latent_row(c)), c.dtype),
             "ik": jnp.zeros((*lead, c.index_head_dim), c.dtype),
-            "dsa_keys": jnp.zeros((c.num_layers, 2, 2, 2), jnp.int32),
+            "dsa_keys": jnp.zeros((c.num_layers, 3, 2, 2), jnp.int32),
         }
     else:
         shape = (c.num_layers, batch_size, max_len, c.num_kv_heads, c.head_dim)
@@ -810,7 +815,8 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool) -> Param
     """The cache after one call: the new state and the running totals
     plus this call's ``aux`` (the layer loop's stacked outputs): an
     expert config's (expert layers, experts held) rows per expert, a
-    latent config's (L, 2) keys visible and selected."""
+    latent config's (L, 2) keys visible and selected, of a single-token
+    step (L, 3): and latent rows read."""
     out = dict(cache, **state)
     if "expert_rows" in aux:
         rows = aux["expert_rows"]
@@ -821,8 +827,9 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool) -> Param
         out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
     if "dsa_keys" in aux:
         kind = int(step)  # runs at [:, :, 0], single-token steps at [:, :, 1]
-        out["dsa_keys"] = cache["dsa_keys"].at[:, :, kind].set(
-            _add_wide(cache["dsa_keys"][:, :, kind], aux["dsa_keys"])
+        n = aux["dsa_keys"].shape[-1]  # a run counts no keys read
+        out["dsa_keys"] = cache["dsa_keys"].at[:, :n, kind].set(
+            _add_wide(cache["dsa_keys"][:, :n, kind], aux["dsa_keys"])
         )
     return out
 
@@ -1023,11 +1030,16 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     while there are fewer).
 
     One token for every row (decode): the rows' new state is written,
-    the indexer reads the layer's whole ``ik`` slab, ``lax.top_k``
-    picks ``index_topk`` rows, ONLY THOSE rows of ``ckv`` are gathered,
-    and attention runs in the absorbed form on them — ``W_kb`` carried
-    into the query, ``W_vb`` applied to the weighted sum of latents:
-    keys and values are never expanded over the cache.
+    the indexer reads the layer's whole ``ik`` slab, and attention runs
+    in the absorbed form over the ``index_topk`` rows of ``ckv`` it
+    chose — ``W_kb`` carried into the query, ``W_vb`` applied to the
+    weighted sum of latents: keys and values are never expanded over the
+    cache.  ``ops/latent_decode_attention.py`` says by the cache's length
+    how the chosen rows are reached: ``streamed`` — the selection is a
+    mask (``_select_mask``) and one Pallas kernel reads each row's blocks
+    of ``ckv`` up to ``pos`` where they lie, once; ``gathered`` (a cache
+    past the crossover, or no whole number of blocks) — ``lax.top_k``'s
+    indices, those rows gathered, two einsums.  The same set either way.
 
     A run (prefill; one row, from position 0: the run's own tokens are
     all the keys there are): K and V are expanded from the run's latents
@@ -1036,7 +1048,8 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     heads x Sq x Sq is live.
 
     Returns (the heads' outputs (R, Sq, H, v_head_dim), state,
-    {"dsa_keys": (2,) int32 keys visible and selected over all queries}
+    {"dsa_keys": (2,) int32 keys visible and selected over all queries,
+    of a step (3,): and latent rows read from ``ckv``}
     — with ``collect`` also ``"selected"``: (R, Sq, T') bool, the keys
     each query attended to (T' = the cache's length for a step, Sq for
     a run))."""
@@ -1091,27 +1104,36 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
             scores = jnp.where(
                 visible, _index_scores(qi, wi, slab)[:, 0], -jnp.inf
             )
+        streamed = latent_decode_attention.implementation(T) == "streamed"
         with jax.named_scope("dsa_select"):
-            _, chosen = lax.top_k(scores, min(K, T))                # (R, K)
-            valid = chosen <= pos[:, None]
-            picked = ckv[layer, rows[:, None], chosen]              # (R, K, row)
+            if streamed:
+                hit = _select_mask(scores, K)                       # (R, T)
+                selected = hit.sum(dtype=jnp.int32)
+                read = latent_decode_attention.keys_read(pos).sum(dtype=jnp.int32)
+            else:
+                _, chosen = lax.top_k(scores, min(K, T))            # (R, K)
+                valid = chosen <= pos[:, None]
+                selected = read = valid.sum(dtype=jnp.int32)
         with jax.named_scope("mla_attn"):
             q_lat = jnp.einsum("rhn,chn->rhc", q_nope[:, 0], p["w_kb"].astype(dt))
             qq = jnp.concatenate(
                 [q_lat, q_rope[:, 0], fill[:, :1].repeat(c.num_heads, 1)], axis=-1
             )                                                       # (R, H, row)
-            att = jnp.einsum(
-                "rhc,rkc->rhk", qq, picked, preferred_element_type=jnp.float32
-            ) * scale
-            att = jnp.where(valid[:, None, :], att, -1e30)
-            probs = jax.nn.softmax(att, axis=-1).astype(dt)
-            mix = jnp.einsum("rhk,rkc->rhc", probs, picked[..., :C])
+            if streamed:
+                mix = latent_decode_attention.latent_decode_attention(
+                    qq, ckv, layer, pos, hit, latent=C, scale=scale
+                )
+            else:
+                mix = latent_decode_attention.gathered_decode_attention(
+                    qq, ckv, layer, chosen, valid, latent=C, scale=scale
+                )
             out = jnp.einsum("rhc,chv->rhv", mix, p["w_vb"].astype(dt))[:, None]
         aux["dsa_keys"] = jnp.stack([
-            visible.sum(dtype=jnp.int32), valid.sum(dtype=jnp.int32)
+            visible.sum(dtype=jnp.int32), selected, read
         ])
         if collect:
-            hit = jnp.zeros((R, T), bool).at[rows[:, None], chosen].set(valid)
+            if not streamed:
+                hit = jnp.zeros((R, T), bool).at[rows[:, None], chosen].set(valid)
             aux["selected"] = hit[:, None, :]
         return out, {"ckv": ckv, "ik": ik}, aux
 

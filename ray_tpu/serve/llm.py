@@ -248,8 +248,12 @@ class LLMEngine:
         clients received.  A latent-attention config: keys the indexer
         saw (``dsa_visible_*``) and keys attention was given
         (``dsa_selected_*``), summed over every (layer, row, query),
-        decode steps (``_step``) and prefills (``_run``) apart.  Sets
-        the gauges of both."""
+        decode steps (``_step``) and prefills (``_run``) apart, and
+        ``dsa_read_step``, latent rows the decode steps' attention
+        fetched from the cache: the selected ones where they were
+        gathered, every row of the blocks up to ``pos`` where they were
+        streamed (``ops/latent_decode_attention.py``).  Sets the gauges
+        of both."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
@@ -279,12 +283,13 @@ class LLMEngine:
                 "moe_layer_steps_total": steps,
             })
         if "dsa_keys" in host:
-            keys = host["dsa_keys"]            # (L, visible|selected, run|step, 2)
+            keys = host["dsa_keys"]       # (L, visible|selected|read, run|step, 2)
             dsa = {
                 f"dsa_{what}_{kind}": wide_total(keys[:, i, j])
                 for i, what in enumerate(("visible", "selected"))
                 for j, kind in enumerate(("run", "step"))
             }
+            dsa["dsa_read_step"] = wide_total(keys[:, 2, 1])
             seen = dsa["dsa_visible_run"] + dsa["dsa_visible_step"]
             if seen:
                 _DSA_SELECTED_SHARE.set(
@@ -566,7 +571,10 @@ class LlamaDeployment:
         ``dsa_visible_step`` / ``dsa_selected_step`` (decode steps) and
         ``dsa_visible_run`` / ``dsa_selected_run`` (prefills): keys its
         indexer saw and keys attention was given, over every (layer,
-        row, query) of the replica's life, and the gauges
+        row, query) of the replica's life, ``dsa_read_step`` (latent
+        rows the decode steps fetched to attend to them: equal to
+        ``dsa_selected_step`` where rows are gathered, the streamed
+        blocks' rows otherwise), and the gauges
         ``llm_dsa_selected_share`` and (experts held here)
         ``llm_moe_held_assignment_share``.  ``cache_bytes`` is what the
         cache holds, by entry."""

@@ -232,12 +232,18 @@ def test_latent_cache_is_never_copied_nor_expanded(
     rows layer by layer inside the layer loop carried the cache through
     it transposed and copied all 3 GB in and out (4.7 GB of temporaries
     at 4,096 tokens; 1.3 GB since the rows are written once, after the
-    loop).  And what the path is for: the decode step gathers 2,048
-    latent rows a row and never makes keys or values of the cache."""
+    loop).  And what the path is for: the decode step attends to 2,048
+    latent rows a row where they lie in the cache, through one Pallas
+    kernel (PR 31: the 32 x 2,048 rows were gathered before, 84 MB a
+    layer written and read back twice), and never makes keys or values
+    of the cache, nor a slice of it in front of the kernel (419 MB a
+    layer)."""
     from chipbench.jobs.serve_dsa import dsa_config
-    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.ops import grouped_matmul, latent_decode_attention
 
     monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    # the default backend is the CPU here: compile the kernel, as the chip does
+    monkeypatch.setattr(latent_decode_attention, "_interpret", lambda: False)
     with open(GLM_FILE) as f:
         served = json.load(f)
     config = dsa_config(served)
@@ -278,8 +284,13 @@ def test_latent_cache_is_never_copied_nor_expanded(
     assert not re.findall(rf"{re.escape(whole)}\S* copy\(", text), "the cache is copied"
     assert "{2,3,1,0" not in "".join(re.findall(rf"{re.escape(whole)}\S*", text))
     if program == "decode":
-        # 32 rows x 2,048 chosen latent rows, gathered; no key or value of
-        # the cache's length per head (64 heads x 192 / 256 over 10,240)
-        assert re.search(r"bf16\[65536,640\]\S* fusion\(", text)
+        # the kernel reads the cache itself: no gathered rows, no layer's
+        # slab cut out for it; no key or value of the cache's length per
+        # head (64 heads x 192 / 256 over 10,240)
+        assert latent_decode_attention.implementation(max_len) == "streamed"
+        assert re.search(r"%latent_decode\S* = \S+ custom-call\(.*tpu_custom_call", text)
+        for made in ("bf16[65536,640]", "bf16[32,2048,640]",
+                     "bf16[32,10240,640]", "bf16[1,32,10240,640]"):
+            assert made not in text, f"latent rows are copied out of the cache: {made}"
         for expanded in ("10240,64,192]", "10240,64,256]", "64,10240,192]", "64,10240,256]"):
             assert expanded not in text, f"keys or values expanded over the cache: {expanded}"

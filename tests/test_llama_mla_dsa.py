@@ -18,8 +18,28 @@ from chipbench.reference import errors
 from chipbench.reference import glm_dsa as ref
 from chipbench.reference.llama import FLOAT32_TOLERANCE
 from ray_tpu.models import llama
+from ray_tpu.ops import latent_decode_attention
 
 TOPK = 8
+_STEP_PROGRAMS = (llama.choices_cached, llama.decode_step_rowwise)
+
+
+@pytest.fixture
+def body(request, monkeypatch):
+    """``"gathered"``: the decode step as these tiny caches trace it;
+    ``"streamed"``: the kernel in blocks of 8 keys, which takes them.
+    The block size is read when a program is traced, so what was traced
+    under another one is forgotten, before and after."""
+    if request.param == "streamed":
+        monkeypatch.setattr(latent_decode_attention, "BLOCK_KEYS", 8)
+    for program in _STEP_PROGRAMS:
+        program.clear_cache()
+    yield request.param
+    for program in _STEP_PROGRAMS:
+        program.clear_cache()
+
+
+both_bodies = pytest.mark.parametrize("body", ["gathered", "streamed"], indirect=True)
 
 
 def tiny(**kw):
@@ -58,20 +78,25 @@ def prompt(cfg, n, seed=0):
     return np.random.default_rng(seed).integers(0, cfg.vocab_size, n).tolist()
 
 
-@pytest.mark.parametrize("length,block,kw", [
-    (3 * TOPK, 128, {}),                      # one block of queries
-    (3 * TOPK, 8, {}),                        # three whole blocks
-    (21, 8, {}),                              # the last block padded
-    (32, 8, {}),                              # four causal groups of one block
-    (3 * TOPK, 8, {"first_dense_layers": 2}),  # two dense blocks lead
-    (3 * TOPK, 8, {"first_dense_layers": 0}),  # expert blocks only
-    (TOPK - 2, 128, {}),                      # never more keys than may be seen
-])
+@pytest.mark.parametrize("length,block,kw,body", [
+    (3 * TOPK, 128, {}, "gathered"),          # one block of queries
+    (3 * TOPK, 8, {}, "gathered"),            # three whole blocks
+    (21, 8, {}, "gathered"),                  # the last block padded
+    (32, 8, {}, "gathered"),                  # four causal groups of one block
+    (3 * TOPK, 8, {"first_dense_layers": 2}, "gathered"),  # two dense blocks lead
+    (3 * TOPK, 8, {"first_dense_layers": 0}, "gathered"),  # expert blocks only
+    (TOPK - 2, 128, {}, "gathered"),          # never more keys than may be seen
+    # the decode steps through the kernel: the cache's 40 keys are 5 blocks
+    (3 * TOPK, 128, {}, "streamed"),          # more visible than chosen, 4 blocks in
+    (21, 8, {}, "streamed"),                  # the steps cross into the 4th block
+    (TOPK - 2, 128, {}, "streamed"),          # fewer, exactly, one more than index_topk
+], indirect=["body"])
 def test_prefill_then_decode_through_the_cache_is_the_reference(
-        monkeypatch, length, block, kw):
+        monkeypatch, length, block, kw, body):
     """Logits, selected sets and chosen experts of ``prefill_into_slot``
     and three ``decode_step_rowwise`` steps in a three-row cache equal the
     reference's full forward at a context of 3 x ``index_topk``."""
+    assert latent_decode_attention.implementation(40) == body
     monkeypatch.setattr(llama, "_QUERY_BLOCK", block)
     cfg = tiny(**kw)
     params = weights(cfg)
@@ -112,11 +137,13 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
     np.testing.assert_allclose(plain[0], system[0], rtol=0, atol=1e-6)
 
 
-def test_absorbed_decode_attention_equals_the_expanded_form():
+@both_bodies
+def test_absorbed_decode_attention_equals_the_expanded_form(body):
     """A decode step (queries carried into the latent space, values
     applied to the mix of latents) gives the logits of a prefill one
     token longer (keys and values expanded), at every length around
     ``index_topk``."""
+    assert latent_decode_attention.implementation(32) == body
     cfg = tiny()
     params = weights(cfg)
     seq = prompt(cfg, 2 * TOPK + 1, seed=3)
@@ -238,7 +265,8 @@ def test_wide_counters_do_not_overflow():
     assert llama.wide_total(total) == 10_000_000_000 + 35 + (5 << 20)
 
 
-def test_the_cache_holds_latent_rows_index_keys_and_counters():
+@both_bodies
+def test_the_cache_holds_latent_rows_index_keys_and_counters(body):
     cfg = tiny()
     cache = llama.init_cache(cfg, 3, 40)
     assert cache["ckv"].shape == (3, 3, 40, 128)    # 24 + 8 values in a 128-lane row
@@ -263,6 +291,10 @@ def test_the_cache_holds_latent_rows_index_keys_and_counters():
     keys = np.asarray(cache["dsa_keys"])
     assert llama.wide_total(keys[0, 0, 1]) == 1 + 1 + 25
     assert llama.wide_total(keys[0, 1, 1]) == 1 + 1 + TOPK
+    # latent rows read: the chosen ones, or the blocks of 8 up to each ``pos``
+    assert llama.wide_total(keys[0, 2, 1]) == (
+        1 + 1 + TOPK if body == "gathered" else 8 + 8 + 32)
+    assert not keys[:, 2, 0].any()                          # a run counts none
 
 
 def test_the_no_cache_forward_refuses_a_latent_config():
@@ -316,11 +348,12 @@ def test_num_params_and_flops_count_the_new_layers():
         assert llama.flops_per_token(old, 64) == 6.0 * n + 12 * old.num_layers * old.embed_dim * 64
 
 
-def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache():
+@both_bodies
+def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache(body):
     """A ``LlamaDeployment`` on the tiny configuration: two concurrent
     requests through the engine get, token for token, the argmax of the
     reference's logits over their own context; ``stats()`` reports the
-    cache by entry and the keys seen and selected."""
+    cache by entry and the keys seen, selected and read."""
     from ray_tpu.serve.llm import LlamaDeployment
     from ray_tpu.util import metrics
 
@@ -357,6 +390,13 @@ def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache
     assert stats["dsa_selected_run"] == cfg.num_layers * sum(
         min(TOPK, t + 1) for n in (12, 19) for t in range(n))
     assert stats["dsa_visible_step"] > stats["dsa_selected_step"] > 0
+    assert before["dsa_read_step"] == 0
+    if body == "gathered":      # the chosen rows and no other
+        assert stats["dsa_read_step"] == stats["dsa_selected_step"]
+    else:                       # whole blocks of 8: each (layer, row) reads 0..7 past ``pos``
+        over = stats["dsa_read_step"] - stats["dsa_visible_step"]
+        assert stats["dsa_read_step"] % 8 == 0
+        assert 0 < over <= 7 * cfg.num_layers * stats["rows_stepped_total"]
     tokens = np.asarray(stats["moe_expert_tokens"])
     assert tokens.shape == (2, 4)
     gauges = {m["name"]: list(m["series"].values())[0]
