@@ -26,19 +26,33 @@ run the same layer, which is what the CPU comparison with the float32
 reference needs; there is no auxiliary load-balancing loss and no
 training cell for it (ROADMAP R1, training half).
 
-``kv_lora_rank`` > 0 makes the attention of every block GLM-5's
-(DeepSeek-V3.2's): multi-head LATENT attention, whose cache row is one
-normed latent and one rotary key shared by all heads, and a learned
-INDEXER beside it that scores every visible key and lets attention see
-the ``index_topk`` best of them (``_latent_attention``).  Such a config
-keeps two kinds of state per layer in the cache (``init_cache``), runs
-decode in the absorbed form over the gathered rows and prefill in the
-expanded form over query blocks, may lead with ``first_dense_layers``
-dense blocks before its expert blocks (two parameter stacks under the
-one block body), routes with DeepSeek-V3's sigmoid router beside a
-shared expert, and may hold only ``experts_held`` of the experts its
-router routes over (one chip's share of an expert-parallel layer).  It
-runs on the cached paths only: ``forward`` / ``loss_fn`` refuse it.
+``kv_lora_rank`` > 0 makes the attention of every block multi-head LATENT
+attention (DeepSeek-V2/V3's), whose cache row is one normed latent and
+one rotary key shared by all heads (``_latent_attention``).  With
+``index_topk`` > 0 a learned INDEXER beside it scores every visible key
+and attention sees the ``index_topk`` best of them (DeepSeek-V3.2's,
+GLM-5's): such a config keeps two kinds of state per layer in the cache
+(``init_cache``) and a decode step attends to the rows the indexer chose.
+With ``index_topk`` == 0 there is no indexer (DeepSeek-V3's own form,
+JoyAI-LLM-Flash's): the cache holds the latent rows alone and a step
+attends to every visible key.  Either runs decode in the absorbed form
+over the cache where it lies (``ops/latent_decode_attention.py``) and
+prefill in the expanded form over query blocks, may lead with
+``first_dense_layers`` dense blocks before its expert blocks (two
+parameter stacks under the one block body), routes with DeepSeek-V3's
+sigmoid router beside a shared expert, and may hold only ``experts_held``
+of the experts its router routes over (one chip's share of an
+expert-parallel layer).  It runs on the cached paths only: ``forward`` /
+``loss_fn`` refuse it.
+
+The cached step takes a run of Sq new tokens per row at per-row offsets.
+Every caller but one has Sq == 1 for all rows (a decode step) or one row
+from position 0 (a prefill); ``mtp_layers`` = 1 adds DeepSeek-V3's
+multi-token-prediction module — one more block with its own cache layer,
+``params["mtp"]`` — and ``models/mtp.py`` drafts a token with it and
+verifies it with a step of Sq == 2 for every row, which yields one or two
+tokens a row.  ``_pick_token`` is the one sampler of every path, and
+``draw_keys`` the one schedule of its keys.
 """
 
 from __future__ import annotations
@@ -122,6 +136,11 @@ class LlamaConfig:
     # (0: all of them are held)
     experts_held: int = 0
     expert_offset: int = 0
+    # multi-token-prediction modules behind the last layer (DeepSeek-V3's
+    # num_nextn_predict_layers; 0 or 1): one more expert block of the
+    # model's shape with its own cache layer, which drafts the token
+    # after the next for a speculative step (``models/mtp.py``)
+    mtp_layers: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -129,9 +148,16 @@ class LlamaConfig:
 
     @property
     def latent(self) -> bool:
-        """The attention kind: latent rows + indexer keys in the cache
-        (True) or K and V per KV head (False)."""
+        """The attention kind: latent rows (and, with ``index_topk``, the
+        indexer's keys) in the cache (True) or K and V per KV head
+        (False)."""
         return self.kv_lora_rank > 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of token state in the cache: the model's and the
+        multi-token-prediction module's behind them."""
+        return self.num_layers + self.mtp_layers
 
     @property
     def experts_here(self) -> int:
@@ -194,11 +220,14 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
             "w_kva": ("layers", "embed", None), "kv_a_norm": ("layers", None),
             "w_kb": ("layers", None, "heads", None),
             "w_vb": ("layers", None, "heads", None),
-            "w_iq": ("layers", None, None, None),
-            "w_ik": ("layers", "embed", None),
-            "ik_norm": ("layers", None), "ik_bias": ("layers", None),
-            "w_iw": ("layers", "embed", None),
         }
+        if c.index_topk:
+            attn.update({
+                "w_iq": ("layers", None, None, None),
+                "w_ik": ("layers", "embed", None),
+                "ik_norm": ("layers", None), "ik_bias": ("layers", None),
+                "w_iw": ("layers", "embed", None),
+            })
     else:
         attn = {
             "wq": ("layers", "embed", "heads", None),
@@ -235,6 +264,12 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         out[name] = expert if experts else dense
     if not c.tie_embeddings:
         out["lm_head"] = ("vocab", "embed")
+    if c.mtp_layers:
+        out["mtp"] = {
+            "enorm": ("embed",), "hnorm": ("embed",), "head_norm": ("embed",),
+            "eh_proj": (None, "embed"),
+            "block": expert if c.num_experts else dense,
+        }
     return out
 
 
@@ -271,12 +306,15 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool) -> Params
             "w_kb": norm(more(2), (L, C, H, Dn), std),
             "w_vb": norm(k[3], (L, C, H, Dv), std),
             "wo": norm(k[4], (L, H, Dv, E), resid_std),
-            "w_iq": norm(more(3), (L, Q, J, Di), std),
-            "w_ik": norm(more(4), (L, E, Di), std),
-            "ik_norm": jnp.ones((L, Di), dt),
-            "ik_bias": jnp.zeros((L, Di), dt),
-            "w_iw": norm(more(5), (L, E, J), std),
         }
+        if c.index_topk:
+            blk.update({
+                "w_iq": norm(more(3), (L, Q, J, Di), std),
+                "w_ik": norm(more(4), (L, E, Di), std),
+                "ik_norm": jnp.ones((L, Di), dt),
+                "ik_bias": jnp.zeros((L, Di), dt),
+                "w_iw": norm(more(5), (L, E, J), std),
+            })
     else:
         blk = {
             "wq": norm(k[1], (L, E, H, D), std),
@@ -339,6 +377,23 @@ def init(rng, config: LlamaConfig) -> Params:
         params["lm_head"] = norm(
             jax.random.fold_in(k0, 1), (c.vocab_size, c.embed_dim), std
         )
+    if c.mtp_layers:
+        if c.mtp_layers != 1 or not c.latent or c.index_topk:
+            raise NotImplementedError(
+                "one multi-token-prediction module, behind a latent-attention "
+                "model without an indexer, is what is written"
+            )
+        key = jax.random.fold_in(rng, 1 << 20)
+        params["mtp"] = {
+            "enorm": jnp.ones((c.embed_dim,), dt),
+            "hnorm": jnp.ones((c.embed_dim,), dt),
+            "head_norm": jnp.ones((c.embed_dim,), dt),
+            # rows: the next token's embedding first, the hidden state behind
+            "eh_proj": norm(key, (2 * c.embed_dim, c.embed_dim), std),
+            "block": _init_blocks(
+                jax.random.fold_in(key, 1), c, 1, bool(c.num_experts)
+            ),
+        }
     return params
 
 
@@ -643,22 +698,23 @@ def num_params(config: LlamaConfig) -> int:
 def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float:
     """fwd+bwd FLOPs per token: 6N + the attention term.  N counts what
     ``init`` makes (the experts HELD here, the shared expert, the latent
-    projections and the indexer) less the embedding.  K/V attention:
-    scores and mix over the whole context.  Latent attention: scores
-    (head size nope + rope) and mix (``v_head_dim``) over the
-    ``index_topk`` keys a query may see, and the indexer's ``index_n_heads
-    x index_head_dim`` over the whole context."""
+    projections, the indexer and the multi-token-prediction module where
+    there is one) less the embedding.  K/V attention: scores and mix over
+    the whole context.  Latent attention: scores (head size nope + rope)
+    and mix (``v_head_dim``) over the keys a query may see — the
+    ``index_topk`` best where there is an indexer, whose ``index_n_heads x
+    index_head_dim`` over the whole context is added, else all of them —
+    in every layer that keeps a cache (the module's block is one)."""
     c = config
     S = seq_len or c.max_seq_len
     n = num_params(c) - c.vocab_size * c.embed_dim * (
         0 if c.tie_embeddings else 1
     )
     if c.latent:
-        seen = min(S, c.index_topk)
+        seen = min(S, c.index_topk) if c.index_topk else S
+        indexer = c.index_n_heads * c.index_head_dim * S if c.index_topk else 0
         per_key = c.num_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim + c.v_head_dim)
-        attn = 6 * c.num_layers * (
-            per_key * seen + c.index_n_heads * c.index_head_dim * S
-        )
+        attn = 6 * c.cache_layers * (per_key * seen + indexer)
     else:
         attn = 12 * c.num_layers * c.embed_dim * S  # 2*2*3 * L * E * S
     return 6.0 * n + attn
@@ -756,7 +812,19 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     calls.  They count what the kernel did: every row of a decode step
     routes, also the rows the engine treats as inactive."""
     c = config
-    if c.latent:
+    if c.latent and not c.index_topk:
+        # no indexer: one kind of state, every visible key attended to;
+        # ``mla_keys`` (layers, 2, 2): keys visible to a row (to its last
+        # query: the others see prefixes) / latent rows read for it, over
+        # every row of every step, as ``_add_wide`` pairs.
+        # The multi-token-prediction module's block is one more layer
+        cache = {
+            "ckv": jnp.zeros(
+                (c.cache_layers, batch_size, max_len, _latent_row(c)), c.dtype
+            ),
+            "mla_keys": jnp.zeros((c.cache_layers, 2, 2), jnp.int32),
+        }
+    elif c.latent:
         lead = (c.num_layers, batch_size, max_len)
         cache = {
             "ckv": jnp.zeros((*lead, _latent_row(c)), c.dtype),
@@ -770,7 +838,7 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             "v": jnp.zeros(shape, c.dtype),
         }
     if c.num_experts:
-        layers = c.num_layers - c.first_dense_layers
+        layers = c.cache_layers - c.first_dense_layers
         cache["moe_expert_tokens"] = jnp.zeros(
             (layers, c.experts_here), jnp.int32
         )
@@ -811,25 +879,43 @@ def wide_total(total) -> int:
     return int(t[:, 0].sum()) * _WIDE + int(t[:, 1].sum())
 
 
-def _with_counts(cache: Params, state: Params, aux: Params, step: bool) -> Params:
+def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
+                 first: int = 0) -> Params:
     """The cache after one call: the new state and the running totals
     plus this call's ``aux`` (the layer loop's stacked outputs): an
     expert config's (expert layers, experts held) rows per expert, a
     latent config's (L, 2) keys visible and selected, of a single-token
-    step (L, 3): and latent rows read."""
+    step (L, 3): and latent rows read; without an indexer (L, 2) keys
+    visible and rows read, of steps only.  ``first``: the cache layer
+    ``aux`` starts at, where it covers a part of the layers (the model
+    without its multi-token-prediction module, or that module alone)."""
     out = dict(cache, **state)
     if "expert_rows" in aux:
         rows = aux["expert_rows"]
-        out["moe_expert_tokens"] = cache["moe_expert_tokens"] + rows
-        out["moe_experts_touched"] = cache["moe_experts_touched"] + (
-            rows > 0
-        ).sum(-1, dtype=jnp.int32)
-        out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
+        if rows.shape[0] == cache["moe_layer_steps"].shape[0]:
+            out["moe_expert_tokens"] = cache["moe_expert_tokens"] + rows
+            out["moe_experts_touched"] = cache["moe_experts_touched"] + (
+                rows > 0
+            ).sum(-1, dtype=jnp.int32)
+            out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
+        else:  # a part of the expert layers: the model's, or (behind
+            # them) the multi-token-prediction module's
+            touched = (rows > 0).sum(-1, dtype=jnp.int32)
+            at = cache["moe_layer_steps"].shape[0] - rows.shape[0] if first else 0
+            part = slice(at, at + rows.shape[0])
+            out["moe_expert_tokens"] = cache["moe_expert_tokens"].at[part].add(rows)
+            out["moe_experts_touched"] = cache["moe_experts_touched"].at[part].add(touched)
+            out["moe_layer_steps"] = cache["moe_layer_steps"].at[part].add(1)
     if "dsa_keys" in aux:
         kind = int(step)  # runs at [:, :, 0], single-token steps at [:, :, 1]
         n = aux["dsa_keys"].shape[-1]  # a run counts no keys read
         out["dsa_keys"] = cache["dsa_keys"].at[:, :n, kind].set(
             _add_wide(cache["dsa_keys"][:, :n, kind], aux["dsa_keys"])
+        )
+    if "mla_keys" in aux:
+        part = slice(first, first + aux["mla_keys"].shape[0])
+        out["mla_keys"] = cache["mla_keys"].at[part].set(
+            _add_wide(cache["mla_keys"][part], aux["mla_keys"])
         )
     return out
 
@@ -1073,24 +1159,51 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
         k_rope = _rope_pairs(kv[:, :, None, C:], positions, c.rope_theta)[:, :, 0]
         fill = jnp.zeros((R, Sq, _latent_row(c) - C - Dr), dt)
         new_ckv = jnp.concatenate([c_kv.astype(dt), k_rope.astype(dt), fill], axis=-1)
-    with jax.named_scope("dsa_index"):
-        def turned(x):  # the first Dr of the last dim rotated, (R, Sq, J, Di)
-            return jnp.concatenate(
-                [_rope_pairs(x[..., :Dr], positions, c.rope_theta), x[..., Dr:]],
-                axis=-1,
-            )
-
-        qi = turned(jnp.einsum("rsq,qjd->rsjd", c_q, p["w_iq"].astype(dt)))
-        ki = _layernorm(
-            jnp.einsum("rse,ed->rsd", h, p["w_ik"].astype(dt)),
-            p["ik_norm"], p["ik_bias"], _INDEX_NORM_EPS,
-        )
-        ki = turned(ki[:, :, None])[:, :, 0].astype(dt)
-        wi = jnp.einsum(
-            "rse,ej->rsj", h, p["w_iw"].astype(dt),
-            preferred_element_type=jnp.float32,
-        )
     aux = {}
+
+    if slot is None and not K:  # ---- Sq tokens for every row, no indexer
+        ckv = state["ckv"]
+        T = ckv.shape[2]
+        rows = jnp.arange(R)
+        with jax.named_scope("mla_attn"):
+            ckv = ckv.at[layer, rows[:, None], positions].set(new_ckv)
+            q_lat = jnp.einsum("rshn,chn->rshc", q_nope, p["w_kb"].astype(dt))
+            qq = jnp.concatenate(
+                [q_lat, q_rope, jnp.zeros((R, Sq, c.num_heads, fill.shape[-1]), dt)],
+                axis=-1,
+            )                                                   # (R, Sq, H, row)
+            if latent_decode_attention.implementation(T) == "streamed":
+                attend = latent_decode_attention.visible_decode_attention
+                read = latent_decode_attention.keys_read(positions[:, -1])
+            else:
+                attend = latent_decode_attention.dense_decode_attention
+                read = jnp.full((R,), T, jnp.int32)
+            mix = attend(qq, ckv, layer, positions, latent=C, scale=scale)
+            out = jnp.einsum("rshc,chv->rshv", mix, p["w_vb"].astype(dt))
+        # a row's queries see prefixes of what its last one sees
+        aux["mla_keys"] = jnp.stack([
+            (positions[:, -1] + 1).sum(dtype=jnp.int32), read.sum(dtype=jnp.int32)
+        ])
+        return out, {"ckv": ckv}, aux
+
+    if K:
+        with jax.named_scope("dsa_index"):
+            def turned(x):  # the first Dr of the last dim rotated, (R, Sq, J, Di)
+                return jnp.concatenate(
+                    [_rope_pairs(x[..., :Dr], positions, c.rope_theta), x[..., Dr:]],
+                    axis=-1,
+                )
+
+            qi = turned(jnp.einsum("rsq,qjd->rsjd", c_q, p["w_iq"].astype(dt)))
+            ki = _layernorm(
+                jnp.einsum("rse,ed->rsd", h, p["w_ik"].astype(dt)),
+                p["ik_norm"], p["ik_bias"], _INDEX_NORM_EPS,
+            )
+            ki = turned(ki[:, :, None])[:, :, 0].astype(dt)
+            wi = jnp.einsum(
+                "rse,ej->rsj", h, p["w_iw"].astype(dt),
+                preferred_element_type=jnp.float32,
+            )
 
     if slot is None and Sq == 1:  # ---- one token for every row
         ckv, ik = state["ckv"], state["ik"]
@@ -1149,7 +1262,9 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     # through the loop with the POSITIONS minor-most (the layout the
     # projections' outputs have) and copies it whole, in and out, every
     # call (3 GB; compile-only, PR 30).
-    aux["ckv_rows"], aux["ik_rows"] = new_ckv[0], ki[0]
+    aux["ckv_rows"] = new_ckv[0]
+    if K:
+        aux["ik_rows"] = ki[0]
     with jax.named_scope("mla_proj"):
         lat = new_ckv[0, :, :C]
         k_nope = jnp.einsum("sc,chn->shn", lat, p["w_kb"].astype(dt))
@@ -1175,15 +1290,20 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
         real = blocked(jnp.ones((Sq,), bool))
         # the pad's queries see every key
         when = jnp.where(real, blocked(positions[0]), n_keys)
-        kb, vb, kib = keys[:n_keys], values[:n_keys], ki[0, :n_keys]
+        kb, vb = keys[:n_keys], values[:n_keys]
+        kib = ki[0, :n_keys] if K else None
 
         def block(args):
-            qb, qib, wib, tb, rb = args
-            with jax.named_scope("dsa_index"):
-                seen = jnp.arange(n_keys)[None, :] <= tb[:, None]   # (blk, keys)
-                scores = jnp.where(seen, _index_scores(qib, wib, kib), -jnp.inf)
-            with jax.named_scope("dsa_select"):
-                chosen = _select_mask(scores, K)
+            if K:
+                qb, qib, wib, tb, rb = args
+                with jax.named_scope("dsa_index"):
+                    seen = jnp.arange(n_keys)[None, :] <= tb[:, None]   # (blk, keys)
+                    scores = jnp.where(seen, _index_scores(qib, wib, kib), -jnp.inf)
+                with jax.named_scope("dsa_select"):
+                    chosen = _select_mask(scores, K)
+            else:  # no indexer: every visible key
+                qb, tb, rb = args
+                chosen = seen = jnp.arange(n_keys)[None, :] <= tb[:, None]
             with jax.named_scope("mla_attn"):
                 att = jnp.einsum(
                     "qhd,shd->hqs", qb, kb, preferred_element_type=jnp.float32
@@ -1197,9 +1317,10 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
             ])
             return ob, count, (chosen if collect else None)
 
-        ob, count, chosen = lax.map(
-            block, (blocked(qq), blocked(qi[0]), blocked(wi[0]), when, real)
-        )
+        args = (blocked(qq),)
+        if K:
+            args += (blocked(qi[0]), blocked(wi[0]))
+        ob, count, chosen = lax.map(block, (*args, when, real))
         if collect:
             chosen = jnp.pad(
                 chosen.reshape(-1, n_keys)[:per], ((0, 0), (0, Sq - n_keys))
@@ -1210,7 +1331,8 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     # queries is given the first g + 1 runs of keys only, which leaves
     # out 3/8 of a masked-everywhere attention's work at four groups
     parts = [attend(g * per, (g + 1) * per) for g in range(groups)]
-    aux["dsa_keys"] = sum(part[1] for part in parts)
+    if K:
+        aux["dsa_keys"] = sum(part[1] for part in parts)
     if collect:
         aux["selected"] = jnp.concatenate([part[2] for part in parts])[None]
     out = jnp.concatenate([part[0] for part in parts])[None]
@@ -1268,15 +1390,28 @@ def _block_step(x, p, state, slot, positions, config: LlamaConfig,
     return x + y, state, aux
 
 
+def _logits(params: Params, x, config: LlamaConfig):
+    """Final-normed hidden states (..., E) -> logits (..., V) float32."""
+    return jnp.einsum(
+        "...e,ve->...v", x, _head_weight(params, config).astype(config.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _cached_step(params: Params, tokens, cache: Params, slot, start,
-                 config: LlamaConfig, collect: bool = False):
+                 config: LlamaConfig, collect: bool = False,
+                 hidden: bool = False):
     """The ONE cached step: a contiguous run of Sq new tokens per row,
     each row's starting at its own offset, through all layers.
 
     tokens: (R, Sq); start: (R,) absolute position of tokens[:, 0];
     slot: None when the R rows are all B rows of the cache, else the
     (traced) index of the one row addressed (R = 1).  Returns
-    (last-token logits (R, V) f32, new cache).  The cache rides the
+    (last-token logits (R, V) f32, new cache) — with ``hidden`` the
+    final-normed hidden state of EVERY new token (R, Sq, E) in the
+    logits' place, for a caller that wants more positions' logits
+    (``_logits``) or feeds the multi-token-prediction module
+    (``models/mtp.py``).  The cache rides the
     layer loop whole, as its carry: under a jit that donates it every
     layer writes the new tokens' state in place and no slab is copied.
     A config with leading dense blocks loops over its two parameter
@@ -1286,7 +1421,9 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
     c = config
     positions = start[:, None] + jnp.arange(tokens.shape[1])
     x = params["tok_embed"].astype(c.dtype)[tokens]
-    step = slot is None and tokens.shape[1] == 1
+    # every row steps (one token each, or the few of a speculative
+    # step's verification) | one row's run from position 0
+    step = slot is None and (tokens.shape[1] == 1 or c.latent)
     state = {k: cache[k] for k in _STATE if k in cache}
     # a latent config's run reads no cache: its state stays out of the
     # loop and gets the run's rows after it (``_latent_attention``)
@@ -1313,12 +1450,7 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
                 state[k], aux.pop(k + "_rows")[:, None], (0, slot, 0, 0)
             )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
-    logits = jnp.einsum(
-        "be,ve->bv",
-        x[:, -1, :],
-        _head_weight(params, c).astype(c.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = x if hidden else _logits(params, x[:, -1, :], c)
     cache = _with_counts(cache, state, aux, step)
     return (logits, cache, aux) if collect else (logits, cache)
 
@@ -1395,13 +1527,47 @@ def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
     )
 
 
+#: what a draw is for, the last word of its key (``draw_keys``)
+DRAW_TOKEN, DRAW_DRAFT, DRAW_ACCEPT, DRAW_RESIDUAL = 0, 1, 2, 3
+
+
+def draw_keys(key, request, position, purpose: int):
+    """One key a row: ``fold_in(fold_in(fold_in(key, request), position),
+    purpose)``.  ``key``: the deployment's (``jax.random.key(seed)``);
+    ``request`` (B,): the request each row serves; ``position`` (B,): the
+    position of the token the draw decides; ``purpose``: ``DRAW_TOKEN`` a
+    token from the model's own distribution, ``DRAW_DRAFT`` the draft for
+    that position, ``DRAW_ACCEPT`` the uniform number that accepts it or
+    not, ``DRAW_RESIDUAL`` the token in its place where it is rejected.
+    So a token's draw hangs on nothing but its request and its position:
+    not on which rows share its step, nor on how many steps it took to get
+    there, and a reference can replay it."""
+    def one(r, p):
+        k = jax.random.fold_in(jax.random.fold_in(key, r), p)
+        return jax.random.fold_in(k, purpose)
+
+    return jax.vmap(one)(request, position)
+
+
 @partial(jax.jit, static_argnames=("temperature",))
 def _pick_token(logits, key, *, temperature):
+    """The ONE sampler: ``argmax`` at temperature 0, else a categorical
+    draw from ``softmax(logits / temperature)`` — with one key for the
+    whole batch, or one a row (a typed key array, ``draw_keys``)."""
     if temperature > 0.0:
-        return jax.random.categorical(key, logits / temperature).astype(
-            jnp.int32
-        )
+        draw = jax.random.categorical
+        if jnp.issubdtype(key.dtype, jax.dtypes.prng_key) and key.ndim:
+            draw = jax.vmap(draw)
+        return draw(key, logits / temperature).astype(jnp.int32)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("temperature",))
+def sample_rows(logits, key, request, position, *, temperature):
+    """logits (B, V) -> (B,) int32: row b's token for ``position[b]`` of
+    ``request[b]``, drawn with its own key (``draw_keys``)."""
+    keys = draw_keys(key, request, position, DRAW_TOKEN)
+    return _pick_token(logits, keys, temperature=temperature)
 
 
 def generate_kv(params: Params, prompt, config: LlamaConfig, *,

@@ -34,6 +34,18 @@ one, so 2,048 of 7,000 stream and 2,048 of 128k gather.  The code sees the
 cache's length, an upper bound of what is visible, in a shape
 (``MAX_STREAMED_KEYS``; the measurements are in PERF.md section 6, PR 31).
 
+A config WITHOUT an indexer attends to every visible key, and a
+speculative step brings SEVERAL queries a row (``visible_decode_attention``:
+the draft's verification has two, at ``pos`` and ``pos + 1``).  The same
+streamed body, kernel ``latent_verify``: a row's Q queries are Q x H query
+rows of one grid step, each with its own visibility ``t <= visible[r, j]``
+made from an iota in the kernel — no mask array is built or read — so a
+row's blocks stream through fast memory ONCE for all its queries.
+``keys_read`` is then a function of the row's LAST query's position: the
+whole blocks up to the one that holds ``visible[r, -1]``, fetched once, not
+once a query.  A cache that is no whole number of blocks (tier-1's tiny
+ones) takes ``dense_decode_attention``, plain XLA over the layer's slab.
+
 Off the chip the kernel runs in Pallas interpret mode (``_interpret`` of
 ``ops/flash_attention.py``, as its kernels do), so the tests run the very kernel.
 """
@@ -81,9 +93,46 @@ def implementation(cache_len: int) -> str:
 
 
 def keys_read(pos):
-    """(R,) int32: latent rows the streamed body fetches for a row at
-    ``pos``: the whole blocks up to the one that holds ``pos``."""
+    """(R,) int32: latent rows the streamed body fetches for a row whose
+    newest position — its LAST query's, where it has several — is ``pos``:
+    the whole blocks up to the one that holds ``pos``, once for all the
+    row's queries."""
     return (pos // BLOCK_KEYS + 1) * BLOCK_KEYS
+
+
+def _init(j, m_ref, l_ref, acc_ref):
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _accumulate(qq_ref, ckv_ref, keep, m_ref, l_ref, acc_ref, scale, latent):
+    """One block of keys into the running max / sum / accumulator.
+    ``keep``: what of the (query rows, block) scores counts."""
+    rows = ckv_ref[...]                                     # (block, W)
+    s = lax.dot_general(
+        qq_ref[...], rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                                               # (H, block)
+    s = jnp.where(keep(s.shape), s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
+        p.astype(rows.dtype), rows[:, :latent], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _finish(j, mix_ref, l_ref, acc_ref):
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        mix_ref[...] = (acc_ref[...] / l_ref[...]).astype(mix_ref.dtype)
 
 
 def _kernel(layer_ref, pos_ref, qq_ref, ckv_ref, mask_ref, mix_ref,
@@ -92,36 +141,40 @@ def _kernel(layer_ref, pos_ref, qq_ref, ckv_ref, mask_ref, mix_ref,
     (block, W), mask (1, block) int32, mix (H, latent)."""
     del layer_ref
     r, j = pl.program_id(0), pl.program_id(1)
-    block = ckv_ref.shape[0]
+    _init(j, m_ref, l_ref, acc_ref)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block <= pos_ref[r])
+    @pl.when(j * ckv_ref.shape[0] <= pos_ref[r])
     def _block():
-        rows = ckv_ref[...]                                     # (block, W)
-        s = lax.dot_general(
-            qq_ref[...], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                               # (H, block)
-        s = jnp.where(mask_ref[...] != 0, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p.astype(rows.dtype), rows[:, :latent], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _accumulate(qq_ref, ckv_ref, lambda shape: mask_ref[...] != 0,
+                    m_ref, l_ref, acc_ref, scale, latent)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        mix_ref[...] = (acc_ref[...] / l_ref[...]).astype(mix_ref.dtype)
+    _finish(j, mix_ref, l_ref, acc_ref)
+
+
+def _visible_kernel(layer_ref, visible_ref, qq_ref, ckv_ref, mix_ref,
+                    m_ref, l_ref, acc_ref, *, scale, latent, queries):
+    """As ``_kernel`` with qq (Q x H, W) — query j's heads are rows [j H,
+    (j + 1) H) — and no mask operand: query j of row r sees the keys t <=
+    ``visible_ref[r * Q + j]``, non-decreasing in j."""
+    del layer_ref
+    r, j = pl.program_id(0), pl.program_id(1)
+    block = ckv_ref.shape[0]
+    heads = qq_ref.shape[0] // queries
+    _init(j, m_ref, l_ref, acc_ref)
+
+    def keep(shape):
+        t = j * block + lax.broadcasted_iota(jnp.int32, shape, 1)
+        row = lax.broadcasted_iota(jnp.int32, shape, 0)
+        limit = jnp.full(shape, visible_ref[r * queries], jnp.int32)
+        for k in range(1, queries):
+            limit = jnp.where(row >= k * heads, visible_ref[r * queries + k], limit)
+        return t <= limit
+
+    @pl.when(j * block <= visible_ref[r * queries + queries - 1])
+    def _block():
+        _accumulate(qq_ref, ckv_ref, keep, m_ref, l_ref, acc_ref, scale, latent)
+
+    _finish(j, mix_ref, l_ref, acc_ref)
 
 
 def latent_decode_attention(qq, ckv, layer, pos, chosen_mask, *, latent: int,
@@ -196,3 +249,72 @@ def gathered_decode_attention(qq, ckv, layer, chosen, valid, *, latent: int,
     att = jnp.where(valid[:, None, :], att, NEG_INF)
     probs = jax.nn.softmax(att, axis=-1).astype(ckv.dtype)
     return jnp.einsum("rhk,rkc->rhc", probs, picked[..., :latent])
+
+
+def visible_decode_attention(qq, ckv, layer, visible, *, latent: int, scale: float):
+    """The streamed body over EVERY visible key, Q queries a row.  qq (R,
+    Q, H, W), ckv (L, R, T, W) whole, layer () int32, visible (R, Q) int32
+    >= 0, non-decreasing along Q: query j of row r attends to the keys t <=
+    visible[r, j] -> mix (R, Q, H, latent) in ``ckv``'s dtype.  T is a whole
+    number of ``BLOCK_KEYS``.  A row's blocks up to the one that holds
+    ``visible[r, -1]`` are fetched once for all Q queries."""
+    R, Q, H, W = qq.shape
+    _, B, T, _ = ckv.shape
+    block = BLOCK_KEYS
+    if B != R or T % block or visible.shape != (R, Q):
+        raise ValueError(
+            f"streamed latent attention wants one cache row a query row and "
+            f"whole blocks of {block} keys: qq {qq.shape}, ckv {ckv.shape}, "
+            f"visible {visible.shape}"
+        )
+
+    def last(j, r, vis_ref):  # the block that holds the row's last visible key
+        return jnp.minimum(j, vis_ref[r * Q + Q - 1] // block)
+
+    mix = pl.pallas_call(
+        functools.partial(_visible_kernel, scale=scale, latent=latent, queries=Q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R, T // block),
+            in_specs=[
+                pl.BlockSpec((None, Q * H, W), lambda r, j, layer, vis: (r, 0, 0)),
+                pl.BlockSpec(
+                    (None, None, block, W),
+                    lambda r, j, layer, vis: (layer[0], r, last(j, r, vis), 0),
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, Q * H, latent), lambda r, j, layer, vis: (r, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((Q * H, 1), jnp.float32),
+                pltpu.VMEM((Q * H, 1), jnp.float32),
+                pltpu.VMEM((Q * H, latent), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, Q * H, latent), ckv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=_interpret(),
+        name="latent_verify",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        visible.astype(jnp.int32).reshape(R * Q),
+        qq.reshape(R, Q * H, W),
+        ckv,
+    )
+    return mix.reshape(R, Q, H, latent)
+
+
+def dense_decode_attention(qq, ckv, layer, visible, *, latent: int, scale: float):
+    """``visible_decode_attention`` in plain XLA over the layer's whole
+    slab, for a cache that is no whole number of blocks."""
+    slab = lax.dynamic_index_in_dim(ckv, layer, 0, keepdims=False)  # (R, T, W)
+    att = jnp.einsum(
+        "rqhc,rtc->rqht", qq, slab, preferred_element_type=jnp.float32
+    ) * scale
+    seen = jnp.arange(slab.shape[1])[None, None, :] <= visible[:, :, None]
+    att = jnp.where(seen[:, :, None, :], att, NEG_INF)
+    probs = jax.nn.softmax(att, axis=-1).astype(ckv.dtype)
+    return jnp.einsum("rqht,rtc->rqhc", probs, slab[..., :latent])

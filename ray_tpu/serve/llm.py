@@ -9,6 +9,17 @@ XLA call over all slots (`llama.decode_step_rowwise`, per-row
 positions), and tokens stream back per request over the core
 streaming-generator transport.
 
+A step yields one token a row — or, where the deployment drafts
+(``speculative_tokens=1``, a model with a multi-token-prediction module:
+``models/mtp.py``), one or two: the module drafts a token, the main model
+verifies it in the same step, and the row advances by two where the draft
+is accepted.  ``temperature`` 0 decodes greedily; above 0 every token is
+drawn from the main model's softmax at that temperature, with draws that
+hang on ``seed``, the request and the position only.  Whatever the
+options, a request gets exactly ``max_new_tokens`` ids, in order, one
+stream item each, every one distributed as the main model's: drafting
+changes how many steps they take, not what they are.
+
 Wire-up::
 
     import ray_tpu
@@ -70,6 +81,11 @@ _MOE_HELD_SHARE = metrics.Gauge(
     "llm_moe_held_assignment_share",
     "routed (token, expert) assignments that fell on an expert held here",
 )
+#: drafting deployments only, set where a step's tokens are delivered
+_SPEC_ACCEPTANCE = metrics.Gauge(
+    "llm_spec_acceptance_rate",
+    "drafted tokens the main model accepted over tokens drafted, so far",
+)
 _OFF = contextlib.nullcontext()
 
 
@@ -84,28 +100,33 @@ def _part(parent: Optional[tracing.Span], name: str):
 
 
 class _Request:
-    __slots__ = ("prompt", "max_new", "queue", "pushed", "trace_id")
+    __slots__ = ("prompt", "max_new", "queue", "pushed", "trace_id", "number")
 
-    def __init__(self, prompt, max_new, queue, trace_id):
+    def __init__(self, prompt, max_new, queue, trace_id, number):
         self.prompt = prompt
         self.max_new = max_new
         self.queue = queue          # per-request token queue
         self.pushed = time.monotonic()
         self.trace_id = trace_id    # of its llm.request span, if traced
+        self.number = number        # in order of arrival: keys its draws
 
 
 class _Slot:
     """What the host knows of a cache row when it launches a step.  The
     row's token is not here: it is row i of ``LLMEngine._tokens``, the
-    last launched step's argmax, which stays on the device."""
+    last launched step's choice, which stays on the device.  Where the
+    deployment drafts, the row's position stays there too (a step advances
+    it by one or two, ``LLMEngine._spec``), and the host counts what it has
+    delivered: ``remaining`` is then tokens still to DELIVER."""
 
-    __slots__ = ("queue", "pos", "remaining", "max_pos")
+    __slots__ = ("queue", "pos", "remaining", "max_pos", "request")
 
-    def __init__(self, queue, pos, remaining, max_pos):
+    def __init__(self, queue, pos, remaining, max_pos, request):
         self.queue = queue          # per-request token queue
         self.pos = pos              # position of the token the next step feeds
         self.remaining = remaining  # decode steps still to launch
         self.max_pos = max_pos
+        self.request = request      # the request's number (its draws' key)
 
 
 class _Step:
@@ -114,8 +135,12 @@ class _Step:
     __slots__ = ("tokens", "rows", "span")
 
     def __init__(self, tokens, rows, span):
-        self.tokens = tokens  # (max_slots,) int32 argmax, on the device
-        self.rows = rows      # [(row, queue, the request's last token?)]
+        # (max_slots,) int32 on the device, the step's choice a row; of a
+        # drafting step (max_slots, 4): two tokens, how many count, accepted?
+        self.tokens = tokens
+        # [(row, its _Slot, the request's last token?)]; of a drafting step
+        # [(row, its _Slot)]: which token is the last is not known yet
+        self.rows = rows
         self.span = span      # its llm.step span, if traced
 
 
@@ -127,10 +152,17 @@ class LLMEngine:
     with one decode step in flight ahead of the one being delivered.
 
     The host never needs a token to schedule the next step: step k+1 is
-    fed step k's argmax as it is, a device array (``_tokens``; a prefill's
+    fed step k's choice as it is, a device array (``_tokens``; a prefill's
     first token is merged into it on the device), a row's position is its
     last plus one, and a row ends by counts the host holds (no stop
-    token).  So the loop launches step k+1, then waits for step k's
+    token).  Where the deployment drafts, a row's position and what it
+    still has to emit are device arrays too (``_spec``: a step advances a
+    row by one or two and only the device knows which), the host learns how
+    many tokens a step gave a row when it delivers them — one step late —
+    and a row is retired by what has been DELIVERED: the step launched
+    meanwhile steps that row once more, into its own slot, for nothing
+    (``spec_wasted_row_steps_total``).  So the loop launches step k+1,
+    then waits for step k's
     tokens, hands them out and yields to their consumers while the device
     computes.  Only an admission drains the pipeline: the prefill is
     launched behind the step in flight, that step's tokens are delivered,
@@ -139,7 +171,9 @@ class LLMEngine:
     row, not two."""
 
     def __init__(self, params, config, *, max_slots: int = 4,
-                 max_len: int = 256, max_prompt_len: Optional[int] = None):
+                 max_len: int = 256, max_prompt_len: Optional[int] = None,
+                 speculative_tokens: int = 0, temperature: float = 0.0,
+                 seed: int = 0):
         import jax.numpy as jnp
 
         from ray_tpu.models import llama
@@ -147,6 +181,27 @@ class LLMEngine:
         self._llama = llama
         self.params = params
         self.config = config
+        self.speculative = int(speculative_tokens)
+        self.temperature = float(temperature or 0.0)
+        if self.speculative not in (0, 1):
+            raise ValueError("speculative_tokens is 0 or 1: one drafted token a step")
+        if self.speculative and not config.mtp_layers:
+            raise ValueError(
+                "speculative_tokens=1 needs a model with a multi-token-prediction "
+                "module to draft with (LlamaConfig.mtp_layers)"
+            )
+        # the two programs: the model's own, or the drafting ones
+        self._programs = llama
+        self._spec = self._key = None
+        if self.speculative:
+            from ray_tpu.models import mtp
+
+            self._programs = mtp
+            self._spec = mtp.init_state(config, max_slots)
+        if self.speculative or self.temperature > 0.0:
+            import jax
+
+            self._key = jax.random.key(seed)
         self.max_slots = max_slots
         self.max_len = max_len
         # Sliding-window models with an explicit prompt cap get a
@@ -180,6 +235,7 @@ class LLMEngine:
         # before prefill
         self._pending: List[tuple] = []
         self._admit_seq = itertools.count()
+        self._request_seq = itertools.count()
         self._runner: Optional[asyncio.Task] = None
         self._wake = asyncio.Event()
         # admitter counters (bench / tests)
@@ -191,6 +247,14 @@ class LLMEngine:
         self.decode_steps_total = 0
         # of those, launched while the one before had not been synced
         self.steps_launched_ahead_total = 0
+        # drafting deployments: live rows a step drafted for, drafts the
+        # main model accepted, tokens delivered from decode steps, and rows
+        # stepped once more after their budget was met (the host hears of
+        # it a step late)
+        self.spec_drafted_total = 0
+        self.spec_accepted_total = 0
+        self.spec_tokens_emitted_total = 0
+        self.spec_wasted_row_steps_total = 0
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -221,7 +285,8 @@ class LLMEngine:
             deadline if deadline is not None else float("inf"),
             next(self._admit_seq),
             _Request(list(prompt), int(max_new_tokens), q,
-                     request.trace_id if request else None),
+                     request.trace_id if request else None,
+                     next(self._request_seq)),
         ))
         self._wake.set()
         try:
@@ -252,15 +317,18 @@ class LLMEngine:
         ``dsa_read_step``, latent rows the decode steps' attention
         fetched from the cache: the selected ones where they were
         gathered, every row of the blocks up to ``pos`` where they were
-        streamed (``ops/latent_decode_attention.py``).  Sets the gauges
-        of both."""
+        streamed (``ops/latent_decode_attention.py``).  Latent attention
+        without an indexer: ``mla_keys_visible_step`` (keys a step's
+        rows could see — a row's last query's, the others see prefixes —
+        over every layer and row) and ``mla_keys_read_step`` (latent rows
+        fetched for them: a row's blocks once for all its queries).  Sets the gauges of both."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
         from ray_tpu.ops import grouped_matmul
 
         out = {}
-        names = [k for k in self.cache if k.startswith(("moe_", "dsa_"))]
+        names = [k for k in self.cache if k.startswith(("moe_", "dsa_", "mla_"))]
         if not names:
             return out
         async with self._cache_lock:
@@ -272,7 +340,9 @@ class LLMEngine:
             if steps:
                 _MOE_TOUCHED_MEAN.set(touched / steps)
                 _MOE_LOAD_MAX_OVER_MEAN.set(float(tokens.max() / tokens.mean()))
-            routed = (self.rows_stepped_total * tokens.shape[0]
+            # a module that is not drafting is given no row
+            idle = 0 if self.speculative else self.config.mtp_layers
+            routed = (self.rows_stepped_total * (tokens.shape[0] - idle)
                       * self.config.experts_per_token)
             if self.config.experts_held and routed:
                 _MOE_HELD_SHARE.set(float(tokens.sum()) / routed)
@@ -296,6 +366,10 @@ class LLMEngine:
                     (dsa["dsa_selected_run"] + dsa["dsa_selected_step"]) / seen
                 )
             out.update(dsa)
+        if "mla_keys" in host:
+            keys = host["mla_keys"]       # (layers, visible|read, 2)
+            out["mla_keys_visible_step"] = wide_total(keys[:, 0])
+            out["mla_keys_read_step"] = wide_total(keys[:, 1])
         return out
 
     # -- engine loop -----------------------------------------------------
@@ -318,7 +392,7 @@ class LLMEngine:
                 # failure) and keep serving
                 live = {id(s.queue): s.queue for s in self.slots if s}
                 for step in self._flying:
-                    live.update((id(q), q) for _, q, _ in step.rows)
+                    live.update((id(r[1].queue), r[1].queue) for r in step.rows)
                 while self._pending:
                     q = heapq.heappop(self._pending)[2].queue
                     live[id(q)] = q
@@ -328,6 +402,10 @@ class LLMEngine:
                 self.slots = [None] * self.max_slots
                 self._flying.clear()
                 self._tokens = jnp.zeros((self.max_slots,), jnp.int32)
+                if self.speculative:
+                    self._spec = self._programs.init_state(
+                        self.config, self.max_slots
+                    )
                 self.cache = self._llama.init_cache(
                     self.config, self.max_slots, self.cache_len
                 )
@@ -393,15 +471,28 @@ class LLMEngine:
                 row = jnp.int32(slot)
 
                 def _prefill():
-                    return llama.prefill_into_slot(
+                    if self.speculative:
+                        return self._programs.prefill_into_slot(
+                            self.params, toks, self.cache, row, self._spec,
+                            self._key, jnp.int32(req.number),
+                            jnp.int32(max_new), cfg, self.temperature,
+                        )[:3]
+                    logits, cache = llama.prefill_into_slot(
                         self.params, toks, self.cache, row, cfg,
                     )
+                    if self.temperature > 0.0:
+                        return llama.sample_rows(
+                            logits, self._key, jnp.asarray([req.number]),
+                            jnp.asarray([S0]), temperature=self.temperature,
+                        )[0], cache
+                    return jnp.argmax(logits[0]), cache
 
                 async with self._cache_lock:
-                    logits, self.cache = await asyncio.to_thread(_prefill)
+                    first, self.cache, *spec = await asyncio.to_thread(_prefill)
                     self.rows_stepped_total += S0
-                first = jnp.argmax(logits[0])
-                if max_new > 1:
+                if spec:  # the row's first token is in its state
+                    self._spec, = spec
+                elif max_new > 1:
                     self._tokens = llama.set_row(self._tokens, row, first)
                 if self._flying:
                     await self._deliver()
@@ -413,7 +504,7 @@ class LLMEngine:
                 continue
             self.slots[slot] = _Slot(
                 queue=q, pos=S0, remaining=max_new - 1,
-                max_pos=self.max_len - 1,
+                max_pos=self.max_len - 1, request=req.number,
             )
         return prefilled
 
@@ -428,10 +519,16 @@ class LLMEngine:
 
         llama = self._llama
         cfg = self.config
+        if self.speculative:
+            await self._launch_drafting(life, active)
+            return
+        sampled = self.temperature > 0.0
         with _part(life, "llm.step.build"):
             pos = np.zeros((self.max_slots,), np.int32)
+            request = np.zeros((self.max_slots,), np.int32)
             for i in active:
                 pos[i] = self.slots[i].pos
+                request[i] = self.slots[i].request
         with _part(life, "llm.step.dispatch") as dispatch:
 
             def _step(tokens=self._tokens):
@@ -442,6 +539,11 @@ class LLMEngine:
                     logits, cache = llama.decode_step_rowwise(
                         self.params, tokens, self.cache, p, cfg,
                     )
+                    if sampled:
+                        return llama.sample_rows(
+                            logits, self._key, jnp.asarray(request), p + 1,
+                            temperature=self.temperature,
+                        ), cache
                     return jnp.argmax(logits, axis=-1), cache
 
             async with self._cache_lock:
@@ -459,10 +561,71 @@ class LLMEngine:
                 s.pos += 1
                 s.remaining -= 1
                 last = s.remaining <= 0 or s.pos >= s.max_pos
-                rows.append((i, s.queue, last))
+                rows.append((i, s, last))
                 if last:
                     self.slots[i] = None
             self._flying.append(_Step(self._tokens, rows, life))
+
+    async def _launch_drafting(self, life: Optional[tracing.Span],
+                               active: List[int]) -> None:
+        """``_launch`` where the deployment drafts: one speculative step
+        over all slots on the rows' state the last one left on the device
+        (positions, tokens, the module's inputs, what each has left to
+        emit).  The host builds no array and waits for nothing; its
+        ``llm.step.build`` is the list of the rows it will hand tokens to
+        (a step's life keeps its five parts: PERF.md section 3)."""
+        with _part(life, "llm.step.build"):
+            # which rows end with this step only its tokens will tell
+            rows = [(i, self.slots[i]) for i in active]
+        with _part(life, "llm.step.dispatch") as dispatch:
+
+            def _step(state=self._spec):
+                with _part(dispatch, "llm.step.launch"):
+                    return self._programs.decode_step_rowwise(
+                        self.params, state, self.cache, self._key,
+                        self.config, self.temperature,
+                    )[:3]
+
+            async with self._cache_lock:
+                outs, self._spec, self.cache = await asyncio.to_thread(_step)
+                # two token rows a slot, in the model and in the module
+                self.rows_stepped_total += 2 * self.max_slots
+            self.decode_steps_total += 1
+            if self._flying:
+                self.steps_launched_ahead_total += 1
+            if life is not None:
+                life.attrs["drafted"] = len(active)
+            self._flying.append(_Step(outs, rows, life))
+
+    async def _hand_out(self, step: _Step, outs) -> None:
+        """A drafting step's tokens to their queues: one or two a row, in
+        order, never one past the request's budget; a row whose budget is
+        met is retired HERE, a step later than its last token was made.
+        ``outs`` (max_slots, 4): two tokens, how many count, accepted?"""
+        emitted = accepted = 0
+        for i, slot in step.rows:
+            if slot.remaining <= 0:
+                # retired when the step before was delivered; this one
+                # had been launched by then
+                self.spec_wasted_row_steps_total += 1
+                continue
+            n = min(int(outs[i, 2]), slot.remaining)
+            for tok in outs[i, :n]:
+                await slot.queue.put(int(tok))
+            slot.remaining -= n
+            emitted += n
+            accepted += int(outs[i, 3])
+            self.spec_drafted_total += 1
+            if slot.remaining <= 0:
+                await slot.queue.put(_END)
+                if self.slots[i] is slot:
+                    self.slots[i] = None
+        self.spec_tokens_emitted_total += emitted
+        self.spec_accepted_total += accepted
+        if self.spec_drafted_total:
+            _SPEC_ACCEPTANCE.set(self.spec_accepted_total / self.spec_drafted_total)
+        if step.span is not None:
+            step.span.attrs.update(emitted=emitted, accepted=accepted)
 
     async def _deliver(self) -> None:
         """Wait for the oldest step in flight, put its tokens on their
@@ -474,10 +637,13 @@ class LLMEngine:
             nxt = np.asarray(step.tokens)
         self._flying.popleft()
         with _part(step.span, "llm.step.deliver"):
-            for i, q, last in step.rows:
-                await q.put(int(nxt[i]))
-                if last:
-                    await q.put(_END)
+            if self.speculative:
+                await self._hand_out(step, nxt)
+            else:
+                for i, slot, last in step.rows:
+                    await slot.queue.put(int(nxt[i]))
+                    if last:
+                        await slot.queue.put(_END)
         # consumers take their tokens and the transport sends them while
         # the device computes the step launched before this one's sync
         with _part(step.span, "llm.step.yield"):
@@ -524,11 +690,16 @@ class LLMEngine:
 @serve.deployment
 class LlamaDeployment:
     """Decode replica: tiny-config by default, or real weights via a
-    ``weights_ref`` (object-store ref) / ``weights_loader`` callable."""
+    ``weights_ref`` (object-store ref) / ``weights_loader`` callable.
+    ``temperature`` (0: greedy), ``speculative_tokens`` (0, or 1 with a
+    model that has a multi-token-prediction module) and ``seed`` (of the
+    default weights and of every draw) are the deployment's: see
+    ``LLMEngine``."""
 
     def __init__(self, config=None, weights_ref=None, weights_loader=None,
                  max_slots: int = 4, max_len: int = 256,
-                 max_prompt_len: Optional[int] = None, seed: int = 0):
+                 max_prompt_len: Optional[int] = None, seed: int = 0,
+                 speculative_tokens: int = 0, temperature: float = 0.0):
         import jax
 
         from ray_tpu.models import llama
@@ -547,6 +718,8 @@ class LlamaDeployment:
         self.engine = LLMEngine(
             params, self.config, max_slots=max_slots, max_len=max_len,
             max_prompt_len=max_prompt_len,
+            speculative_tokens=speculative_tokens, temperature=temperature,
+            seed=seed,
         )
 
     async def stats(self) -> dict:
@@ -576,8 +749,17 @@ class LlamaDeployment:
         ``dsa_selected_step`` where rows are gathered, the streamed
         blocks' rows otherwise), and the gauges
         ``llm_dsa_selected_share`` and (experts held here)
-        ``llm_moe_held_assignment_share``.  ``cache_bytes`` is what the
-        cache holds, by entry."""
+        ``llm_moe_held_assignment_share``.  Latent attention without an
+        indexer: ``mla_keys_visible_step`` / ``mla_keys_read_step``.  A
+        drafting deployment: ``spec_drafted_total`` (live rows its steps
+        drafted for), ``spec_accepted_total``, ``spec_tokens_emitted_total``
+        (tokens delivered from decode steps: 1 + accepted a live row-step,
+        less what fell past a budget), ``spec_wasted_row_steps_total`` (rows
+        stepped once more after their budget was met), and the gauge
+        ``llm_spec_acceptance_rate``; ``programs`` then counts the drafting
+        versions of the two programs (``models/mtp.py``).  ``cache_bytes``
+        is what the cache holds, by entry (the module's layer is one of
+        ``ckv``'s)."""
         import jax
 
         from ray_tpu.models import llama
@@ -586,6 +768,14 @@ class LlamaDeployment:
         dev = devices[0]
         mem = dev.memory_stats() or {}
         counters = await self.engine.cache_counters()
+        programs = self.engine._programs  # llama's, or the drafting ones
+        if self.engine.speculative:
+            counters.update({
+                k: getattr(self.engine, k) for k in (
+                    "spec_drafted_total", "spec_accepted_total",
+                    "spec_tokens_emitted_total", "spec_wasted_row_steps_total",
+                )
+            })
         return {
             **counters,
             # what the cache holds, entry by entry (``k``/``v``, or a
@@ -594,13 +784,15 @@ class LlamaDeployment:
                 k: int(v.size) * v.dtype.itemsize
                 for k, v in self.engine.cache.items()
             },
+            "max_slots": self.engine.max_slots,
+            "max_len": self.engine.max_len,
             "platform": dev.platform,
             "device_kind": dev.device_kind,
             "device_count": len(devices),
             "compiles": self._compiles.snapshot(),
             "programs": {
-                "prefill_into_slot": llama.prefill_into_slot._cache_size(),
-                "decode_step_rowwise": llama.decode_step_rowwise._cache_size(),
+                "prefill_into_slot": programs.prefill_into_slot._cache_size(),
+                "decode_step_rowwise": programs.decode_step_rowwise._cache_size(),
             },
             "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
             "admitted_total": self.engine.admitted_total,

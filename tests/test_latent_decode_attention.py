@@ -123,3 +123,84 @@ def test_a_cache_of_other_rows_or_ragged_blocks_is_refused(monkeypatch):
     with pytest.raises(ValueError, match="one cache row a query row"):
         lda.latent_decode_attention(
             qq[:2], ckv, jnp.int32(0), p[:2], mask[:2], latent=C, scale=SCALE)
+
+
+# ---- every visible key, several queries a row (``latent_verify``) ------------
+
+VERIFY_POSITIONS = {
+    "mixed_lengths": [3, 17, 38, 61],
+    "a_blocks_last_key": [15, 31, 47, 7],       # the second query opens a block
+    "the_next_blocks_first": [16, 32, 48, 8],
+    "the_caches_last_two": [T - 2] * R,
+    "an_idle_slot": [0, 40, 0, 22],
+}
+
+
+def verify_case(pos, dtype=jnp.bfloat16, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 2)
+    qq = jax.random.normal(ks[0], (R, 2, H, W), dtype)
+    ckv = jax.random.normal(ks[1], (L, R, T, W), dtype)
+    visible = jnp.asarray(pos, jnp.int32)[:, None] + jnp.arange(2)
+    return qq, ckv, visible
+
+
+@pytest.mark.parametrize("block", [8, 16])
+@pytest.mark.parametrize("pos", VERIFY_POSITIONS.values(), ids=VERIFY_POSITIONS.keys())
+def test_two_queries_a_row_equal_two_one_query_calls(monkeypatch, pos, block):
+    """The verify kernel — a row's two queries as 2 x H query rows, each
+    with its own visibility, the row's blocks streamed once — against the
+    same kernel called with one query a row, twice, and against plain XLA."""
+    monkeypatch.setattr(lda, "BLOCK_KEYS", block)
+    qq, ckv, visible = verify_case(pos)
+    kw = dict(latent=C, scale=SCALE)
+    both_at_once = lda.visible_decode_attention(qq, ckv, jnp.int32(1), visible, **kw)
+    one_by_one = jnp.stack([
+        lda.visible_decode_attention(qq[:, j:j + 1], ckv, jnp.int32(1), visible[:, j:j + 1], **kw)[:, 0]
+        for j in range(2)
+    ], axis=1)
+    # the same sums in the same order but for the matmuls' own (2 x H query
+    # rows in one, H in the other): one bfloat16 rounding at most
+    np.testing.assert_allclose(np.asarray(both_at_once, np.float32),
+                               np.asarray(one_by_one, np.float32), rtol=0, atol=0.004)
+    dense = lda.dense_decode_attention(qq, ckv, jnp.int32(1), visible, **kw)
+    np.testing.assert_allclose(np.asarray(both_at_once, np.float32),
+                               np.asarray(dense, np.float32), rtol=0, atol=0.02)
+    # one query a row with every visible key chosen IS the selection kernel
+    everything = jnp.arange(T)[None, :] <= visible[:, :1]
+    selected = lda.latent_decode_attention(
+        qq[:, 0], ckv, jnp.int32(1), visible[:, 0], everything, **kw)
+    np.testing.assert_allclose(np.asarray(selected, np.float32),
+                               np.asarray(both_at_once[:, 0], np.float32),
+                               rtol=0, atol=0.004)
+    assert np.abs(np.asarray(dense, np.float32)).max() > 0.5
+
+
+@pytest.mark.parametrize("block", [8, 16])
+def test_the_verify_kernel_reads_a_rows_blocks_up_to_its_last_query(monkeypatch, block):
+    """NaNs in every block wholly behind a row's LAST query and in the other
+    layer change nothing; ``keys_read`` goes by that query's position."""
+    monkeypatch.setattr(lda, "BLOCK_KEYS", block)
+    pos = VERIFY_POSITIONS["a_blocks_last_key"]
+    qq, ckv, visible = verify_case(pos, dtype=jnp.float32)
+    kw = dict(latent=C, scale=SCALE)
+    clean = lda.visible_decode_attention(qq, ckv, jnp.int32(1), visible, **kw)
+    last = visible[:, -1]
+    behind = (jnp.arange(T)[None, :] // block) > (last[:, None] // block)
+    poisoned = jnp.where(behind[None, :, :, None], jnp.nan, ckv).at[0].set(jnp.nan)
+    dirty = lda.visible_decode_attention(qq, poisoned, jnp.int32(1), visible, **kw)
+    assert np.array_equal(np.asarray(clean), np.asarray(dirty))
+    assert np.asarray(lda.keys_read(last)).tolist() == [
+        (int(p) // block + 1) * block for p in last]
+    dense = lda.dense_decode_attention(qq, ckv, jnp.int32(1), visible, **kw)
+    np.testing.assert_allclose(clean, dense, rtol=0, atol=2e-5)
+
+
+def test_the_verify_kernel_refuses_ragged_blocks_and_other_rows(monkeypatch):
+    qq, ckv, visible = verify_case(VERIFY_POSITIONS["mixed_lengths"])
+    monkeypatch.setattr(lda, "BLOCK_KEYS", 24)
+    with pytest.raises(ValueError, match="whole blocks"):
+        lda.visible_decode_attention(qq, ckv, jnp.int32(0), visible, latent=C, scale=SCALE)
+    monkeypatch.setattr(lda, "BLOCK_KEYS", 8)
+    with pytest.raises(ValueError, match="one cache row a query row"):
+        lda.visible_decode_attention(
+            qq[:2], ckv, jnp.int32(0), visible[:2], latent=C, scale=SCALE)

@@ -294,3 +294,81 @@ def test_latent_cache_is_never_copied_nor_expanded(
             assert made not in text, f"latent rows are copied out of the cache: {made}"
         for expanded in ("10240,64,192]", "10240,64,256]", "64,10240,192]", "64,10240,256]"):
             assert expanded not in text, f"keys or values expanded over the cache: {expanded}"
+
+
+JOYAI_FILE = os.path.join(
+    os.path.dirname(GLM_FILE), "joyai-llm-flash-ep32.json"
+)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_joyais_speculative_programs_fit_and_stream_a_rows_blocks_once(
+        v5e_chip, monkeypatch, program):
+    """JoyAI-LLM-Flash's two DRAFTING programs (``models/mtp.py``) at the
+    served shapes (32 slots x 4,096 positions, all 40 layers and the
+    module: 12.7 GB of weights and cache live of 16), compiled for the
+    chip: the 6.9 GB cache is written in place by the module's block and by
+    the 40 layers of the verification, never copied and never laid out
+    otherwise; a step's temporaries are megabytes; and each of the 41
+    attention calls of a step is ONE ``latent_verify`` kernel over both
+    queries of a row (2 x 32 heads as 64 query rows), never keys or values
+    of the cache's length."""
+    from chipbench.jobs.serve_mtp import joyai_config
+    from ray_tpu.models import mtp
+    from ray_tpu.ops import grouped_matmul, latent_decode_attention
+
+    monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    monkeypatch.setattr(latent_decode_attention, "_interpret", lambda: False)
+    with open(JOYAI_FILE) as f:
+        served = json.load(f)
+    config = joyai_config(served)
+    slots, max_len = served["serving"]["max_slots"], served["serving"]["max_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    state = on_chip(jax.eval_shape(functools.partial(mtp.init_state, config, slots)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert weights == 2 * 2_918_719_488          # 5.84 GB
+    held = cache["ckv"].size * 2
+    assert held == 41 * 32 * 4096 * 640 * 2      # 6.88 GB
+    if program == "decode":
+        compiled = mtp.decode_step_rowwise.lower(
+            params, state, cache, key, config, 1.0).compile()
+        limit = 64 * 2**20
+    else:
+        compiled = mtp.prefill_into_slot.lower(
+            params, jax.ShapeDtypeStruct((1, 1536), jnp.int32, sharding=v5e_chip),
+            cache, scalar, state, key, scalar, scalar, config, 1.0,
+        ).compile()
+        limit = 512 * 2**20
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held, mem          # written in place
+    assert mem.temp_size_in_bytes < limit, mem
+    assert weights + held + mem.temp_size_in_bytes < 14e9
+    text = compiled.as_text()
+    whole = "bf16[{}]".format(",".join(map(str, cache["ckv"].shape)))
+    assert not re.findall(rf"{re.escape(whole)}\S* copy\(", text), "the cache is copied"
+    assert "{2,3,1,0" not in "".join(re.findall(rf"{re.escape(whole)}\S*", text))
+    if program == "decode":
+        assert latent_decode_attention.implementation(max_len) == "streamed"
+        # the module's block and the two parameter stacks' loop bodies (the
+        # dense layer, the 39 expert layers): one kernel each, 64 query rows
+        # a grid step
+        calls = re.findall(r"%latent_verify\S* = (\S+) custom-call\(.*tpu_custom_call", text)
+        assert len(calls) == 3 and all(c.startswith("bf16[32,64,512]") for c in calls), calls
+        for made in ("bf16[32,4096,640]", "bf16[1,32,4096,640]"):
+            assert made not in text, f"a layer's slab is cut out of the cache: {made}"
+        for expanded in ("4096,32,192]", "4096,32,128]", "32,4096,192]", "32,4096,128]"):
+            assert expanded not in text, f"keys or values expanded over the cache: {expanded}"

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.jobs import serve_dsa
+from chipbench.jobs import serve_dsa, serve_mtp
 from chipbench.reference import errors
 from chipbench.reference import glm_dsa as ref
 from chipbench.reference.llama import FLOAT32_TOLERANCE
@@ -193,24 +193,31 @@ def test_selection_is_exact(scores, k, want):
         assert np.array_equal(picked, np.asarray(want, bool))
 
 
-def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer():
-    """16 chips, 2 of 32 experts each: the routed parts the 16 shares
-    compute, with the shared expert (which every chip computes alike)
-    counted once, add up to the uncut reference's layer."""
-    cfg = tiny(num_experts=32, experts_held=0, expert_offset=0, first_dense_layers=0)
-    params = weights(cfg, seed=2)
+@pytest.mark.parametrize("chips,held,spec_of,kw", [
+    (16, 2, serve_dsa.spec_of, {}),
+    # JoyAI-LLM-Flash's cut: 32 chips share a layer, no indexer beside it
+    (32, 2, serve_mtp.spec_of, dict(index_topk=0, index_n_heads=0, index_head_dim=0)),
+], ids=["glm5_16_chips", "joyai_32_chips"])
+def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer(chips, held, spec_of, kw):
+    """``chips`` chips, ``held`` of the experts each: the routed parts the
+    shares compute, with the shared expert (which every chip computes
+    alike) counted once, add up to the uncut reference's layer."""
+    X = chips * held
+    cfg = tiny(num_experts=X, experts_held=0, expert_offset=0, first_dense_layers=0, **kw)
+    params = weights(cfg, seed=2) if not kw else llama.init(jax.random.key(2), cfg)
     p = {k: v[1] for k, v in params["blocks"].items()}          # one layer, whole
     h = jax.random.normal(jax.random.key(9), (2, 24, cfg.embed_dim), jnp.float32)
-    spec = serve_dsa.spec_of(cfg)
+    spec = spec_of(cfg)
     with jax.default_matmul_precision("highest"):
         whole, chosen, _ = ref._experts(h.reshape(-1, cfg.embed_dim), p, spec)
         shared = ref._swiglu(h.reshape(-1, cfg.embed_dim),
                              p["ws_gate"], p["ws_up"], p["ws_down"])
     total, rows = 0.0, []
-    for rank in range(16):
-        share = dataclasses.replace(cfg, experts_held=2, expert_offset=2 * rank)
+    for rank in range(chips):
+        share = dataclasses.replace(cfg, experts_held=held, expert_offset=held * rank)
         mine = dict(p, layer=jnp.int32(0), **{
-            k: p[k][None, 2 * rank:2 * rank + 2] for k in ("w_gate", "w_up", "w_down")})
+            k: p[k][None, held * rank:held * (rank + 1)]
+            for k in ("w_gate", "w_up", "w_down")})
         y, routing = llama._ffn(h, mine, share)
         total = total + (y.reshape(-1, cfg.embed_dim) - shared)
         rows.append(np.asarray(routing["rows"]))
@@ -219,13 +226,13 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer():
             part, _, _ = ref._experts(
                 h.reshape(-1, cfg.embed_dim),
                 {**p, **{k: mine[k][0] for k in ("w_gate", "w_up", "w_down")}},
-                serve_dsa.spec_of(share))
+                spec_of(share))
         np.testing.assert_allclose(y.reshape(-1, cfg.embed_dim), part, rtol=0, atol=2e-6)
     np.testing.assert_allclose(total + shared, whole, rtol=0, atol=5e-6)
     # every routed assignment was computed by exactly one share
     assert np.concatenate(rows).sum() == 2 * 24 * cfg.experts_per_token
     assert np.array_equal(
-        np.concatenate(rows), np.bincount(np.asarray(chosen).ravel(), minlength=32))
+        np.concatenate(rows), np.bincount(np.asarray(chosen).ravel(), minlength=X))
 
 
 def test_rows_whose_experts_all_live_elsewhere_get_the_shared_expert_alone():
