@@ -15,9 +15,11 @@ import logging
 import os
 import subprocess
 import sys
+import time
 from typing import Dict, Optional
 
 from ray_tpu.common.config import cfg
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -30,6 +32,32 @@ _PROBE_SRC = (
     "import jax; ds=[d for d in jax.devices() if d.platform != 'cpu']; "
     "print(len(ds)); print(ds[0].device_kind if ds else '')"
 )
+
+
+def open_leased_chips() -> None:
+    """Initialise the jax backend of a worker whose lease names chips
+    (``TPU_VISIBLE_CHIPS``, bound by the raylet's lease), inside the
+    start-up span ``rt.start.chip_open``.  JAX sends no event for it, so
+    whoever is about to touch the first array calls this first: the
+    decode replica before its weights, a train worker before its
+    training function (after ``jax.distributed.initialize``, which must
+    come before the backend).  Elsewhere, and the second time, nothing."""
+    global _chips_opened
+    chips = os.environ.get("TPU_VISIBLE_CHIPS")
+    if not chips or _chips_opened:
+        return
+    _chips_opened = True
+    import jax
+
+    cpu0 = time.process_time()
+    with tracing.startup("rt.start.chip_open", chips=chips) as s:
+        s.attrs.update(
+            device_kind=jax.devices()[0].device_kind,
+            process_cpu_s=round(time.process_time() - cpu0, 3),
+        )
+
+
+_chips_opened = False
 
 
 class TPUAcceleratorManager:

@@ -18,6 +18,7 @@ from ray_tpu.core.object_ref import ObjectRef  # noqa: F401
 from ray_tpu.core.runtime import ObjectRefGenerator  # noqa: F401
 from ray_tpu.core.remote_function import RemoteFunction
 from ray_tpu.core.runtime import Runtime, get_runtime, set_runtime
+from ray_tpu.util import tracing
 
 _node_group: Optional[node_mod.NodeProcessGroup] = None
 
@@ -54,6 +55,9 @@ def init(
     if is_initialized():
         raise RayTpuError("ray_tpu.init() called twice; call shutdown() first")
 
+    # the root of this process's start-up time line; recorded when the
+    # driver is attached (a failed init leaves no span)
+    cluster = tracing.startup("rt.start.cluster", root=True)
     if address is None:
         sdir = session_dir or node_mod.default_session_dir()
         from ray_tpu.accelerators.tpu import TPUAcceleratorManager
@@ -103,6 +107,10 @@ def init(
             _node_group.kill()
             _node_group = None
         raise
+    cluster.attrs.update(
+        nodes=int(address is None), tpu_detected_by=tpu_detected_by
+    )
+    cluster.finish()
     set_runtime(rt)
     if log_to_driver:
         from ray_tpu.core import log_streaming

@@ -2858,6 +2858,7 @@ class GcsServer:
                     "lease_id": lease_id,
                     "resources": demand.to_dict(),
                     "runtime_env": p.get("runtime_env"),
+                    "trace_ctx": p.get("trace_ctx"),
                 },
                 timeout=cfg.worker_start_timeout_s,
             )
@@ -3316,6 +3317,7 @@ class GcsServer:
                     {
                         "actor_id": actor.actor_id.binary(),
                         "runtime_env": getattr(actor, "runtime_env", None),
+                        "trace_ctx": actor.creation_spec.get("trace_ctx"),
                     },
                 )
             worker_conn = None

@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from ray_tpu.common.ids import NodeID
+from ray_tpu.util import tracing
 
 
 def _read_tagged_line(proc: subprocess.Popen, tag: str, timeout: float) -> str:
@@ -71,18 +72,19 @@ class NodeProcessGroup:
 def start_gcs(session_dir: str, host: str = "127.0.0.1", port: int = 0) -> tuple:
     os.makedirs(session_dir, exist_ok=True)
     log = open(os.path.join(session_dir, "gcs.log"), "ab")
-    proc = subprocess.Popen(
-        [
-            sys.executable, "-m", "ray_tpu.core.gcs",
-            "--host", host, "--port", str(port),
-            "--session-dir", session_dir,
-        ],
-        stdout=subprocess.PIPE,
-        stderr=log,
-        env=_control_plane_env(),
-    )
-    log.close()
-    address = _read_tagged_line(proc, "GCS_ADDRESS", 30)
+    with tracing.startup("rt.start.gcs"):  # spawned -> listening
+        proc = subprocess.Popen(
+            [
+                sys.executable, "-m", "ray_tpu.core.gcs",
+                "--host", host, "--port", str(port),
+                "--session-dir", session_dir,
+            ],
+            stdout=subprocess.PIPE,
+            stderr=log,
+            env=_control_plane_env(),
+        )
+        log.close()
+        address = _read_tagged_line(proc, "GCS_ADDRESS", 30)
     return proc, address
 
 
@@ -116,12 +118,15 @@ def start_raylet(
         # slice identity for the raylet and its workers (TPU_NAME etc. —
         # what accelerators/tpu.py turns into slice/head resources)
         env.update(extra_env)
-    proc = subprocess.Popen(
-        cmd, stdout=subprocess.PIPE, stderr=log, env=env
-    )
-    log.close()
-    address = _read_tagged_line(proc, "RAYLET_ADDRESS", 60)
-    nid = _read_tagged_line(proc, "RAYLET_NODE_ID", 10)
+    with tracing.startup("rt.start.raylet"):  # spawned -> registered
+        # the raylet's own start-up spans (its workers') hang under this one
+        env[tracing.START_ENV] = tracing.inject()[tracing.CARRIER_KEY]
+        proc = subprocess.Popen(
+            cmd, stdout=subprocess.PIPE, stderr=log, env=env
+        )
+        log.close()
+        address = _read_tagged_line(proc, "RAYLET_ADDRESS", 60)
+        nid = _read_tagged_line(proc, "RAYLET_NODE_ID", 10)
     store_path = f"/dev/shm/rt_store_{nid[:12]}"
     return proc, address, nid, store_path
 

@@ -38,6 +38,7 @@ from ray_tpu.common.config import cfg
 from ray_tpu.common.ids import NodeID, WorkerID
 from ray_tpu.core import rpc
 from ray_tpu.core.errors import FencedError, is_fenced
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -91,6 +92,8 @@ class WorkerEntry:
     # containerized workers: `docker/podman kill <name>` argv — SIGKILL
     # on `proc` (the run CLIENT) never reaches the container
     container_kill_argv: Optional[list] = None
+    # rt.start.worker: Popen -> worker_ready; None once it reported in
+    start_span: Optional[tracing.Span] = None
 
     @property
     def idle(self) -> bool:
@@ -345,6 +348,7 @@ class Raylet:
                     await self._fence_self(str(e.remote_exception))
             except Exception:
                 pass
+            await self._push_spans()
             # collect dead worker processes
             for w in list(self.workers.values()):
                 if w.proc.poll() is not None:
@@ -778,9 +782,19 @@ class Raylet:
 
     def _spawn_worker(self, python_exe: Optional[str] = None,
                       venv_key: str = "",
-                      container: Optional[tuple] = None) -> WorkerEntry:
+                      container: Optional[tuple] = None,
+                      trace_ctx: Optional[dict] = None) -> WorkerEntry:
+        """``trace_ctx``: the carrier of the lease this worker is started
+        for; a pooled one hangs under this raylet's own start."""
         worker_id = WorkerID.random()
+        start_span = tracing.startup(
+            "rt.start.worker", carrier=trace_ctx or tracing.inject(),
+            worker_id=worker_id.hex(),
+        )
         env = dict(os.environ)
+        env[tracing.START_ENV] = tracing.traceparent(
+            start_span.trace_id, start_span.span_id
+        )
         env["RT_WORKER_ID"] = worker_id.hex()
         env["RT_RAYLET_ADDR"] = self.server.address
         env["RT_GCS_ADDR"] = self.gcs_address
@@ -818,7 +832,7 @@ class Raylet:
         logf.close()
         entry = WorkerEntry(
             worker_id=worker_id, proc=proc, venv_key=venv_key,
-            container_kill_argv=container_kill_argv,
+            container_kill_argv=container_kill_argv, start_span=start_span,
         )
         self.workers[worker_id] = entry
         return entry
@@ -1037,6 +1051,10 @@ class Raylet:
         w.conn = conn
         w.addr = p["address"]
         conn.peer_info["worker_id"] = wid
+        if w.start_span is not None:
+            w.start_span.attrs["spoken_for"] = w.spoken_for
+            w.start_span.finish()
+            w.start_span = None  # the next heartbeat pushes it
         if not w.spoken_for:
             # pooled until the lease that spawned it woke from its poll,
             # a fresh worker was taken by a lease arriving in between as
@@ -1044,6 +1062,20 @@ class Raylet:
             key = _env_key(w.bound_env, w.rtenv_key) if w.bound_env else ()
             self._idle_by_env.setdefault(key, []).append(w)
         return True
+
+    async def _push_spans(self):
+        """This raylet's finished start-up spans, into the GCS's span
+        table (workers and drivers push theirs with their metrics; a
+        raylet has no other telemetry to send)."""
+        spans = tracing.drain()
+        if spans:
+            try:
+                await self.gcs.notify("metrics_push", {
+                    "reporter": f"raylet-{self.node_id.hex()}", "metrics": [],
+                    "spans": spans, "pid": os.getpid(),
+                })
+            except Exception:
+                pass  # best effort, as Runtime.push_telemetry
 
     async def _wait_for_worker(self, w: WorkerEntry):
         deadline = time.monotonic() + cfg.worker_start_timeout_s
@@ -1258,7 +1290,8 @@ class Raylet:
             )
             w = self._spawn_worker(python_exe=venv_python,
                                    venv_key=venv_key,
-                                   container=container)
+                                   container=container,
+                                   trace_ctx=p.get("trace_ctx"))
             w.spoken_for = True
             try:
                 await self._wait_for_worker(w)
@@ -1269,7 +1302,8 @@ class Raylet:
         if w.bound_env is None:
             try:
                 await w.conn.call(
-                    "bind_env", {"env": accel_env, "runtime_env": rtenv}
+                    "bind_env", {"env": accel_env, "runtime_env": rtenv,
+                                 "trace_ctx": p.get("trace_ctx")}
                 )
             except Exception:
                 # failed bind (e.g. missing runtime-env package): the

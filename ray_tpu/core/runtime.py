@@ -2519,6 +2519,10 @@ class Runtime:
             "args": self._pack_args(args, kwargs),
             "max_task_retries": max_task_retries,
             "job": self._job_hex(),
+            # always, not only with tracing on: the worker's start-up
+            # spans join the trace of whoever asked for the actor (one
+            # dict a creation, never a task)
+            "trace_ctx": tracing.inject(),
         }
         if max_concurrency is not None:
             creation_spec["max_concurrency"] = int(max_concurrency)
@@ -2568,6 +2572,7 @@ class Runtime:
                             "strategy": strategy,
                             "actor_id": actor_id.binary(),
                             "runtime_env": runtime_env,
+                            "trace_ctx": creation_spec["trace_ctx"],
                         },
                         timeout=cfg.sched_max_pending_lease_s
                         + cfg.worker_start_timeout_s,

@@ -127,7 +127,7 @@ class WorkerServer:
         if method == "checkpoint_abort":
             return await self.handle_checkpoint_abort()
         if method == "bind_env":
-            _bind_accelerator_env(p["env"])
+            _bind_accelerator_env(p["env"], p.get("trace_ctx"))
             if p.get("runtime_env"):
                 from ray_tpu.core import runtime_env as rtenv_mod
 
@@ -560,9 +560,18 @@ class WorkerServer:
     async def handle_create_actor(self, p) -> bool:
         spec = p["creation_spec"]
         if p.get("accelerator_env"):
-            _bind_accelerator_env(p["accelerator_env"])
-        cls = await self.rt.resolve_fn(spec["cls_hash"])
-        args, kwargs = await self.rt.unpack_args(spec["args"])
+            _bind_accelerator_env(
+                p["accelerator_env"], spec.get("trace_ctx")
+            )
+        # the class and its arguments arrive pickled: unpickling them is
+        # where a fresh worker imports what the actor is made of
+        with tracing.startup(
+            "rt.start.actor_load", carrier=spec.get("trace_ctx"),
+            actor_id=p["actor_id"].hex(),
+        ) as loaded:
+            cls = await self.rt.resolve_fn(spec["cls_hash"])
+            args, kwargs = await self.rt.unpack_args(spec["args"])
+            loaded.attrs["class"] = cls.__name__
         self.actor_id = ActorID(p["actor_id"])
         self.rt.actor_id = self.actor_id
         # async actor iff any public method is a coroutine function
@@ -607,10 +616,18 @@ class WorkerServer:
         if log_streaming._publisher is not None:
             # driver-side log prefix becomes "(ClassName pid=..., ...)"
             log_streaming._publisher.set_actor_name(cls.__name__)
+
+        def init():
+            # entered on the thread __init__ runs on: the spans it starts
+            # itself (llm.start.*) are this one's children
+            with tracing.startup(
+                "rt.start.actor_init", carrier=spec.get("trace_ctx"),
+                **{"class": cls.__name__}, actor_id=self.actor_id.hex(),
+            ):
+                return cls(*args, **kwargs)
+
         loop = asyncio.get_running_loop()
-        self.actor_instance = await loop.run_in_executor(
-            self._exec, lambda: cls(*args, **kwargs)
-        )
+        self.actor_instance = await loop.run_in_executor(self._exec, init)
         # graceful-drain handoff: restore the migrated state (opt-in
         # __rt_checkpoint__/__rt_restore__ pair), then re-join any
         # collective groups the predecessor process was a member of —
@@ -1263,24 +1280,50 @@ def _apply_jax_platform(env: dict) -> None:
     jax.config.update("jax_platforms", jp)
 
 
-def _bind_accelerator_env(env: dict) -> None:
+def _bind_accelerator_env(env: dict, trace_ctx: Optional[dict] = None) -> None:
     """Apply a lease's accelerator env to this process: chip visibility
     for libtpu, the jax platform, and — for a worker that will compile
-    for the chip — the persistent compile cache."""
-    os.environ.update(env)
-    _apply_jax_platform(env)
-    if env.get("TPU_VISIBLE_CHIPS"):
-        from ray_tpu.util import compile_cache
+    for the chip — the persistent compile cache and the start-up spans
+    of its compiles.  ``trace_ctx``: the carrier of whoever asked for
+    the lease."""
+    with tracing.startup(
+        "rt.start.lease_bind", carrier=trace_ctx,
+        chips=env.get("TPU_VISIBLE_CHIPS", ""),
+        platform=env.get("JAX_PLATFORMS", ""),
+    ):
+        os.environ.update(env)
+        _apply_jax_platform(env)
+        if env.get("TPU_VISIBLE_CHIPS"):
+            from ray_tpu.util import compile_cache
 
-        compile_cache.configure()
+            compile_cache.configure()
+
+
+def _process_start_ns() -> int:
+    """When the OS started this process, on ``time.time_ns()``'s clock
+    (to a clock tick): the interpreter's start and every import before
+    ``main`` lie after it."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])  # starttime
+        age_s = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                 - ticks / os.sysconf("SC_CLK_TCK"))
+        return time.time_ns() - int(age_s * 1e9)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return time.time_ns()
 
 
 def main():
     logging.basicConfig(
         level=logging.INFO, format="[worker %(process)d] %(levelname)s %(message)s"
     )
-    _apply_jax_platform(os.environ)
     worker_id = WorkerID.from_hex(os.environ["RT_WORKER_ID"])
+    # from the process's own start to worker_ready sent
+    boot_span = tracing.startup(
+        "rt.start.boot", root=True, worker_id=worker_id.hex()
+    )
+    boot_span.start_ns = _process_start_ns()
+    _apply_jax_platform(os.environ)
     raylet_addr = os.environ["RT_RAYLET_ADDR"]
     gcs_addr = os.environ["RT_GCS_ADDR"]
     node_id = os.environ["RT_NODE_ID"]
@@ -1312,6 +1355,7 @@ def main():
         raylet_conn = await rpc.connect(
             raylet_addr, server._handle, name="worker->raylet"
         )
+        boot_span.finish()
         await raylet_conn.call(
             "worker_ready",
             {"worker_id": worker_id.binary(), "address": server.server.address},

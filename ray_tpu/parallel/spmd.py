@@ -24,6 +24,7 @@ from ray_tpu.parallel.sharding import (
     logical_to_spec,
     tree_shardings,
 )
+from ray_tpu.util import tracing
 
 
 class TrainState(NamedTuple):
@@ -146,6 +147,21 @@ def sharded_init(
     exists unsharded anywhere.
     """
     optimizer = optimizer or optax.identity()
+    with tracing.startup(
+        "train.start.state",
+        mesh=",".join(f"{k}={v}" for k, v in mesh.shape.items()),
+    ) as started:
+        state = _sharded_init(
+            mesh, init_fn, rng, param_logical, optimizer, rules
+        )
+        started.attrs["param_bytes"] = sum(
+            x.nbytes for x in jax.tree.leaves(state.params)
+        )
+        return state
+
+
+def _sharded_init(mesh, init_fn, rng, param_logical, optimizer,
+                  rules) -> TrainState:
     # pre-check divisibility so a mismatch (e.g. num_experts=6 on ep=4)
     # surfaces as a clear error naming the param and axis, not a GSPMD
     # partitioning failure deep inside jit
@@ -178,9 +194,11 @@ def sharded_init(
         mesh, init_fn, rng, param_logical, optimizer, rules
     )
     with jax.set_mesh(mesh):
-        return jax.jit(
+        state = jax.jit(
             _full_init(init_fn, optimizer), out_shardings=out_shardings
         )(rng)
+    # the span ends with the state on the devices, not with its dispatch
+    return jax.block_until_ready(state)
 
 
 def compile_train_step(

@@ -15,6 +15,7 @@ from ray_tpu.serve.controller import (
 )
 from ray_tpu.serve.deployment import Application, Deployment, HandleRef
 from ray_tpu.serve.handle import DeploymentHandle
+from ray_tpu.util import tracing
 
 PROXY_NAME = "SERVE_PROXY"
 
@@ -138,12 +139,18 @@ def run(
         target = Application(target)
     if not isinstance(target, Application):
         raise TypeError("serve.run expects Application (deployment.bind(...))")
-    controller = get_or_create_controller()
     deployments, root_name = _flatten_graph(target)
-    ray_tpu.get(
-        controller.deploy_application.remote(name, deployments, root_name),
-        timeout=120,
-    )
+    # until the controller has asked for the replicas: from there each
+    # replica's own start-up spans go on (rt.start.worker ... actor_init)
+    with tracing.startup(
+        "serve.start.app", app=name,
+        replicas=sum(d.num_replicas for d in deployments),
+    ):
+        controller = get_or_create_controller()
+        ray_tpu.get(
+            controller.deploy_application.remote(name, deployments, root_name),
+            timeout=120,
+        )
     if route_prefix is not None:
         ray_tpu.get(
             controller.set_route_prefix.remote(route_prefix, name, root_name),

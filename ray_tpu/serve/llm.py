@@ -47,6 +47,7 @@ import time
 from typing import Any, List, Optional
 
 from ray_tpu import serve
+from ray_tpu.accelerators import tpu
 from ray_tpu.util import metrics, tracing
 
 #: histogram boundaries in ms, 1 ms .. 62 s in steps of 1.15x: a median
@@ -687,6 +688,13 @@ class LLMEngine:
                 await self._deliver()
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes the arrays of a pytree hold (no copy, no device sync)."""
+    import jax
+
+    return sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(tree))
+
+
 @serve.deployment
 class LlamaDeployment:
     """Decode replica: tiny-config by default, or real weights via a
@@ -707,20 +715,26 @@ class LlamaDeployment:
 
         self._compiles = CompileLog()
         self.config = config or llama.LlamaConfig.tiny()
-        if weights_ref is not None:
-            import ray_tpu
+        tpu.open_leased_chips()
+        with tracing.startup("llm.start.weights") as started:
+            if weights_ref is not None:
+                import ray_tpu
 
-            params = ray_tpu.get(weights_ref)
-        elif weights_loader is not None:
-            params = weights_loader()
-        else:
-            params = llama.init(jax.random.key(seed), self.config)
-        self.engine = LLMEngine(
-            params, self.config, max_slots=max_slots, max_len=max_len,
-            max_prompt_len=max_prompt_len,
-            speculative_tokens=speculative_tokens, temperature=temperature,
-            seed=seed,
-        )
+                params = ray_tpu.get(weights_ref)
+            elif weights_loader is not None:
+                params = weights_loader()
+            else:
+                params = llama.init(jax.random.key(seed), self.config)
+            started.attrs["param_bytes"] = _tree_bytes(params)
+        # cache allocation, the rows' state, the programs' adapters
+        with tracing.startup("llm.start.engine") as started:
+            self.engine = LLMEngine(
+                params, self.config, max_slots=max_slots, max_len=max_len,
+                max_prompt_len=max_prompt_len,
+                speculative_tokens=speculative_tokens,
+                temperature=temperature, seed=seed,
+            )
+            started.attrs["cache_bytes"] = _tree_bytes(self.engine.cache)
 
     async def stats(self) -> dict:
         """What this replica runs on and what it has cost so far: the

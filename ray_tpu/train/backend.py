@@ -14,6 +14,8 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING, Dict, List
 
+from ray_tpu.accelerators import tpu
+
 if TYPE_CHECKING:
     from ray_tpu.train.worker_group import WorkerGroup
 
@@ -70,6 +72,7 @@ def _jax_distributed_init(coordinator: str, num_processes: int, process_id: int)
         num_processes=num_processes,
         process_id=process_id,
     )
+    tpu.open_leased_chips()
     # prove the gang actually formed — callers gate training on this
     return jax.process_count() == num_processes
 

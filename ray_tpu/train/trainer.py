@@ -30,6 +30,7 @@ from ray_tpu.train.checkpoint import (
     _read_metrics_sidecar,
 )
 from ray_tpu.train.config import FailureConfig, RunConfig, ScalingConfig
+from ray_tpu.util import tracing
 
 
 @dataclasses.dataclass
@@ -77,11 +78,18 @@ class JaxTrainer:
         )
         while True:
             try:
-                executor.start()
-                executor.start_training(
-                    self._train_fn, self._config, latest_checkpoint,
-                    datasets=self._datasets,
-                )
+                # until every worker's training function has been started
+                with tracing.startup(
+                    "train.start.workers",
+                    workers=self.scaling_config.num_workers,
+                    chips=int(self.scaling_config.num_workers
+                              * self.scaling_config.bundle().get("TPU", 0)),
+                ):
+                    executor.start()
+                    executor.start_training(
+                        self._train_fn, self._config, latest_checkpoint,
+                        datasets=self._datasets,
+                    )
                 while True:
                     reports = executor.next_reports()
                     if reports is None:

@@ -16,6 +16,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.accelerators import tpu
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.session import (
     TrainContext,
@@ -85,6 +86,7 @@ class TrainWorkerActor:
 
         def run():
             try:
+                tpu.open_leased_chips()
                 session.result = train_fn(config)
             except BaseException as e:  # noqa: BLE001 — reported to driver
                 session.error = e

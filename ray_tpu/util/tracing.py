@@ -22,6 +22,15 @@ of the same name, so it shows above the device's operations in xprof /
 Perfetto on the profiler's own clock.  Off, a span site costs the
 ``enabled()`` check and nothing else.
 
+Beside the switch, ``startup()`` starts a span that is recorded whatever
+the switch says: the start-up time line of a process (``rt.start.*``,
+``serve.start.*``, ``train.start.*``, ``llm.start.*``) and its XLA
+builds (``xla.trace`` / ``xla.lower`` / ``xla.compile``,
+util/compile_cache.py).  Start-up happens before any profiler session;
+these number a dozen a process and three a compiled program (about 170
+in a 7B replica's start), and none sits on a per-task, per-step or
+per-token path.
+
 The recorder: ``start_ns`` / ``end_ns`` are integer nanoseconds of
 ``time.time_ns()`` (the processes of one host share that clock); a
 finished span is one tuple ``(name, trace_id, span_id, parent_id,
@@ -57,6 +66,9 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 CARRIER_KEY = "traceparent"  # W3C trace context header
+#: a spawned process finds its spawner's context here (core/node.py,
+#: core/raylet.py set it in the child's environment, never in their own)
+START_ENV = "RT_TRACEPARENT"
 
 #: entered and not yet left, oldest first (what a stalled process names)
 _OPEN: Dict[int, str] = {}
@@ -126,14 +138,20 @@ def enabled() -> bool:
 # -- context propagation (W3C traceparent) ---------------------------------
 
 
+def traceparent(trace_id: str, span_id: str) -> str:
+    """The W3C header that names a span as a parent."""
+    return f"00-{trace_id}-{span_id}-01"
+
+
 def inject() -> Optional[Dict[str, str]]:
     """Carrier dict for the current trace context, to ride a TaskSpec.
-    Starts a fresh trace when none is active (every task belongs to some
-    trace once tracing is on)."""
-    cur = _CURRENT.get()
+    Outside any span it names this process's start-up (``startup``), and
+    where there was none starts a fresh trace (every task belongs to
+    some trace once tracing is on)."""
+    cur = _CURRENT.get() or _start
     if cur is None:
         cur = (_TRACE_PREFIX + _next_id(), _SPAN_PREFIX + _next_id())
-    return {CARRIER_KEY: f"00-{cur[0]}-{cur[1]}-01"}
+    return {CARRIER_KEY: traceparent(*cur)}
 
 
 def _extract(carrier: Optional[Dict[str, str]]):
@@ -144,6 +162,13 @@ def _extract(carrier: Optional[Dict[str, str]]):
         return (trace_id, span_id)
     except (KeyError, ValueError):
         return None
+
+
+#: what start-up spans hang under where no span is open and no carrier is
+#: given: the spawner's context, then this process's own root
+#: (``rt.start.cluster`` in a driver, ``rt.start.boot`` in a worker)
+_spawner = _extract({CARRIER_KEY: os.environ.get(START_ENV, "")})
+_start = _spawner
 
 
 def current() -> Optional[Tuple[str, str]]:
@@ -206,9 +231,12 @@ class Span:
         self.finish(exc)
         return False
 
-    def finish(self, exc: Optional[BaseException] = None) -> None:
+    def finish(self, exc: Optional[BaseException] = None,
+               end_ns: Optional[int] = None) -> None:
+        """``end_ns``: the end someone else's clock took (JAX's compile
+        events carry both of theirs); now otherwise."""
         global _undrained
-        self.end_ns = time.time_ns()
+        self.end_ns = end_ns or time.time_ns()
         if exc is not None:
             self.attrs["error"] = type(exc).__name__
         if self._otel_span is not None:
@@ -247,7 +275,7 @@ def _otel_start(s: Span):
     parent_ctx = None
     if s.parent_id:
         parent_ctx = _otel[3].extract({
-            CARRIER_KEY: f"00-{s.trace_id}-{s.parent_id}-01",
+            CARRIER_KEY: traceparent(s.trace_id, s.parent_id),
         })
     return _otel[0].start_span(s.name, context=parent_ctx, attributes=s.attrs)
 
@@ -264,6 +292,27 @@ def root(name: str, **attrs) -> Span:
     """Start a span that is the root of a fresh trace whatever the
     ambient context (a loop that serves many requests)."""
     return Span(name, None, attrs)
+
+
+def startup(name: str, carrier: Optional[Dict[str, str]] = None,
+            root: bool = False, **attrs) -> Span:
+    """Start a span of a process's start-up: like ``span``, but the
+    caller does not ask ``enabled()`` first, so it is always recorded.
+    Outside any open span and without a carrier it hangs under this
+    process's start-up root.  ``root``: this span is that root from now
+    on, and itself hangs under the spawner's context (``START_ENV``),
+    where there is one."""
+    global _start
+    if carrier is not None:
+        parent = _extract(carrier)
+    elif root:
+        parent = _spawner
+    else:
+        parent = _CURRENT.get() or _start
+    s = Span(name, parent, attrs)
+    if root:
+        _start = (s.trace_id, s.span_id)
+    return s
 
 
 def as_dict(row: tuple, pid: Optional[int] = None) -> dict:

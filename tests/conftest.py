@@ -151,10 +151,23 @@ def pytest_collection_modifyitems(config, items):
                 "file under BENCHMARK.json's paths (PERF.md section 7, harness "
                 "edit 11): strict, so the mark goes when the test is relaxed"
             )))
+        if item.nodeid.endswith(_PINNED_TO_LAST_SIXTEEN):
+            item.add_marker(pytest.mark.xfail(strict=True, reason=(
+                "PR 32's test holds per_layer[-16:] to be JoyAI's; PR 34 appended "
+                "the six setup_* entries after them, because the driver refuses an "
+                "entry put anywhere but at the end of its list (it refused them at "
+                "the head: 'the PR changes the per-layer metric train_step_ms_p50'), "
+                "and only a `benchmark` PR may edit a file under BENCHMARK.json's "
+                "paths (PERF.md section 7, harness edit 12): strict, as above"
+            )))
 
 
 _PINNED_TO_SEVEN_CELLS = (
     "test_chipbench_glm.py::test_benchmark_json_gains_the_cell_and_nothing_else_changes"
+)
+_PINNED_TO_LAST_SIXTEEN = (
+    "test_chipbench_joyai.py::"
+    "test_the_benchmark_gains_one_configuration_one_cell_and_the_joy_metrics"
 )
 
 
