@@ -322,7 +322,18 @@ def _features_aux(params: Params, tokens, config: GPTConfig, mask=None):
         xx, aux_sum = carry
         fn = _block
         if c.remat:
-            fn = jax.checkpoint(_block, static_argnums=(2,))
+            # Everything of the block is recomputed in the backward pass
+            # but the flash kernel's (o, lse): one forward call costs
+            # eight times what the next dearest recompute (qkv) does per
+            # byte kept.  The dense path has no such names.
+            from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+            fn = jax.checkpoint(
+                _block, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *RESIDUAL_NAMES
+                ),
+            )
         xx, aux = fn(xx, layer_params, c, mask)
         return (xx, aux_sum + aux), None
 

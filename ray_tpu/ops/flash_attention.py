@@ -8,20 +8,20 @@ HBM-bandwidth lever for transformer training on TPU — the dense einsum
 path writes + rereads ~400 MB of f32 scores per layer for (B=8, H=12,
 S=1024) while this kernel writes only the (B, H, S) log-sum-exp.
 
-Layout: q, k, v are (B, S, H, D) (model-native).  The kernel grid is
-(B, H, nq[, nk]) and BlockSpecs pick (1, blk, 1, D) slices, so no
-transposes are needed on the HBM side.
+Layout: the kernels take q, k, v as (B, H, S, D) (`flash_attention`
+transposes for a (B, S, H, D) caller).  Grids are (B, H, nq, nk), kv
+innermost ((B, H, nk, nq) for dk/dv); a block is one head's (blk, D).
 
 Backward follows the flash-attention-2 recipe: save (o, lse), compute
 delta = rowsum(do ⊙ o), then one kernel accumulates dq over KV blocks
 and another accumulates (dk, dv) over Q blocks, recomputing p = exp(s −
-lse) on the fly.
+lse) on the fly.  (o, lse) carry checkpoint names (`RESIDUAL_NAMES`), so
+a rematted caller can keep them and not run the forward kernel twice.
 
-Role-equivalent to the reference's fused GPU attention paths (the
-reference delegates to torch/cutlass; here the MXU/VMEM design is
-original).  Off the chip the same kernels run in Pallas interpret mode
-(`_interpret`), so tests exercise them on CPU; nothing here switches to
-the dense einsum.
+Role-equivalent to the reference's fused GPU attention paths (those
+delegate to torch/cutlass; the MXU/VMEM design here is original).  Off
+the chip the kernels run in Pallas interpret mode (`_interpret`): tests
+exercise the same code on CPU, and nothing switches to the dense einsum.
 """
 
 from __future__ import annotations
@@ -300,7 +300,7 @@ def flash_attention_bhsd(q, k, v, scale: float | None = None):
 
 def _flash_fwd(q, k, v, scale):
     s = scale or 1.0 / math.sqrt(q.shape[-1])
-    o, lse = _fwd(q, k, v, s)
+    o, lse = _named_residuals(*_fwd(q, k, v, s))
     return o, (q, k, v, o, lse)
 
 
@@ -362,3 +362,32 @@ def sharded_flash_attention_bhsd(q, k, v, scale: float | None = None):
         out_specs=spec,
     )
     return fn(q, k, v)
+
+
+# Below every pallas_call and their callers, its import too: a kernel's
+# serialized body holds its callers' source lines, and the persistent
+# compile cache keys on it (PERF.md section 5, (7)).
+
+#: What `jax.checkpoint_policies.save_only_these_names` has to be given
+#: for the backward pass of a rematted caller to reuse the forward
+#: kernel's outputs (models/gpt2.py); outside such a policy the names
+#: are identities.
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
+def _named_residuals(o, lse):
+    """The forward kernel's (o, lse) under `RESIDUAL_NAMES`.  What a
+    policy keeps is the named value as it lies: `o` is named in rows of
+    128 lanes, because a layer scan that stacks a 64-wide minor
+    dimension pads it to the tile's 128 and keeps twice the bytes
+    (1.26 GB more a chip in GPT-2 XL's step) for a step 0.4% slower
+    on one v5e and 0.2% faster on four (PERF.md section 6, PR 35).
+    The primal output comes from the named values, so nothing of a
+    recompute needs the kernel."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    B, H, S, D = o.shape
+    if D % 128:  # S is a multiple of 128 (_block_sizes), so S * D is
+        o = o.reshape(B, H, S * D // 128, 128)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0]).reshape(B, H, S, D)
+    return o, checkpoint_name(lse, RESIDUAL_NAMES[1])

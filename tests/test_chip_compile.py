@@ -9,16 +9,26 @@ that passes is not a chip run: nothing executes here.
 
 The shapes are the two head layouts the repo trains and serves at
 width: GPT-2-124M at B=8 x S=1024 (12 heads x 64) and the Llama family
-at one 2048-token sequence (32 heads x 128).
+at one 2048-token sequence (32 heads x 128); and, whole, the two
+training cells' step programs (GPT-2 medium on one chip, GPT-2 XL on
+the four of the 2x2 under `fsdp=4`): the forward kernel once a layer,
+and XL inside its chips' memory.
 """
 
+import json
 import os
+import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
+import optax
 import pytest
 
+from ray_tpu.models import gpt2
 from ray_tpu.ops import flash_attention as fa
+from ray_tpu.parallel import mesh as mesh_mod
+from ray_tpu.parallel import spmd
 
 # the compiler otherwise logs under /tmp
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -30,8 +40,8 @@ HEAD_SHAPES = {
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One device of a described v5e 2x2, or skip."""
+def v5e_2x2():
+    """The four devices of a described v5e 2x2, or skip."""
     from jax.experimental import topologies
 
     try:
@@ -40,7 +50,13 @@ def v5e_chip():
         )
     except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
-    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_2x2):
+    """One of them."""
+    return jax.sharding.SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture
@@ -81,4 +97,110 @@ def test_flash_kernel_compiles_for_v5e(
     assert text.count("tpu_custom_call") == n_kernels, (
         f"{shape_name} {direction}: expected {n_kernels} compiled Pallas "
         f"kernel(s) in the program"
+    )
+    # under no remat policy the residuals' names are identities, and the
+    # pair of reshapes that names a 64-wide `o` in rows of 128 lanes folds
+    # away: no array of that shape, no copy into it
+    B, H, S, D = HEAD_SHAPES[shape_name]
+    assert D % 128 == 0 or f"[{B},{H},{S * D // 128},128]" not in text
+
+
+# ---- the training cells' step programs ----------------------------------
+
+BENCH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "chipbench"
+)
+#: cell -> (configuration, traffic, chips): BENCHMARK.json's two training cells
+TRAIN_CELLS = {
+    "train_gpt2m_1chip": ("gpt2-medium", "train_8x1024", 1),
+    "train_gpt2xl_mesh4": ("gpt2-xl", "train_32x1024_fsdp4", 4),
+}
+#: what the compiler allows one v5e program (CHANGES.md, PR 28: "15.76 of
+#: 15.75 GB"), and what a step has to leave under it.  GPT-2 XL's,
+#: measured at PR 35: 14.92 GiB by `memory_analysis()`'s arguments +
+#: temporaries (12.59 before the block kept `o` and `lse`), 11.93 GiB by
+#: the buffer assignment's own total — `temp_size_in_bytes` counts more
+#: than the program holds at once, and the benchmark's
+#: `memory_peak_bytes` is made from it.
+V5E_PROGRAM_LIMIT = 15.75 * 2**30
+MARGIN = 0.5 * 2**30
+
+
+def _compiled_step(cell, devices):
+    """`chipbench/jobs/train_spmd.py`'s step program for `cell`, compiled
+    for `devices`: the configuration's model, the traffic's mesh and
+    batch, AdamW, the donated state — from shapes, nothing is placed."""
+    from chipbench.jobs.train_spmd import gpt_config
+
+    config, traffic, chips = TRAIN_CELLS[cell]
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
+        traffic = json.load(f)
+    model = gpt_config(cfg, traffic["seq_len"])
+    optimizer = optax.adamw(
+        cfg["optimizer"]["learning_rate"],
+        weight_decay=cfg["optimizer"]["weight_decay"],
+    )
+    mesh = mesh_mod.make_mesh(
+        mesh_mod.MeshConfig(**traffic["mesh"]), devices=devices[:chips]
+    )
+    try:
+        def init(rng):
+            return gpt2.init(rng, model)
+
+        key = jax.random.key(0)
+        state = jax.tree.map(
+            lambda x, sharding: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=sharding
+            ),
+            jax.eval_shape(spmd._full_init(init, optimizer), key),
+            spmd.state_shardings(
+                mesh, init, key, gpt2.param_logical_axes(model), optimizer
+            ),
+        )
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (traffic["batch"], traffic["seq_len"] + 1), jnp.int32,
+            sharding=spmd.batch_sharding(mesh),
+        )}
+        step = spmd.compile_train_step(
+            lambda p, b: gpt2.loss_fn(p, b, model), optimizer
+        )
+        with mesh_mod.use(mesh):
+            return model, traffic, step.lower(state, batch).compile()
+    finally:
+        mesh_mod.set_current_mesh(None)
+
+
+@pytest.mark.limit(400)
+@pytest.mark.parametrize("cell", sorted(TRAIN_CELLS))
+def test_training_step_runs_the_forward_kernel_once_and_fits(
+    v5e_2x2, compiled_not_interpreted, cell
+):
+    model, traffic, compiled = _compiled_step(cell, v5e_2x2)
+    text = compiled.as_text()
+    kernels = Counter(re.findall(
+        r'custom_call_target="tpu_custom_call".*op_name="[^"]*/(\w+)/pallas_call"',
+        text,
+    ))
+    # the rolled layer scan has one body forward and one backward; a block
+    # that recomputed its attention would show `flash_fwd` in both
+    assert kernels == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+
+    # what the forward scan stacks of the kernel's output lies in rows of
+    # 128 lanes: a 64-wide minor dimension would be padded to twice the bytes
+    chips = TRAIN_CELLS[cell][2]
+    L, H, D = model.num_layers, model.num_heads, model.head_dim
+    B, S = traffic["batch"] // chips, traffic["seq_len"]
+    assert f"bf16[{L},{B},{H},{S * D // 128},128]" in text
+    assert f"bf16[{L},{B},{H},{S},{D}]" not in text
+
+    m = compiled.memory_analysis()
+    held = (
+        m.argument_size_in_bytes + m.temp_size_in_bytes
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert held + MARGIN < V5E_PROGRAM_LIMIT, (
+        f"{cell}: the step holds {held / 2**30:.2f} GiB a chip by the "
+        f"compiler's analysis; a v5e program may hold 15.75"
     )
