@@ -118,8 +118,19 @@ def params_from_hf(model, *, pad_vocab_to: int = 128,
 # ---------------------------------------------------------------------------
 
 
+#: ``model_type``s whose block is Qwen3's: q and k RMS-normed per head
+#: (``q_norm`` / ``k_norm``, one (head_dim,) scale each); the ``_moe`` ones
+#: with a softmax router over ``num_experts`` experts of width
+#: ``moe_intermediate_size`` in every layer.  SDAR (``sdar``, ``sdar_moe``)
+#: is the same block served by diffusion over blocks
+#: (``models/block_diffusion.py``)
+_PER_HEAD_NORM = ("qwen3", "qwen3_moe", "sdar", "sdar_moe")
+
+
 def llama_config_from_hf(hf_config, **overrides):
-    """Map a transformers LlamaConfig onto LlamaConfig."""
+    """Map a transformers LlamaConfig — or a Qwen3-MoE / SDAR one: ``head_dim``,
+    the per-head ``q_norm`` / ``k_norm``, the experts and ``norm_topk_prob`` —
+    onto LlamaConfig."""
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import LlamaConfig
@@ -138,7 +149,22 @@ def llama_config_from_hf(hf_config, **overrides):
         rms_eps=hf_config.rms_norm_eps,
         tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
         dtype=jnp.bfloat16,
+        # 0: embed_dim // num_heads
+        head_dim=getattr(hf_config, "head_dim", None) or 0,
     )
+    family = getattr(hf_config, "model_type", "")
+    if family in _PER_HEAD_NORM:
+        kwargs["qk_norm"] = "head"
+    if family.endswith("_moe"):
+        if getattr(hf_config, "mlp_only_layers", None) or getattr(
+                hf_config, "decoder_sparse_step", 1) != 1:
+            raise NotImplementedError("dense blocks among the expert blocks")
+        kwargs.update(
+            mlp_dim=0, num_experts=hf_config.num_experts,
+            experts_per_token=hf_config.num_experts_per_tok,
+            expert_dim=hf_config.moe_intermediate_size,
+            router_norm_topk=bool(hf_config.norm_topk_prob),
+        )
     kwargs.update(overrides)
     return LlamaConfig(**kwargs)
 
@@ -185,21 +211,34 @@ def llama_params_from_hf(model, **config_overrides):
             "mlp_norm": j(
                 stacked("model.layers.{i}.post_attention_layernorm.weight")
             ),
-            "w_gate": j(
-                stacked("model.layers.{i}.mlp.gate_proj.weight")
-                .transpose(0, 2, 1)
-            ),
-            "w_up": j(
-                stacked("model.layers.{i}.mlp.up_proj.weight")
-                .transpose(0, 2, 1)
-            ),
-            "w_down": j(
-                stacked("model.layers.{i}.mlp.down_proj.weight")
-                .transpose(0, 2, 1)
-            ),
         },
         "final_norm": j(sd["model.norm.weight"]),
     }
+    blocks = params["blocks"]
+    if config.qk_norm == "head":
+        blocks["q_norm"] = j(stacked("model.layers.{i}.self_attn.q_norm.weight"))
+        blocks["k_norm"] = j(stacked("model.layers.{i}.self_attn.k_norm.weight"))
+    if config.num_experts:
+        # (L, X, in, out): HF keeps one Linear (out, in) an expert
+        def experts(name: str) -> np.ndarray:
+            return np.stack([
+                np.stack([
+                    sd[f"model.layers.{i}.mlp.experts.{e}.{name}.weight"].T
+                    for e in range(config.num_experts)
+                ]) for i in range(L)
+            ])
+
+        blocks["w_router"] = j(
+            stacked("model.layers.{i}.mlp.gate.weight").transpose(0, 2, 1)
+        )
+    else:
+        def experts(name: str) -> np.ndarray:
+            return stacked(
+                "model.layers.{i}.mlp." + name + ".weight"
+            ).transpose(0, 2, 1)
+    blocks["w_gate"] = j(experts("gate_proj"))
+    blocks["w_up"] = j(experts("up_proj"))
+    blocks["w_down"] = j(experts("down_proj"))
     if not config.tie_embeddings:
         params["lm_head"] = j(sd["lm_head.weight"])
     return params, config

@@ -53,10 +53,22 @@ multi-token-prediction module — one more block with its own cache layer,
 verifies it with a step of Sq == 2 for every row, which yields one or two
 tokens a row.  ``_pick_token`` is the one sampler of every path, and
 ``draw_keys`` the one schedule of its keys.
+
+``mask_block`` B > 1 makes the cached paths' mask BLOCK-causal (a query
+sees every key up to the end of its own block of B positions), which is
+what a model generating by diffusion over blocks (SDAR) is run under:
+``models/block_diffusion.py`` refines a block of B tokens a row in place
+with steps of Sq == B for every row — the K/V path's every-row run,
+written into the cache in place (``_write_and_read``) — and commits it.
+``head_dim`` is a field (the projections' width need not be
+``embed_dim``), ``qk_norm="head"`` norms each head of q and k apart, and
+the softmax router renormalises its chosen weights with
+``router_norm_topk``: Qwen3-MoE's block, SDAR's.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -100,8 +112,11 @@ class LlamaConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     expert_dim: int = 0
-    # RMSNorm over the whole projected q and k vectors (before heads)
-    qk_norm: bool = False
+    # RMSNorm of q and k before the rotation: True over the WHOLE
+    # projected vectors, all heads together (OLMoE) | "head" over each
+    # head's head_dim values, one (head_dim,) scale for all heads (Qwen3,
+    # SDAR)
+    qk_norm: Any = False
     # latent attention (MLA, DeepSeek-V2/V3, GLM-5): kv_lora_rank > 0
     # replaces wq/wk/wv by low-rank projections; the cache holds
     # kv_lora_rank + qk_rope_head_dim numbers a token, for all heads
@@ -125,8 +140,8 @@ class LlamaConfig:
     shared_expert_dim: int = 0
     # the router: "softmax" over all experts, the chosen probabilities
     # as they are (OLMoE) | "sigmoid" scores, chosen by score + a
-    # selection-only bias, the chosen scores renormalised
-    # (router_norm_topk) and times router_scale (DeepSeek-V3, GLM-5)
+    # selection-only bias (DeepSeek-V3, GLM-5); either's chosen weights
+    # divided by their sum with router_norm_topk, times router_scale
     router_scoring: str = "softmax"
     router_norm_topk: bool = False
     router_scale: float = 1.0
@@ -141,10 +156,18 @@ class LlamaConfig:
     # model's shape with its own cache layer, which drafts the token
     # after the next for a speculative step (``models/mtp.py``)
     mtp_layers: int = 0
+    # values a head (0: embed_dim // num_heads).  A field because the
+    # projections' width num_heads * head_dim need not be embed_dim
+    # (SDAR-30B-A3B: 32 heads of 128 on 2,048)
+    head_dim: int = 0
+    # the cached paths' mask: a query sees every key up to the END of its
+    # own block of mask_block positions (block diffusion: the tokens of a
+    # block see each other); 1 is causal
+    mask_block: int = 1
 
-    @property
-    def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.embed_dim // self.num_heads)
 
     @property
     def latent(self) -> bool:
@@ -324,8 +347,8 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool) -> Params
         }
         if c.qk_norm:
             blk.update({
-                "q_norm": jnp.ones((L, H * D), dt),
-                "k_norm": jnp.ones((L, KV * D), dt),
+                "q_norm": jnp.ones((L, D if c.qk_norm == "head" else H * D), dt),
+                "k_norm": jnp.ones((L, D if c.qk_norm == "head" else KV * D), dt),
             })
     blk.update({
         "attn_norm": jnp.ones((L, E), dt),
@@ -456,14 +479,17 @@ def _layer_params(blocks: Params, config: LlamaConfig):
 def _qkv(h, p, positions, config: LlamaConfig):
     """Projections of the normed input: q (B, S, H, D) and k (B, S, KV,
     D) with rotary positions applied, v (B, S, KV, D).  With ``qk_norm``
-    q and k are RMS-normed over their WHOLE projected width (all heads
-    together, scales ``q_norm`` / ``k_norm``) before the rotation."""
+    q and k are RMS-normed before the rotation (scales ``q_norm`` /
+    ``k_norm``): over their WHOLE projected width, all heads together, or
+    (``"head"``) each head over its own D values under one (D,) scale."""
     c = config
     B, S = h.shape[:2]
 
     def normed(x, scale):
         if not c.qk_norm:
             return x
+        if c.qk_norm == "head":
+            return _rmsnorm(x, p[scale], c.rms_eps)
         return _rmsnorm(x.reshape(B, S, -1), p[scale], c.rms_eps).reshape(x.shape)
 
     q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype))
@@ -486,7 +512,8 @@ def _route(x, p, config: LlamaConfig):
     """Router of the expert layer.  x: (N, E).  Returns ``(weight (N,
     k) float32, expert (N, k) int32)`` in order of falling selection
     score.  ``softmax``: probabilities over ALL experts in float32,
-    ``lax.top_k`` of them, the chosen probabilities as they are.
+    ``lax.top_k`` of them, the chosen probabilities as they are (OLMoE)
+    or, with ``router_norm_topk``, divided by their sum (Qwen3-MoE, SDAR).
     ``sigmoid`` (DeepSeek-V3's ``noaux_tc`` with one group): scores
     ``sigmoid(logits)`` in float32; the top k of score + ``router_bias``
     are chosen, the bias is in the choice only; the weights are the
@@ -498,12 +525,17 @@ def _route(x, p, config: LlamaConfig):
         preferred_element_type=jnp.float32,
     )
     if c.router_scoring == "softmax":
-        return lax.top_k(jax.nn.softmax(logits, axis=-1), c.experts_per_token)
-    score = jax.nn.sigmoid(logits)
-    _, expert = lax.top_k(
-        score + p["router_bias"].astype(jnp.float32), c.experts_per_token
-    )
-    weight = jnp.take_along_axis(score, expert, axis=-1)
+        weight, expert = lax.top_k(
+            jax.nn.softmax(logits, axis=-1), c.experts_per_token
+        )
+        if not c.router_norm_topk:  # OLMoE: as they are
+            return weight, expert
+    else:
+        score = jax.nn.sigmoid(logits)
+        _, expert = lax.top_k(
+            score + p["router_bias"].astype(jnp.float32), c.experts_per_token
+        )
+        weight = jnp.take_along_axis(score, expert, axis=-1)
     if c.router_norm_topk:
         weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
     return weight * c.router_scale, expert
@@ -716,7 +748,7 @@ def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float
         per_key = c.num_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim + c.v_head_dim)
         attn = 6 * c.cache_layers * (per_key * seen + indexer)
     else:
-        attn = 12 * c.num_layers * c.embed_dim * S  # 2*2*3 * L * E * S
+        attn = 12 * c.num_layers * c.num_heads * c.head_dim * S  # 2*2*3 * L * HD * S
     return 6.0 * n + attn
 
 
@@ -930,15 +962,22 @@ def rolling_cache_len(config: LlamaConfig, prefill_chunk: int) -> int:
     return config.sliding_window + max(1, prefill_chunk) - 1
 
 
-def _cache_mask(positions, T: int, window: int):
+def _cache_mask(positions, T: int, window: int, block: int = 1):
     """(R, Sq, T), True where the query at ``positions[r, s]`` may see
-    cache slot t.  Full causal: slot t holds position t.  With a window
+    cache slot t.  Full causal: slot t holds position t.  With ``block``
+    B > 1 a query sees up to the END of its own block of B positions, ``t
+    <= (q // B) * B + B - 1``: the tokens of a block see each other and
+    every block before theirs (block diffusion).  With a window
     the cache is a rolling buffer: slot t as seen by query position q
     holds position q - ((q - t) mod T) — the newest position <= q
     congruent to t — valid iff non-negative and inside the window (slot
     correctness needs T >= window + Sq - 1: ``rolling_cache_len``)."""
     q_pos = positions[:, :, None]
     t_idx = jnp.arange(T)
+    if block > 1:
+        if window:
+            raise NotImplementedError("mask_block with a sliding window")
+        return t_idx <= (q_pos // block) * block + (block - 1)
     if not window:
         return t_idx <= q_pos
     t_pos = q_pos - ((q_pos - t_idx) % T)
@@ -966,6 +1005,11 @@ def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
     return out.reshape(B, Sq, H, D)
 
 
+#: tokens a row of an every-row step may bring and still be written into
+#: the carried cache in place (``_write_and_read``)
+_STEP_RUN = 8
+
+
 def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
     """The one place K/V enter a cache.  Writes ``new`` (R, Sq, KV, D)
     into the WHOLE carried ``cache`` (L, B, T, KV, D) at ``layer``, rows
@@ -974,11 +1018,14 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
     attention.  Which of the two comes first is chosen from static
     shapes, because each order copies where the other is in place:
 
-    - one token for every row (the decode step): write the B rows into
-      the carried cache, THEN index the layer's slab out of it.  XLA
-      reads that slab where it lies; taken first, with the rows put
-      into the copy, 67 MB of slab would move a layer.
-    - a run of tokens (a prefill, a chunk): take the addressed rows'
+    - a token, or a few (at most ``_STEP_RUN``: a block-diffusion step's
+      block), for every row: write them into the carried cache, THEN
+      index the layer's slab out of it.  The one-token step's attention
+      reads that slab where it lies; taken first, with the rows put into
+      the copy, 67 MB of slab would move a layer.  (At a block's 32 query
+      rows a KV head the XLA attention runs on the MXU and its own
+      lowering copies the slab once: PERF.md section 5, PR 36.)
+    - a longer run of tokens (a prefill, a chunk): take the addressed rows'
       slab FIRST, put the run into that copy for attention, and write
       the run into the cache separately.  Written first and then
       sliced, XLA copies the whole K and V cache every call (2 GiB at
@@ -987,10 +1034,12 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
     R, Sq = positions.shape
     rolling = config.sliding_window > 0
 
+    in_place = slot is None and Sq <= _STEP_RUN
+
     def write(buf, *lead):
         # buf[*lead[r], slot of positions[r, s]] = new[r, s]
-        if rolling or Sq == 1:
-            # one row write per token: a decode step's tokens lie in R
+        if rolling or in_place:
+            # one row write per token: a step's tokens lie in R
             # different rows; in a rolling buffer position t lives in
             # slot t mod T, so a run may wrap
             slots = positions % T if rolling else positions
@@ -1006,7 +1055,7 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
 
     rows, row0 = jnp.arange(R), 0 if slot is None else slot
     lead = (jnp.full((R,), layer), rows + row0)
-    if slot is None and Sq == 1:
+    if in_place:
         cache = write(cache, *lead)
         return cache, lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
     slab = lax.dynamic_slice(cache, (layer, row0, 0, 0, 0), (1, R, T, KV, D))[0]
@@ -1019,14 +1068,18 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
     heads' outputs (R, Sq, H, D), state, no counters)."""
     c = config
     q, kk, vv = _qkv(h, p, positions, c)
-    cache_k, slab_k = _write_and_read(
-        state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c
-    )
-    cache_v, slab_v = _write_and_read(
-        state["v"], vv.astype(c.dtype), p["cache_layer"], slot, positions, c
-    )
-    mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window)
-    attn = _grouped_attention(q, slab_k, slab_v, mask, c)
+    # named where it is the block attention, so that a trace finds its
+    # operations: the block's rows written, the slabs read, scores and mix
+    scope = jax.named_scope("block_attn") if c.mask_block > 1 else contextlib.nullcontext()
+    with scope:
+        cache_k, slab_k = _write_and_read(
+            state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c
+        )
+        cache_v, slab_v = _write_and_read(
+            state["v"], vv.astype(c.dtype), p["cache_layer"], slot, positions, c
+        )
+        mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window, c.mask_block)
+        attn = _grouped_attention(q, slab_k, slab_v, mask, c)
     return attn, {"k": cache_k, "v": cache_v}, {}
 
 
@@ -1422,8 +1475,9 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
     positions = start[:, None] + jnp.arange(tokens.shape[1])
     x = params["tok_embed"].astype(c.dtype)[tokens]
     # every row steps (one token each, or the few of a speculative
-    # step's verification) | one row's run from position 0
-    step = slot is None and (tokens.shape[1] == 1 or c.latent)
+    # step's verification or of a block-diffusion step's block) | one
+    # row's run from position 0
+    step = slot is None and (tokens.shape[1] <= _STEP_RUN or c.latent)
     state = {k: cache[k] for k in _STATE if k in cache}
     # a latent config's run reads no cache: its state stays out of the
     # loop and gets the run's rows after it (``_latent_attention``)
@@ -1528,7 +1582,7 @@ def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
 
 
 #: what a draw is for, the last word of its key (``draw_keys``)
-DRAW_TOKEN, DRAW_DRAFT, DRAW_ACCEPT, DRAW_RESIDUAL = 0, 1, 2, 3
+DRAW_TOKEN, DRAW_DRAFT, DRAW_ACCEPT, DRAW_RESIDUAL, DRAW_UNMASK = 0, 1, 2, 3, 4
 
 
 def draw_keys(key, request, position, purpose: int):
@@ -1538,7 +1592,9 @@ def draw_keys(key, request, position, purpose: int):
     position of the token the draw decides; ``purpose``: ``DRAW_TOKEN`` a
     token from the model's own distribution, ``DRAW_DRAFT`` the draft for
     that position, ``DRAW_ACCEPT`` the uniform number that accepts it or
-    not, ``DRAW_RESIDUAL`` the token in its place where it is rejected.
+    not, ``DRAW_RESIDUAL`` the token in its place where it is rejected,
+    ``DRAW_UNMASK`` a block-diffusion pass's candidate for a masked position
+    (``models/block_diffusion.py`` folds the pass's number in behind it).
     So a token's draw hangs on nothing but its request and its position:
     not on which rows share its step, nor on how many steps it took to get
     there, and a reference can replay it."""

@@ -13,12 +13,18 @@ A step yields one token a row — or, where the deployment drafts
 (``speculative_tokens=1``, a model with a multi-token-prediction module:
 ``models/mtp.py``), one or two: the module drafts a token, the main model
 verifies it in the same step, and the row advances by two where the draft
-is accepted.  ``temperature`` 0 decodes greedily; above 0 every token is
+is accepted.  Where it generates by diffusion over blocks
+(``diffusion_block=4``, a model trained for it: ``models/
+block_diffusion.py``) a step refines every row's block of four tokens in
+place and a row gets none of them until its block is committed, then all
+four.  Both are the ONE path of "a step with state on the device that
+yields 0..k ids a row" (``_launch_stateful`` / ``_hand_out``).
+``temperature`` 0 decodes greedily; above 0 every token is
 drawn from the main model's softmax at that temperature, with draws that
 hang on ``seed``, the request and the position only.  Whatever the
 options, a request gets exactly ``max_new_tokens`` ids, in order, one
-stream item each, every one distributed as the main model's: drafting
-changes how many steps they take, not what they are.
+stream item each; drafting changes how many steps they take, not what
+they are.
 
 Wire-up::
 
@@ -87,6 +93,11 @@ _SPEC_ACCEPTANCE = metrics.Gauge(
     "llm_spec_acceptance_rate",
     "drafted tokens the main model accepted over tokens drafted, so far",
 )
+#: block-diffusion deployments only, set where a step's tokens are delivered
+_DIFF_TOKENS_PER_FORWARD = metrics.Gauge(
+    "llm_diffusion_tokens_per_row_forward",
+    "tokens delivered over forwards of live rows (refining and commit), so far",
+)
 _OFF = contextlib.nullcontext()
 
 
@@ -116,18 +127,22 @@ class _Slot:
     """What the host knows of a cache row when it launches a step.  The
     row's token is not here: it is row i of ``LLMEngine._tokens``, the
     last launched step's choice, which stays on the device.  Where the
-    deployment drafts, the row's position stays there too (a step advances
-    it by one or two, ``LLMEngine._spec``), and the host counts what it has
+    step keeps its state on the device (drafting, block diffusion), the
+    row's position stays there too (a step advances it by a number only
+    the device knows, ``LLMEngine._spec``), and the host counts what it has
     delivered: ``remaining`` is then tokens still to DELIVER."""
 
-    __slots__ = ("queue", "pos", "remaining", "max_pos", "request")
+    __slots__ = ("queue", "pos", "remaining", "max_pos", "request", "pushed")
 
-    def __init__(self, queue, pos, remaining, max_pos, request):
+    def __init__(self, queue, pos, remaining, max_pos, request, pushed=None):
         self.queue = queue          # per-request token queue
         self.pos = pos              # position of the token the next step feeds
         self.remaining = remaining  # decode steps still to launch
         self.max_pos = max_pos
         self.request = request      # the request's number (its draws' key)
+        # when the request was pushed, while it waits for its first token
+        # (a block-diffusion prefill gives none); None once it has one
+        self.pushed = pushed
 
 
 class _Step:
@@ -137,9 +152,10 @@ class _Step:
 
     def __init__(self, tokens, rows, span):
         # (max_slots,) int32 on the device, the step's choice a row; of a
-        # drafting step (max_slots, 4): two tokens, how many count, accepted?
+        # stateful step (max_slots, k + extras): up to k ids, how many of
+        # them count, and what the step's kind tallies (``_hand_out``)
         self.tokens = tokens
-        # [(row, its _Slot, the request's last token?)]; of a drafting step
+        # [(row, its _Slot, the request's last token?)]; of a stateful step
         # [(row, its _Slot)]: which token is the last is not known yet
         self.rows = rows
         self.span = span      # its llm.step span, if traced
@@ -156,13 +172,15 @@ class LLMEngine:
     fed step k's choice as it is, a device array (``_tokens``; a prefill's
     first token is merged into it on the device), a row's position is its
     last plus one, and a row ends by counts the host holds (no stop
-    token).  Where the deployment drafts, a row's position and what it
-    still has to emit are device arrays too (``_spec``: a step advances a
-    row by one or two and only the device knows which), the host learns how
-    many tokens a step gave a row when it delivers them — one step late —
-    and a row is retired by what has been DELIVERED: the step launched
-    meanwhile steps that row once more, into its own slot, for nothing
-    (``spec_wasted_row_steps_total``).  So the loop launches step k+1,
+    token).  Where the step keeps its state on the device (the deployment
+    drafts, or generates by diffusion over blocks), a row's position and
+    what it still has to emit are device arrays too (``_spec``: a step
+    gives a row 0..k ids and only the device knows how many), the host
+    learns how many tokens a step gave a row when it delivers them — one
+    step late — and a row is retired by what has been DELIVERED: the step
+    launched meanwhile steps that row once more, into its own slot, for
+    nothing (``spec_`` / ``diffusion_wasted_row_steps_total``).  So the loop
+    launches step k+1,
     then waits for step k's
     tokens, hands them out and yields to their consumers while the device
     computes.  Only an admission drains the pipeline: the prefill is
@@ -174,15 +192,19 @@ class LLMEngine:
     def __init__(self, params, config, *, max_slots: int = 4,
                  max_len: int = 256, max_prompt_len: Optional[int] = None,
                  speculative_tokens: int = 0, temperature: float = 0.0,
-                 seed: int = 0):
+                 seed: int = 0, diffusion_block: int = 0,
+                 denoising_steps: Optional[int] = None,
+                 confidence_threshold: float = 0.9):
+        import dataclasses
+
         import jax.numpy as jnp
 
         from ray_tpu.models import llama
 
         self._llama = llama
         self.params = params
-        self.config = config
         self.speculative = int(speculative_tokens)
+        self.diffusion_block = int(diffusion_block)
         self.temperature = float(temperature or 0.0)
         if self.speculative not in (0, 1):
             raise ValueError("speculative_tokens is 0 or 1: one drafted token a step")
@@ -191,15 +213,49 @@ class LLMEngine:
                 "speculative_tokens=1 needs a model with a multi-token-prediction "
                 "module to draft with (LlamaConfig.mtp_layers)"
             )
-        # the two programs: the model's own, or the drafting ones
+        if self.diffusion_block and (
+            self.speculative or config.latent or config.sliding_window
+            or max_len % self.diffusion_block
+        ):
+            raise ValueError(
+                "diffusion_block goes with a K/V-cache model without a sliding "
+                "window, without speculative_tokens, and with max_len a whole "
+                f"number of blocks (max_len {max_len}, block {self.diffusion_block})"
+            )
+        # the two programs: the model's own, or a kind whose step keeps the
+        # rows' state on the device and yields 0..k ids a row (drafting,
+        # block diffusion): their state, the tokens a step brings a row (as
+        # many ids as it may give it), and the programs' further static
+        # arguments
         self._programs = llama
         self._spec = self._key = None
+        self._step_ids = 1
+        self._step_options: dict = {}
         if self.speculative:
             from ray_tpu.models import mtp
 
             self._programs = mtp
             self._spec = mtp.init_state(config, max_slots)
-        if self.speculative or self.temperature > 0.0:
+            self._step_ids = 2  # in the model and in the module
+        elif self.diffusion_block:
+            from ray_tpu.models import block_diffusion
+
+            # the model is run under the block mask the deployment generates by
+            config = dataclasses.replace(config, mask_block=self.diffusion_block)
+            settings = block_diffusion.Settings(
+                block=self.diffusion_block,
+                denoising_steps=denoising_steps or self.diffusion_block,
+                threshold=float(confidence_threshold),
+                # the vocabulary's last row stands for the MASK token
+                mask_id=config.vocab_size - 1,
+            )
+            self._programs = block_diffusion
+            self._spec = block_diffusion.init_state(config, max_slots, settings)
+            self._step_ids = self.diffusion_block
+            self._step_options = {"settings": settings}
+        self.config = config
+        self.stateful = self._spec is not None
+        if self.stateful or self.temperature > 0.0:
             import jax
 
             self._key = jax.random.key(seed)
@@ -256,6 +312,18 @@ class LLMEngine:
         self.spec_accepted_total = 0
         self.spec_tokens_emitted_total = 0
         self.spec_wasted_row_steps_total = 0
+        # block-diffusion deployments, by ``block_diffusion.OUT_FIELDS``:
+        # forwards of live rows, of those commits, tokens unmasked, of those
+        # by the threshold, blocks committed (= commit forwards), rows
+        # stepped once more after their budget was met, and keys the live
+        # rows' queries could see, summed over layers
+        self.diffusion_forwards_total = 0
+        self.diffusion_commit_forwards_total = 0
+        self.diffusion_tokens_unmasked_total = 0
+        self.diffusion_threshold_transfers_total = 0
+        self.diffusion_tokens_emitted_total = 0
+        self.diffusion_wasted_row_steps_total = 0
+        self.kv_keys_visible_step = 0
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -403,9 +471,9 @@ class LLMEngine:
                 self.slots = [None] * self.max_slots
                 self._flying.clear()
                 self._tokens = jnp.zeros((self.max_slots,), jnp.int32)
-                if self.speculative:
+                if self.stateful:
                     self._spec = self._programs.init_state(
-                        self.config, self.max_slots
+                        self.config, self.max_slots, *self._step_options.values()
                     )
                 self.cache = self._llama.init_cache(
                     self.config, self.max_slots, self.cache_len
@@ -472,11 +540,12 @@ class LLMEngine:
                 row = jnp.int32(slot)
 
                 def _prefill():
-                    if self.speculative:
+                    if self.stateful:
                         return self._programs.prefill_into_slot(
                             self.params, toks, self.cache, row, self._spec,
                             self._key, jnp.int32(req.number),
                             jnp.int32(max_new), cfg, self.temperature,
+                            **self._step_options,
                         )[:3]
                     logits, cache = llama.prefill_into_slot(
                         self.params, toks, self.cache, row, cfg,
@@ -490,22 +559,30 @@ class LLMEngine:
 
                 async with self._cache_lock:
                     first, self.cache, *spec = await asyncio.to_thread(_prefill)
-                    self.rows_stepped_total += S0
+                    # a block-diffusion prefill is given the prompt's whole blocks
+                    self.rows_stepped_total += (
+                        S0 - S0 % self.diffusion_block if self.diffusion_block else S0
+                    )
                 if spec:  # the row's first token is in its state
                     self._spec, = spec
                 elif max_new > 1:
                     self._tokens = llama.set_row(self._tokens, row, first)
                 if self._flying:
                     await self._deliver()
-                await q.put(int(first))
-            _ENGINE_TTFT_MS.observe((time.monotonic() - req.pushed) * 1e3)
+                first = int(first)  # -1: this prefill gives no token (diffusion)
+                if first >= 0:
+                    await q.put(first)
             prefilled += 1
-            if max_new <= 1:
+            if first >= 0:
+                _ENGINE_TTFT_MS.observe((time.monotonic() - req.pushed) * 1e3)
+                max_new -= 1
+            if max_new <= 0:
                 await q.put(_END)
                 continue
             self.slots[slot] = _Slot(
-                queue=q, pos=S0, remaining=max_new - 1,
+                queue=q, pos=S0, remaining=max_new,
                 max_pos=self.max_len - 1, request=req.number,
+                pushed=None if first >= 0 else req.pushed,
             )
         return prefilled
 
@@ -520,8 +597,8 @@ class LLMEngine:
 
         llama = self._llama
         cfg = self.config
-        if self.speculative:
-            await self._launch_drafting(life, active)
+        if self.stateful:
+            await self._launch_stateful(life, active)
             return
         sampled = self.temperature > 0.0
         with _part(life, "llm.step.build"):
@@ -567,14 +644,16 @@ class LLMEngine:
                     self.slots[i] = None
             self._flying.append(_Step(self._tokens, rows, life))
 
-    async def _launch_drafting(self, life: Optional[tracing.Span],
+    async def _launch_stateful(self, life: Optional[tracing.Span],
                                active: List[int]) -> None:
-        """``_launch`` where the deployment drafts: one speculative step
-        over all slots on the rows' state the last one left on the device
-        (positions, tokens, the module's inputs, what each has left to
-        emit).  The host builds no array and waits for nothing; its
-        ``llm.step.build`` is the list of the rows it will hand tokens to
-        (a step's life keeps its five parts: PERF.md section 3)."""
+        """``_launch`` where the step keeps the rows' state on the device
+        and yields 0..k ids a row — a speculative step (``models/mtp.py``),
+        a block-diffusion step (``models/block_diffusion.py``): one step
+        over all slots on the state the last one left there (positions,
+        tokens or blocks, what each row has left to emit).  The host builds
+        no array and waits for nothing; its ``llm.step.build`` is the list
+        of the rows it will hand tokens to (a step's life keeps its five
+        parts: PERF.md section 3)."""
         with _part(life, "llm.step.build"):
             # which rows end with this step only its tokens will tell
             rows = [(i, self.slots[i]) for i in active]
@@ -584,49 +663,82 @@ class LLMEngine:
                 with _part(dispatch, "llm.step.launch"):
                     return self._programs.decode_step_rowwise(
                         self.params, state, self.cache, self._key,
-                        self.config, self.temperature,
+                        self.config, self.temperature, **self._step_options,
                     )[:3]
 
             async with self._cache_lock:
                 outs, self._spec, self.cache = await asyncio.to_thread(_step)
-                # two token rows a slot, in the model and in the module
-                self.rows_stepped_total += 2 * self.max_slots
+                self.rows_stepped_total += self._step_ids * self.max_slots
             self.decode_steps_total += 1
             if self._flying:
                 self.steps_launched_ahead_total += 1
-            if life is not None:
+            if life is not None and self.speculative:
                 life.attrs["drafted"] = len(active)
             self._flying.append(_Step(outs, rows, life))
 
     async def _hand_out(self, step: _Step, outs) -> None:
-        """A drafting step's tokens to their queues: one or two a row, in
-        order, never one past the request's budget; a row whose budget is
-        met is retired HERE, a step later than its last token was made.
-        ``outs`` (max_slots, 4): two tokens, how many count, accepted?"""
-        emitted = accepted = 0
+        """A stateful step's tokens to their queues: 0..k a row, in order,
+        never one past the request's budget; a row whose budget is met is
+        retired HERE, a step later than its last token was made.  ``outs``
+        (max_slots, k + extras): k ids, how many of them count, and what
+        the step's kind tallies (``_tally``)."""
+        import numpy as np
+
+        k = self._step_ids
+        emitted = wasted = 0
+        live = []
         for i, slot in step.rows:
             if slot.remaining <= 0:
                 # retired when the step before was delivered; this one
                 # had been launched by then
-                self.spec_wasted_row_steps_total += 1
+                wasted += 1
                 continue
-            n = min(int(outs[i, 2]), slot.remaining)
+            live.append(i)
+            n = min(int(outs[i, k]), slot.remaining)
             for tok in outs[i, :n]:
                 await slot.queue.put(int(tok))
+            if n and slot.pushed is not None:  # its prefill gave no token
+                _ENGINE_TTFT_MS.observe((time.monotonic() - slot.pushed) * 1e3)
+                slot.pushed = None
             slot.remaining -= n
             emitted += n
-            accepted += int(outs[i, 3])
-            self.spec_drafted_total += 1
             if slot.remaining <= 0:
                 await slot.queue.put(_END)
                 if self.slots[i] is slot:
                     self.slots[i] = None
-        self.spec_tokens_emitted_total += emitted
-        self.spec_accepted_total += accepted
-        if self.spec_drafted_total:
-            _SPEC_ACCEPTANCE.set(self.spec_accepted_total / self.spec_drafted_total)
+        extras = outs[np.asarray(live, np.int64), k + 1:].sum(0)
+        attrs = self._tally(emitted, wasted, extras, len(live))
         if step.span is not None:
-            step.span.attrs.update(emitted=emitted, accepted=accepted)
+            step.span.attrs.update(emitted=emitted, **attrs)
+
+    def _tally(self, emitted: int, wasted: int, extras, rows: int) -> dict:
+        """One delivered stateful step into the running totals of its kind;
+        -> the step's span attributes.  ``extras``: the columns of ``outs``
+        behind the count, summed over the ``rows`` rows the host still held
+        a request in (drafting: accepted?; block diffusion:
+        ``block_diffusion.OUT_FIELDS`` behind ``count``)."""
+        if self.speculative:
+            accepted = int(extras[0])
+            self.spec_wasted_row_steps_total += wasted
+            self.spec_drafted_total += rows
+            self.spec_tokens_emitted_total += emitted
+            self.spec_accepted_total += accepted
+            if self.spec_drafted_total:
+                _SPEC_ACCEPTANCE.set(self.spec_accepted_total / self.spec_drafted_total)
+            return {"accepted": accepted}
+        forwards, committed, unmasked, by_threshold, visible = (int(x) for x in extras)
+        self.diffusion_wasted_row_steps_total += wasted
+        self.diffusion_forwards_total += forwards
+        self.diffusion_commit_forwards_total += committed
+        self.diffusion_tokens_unmasked_total += unmasked
+        self.diffusion_threshold_transfers_total += by_threshold
+        self.diffusion_tokens_emitted_total += emitted
+        self.kv_keys_visible_step += visible * self.config.num_layers
+        if self.diffusion_forwards_total:
+            _DIFF_TOKENS_PER_FORWARD.set(
+                self.diffusion_tokens_emitted_total / self.diffusion_forwards_total
+            )
+        return {"committed": committed, "unmasked": unmasked}
 
     async def _deliver(self) -> None:
         """Wait for the oldest step in flight, put its tokens on their
@@ -638,7 +750,7 @@ class LLMEngine:
             nxt = np.asarray(step.tokens)
         self._flying.popleft()
         with _part(step.span, "llm.step.deliver"):
-            if self.speculative:
+            if self.stateful:
                 await self._hand_out(step, nxt)
             else:
                 for i, slot, last in step.rows:
@@ -700,14 +812,21 @@ class LlamaDeployment:
     """Decode replica: tiny-config by default, or real weights via a
     ``weights_ref`` (object-store ref) / ``weights_loader`` callable.
     ``temperature`` (0: greedy), ``speculative_tokens`` (0, or 1 with a
-    model that has a multi-token-prediction module) and ``seed`` (of the
-    default weights and of every draw) are the deployment's: see
+    model that has a multi-token-prediction module), ``diffusion_block``
+    (0, or the block length of a model that generates by diffusion over
+    blocks, with ``denoising_steps`` (default: the block's length) and
+    ``confidence_threshold``; the vocabulary's last row stands for the MASK
+    token: ``models/block_diffusion.py``) and ``seed`` (of
+    the default weights and of every draw) are the deployment's: see
     ``LLMEngine``."""
 
     def __init__(self, config=None, weights_ref=None, weights_loader=None,
                  max_slots: int = 4, max_len: int = 256,
                  max_prompt_len: Optional[int] = None, seed: int = 0,
-                 speculative_tokens: int = 0, temperature: float = 0.0):
+                 speculative_tokens: int = 0, temperature: float = 0.0,
+                 diffusion_block: int = 0,
+                 denoising_steps: Optional[int] = None,
+                 confidence_threshold: float = 0.9):
         import jax
 
         from ray_tpu.models import llama
@@ -733,7 +852,12 @@ class LlamaDeployment:
                 max_prompt_len=max_prompt_len,
                 speculative_tokens=speculative_tokens,
                 temperature=temperature, seed=seed,
+                diffusion_block=diffusion_block,
+                denoising_steps=denoising_steps,
+                confidence_threshold=confidence_threshold,
             )
+            # the engine's: under the block mask where it generates by diffusion
+            self.config = self.engine.config
             started.attrs["cache_bytes"] = _tree_bytes(self.engine.cache)
 
     async def stats(self) -> dict:
@@ -771,7 +895,18 @@ class LlamaDeployment:
         less what fell past a budget), ``spec_wasted_row_steps_total`` (rows
         stepped once more after their budget was met), and the gauge
         ``llm_spec_acceptance_rate``; ``programs`` then counts the drafting
-        versions of the two programs (``models/mtp.py``).  ``cache_bytes``
+        versions of the two programs (``models/mtp.py``).  A block-diffusion
+        deployment: ``diffusion_forwards_total`` (forwards of live rows),
+        ``diffusion_commit_forwards_total`` = ``diffusion_blocks_committed_
+        total`` (of those, the ones that found a block without MASK and
+        committed it), ``diffusion_tokens_unmasked_total`` (of those,
+        ``diffusion_threshold_transfers_total`` by the confidence
+        threshold), ``diffusion_tokens_emitted_total``, ``diffusion_wasted_
+        row_steps_total``, ``kv_keys_visible_step`` (keys the live rows'
+        queries could see, over layers, rows and steps: a row's block sees
+        the same keys), and the gauge ``llm_diffusion_tokens_per_row_
+        forward``; ``programs`` then counts ``models/block_diffusion.py``'s
+        versions.  ``cache_bytes``
         is what the cache holds, by entry (the module's layer is one of
         ``ckv``'s)."""
         import jax
@@ -782,7 +917,7 @@ class LlamaDeployment:
         dev = devices[0]
         mem = dev.memory_stats() or {}
         counters = await self.engine.cache_counters()
-        programs = self.engine._programs  # llama's, or the drafting ones
+        programs = self.engine._programs  # llama's, or a stateful step's
         if self.engine.speculative:
             counters.update({
                 k: getattr(self.engine, k) for k in (
@@ -790,6 +925,19 @@ class LlamaDeployment:
                     "spec_tokens_emitted_total", "spec_wasted_row_steps_total",
                 )
             })
+        if self.engine.diffusion_block:
+            counters.update({
+                k: getattr(self.engine, k) for k in (
+                    "diffusion_forwards_total", "diffusion_commit_forwards_total",
+                    "diffusion_tokens_unmasked_total",
+                    "diffusion_threshold_transfers_total",
+                    "diffusion_tokens_emitted_total",
+                    "diffusion_wasted_row_steps_total", "kv_keys_visible_step",
+                )
+            })
+            counters["diffusion_blocks_committed_total"] = (
+                self.engine.diffusion_commit_forwards_total
+            )
         return {
             **counters,
             # what the cache holds, entry by entry (``k``/``v``, or a

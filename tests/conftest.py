@@ -160,10 +160,21 @@ def pytest_collection_modifyitems(config, items):
                 "and only a `benchmark` PR may edit a file under BENCHMARK.json's "
                 "paths (PERF.md section 7, harness edit 12): strict, as above"
             )))
+        if _PINNED_TO_EIGHT_CELLS in item.nodeid:
+            item.add_marker(pytest.mark.xfail(strict=True, reason=(
+                "PR 34's test holds each setup_* metric's cells to be the "
+                "benchmark's eight (len(workloads) == 8); PR 36 appended the ninth "
+                "cell to the benchmark and to those lists, and only a `benchmark` "
+                "PR may edit a file under BENCHMARK.json's paths (PERF.md section 7, "
+                "harness edit 13): strict, as above"
+            )))
 
 
 _PINNED_TO_SEVEN_CELLS = (
     "test_chipbench_glm.py::test_benchmark_json_gains_the_cell_and_nothing_else_changes"
+)
+_PINNED_TO_EIGHT_CELLS = (
+    "test_chipbench_startup.py::test_declared_with_a_reader_in_all_eight_cells["
 )
 _PINNED_TO_LAST_SIXTEEN = (
     "test_chipbench_joyai.py::"

@@ -268,6 +268,46 @@ class TestLlamaHF:
         np.testing.assert_allclose(ours, hf_logits, atol=2e-3, rtol=2e-3)
 
 
+class TestQwen3MoeHF:
+    """The block SDAR-30B-A3B shares with Qwen3-MoE, against transformers'
+    own: ``head_dim`` off ``hidden_size // heads``, per-head ``q_norm`` /
+    ``k_norm``, softmax top-k renormalised (``norm_topk_prob``)."""
+
+    def test_logit_parity(self):
+        transformers = pytest.importorskip("transformers")
+        torch = pytest.importorskip("torch")
+        if not hasattr(transformers, "Qwen3MoeForCausalLM"):
+            pytest.skip("this transformers has no Qwen3-MoE")
+        torch.manual_seed(0)
+        hf_cfg = transformers.Qwen3MoeConfig(
+            vocab_size=128, hidden_size=32, intermediate_size=64,
+            moe_intermediate_size=24, num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, num_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=True, decoder_sparse_step=1, mlp_only_layers=[],
+            max_position_embeddings=64, rms_norm_eps=1e-6, rope_theta=1e6,
+            tie_word_embeddings=False, attention_dropout=0.0,
+            use_sliding_window=False, router_aux_loss_coef=0.0,
+        )
+        model = transformers.Qwen3MoeForCausalLM(hf_cfg).eval()
+        with torch.no_grad():  # norms off one, so that they are seen
+            for name, p in model.named_parameters():
+                if "norm" in name:
+                    p.add_(0.2 * torch.randn_like(p))
+        from ray_tpu.models.hf import llama_params_from_hf
+
+        params, config = llama_params_from_hf(model, dtype=jnp.float32, remat=False)
+        assert config.head_dim == 16 != config.embed_dim // config.num_heads
+        assert config.qk_norm == "head" and config.router_norm_topk
+        assert params["blocks"]["q_norm"].shape == (2, 16)
+        assert params["blocks"]["w_gate"].shape == (2, 8, 32, 24)
+        tokens = np.random.default_rng(0).integers(0, 128, size=(2, 13), dtype=np.int64)
+        with torch.no_grad():
+            hf_logits = model(torch.from_numpy(tokens)).logits.numpy()
+        ours = np.asarray(
+            llama.forward(params, jnp.asarray(tokens, jnp.int32), config), np.float32)
+        np.testing.assert_allclose(ours, hf_logits, atol=2e-3, rtol=2e-3)
+
+
 class TestLlamaServe:
     def test_llama_inference_replica(self):
         """SURVEY §7 config-5 shape: a Serve replica hosting the LM,

@@ -10,6 +10,7 @@ before, under 1 MB after — and as arrays of the expanded shape in its
 text.  A compile that passes is not a chip run: nothing executes here.
 """
 
+import dataclasses
 import functools
 import json
 import os
@@ -381,3 +382,75 @@ def test_joyais_speculative_programs_fit_and_stream_a_rows_blocks_once(
             assert made not in text, f"a layer's slab is cut out of the cache: {made}"
         for expanded in ("4096,32,192]", "4096,32,128]", "32,4096,192]", "32,4096,128]"):
             assert expanded not in text, f"keys or values expanded over the cache: {expanded}"
+
+
+SDAR_FILE = os.path.join(os.path.dirname(CONFIG_FILE), "sdar-30b-a3b-ep8.json")
+
+
+def test_block_diffusion_step_copies_no_cache_slab(
+        v5e_chip, no_compile_cache, monkeypatch):
+    """SDAR's step program at the published widths (``sdar-30b-a3b-ep8``:
+    14.07 GB of weights and cache live of 16), compiled for the chip.  The
+    step brings FOUR tokens for every row.  Written through the
+    run-of-tokens branch of ``_write_and_read`` (slab first, the run put into
+    the copy, the run written into the cache apart) XLA copies K and V WHOLE,
+    2.25 GB each, and the program does not fit the chip (17.6 GB of 15.75;
+    compile-only, PR 36).  Written in place and then indexed, both caches are
+    aliased and the temporaries are 85 MB: the float32 scores (25 MB) and
+    ONE layer's slab (50 MB, K's and then V's through the same buffer),
+    which the XLA attention's own lowering makes — at 32 query rows a KV head
+    it runs on the MXU and wants the positions on the sublanes, where the
+    cache has the 4 KV heads; the one-token step (4 rows a head) reads the
+    slab where it lies.  That copy is the attention's, not the write's: a
+    length-aware kernel in its place is ROADMAP R6's, and this guard holds
+    what ``_write_and_read`` owes: no copy of a cache, and no second slab."""
+    from chipbench.jobs.serve_diffusion import sdar_config
+    from ray_tpu.models import block_diffusion
+    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.serve.llm import LLMEngine  # noqa: F401 — the options' one reader
+
+    monkeypatch.setattr(grouped_matmul, "implementation", lambda: "pallas_gmm")
+    with open(SDAR_FILE) as f:
+        served = json.load(f)
+    serving = served["serving"]
+    config = dataclasses.replace(
+        sdar_config(served), mask_block=serving["diffusion_block"])
+    slots, max_len = serving["max_slots"], serving["max_len"]
+    settings = block_diffusion.Settings(
+        block=serving["diffusion_block"], denoising_steps=serving["denoising_steps"],
+        threshold=serving["confidence_threshold"], mask_id=config.vocab_size - 1,
+    )
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init, config=config), jax.random.key(0)
+    ))
+    cache = on_chip(jax.eval_shape(
+        functools.partial(llama.init_cache, config, slots, max_len)
+    ))
+    state = on_chip(jax.eval_shape(
+        functools.partial(block_diffusion.init_state, config, slots, settings)
+    ))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert weights == 2 * 4_620_433_408  # the configuration's reckoning, bf16
+    compiled = block_diffusion.decode_step_rowwise.lower(
+        params, state, cache, key, config, 1.0, settings
+    ).compile()
+    mem = compiled.memory_analysis()
+    # K and V (2 x 2.42 GB) are updated in place
+    assert mem.alias_size_in_bytes >= 4.8e9, mem
+    assert mem.temp_size_in_bytes < 128 * 2**20, mem
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%gmm." in text
+    kv, d = config.num_kv_heads, config.head_dim
+    assert (slots, max_len, kv, d) == (32, 1536, 4, 128)
+    # no instruction makes a new K or V cache: the block's rows go into the
+    # carried one (a scatter of 128 rows)
+    whole = "bf16[{}]".format(",".join(map(str, cache["k"].shape)))
+    assert not re.findall(rf"= {re.escape(whole)}\S* copy\(", text)
