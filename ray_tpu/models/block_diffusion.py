@@ -73,8 +73,10 @@ from ray_tpu.models.llama import DRAW_UNMASK, LlamaConfig, Params
 
 #: columns of a step's ``outs`` behind the block's ids: how many of them
 #: count, and of this row-step: was the row live, did it commit a block,
-#: tokens unmasked, of those by the threshold, keys its queries could see
-OUT_FIELDS = ("count", "live", "committed", "unmasked", "by_threshold", "visible")
+#: tokens unmasked, of those by the threshold, keys its queries could see;
+#: and, live or not, the last cache slot the row's attention was given
+OUT_FIELDS = ("count", "live", "committed", "unmasked", "by_threshold", "visible",
+              "last")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,7 +142,7 @@ def transfers(conf, masked, settings: Settings):
 
 def _refine(params: Params, state: Params, cache: Params, key,
             config: LlamaConfig, temperature: float, settings: Settings):
-    """One step -> (outs, state, cache, detail): ``outs`` (B, Bk + 6) int32:
+    """One step -> (outs, state, cache, detail): ``outs`` (B, Bk + 7) int32:
     the ids a row emits first, then ``OUT_FIELDS``; ``detail``: what the
     step decided from (module docstring)."""
     c, s = config, settings
@@ -183,7 +185,7 @@ def _refine(params: Params, state: Params, cache: Params, key,
         unmasked = transfer.sum(-1, dtype=jnp.int32)
         outs = jnp.concatenate([ids, jnp.stack([
             count, live.astype(jnp.int32), commit.astype(jnp.int32), unmasked,
-            jnp.where(enough, unmasked, 0), jnp.where(live, n + Bk, 0),
+            jnp.where(enough, unmasked, 0), jnp.where(live, n + Bk, 0), n + Bk - 1,
         ], axis=1)], axis=1)
     return outs, state, cache, {
         "pos": n, "passes": passes, "block": cur, "logits": logits,
@@ -198,7 +200,7 @@ def decode_step_rowwise(params, state, cache, key, config: LlamaConfig,
                         temperature: float, settings: Settings):
     """One block-diffusion step for every row: the engine's decode program
     where it generates by diffusion (the module's docstring).  -> (outs (B,
-    Bk + 6) int32, state, cache, detail)."""
+    Bk + 7) int32, state, cache, detail)."""
     return _refine(params, state, cache, key, config, temperature, settings)
 
 

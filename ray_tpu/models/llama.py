@@ -78,7 +78,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import latent_decode_attention
+from ray_tpu.ops import kv_decode_attention, latent_decode_attention
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -804,12 +804,20 @@ def _gen_step(params, padded, length, key, *, config, temperature):
 
 
 def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
-    """Fixed-bucket KV cache: (L, B, max_len, KV, D) per tensor, bf16.
-    Static shapes — one compiled prefill per prompt length + one compiled
-    decode step serve any request up to max_len.  The jitted entry
-    points take it donated and hand it back: the one step behind them
-    (``_cached_step``) carries it whole through its layer loop, writes
-    only the new tokens' K/V in place, and attention reads it as stored.
+    """Fixed-bucket KV cache: (L, B, max_len, KV x D) per tensor, bf16: a
+    token's KV heads side by side in ONE row of whole 128-lane tiles, head
+    ``kv`` the static lane slice ``[kv D, (kv + 1) D)``.  (With the heads
+    an axis of their own, (..., KV, D), 4 or 8 of them lie on the chip's
+    sublanes, and XLA's attention first copied each layer's slab into
+    another layout: 2 x 50 MB a layer in the block-diffusion step, PR 36;
+    no kernel can fetch a block of keys out of that without the same
+    relayout.)  Static shapes — one compiled prefill per prompt length +
+    one compiled decode step serve any request up to max_len.  The jitted
+    entry points take it donated and hand it back: the one step behind
+    them (``_cached_step``) carries it whole through its layer loop,
+    writes only the new tokens' K/V in place, and an every-row step's
+    attention reads it where it lies, block by block up to each row's
+    last visible key (``ops/kv_decode_attention.py``).
 
     With ``sliding_window`` the cache is a ROLLING buffer (slot =
     position mod max_len), so ``max_len`` can be as small as
@@ -864,7 +872,7 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             "dsa_keys": jnp.zeros((c.num_layers, 3, 2, 2), jnp.int32),
         }
     else:
-        shape = (c.num_layers, batch_size, max_len, c.num_kv_heads, c.head_dim)
+        shape = (c.num_layers, batch_size, max_len, c.num_kv_heads * c.head_dim)
         cache = {
             "k": jnp.zeros(shape, c.dtype),
             "v": jnp.zeros(shape, c.dtype),
@@ -984,17 +992,30 @@ def _cache_mask(positions, T: int, window: int, block: int = 1):
     return (t_pos >= 0) & (t_pos > q_pos - window)
 
 
+def _last_visible(positions, block: int = 1):
+    """(R, Sq): the last cache slot the query at ``positions[r, s]`` may see
+    in a full-causal cache — its own, or under ``block`` B > 1 the END of
+    its block of B positions (``_cache_mask``'s rule as a position)."""
+    if block > 1:
+        return (positions // block) * block + (block - 1)
+    return positions
+
+
 def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
-    """The ONE cached-attention body.  q: (B, Sq, H, D) attends over the
-    cache AS STORED, (B, T, KV, D): the H = KV * G query heads fold to
-    (KV, G) and contract against their KV head directly, so K/V are
-    never expanded (no ``jnp.repeat``) and every cached byte is read
-    once, in the cache's dtype.  MHA is G = 1, the same code.  mask:
-    (B, Sq, T), True where query q may see slot t.  Scores and softmax
-    in f32, probabilities and values in ``config.dtype``."""
+    """The ONE cached-attention body in plain XLA.  q: (B, Sq, H, D)
+    attends over the rows' slabs of the cache, (B, T, KV x D): the H = KV
+    * G query heads fold to (KV, G) and contract against their KV head
+    directly, so K/V are never expanded (no ``jnp.repeat``) and every
+    cached byte is read once, in the cache's dtype.  MHA is G = 1, the
+    same code.  mask: (B, Sq, T), True where query q may see slot t.
+    Scores and softmax in f32, probabilities and values in
+    ``config.dtype``.  (An every-row step over whole blocks runs the same
+    mathematics in flash order: ``ops/kv_decode_attention.py``.)"""
     c = config
     B, Sq, H, D = q.shape
-    KV = k_cache.shape[2]
+    KV = c.num_kv_heads
+    k_cache = k_cache.reshape(*k_cache.shape[:2], KV, D)
+    v_cache = v_cache.reshape(*v_cache.shape[:2], KV, D)
     q = q.reshape(B, Sq, KV, H // KV, D)
     scores = jnp.einsum(
         "bqkgd,btkd->bkgqt", q, k_cache, preferred_element_type=jnp.float32
@@ -1010,28 +1031,32 @@ def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
 _STEP_RUN = 8
 
 
-def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
+def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig,
+                    slab: bool = True):
     """The one place K/V enter a cache.  Writes ``new`` (R, Sq, KV, D)
-    into the WHOLE carried ``cache`` (L, B, T, KV, D) at ``layer``, rows
+    into the WHOLE carried ``cache`` (L, B, T, KV x D) at ``layer``, rows
     ``slot`` (None: all B rows) and ``positions``; returns the cache and
-    the (R, T, KV, D) slab of those rows, new tokens included, for
-    attention.  Which of the two comes first is chosen from static
-    shapes, because each order copies where the other is in place:
+    the (R, T, KV x D) slab of those rows, new tokens included, for
+    attention in plain XLA — or, with ``slab`` False, no slab: the
+    attention kernel reads the carried cache itself.  Which of the two
+    comes first is chosen from static shapes, because each order copies
+    where the other is in place:
 
     - a token, or a few (at most ``_STEP_RUN``: a block-diffusion step's
       block), for every row: write them into the carried cache, THEN
-      index the layer's slab out of it.  The one-token step's attention
-      reads that slab where it lies; taken first, with the rows put into
-      the copy, 67 MB of slab would move a layer.  (At a block's 32 query
-      rows a KV head the XLA attention runs on the MXU and its own
-      lowering copies the slab once: PERF.md section 5, PR 36.)
+      read.  ``ops/kv_decode_attention.py`` fetches the live blocks of
+      each row out of the carried cache and nothing is made in front of
+      it; where its static rule keeps XLA's body (a cache of no whole
+      blocks, a rolling one), the layer's slab is indexed out of the
+      cache and read where it lies.
     - a longer run of tokens (a prefill, a chunk): take the addressed rows'
       slab FIRST, put the run into that copy for attention, and write
       the run into the cache separately.  Written first and then
       sliced, XLA copies the whole K and V cache every call (2 GiB at
       the serving widths; tests/test_llama_decode_compile.py)."""
-    L, B, T, KV, D = cache.shape
+    L, B, T, row = cache.shape
     R, Sq = positions.shape
+    new = new.reshape(R, Sq, row)
     rolling = config.sliding_window > 0
 
     in_place = slot is None and Sq <= _STEP_RUN
@@ -1049,7 +1074,7 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
         # the cache through the layer loop in another layout and copies
         # it whole twice a call; token by token it is Sq row writes.
         for r in range(R):
-            at = (*(i[r] for i in lead), positions[r, 0], 0, 0)
+            at = (*(i[r] for i in lead), positions[r, 0], 0)
             buf = lax.dynamic_update_slice(buf, new[r][(None,) * len(lead)], at)
         return buf
 
@@ -1057,29 +1082,48 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig):
     lead = (jnp.full((R,), layer), rows + row0)
     if in_place:
         cache = write(cache, *lead)
+        if not slab:
+            return cache, None
         return cache, lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
-    slab = lax.dynamic_slice(cache, (layer, row0, 0, 0, 0), (1, R, T, KV, D))[0]
-    return write(cache, *lead), write(slab, rows)
+    taken = lax.dynamic_slice(cache, (layer, row0, 0, 0), (1, R, T, row))[0]
+    return write(cache, *lead), write(taken, rows)
 
 
 def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
     """Attention over a K/V cache: projections, the new tokens' K/V
-    written, grouped attention over the rows' slabs.  Returns (the
+    written, then attention over the rows' keys — for an every-row step
+    over whole blocks the kernel of ``ops/kv_decode_attention.py`` on the
+    carried cache (which body: its ``implementation``, from static
+    shapes), else grouped attention over the rows' slabs.  Returns (the
     heads' outputs (R, Sq, H, D), state, no counters)."""
     c = config
     q, kk, vv = _qkv(h, p, positions, c)
+    T = state["k"].shape[2]
+    streamed = (
+        slot is None and positions.shape[1] <= _STEP_RUN
+        and kv_decode_attention.implementation(T, c.head_dim, c.sliding_window)
+        == "streamed"
+    )
     # named where it is the block attention, so that a trace finds its
-    # operations: the block's rows written, the slabs read, scores and mix
+    # operations: the block's rows written, the keys read, scores and mix
     scope = jax.named_scope("block_attn") if c.mask_block > 1 else contextlib.nullcontext()
     with scope:
         cache_k, slab_k = _write_and_read(
-            state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c
+            state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c,
+            slab=not streamed,
         )
         cache_v, slab_v = _write_and_read(
-            state["v"], vv.astype(c.dtype), p["cache_layer"], slot, positions, c
+            state["v"], vv.astype(c.dtype), p["cache_layer"], slot, positions, c,
+            slab=not streamed,
         )
-        mask = _cache_mask(positions, cache_k.shape[2], c.sliding_window, c.mask_block)
-        attn = _grouped_attention(q, slab_k, slab_v, mask, c)
+        if streamed:
+            attn = kv_decode_attention.kv_decode_attention(
+                q, cache_k, cache_v, p["cache_layer"],
+                _last_visible(positions, c.mask_block),
+            )
+        else:
+            mask = _cache_mask(positions, T, c.sliding_window, c.mask_block)
+            attn = _grouped_attention(q, slab_k, slab_v, mask, c)
     return attn, {"k": cache_k, "v": cache_v}, {}
 
 
@@ -1556,7 +1600,9 @@ def decode_step_rowwise(params, tokens, cache, pos, config: LlamaConfig):
     position.  Returns (logits (B, V) f32, new cache).  Inactive rows
     simply keep decoding garbage into their own slots — the engine masks
     them out — so the compiled shape never changes.  In place: one new
-    K/V row per sequence and layer, each slab read once, unexpanded."""
+    K/V row per sequence and layer; each row's keys are read once,
+    unexpanded, and over a cache of whole blocks only up to the block
+    that holds ``pos`` (``ops/kv_decode_attention.py``)."""
     return _cached_step(params, tokens[:, None], cache, None, pos, config)
 
 
@@ -1572,7 +1618,7 @@ def set_row(tokens, row, token):
 def prefill_into_slot(params, tokens, cache, slot, config: LlamaConfig):
     """Prefill ONE sequence into batched-cache row ``slot``.
 
-    tokens: (1, S) prompt; cache: the engine's (L, B, T, KV, D) batch
+    tokens: (1, S) prompt; cache: the engine's (L, B, T, KV x D) batch
     cache, donated and written in place at that row only.  Returns
     (last-token logits (1, V), updated cache).  One compile per
     prompt-bucket length serves every slot (slot is traced)."""

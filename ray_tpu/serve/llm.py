@@ -315,15 +315,21 @@ class LLMEngine:
         # block-diffusion deployments, by ``block_diffusion.OUT_FIELDS``:
         # forwards of live rows, of those commits, tokens unmasked, of those
         # by the threshold, blocks committed (= commit forwards), rows
-        # stepped once more after their budget was met, and keys the live
-        # rows' queries could see, summed over layers
+        # stepped once more after their budget was met
         self.diffusion_forwards_total = 0
         self.diffusion_commit_forwards_total = 0
         self.diffusion_tokens_unmasked_total = 0
         self.diffusion_threshold_transfers_total = 0
         self.diffusion_tokens_emitted_total = 0
         self.diffusion_wasted_row_steps_total = 0
+        # K/V configs, every-row steps, summed over layers: keys the live
+        # rows' queries could see, and keys the step's attention FETCHED for
+        # all rows, free ones too (whole blocks up to each row's last
+        # visible key where the kernel runs, the whole slab where XLA's body
+        # does: ``ops/kv_decode_attention.keys_read``).  From the positions
+        # the host feeds the step, or from the stateful step's ``outs``
         self.kv_keys_visible_step = 0
+        self.kv_keys_read_step = 0
 
     # -- client side -----------------------------------------------------
     async def stream(self, prompt: List[int], max_new_tokens: int = 16):
@@ -607,6 +613,8 @@ class LLMEngine:
             for i in active:
                 pos[i] = self.slots[i].pos
                 request[i] = self.slots[i].request
+            if not cfg.latent:
+                self._count_kv_keys(int(pos[active].sum()) + len(active), pos)
         with _part(life, "llm.step.dispatch") as dispatch:
 
             def _step(tokens=self._tokens):
@@ -643,6 +651,18 @@ class LLMEngine:
                 if last:
                     self.slots[i] = None
             self._flying.append(_Step(self._tokens, rows, life))
+
+    def _count_kv_keys(self, visible: int, last) -> None:
+        """One every-row step into ``kv_keys_visible_step`` (``visible``:
+        keys its live rows could see) and ``kv_keys_read_step`` (``last``
+        (max_slots,): EVERY row's last visible key as the step was given
+        it; a free slot's is 0 in a one-token step)."""
+        from ray_tpu.ops.kv_decode_attention import keys_read
+
+        c = self.config
+        read = keys_read(last, self.cache_len, c.head_dim, c.sliding_window)
+        self.kv_keys_visible_step += visible * c.num_layers
+        self.kv_keys_read_step += int(read.sum()) * c.num_layers
 
     async def _launch_stateful(self, life: Optional[tracing.Span],
                                active: List[int]) -> None:
@@ -707,6 +727,12 @@ class LLMEngine:
                 if self.slots[i] is slot:
                     self.slots[i] = None
         extras = outs[np.asarray(live, np.int64), k + 1:].sum(0)
+        if self.diffusion_block:
+            # the live rows' visible keys; EVERY row's last visible key, a
+            # retired row's stale one too
+            fields = self._programs.OUT_FIELDS
+            self._count_kv_keys(int(extras[fields.index("visible") - 1]),
+                                outs[:, k + fields.index("last")])
         attrs = self._tally(emitted, wasted, extras, len(live))
         if step.span is not None:
             step.span.attrs.update(emitted=emitted, **attrs)
@@ -726,14 +752,13 @@ class LLMEngine:
             if self.spec_drafted_total:
                 _SPEC_ACCEPTANCE.set(self.spec_accepted_total / self.spec_drafted_total)
             return {"accepted": accepted}
-        forwards, committed, unmasked, by_threshold, visible = (int(x) for x in extras)
+        forwards, committed, unmasked, by_threshold = (int(x) for x in extras[:4])
         self.diffusion_wasted_row_steps_total += wasted
         self.diffusion_forwards_total += forwards
         self.diffusion_commit_forwards_total += committed
         self.diffusion_tokens_unmasked_total += unmasked
         self.diffusion_threshold_transfers_total += by_threshold
         self.diffusion_tokens_emitted_total += emitted
-        self.kv_keys_visible_step += visible * self.config.num_layers
         if self.diffusion_forwards_total:
             _DIFF_TOKENS_PER_FORWARD.set(
                 self.diffusion_tokens_emitted_total / self.diffusion_forwards_total
@@ -902,11 +927,16 @@ class LlamaDeployment:
         committed it), ``diffusion_tokens_unmasked_total`` (of those,
         ``diffusion_threshold_transfers_total`` by the confidence
         threshold), ``diffusion_tokens_emitted_total``, ``diffusion_wasted_
-        row_steps_total``, ``kv_keys_visible_step`` (keys the live rows'
-        queries could see, over layers, rows and steps: a row's block sees
-        the same keys), and the gauge ``llm_diffusion_tokens_per_row_
+        row_steps_total``, and the gauge ``llm_diffusion_tokens_per_row_
         forward``; ``programs`` then counts ``models/block_diffusion.py``'s
-        versions.  ``cache_bytes``
+        versions.  A K/V config (one-token or block-diffusion steps):
+        ``kv_keys_visible_step`` (keys the live rows' queries could see,
+        over layers, rows and steps: a row's block sees the same keys) and
+        ``kv_keys_read_step`` (keys the steps' attention fetched, for every
+        row, free slots too: whole blocks up to each row's last visible key
+        where ``ops/kv_decode_attention.py``'s kernel runs, the whole slab
+        where XLA's body does; read / visible is the over-read).
+        ``cache_bytes``
         is what the cache holds, by entry (the module's layer is one of
         ``ckv``'s)."""
         import jax
@@ -932,12 +962,15 @@ class LlamaDeployment:
                     "diffusion_tokens_unmasked_total",
                     "diffusion_threshold_transfers_total",
                     "diffusion_tokens_emitted_total",
-                    "diffusion_wasted_row_steps_total", "kv_keys_visible_step",
+                    "diffusion_wasted_row_steps_total",
                 )
             })
             counters["diffusion_blocks_committed_total"] = (
                 self.engine.diffusion_commit_forwards_total
             )
+        if not (self.engine.config.latent or self.engine.speculative):
+            counters["kv_keys_visible_step"] = self.engine.kv_keys_visible_step
+            counters["kv_keys_read_step"] = self.engine.kv_keys_read_step
         return {
             **counters,
             # what the cache holds, entry by entry (``k``/``v``, or a

@@ -373,7 +373,7 @@ class TestKVCacheDecode:
     def test_gqa_cache_shapes(self):
         cfg = llama.LlamaConfig.tiny(num_heads=4, num_kv_heads=2)
         cache = llama.init_cache(cfg, 3, 32)
-        assert cache["k"].shape == (2, 3, 32, 2, 16)
+        assert cache["k"].shape == (2, 3, 32, 2 * 16)
 
     def test_generate_kv_decodes_with_the_engines_programs(self):
         """generate_kv has no decode program of its own: on a shape no

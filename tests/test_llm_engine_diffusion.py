@@ -64,6 +64,10 @@ def test_streams_hold_their_budget_in_order(streamed):
     assert eng.diffusion_tokens_unmasked_total <= BLOCK * blocks + BLOCK * 3
     assert eng.diffusion_threshold_transfers_total == 0
     assert eng.diffusion_wasted_row_steps_total > 0 and eng.kv_keys_visible_step > 0
+    # a cache of 32 slots takes XLA's body: every row's slab whole, every step
+    assert eng.kv_keys_read_step == (
+        eng.decode_steps_total * (SLOTS - 1) * MAX_LEN * eng.config.num_layers)
+    assert eng.kv_keys_read_step > eng.kv_keys_visible_step
     assert eng.admitted_total == len(BUDGETS) and eng.slots == [None] * (SLOTS - 1)
     assert eng.steps_launched_ahead_total > 0
 
