@@ -37,7 +37,8 @@ With ``index_topk`` == 0 there is no indexer (DeepSeek-V3's own form,
 JoyAI-LLM-Flash's): the cache holds the latent rows alone and a step
 attends to every visible key.  Either runs decode in the absorbed form
 over the cache where it lies (``ops/latent_decode_attention.py``) and
-prefill in the expanded form over query blocks, may lead with
+prefill in the expanded form (``ops/latent_prefill_attention.py``: one
+flash kernel, or XLA over query blocks), may lead with
 ``first_dense_layers`` dense blocks before its expert blocks (two
 parameter stacks under the one block body), routes with DeepSeek-V3's
 sigmoid router beside a shared expert, and may hold only ``experts_held``
@@ -78,7 +79,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import kv_decode_attention, latent_decode_attention
+from ray_tpu.ops import (
+    kv_decode_attention,
+    latent_decode_attention,
+    latent_prefill_attention,
+)
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -837,11 +842,14 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     (``ops/latent_decode_attention.py``); and ``ik`` (L, B, max_len,
     index_head_dim), the indexer's keys, of which it reads every row up
     to ``pos``.  Beside them ``dsa_keys`` (L, 3, 2, 2) int32: keys
-    visible / keys selected / latent rows read from ``ckv`` (single-token
-    steps only: blocks streamed or rows gathered), summed over every
-    (row, query) of every call, for runs (prefills) / single-token steps
-    apart, each as two words (millions, rest: ``_add_wide``) because 32
-    rows at 10k keys are 2 M a step and int32 would last 1,000 steps.
+    visible / keys selected / keys read (a single-token step: latent rows
+    fetched from ``ckv``, blocks streamed or rows gathered; a run: the
+    (query, key) pairs its attention computed scores for, a head — whole
+    live tiles under the prefill kernel, the causal groups' rectangles in
+    XLA's body), summed over every (row, query) of every call, for runs
+    (prefills) / single-token steps apart, each as two words (millions,
+    rest: ``_add_wide``) because 32 rows at 10k keys are 2 M a step and
+    int32 would last 1,000 steps.
 
     An expert config adds int32 running totals that ride the donated
     cache like K and V, so no step pays a device-to-host copy for them
@@ -924,9 +932,10 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
     """The cache after one call: the new state and the running totals
     plus this call's ``aux`` (the layer loop's stacked outputs): an
     expert config's (expert layers, experts held) rows per expert, a
-    latent config's (L, 2) keys visible and selected, of a single-token
-    step (L, 3): and latent rows read; without an indexer (L, 2) keys
-    visible and rows read, of steps only.  ``first``: the cache layer
+    latent config's (L, 3) keys visible, selected and read (a single-token
+    step: latent rows fetched; a run: (query, key) pairs its attention
+    computed scores for); without an indexer (L, 2) keys visible and rows
+    read, of steps only.  ``first``: the cache layer
     ``aux`` starts at, where it covers a part of the layers (the model
     without its multi-token-prediction module, or that module alone)."""
     out = dict(cache, **state)
@@ -948,9 +957,8 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
             out["moe_layer_steps"] = cache["moe_layer_steps"].at[part].add(1)
     if "dsa_keys" in aux:
         kind = int(step)  # runs at [:, :, 0], single-token steps at [:, :, 1]
-        n = aux["dsa_keys"].shape[-1]  # a run counts no keys read
-        out["dsa_keys"] = cache["dsa_keys"].at[:, :n, kind].set(
-            _add_wide(cache["dsa_keys"][:, :n, kind], aux["dsa_keys"])
+        out["dsa_keys"] = cache["dsa_keys"].at[:, :, kind].set(
+            _add_wide(cache["dsa_keys"][:, :, kind], aux["dsa_keys"])
         )
     if "mla_keys" in aux:
         part = slice(first, first + aux["mla_keys"].shape[0])
@@ -1226,13 +1234,23 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
 
     A run (prefill; one row, from position 0: the run's own tokens are
     all the keys there are): K and V are expanded from the run's latents
-    once, and attention runs in blocks of ``_QUERY_BLOCK`` queries, each
-    block's selection a mask over the run's keys, so nothing of size
-    heads x Sq x Sq is live.
+    once, and the indexer's selection is made block by block over the
+    queries (``_QUERY_BLOCK``; four causal groups, each given only the keys
+    up to its own end).  ``ops/latent_prefill_attention.py`` says by the
+    run's shape which body attends (``implementation``): ``flash`` — a run
+    of whole tiles with heads of whole 128-lane tiles: the blocks'
+    selections are laid into ONE (Sq, Sq) int8 mask and one Pallas kernel
+    (online softmax over the live causal tiles) reads it, so no score ever
+    reaches HBM; ``blocked`` — everything else (tiny and ragged runs, a
+    192-wide key head): XLA's body, each block of queries' float32 scores
+    (heads x block x keys) written, masked, softmaxed and multiplied out
+    inside the same loop as its selection.  The same selection and the
+    same mathematics either way; ``collect`` takes the same body.
 
     Returns (the heads' outputs (R, Sq, H, v_head_dim), state,
-    {"dsa_keys": (2,) int32 keys visible and selected over all queries,
-    of a step (3,): and latent rows read from ``ckv``}
+    {"dsa_keys": (3,) int32 keys visible and selected over all queries,
+    and keys read: latent rows a step fetched from ``ckv``, (query, key)
+    pairs a run computed scores for}
     — with ``collect`` also ``"selected"``: (R, Sq, T') bool, the keys
     each query attended to (T' = the cache's length for a step, Sq for
     a run))."""
@@ -1372,12 +1390,16 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
         values = jnp.einsum("sc,chv->shv", lat, p["w_vb"].astype(dt))
         qq = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)       # (Sq, H, Dn+Dr)
     H = c.num_heads
+    flash = latent_prefill_attention.implementation(
+        Sq, Dn + Dr, c.v_head_dim
+    ) == "flash"
     blk = max(8, min(_QUERY_BLOCK, Sq, _SCORE_ELEMENTS // (H * Sq)))
     groups = _CAUSAL_GROUPS if Sq % (_CAUSAL_GROUPS * blk) == 0 else 1
     per = Sq // groups
 
     def attend(lo, n_keys):
-        """Queries [lo, lo + per) over keys [0, n_keys), in blocks."""
+        """Queries [lo, lo + per) over keys [0, n_keys), in blocks: their
+        selection and, in XLA's body, their attention."""
         pad = -per % blk  # only a run that is one group has one
 
         def blocked(a):  # (per, ...) -> (blocks, blk, ...); the pad attends, is dropped
@@ -1401,6 +1423,12 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
             else:  # no indexer: every visible key
                 qb, tb, rb = args
                 chosen = seen = jnp.arange(n_keys)[None, :] <= tb[:, None]
+            count = jnp.stack([
+                (seen & rb[:, None]).sum(dtype=jnp.int32),
+                (chosen & rb[:, None]).sum(dtype=jnp.int32),
+            ])
+            if flash:  # the kernel's mask operand, a block of its lines
+                return None, count, chosen.astype(jnp.int8)
             with jax.named_scope("mla_attn"):
                 att = jnp.einsum(
                     "qhd,shd->hqs", qb, kb, preferred_element_type=jnp.float32
@@ -1408,31 +1436,45 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
                 att = jnp.where(chosen[None], att, -1e30)
                 probs = jax.nn.softmax(att, axis=-1).astype(dt)
                 ob = jnp.einsum("hqs,shv->qhv", probs, vb)
-            count = jnp.stack([
-                (seen & rb[:, None]).sum(dtype=jnp.int32),
-                (chosen & rb[:, None]).sum(dtype=jnp.int32),
-            ])
             return ob, count, (chosen if collect else None)
 
         args = (blocked(qq),)
         if K:
             args += (blocked(qi[0]), blocked(wi[0]))
         ob, count, chosen = lax.map(block, (*args, when, real))
-        if collect:
+        if chosen is not None:
             chosen = jnp.pad(
                 chosen.reshape(-1, n_keys)[:per], ((0, 0), (0, Sq - n_keys))
             )
-        return ob.reshape(-1, *ob.shape[2:])[:per], count.sum(0), chosen
+        if ob is not None:
+            ob = ob.reshape(-1, *ob.shape[2:])[:per]
+        return ob, count.sum(0), chosen
 
     # a query sees no key behind it: the g-th of ``groups`` runs of
     # queries is given the first g + 1 runs of keys only, which leaves
     # out 3/8 of a masked-everywhere attention's work at four groups
-    parts = [attend(g * per, (g + 1) * per) for g in range(groups)]
-    if K:
-        aux["dsa_keys"] = sum(part[1] for part in parts)
-    if collect:
-        aux["selected"] = jnp.concatenate([part[2] for part in parts])[None]
-    out = jnp.concatenate([part[0] for part in parts])[None]
+    spans = [(g * per, (g + 1) * per) for g in range(groups)]
+    # without an indexer the kernel needs no mask: visibility is causal
+    parts = [attend(lo, n_keys) for lo, n_keys in spans] if K or not flash else []
+    if flash:
+        mask = jnp.concatenate([part[2] for part in parts]) if K else None
+        with jax.named_scope("mla_attn"):
+            out = latent_prefill_attention.latent_prefill_attention(
+                qq, keys, values, mask, scale=scale
+            )[None]
+        read = latent_prefill_attention.pairs_computed(Sq)
+        if collect:
+            hit = mask != 0 if K else jnp.tril(jnp.ones((Sq, Sq), bool))
+            aux["selected"] = hit[None]
+    else:
+        out = jnp.concatenate([part[0] for part in parts])[None]
+        read = sum(per * n_keys for _, n_keys in spans)
+        if collect:
+            aux["selected"] = jnp.concatenate([part[2] for part in parts])[None]
+    if K:  # (query, key) pairs: visible, selected, scores computed for
+        aux["dsa_keys"] = jnp.concatenate([
+            sum(part[1] for part in parts), jnp.full((1,), read, jnp.int32)
+        ])
     return out, state, aux
 
 

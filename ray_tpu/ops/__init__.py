@@ -1,4 +1,12 @@
-"""TPU compute kernels: ring/flash attention, fused ops (Pallas + XLA)."""
+"""TPU compute kernels: ring/flash attention, fused ops (Pallas + XLA).
+
+The serving path's attention kernels each say by shape, in ONE function
+``implementation``, whether the kernel or XLA's body runs:
+``kv_decode_attention`` (a K/V config's every-row step),
+``latent_decode_attention`` (a latent config's decode step) and
+``latent_prefill_attention`` (a latent config's prefill: the flash kernel
+for a run of whole 512-token tiles with heads of whole 128-lane tiles, XLA's
+blocked body in ``models/llama.py:_latent_attention`` otherwise)."""
 
 from ray_tpu.ops.ring_attention import (  # noqa: F401
     ring_attention,

@@ -392,7 +392,11 @@ class LLMEngine:
         ``dsa_read_step``, latent rows the decode steps' attention
         fetched from the cache: the selected ones where they were
         gathered, every row of the blocks up to ``pos`` where they were
-        streamed (``ops/latent_decode_attention.py``).  Latent attention
+        streamed (``ops/latent_decode_attention.py``), and
+        ``dsa_read_run``, the (query, key) pairs the prefills' attention
+        computed scores for, a head: over ``dsa_visible_run`` the body's
+        over-compute, which says which body the prefills took
+        (``ops/latent_prefill_attention.py``).  Latent attention
         without an indexer: ``mla_keys_visible_step`` (keys a step's
         rows could see — a row's last query's, the others see prefixes —
         over every layer and row) and ``mla_keys_read_step`` (latent rows
@@ -431,10 +435,9 @@ class LLMEngine:
             keys = host["dsa_keys"]       # (L, visible|selected|read, run|step, 2)
             dsa = {
                 f"dsa_{what}_{kind}": wide_total(keys[:, i, j])
-                for i, what in enumerate(("visible", "selected"))
+                for i, what in enumerate(("visible", "selected", "read"))
                 for j, kind in enumerate(("run", "step"))
             }
-            dsa["dsa_read_step"] = wide_total(keys[:, 2, 1])
             seen = dsa["dsa_visible_run"] + dsa["dsa_visible_step"]
             if seen:
                 _DSA_SELECTED_SHARE.set(
@@ -910,7 +913,9 @@ class LlamaDeployment:
         row, query) of the replica's life, ``dsa_read_step`` (latent
         rows the decode steps fetched to attend to them: equal to
         ``dsa_selected_step`` where rows are gathered, the streamed
-        blocks' rows otherwise), and the gauges
+        blocks' rows otherwise), ``dsa_read_run`` ((query, key) pairs the
+        prefills computed scores for: whole live tiles under the prefill
+        kernel), and the gauges
         ``llm_dsa_selected_share`` and (experts held here)
         ``llm_moe_held_assignment_share``.  Latent attention without an
         indexer: ``mla_keys_visible_step`` / ``mla_keys_read_step``.  A

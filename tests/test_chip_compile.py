@@ -12,7 +12,8 @@ width: GPT-2-124M at B=8 x S=1024 (12 heads x 64) and the Llama family
 at one 2048-token sequence (32 heads x 128); and, whole, the two
 training cells' step programs (GPT-2 medium on one chip, GPT-2 XL on
 the four of the 2x2 under `fsdp=4`): the forward kernel once a layer,
-and XL inside its chips' memory.
+and XL inside its chips' memory; and GLM-5's prefill attention kernel
+at 64 heads of 256 | 256.
 """
 
 import json
@@ -27,6 +28,7 @@ import pytest
 
 from ray_tpu.models import gpt2
 from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops import latent_prefill_attention as lpa
 from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel import spmd
 
@@ -103,6 +105,34 @@ def test_flash_kernel_compiles_for_v5e(
     # away: no array of that shape, no copy into it
     B, H, S, D = HEAD_SHAPES[shape_name]
     assert D % 128 == 0 or f"[{B},{H},{S * D // 128},128]" not in text
+
+
+@pytest.mark.parametrize("run_len,masked", [
+    (8192, True), (2560, True),     # the cell's longest prompt, the comparison's
+    (4096, False),                  # no indexer: causal from an iota
+])
+def test_latent_prefill_kernel_compiles_for_v5e(
+    v5e_chip, compiled_not_interpreted, monkeypatch, run_len, masked
+):
+    """GLM-5's prefill attention at its published widths, 64 heads of 256 |
+    256 over a run of whole tiles, the selection an int8 mask operand
+    (``ops/latent_prefill_attention.py``): one kernel, inside its fast
+    memory, and no float32 score array of heads x queries x keys."""
+    monkeypatch.setattr(lpa, "_interpret", lambda: False)
+    assert lpa.implementation(run_len, 256, 256) == "flash"
+    x = jax.ShapeDtypeStruct((run_len, 64, 256), jnp.bfloat16, sharding=v5e_chip)
+    mask = jax.ShapeDtypeStruct((run_len, run_len), jnp.int8, sharding=v5e_chip)
+
+    def attend(q, k, v, *mask):
+        return lpa.latent_prefill_attention(q, k, v, *mask, scale=0.0625)
+
+    args = (x, x, x, mask) if masked else (x, x, x)
+    compiled = jax.jit(attend).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # at most a copy of q, k and v each (these are parameters in the default
+    # layout; inside the model their producers write the kernel's): the
+    # float32 scores would be 4 x 64 x run_len^2 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.1 * 64 * run_len * 256 * 2
 
 
 # ---- the training cells' step programs ----------------------------------

@@ -301,7 +301,8 @@ def test_the_cache_holds_latent_rows_index_keys_and_counters(body):
     # latent rows read: the chosen ones, or the blocks of 8 up to each ``pos``
     assert llama.wide_total(keys[0, 2, 1]) == (
         1 + 1 + TOPK if body == "gathered" else 8 + 8 + 32)
-    assert not keys[:, 2, 0].any()                          # a run counts none
+    # a run: the pairs its attention computed scores for (one causal group)
+    assert [llama.wide_total(keys[layer, 2, 0]) for layer in range(3)] == [24 * 24] * 3
 
 
 def test_the_no_cache_forward_refuses_a_latent_config():
@@ -397,7 +398,9 @@ def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache
     assert stats["dsa_selected_run"] == cfg.num_layers * sum(
         min(TOPK, t + 1) for n in (12, 19) for t in range(n))
     assert stats["dsa_visible_step"] > stats["dsa_selected_step"] > 0
-    assert before["dsa_read_step"] == 0
+    assert before["dsa_read_step"] == before["dsa_read_run"] == 0
+    # XLA's body at these lengths: one causal group, every (query, key) pair
+    assert stats["dsa_read_run"] == cfg.num_layers * (12 * 12 + 19 * 19)
     if body == "gathered":      # the chosen rows and no other
         assert stats["dsa_read_step"] == stats["dsa_selected_step"]
     else:                       # whole blocks of 8: each (layer, row) reads 0..7 past ``pos``

@@ -376,12 +376,15 @@ def test_drafting_needs_a_module_and_one_token():
 #: from the parent commit of a PR that means to change the step, never to
 #: make this pass.  ``kv`` and ``moe``: PR 37's, which changed the K/V cache's
 #: stored shape to (L, B, T, KV x D) — the four programs' text changed with it
-#: (their tiny caches keep XLA's attention body); ``glm`` is still PR 31's
+#: (their tiny caches keep XLA's attention body); ``glm``'s step is still
+#: PR 31's, its prefill PR 40's, which means to change it: a run now counts
+#: the (query, key) pairs it computed scores for into ``dsa_keys`` (the tiny
+#: run keeps XLA's attention body)
 _PINNED_JAX = "0.9.0"
 _LOWERED = {
     "kv": ("25631a6d7e31deaf", "6d314943030ee47b"),
     "moe": ("a2af198d06292884", "54cadcea029f716f"),
-    "glm": ("e5f748fd685aca08", "b6db0e86051ffada"),
+    "glm": ("e5f748fd685aca08", "c96dd208b4a9797a"),
 }
 
 
