@@ -265,6 +265,7 @@ def run(ctx: dict) -> dict:
         time.sleep(traffic["trace_for_s"])
         traced["t2"] = time.perf_counter() - clock0
         traced["host_s"] = call("trace_stop", timeout_s=300.0)
+        traced["t3"] = time.perf_counter() - clock0
     time.sleep(max(0.0, clock0 + seconds - time.perf_counter()))
     stop_sending.set()
     after = call("stats")
@@ -319,6 +320,8 @@ def run(ctx: dict) -> dict:
             if traced["t1"] <= t < traced["t2"]
         )
         facts["traced_client_s"] = traced["t2"] - traced["t1"]
+        # how long stop_trace() held the replica (no guard reads it here)
+        facts["trace_stop_s"] = traced["t3"] - traced["t2"]
     serve.delete("chipbench")
     serve.shutdown()
     return {

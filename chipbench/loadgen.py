@@ -189,8 +189,14 @@ def request_failed(o: Outcome, vocab_size: int, cut_ok: bool = False) -> Optiona
     return None
 
 
+#: a request that starts this long before the replica froze still has
+#: its first token inside the freeze (admission and prefill take 0.06-
+#: 0.3 s at the mixes' loads: PERF.md section 6)
+FROZEN_LEAD_S = 1.0
+
+
 def summarize(outcomes: Sequence[Outcome], seconds: float,
-              open_loop: bool) -> dict:
+              open_loop: bool, frozen: Optional[tuple] = None) -> dict:
     """Latencies and rates of one window.
 
     A request is *measured* if it was due (open loop) or sent (closed
@@ -204,6 +210,13 @@ def summarize(outcomes: Sequence[Outcome], seconds: float,
     gaps are those between consecutive tokens of one stream, over every
     measured request.  ``tokens_per_s`` counts every token that arrived
     inside the window, of any request, over the window's length.
+
+    ``frozen`` = (t2, t3), window offsets between which a traced run's
+    ``jax.profiler.stop_trace()`` held the replica: the time to first
+    token of a measured request that started in [t2 - FROZEN_LEAD_S, t3]
+    is the profiler's, not the program's, and is left out of
+    ``ttft_ms`` (``ttft_left_out`` says how many were).  Nothing else
+    changes, and an untraced run has no ``frozen``.
     """
     def start(o):
         return o.request.due_s if open_loop else o.sent_s
@@ -213,8 +226,12 @@ def summarize(outcomes: Sequence[Outcome], seconds: float,
         if 0.0 <= start(o) < seconds
         and (open_loop or o.token_s or o.error or o.finished)
     ]
+    # the profiler's seconds: an empty interval where nothing froze
+    lo, hi = (frozen[0] - FROZEN_LEAD_S, frozen[1]) if frozen else (0.0, -1.0)
+    first = [o for o in measured if o.token_s]
     ttft = [
-        (o.token_s[0] - start(o)) * 1e3 for o in measured if o.token_s
+        (o.token_s[0] - start(o)) * 1e3 for o in first
+        if not lo <= start(o) <= hi
     ]
     itl = [
         (b - a) * 1e3
@@ -228,8 +245,30 @@ def summarize(outcomes: Sequence[Outcome], seconds: float,
     return {
         "measured": measured,
         "ttft_ms": ttft,
+        "ttft_left_out": len(first) - len(ttft),
         "itl_ms": itl,
         "lag_ms": lag,
         "tokens_in_window": in_window,
         "tokens_per_s": in_window / seconds,
     }
+
+
+#: a mix's ``ttft_p50_limit_ms`` is held against a median of at least
+#: this many requests: fewer (a traced run whose freeze left them out)
+#: say nothing about the program
+MIN_TTFT_GUARDED = 8
+
+
+def ttft_guard(ttft_ms: Sequence[float], limit_ms: Optional[float]) -> Optional[str]:
+    """Why a run breaks its mix's ``ttft_p50_limit_ms``, or None (the
+    mix states no limit, or half of ``ttft_ms`` lies within it)."""
+    if limit_ms is None:
+        return None
+    if len(ttft_ms) < MIN_TTFT_GUARDED:
+        return (f"only {len(ttft_ms)} request(s) are left to hold to the mix's "
+                f"limit on the time to first token, under {MIN_TTFT_GUARDED}")
+    p50 = percentile(ttft_ms, 50)
+    if p50 > limit_ms:
+        return (f"median time to first token {p50:.0f} ms is over the mix's "
+                f"limit of {limit_ms:g} ms")
+    return None

@@ -35,13 +35,18 @@ import tempfile  # noqa: E402
 import threading  # noqa: E402
 import traceback  # noqa: E402
 
-from chipbench import contract, trace_reduce  # noqa: E402
+from chipbench import chips, contract, trace_reduce  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 #: a run must end inside the driver's 360 s; the first in a checkout,
 #: which compiles, inside 1200 s.  Whether programs are cached is not
 #: known before the run, so the watchdog takes the longer limit.
 DEADLINE_S = 1150.0
+#: the longest a run waits for the chips: at its start, for a process
+#: before it to let go of them, and at its end, for its own to (a clean
+#: ``ray_tpu.shutdown`` of the four-chip cell took 13.0-16.8 s, a killed
+#: holder of one chip let go 5.9 s later: chip runs of PR 43's builder)
+CHIPS_CEILING_S = 60.0
 
 
 def _say(msg: str) -> None:
@@ -53,17 +58,22 @@ def _load_json(*parts: str) -> dict:
         return json.load(f)
 
 
+def _stat(pid) -> list:
+    """``/proc/<pid>/stat`` after the command's name: state, parent's
+    pid, ...; empty where the process is gone."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rsplit(")", 1)[1].split()
+    except (OSError, IndexError):
+        return []
+
+
 def _descendants(root: int) -> list:
     children: dict = {}
-    for pid in os.listdir("/proc"):
-        if not pid.isdigit():
-            continue
-        try:
-            with open(f"/proc/{pid}/stat") as f:
-                ppid = int(f.read().rsplit(")", 1)[1].split()[1])
-        except (OSError, ValueError, IndexError):
-            continue
-        children.setdefault(ppid, []).append(int(pid))
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        stat = _stat(pid)
+        if len(stat) > 1 and stat[1].isdigit():
+            children.setdefault(int(stat[1]), []).append(int(pid))
     out, todo = [], [root]
     while todo:
         for kid in children.get(todo.pop(), []):
@@ -72,8 +82,16 @@ def _descendants(root: int) -> list:
     return out
 
 
-def _stop_cluster() -> None:
-    """Shut the cluster down and leave no process behind."""
+def _alive(pid: int) -> bool:
+    """In the process table and not a zombie: a zombie holds nothing,
+    and one whose parent is gone is not this process's to reap."""
+    return _stat(pid)[:1] not in ([], ["Z"])
+
+
+def _stop_cluster(ceiling_s: float = CHIPS_CEILING_S) -> None:
+    """Shut the cluster down, leave no process behind, and return once
+    the chips it used can be opened again: the next run on this machine
+    may start the moment this one has exited."""
     started = _descendants(os.getpid())
     try:
         import ray_tpu
@@ -89,15 +107,27 @@ def _stop_cluster() -> None:
             os.kill(pid, signal.SIGKILL)
         except OSError:
             pass
-    for pid in started:  # wait until each has ended
-        for _ in range(100):
-            if not os.path.exists(f"/proc/{pid}"):
-                break
+    # ONE wait against one ceiling: a killed worker's children are
+    # orphans that only the host's init reaps, and a dead holder's
+    # device files stay busy for seconds after it
+    t0 = time.monotonic()
+    while True:
+        for pid in started:
             try:
                 os.waitpid(pid, os.WNOHANG)
             except OSError:
                 pass
-            time.sleep(0.05)
+        alive = [pid for pid in started if _alive(pid)]
+        held = chips.busy()
+        if not alive and not held:
+            return
+        if time.monotonic() - t0 >= ceiling_s:
+            left = [f"pid(s) {alive} still alive"] if alive else []
+            left += [chips.describe(held)] if held else []
+            _say(f"{ceiling_s:g} s after the cluster was stopped: "
+                 f"{'; '.join(left)}; ending all the same")
+            return
+        time.sleep(0.1)
 
 
 _ENDING = threading.Lock()
@@ -109,7 +139,10 @@ def _end(real_stdout: int, line, code: int, scratch) -> None:
     after it.  Runs once: the watchdog and the main thread may both
     arrive."""
     _ENDING.acquire()
+    t0 = time.monotonic()
     _stop_cluster()
+    _say(f"end: the cluster stopped and the chips free {time.monotonic() - t0:.1f} s "
+         "after the run's work was done")
     if scratch:
         shutil.rmtree(scratch, ignore_errors=True)
     sys.stderr.flush()
@@ -246,6 +279,13 @@ def main() -> None:
         if args.trace:
             os.makedirs(trace_dir)
 
+        # the clock of ``setup_s`` starts where the chips were free: what
+        # a process before this one still held is not this run's set-up
+        waited = chips.wait_until_free(CHIPS_CEILING_S)
+        if waited:
+            _say(f"{args.workload}: waited {waited:.1f} s for the chips a process "
+                 "before this one still held")
+
         import ray_tpu
 
         info = ray_tpu.init(session_dir=os.path.join(scratch, "session"))
@@ -261,7 +301,7 @@ def main() -> None:
             "seed": args.seed, "seconds": args.seconds, "trace": args.trace,
             "rehearse": args.rehearse, "trace_dir": trace_dir,
             "storage_dir": os.path.join(scratch, "results"),
-            "t_process_start": T_PROCESS_START,
+            "t_process_start": T_PROCESS_START + waited,
         })
         if not args.rehearse and (
             job["device"]["platform"] != "tpu" or job["device"]["count"] != cell["chips"]
@@ -276,6 +316,7 @@ def main() -> None:
                          args.rehearse)
         facts = {k: v for k, v in job["facts"].items()
                  if not isinstance(v, (list, dict))}
+        facts["chips_waited_s"] = waited
         _say(f"facts: {json.dumps(facts)}")
         line = json.dumps(obj)
         contract.validate(line, args.workload, args.trace, bench)

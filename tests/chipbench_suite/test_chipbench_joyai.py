@@ -122,7 +122,7 @@ def test_the_benchmark_gains_one_configuration_one_cell_and_the_joy_metrics():
     assert len(bench["workloads"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert tokens["workloads"][-1] == CELL and tokens["bound"] == 0.02
+    assert tokens["workloads"][-1] == CELL and tokens["bound"] == 0.03
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".joy")]
     assert len(mine) == 16 and bench["per_layer"][-16:] == mine
     for m in mine:

@@ -121,3 +121,69 @@ def test_closed_loop_only_unserved_queue_filler_is_left_out(second, measured, fa
     fails = [f for f in (loadgen.request_failed(o, 10, cut_ok=True)
                          for o in s["measured"]) if f]
     assert len(fails) == failed
+
+
+def _replayed(mix, seed, seconds, froze_at, froze_s, service_s=0.06, gap_s=0.03):
+    """The mix's own schedule against a replica that answers a request
+    ``service_s`` after it is due and a token every ``gap_s``, except
+    that nothing leaves it from ``froze_at`` for ``froze_s`` seconds."""
+    thaw = lambda t: t if t < froze_at else max(t, froze_at + froze_s)  # noqa: E731
+    out = []
+    for r in loadgen.schedule(mix, seed, seconds, 1024):
+        first = thaw(r.due_s + service_s)
+        stamps = [thaw(first + k * gap_s) for k in range(r.new_tokens)]
+        out.append(loadgen.Outcome(r, r.due_s, stamps, [1] * r.new_tokens, finished=True))
+    return out
+
+
+@pytest.mark.parametrize("seed", [1, 2_147_483_833, 3_000_000_019])
+def test_a_traced_chat_run_is_held_to_the_requests_the_profiler_did_not_freeze(seed):
+    """PR 44: ``stop_trace`` sent at 9 s of the window returns 25 s
+    later.  Counted, the frozen requests make the run "incorrect";
+    left out, the guard reads the program."""
+    mix = traffic("chat_poisson")
+    outcomes = _replayed(mix, seed, 40.0, froze_at=9.0, froze_s=25.0)
+    plain = loadgen.summarize(outcomes, 40.0, open_loop=True)
+    kept = loadgen.summarize(outcomes, 40.0, open_loop=True, frozen=(9.0, 34.0))
+    assert loadgen.percentile(plain["ttft_ms"], 50) > mix["ttft_p50_limit_ms"]
+    assert "over the mix's limit of 1000 ms" in loadgen.ttft_guard(
+        plain["ttft_ms"], mix["ttft_p50_limit_ms"])
+    assert loadgen.ttft_guard(kept["ttft_ms"], mix["ttft_p50_limit_ms"]) is None
+    assert loadgen.percentile(kept["ttft_ms"], 95) == pytest.approx(60.0)
+    in_freeze = [o for o in kept["measured"]
+                 if 9.0 - loadgen.FROZEN_LEAD_S <= o.request.due_s <= 34.0]
+    assert kept["ttft_left_out"] == len(in_freeze) > 30
+    assert plain["ttft_left_out"] == 0
+    assert len(kept["ttft_ms"]) == len(plain["ttft_ms"]) - len(in_freeze) >= 12
+    for same in ("measured", "itl_ms", "lag_ms", "tokens_in_window", "tokens_per_s"):
+        assert kept[same] == plain[same], same
+
+
+def test_the_guard_wants_eight_requests_and_no_mix_without_a_limit():
+    fast = [50.0] * 7
+    assert loadgen.ttft_guard(fast, None) is None
+    assert loadgen.ttft_guard([5e4] * 3, None) is None
+    assert "only 7 request(s) are left" in loadgen.ttft_guard(fast, 1000)
+    assert loadgen.ttft_guard(fast + [50.0], 1000) is None
+    assert loadgen.ttft_guard(fast + [5e4], 1000) is None       # a median, not a tail
+    assert "median time to first token 50000 ms" in loadgen.ttft_guard([5e4] * 8, 1000)
+    # a freeze that leaves fewer than eight of a window's requests
+    mk = lambda i: loadgen.Request(i, float(i), None, 128, 2, i)  # noqa: E731
+    outcomes = [loadgen.Outcome(mk(i), float(i), [i + 0.05, i + 0.1], [1, 2], finished=True)
+                for i in range(12)]
+    kept = loadgen.summarize(outcomes, 12.0, open_loop=True, frozen=(5.5, 20.0))
+    assert kept["ttft_left_out"] == 7 and len(kept["ttft_ms"]) == 5   # due 0..4 are left
+    assert "only 5 request(s)" in loadgen.ttft_guard(kept["ttft_ms"], 1000)
+
+
+def test_a_closed_loop_is_frozen_by_when_a_request_was_sent():
+    mk = lambda i: loadgen.Request(i, None, i, 128, 2, i)  # noqa: E731
+    sent = [0.5, 1.9, 2.0, 3.0, 4.0, 4.1]
+    outcomes = [loadgen.Outcome(mk(i), s, [s + 0.1, s + 0.2], [1, 2], finished=True)
+                for i, s in enumerate(sent)]
+    plain = loadgen.summarize(outcomes, 5.0, open_loop=False)
+    kept = loadgen.summarize(outcomes, 5.0, open_loop=False, frozen=(3.0, 4.0))
+    assert len(plain["ttft_ms"]) == 6 and plain["ttft_left_out"] == 0
+    assert kept["ttft_left_out"] == 3                      # sent at 2.0, 3.0 and 4.0
+    assert kept["ttft_ms"] == pytest.approx([100.0] * 3)
+    assert kept["measured"] == plain["measured"] and kept["itl_ms"] == plain["itl_ms"]

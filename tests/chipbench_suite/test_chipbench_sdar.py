@@ -136,7 +136,7 @@ def test_my_benchmark_entries_are_there_in_this_order():
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert CELL in tokens["workloads"] and tokens["bound"] == 0.02
+    assert CELL in tokens["workloads"] and tokens["bound"] == 0.03
     assert tokens["workloads"].index(CELL) > tokens["workloads"].index(
         "serve_joyai_reason_mtp")
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".sdar")]

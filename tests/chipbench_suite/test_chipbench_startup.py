@@ -181,3 +181,11 @@ def test_a_rehearsed_cell_prints_the_six_metrics_and_the_time_line(cell, state_s
                  state_span + " | holder",
                  "program | trace s | lower s | compile s"):
         assert name in out.stderr, name
+    # PR 44: the run waited for no chips (the CPU has none), said how long
+    # its end took, and a traced serving run says how long the profiler's
+    # stop held the replica and how many first tokens it left out
+    facts = json.loads(out.stderr.split("[chipbench] facts: ")[1].splitlines()[0])
+    assert facts["chips_waited_s"] == 0.0
+    assert "[chipbench] end: the cluster stopped and the chips free" in out.stderr
+    if cell.startswith("serve"):
+        assert facts["trace_stop_s"] > 0 and facts["ttft_left_out"] >= 0
