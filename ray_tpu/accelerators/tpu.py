@@ -10,9 +10,11 @@ gang scheduling uses the slice-name resource + STRICT_PACK placement groups.
 
 from __future__ import annotations
 
+import errno
 import glob
 import logging
 import os
+import re
 import subprocess
 import sys
 import time
@@ -41,7 +43,14 @@ def open_leased_chips() -> None:
     whoever is about to touch the first array calls this first: the
     decode replica before its weights, a train worker before its
     training function (after ``jax.distributed.initialize``, which must
-    come before the backend).  Elsewhere, and the second time, nothing."""
+    come before the backend).  Elsewhere, and the second time, nothing.
+
+    The raylet frees a chip only when the worker that held it has been
+    reaped.  A holder it did not start (the run before this one, still
+    dying; another tenant) it cannot see, so the lease first waits, at
+    most ``cfg.worker_start_timeout_s``, until its chips' device files
+    open (the span's ``waited_s``): libtpu does not wait, and a backend
+    that failed to initialise cannot be asked again."""
     global _chips_opened
     chips = os.environ.get("TPU_VISIBLE_CHIPS")
     if not chips or _chips_opened:
@@ -51,6 +60,10 @@ def open_leased_chips() -> None:
 
     cpu0 = time.process_time()
     with tracing.startup("rt.start.chip_open", chips=chips) as s:
+        waited = s.attrs["waited_s"] = round(_wait_until_free(chips), 3)
+        if waited >= 1:
+            logger.warning("chips %s: waited %.3f s for a holder this raylet "
+                           "did not start to let go of them", chips, waited)
         s.attrs.update(
             device_kind=jax.devices()[0].device_kind,
             process_cpu_s=round(time.process_time() - cpu0, 3),
@@ -58,6 +71,56 @@ def open_leased_chips() -> None:
 
 
 _chips_opened = False
+
+
+def _device_files() -> list:
+    """This host's chips as device files, in the order of their ids in
+    ``TPU_VISIBLE_CHIPS``: ``/dev/accel<N>``, or the numbered groups of
+    ``/dev/vfio``."""
+    files = glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*")
+    return sorted(files, key=lambda p: int(re.search(r"\d+", p).group()))
+
+
+def _open_errno(path: str) -> int:
+    """0 if ``path`` can be opened (and closed again at once)."""
+    try:
+        os.close(os.open(path, os.O_RDWR))
+    except OSError as e:
+        return e.errno
+    return 0
+
+
+def _holder_of(path: str) -> str:
+    """" (held by pid N)" where /proc shows who has ``path`` open."""
+    for fd in glob.glob("/proc/[0-9]*/fd/*"):
+        try:
+            if os.readlink(fd) == path:
+                return f" (held by pid {fd.split('/')[2]})"
+        except OSError:
+            pass
+    return ""
+
+
+def _wait_until_free(chips: str) -> float:
+    """Seconds waited until every device file of ``chips`` could be
+    opened.  Only EBUSY means "held"; any other answer is left to
+    ``jax.devices()``, which says what is wrong."""
+    files = _device_files()
+    t0 = time.monotonic()
+    for c in chips.split(","):
+        if not c.isdigit() or int(c) >= len(files):
+            continue  # fake chips, or ids jax will have to explain
+        path = files[int(c)]
+        while _open_errno(path) == errno.EBUSY:
+            waited = time.monotonic() - t0
+            if waited > cfg.worker_start_timeout_s:
+                raise RuntimeError(
+                    f"chip {c} of this lease ({chips}) cannot be opened: {path} "
+                    f"is still busy after {waited:.0f} s{_holder_of(path)}; "
+                    "a chip belongs to one process at a time"
+                )
+            time.sleep(0.1)
+    return time.monotonic() - t0
 
 
 class TPUAcceleratorManager:
@@ -79,14 +142,11 @@ class TPUAcceleratorManager:
             self.detected_by = "RT_TPU_CHIPS_OVERRIDE"
             return cfg.tpu_chips_override
         # 1) and 2) device files of a TPU VM: /dev/accel* or /dev/vfio/*
-        n = len(glob.glob("/dev/accel*"))
-        if n > 0:
-            self.detected_by = "/dev/accel*"
-            return n
-        n = len([p for p in glob.glob("/dev/vfio/*") if p != "/dev/vfio/vfio"])
-        if n > 0:
-            self.detected_by = "/dev/vfio/*"
-            return n
+        files = _device_files()
+        if files:
+            kind = "accel" if "accel" in files[0] else "vfio/"
+            self.detected_by = f"/dev/{kind}*"
+            return len(files)
         # 3) ask jax, in a child process so that this one never claims
         #    the chips.  The child fails if this process already holds
         #    them (a caller of init() that has touched jax): that is

@@ -198,7 +198,7 @@ def up(config_path: str, wait_min_workers_s: float = 0.0) -> Dict[str, Any]:
             )
         )
     except BaseException:
-        gcs_proc.terminate()
+        node_mod.stop_processes([gcs_proc], node_mod.GCS_STOP_GRACE_S)
         raise
 
     # the monitor daemon rebuilds the provider from the SAME yaml —

@@ -17,6 +17,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ray_tpu.core import node as node_mod
+
 logger = logging.getLogger(__name__)
 
 
@@ -55,8 +57,6 @@ class LocalSubprocessProvider(NodeProvider):
         self._lock = threading.Lock()
 
     def create_node(self, node_type, resources, labels) -> ProviderNode:
-        from ray_tpu.core import node as node_mod
-
         labels = dict(labels)
         labels["ray_tpu.node_type"] = node_type
         proc, address, node_id, _store = node_mod.start_raylet(
@@ -81,12 +81,8 @@ class LocalSubprocessProvider(NodeProvider):
     def terminate_node(self, node: ProviderNode) -> None:
         with self._lock:
             self._nodes.pop(node.provider_id, None)
-        if node.proc is not None and node.proc.poll() is None:
-            node.proc.terminate()
-            try:
-                node.proc.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                node.proc.kill()
+        if node.proc is not None:
+            node_mod.stop_processes([node.proc], node_mod.RAYLET_STOP_GRACE_S)
         logger.info("provider terminated %s", node.provider_id)
 
     def non_terminated_nodes(self) -> List[ProviderNode]:

@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from ray_tpu.autoscaler.node_provider import NodeProvider, ProviderNode
+from ray_tpu.core import node as node_mod
 
 logger = logging.getLogger(__name__)
 
@@ -238,8 +239,6 @@ class TpuPodProvider(NodeProvider):
         """node_type must be an accelerator_type key (e.g. v5litepod-16);
         `resources` describe ONE HOST and are merged over the detected
         slice resources."""
-        from ray_tpu.core import node as node_mod
-
         with self._lock:
             self._counter += 1
             slice_name = f"rt-{node_type}-{self._counter}"
@@ -275,8 +274,7 @@ class TpuPodProvider(NodeProvider):
                 procs.append(proc)
                 node_ids.append(nid)
         except BaseException:
-            for p in procs:
-                p.terminate()
+            node_mod.stop_processes(procs, node_mod.RAYLET_STOP_GRACE_S)
             self.api.delete_slice(slice_name)
             raise
         pn = ProviderNode(
@@ -298,14 +296,9 @@ class TpuPodProvider(NodeProvider):
     def terminate_node(self, node: ProviderNode) -> None:
         with self._lock:
             self._nodes.pop(node.provider_id, None)
-        for p in node.meta.get("procs", []):
-            if p.poll() is None:
-                p.terminate()
-        for p in node.meta.get("procs", []):
-            try:
-                p.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                p.kill()
+        node_mod.stop_processes(
+            node.meta.get("procs", []), node_mod.RAYLET_STOP_GRACE_S
+        )
         self.api.delete_slice(node.provider_id)
         logger.info("terminated TPU slice %s", node.provider_id)
 

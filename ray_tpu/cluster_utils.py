@@ -27,16 +27,12 @@ class ClusterNode:
     resources: Dict[str, float]
 
     def kill(self, graceful: bool = True):
-        if self.proc.poll() is None:
-            if graceful:
-                self.proc.terminate()
-            else:
-                self.proc.kill()
-        try:
-            self.proc.wait(timeout=5)
-        except subprocess.TimeoutExpired:
+        if not graceful and self.proc.poll() is None:
+            # a crash: no close(), so the workers, orphans now, leave
+            # when they see the connection drop
             self.proc.kill()
-            self.proc.wait(timeout=5)
+        node_mod.stop_processes([self.proc], node_mod.RAYLET_STOP_GRACE_S)
+        node_mod.unlink_arena_of(self.proc, self.store_path)
 
 
 class Cluster:
@@ -171,13 +167,11 @@ class Cluster:
 
     def shutdown(self):
         """Tear down all raylets and the GCS."""
-        for node in list(self._nodes):
-            node.kill(graceful=True)
+        node_mod.stop_processes(
+            [node.proc for node in self._nodes], node_mod.RAYLET_STOP_GRACE_S
+        )
+        for node in self._nodes:
+            node_mod.unlink_arena_of(node.proc, node.store_path)
         self._nodes.clear()
         self.head_node = None
-        if self.gcs_proc.poll() is None:
-            self.gcs_proc.terminate()
-            try:
-                self.gcs_proc.wait(timeout=5)
-            except subprocess.TimeoutExpired:
-                self.gcs_proc.kill()
+        node_mod.stop_processes([self.gcs_proc], node_mod.GCS_STOP_GRACE_S)

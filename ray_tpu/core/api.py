@@ -76,7 +76,7 @@ def init(
                 store_capacity=object_store_bytes,
             )
         except Exception:
-            gcs_proc.terminate()
+            node_mod.stop_processes([gcs_proc], node_mod.GCS_STOP_GRACE_S)
             raise
         _node_group = node_mod.NodeProcessGroup(
             session_dir=sdir,
@@ -158,6 +158,10 @@ def _find_local_raylet(gcs_addr: str):
 
 
 def shutdown() -> None:
+    """Detach this driver and, where init() started the cluster, end it.
+    When this returns no process that init() started, and none that
+    those started, exists: each has been reaped by its own parent
+    (docs/architecture.md, process lifetimes)."""
     global _node_group
     from ray_tpu.core import runtime as rt_mod
 

@@ -49,6 +49,7 @@ from ray_tpu.common.ids import ActorID, JobID, NodeID, PlacementGroupID, WorkerI
 from ray_tpu.common.resources import ResourceSet
 from ray_tpu.core import rpc
 from ray_tpu.core.errors import FencedError
+from ray_tpu.core.node import WORKER_STOP_GRACE_S, stop_processes
 
 logger = logging.getLogger(__name__)
 
@@ -2046,18 +2047,10 @@ class GcsServer:
             return False
         proc = info.get("_proc")
         if info["status"] == RUNNING_JOB and proc is not None:
-            proc.terminate()
-
-            def wait_or_kill():
-                try:
-                    proc.wait(timeout=5)
-                except Exception:
-                    proc.kill()
-
             # off-loop: an entrypoint ignoring SIGTERM must not stall the
             # control plane for the grace period
             await asyncio.get_running_loop().run_in_executor(
-                None, wait_or_kill
+                None, stop_processes, [proc], WORKER_STOP_GRACE_S
             )
             info["status"] = STOPPED_JOB
             info["end_time"] = time.time()
@@ -3489,22 +3482,13 @@ def main():
 
     faulthandler.register(_sig.SIGUSR1)
 
-    prof_dir = os.environ.get("RT_PROFILE_DIR")
-    if prof_dir:
+    prof = None
+    if os.environ.get("RT_PROFILE_DIR"):
         # dev profiling (see util/profiling.py): capture the whole server
         # loop; SIGTERM (the normal teardown signal) dumps the stats
         import cProfile
-        import signal
 
         prof = cProfile.Profile()
-        path = os.path.join(prof_dir, f"gcs-{os.getpid()}.pstats")
-
-        def _term(_sig, _frm):
-            prof.disable()
-            prof.dump_stats(path)
-            sys.exit(0)
-
-        signal.signal(signal.SIGTERM, _term)
         prof.enable()
 
     async def run():
@@ -3512,6 +3496,24 @@ def main():
             host=args.host, port=args.port, session_dir=args.session_dir
         )
         await gcs.start()
+
+        def on_sigterm(_signum, _frame):
+            # a plain handler, not the loop's: a GCS under load must
+            # still go at once.  Submitted jobs' entrypoints are this
+            # process's children: nobody else can reap them
+            stop_processes(
+                [info["_proc"] for info in gcs.submitted_jobs.values()
+                 if info.get("_proc") is not None],
+                WORKER_STOP_GRACE_S,
+            )
+            if prof is not None:
+                prof.disable()
+                prof.dump_stats(os.path.join(
+                    os.environ["RT_PROFILE_DIR"], f"gcs-{os.getpid()}.pstats"
+                ))
+            os._exit(0)
+
+        _sig.signal(_sig.SIGTERM, on_sigterm)
         # report the bound address to the parent on stdout
         print(f"GCS_ADDRESS={gcs.address}", flush=True)
         await asyncio.Event().wait()

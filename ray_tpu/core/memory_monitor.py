@@ -110,10 +110,7 @@ class MemoryMonitor:
             usage, cfg.memory_usage_threshold, kind,
             victim.worker_id.hex()[:12],
         )
-        try:
-            victim.proc.kill()
-        except Exception:
-            pass
+        self.raylet._hard_kill_worker(victim)
         await self.raylet._on_worker_exit(
             victim,
             reason=(

@@ -6,18 +6,20 @@ Role-equivalent of ray: python/ray/_private/node.py:37 and services.py
 
 from __future__ import annotations
 
-import atexit
+import contextlib
 import json
+import logging
 import os
 import subprocess
 import sys
 import time
-import uuid
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from dataclasses import dataclass
+from typing import Callable, Dict, Iterable, Optional
 
 from ray_tpu.common.ids import NodeID
 from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
 
 
 def _read_tagged_line(proc: subprocess.Popen, tag: str, timeout: float) -> str:
@@ -44,6 +46,63 @@ def default_session_dir() -> str:
     )
 
 
+#: A chip is free when the process that held it has been reaped by its
+#: parent; whatever ends a process keeps that rule, with these.
+#: SIGTERM-to-SIGKILL grace a raylet gives a worker it retires:
+WORKER_STOP_GRACE_S = 5.0
+#: what the kernel may take to release a SIGKILLed process's devices (3-6 s
+#: for a holder of one v5e chip, 13-24 s of four: PERF.md section 6) before
+#: whoever waits for it says so, loudly, and goes on waiting:
+REAP_CEILING_S = 30.0
+#: grace for a raylet: its workers' grace, their reaping, its own close()
+RAYLET_STOP_GRACE_S = WORKER_STOP_GRACE_S + REAP_CEILING_S + 5.0
+#: ... and for a GCS, which gives its jobs' entrypoints a worker's grace
+GCS_STOP_GRACE_S = WORKER_STOP_GRACE_S + 5.0
+
+
+def stop_processes(
+    procs: Iterable[subprocess.Popen], grace_s: float,
+    kill: Callable[[subprocess.Popen], None] = subprocess.Popen.kill,
+) -> None:
+    """End ``procs`` and return only when every one has been reaped:
+    SIGTERM all that live, wait for all against one deadline, ``kill``
+    what is left (SIGKILL; the raylet's also knows containers), then wait
+    until ``poll()`` answers for each (a killed process cannot refuse:
+    that wait is the kernel releasing its devices).  It blocks: an event
+    loop runs it in an executor (``Raylet._reap``, ``rpc_stop_job``)."""
+    procs = [p for p in procs if p.poll() is None]
+    for p in procs:
+        p.terminate()
+    deadline = time.monotonic() + grace_s
+    killed = []
+    for p in procs:
+        try:
+            p.wait(max(0.0, deadline - time.monotonic()))
+        except subprocess.TimeoutExpired:
+            kill(p)
+            killed.append(p)
+    for p in killed:
+        since = time.monotonic()
+        while True:
+            try:
+                p.wait(REAP_CEILING_S)
+                break
+            except subprocess.TimeoutExpired:
+                logger.error(
+                    "pid %d is not reaped %.0f s after SIGKILL: the kernel "
+                    "still holds what it had open (a chip it held is NOT "
+                    "free); waiting on", p.pid, time.monotonic() - since,
+                )
+
+
+def unlink_arena_of(raylet_proc: subprocess.Popen, store_path: str) -> None:
+    """A stopped raylet that did not get to its close() (SIGKILLed or
+    crashed: any exit but 0) left its arena in /dev/shm."""
+    if raylet_proc.returncode != 0:
+        with contextlib.suppress(FileNotFoundError):
+            os.unlink(store_path)
+
+
 @dataclass
 class NodeProcessGroup:
     """Handles to the subprocesses composing one logical node (plus the GCS
@@ -58,15 +117,14 @@ class NodeProcessGroup:
     raylet_proc: Optional[subprocess.Popen] = None
 
     def kill(self):
-        for proc in (self.raylet_proc, self.gcs_proc):
-            if proc is not None and proc.poll() is None:
-                proc.terminate()
-        for proc in (self.raylet_proc, self.gcs_proc):
-            if proc is not None:
-                try:
-                    proc.wait(timeout=3)
-                except subprocess.TimeoutExpired:
-                    proc.kill()
+        """End this node's processes: the raylet, and when it has gone
+        (its close() talks to the GCS) the GCS.  Returns with both, and
+        all the raylet started, reaped."""
+        if self.raylet_proc is not None:
+            stop_processes([self.raylet_proc], RAYLET_STOP_GRACE_S)
+            unlink_arena_of(self.raylet_proc, self.store_path)
+        if self.gcs_proc is not None:
+            stop_processes([self.gcs_proc], GCS_STOP_GRACE_S)
 
 
 def start_gcs(session_dir: str, host: str = "127.0.0.1", port: int = 0) -> tuple:
@@ -84,7 +142,11 @@ def start_gcs(session_dir: str, host: str = "127.0.0.1", port: int = 0) -> tuple
             env=_control_plane_env(),
         )
         log.close()
-        address = _read_tagged_line(proc, "GCS_ADDRESS", 30)
+        try:
+            address = _read_tagged_line(proc, "GCS_ADDRESS", 30)
+        except BaseException:
+            stop_processes([proc], GCS_STOP_GRACE_S)
+            raise
     return proc, address
 
 
@@ -125,8 +187,12 @@ def start_raylet(
             cmd, stdout=subprocess.PIPE, stderr=log, env=env
         )
         log.close()
-        address = _read_tagged_line(proc, "RAYLET_ADDRESS", 60)
-        nid = _read_tagged_line(proc, "RAYLET_NODE_ID", 10)
+        try:
+            address = _read_tagged_line(proc, "RAYLET_ADDRESS", 60)
+            nid = _read_tagged_line(proc, "RAYLET_NODE_ID", 10)
+        except BaseException:
+            stop_processes([proc], RAYLET_STOP_GRACE_S)
+            raise
     store_path = f"/dev/shm/rt_store_{nid[:12]}"
     return proc, address, nid, store_path
 

@@ -38,6 +38,7 @@ from ray_tpu.common.config import cfg
 from ray_tpu.common.ids import NodeID, WorkerID
 from ray_tpu.core import rpc
 from ray_tpu.core.errors import FencedError, is_fenced
+from ray_tpu.core.node import WORKER_STOP_GRACE_S, stop_processes
 from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
@@ -69,9 +70,6 @@ _TPU_CHIP_BOUNDS_VARS = (
 )
 _TPU_PROCESS_BOUNDS_VARS = ("TPU_PROCESS_BOUNDS", "TPU_HOST_BOUNDS")
 
-#: SIGTERM-to-SIGKILL grace for a chip-holding worker being retired
-_CHIP_RECLAIM_GRACE_S = 5.0
-
 
 @dataclass
 class WorkerEntry:
@@ -92,6 +90,8 @@ class WorkerEntry:
     # containerized workers: `docker/podman kill <name>` argv — SIGKILL
     # on `proc` (the run CLIENT) never reaches the container
     container_kill_argv: Optional[list] = None
+    # that command once run (_hard_kill_worker): a child to reap too
+    container_kill_proc: Optional[subprocess.Popen] = None
     # rt.start.worker: Popen -> worker_ready; None once it reported in
     start_span: Optional[tracing.Span] = None
 
@@ -129,12 +129,13 @@ class Raylet:
         self._tpu_chips_free: Set[int] = set(
             range(int(self.resources.get("TPU", 0)))
         )
-        # killed chip holders that have not exited yet (_reclaim_chips)
-        self._chip_reclaims: Dict[asyncio.Task, WorkerEntry] = {}
+        # workers being ended and not reaped yet (_retire)
+        self._retiring: Dict[asyncio.Task, WorkerEntry] = {}
         self._peer_conns: Dict[str, rpc.Connection] = {}
         self._inflight_pulls: Dict[bytes, asyncio.Future] = {}
         self._tasks: List[asyncio.Task] = []
         self._closing = False
+        self._exit_task: Optional[asyncio.Task] = None  # _on_gcs_lost
         # Object spilling (reference role: raylet/local_object_manager.h:41
         # SpillObjects + python/ray/_private/external_storage.py).  Primary
         # copies are `protect`ed in the arena (LRU cannot evict them);
@@ -277,42 +278,33 @@ class Raylet:
 
     def _on_gcs_lost(self):
         if not self._closing:
+            self._closing = True  # now: a second call starts no second close()
             logger.error(
                 "raylet %s: GCS unreachable past the reconnect budget; "
                 "shutting down", self.node_id,
             )
-            for w in self.workers.values():
-                if w.container_kill_argv:
-                    # fire-and-forget: this process is about to _exit and
-                    # a terminated run client strands its container
-                    try:
-                        subprocess.Popen(
-                            w.container_kill_argv,
-                            stdout=subprocess.DEVNULL,
-                            stderr=subprocess.DEVNULL,
-                        )
-                    except Exception:
-                        pass
-                w.proc.terminate()
-            os._exit(1)
+            async def close_and_exit():
+                try:
+                    await self.close()
+                finally:
+                    os._exit(1)
+
+            # referenced: the loop holds a task weakly
+            self._exit_task = asyncio.ensure_future(close_and_exit())
 
     async def close(self):
+        """End every worker and return when no child of this raylet is
+        alive and none is a zombie: those it still has are retired now,
+        all at once, and those retired earlier are waited for with them."""
         self._closing = True
         for t in self._tasks:
             t.cancel()
-        for t, w in list(self._chip_reclaims.items()):
-            t.cancel()
-            self._hard_kill_worker(w)
-        for w in list(self.workers.values()):
-            try:
-                w.proc.terminate()
-            except Exception:
-                pass
-        for w in list(self.workers.values()):
-            try:
-                w.proc.wait(timeout=2)
-            except Exception:
-                self._hard_kill_worker(w)
+        while self.workers or self._retiring:
+            for w in list(self.workers.values()):
+                del self.workers[w.worker_id]
+                self._retire(w)
+            await asyncio.wait(list(self._retiring))
+        self._idle_by_env.clear()
         if self.gcs:
             await self.gcs.close()
         await self.server.close()
@@ -646,10 +638,10 @@ class Raylet:
             self.node_id, reason, len(self.workers),
         )
         for w in list(self.workers.values()):
-            self._hard_kill_worker(w)
+            # a holder's chips come back when it has been reaped
+            self._retire(w, hard=True)
         self.workers.clear()
         self._idle_by_env.clear()
-        self._tpu_chips_free = set(range(int(self.resources.get("TPU", 0))))
         for oid in list(self._spilled):
             self._drop_spill_file(oid)
         try:
@@ -861,10 +853,11 @@ class Raylet:
         client detaches on SIGKILL without stopping the container, so
         the container is killed by name first.  Fire-and-forget — this
         runs inside async close(); blocking on a wedged container
-        runtime daemon would stall the event loop per worker."""
-        if w.container_kill_argv:
+        runtime daemon would stall the event loop per worker; _retire
+        reaps the command with the worker."""
+        if w.container_kill_argv and w.container_kill_proc is None:
             try:
-                subprocess.Popen(
+                w.container_kill_proc = subprocess.Popen(
                     w.container_kill_argv,
                     stdout=subprocess.DEVNULL,
                     stderr=subprocess.DEVNULL,
@@ -1158,29 +1151,49 @@ class Raylet:
 
     async def _evict_idle_chip_holders(self, n_tpu_needed: int):
         """Kill idle workers holding chips until n_tpu_needed are free
-        or on their way back (_reclaim_chips)."""
+        or on their way back (_retire)."""
         for pool in list(self._idle_by_env.values()):
             for cand in list(pool):
                 coming = sum(
-                    len(w.tpu_chips) for w in self._chip_reclaims.values()
+                    len(w.tpu_chips) for w in self._retiring.values()
                 )
                 if len(self._tpu_chips_free) + coming >= n_tpu_needed:
                     return
                 if cand.tpu_chips and cand.idle:
                     pool.remove(cand)
-                    await self._on_worker_exit(cand, kill=True)
+                    await self._on_worker_exit(cand)
 
-    async def _reclaim_chips(self, w: WorkerEntry):
-        """Hand a retired worker's chips back once its process is gone.
-        A chip belongs to one process at a time: freed any earlier, the
-        next lease would bind a worker that cannot open it."""
-        deadline = time.monotonic() + _CHIP_RECLAIM_GRACE_S
-        while w.proc.poll() is None:
-            if time.monotonic() > deadline:
-                self._hard_kill_worker(w)
-                deadline = float("inf")
-            await asyncio.sleep(0.01)
-        self._release_accel_env(w.bound_env)
+    def _retire(self, w: WorkerEntry, hard: bool = False) -> None:
+        """The one way a worker's process is ended: SIGTERM now (``hard``:
+        SIGKILL), SIGKILL after the grace, and its chips go back when it
+        has been reaped.  A chip belongs to one process at a time: freed
+        any earlier, the next lease would bind a worker that cannot open it."""
+        if hard:
+            self._hard_kill_worker(w)
+        task = asyncio.ensure_future(self._reap(w))
+        self._retiring[task] = w
+        task.add_done_callback(self._retiring.pop)
+
+    async def _reap(self, w: WorkerEntry):
+        """``stop_processes`` on ``w``, off the loop (it blocks until the
+        process has been reaped), the kill at the end of the grace being
+        ``_hard_kill_worker`` on the loop; then its chips are free."""
+        loop = asyncio.get_running_loop()
+        told_at = time.monotonic()
+        await loop.run_in_executor(
+            None, stop_processes, [w.proc], WORKER_STOP_GRACE_S,
+            lambda _proc: loop.call_soon_threadsafe(self._hard_kill_worker, w),
+        )
+        if w.container_kill_proc is not None:
+            await loop.run_in_executor(None, w.container_kill_proc.wait)
+        if w.tpu_chips:
+            logger.info(
+                "worker %s (pid %d, chips %s) reaped %.2f s after it was "
+                "told to go, exit code %s", w.worker_id, w.proc.pid,
+                list(w.tpu_chips), time.monotonic() - told_at, w.proc.returncode,
+            )
+        if w.bound_env:
+            self._release_accel_env(w.bound_env)
 
     async def _await_reclaimed_chips(self, n_tpu: int):
         """Park a lease until the chips it needs have come back from
@@ -1189,16 +1202,25 @@ class Raylet:
         deadline = time.monotonic() + cfg.worker_start_timeout_s
         # "an aligned block is free", not "n chips are free": chips come
         # back one at a time, and two odd ones are no pair
-        while (
-            _pick_chips(self._tpu_chips_free, n_tpu) is None
-            and self._chip_reclaims
-        ):
+        while _pick_chips(self._tpu_chips_free, n_tpu) is None:
+            coming = [t for t, w in self._retiring.items() if w.tpu_chips]
             left = deadline - time.monotonic()
-            if left <= 0:
+            if not coming or left <= 0:
                 return
             await asyncio.wait(
-                list(self._chip_reclaims), timeout=left,
-                return_when=asyncio.FIRST_COMPLETED,
+                coming, timeout=left, return_when=asyncio.FIRST_COMPLETED,
+            )
+
+    def _refuse_lease_if_going(self) -> None:
+        if self.draining or self._fencing or self._closing:
+            # belt-and-braces with the GCS-side exclusion: a grant that
+            # was in flight when the drain notify landed must not bind a
+            # fresh worker to a node about to be terminated (or one
+            # mid-fence, whose workers are being purged)
+            raise rpc.RpcError(
+                f"node {self.node_id.hex()[:12]} is "
+                f"{'draining' if self.draining else 'fencing or closing'}; "
+                f"lease refused"
             )
 
     async def rpc_lease_worker(self, conn: rpc.Connection, p):
@@ -1206,16 +1228,7 @@ class Raylet:
         Returns its address."""
         from ray_tpu.core import runtime_env as rtenv_mod
 
-        if self.draining or self._fencing:
-            # belt-and-braces with the GCS-side exclusion: a grant that
-            # was in flight when the drain notify landed must not bind a
-            # fresh worker to a node about to be terminated (or one
-            # mid-fence, whose workers are being purged)
-            raise rpc.RpcError(
-                f"node {self.node_id.hex()[:12]} is "
-                f"{'draining' if self.draining else 'fencing'}; "
-                f"lease refused"
-            )
+        self._refuse_lease_if_going()
         resources = p["resources"]
         rtenv = p.get("runtime_env")
         rtenv_key = rtenv_mod.descriptor_key(rtenv)
@@ -1257,6 +1270,10 @@ class Raylet:
                         if not k.startswith("_")
                     },
                 }
+        # again after the waits above: nothing yields from here to
+        # _spawn_worker, and a worker spawned after close() has retired
+        # the last one would be nobody's
+        self._refuse_lease_if_going()
         accel_env = self._accel_env_for(resources)
         key = _env_key(accel_env, rtenv_key)
         # exact-match idle worker?
@@ -1295,6 +1312,10 @@ class Raylet:
             w.spoken_for = True
             try:
                 await self._wait_for_worker(w)
+            except BaseException:
+                # nobody holds the chips picked above
+                self._release_accel_env(accel_env)
+                raise
             finally:
                 # handed out below; or, after a failed start, anyone's
                 # should it still come up
@@ -1310,7 +1331,7 @@ class Raylet:
                 # chips allocated above and the worker itself must not
                 # leak — refund and retire it
                 self._release_accel_env(accel_env)
-                await self._on_worker_exit(w, kill=True)
+                await self._on_worker_exit(w)
                 raise
             w.bound_env = accel_env
             w.rtenv_key = rtenv_key
@@ -1343,7 +1364,7 @@ class Raylet:
         if p.get("broken") or w.proc.poll() is not None or (
             w.conn is None or w.conn.closed
         ):
-            await self._on_worker_exit(w, kill=True)
+            await self._on_worker_exit(w)
             return True
         self._idle_by_env.setdefault(
             _env_key(w.bound_env, w.rtenv_key), []
@@ -1351,26 +1372,17 @@ class Raylet:
         return True
 
     async def _on_worker_exit(
-        self, w: WorkerEntry, kill: bool = False,
-        reason: Optional[str] = None,
+        self, w: WorkerEntry, reason: Optional[str] = None,
     ):
+        """A worker has died, or is to: forget it, end its process
+        (_retire) and tell the GCS."""
         self.workers.pop(w.worker_id, None)
         for pool in self._idle_by_env.values():
             if w in pool:
                 pool.remove(w)
-        if w.tpu_chips and w.proc.poll() is None:
-            task = asyncio.ensure_future(self._reclaim_chips(w))
-            self._chip_reclaims[task] = w
-            task.add_done_callback(self._chip_reclaims.pop)
-        elif w.bound_env:
-            self._release_accel_env(w.bound_env)
-        if kill and w.proc.poll() is None:
-            try:
-                w.proc.terminate()
-            except Exception:
-                pass
         if reason is None:
             reason = f"exit code {w.proc.poll()}"
+        self._retire(w)
         try:
             await self.gcs.notify(
                 "worker_died",

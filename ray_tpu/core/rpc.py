@@ -564,9 +564,11 @@ class Server:
     async def close(self):
         if self._server:
             self._server.close()
-            await self._server.wait_closed()
         for conn in list(self.connections):
             await conn.close()
+        if self._server:
+            # after the connections: since Python 3.12 this waits for them
+            await self._server.wait_closed()
 
 
 class ReconnectingConnection:

@@ -358,6 +358,9 @@ class CollectiveManager:
         nbytes = 0
         try:
             while nbytes < expected_bytes:
+                if gh is not None and gh.failed is not None:
+                    # it may have failed before this mailbox existed
+                    raise gh.failed
                 if box.failed is not None:
                     raise box.failed
                 if not box.chunks:

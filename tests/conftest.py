@@ -33,7 +33,9 @@ except RuntimeError:
 
 import contextlib  # noqa: E402
 import faulthandler  # noqa: E402
+import glob  # noqa: E402
 import hashlib  # noqa: E402
+import shutil  # noqa: E402
 import signal  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -124,6 +126,34 @@ def _test_limit(request):
     finally:
         if running:
             os.unlink(running)
+
+
+def _own_session_dirs() -> set:
+    """The session directories of clusters THIS process started:
+    ``default_session_dir()`` ends their names in the starter's pid."""
+    return set(glob.glob(f"/tmp/ray_tpu/session_*_{os.getpid()}"))
+
+
+def pytest_sessionstart(session):
+    # a directory of an earlier process that had this pid is not ours
+    session.config._rt_found = _own_session_dirs()
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_sessionfinish(session):
+    """Remove the session directories this process's clusters left (3.5 GB
+    a run, over six xdist workers: each removes its own), but none that a
+    live GCS or raylet still names on its command line.  Nobody else's are
+    touched: another run's carry another pid.  A killed raylet's arena in
+    /dev/shm is the program's to unlink (``node.unlink_arena_of``)."""
+    cmdlines = []
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        with contextlib.suppress(OSError):
+            with open(f"/proc/{pid}/cmdline", errors="replace") as f:
+                cmdlines.append(f.read())
+    for path in _own_session_dirs() - session.config._rt_found:
+        if not any(path in text for text in cmdlines):
+            shutil.rmtree(path, ignore_errors=True)
 
 
 def pytest_collection_modifyitems(config, items):

@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 import time
+import types
 
 import pytest
 
@@ -152,8 +153,8 @@ class TestAccelEnvFor:
 
             for chip, after in ((2, 0.05), (1, 0.1)):
                 task = asyncio.ensure_future(comes_back(chip, after))
-                r._chip_reclaims[task] = None
-                task.add_done_callback(r._chip_reclaims.pop)
+                r._retiring[task] = types.SimpleNamespace(tpu_chips=(chip,))
+                task.add_done_callback(r._retiring.pop)
             await r._await_reclaimed_chips(2)
             return r._accel_env_for({"TPU": 2})["TPU_VISIBLE_CHIPS"]
 

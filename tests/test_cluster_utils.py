@@ -117,7 +117,7 @@ class TestMultiNode:
                 return True
 
         r = raylet_mod.Raylet.__new__(raylet_mod.Raylet)
-        r.draining = r._fencing = False
+        r.draining = r._fencing = r._closing = False
         r._idle_by_env, r.workers, spawned = {}, {}, []
 
         def spawn(**kw):

@@ -133,11 +133,12 @@ class TestAutoscaling:
                 return 1
 
         h = serve.run(Slow.bind(), name="auto", route_prefix=None)
-        # generate sustained concurrent load
-        t_end = time.time() + 8
+        # sustained concurrent load, until a second replica has been
+        # seen: two worker starts under a loaded host take what they take
+        t_end = time.time() + 30
         peak = 1
         responses = []
-        while time.time() < t_end:
+        while peak < 2 and time.time() < t_end:
             responses = [h.remote() for _ in range(6)]
             s = serve.status()["auto"]["Slow"]
             peak = max(peak, s["running_replicas"])
