@@ -65,6 +65,19 @@ written into the cache in place (``_write_and_read``) — and commits it.
 ``embed_dim``), ``qk_norm="head"`` norms each head of q and k apart, and
 the softmax router renormalises its chosen weights with
 ``router_norm_topk``: Qwen3-MoE's block, SDAR's.
+
+``layer_types`` gives every layer its own MIXER kind (Olmo-Hybrid's,
+Qwen3-Next's): ``full_attention`` as above, or ``linear_attention`` — the
+gated delta rule (``ops/gated_delta.py``; ``_gated_delta_mixer``), whose
+state is no list of per-token rows but ONE (d_k, d_v) float32 matrix a
+head and the last inputs of a short convolution.  Each kind has its own
+parameter stack, the one layer loop (``_layer_loop``) scans over the
+pattern's periods, and the cache holds both kinds of state in one tree
+(``init_cache``): K/V for the full layers only, ``gdn_state`` / ``gdn_conv``
+for the linear ones.  A prefill leaves a row the state of its own tokens
+from ZERO, whatever the row held; a decode step updates it where it lies.
+``post_norm`` is OLMo-2's block (the norm on each sub-layer's OUTPUT), and
+``rope_theta`` None leaves q and k unrotated.
 """
 
 from __future__ import annotations
@@ -77,9 +90,11 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu.ops import (
+    gated_delta,
     kv_decode_attention,
     latent_decode_attention,
     latent_prefill_attention,
@@ -87,6 +102,9 @@ from ray_tpu.ops import (
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
+
+#: a layer's mixer kind (``LlamaConfig.layer_types``; the published names)
+LINEAR, FULL = "linear_attention", "full_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +116,7 @@ class LlamaConfig:
     num_kv_heads: int = 32
     embed_dim: int = 4096
     mlp_dim: int = 11008
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: no rotation
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -169,10 +187,61 @@ class LlamaConfig:
     # own block of mask_block positions (block diffusion: the tokens of a
     # block see each other); 1 is causal
     mask_block: int = 1
+    # the mixer of every layer, in layer order: LINEAR | FULL, whole repeats
+    # of one period (Olmo-Hybrid: 3 linear, 1 full); () = all full attention
+    layer_types: tuple = ()
+    # the linear layers' gated delta rule: heads (keys' and values' alike),
+    # a key's and a value's size, the short convolution's taps, write
+    # strength in (0, 2) (``linear_allow_neg_eigval``) or (0, 1), and the
+    # tokens of a prefill's chunk
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_neg_eigval: bool = False
+    linear_chunk: int = 64
+    # the block's residual path: x + f(norm(x)) (False) | x + norm(f(x)),
+    # OLMo-2's and OLMo-3's (True); the same two scales either way
+    post_norm: bool = False
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.embed_dim // self.num_heads)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if (len(self.layer_types) != self.num_layers
+                    or set(self.layer_types) - {LINEAR, FULL}):
+                raise ValueError(
+                    f"layer_types names each of the {self.num_layers} layers "
+                    f"{LINEAR!r} or {FULL!r}; got {self.layer_types}"
+                )
+            if (self.latent or self.num_experts or self.first_dense_layers
+                    or self.mtp_layers or self.mask_block > 1):
+                raise NotImplementedError(
+                    "layer_types goes with K/V attention and a dense SwiGLU: no "
+                    "latent attention, experts, leading dense blocks, "
+                    "multi-token-prediction module or block mask"
+                )
+
+    @property
+    def period(self) -> tuple:
+        """The shortest run of kinds that ``layer_types`` repeats (the
+        whole list, where nothing shorter tiles it)."""
+        kinds = self.layer_types
+        for n in range(1, len(kinds) + 1):
+            if len(kinds) % n == 0 and kinds[:n] * (len(kinds) // n) == kinds:
+                return kinds[:n]
+        return ()
+
+    @property
+    def linear_layers(self) -> int:
+        return self.layer_types.count(LINEAR)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep K and V per token: all, or the full-attention
+        ones of a config with ``layer_types``."""
+        return self.layer_types.count(FULL) if self.layer_types else self.num_layers
 
     @property
     def latent(self) -> bool:
@@ -223,12 +292,52 @@ class LlamaConfig:
         defaults.update(kw)
         return LlamaConfig(**defaults)
 
+    @staticmethod
+    def olmo_hybrid_7b(**kw) -> "LlamaConfig":
+        """Olmo-Hybrid-7B's published shape: 32 layers of (3 gated-delta-rule,
+        1 full attention), OLMo-2's block, no rotation.  ``num_layers`` may
+        be cut to fewer whole periods."""
+        layers = kw.setdefault("num_layers", 32)
+        defaults = dict(
+            vocab_size=100352, max_seq_len=65536, num_heads=30, num_kv_heads=30,
+            embed_dim=3840, mlp_dim=11008, rope_theta=None, rms_eps=1e-6,
+            qk_norm=True, post_norm=True,
+            layer_types=(LINEAR, LINEAR, LINEAR, FULL) * (layers // 4),
+            linear_num_heads=30, linear_key_head_dim=96,
+            linear_value_head_dim=192, linear_neg_eigval=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def tiny_hybrid(**kw) -> "LlamaConfig":
+        """``olmo_hybrid_7b`` at toy widths: two periods, chunks of four."""
+        defaults = dict(
+            num_layers=8, num_kv_heads=4, rope_theta=None, qk_norm=True,
+            post_norm=True, layer_types=(LINEAR, LINEAR, LINEAR, FULL) * 2,
+            linear_num_heads=4, linear_key_head_dim=8,
+            linear_value_head_dim=16, linear_neg_eigval=True, linear_chunk=4,
+        )
+        defaults.update(kw)
+        return LlamaConfig.tiny(**defaults)
+
+
+#: the parameter stack of each mixer kind (``_stacks``)
+_STACK_OF = {LINEAR: "gdn_blocks", FULL: "blocks"}
+
 
 def _stacks(config: LlamaConfig):
     """The parameter stacks of the one layer loop, in layer order:
     ``[(name in the tree, layers, first layer's index, expert FFN?)]``.
-    One stack, ``blocks``, unless dense blocks lead the expert blocks."""
+    One stack, ``blocks``, unless dense blocks lead the expert blocks —
+    or the layers are of two mixer kinds (``layer_types``): then one stack
+    a kind, ``gdn_blocks`` the linear layers' and ``blocks`` the full
+    ones', each in its own layers' order, and the loop interleaves them
+    (``_layer_loop``)."""
     c = config
+    if c.layer_types:
+        return [(_STACK_OF[LINEAR], c.linear_layers, 0, False),
+                (_STACK_OF[FULL], c.kv_layers, 0, False)]
     if not c.first_dense_layers:
         return [("blocks", c.num_layers, 0, bool(c.num_experts))]
     return [
@@ -290,6 +399,19 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     out = {"tok_embed": ("vocab", "embed"), "final_norm": ("embed",)}
     for name, _n, _first, experts in _stacks(c):
         out[name] = expert if experts else dense
+    if c.layer_types:
+        out[_STACK_OF[LINEAR]] = {
+            **{k: v for k, v in dense.items() if k not in (*attn, "wo")},
+            "gdn_wq": ("layers", "embed", "heads", None),
+            "gdn_wk": ("layers", "embed", "heads", None),
+            "gdn_wv": ("layers", "embed", "heads", None),
+            "gdn_wz": ("layers", "embed", "heads", None),
+            "gdn_wa": ("layers", "embed", "heads"),
+            "gdn_wb": ("layers", "embed", "heads"),
+            "gdn_wo": ("layers", "heads", None, "embed"),
+            "gdn_conv": ("layers", None, None), "gdn_norm": ("layers", None),
+            "a_log": ("layers", "heads"), "dt_bias": ("layers", "heads"),
+        }
     if not c.tie_embeddings:
         out["lm_head"] = ("vocab", "embed")
     if c.mtp_layers:
@@ -301,11 +423,15 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     return out
 
 
-def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool) -> Params:
+def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
+                 linear: bool = False) -> Params:
     """One stack of ``layers`` blocks: a leading layer axis on every
     leaf.  ``experts``: the feed-forward is the expert layer (router,
     ``experts_here`` SwiGLUs of width ``expert_dim``, the shared expert
-    if any), else one SwiGLU of width ``mlp_dim``."""
+    if any), else one SwiGLU of width ``mlp_dim``.  ``linear``: the mixer
+    is the gated delta rule (``_gated_delta_mixer`` names its tensors;
+    ``a_log`` = log U(0, 16) and ``dt_bias`` = 1 as ``fla``'s
+    ``GatedDeltaNet`` starts them)."""
     c = config
     dt = c.param_dtype
     L, E, H, KV, D = layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim
@@ -321,7 +447,24 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool) -> Params
     def more(i):  # keys for tensors newer than the eight above
         return jax.random.fold_in(k[1], i)
 
-    if c.latent:
+    if linear:
+        Hl, Dk, Dv = c.linear_num_heads, c.linear_key_head_dim, c.linear_value_head_dim
+        blk = {
+            "gdn_wq": norm(k[1], (L, E, Hl, Dk), std),
+            "gdn_wk": norm(k[2], (L, E, Hl, Dk), std),
+            "gdn_wv": norm(k[3], (L, E, Hl, Dv), std),
+            "gdn_wz": norm(more(1), (L, E, Hl, Dv), std),
+            "gdn_wa": norm(more(2), (L, E, Hl), std),
+            "gdn_wb": norm(more(3), (L, E, Hl), std),
+            "gdn_wo": norm(k[4], (L, Hl, Dv, E), resid_std),
+            # the taps in front: a tap's channels lie along the lanes
+            "gdn_conv": norm(more(4), (L, c.linear_conv_kernel, Hl * (2 * Dk + Dv)), std),
+            "gdn_norm": jnp.ones((L, Dv), dt),
+            "a_log": jnp.log(jax.random.uniform(
+                more(5), (L, Hl), jnp.float32, 1e-6, 16.0)).astype(dt),
+            "dt_bias": jnp.ones((L, Hl), dt),
+        }
+    elif c.latent:
         Q, C = c.q_lora_rank, c.kv_lora_rank
         Dn, Dr, Dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         J, Di = c.index_n_heads, c.index_head_dim
@@ -400,7 +543,10 @@ def init(rng, config: LlamaConfig) -> Params:
         # the one stack of a config without leading dense blocks draws
         # from ``rng`` itself, as it always did
         key = jax.random.fold_in(rng, first) if first else rng
-        params[name] = _init_blocks(key, c, layers, experts)
+        linear = name == _STACK_OF[LINEAR]
+        if linear:
+            key = jax.random.fold_in(rng, 1 << 21)
+        params[name] = _init_blocks(key, c, layers, experts, linear)
     if not c.tie_embeddings:
         params["lm_head"] = norm(
             jax.random.fold_in(k0, 1), (c.vocab_size, c.embed_dim), std
@@ -432,7 +578,10 @@ def _rmsnorm(x, scale, eps):
 
 
 def _rope(x, positions, theta):
-    """Rotary embedding over the last dim.  x: (B, S, H, D)."""
+    """Rotary embedding over the last dim.  x: (B, S, H, D).  ``theta``
+    None: no rotation."""
+    if theta is None:
+        return x
     D = x.shape[-1]
     half = D // 2
     freqs = jnp.exp(
@@ -623,23 +772,157 @@ def _ffn(h, p, config: LlamaConfig):
     return y, {"rows": rows, "experts": expert.reshape(B, S, K)}
 
 
+def _norm_in(x, p, name: str, config: LlamaConfig):
+    """A sub-layer's input: the residual normed, or (``post_norm``) as it is."""
+    return x if config.post_norm else _rmsnorm(x, p[name], config.rms_eps)
+
+
+def _norm_out(y, p, name: str, config: LlamaConfig):
+    """A sub-layer's output as it is, or (``post_norm``) normed: the same
+    scale on the other side of the sub-layer."""
+    return _rmsnorm(y, p[name], config.rms_eps) if config.post_norm else y
+
+
+def _gated_delta_mixer(x, p, config: LlamaConfig, state=None, tail=None):
+    """A linear-attention layer's mixer: the gated delta rule behind its
+    projections and short convolution (Qwen3-Next's ``GatedDeltaNet``,
+    Olmo-Hybrid's).  x: (R, S, E).  Per token: ``q~, k~`` (H d_k each),
+    ``v~, z`` (H d_v each), ``a, b`` (H each), all plain projections of x;
+    ``[q~; k~; v~]`` through a causal depthwise convolution of
+    ``linear_conv_kernel`` taps and a silu; per head ``q = l2norm(q) /
+    sqrt(d_k)``, ``k = l2norm(k)``; ``beta = sigmoid(b)`` (doubled with
+    ``linear_neg_eigval``), ``log alpha = -exp(a_log) softplus(a +
+    dt_bias)``, float32; the rule (``ops/gated_delta.py``); ``y = W_o
+    [RMSNorm_{d_v}(o) gdn_norm * silu(z)]``.
+
+    ``state`` (R, H, d_k, d_v) float32 and ``tail`` (K - 1, R, channels),
+    the last pre-activation inputs of the convolution: ONE token of every
+    row is a ``gated_delta.step`` from them.  Without them the run starts
+    from nothing (zero state, zeros in front of the convolution) and goes
+    through the chunked ``gated_delta.scan``.  Returns (y (R, S, E), the
+    state after the run, the new tail)."""
+    c = config
+    R, S, _ = x.shape
+    H, Dk, Dv, K = (c.linear_num_heads, c.linear_key_head_dim,
+                    c.linear_value_head_dim, c.linear_conv_kernel)
+    dt, f32 = c.dtype, jnp.float32
+    with jax.named_scope("gdn_proj"):
+        def heads(name):  # (R, S, H * d) in the compute dtype
+            return jnp.einsum("rse,ehd->rshd", x, p[name].astype(dt)).reshape(R, S, -1)
+
+        u = jnp.concatenate([heads("gdn_wq"), heads("gdn_wk"), heads("gdn_wv")], -1)
+        z = heads("gdn_wz").reshape(R, S, H, Dv)
+        a = jnp.einsum("rse,eh->rsh", x, p["gdn_wa"].astype(dt), preferred_element_type=f32)
+        b = jnp.einsum("rse,eh->rsh", x, p["gdn_wb"].astype(dt), preferred_element_type=f32)
+        u = jnp.swapaxes(u, 0, 1)                               # (S, R, channels)
+        if tail is None:
+            tail = jnp.zeros((K - 1, *u.shape[1:]), dt)
+        window = jnp.concatenate([tail, u])                     # (K - 1 + S, R, channels)
+        taps = p["gdn_conv"].astype(f32)
+        mixed = jax.nn.silu(sum(
+            window[j:j + S].astype(f32) * taps[j] for j in range(K)
+        ))
+        mixed = jnp.swapaxes(mixed, 0, 1)                       # (R, S, channels)
+        q, k, v = (part.reshape(R, S, H, -1) for part in jnp.split(
+            mixed, [H * Dk, 2 * H * Dk], axis=-1))
+
+        def l2norm(t):
+            return t * lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+        q, k = l2norm(q) / math.sqrt(Dk), l2norm(k)
+        beta = jax.nn.sigmoid(b) * (2.0 if c.linear_neg_eigval else 1.0)
+        log_alpha = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+            a + p["dt_bias"].astype(f32))
+    if state is not None:
+        with jax.named_scope("gdn_step"):
+            o, state = gated_delta.step(
+                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], state)
+            o = o[:, None]
+    else:
+        with jax.named_scope("gdn_scan"):
+            o, state = gated_delta.scan(
+                q, k, v, log_alpha, beta, jnp.zeros((R, H, Dk, Dv), f32),
+                chunk=c.linear_chunk)
+    with jax.named_scope("gdn_out"):
+        o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + c.rms_eps)
+        o = o * p["gdn_norm"].astype(f32) * jax.nn.silu(z.astype(f32))
+        y = jnp.einsum("rshv,hve->rse", o.astype(dt), p["gdn_wo"].astype(dt))
+    return y, state, window[S:]
+
+
 def _block(x, p, positions, config: LlamaConfig):
     c = config
-    h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-    q, kk, vv = _qkv(h, p, positions, c)
-    # GQA: repeat each KV head across its query group
-    if c.q_per_kv > 1:
-        kk = jnp.repeat(kk, c.q_per_kv, axis=2)
-        vv = jnp.repeat(vv, c.q_per_kv, axis=2)
-    q = constrain(q, ("batch", "seq", "heads", None))
-    kk = constrain(kk, ("batch", "seq", "heads", None))
-    vv = constrain(vv, ("batch", "seq", "heads", None))
-    attn = _attention(q, kk, vv, c)
-    x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
+    h = _norm_in(x, p, "attn_norm", c)
+    if "a_log" in p:
+        y = _gated_delta_mixer(h, p, c)[0]
+    else:
+        q, kk, vv = _qkv(h, p, positions, c)
+        # GQA: repeat each KV head across its query group
+        if c.q_per_kv > 1:
+            kk = jnp.repeat(kk, c.q_per_kv, axis=2)
+            vv = jnp.repeat(vv, c.q_per_kv, axis=2)
+        q = constrain(q, ("batch", "seq", "heads", None))
+        kk = constrain(kk, ("batch", "seq", "heads", None))
+        vv = constrain(vv, ("batch", "seq", "heads", None))
+        attn = _attention(q, kk, vv, c)
+        y = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
+    x = x + _norm_out(y, p, "attn_norm", c)
     x = constrain(x, ("batch", "seq", "embed"))
-    y, routing = _ffn(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
-    x = constrain(x + y, ("batch", "seq", "embed"))
+    y, routing = _ffn(_norm_in(x, p, "mlp_norm", c), p, c)
+    x = constrain(x + _norm_out(y, p, "mlp_norm", c), ("batch", "seq", "embed"))
     return x, routing and routing["experts"]
+
+
+def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int = 1):
+    """The ONE layer loop: ``carry`` through every block in layer order.
+    ``block(carry, p, cache_layer) -> (carry, aux)``: p one layer's
+    parameters (and ``p["layer"]``, its index in its stack),
+    ``cache_layer`` its index among the layers that keep its kind of
+    state.  Returns (carry, aux with a leading axis over the layers that
+    gave it).
+
+    One ``lax.scan`` a parameter stack, in turn (``_stacks``) — or, where
+    the layers are of two mixer kinds, ONE scan over the pattern's periods
+    whose body runs a period's layers in order, each on its own kind's
+    stack: a period of (3 linear, 1 full) is four blocks in the loop's
+    body and not ``num_layers`` unrolled."""
+    c = config
+    if c.layer_types:
+        kinds = c.period
+        periods = c.num_layers // len(kinds)
+        per = {kind: kinds.count(kind) for kind in sorted(set(kinds))}
+
+        def body(carry, period):
+            seen, kept = dict.fromkeys(per, 0), {}
+            for kind in kinds:
+                l = period * per[kind] + seen[kind]
+                seen[kind] += 1
+                # the layer's slice of each stacked leaf, taken where it is
+                # used: sliced a period at a time (the scan's ``xs``) and
+                # then a layer, every weight is copied once a call
+                p = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+                    params[_STACK_OF[kind]],
+                )
+                carry, aux = block(carry, dict(p, layer=l), l)
+                for key, v in aux.items():
+                    kept.setdefault(key, []).append(v)
+            return carry, {key: jnp.stack(v) for key, v in kept.items()}
+
+        carry, aux = lax.scan(body, carry, jnp.arange(periods), unroll=unroll)
+        return carry, {k: v.reshape(-1, *v.shape[2:]) for k, v in aux.items()}
+    kept = {}
+    for name, _layers, first, _experts in _stacks(c):
+        xs, whole = _layer_params(params[name], c)
+
+        def body(carry, layer, first=first, whole=whole):
+            p, l = layer
+            return block(carry, dict(p, layer=l, **whole), l + first if first else l)
+
+        carry, aux = lax.scan(body, carry, xs, unroll=unroll)
+        for k, v in aux.items():
+            kept.setdefault(k, []).append(v)
+    return carry, {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
 
 
 def _features_and_choices(params: Params, tokens, config: LlamaConfig):
@@ -655,17 +938,15 @@ def _features_and_choices(params: Params, tokens, config: LlamaConfig):
     x = constrain(x, ("batch", "seq", "embed"))
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
-    xs, whole = _layer_params(params["blocks"], c)
-
-    def body(carry, layer):
+    def block(carry, p, _cache_layer):
         fn = _block
         if c.remat:
             fn = jax.checkpoint(_block, static_argnums=(3,))
-        p, l = layer
-        return fn(carry, dict(p, layer=l, **whole), positions, c)
+        x, experts = fn(carry, p, positions, c)
+        return x, {} if experts is None else {"experts": experts}
 
-    x, experts = lax.scan(body, x, xs, unroll=max(1, c.scan_unroll))
-    return _rmsnorm(x, params["final_norm"], c.rms_eps), experts
+    x, aux = _layer_loop(params, c, x, block, unroll=max(1, c.scan_unroll))
+    return _rmsnorm(x, params["final_norm"], c.rms_eps), aux.get("experts")
 
 
 def features(params: Params, tokens, config: LlamaConfig):
@@ -753,7 +1034,7 @@ def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float
         per_key = c.num_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim + c.v_head_dim)
         attn = 6 * c.cache_layers * (per_key * seen + indexer)
     else:
-        attn = 12 * c.num_layers * c.num_heads * c.head_dim * S  # 2*2*3 * L * HD * S
+        attn = 12 * c.kv_layers * c.num_heads * c.head_dim * S  # 2*2*3 * L * HD * S
     return 6.0 * n + attn
 
 
@@ -851,6 +1132,20 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     rest: ``_add_wide``) because 32 rows at 10k keys are 2 M a step and
     int32 would last 1,000 steps.
 
+    A config with ``layer_types`` keeps TWO KINDS OF STATE in the one
+    tree, each for its own layers: ``k`` / ``v`` as above for the FULL
+    layers only (4 of Olmo-Hybrid's 16 here, not 16), and for the linear
+    layers no per-token rows at all but, a row, ``gdn_state`` (linear
+    layers, B, H, d_k, d_v) float32, the gated delta rule's matrix a head,
+    and ``gdn_conv`` (linear layers, K - 1, B, H (2 d_k + d_v)), the last
+    K - 1 pre-activation inputs of the short convolution — the taps in
+    front of the rows, so that the two minor dimensions are (rows,
+    channels) and no tile is padded from 3 rows to 16.  Neither grows with
+    ``max_len``; a row's length says nothing about them, so a prefill
+    WRITES the row's state from zero and nobody "forgets" it by a length.
+    ``gdn_counts`` (``GDN_COUNTS``, 2) rides beside them as ``_add_wide``
+    pairs.
+
     An expert config adds int32 running totals that ride the donated
     cache like K and V, so no step pays a device-to-host copy for them
     (``serve/llm.py`` reads them in ``stats()``): ``moe_expert_tokens``
@@ -880,11 +1175,21 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             "dsa_keys": jnp.zeros((c.num_layers, 3, 2, 2), jnp.int32),
         }
     else:
-        shape = (c.num_layers, batch_size, max_len, c.num_kv_heads * c.head_dim)
+        shape = (c.kv_layers, batch_size, max_len, c.num_kv_heads * c.head_dim)
         cache = {
             "k": jnp.zeros(shape, c.dtype),
             "v": jnp.zeros(shape, c.dtype),
         }
+    if c.layer_types:
+        H, Dk, Dv = c.linear_num_heads, c.linear_key_head_dim, c.linear_value_head_dim
+        cache.update({
+            "gdn_state": jnp.zeros((c.linear_layers, batch_size, H, Dk, Dv), jnp.float32),
+            "gdn_conv": jnp.zeros(
+                (c.linear_layers, c.linear_conv_kernel - 1, batch_size, H * (2 * Dk + Dv)),
+                c.dtype,
+            ),
+            "gdn_counts": jnp.zeros((len(GDN_COUNTS), 2), jnp.int32),
+        })
     if c.num_experts:
         layers = c.cache_layers - c.first_dense_layers
         cache["moe_expert_tokens"] = jnp.zeros(
@@ -906,7 +1211,14 @@ def _latent_row(config: LlamaConfig) -> int:
 
 
 #: the cache's entries that hold tokens' state (the rest are counters)
-_STATE = ("k", "v", "ckv", "ik")
+_STATE = ("k", "v", "ckv", "ik", "gdn_state", "gdn_conv")
+#: ``cache["gdn_counts"]``'s rows, each an ``_add_wide`` pair, all over
+#: (row, linear layer): one-token updates of decode steps (every row
+#: steps, free slots too); prompt tokens the prefills' chunked rule ran
+#: and the identity positions that filled their last chunks; bytes of
+#: recurrent state the decode steps read and wrote (once each)
+GDN_COUNTS = ("gdn_rows_stepped", "gdn_tokens_scanned", "gdn_tokens_padded",
+              "gdn_state_bytes_step")
 _WIDE = 1 << 20
 
 
@@ -918,11 +1230,22 @@ def _add_wide(total, amount):
     return jnp.stack([high, low % _WIDE], axis=-1)
 
 
+def _gdn_amounts(config: LlamaConfig, rows: int, run: int, step: bool):
+    """What one call adds to ``gdn_counts``, from its static shapes alone
+    (int64, split into words before it meets an int32)."""
+    c = config
+    L = c.linear_layers
+    state = c.linear_num_heads * c.linear_key_head_dim * c.linear_value_head_dim * 4
+    if step:
+        return np.asarray([rows * L, 0, 0, 2 * rows * L * state], np.int64)
+    return np.asarray(
+        [0, rows * run * L, rows * (-run % c.linear_chunk) * L, 0], np.int64
+    )
+
+
 def wide_total(total) -> int:
     """A ``_add_wide`` total, summed over its leading axes, as an int
     (host side: numpy in, Python int out)."""
-    import numpy as np
-
     t = np.asarray(total).astype(np.int64).reshape(-1, 2)
     return int(t[:, 0].sum()) * _WIDE + int(t[:, 1].sum())
 
@@ -1093,6 +1416,8 @@ def _write_and_read(cache, new, layer, slot, positions, config: LlamaConfig,
         if not slab:
             return cache, None
         return cache, lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    if not slab:  # a run that attends to its own tokens alone
+        return write(cache, *lead), None
     taken = lax.dynamic_slice(cache, (layer, row0, 0, 0), (1, R, T, row))[0]
     return write(cache, *lead), write(taken, rows)
 
@@ -1107,6 +1432,15 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
     c = config
     q, kk, vv = _qkv(h, p, positions, c)
     T = state["k"].shape[2]
+    if c.layer_types and slot is not None:
+        # beside linear layers a run starts at position 0 (they can do
+        # nothing else): its own tokens are all the keys there are, and
+        # the slab's other max_len - Sq rows need not be read or scored
+        write = partial(_write_and_read, layer=p["cache_layer"], slot=slot,
+                        positions=positions, config=c, slab=False)
+        cache_k, _ = write(state["k"], kk.astype(c.dtype))
+        cache_v, _ = write(state["v"], vv.astype(c.dtype))
+        return _run_attention(q, kk, vv, c), dict(state, k=cache_k, v=cache_v), {}
     streamed = (
         slot is None and positions.shape[1] <= _STEP_RUN
         and kv_decode_attention.implementation(T, c.head_dim, c.sliding_window)
@@ -1132,7 +1466,29 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
         else:
             mask = _cache_mask(positions, T, c.sliding_window, c.mask_block)
             attn = _grouped_attention(q, slab_k, slab_v, mask, c)
-    return attn, {"k": cache_k, "v": cache_v}, {}
+    return attn, dict(state, k=cache_k, v=cache_v), {}
+
+
+def _run_attention(q, kk, vv, config: LlamaConfig):
+    """Causal attention of a run from position 0 over its own keys.  q:
+    (R, S, H, D); kk, vv: (R, S, KV, D).  Heads of whole 128-lane tiles go
+    through the flash kernel (``ops/flash_attention.py``; the run padded
+    up to its 128-token blocks: a padded key lies behind every real
+    query), so no (S, S) score reaches memory; toy heads through XLA's
+    dense body."""
+    c = config
+    if c.q_per_kv > 1:
+        kk = jnp.repeat(kk, c.q_per_kv, axis=2)
+        vv = jnp.repeat(vv, c.q_per_kv, axis=2)
+    if c.head_dim % 128:
+        from ray_tpu.ops.attention import dense_attention
+
+        return dense_attention(q, kk, vv)
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    S = q.shape[1]
+    pad = ((0, 0), (0, -S % 128), (0, 0), (0, 0))
+    return flash_attention(*(jnp.pad(t, pad) for t in (q, kk, vv)))[:, :S]
 
 
 def _rope_pairs(x, positions, theta):
@@ -1478,6 +1834,49 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     return out, state, aux
 
 
+def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
+    """A linear layer's mixer over the cache's recurrent state:
+    ``gdn_state`` (linear layers, B, H, d_k, d_v) float32 and ``gdn_conv``
+    (linear layers, K - 1, B, channels), layer ``p["cache_layer"]`` of
+    both.  One token for every row: each row's state and tail are read,
+    updated and written back where they lie.  A run of the one row
+    ``slot``: it starts from ZERO — whatever the slot held is another
+    request's — and the row is given the state and the tail its own tokens
+    leave.  Returns (y (R, Sq, E), state, no counters: what a call does is
+    known from its shapes, ``_gdn_amounts``)."""
+    R, Sq = positions.shape
+    layer = p["cache_layer"]
+    rec, conv = state["gdn_state"], state["gdn_conv"]
+    if slot is None and Sq == 1:
+        y, new, tail = _gated_delta_mixer(
+            h, p, config,
+            lax.dynamic_index_in_dim(rec, layer, 0, keepdims=False),
+            lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False),
+        )
+        # under the update's scope: XLA fuses the rule's last line into this
+        # write, and a fusion goes by its root's scope in a trace
+        with jax.named_scope("gdn_step"):
+            rec = lax.dynamic_update_index_in_dim(rec, new, layer, 0)
+        conv = lax.dynamic_update_index_in_dim(conv, tail, layer, 0)
+    elif slot is not None and R == 1:
+        # the run reads no state, so it writes none here: ``_cached_step``
+        # writes every layer's row at once after the loop.  Written here,
+        # layer by layer, XLA carries ``gdn_conv`` through the loop with
+        # the taps minor-most (the layout the projections' output has),
+        # padded 3 -> 128, and copies it in and out every call (1.1 GB;
+        # compile-only, PR 46)
+        y, new, tail = _gated_delta_mixer(h, p, config)
+        return y, state, {"gdn_state_rows": new, "gdn_conv_rows": tail}
+    else:
+        raise NotImplementedError(
+            "a config with linear-attention layers prefills whole prompts, one "
+            "row at a time, and steps one token a row (prefill_into_slot / "
+            "decode_step_rowwise): a run that continues a row's state is not "
+            "written"
+        )
+    return y, dict(state, gdn_state=rec, gdn_conv=conv), {}
+
+
 #: token rows of a run above which its feed-forward goes through the
 #: expert layer in chunks: the sorted (token, choice) rows of 8,192
 #: tokens x 8 are 805 MB a copy at 6,144 wide
@@ -1512,16 +1911,21 @@ def _block_step(x, p, state, slot, positions, config: LlamaConfig,
     ``collect`` also the choices made (``selected``, ``experts``)."""
     c = config
     with jax.named_scope("decode_attn"):
-        h = _rmsnorm(x, p["attn_norm"], c.rms_eps)
-        if c.latent:
-            attn, state, aux = _latent_attention(
-                h, p, state, slot, positions, c, collect
-            )
+        h = _norm_in(x, p, "attn_norm", c)
+        if "a_log" in p:
+            y, state, aux = _gated_delta_state(h, p, state, slot, positions, c)
         else:
-            attn, state, aux = _kv_attention(h, p, state, slot, positions, c)
-        x = x + jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
+            if c.latent:
+                attn, state, aux = _latent_attention(
+                    h, p, state, slot, positions, c, collect
+                )
+            else:
+                attn, state, aux = _kv_attention(h, p, state, slot, positions, c)
+            y = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
+        x = x + _norm_out(y, p, "attn_norm", c)
     with jax.named_scope("decode_mlp"):
-        y, routing = _ffn_in_chunks(_rmsnorm(x, p["mlp_norm"], c.rms_eps), p, c)
+        y, routing = _ffn_in_chunks(_norm_in(x, p, "mlp_norm", c), p, c)
+        y = _norm_out(y, p, "mlp_norm", c)
     if routing:
         aux["expert_rows"] = routing["rows"]
         if collect:
@@ -1568,30 +1972,35 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
     # a latent config's run reads no cache: its state stays out of the
     # loop and gets the run's rows after it (``_latent_attention``)
     riding = {} if c.latent and not step else state
-    kept = {}
-    for name, _layers, first, _experts in _stacks(c):
-        xs, whole = _layer_params(params[name], c)
 
-        def body(carry, layer, first=first, whole=whole):
-            xx, st = carry
-            p, l = layer
-            p = dict(p, layer=l, cache_layer=l + first if first else l, **whole)
-            xx, st, aux = _block_step(xx, p, st, slot, positions, c, collect)
-            return (xx, st), aux
+    def block(carry, p, cache_layer):
+        xx, st = carry
+        xx, st, aux = _block_step(
+            xx, dict(p, cache_layer=cache_layer), st, slot, positions, c, collect
+        )
+        return (xx, st), aux
 
-        (x, riding), aux = lax.scan(body, (x, riding), xs)
-        for k, v in aux.items():
-            kept.setdefault(k, []).append(v)
-    aux = {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
+    (x, riding), aux = _layer_loop(params, c, (x, riding), block)
     state = dict(state, **riding)
     for k in ("ckv", "ik"):
         if k + "_rows" in aux:  # (L, Sq, width) -> every layer, row ``slot``
             state[k] = lax.dynamic_update_slice(
                 state[k], aux.pop(k + "_rows")[:, None], (0, slot, 0, 0)
             )
+    if "gdn_state_rows" in aux:  # (linear layers, 1, ...) -> row ``slot``
+        state["gdn_state"] = lax.dynamic_update_slice(
+            state["gdn_state"], aux.pop("gdn_state_rows"), (0, slot, 0, 0, 0)
+        )
+        state["gdn_conv"] = lax.dynamic_update_slice(
+            state["gdn_conv"], aux.pop("gdn_conv_rows"), (0, 0, slot, 0)
+        )
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = x if hidden else _logits(params, x[:, -1, :], c)
     cache = _with_counts(cache, state, aux, step)
+    if c.layer_types:
+        cache["gdn_counts"] = _add_wide(
+            cache["gdn_counts"], _gdn_amounts(c, *tokens.shape, step)
+        )
     return (logits, cache, aux) if collect else (logits, cache)
 
 

@@ -6,7 +6,9 @@ The serving path's attention kernels each say by shape, in ONE function
 ``latent_decode_attention`` (a latent config's decode step) and
 ``latent_prefill_attention`` (a latent config's prefill: the flash kernel
 for a run of whole 512-token tiles with heads of whole 128-lane tiles, XLA's
-blocked body in ``models/llama.py:_latent_attention`` otherwise)."""
+blocked body in ``models/llama.py:_latent_attention`` otherwise).
+``gated_delta`` is a linear-attention layer's recurrence (one token of every
+row, and the chunked form for a prompt), plain XLA on and off the chip."""
 
 from ray_tpu.ops.ring_attention import (  # noqa: F401
     ring_attention,

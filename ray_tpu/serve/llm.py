@@ -187,7 +187,13 @@ class LLMEngine:
     launched behind the step in flight, that step's tokens are delivered,
     the prefill's first token is waited for, and the next step starts on
     an idle device — one long token gap per admission for every live
-    row, not two."""
+    row, not two.
+
+    What a slot holds is the cache's business: K/V rows a free slot's
+    next request overwrites position by position, and, where the config
+    has linear-attention layers (``layer_types``), a recurrent state that
+    no length describes — the admission's prefill writes it from zero,
+    whatever the slot held (``llama._gated_delta_state``)."""
 
     def __init__(self, params, config, *, max_slots: int = 4,
                  max_len: int = 256, max_prompt_len: Optional[int] = None,
@@ -208,6 +214,26 @@ class LLMEngine:
         self.temperature = float(temperature or 0.0)
         if self.speculative not in (0, 1):
             raise ValueError("speculative_tokens is 0 or 1: one drafted token a step")
+        if config.layer_types:
+            # a linear-attention layer's state is ONE matrix a row, not a
+            # row a position: nothing of it can be taken back or rewritten
+            refused = {
+                "speculative_tokens": self.speculative and (
+                    "a rejected draft has already moved the row's recurrent "
+                    "state, and there is no row of it to overwrite"),
+                "diffusion_block": self.diffusion_block and (
+                    "every refining pass runs the block's positions again, and "
+                    "the recurrent state can take a position once"),
+                "sliding_window": config.sliding_window and (
+                    "its rolling cache re-addresses positions (slot = position "
+                    "mod length), and the two caches have no common allocator"),
+            }
+            for option, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f"{option} does not go with a config that has "
+                        f"linear-attention layers (layer_types): {why}"
+                    )
         if self.speculative and not config.mtp_layers:
             raise ValueError(
                 "speculative_tokens=1 needs a model with a multi-token-prediction "
@@ -400,14 +426,22 @@ class LLMEngine:
         without an indexer: ``mla_keys_visible_step`` (keys a step's
         rows could see — a row's last query's, the others see prefixes —
         over every layer and row) and ``mla_keys_read_step`` (latent rows
-        fetched for them: a row's blocks once for all its queries).  Sets the gauges of both."""
+        fetched for them: a row's blocks once for all its queries).  Sets the
+        gauges of both.  A config with linear-attention layers:
+        ``llama.GDN_COUNTS``, all over (row, linear layer) —
+        ``gdn_rows_stepped`` one-token updates of decode steps,
+        ``gdn_tokens_scanned`` / ``gdn_tokens_padded`` prompt tokens the
+        prefills' chunked rule ran and the identity positions that filled
+        their last chunks, ``gdn_state_bytes_step`` bytes of recurrent state
+        the decode steps read and wrote."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
         from ray_tpu.ops import grouped_matmul
 
         out = {}
-        names = [k for k in self.cache if k.startswith(("moe_", "dsa_", "mla_"))]
+        names = [k for k in self.cache
+                 if k.startswith(("moe_", "dsa_", "mla_", "gdn_counts"))]
         if not names:
             return out
         async with self._cache_lock:
@@ -448,6 +482,13 @@ class LLMEngine:
             keys = host["mla_keys"]       # (layers, visible|read, 2)
             out["mla_keys_visible_step"] = wide_total(keys[:, 0])
             out["mla_keys_read_step"] = wide_total(keys[:, 1])
+        if "gdn_counts" in host:
+            from ray_tpu.models.llama import GDN_COUNTS
+
+            out.update(
+                (name, wide_total(host["gdn_counts"][i]))
+                for i, name in enumerate(GDN_COUNTS)
+            )
         return out
 
     # -- engine loop -----------------------------------------------------
@@ -664,8 +705,9 @@ class LLMEngine:
 
         c = self.config
         read = keys_read(last, self.cache_len, c.head_dim, c.sliding_window)
-        self.kv_keys_visible_step += visible * c.num_layers
-        self.kv_keys_read_step += int(read.sum()) * c.num_layers
+        # the layers that keep K and V: all, or a hybrid config's full ones
+        self.kv_keys_visible_step += visible * c.kv_layers
+        self.kv_keys_read_step += int(read.sum()) * c.kv_layers
 
     async def _launch_stateful(self, life: Optional[tracing.Span],
                                active: List[int]) -> None:
@@ -940,7 +982,11 @@ class LlamaDeployment:
         ``kv_keys_read_step`` (keys the steps' attention fetched, for every
         row, free slots too: whole blocks up to each row's last visible key
         where ``ops/kv_decode_attention.py``'s kernel runs, the whole slab
-        where XLA's body does; read / visible is the over-read).
+        where XLA's body does; read / visible is the over-read); over the
+        FULL layers only where the config has ``layer_types``, which adds
+        the recurrent layers' ``gdn_rows_stepped``, ``gdn_tokens_scanned``,
+        ``gdn_tokens_padded`` and ``gdn_state_bytes_step``
+        (``LLMEngine.cache_counters``).
         ``cache_bytes``
         is what the cache holds, by entry (the module's layer is one of
         ``ckv``'s)."""
