@@ -98,6 +98,7 @@ from ray_tpu.ops import (
     kv_decode_attention,
     latent_decode_attention,
     latent_prefill_attention,
+    topk_mask,
 )
 from ray_tpu.parallel.sharding import constrain
 
@@ -1548,15 +1549,13 @@ def _select_mask(scores, k: int):
     EXACTLY k of them where more than k are visible (ties at the k-th
     value go to the lower index, as ``lax.top_k`` orders them), every
     visible key where at most k are.  ``scores`` (Q, T) float32 holds
-    -inf at the keys a query may not see."""
-    visible = scores > -jnp.inf
-    if scores.shape[-1] <= k:
-        return visible
-    kth = lax.top_k(scores, k)[0][:, -1:]
-    above = scores > kth
-    tied = (scores == kth) & visible
-    need = k - above.sum(-1, keepdims=True, dtype=jnp.int32)
-    return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need))
+    -inf at the keys a query may not see.  ``ops/topk_mask.py`` says by
+    the block's shape how the k-th value and the tie are found
+    (``implementation``): ``counted`` — whole (8, 128) tiles: one Pallas
+    kernel counts its way to both, no score sorted or moved; ``sorted`` —
+    everything else: ``lax.top_k`` and a running count.  The same set
+    either way."""
+    return topk_mask.topk_mask(scores, k)
 
 
 def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
@@ -1583,16 +1582,21 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     weighted sum of latents: keys and values are never expanded over the
     cache.  ``ops/latent_decode_attention.py`` says by the cache's length
     how the chosen rows are reached: ``streamed`` — the selection is a
-    mask (``_select_mask``) and one Pallas kernel reads each row's blocks
-    of ``ckv`` up to ``pos`` where they lie, once; ``gathered`` (a cache
-    past the crossover, or no whole number of blocks) — ``lax.top_k``'s
-    indices, those rows gathered, two einsums.  The same set either way.
+    mask (``_select_mask``: the k-th score and the tie rule found by
+    counting in the kernel of ``ops/topk_mask.py``, nothing sorted) and
+    one Pallas kernel reads each row's blocks of ``ckv`` up to ``pos``
+    where they lie, once; ``gathered`` (a cache past the crossover, or no
+    whole number of blocks) — this body alone needs INDICES and sorts for
+    them: ``lax.top_k``'s, those rows gathered, two einsums.  The same
+    set either way.
 
     A run (prefill; one row, from position 0: the run's own tokens are
     all the keys there are): K and V are expanded from the run's latents
     once, and the indexer's selection is made block by block over the
     queries (``_QUERY_BLOCK``; four causal groups, each given only the keys
-    up to its own end).  ``ops/latent_prefill_attention.py`` says by the
+    up to its own end; a block's mask by the same ``_select_mask``, a group
+    of no more than ``index_topk`` keys takes what is visible).
+    ``ops/latent_prefill_attention.py`` says by the
     run's shape which body attends (``implementation``): ``flash`` — a run
     of whole tiles with heads of whole 128-lane tiles: the blocks'
     selections are laid into ONE (Sq, Sq) int8 mask and one Pallas kernel

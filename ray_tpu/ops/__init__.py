@@ -7,6 +7,9 @@ The serving path's attention kernels each say by shape, in ONE function
 ``latent_prefill_attention`` (a latent config's prefill: the flash kernel
 for a run of whole 512-token tiles with heads of whole 128-lane tiles, XLA's
 blocked body in ``models/llama.py:_latent_attention`` otherwise).
+``topk_mask`` is a sparse-attention indexer's exact top-k as a mask: by
+shape again (``implementation``), a Pallas kernel that counts its way to
+the k-th score and the tie rule, or ``lax.top_k`` and a running count.
 ``gated_delta`` is a linear-attention layer's recurrence (one token of every
 row, and the chunked form for a prompt), plain XLA on and off the chip."""
 

@@ -422,7 +422,12 @@ class LLMEngine:
         ``dsa_read_run``, the (query, key) pairs the prefills' attention
         computed scores for, a head: over ``dsa_visible_run`` the body's
         over-compute, which says which body the prefills took
-        (``ops/latent_prefill_attention.py``).  Latent attention
+        (``ops/latent_prefill_attention.py``), and ``topk_mask``, which
+        body finds the indexer's top-k at the decode step's shape —
+        ``counted`` (the kernel) or ``sorted`` — as
+        ``ops/topk_mask.py:implementation`` reads it from (rows, cache
+        length, ``index_topk``); a prefill's blocks ask it again by their
+        own shapes.  Latent attention
         without an indexer: ``mla_keys_visible_step`` (keys a step's
         rows could see — a row's last query's, the others see prefixes —
         over every layer and row) and ``mla_keys_read_step`` (latent rows
@@ -437,7 +442,7 @@ class LLMEngine:
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
-        from ray_tpu.ops import grouped_matmul
+        from ray_tpu.ops import grouped_matmul, topk_mask
 
         out = {}
         names = [k for k in self.cache
@@ -478,6 +483,10 @@ class LLMEngine:
                     (dsa["dsa_selected_run"] + dsa["dsa_selected_step"]) / seen
                 )
             out.update(dsa)
+            # no ``dsa_`` name: those are running totals, differenced over a window
+            out["topk_mask"] = topk_mask.implementation(
+                self.max_slots, self.cache_len, self.config.index_topk
+            )
         if "mla_keys" in host:
             keys = host["mla_keys"]       # (layers, visible|read, 2)
             out["mla_keys_visible_step"] = wide_total(keys[:, 0])
@@ -957,7 +966,8 @@ class LlamaDeployment:
         ``dsa_selected_step`` where rows are gathered, the streamed
         blocks' rows otherwise), ``dsa_read_run`` ((query, key) pairs the
         prefills computed scores for: whole live tiles under the prefill
-        kernel), and the gauges
+        kernel), ``topk_mask`` (which body finds the top-k at the decode
+        step's shape: ``counted`` or ``sorted``), and the gauges
         ``llm_dsa_selected_share`` and (experts held here)
         ``llm_moe_held_assignment_share``.  Latent attention without an
         indexer: ``mla_keys_visible_step`` / ``mla_keys_read_step``.  A

@@ -13,7 +13,8 @@ at one 2048-token sequence (32 heads x 128); and, whole, the two
 training cells' step programs (GPT-2 medium on one chip, GPT-2 XL on
 the four of the 2x2 under `fsdp=4`): the forward kernel once a layer,
 and XL inside its chips' memory; and GLM-5's prefill attention kernel
-at 64 heads of 256 | 256.
+at 64 heads of 256 | 256, and its top-2,048 selection kernel at the six
+block shapes the cell sends.
 """
 
 import json
@@ -29,6 +30,7 @@ import pytest
 from ray_tpu.models import gpt2
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops import latent_prefill_attention as lpa
+from ray_tpu.ops import topk_mask as tkm
 from ray_tpu.parallel import mesh as mesh_mod
 from ray_tpu.parallel import spmd
 
@@ -133,6 +135,26 @@ def test_latent_prefill_kernel_compiles_for_v5e(
     # layout; inside the model their producers write the kernel's): the
     # float32 scores would be 4 x 64 x run_len^2 bytes
     assert compiled.memory_analysis().temp_size_in_bytes < 3.1 * 64 * run_len * 256 * 2
+
+
+@pytest.mark.parametrize("rows,keys", [
+    (32, 10240),                                # a layer of the decode step
+    (64, 4096), (64, 6144), (64, 8192),         # an 8,192-token prefill's blocks
+    (128, 3072), (128, 4096),                   # a 4,096-token one's
+])
+def test_topk_mask_kernel_compiles_for_v5e(
+    v5e_chip, compiled_not_interpreted, monkeypatch, rows, keys
+):
+    """GLM-5's exact top-2,048 at the shapes its cell sends
+    (``ops/topk_mask.py``): one kernel, its block, the keys' scratch and
+    the tie rule's position search inside fast memory, and no sort."""
+    monkeypatch.setattr(tkm, "_interpret", lambda: False)
+    assert tkm.implementation(rows, keys, 2048) == "counted"
+    scores = jax.ShapeDtypeStruct((rows, keys), jnp.float32, sharding=v5e_chip)
+    compiled = jax.jit(lambda s: tkm.topk_mask(s, 2048)).lower(scores).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " sort(" not in text and "reduce-window" not in text
 
 
 # ---- the training cells' step programs ----------------------------------

@@ -18,7 +18,7 @@ from chipbench.reference import errors
 from chipbench.reference import glm_dsa as ref
 from chipbench.reference.llama import FLOAT32_TOLERANCE
 from ray_tpu.models import llama
-from ray_tpu.ops import latent_decode_attention
+from ray_tpu.ops import latent_decode_attention, topk_mask
 
 TOPK = 8
 _STEP_PROGRAMS = (llama.choices_cached, llama.decode_step_rowwise)
@@ -180,8 +180,15 @@ INF = float("inf")
     # no more keys than k at all: the mask is the visible keys
     ([[1., -INF, 2.]], 8, [[1, 0, 1]]),
 ])
-def test_selection_is_exact(scores, k, want):
-    got = llama._select_mask(jnp.asarray(scores, jnp.float32), k)
+@pytest.mark.parametrize("body", ["sorted", "counted"])
+def test_selection_is_exact(scores, k, want, body):
+    if body == "sorted":    # as the model traces a run this small
+        assert topk_mask.implementation(1, len(scores[0]), k) == "sorted"
+        got = llama._select_mask(jnp.asarray(scores, jnp.float32), k)
+    else:   # the kernel: the row eight times over whole lane tiles of unseen keys
+        wide = np.full((8, 128), -INF, np.float32)
+        wide[:, :len(scores[0])] = scores
+        got = topk_mask.counted_mask(jnp.asarray(wide), k)[:1, :len(scores[0])]
     assert np.array_equal(np.asarray(got), np.asarray(want, bool))
     # the decode step's ``lax.top_k`` orders ties the same way
     s = jnp.asarray(scores, jnp.float32)
@@ -356,6 +363,51 @@ def test_num_params_and_flops_count_the_new_layers():
         assert llama.flops_per_token(old, 64) == 6.0 * n + 12 * old.num_layers * old.embed_dim * 64
 
 
+@pytest.mark.parametrize("body", ["streamed"], indirect=True)
+def test_whole_tiles_take_the_counted_selection_and_give_the_references_sets(
+        monkeypatch, body):
+    """A 128-token prompt (one block of 128 queries over 128 keys) and three
+    decode steps in an 8-row cache of 256 keys: every selection of the model
+    goes through the kernel, and logits and selected sets are the
+    reference's."""
+    rows, cache_len, length = 8, 256, 128
+    assert topk_mask.implementation(length, length, TOPK) == "counted"
+    assert topk_mask.implementation(rows, cache_len, TOPK) == "counted"
+    traced = []
+    real = topk_mask.counted_mask
+    monkeypatch.setattr(topk_mask, "counted_mask", lambda scores, k: (
+        traced.append(scores.shape), real(scores, k))[1])
+    monkeypatch.setattr(topk_mask, "sorted_mask", None)     # never asked for
+    cfg = tiny(max_seq_len=cache_len)
+    params = weights(cfg)
+    cache = llama.init_cache(cfg, rows, cache_len)
+    seq = prompt(cfg, length, seed=5)
+    logits, cache, prefill = llama.choices_cached(
+        params, jnp.asarray([seq], jnp.int32), cache, jnp.int32(1), None, cfg)
+    system, steps = [logits[0]], []
+    for _ in range(3):
+        seq.append(int(jnp.argmax(system[-1])))
+        tokens, pos = np.zeros(rows, np.int32), np.zeros(rows, np.int32)
+        tokens[1], pos[1] = seq[-1], len(seq) - 1
+        logits, cache, chose = llama.choices_cached(
+            params, jnp.asarray(tokens), cache, None, jnp.asarray(pos), cfg)
+        system.append(logits[1])
+        steps.append(chose)
+    assert set(traced) == {(length, length), (rows, cache_len)}
+    want, info = ref.forward(
+        params, jnp.asarray(seq, jnp.int32), serve_dsa.spec_of(cfg),
+        positions=list(range(length - 1, length + 3)), head_rows=64)
+    err = errors(jnp.stack(system), want)
+    assert err["rms"] < FLOAT32_TOLERANCE["rms"] and err["max"] < FLOAT32_TOLERANCE["max"]
+    sets = np.asarray(info["selected"])                       # (L, S, S)
+    assert np.array_equal(np.asarray(prefill["selected"])[:, 0], sets[:, :length, :length])
+    for i, chose in enumerate(steps):
+        t = length + i
+        picked = np.asarray(chose["selected"])[:, :, 0]       # (L, rows, T)
+        assert np.array_equal(picked[:, 1, :t + 1], sets[:, t, :t + 1])
+        assert picked.sum(-1).tolist() == [[1, TOPK] + [1] * (rows - 2)] * cfg.num_layers
+
+
 @both_bodies
 def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache(body):
     """A ``LlamaDeployment`` on the tiny configuration: two concurrent
@@ -399,6 +451,8 @@ def test_a_deployment_streams_the_references_greedy_tokens_and_reports_its_cache
         min(TOPK, t + 1) for n in (12, 19) for t in range(n))
     assert stats["dsa_visible_step"] > stats["dsa_selected_step"] > 0
     assert before["dsa_read_step"] == before["dsa_read_run"] == 0
+    # three rows are no whole sublane tile: the sorted selection
+    assert before["topk_mask"] == stats["topk_mask"] == "sorted"
     # XLA's body at these lengths: one causal group, every (query, key) pair
     assert stats["dsa_read_run"] == cfg.num_layers * (12 * 12 + 19 * 19)
     if body == "gathered":      # the chosen rows and no other
