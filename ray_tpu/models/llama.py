@@ -784,7 +784,7 @@ def _norm_out(y, p, name: str, config: LlamaConfig):
     return _rmsnorm(y, p[name], config.rms_eps) if config.post_norm else y
 
 
-def _gated_delta_mixer(x, p, config: LlamaConfig, state=None, tail=None):
+def _gated_delta_mixer(x, p, config: LlamaConfig, states=None, tail=None):
     """A linear-attention layer's mixer: the gated delta rule behind its
     projections and short convolution (Qwen3-Next's ``GatedDeltaNet``,
     Olmo-Hybrid's).  x: (R, S, E).  Per token: ``q~, k~`` (H d_k each),
@@ -796,12 +796,14 @@ def _gated_delta_mixer(x, p, config: LlamaConfig, state=None, tail=None):
     dt_bias)``, float32; the rule (``ops/gated_delta.py``); ``y = W_o
     [RMSNorm_{d_v}(o) gdn_norm * silu(z)]``.
 
-    ``state`` (R, H, d_k, d_v) float32 and ``tail`` (K - 1, R, channels),
-    the last pre-activation inputs of the convolution: ONE token of every
-    row is a ``gated_delta.step`` from them.  Without them the run starts
-    from nothing (zero state, zeros in front of the convolution) and goes
-    through the chunked ``gated_delta.scan``.  Returns (y (R, S, E), the
-    state after the run, the new tail)."""
+    ``states``, the cache's whole ``gdn_state`` (``gated_delta.packed``: L,
+    R, d_k, H d_v) float32, and ``tail`` (K - 1, R, channels), the last
+    pre-activation inputs of the convolution: ONE token of every row is a
+    ``gated_delta.step_layer`` on layer ``p["cache_layer"]`` of the leaf,
+    where it lies.  Without them the run starts from nothing (zero state,
+    zeros in front of the convolution) and goes through the chunked
+    ``gated_delta.scan``.  Returns (y (R, S, E), the leaf after the step |
+    the run's final state (R, H, d_k, d_v), the new tail)."""
     c = config
     R, S, _ = x.shape
     H, Dk, Dv, K = (c.linear_num_heads, c.linear_key_head_dim,
@@ -834,10 +836,13 @@ def _gated_delta_mixer(x, p, config: LlamaConfig, state=None, tail=None):
         beta = jax.nn.sigmoid(b) * (2.0 if c.linear_neg_eigval else 1.0)
         log_alpha = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
             a + p["dt_bias"].astype(f32))
-    if state is not None:
+    if states is not None:
+        # the kernel's custom call and what feeds it go by this scope in a
+        # trace (``chipbench/gdn_trace.py``)
         with jax.named_scope("gdn_step"):
-            o, state = gated_delta.step(
-                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], state)
+            o, state = gated_delta.step_layer(
+                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], states,
+                p["cache_layer"])
             o = o[:, None]
     else:
         with jax.named_scope("gdn_scan"):
@@ -898,6 +903,17 @@ def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int =
             for kind in kinds:
                 l = period * per[kind] + seen[kind]
                 seen[kind] += 1
+                # the layer's index waits for the layers before it, and its
+                # weights' slices with the index.  Left free, XLA slices the
+                # three linear layers' weights at the top of the body in one
+                # fusion whose outputs do not all fit fast memory: 110 MB a
+                # period go out to HBM and come back (1.2 ms of a 20 ms
+                # decode step at Olmo-Hybrid's widths, PR 48; the program
+                # before it escaped only by being large enough for XLA's
+                # rematerialisation to start)
+                leaves, tree = jax.tree.flatten(carry)
+                leaves[0], l = lax.optimization_barrier((leaves[0], l))
+                carry = jax.tree.unflatten(tree, leaves)
                 # the layer's slice of each stacked leaf, taken where it is
                 # used: sliced a period at a time (the scan's ``xs``) and
                 # then a layer, every weight is copied once a call
@@ -1137,7 +1153,10 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     tree, each for its own layers: ``k`` / ``v`` as above for the FULL
     layers only (4 of Olmo-Hybrid's 16 here, not 16), and for the linear
     layers no per-token rows at all but, a row, ``gdn_state`` (linear
-    layers, B, H, d_k, d_v) float32, the gated delta rule's matrix a head,
+    layers, B, d_k, H d_v) float32, the gated delta rule's (d_k, d_v) matrix
+    a head with the heads side by side (``gated_delta.packed``: whole (8,
+    128) tiles at Olmo-Hybrid's 96 x 30 x 192, where a (96, 192) matrix a
+    head is padded to 256 lanes, a third more bytes to hold and to move),
     and ``gdn_conv`` (linear layers, K - 1, B, H (2 d_k + d_v)), the last
     K - 1 pre-activation inputs of the short convolution — the taps in
     front of the rows, so that the two minor dimensions are (rows,
@@ -1184,7 +1203,7 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     if c.layer_types:
         H, Dk, Dv = c.linear_num_heads, c.linear_key_head_dim, c.linear_value_head_dim
         cache.update({
-            "gdn_state": jnp.zeros((c.linear_layers, batch_size, H, Dk, Dv), jnp.float32),
+            "gdn_state": jnp.zeros((c.linear_layers, batch_size, Dk, H * Dv), jnp.float32),
             "gdn_conv": jnp.zeros(
                 (c.linear_layers, c.linear_conv_kernel - 1, batch_size, H * (2 * Dk + Dv)),
                 c.dtype,
@@ -1840,11 +1859,12 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
 
 def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
     """A linear layer's mixer over the cache's recurrent state:
-    ``gdn_state`` (linear layers, B, H, d_k, d_v) float32 and ``gdn_conv``
+    ``gdn_state`` (linear layers, B, d_k, H d_v) float32 and ``gdn_conv``
     (linear layers, K - 1, B, channels), layer ``p["cache_layer"]`` of
     both.  One token for every row: each row's state and tail are read,
-    updated and written back where they lie.  A run of the one row
-    ``slot``: it starts from ZERO — whatever the slot held is another
+    updated and written back where they lie (the state by
+    ``gated_delta.step_layer``: in one pass where its kernel runs).  A run
+    of the one row ``slot``: it starts from ZERO — whatever the slot held is another
     request's — and the row is given the state and the tail its own tokens
     leave.  Returns (y (R, Sq, E), state, no counters: what a call does is
     known from its shapes, ``_gdn_amounts``)."""
@@ -1852,15 +1872,9 @@ def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
     layer = p["cache_layer"]
     rec, conv = state["gdn_state"], state["gdn_conv"]
     if slot is None and Sq == 1:
-        y, new, tail = _gated_delta_mixer(
-            h, p, config,
-            lax.dynamic_index_in_dim(rec, layer, 0, keepdims=False),
-            lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False),
+        y, rec, tail = _gated_delta_mixer(
+            h, p, config, rec, lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
         )
-        # under the update's scope: XLA fuses the rule's last line into this
-        # write, and a fusion goes by its root's scope in a trace
-        with jax.named_scope("gdn_step"):
-            rec = lax.dynamic_update_index_in_dim(rec, new, layer, 0)
         conv = lax.dynamic_update_index_in_dim(conv, tail, layer, 0)
     elif slot is not None and R == 1:
         # the run reads no state, so it writes none here: ``_cached_step``
@@ -1993,7 +2007,8 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
             )
     if "gdn_state_rows" in aux:  # (linear layers, 1, ...) -> row ``slot``
         state["gdn_state"] = lax.dynamic_update_slice(
-            state["gdn_state"], aux.pop("gdn_state_rows"), (0, slot, 0, 0, 0)
+            state["gdn_state"], gated_delta.packed(aux.pop("gdn_state_rows")),
+            (0, slot, 0, 0)
         )
         state["gdn_conv"] = lax.dynamic_update_slice(
             state["gdn_conv"], aux.pop("gdn_conv_rows"), (0, 0, slot, 0)

@@ -10,8 +10,11 @@ blocked body in ``models/llama.py:_latent_attention`` otherwise).
 ``topk_mask`` is a sparse-attention indexer's exact top-k as a mask: by
 shape again (``implementation``), a Pallas kernel that counts its way to
 the k-th score and the tie rule, or ``lax.top_k`` and a running count.
-``gated_delta`` is a linear-attention layer's recurrence (one token of every
-row, and the chunked form for a prompt), plain XLA on and off the chip."""
+``gated_delta`` is a linear-attention layer's recurrence: the chunked form
+for a prompt (plain XLA), and one token of every row — by shape once more
+(``implementation``), the Pallas kernel ``gated_delta_step``, one pass over
+the layer's rows of the cache's stacked state where they lie, or XLA's
+body."""
 
 from ray_tpu.ops.ring_attention import (  # noqa: F401
     ring_attention,

@@ -20,14 +20,87 @@ Both take the decay as ``log_alpha`` = log(alpha) <= 0: a chunk's
 cumulative decay is a SUM there, where the product of 64 alphas underflows
 float32 (a head whose A is 16 decays by e^-20 a token).  Everything is
 float32 with the products at ``Precision.HIGHEST`` (the chip's default for
-float32 operands is one bfloat16 pass, which would round the state);
-plain ``jax.numpy``: XLA's body is the only body.
+float32 operands is one bfloat16 pass, which would round the state).
+
+``scan`` is plain ``jax.numpy``.  The one-token update of a cache's layer
+(``step_layer``) has two bodies, chosen from the operand's SHAPE in ONE place
+(``implementation``):
+
+* ``in_place`` — the Pallas kernel ``gated_delta_step`` (the custom call
+  shows as ``gated_delta_step.N`` on a trace's op line).  The cache keeps
+  every linear layer's states in ONE leaf, ``packed``: (layers, rows, d_k,
+  heads x d_v), a row's heads side by side, so that at Olmo-Hybrid's 96 x 30
+  x 192 every (8, 128) tile is whole (a (96, 192) matrix a head lies in 256
+  lanes: a third more bytes held and moved).  The kernel takes the WHOLE
+  leaf, the layer by scalar prefetch, and gives the leaf back
+  (``input_output_aliases``): a block of (rows a step, d_k, columns of some
+  heads) comes into fast memory once, both contractions over d_k are taken
+  of it on the VPU (float32 multiplies and adds: exact float32, nothing on
+  the MXU), ``alpha S + k (x) delta`` goes back out, and nothing else of the
+  leaf moves.  ``step``'s algebra, the sum over d_k in another order.
+* ``xla`` — ``step`` on the layer's rows, sliced out and written back:
+  states that are no whole tiles (tier-1's toy widths: four heads of 16 are
+  half a lane tile).
+
+What was swept (a v5e, PR 48; the cell's shape, 32 rows x 30 heads x (96,
+192), 12 layers stacked: 141.5 MB a layer read once and written once, 0.173
+ms at the chip's 819 GB/s):
+
+  alone, a loop over the 12 layers in one program (calls 1-3; ms a layer):
+    XLA, (layers, rows, H, d_k, d_v): slice, ``step``, write back     0.495-0.500
+    whole-array ``jnp`` on (d_k, 384) a head pair, k and q handed over
+      TRANSPOSED (d_k, H) and broadcast along the lanes; columns of
+      2 / 6 / 10 / 30 heads a step, one row                         .427 .314 .437 .423
+    the same, k and q to their lanes by a one-hot product on the MXU
+      (three bfloat16 pieces a value; as run, XLA had folded the pieces
+      into one and the state was off by 2e-5), 2 / 6 / 10 / 30 heads .463 .323 .314 .316
+    eight sublanes at a time into two accumulators, k stashed for the
+      second pass (this body), 6 / 10 heads a step, one row           .321 .309
+    the same with the sublane tiles a ``fori_loop``                   .722 .689
+    rows a ``fori_loop`` inside a step of 8 rows, 2 / 6 / 10 heads    .325 .310 .301
+    a step that only copies its block, 10 heads x 1 row / 6 x 8       .307 .303
+  A copy is as slow as the rule: alone, the pipeline's two DMAs a step set
+  the pace, not the arithmetic — and less so inside the model's step, so
+  the rest was read there.
+  in the decode program, by scope from a trace of one step (calls 3-5; ms
+  a layer = ``gdn_step`` / 12; the parent's XLA body 0.414):
+    heads x rows a step     2x8   2x32  6x4   6x8   10x1  10x2  10x4  10x8  10x16
+    ms a layer              .249  .246  .225  .226  .224  .224  .222  .220  .222
+  Runs of 12 KB (a head pair's 384 columns) cost a tenth against 36-60 KB;
+  past that neither the block (1.5-15 MB) nor the rows a step matter, so the
+  smallest footprint that is no slower is kept: 10 heads x 4 rows, 2.9 MB a
+  block, 12 MB of fast memory in four buffers (``STEP_BYTES``,
+  ``ROWS_A_STEP``).  30 heads x 8 rows (71 MB) does not fit.  The rows of a
+  step are a loop whose body is one row's head pairs, unrolled (static lane
+  slices): 1.0 s of compile a call where a whole row's 15 pairs a grid step
+  took 3.1 s and the rows unrolled as well 1.5 s more a program; the
+  sublane tiles of a pair are a ``fori_loop`` that is unrolled when LOWERED,
+  not in Python: the same kernel, and the decode program's trace takes 2.3 s
+  of set-up where twelve copies of the body a pair took 5.6 (the parent's
+  1.3; ``setup_s`` is judged).  What the
+  kernel's operands look like decided more than its blocks: handed k and q
+  transposed, or the gates with the rows in front, XLA passed the layout up
+  to the convolution's tail and carried ``gdn_conv`` through the loop in
+  another layout — copied in and out of every step, 2.6 ms of a 21 ms step —
+  so they go in as the layer makes them and the kernel transposes k and q
+  itself (two 128 x 128 transposes a row).
+
+Off the chip the kernel runs in Pallas interpret mode (``_interpret`` of
+``ops/flash_attention.py``), so the tests run the very kernel.
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.flash_attention import _interpret
 
 #: tokens of a chunk: the paper's, ``fla``'s and ``transformers``' 64
 CHUNK = 64
@@ -51,6 +124,204 @@ def step(q, k, v, log_alpha, beta, state):
     o = alpha * seen_q + (k * q).sum(-1, keepdims=True) * delta
     state = alpha[..., None] * state + k[..., None] * delta[..., None, :]
     return o, state
+
+
+def packed(state):
+    """(..., H, d_k, d_v) -> (..., d_k, H d_v): a row's heads side by side,
+    the layout the cache keeps and ``step_layer`` updates."""
+    *lead, H, d_k, d_v = state.shape
+    return jnp.moveaxis(state, -3, -2).reshape(*lead, d_k, H * d_v)
+
+
+def unpacked(state, heads: int):
+    """``packed``'s inverse: (..., d_k, H d_v) -> (..., H, d_k, d_v)."""
+    *lead, d_k, columns = state.shape
+    return jnp.moveaxis(state.reshape(*lead, d_k, heads, columns // heads), -2, -3)
+
+
+def step_layer(q, k, v, log_alpha, beta, states, layer):
+    """``step`` on layer ``layer`` (a traced scalar) of ``states`` (L, B,
+    d_k, H d_v) float32, every layer's rows ``packed``.  Returns (o (B, H,
+    d_v) float32, ``states`` with that layer's rows updated)."""
+    B, H, d_k = k.shape
+    if implementation(B, H, d_k, v.shape[-1]) == "in_place":
+        return step_in_place(q, k, v, log_alpha, beta, states, layer)
+    state = unpacked(lax.dynamic_index_in_dim(states, layer, 0, keepdims=False), H)
+    o, state = step(q, k, v, log_alpha, beta, state)
+    return o, lax.dynamic_update_index_in_dim(states, packed(state), layer, 0)
+
+
+def _group(d_v: int) -> int:
+    """Heads whose columns side by side are whole 128-lane tiles."""
+    return 128 // math.gcd(d_v, 128)
+
+
+def implementation(rows: int, heads: int, d_k: int, d_v: int) -> str:
+    """Which body the one-token update of (rows, heads) states of (d_k, d_v)
+    traces: ``"in_place"`` — the kernel — where a row's state (d_k, heads x
+    d_v) is whole (8, 128) tiles (d_k whole sublane tiles inside the one
+    transpose a row, the heads whole groups of lane tiles) and the rows are
+    whole sublane tiles of the gates, or fewer than one; else ``"xla"``."""
+    if (d_k % 8 == 0 and d_k <= 128 and heads % _group(d_v) == 0
+            and (rows % 8 == 0 or rows < 8)):
+        return "in_place"
+    return "xla"
+
+
+#: rows of one grid step's block of the state (where the rows are whole
+#: sublane tiles), and the bytes of that block at most: in and out, two
+#: buffers each, lie in fast memory at once
+ROWS_A_STEP = 4
+STEP_BYTES = 3 << 20
+VMEM_LIMIT_BYTES = 24 << 20
+
+
+def _heads_a_step(heads: int, d_k: int, d_v: int) -> int:
+    """Heads whose columns are one grid step's block: the most whole groups
+    that divide ``heads`` and fit ``STEP_BYTES`` at ``ROWS_A_STEP`` rows."""
+    g = _group(d_v)
+    fit = [n for n in range(g, min(heads, 128) + 1, g)
+           if heads % n == 0 and ROWS_A_STEP * 4 * d_k * n * d_v <= STEP_BYTES]
+    return max(fit, default=g)
+
+
+def _kernel(layer_ref, kq_ref, gates_ref, s_ref, o_ref, out_ref, kt_ref, kb_ref, *, d_v):
+    """One grid step: ``rows`` rows' columns of ``heads`` heads.  kq (rows,
+    2, heads padded to whole sublane tiles, 128): k and q as the layer hands
+    them over, a head a sublane, d_k along the lanes; gates (4, 8 rows, C):
+    alpha, beta, v and k . q at every column of their head, a row a sublane;
+    s, out (rows, d_k, C); o (8 rows, C).  Scratch: kt (2, 128, 128), k and
+    q with d_k DOWN the sublanes (one transpose a row), kb (d_k, W) a
+    group's k at the lanes of its heads.
+
+    Inside a row, a GROUP of heads at a time (W = whole lane tiles: a head
+    pair, 384 lanes, at d_v 192), static slices all: the first pass takes
+    both contractions of the old state, eight sublanes at a time, into two
+    (8, W) accumulators; the second writes ``alpha S + k (x) delta``."""
+    del layer_ref
+    rows, d_k, C = s_ref.shape
+    # the block of gates and o holds this step's rows from ``base`` on
+    base = (pl.program_id(1) * rows) % o_ref.shape[0]
+    g = _group(d_v)
+    W = g * d_v
+    lane = lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    f32 = jnp.float32
+
+    def a_row(r, carry):
+        for i in range(2):
+            x = kq_ref[r, i]
+            x = jnp.concatenate([x, jnp.zeros((128 - x.shape[0], 128), f32)], 0)
+            kt_ref[i] = x.T
+        for p in range(C // W):
+            cols = pl.ds(p * W, W)
+
+            def at_its_lanes(i, sl):  # k or q, sublanes ``sl``, at its head's columns: (8, W)
+                heads = [
+                    jnp.broadcast_to(kt_ref[i, sl, p * g + h:p * g + h + 1], (8, 128))
+                    for h in range(g)
+                ]
+                tiles = []
+                for t in range(W // 128):  # the heads that own lanes of tile t
+                    first, last = t * 128 // d_v, (t * 128 + 127) // d_v
+                    tile = heads[first]
+                    for h in range(first + 1, last + 1):
+                        tile = jnp.where(lane < h * d_v - t * 128, tile, heads[h])
+                    tiles.append(tile)
+                return jnp.concatenate(tiles, 1)
+
+            alpha, beta, v, kq = (gates_ref[i, pl.ds(base + r, 1), cols] for i in range(4))
+
+            def contract(n, acc):  # sublanes n 8 .. of both contractions
+                sl = pl.ds(pl.multiple_of(n * 8, 8), 8)
+                S, K = s_ref[r, sl, cols], at_its_lanes(0, sl)
+                kb_ref[sl, :] = K
+                return acc[0] + S * K, acc[1] + S * at_its_lanes(1, sl)
+
+            # traced once, unrolled when lowered: static slices in the kernel
+            # (a loop the chip runs is 2.2 times slower), a twelfth of the
+            # equations in the program's trace
+            acc_k, acc_q = lax.fori_loop(
+                0, d_k // 8, contract, (jnp.zeros((8, W), f32),) * 2, unroll=True)
+            seen_k = jnp.sum(acc_k, axis=0, keepdims=True)           # S^T k
+            seen_q = jnp.sum(acc_q, axis=0, keepdims=True)           # S^T q
+            delta = beta * (v - alpha * seen_k)                      # (1, W)
+            o_ref[pl.ds(base + r, 1), cols] = alpha * seen_q + kq * delta
+            alpha8, delta8 = jnp.broadcast_to(alpha, (8, W)), jnp.broadcast_to(delta, (8, W))
+
+            def write(n, carry):
+                sl = pl.ds(pl.multiple_of(n * 8, 8), 8)
+                out_ref[r, sl, cols] = alpha8 * s_ref[r, sl, cols] + kb_ref[sl, :] * delta8
+                return carry
+
+            lax.fori_loop(0, d_k // 8, write, 0, unroll=True)
+        return carry
+
+    lax.fori_loop(0, rows, a_row, 0)
+
+
+def step_in_place(q, k, v, log_alpha, beta, states, layer):
+    """The kernel: ``step`` on layer ``layer`` () int32 of ``states`` (L, B,
+    d_k, H d_v) float32, which is the output's buffer
+    (``input_output_aliases``): the layer's blocks move, nothing else.  q,
+    k: (B, H, d_k); v: (B, H, d_v); log_alpha, beta: (B, H).  Returns (o (B,
+    H, d_v) float32, ``states``)."""
+    B, H, d_k = k.shape
+    d_v = v.shape[-1]
+    if implementation(B, H, d_k, d_v) != "in_place" or states.shape[1:] != (B, d_k, H * d_v):
+        raise ValueError(
+            f"the one-pass update wants a row's state in whole (8, 128) tiles, "
+            f"packed (layers, rows, d_k, heads x d_v): q {q.shape}, v {v.shape}, "
+            f"states {states.shape}"
+        )
+    hb = _heads_a_step(H, d_k, d_v)
+    nb, C = H // hb, hb * d_v
+    rb, gr = (ROWS_A_STEP, 8) if B % 8 == 0 else (1, B)
+    f32 = jnp.float32
+    q, k, v, beta = (x.astype(f32) for x in (q, k, v, beta))
+    alpha = jnp.exp(log_alpha.astype(f32))
+    # a row a sublane, as the layer makes ``v``: with the rows in front XLA
+    # hands that layout on to what made ``v``, and the loop's carried
+    # ``gdn_conv`` was copied in and out of every step (compile-only, PR 48)
+    gates = jnp.stack([
+        jnp.repeat(alpha, d_v, axis=-1), jnp.repeat(beta, d_v, axis=-1),
+        v.reshape(B, H * d_v), jnp.repeat((k * q).sum(-1), d_v, axis=-1),
+    ])                                                               # (4, B, H d_v)
+    # k and q as they are made too, a head a row: the kernel transposes
+    hbp = -(-hb // 8) * 8
+    kq = jnp.stack([k, q], 1).reshape(B, 2, nb, hb, d_k).swapaxes(1, 2)
+    kq = jnp.pad(kq, ((0, 0),) * 3 + ((0, hbp - hb), (0, 128 - d_k)))   # (B, nb, 2, hbp, 128)
+    # columns outermost, rows innermost: a block of gates and of o serves
+    # the ``gr / rb`` steps that follow one another
+    state = pl.BlockSpec((None, rb, d_k, C), lambda j, b, layer: (layer[0], b, 0, j))
+    o, states = pl.pallas_call(
+        functools.partial(_kernel, d_v=d_v),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb, B // rb),
+            in_specs=[
+                pl.BlockSpec((rb, None, 2, hbp, 128), lambda j, b, layer: (b, j, 0, 0, 0)),
+                pl.BlockSpec((4, gr, C), lambda j, b, layer: (0, b * rb // gr, j)),
+                state,
+            ],
+            out_specs=[pl.BlockSpec((gr, C), lambda j, b, layer: (b * rb // gr, j)), state],
+            scratch_shapes=[
+                pltpu.VMEM((2, 128, 128), f32),
+                pltpu.VMEM((d_k, _group(d_v) * d_v), f32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H * d_v), f32),
+            jax.ShapeDtypeStruct(states.shape, f32),
+        ],
+        input_output_aliases={3: 1},  # operands count the scalar-prefetch one
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=_interpret(),
+        name="gated_delta_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), kq, gates, states)
+    return o.reshape(B, H, d_v), states
 
 
 def scan(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):

@@ -438,11 +438,15 @@ class LLMEngine:
         ``gdn_tokens_scanned`` / ``gdn_tokens_padded`` prompt tokens the
         prefills' chunked rule ran and the identity positions that filled
         their last chunks, ``gdn_state_bytes_step`` bytes of recurrent state
-        the decode steps read and wrote."""
+        the decode steps read and wrote — and ``gated_delta_step``, which
+        body updates the state at the decode step's shape: ``in_place``
+        (the kernel, one pass over the layer's rows where they lie) or
+        ``xla``, as ``ops/gated_delta.py:implementation`` reads it from
+        (rows, heads, d_k, d_v)."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
-        from ray_tpu.ops import grouped_matmul, topk_mask
+        from ray_tpu.ops import gated_delta, grouped_matmul, topk_mask
 
         out = {}
         names = [k for k in self.cache
@@ -497,6 +501,12 @@ class LLMEngine:
             out.update(
                 (name, wide_total(host["gdn_counts"][i]))
                 for i, name in enumerate(GDN_COUNTS)
+            )
+            c = self.config
+            # no ``gdn_`` name: those are running totals, differenced over a window
+            out["gated_delta_step"] = gated_delta.implementation(
+                self.max_slots, c.linear_num_heads, c.linear_key_head_dim,
+                c.linear_value_head_dim,
             )
         return out
 

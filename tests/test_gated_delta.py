@@ -115,3 +115,99 @@ def test_the_step_is_the_rule_as_written():
     np.testing.assert_allclose(new, want, atol=1e-6)
     np.testing.assert_allclose(o, np.einsum("bhkv,bhk->bhv", want, q), atol=1e-6)
     assert new.dtype == jnp.float32 and o.dtype == jnp.float32
+
+
+# ---- the one-token update as ONE pass over the cache's leaf (the kernel) ----
+
+def layer_inputs(L, rows, heads, dk, dv, seed):
+    """Seeded (q, k, v, log_alpha, beta) of one token of every row and the
+    stacked states (L, rows, heads, dk, dv) of L layers."""
+    rng = np.random.default_rng(seed)
+    f = np.float32
+    q = rng.normal(size=(rows, heads, dk)).astype(f)
+    k = rng.normal(size=(rows, heads, dk)).astype(f)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=(rows, heads, dv)).astype(f)
+    log_alpha = -np.exp(rng.uniform(np.log(1e-3), np.log(16.0), size=(rows, heads))).astype(f)
+    beta = (2.0 / (1.0 + np.exp(-rng.normal(size=(rows, heads))))).astype(f)
+    states = rng.normal(size=(L, rows, heads, dk, dv)).astype(f)
+    return q, k, v, log_alpha, beta, states
+
+
+def low_bits(x):
+    """Share of the non-zero float32 words with a bit set below bfloat16's
+    sixteen."""
+    words = np.asarray(x, np.float32).view(np.uint32)
+    return float(((words & 0xFFFF) != 0).sum() / max(1, (words != 0).sum()))
+
+
+#: (layers, rows, heads, d_k, d_v): whole (8, 128) tiles a row — a head a
+#: tile (one layer; odd heads, several layers), a head pair three tiles
+#: (Olmo-Hybrid's 192), two heads a tile, eight heads a tile
+TILE_WHOLE = [(1, 2, 2, 8, 128), (3, 2, 3, 16, 128), (2, 3, 4, 8, 192),
+              (2, 2, 6, 24, 64), (3, 2, 8, 8, 16)]
+VALUES = ["random", "decay e^-20", "beta 0", "beta 2", "zero state", "low bits"]
+
+
+@pytest.mark.parametrize("values", VALUES)
+@pytest.mark.parametrize("shape", TILE_WHOLE, ids=lambda s: "x".join(map(str, s)))
+def test_the_kernel_is_the_step_where_the_state_lies(torch_rule, shape, values):
+    """``step_in_place`` (interpret mode here: the very kernel) on the LAST
+    layer of a stacked, packed leaf against ``step`` and ``transformers``'
+    recurrent rule on that layer's states; the other layers bit for bit
+    what they were."""
+    L, rows, heads, dk, dv = shape
+    assert gated_delta.implementation(rows, heads, dk, dv) == "in_place"
+    q, k, v, g, beta, states = layer_inputs(*shape, seed=len(values) + sum(shape))
+    layer = L - 1
+    if values == "decay e^-20":
+        g[:] = -20.0
+    elif values == "beta 0":
+        beta[:] = 0.0
+    elif values == "beta 2":
+        beta[:] = 2.0
+    elif values == "zero state":          # a free slot's, or a row just admitted
+        states[layer] = 0.0
+    elif values == "low bits":
+        assert low_bits(states) > 0.99
+    q = q / np.sqrt(dk).astype(np.float32)
+    o_step, new_step = gated_delta.step(q, k, v, g, beta, states[layer])
+    o, leaf = gated_delta.step_in_place(
+        q, k, v, g, beta, gated_delta.packed(jnp.asarray(states)), jnp.int32(layer))
+    assert o.dtype == leaf.dtype == jnp.float32 and leaf.shape == (L, rows, dk, heads * dv)
+    after = np.asarray(gated_delta.unpacked(leaf, heads))
+    np.testing.assert_array_equal(after[:layer], states[:layer])      # nothing else moved
+    np.testing.assert_allclose(o, o_step, atol=2e-6)
+    np.testing.assert_allclose(after[layer], new_step, atol=2e-6)
+    o_t, new_t = torch_rule(*(x[:, None] for x in (q * np.sqrt(dk), k, v, g, beta)), states[layer])
+    np.testing.assert_allclose(o, o_t[:, 0], atol=2e-5)
+    np.testing.assert_allclose(after[layer], new_t, atol=2e-5)
+    alpha = np.asarray(jnp.exp(g))[..., None, None]
+    if values == "beta 0":                # nothing written: the decayed state to the bit
+        np.testing.assert_array_equal(after[layer], alpha * states[layer])
+    if values == "zero state":            # k (x) beta v, to the bit
+        np.testing.assert_array_equal(
+            after[layer], k[..., None] * (beta[..., None] * v)[..., None, :])
+    if values in ("low bits", "random", "beta 2"):
+        assert low_bits(after[layer]) > 0.99          # float32 kept, not bfloat16's sixteen
+
+
+@pytest.mark.parametrize("rows, heads, dk, dv, body", [
+    (32, 30, 96, 192, "in_place"),        # Olmo-Hybrid's, the cell's
+    (4, 4, 8, 16, "xla"),                 # tier-1's toy: four heads are half a lane tile
+    (2, 3, 8, 192, "xla"),                # an odd head of 192 ends inside a tile
+    (2, 2, 12, 128, "xla"),               # d_k no whole sublane tile
+    (2, 2, 136, 128, "xla"),              # d_k beyond the one transpose a row
+])
+def test_the_body_is_chosen_from_the_shape(rows, heads, dk, dv, body):
+    assert gated_delta.implementation(rows, heads, dk, dv) == body
+    if body == "xla":                     # ``step_layer`` still runs it, packed
+        q, k, v, g, beta, states = layer_inputs(2, rows, heads, dk, dv, seed=1)
+        o, leaf = gated_delta.step_layer(
+            q, k, v, g, beta, gated_delta.packed(jnp.asarray(states)), jnp.int32(1))
+        o_step, new_step = gated_delta.step(q, k, v, g, beta, states[1])
+        np.testing.assert_allclose(o, o_step, atol=2e-6)
+        after = np.asarray(gated_delta.unpacked(leaf, heads))
+        np.testing.assert_allclose(after[1], new_step, atol=2e-6)
+        np.testing.assert_array_equal(after[0], states[0])

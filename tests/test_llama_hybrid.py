@@ -9,6 +9,7 @@ import dataclasses
 import functools
 import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +101,7 @@ def test_the_cache_holds_each_kind_of_state_for_its_own_layers(model, served):
     assert cfg.period == (LINEAR, LINEAR, LINEAR, FULL)
     assert set(cache) == {"k", "v", "gdn_state", "gdn_conv", "gdn_counts"}
     assert cache["k"].shape == cache["v"].shape == (2, SLOTS, MAX_LEN, 4 * 16)
-    assert cache["gdn_state"].shape == (6, SLOTS, 4, 8, 16)
+    assert cache["gdn_state"].shape == (6, SLOTS, 8, 4 * 16)   # gated_delta.packed
     assert cache["gdn_state"].dtype == jnp.float32
     assert cache["gdn_conv"].shape == (6, 3, SLOTS, 4 * (2 * 8 + 16))
     # what the calls did, from their shapes: 4 prefills, 24 steps of 4 rows
@@ -252,9 +253,10 @@ def test_the_decode_step_updates_both_kinds_of_state_where_they_lie(
     program's temporaries stay far under one layer's slab — no state is
     copied on its way through the loop, no weight sliced out a period at a
     time (1.4 GB of temporaries when the loop scanned over periods' slices)."""
-    from ray_tpu.ops import kv_decode_attention
+    from ray_tpu.ops import gated_delta, kv_decode_attention
 
     monkeypatch.setattr(kv_decode_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(gated_delta, "_interpret", lambda: False)
     cfg = LlamaConfig.olmo_hybrid_7b(
         num_layers=16, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     slots, max_len = 32, 2560
@@ -279,3 +281,17 @@ def test_the_decode_step_updates_both_kinds_of_state_where_they_lie(
     text = compiled.as_text()
     assert "tpu_custom_call" in text            # the full layers' decode attention kernel
     assert text.count(" while(") == 1           # one loop over four periods
+    # the linear layers' update is the kernel, three calls in the loop's body,
+    # each on the WHOLE leaf and giving it back (operand 3 -> output 1), under
+    # the scope the benchmark's reader finds it by
+    calls = re.findall(r"%(gated_delta_step[.\d]*) = .*?custom-call\(.*", text)
+    assert len(calls) == 3, calls
+    for line in (ln for ln in text.splitlines() if " custom-call(" in ln and "%gated_delta_step" in ln):
+        assert "f32[12,32,96,5760]" in line and "output_to_operand_aliasing={{1}: (3, {})}" in line
+        assert re.search(r'op_name="[^"]*/gdn_step/', line), line
+    from chipbench import gdn_trace
+
+    found = gdn_trace.version(text)["scopes"]["gdn_step"]
+    assert set(calls) <= set(found)
+    # and nothing slices the layer out or writes it back
+    assert not re.search(r"= f32\[12,32,96,5760\]\S* dynamic-update-slice\(", text)

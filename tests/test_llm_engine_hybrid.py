@@ -75,6 +75,11 @@ def test_stats_carry_the_recurrent_layers_counters(replica, streamed):
     assert 0 < stats["kv_keys_visible_step"] < stats["kv_keys_read_step"]
     assert set(stats["cache_bytes"]) == {"k", "v", "gdn_state", "gdn_conv", "gdn_counts"}
     assert stats["cache_bytes"]["k"] == cfg.kv_layers * SLOTS * MAX_LEN * 4 * 16 * 4
+    # a (d_k, heads x d_v) float32 matrix a row and linear layer, no lane of padding
+    assert eng.cache["gdn_state"].shape == (lin, SLOTS, 8, 4 * 16)
+    assert stats["cache_bytes"]["gdn_state"] == lin * SLOTS * state
+    # four heads of 16 are half a lane tile: the toy's update is XLA's body
+    assert stats["gated_delta_step"] == "xla"
     assert eng.slots == [None] * SLOTS and eng.steps_launched_ahead_total > 0
     assert stats["programs"]["prefill_into_slot"] >= len(set(admitted))
 
