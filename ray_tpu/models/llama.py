@@ -78,6 +78,16 @@ for the linear ones.  A prefill leaves a row the state of its own tokens
 from ZERO, whatever the row held; a decode step updates it where it lies.
 ``post_norm`` is OLMo-2's block (the norm on each sub-layer's OUTPUT), and
 ``rope_theta`` None leaves q and k unrotated.
+
+``block_form`` "shortcut" is LongCat-Flash's shortcut-connected DOUBLE
+layer (``_shortcut_block_step``): two latent attentions and two dense
+SwiGLUs in series — a layer owns TWO cache layers — and one expert layer
+(the same ``_ffn``) that reads the first sub-layer's normed residual and is
+added only at the layer's end.  Its softmax router chooses by score + a
+selection-only bias among ``num_experts`` experts and ``zero_experts``
+identity ("zero-compute") experts, whose term is weight x input and costs
+no matrix; ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` scale the two
+latents.  Cached paths only, like every latent config.
 """
 
 from __future__ import annotations
@@ -162,10 +172,12 @@ class LlamaConfig:
     # a SwiGLU of this width added for every token beside the routed
     # experts (0: none)
     shared_expert_dim: int = 0
-    # the router: "softmax" over all experts, the chosen probabilities
-    # as they are (OLMoE) | "sigmoid" scores, chosen by score + a
-    # selection-only bias (DeepSeek-V3, GLM-5); either's chosen weights
-    # divided by their sum with router_norm_topk, times router_scale
+    # the router: "softmax" probabilities over all its outputs (OLMoE) |
+    # "sigmoid" scores (DeepSeek-V3, GLM-5).  A sigmoid router, and a
+    # softmax router over zero_experts (LongCat-Flash), chooses by score +
+    # a selection-only bias (``router_bias``) and weighs by the score
+    # alone; either's chosen weights divided by their sum with
+    # router_norm_topk, times router_scale
     router_scoring: str = "softmax"
     router_norm_topk: bool = False
     router_scale: float = 1.0
@@ -204,6 +216,25 @@ class LlamaConfig:
     # the block's residual path: x + f(norm(x)) (False) | x + norm(f(x)),
     # OLMo-2's and OLMo-3's (True); the same two scales either way
     post_norm: bool = False
+    # the block's form: "serial" — one mixer, then one feed-forward, dense
+    # OR experts | "shortcut" — LongCat-Flash's shortcut-connected double
+    # layer: two (latent attention, dense SwiGLU of width mlp_dim)
+    # sub-layers in series, each attention with its own cache layer, and
+    # ONE expert layer that reads the first sub-layer's normed
+    # post-attention residual and is added only at the layer's end, across
+    # the second attention and dense SwiGLU (``_shortcut_block_step``)
+    block_form: str = "serial"
+    # identity ("zero-compute") experts behind the num_experts routed
+    # ones: the router has num_experts + zero_experts outputs, and a choice
+    # that falls on one of the last zero_experts adds weight x the expert
+    # layer's input — no matrix, whichever chip holds which experts
+    zero_experts: int = 0
+    # latent attention's LoRA scales (LongCat-Flash's keys of these names):
+    # the normed query latent times sqrt(embed_dim / q_lora_rank), the
+    # normed key-value latent times sqrt(embed_dim / kv_lora_rank) — so a
+    # head's nope key and its value carry the factor, the rotary key not
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     def __post_init__(self):
         if not self.head_dim:
@@ -223,6 +254,23 @@ class LlamaConfig:
                     "latent attention, experts, leading dense blocks, "
                     "multi-token-prediction module or block mask"
                 )
+        if self.block_form not in ("serial", "shortcut"):
+            raise ValueError(
+                f"block_form is 'serial' or 'shortcut'; got {self.block_form!r}"
+            )
+        if self.block_form == "shortcut" and (
+            not self.latent or not self.num_experts or not self.mlp_dim
+            or self.index_topk or self.first_dense_layers or self.mtp_layers
+            or self.layer_types or self.post_norm
+        ):
+            raise NotImplementedError(
+                "the shortcut-connected double layer is two latent attentions "
+                "over every visible key, two dense SwiGLUs and one expert "
+                "layer: no indexer, leading dense blocks, multi-token-"
+                "prediction module, layer_types or post_norm"
+            )
+        if self.zero_experts and not self.num_experts:
+            raise ValueError("zero_experts stand behind num_experts routed experts")
 
     @property
     def period(self) -> tuple:
@@ -252,14 +300,38 @@ class LlamaConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def mixers_per_layer(self) -> int:
+        """Attentions a layer runs, each with a cache layer of its own."""
+        return 2 if self.block_form == "shortcut" else 1
+
+    @property
     def cache_layers(self) -> int:
-        """Layers of token state in the cache: the model's and the
-        multi-token-prediction module's behind them."""
-        return self.num_layers + self.mtp_layers
+        """Layers of token state in the cache: one an attention of the
+        model's (layer l of a shortcut-connected config owns 2l and 2l +
+        1) and the multi-token-prediction module's behind them."""
+        return self.num_layers * self.mixers_per_layer + self.mtp_layers
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers with routed experts, the module's block among them."""
+        if not self.num_experts:
+            return 0
+        return self.num_layers + self.mtp_layers - self.first_dense_layers
 
     @property
     def experts_here(self) -> int:
         return self.experts_held or self.num_experts
+
+    @property
+    def router_outputs(self) -> int:
+        """The router's width: the routed experts (all of them, wherever
+        they are held) and the identity experts behind them."""
+        return self.num_experts + self.zero_experts
+
+    @property
+    def router_bias(self) -> bool:
+        """Does the router choose by score + a selection-only bias?"""
+        return self.router_scoring == "sigmoid" or self.zero_experts > 0
 
     @property
     def q_per_kv(self) -> int:
@@ -322,6 +394,38 @@ class LlamaConfig:
         defaults.update(kw)
         return LlamaConfig.tiny(**defaults)
 
+    @staticmethod
+    def longcat_flash(**kw) -> "LlamaConfig":
+        """LongCat-Flash's published language model (LongCat-Flash-Omni's
+        ``config.json``): 28 shortcut-connected double layers, latent
+        attention with both LoRA scales, a softmax router over 512 experts
+        and 256 identity experts, top 12, weights x 6."""
+        defaults = dict(
+            vocab_size=131072, max_seq_len=131072, num_layers=28, num_heads=64,
+            num_kv_heads=64, embed_dim=6144, mlp_dim=12288, rope_theta=1e7,
+            rms_eps=1e-5, q_lora_rank=1536, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            mla_scale_q_lora=True, mla_scale_kv_lora=True,
+            block_form="shortcut", num_experts=512, zero_experts=256,
+            experts_per_token=12, expert_dim=2048, router_scale=6.0,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def tiny_shortcut(**kw) -> "LlamaConfig":
+        """``longcat_flash`` at toy widths: two double layers, 8 experts
+        and 4 identity experts, top 3."""
+        defaults = dict(
+            num_kv_heads=4, mlp_dim=96, q_lora_rank=32, kv_lora_rank=24,
+            qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16,
+            mla_scale_q_lora=True, mla_scale_kv_lora=True,
+            block_form="shortcut", num_experts=8, zero_experts=4,
+            experts_per_token=3, expert_dim=32, router_scale=6.0,
+        )
+        defaults.update(kw)
+        return LlamaConfig.tiny(**defaults)
+
 
 #: the parameter stack of each mixer kind (``_stacks``)
 _STACK_OF = {LINEAR: "gdn_blocks", FULL: "blocks"}
@@ -346,6 +450,14 @@ def _stacks(config: LlamaConfig):
         ("blocks", c.num_layers - c.first_dense_layers,
          c.first_dense_layers, bool(c.num_experts)),
     ]
+
+
+_EXPERT_TENSORS = ("w_gate", "w_up", "w_down")
+#: a shortcut-connected layer's tensors that are one a SUB-LAYER, (L, 2, ..):
+#: the latent attention, the two norms, the dense SwiGLU (``wd_*``: the
+#: plain names are the expert layer's)
+_SUB_LAYER = ("attn_norm", "w_qa", "q_a_norm", "w_qb", "w_kva", "kv_a_norm",
+              "w_kb", "w_vb", "wo", "mlp_norm", "wd_gate", "wd_up", "wd_down")
 
 
 def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
@@ -389,7 +501,7 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         "w_up": ("layers", "expert", "embed", "mlp"),
         "w_down": ("layers", "expert", "mlp", "embed"),
     })
-    if c.router_scoring == "sigmoid":
+    if c.router_bias:
         expert["router_bias"] = ("layers", None)
     if c.shared_expert_dim:
         expert.update({
@@ -397,6 +509,17 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
             "ws_up": ("layers", "embed", "mlp"),
             "ws_down": ("layers", "mlp", "embed"),
         })
+    if c.block_form == "shortcut":
+        # a double layer: its two sub-layers' tensors (``_SUB_LAYER``, the
+        # dense SwiGLUs under names of their own) have the sub-layer behind
+        # the layer, the one expert layer's are as above
+        twice = {k: (v[0], None, *v[1:]) for k, v in dense.items()}
+        expert = {
+            **{k: v for k, v in expert.items() if k not in dense},
+            **{k: v for k, v in twice.items() if k not in _EXPERT_TENSORS},
+            **{"wd" + k[1:]: twice[k] for k in _EXPERT_TENSORS},
+            **{k: expert[k] for k in _EXPERT_TENSORS},
+        }
     out = {"tok_embed": ("vocab", "embed"), "final_norm": ("embed",)}
     for name, _n, _first, experts in _stacks(c):
         out[name] = expert if experts else dense
@@ -432,10 +555,13 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
     if any), else one SwiGLU of width ``mlp_dim``.  ``linear``: the mixer
     is the gated delta rule (``_gated_delta_mixer`` names its tensors;
     ``a_log`` = log U(0, 16) and ``dt_bias`` = 1 as ``fla``'s
-    ``GatedDeltaNet`` starts them)."""
+    ``GatedDeltaNet`` starts them).  A shortcut-connected stack
+    (``block_form``): the attention, the norms and a dense SwiGLU
+    (``wd_*``) twice a layer, (L, 2, ..), beside the one expert layer."""
     c = config
     dt = c.param_dtype
     L, E, H, KV, D = layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim
+    A = (L, 2) if c.block_form == "shortcut" else (L,)  # a sub-layer's lead
     X = (c.experts_here,) if experts else ()
     M = c.expert_dim if experts else c.mlp_dim
     k = jax.random.split(rng, 8)
@@ -470,14 +596,14 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
         Dn, Dr, Dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         J, Di = c.index_n_heads, c.index_head_dim
         blk = {
-            "w_qa": norm(k[1], (L, E, Q), std),
-            "q_a_norm": jnp.ones((L, Q), dt),
-            "w_qb": norm(more(1), (L, Q, H, Dn + Dr), std),
-            "w_kva": norm(k[2], (L, E, C + Dr), std),
-            "kv_a_norm": jnp.ones((L, C), dt),
-            "w_kb": norm(more(2), (L, C, H, Dn), std),
-            "w_vb": norm(k[3], (L, C, H, Dv), std),
-            "wo": norm(k[4], (L, H, Dv, E), resid_std),
+            "w_qa": norm(k[1], (*A, E, Q), std),
+            "q_a_norm": jnp.ones((*A, Q), dt),
+            "w_qb": norm(more(1), (*A, Q, H, Dn + Dr), std),
+            "w_kva": norm(k[2], (*A, E, C + Dr), std),
+            "kv_a_norm": jnp.ones((*A, C), dt),
+            "w_kb": norm(more(2), (*A, C, H, Dn), std),
+            "w_vb": norm(k[3], (*A, C, H, Dv), std),
+            "wo": norm(k[4], (*A, H, Dv, E), resid_std),
         }
         if c.index_topk:
             blk.update({
@@ -500,23 +626,34 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
                 "k_norm": jnp.ones((L, D if c.qk_norm == "head" else KV * D), dt),
             })
     blk.update({
-        "attn_norm": jnp.ones((L, E), dt),
-        "mlp_norm": jnp.ones((L, E), dt),
+        "attn_norm": jnp.ones((*A, E), dt),
+        "mlp_norm": jnp.ones((*A, E), dt),
         "w_gate": norm(k[5], (L, *X, E, M), std),
         "w_up": norm(k[6], (L, *X, E, M), std),
         "w_down": norm(k[7], (L, *X, M, E), resid_std),
     })
+    if c.block_form == "shortcut":
+        blk.update({
+            "wd_gate": norm(more(10), (*A, E, c.mlp_dim), std),
+            "wd_up": norm(more(11), (*A, E, c.mlp_dim), std),
+            "wd_down": norm(more(12), (*A, c.mlp_dim, E), resid_std),
+        })
     if experts:
         blk["w_router"] = norm(
-            jax.random.fold_in(k[5], 1), (L, E, c.num_experts), std
+            jax.random.fold_in(k[5], 1), (L, E, c.router_outputs), std
         )
-        if c.router_scoring == "sigmoid":
+        if c.router_scoring == "softmax" and c.router_bias:
+            # as the published buffer starts: softmax scores lie near 1 /
+            # router_outputs, a hundred times under a sigmoid's, and a bias
+            # drawn as below would decide every choice alone
+            blk["router_bias"] = jnp.zeros((L, c.router_outputs), dt)
+        elif c.router_bias:
             # selection-only: it moves which experts are chosen, never
             # their weights.  A hundredth of a sigmoid's range, the size
             # of the gaps between the best scores: at a tenth the bias
             # alone chose the experts and one took 5-10x the mean load
             # (my chip run, PR 30)
-            blk["router_bias"] = norm(more(6), (L, c.num_experts), 0.01)
+            blk["router_bias"] = norm(more(6), (L, c.router_outputs), 0.01)
         if c.shared_expert_dim:
             Ms = c.shared_expert_dim
             blk.update({
@@ -613,20 +750,28 @@ def _attention(q, k, v, config: LlamaConfig):
     return dense_attention(q, k, v, window=config.sliding_window)
 
 
-_EXPERT_TENSORS = ("w_gate", "w_up", "w_down")
-
-
 def _layer_params(blocks: Params, config: LlamaConfig):
     """``(xs, whole)`` for a layer loop over one stack: ``xs`` is what
     it scans over (every stacked leaf, and the layer's index in the
     stack), ``whole`` what its body adds unsliced to the layer's
     parameters: an expert stack's three expert tensors in the compute
     dtype (see ``_ffn``; cast here, once a forward and not once a
-    layer), nothing for a dense stack."""
+    layer) — and a shortcut-connected stack's sub-layer tensors, as they
+    are — nothing for a dense stack."""
     layers = jnp.arange(blocks["attn_norm"].shape[0])
     if "w_router" not in blocks:
         return (blocks, layers), {}
     whole = {k: blocks[k].astype(config.dtype) for k in _EXPERT_TENSORS}
+    if config.block_form == "shortcut":
+        # the sub-layers' tensors whole too, as (2L, ..) — sub-layer i of
+        # layer l is 2l + i — for ``_shortcut_block_step`` to take each
+        # matrix's slice where it uses it: sliced a layer at a time by the
+        # scan and then a sub-layer, every matrix outside the experts was
+        # COPIED once a call, 13.4 ms of a 27 ms decode step at
+        # LongCat-Flash's widths (my chip run, PR 49, call 2)
+        whole.update({
+            k: blocks[k].reshape(-1, *blocks[k].shape[2:]) for k in _SUB_LAYER
+        })
     rest = {k: v for k, v in blocks.items() if k not in whole}
     return (rest, layers), whole
 
@@ -665,28 +810,30 @@ def _swiglu(h, w_gate, w_up, w_down, config: LlamaConfig):
 
 def _route(x, p, config: LlamaConfig):
     """Router of the expert layer.  x: (N, E).  Returns ``(weight (N,
-    k) float32, expert (N, k) int32)`` in order of falling selection
-    score.  ``softmax``: probabilities over ALL experts in float32,
-    ``lax.top_k`` of them, the chosen probabilities as they are (OLMoE)
-    or, with ``router_norm_topk``, divided by their sum (Qwen3-MoE, SDAR).
-    ``sigmoid`` (DeepSeek-V3's ``noaux_tc`` with one group): scores
-    ``sigmoid(logits)`` in float32; the top k of score + ``router_bias``
-    are chosen, the bias is in the choice only; the weights are the
-    chosen SCORES, divided by their sum if ``router_norm_topk``, times
-    ``router_scale``."""
+    k) float32, output (N, k) int32)`` in order of falling selection
+    score, over ALL ``router_outputs`` (the identity experts are the
+    outputs from ``num_experts`` on).  Scores in float32: ``softmax``
+    probabilities over all outputs, or ``sigmoid(logits)`` (DeepSeek-V3's
+    ``noaux_tc`` with one group).  Without a bias (OLMoE, Qwen3-MoE, SDAR:
+    ``LlamaConfig.router_bias``) the top k scores are chosen; with it
+    (every sigmoid router; LongCat-Flash's softmax router) the top k of
+    score + ``router_bias`` are, and the bias is in the choice only.  The
+    weights are the chosen SCORES, divided by their sum if
+    ``router_norm_topk``, times ``router_scale``."""
     c = config
     logits = jnp.einsum(
         "ne,ex->nx", x, p["w_router"].astype(c.dtype),
         preferred_element_type=jnp.float32,
     )
-    if c.router_scoring == "softmax":
+    if not c.router_bias:
         weight, expert = lax.top_k(
             jax.nn.softmax(logits, axis=-1), c.experts_per_token
         )
-        if not c.router_norm_topk:  # OLMoE: as they are
+        if not c.router_norm_topk and c.router_scale == 1.0:  # OLMoE: as they are
             return weight, expert
     else:
-        score = jax.nn.sigmoid(logits)
+        score = (jax.nn.softmax(logits, axis=-1) if c.router_scoring == "softmax"
+                 else jax.nn.sigmoid(logits))
         _, expert = lax.top_k(
             score + p["router_bias"].astype(jnp.float32), c.experts_per_token
         )
@@ -700,8 +847,10 @@ def _ffn(h, p, config: LlamaConfig):
     """The ONE feed-forward body.  h: (B, S, E), already normed.
     Returns ``(y, routing)``: y (B, S, E) to add to the residual, and
     for an expert block ``{"rows": (experts held,) int32 rows each
-    expert computed in this call, "experts": (B, S, k) the experts each
-    token chose}`` (None for a dense block: one without ``w_router``).
+    expert computed in this call, "experts": (B, S, k) the router
+    outputs each token chose, with ``zero_experts`` also "zero": () int32
+    (token, choice) pairs that fell on identity experts}`` (None for a
+    dense block: one without ``w_router``).
 
     Dense: SwiGLU, ``w_down(silu(w_gate h) * w_up h)``.
 
@@ -722,6 +871,14 @@ def _ffn(h, p, config: LlamaConfig):
     (its experts' terms and the shared expert), and a token whose k
     experts all live elsewhere gets the shared expert alone.
 
+    ``zero_experts``: the router's outputs from ``num_experts`` on are
+    identity experts, whose term is ``weight x h``.  Their rows sort
+    behind the groups like rows held elsewhere — the grouped matmul
+    computes nothing for them, so what a token costs hangs on how many
+    REAL experts it chose, 0 to k — and their weights' sum times h is
+    added behind the combine, for every token, on whichever chip it
+    lives (like a shared expert, it belongs to no chip's share).
+
     The three expert tensors arrive STACKED over the stack's layers,
     (L, X, ..), with ``p["layer"]`` saying which layer this is
     (``_layer_params``): a layer loop that handed the kernel one
@@ -739,7 +896,11 @@ def _ffn(h, p, config: LlamaConfig):
     with jax.named_scope("moe_route"):
         weight, expert = _route(x, p, c)
         flat = expert.reshape(-1)                      # (N*K,) row -> expert
-        if c.experts_held:
+        some = bool(c.experts_held or c.zero_experts)  # not every row has a group
+        if c.zero_experts:
+            zero = expert >= c.num_experts             # (N, K) identity choices
+            zero_weight = jnp.where(zero, weight, 0.0).sum(-1)
+        if some:
             flat = flat - c.expert_offset
             here = (flat >= 0) & (flat < X)            # (N*K,) computed here?
             flat = jnp.where(here, flat, X)            # the others sort last
@@ -763,14 +924,19 @@ def _ffn(h, p, config: LlamaConfig):
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)                      # row -> sorted row
         y = ys[back].reshape(B * S, K, E).astype(jnp.float32)
-        if c.experts_held:  # rows of no group come back undefined
+        if some:  # rows of no group come back undefined
             y = jnp.where(here.reshape(-1, K, 1), y, 0.0)
-        y = (y * weight[:, :, None]).sum(1).astype(c.dtype)
-    y = y.reshape(B, S, E)
+        y = (y * weight[:, :, None]).sum(1)
+    routing = {"rows": rows, "experts": expert.reshape(B, S, K)}
+    if c.zero_experts:
+        with jax.named_scope("moe_zero"):
+            y = y + zero_weight[:, None] * x.astype(jnp.float32)
+            routing["zero"] = zero.sum(dtype=jnp.int32)
+    y = y.astype(c.dtype).reshape(B, S, E)
     if c.shared_expert_dim:
         with jax.named_scope("moe_shared"):
             y = y + _swiglu(h, p["ws_gate"], p["ws_up"], p["ws_down"], c)
-    return y, {"rows": rows, "experts": expert.reshape(B, S, K)}
+    return y, routing
 
 
 def _norm_in(x, p, name: str, config: LlamaConfig):
@@ -1039,12 +1205,22 @@ def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float
     and mix (``v_head_dim``) over the keys a query may see — the
     ``index_topk`` best where there is an indexer, whose ``index_n_heads x
     index_head_dim`` over the whole context is added, else all of them —
-    in every layer that keeps a cache (the module's block is one)."""
+    in every layer that keeps a cache (the module's block is one; a
+    shortcut-connected layer's two attentions are two).  With
+    ``zero_experts`` a token's routed experts are COUNTED, not taken for
+    ``experts_per_token``: under an even router the share ``experts_here /
+    router_outputs`` of its k choices falls on an expert held here (8 of
+    LongCat-Flash's 12 on a real expert where all 512 are held), and N
+    holds that many experts a layer in place of all the held ones."""
     c = config
     S = seq_len or c.max_seq_len
     n = num_params(c) - c.vocab_size * c.embed_dim * (
         0 if c.tie_embeddings else 1
     )
+    if c.zero_experts:
+        chosen_here = c.experts_per_token * c.experts_here / c.router_outputs
+        n -= c.expert_layers * 3 * c.embed_dim * c.expert_dim * (
+            c.experts_here - chosen_here)
     if c.latent:
         seen = min(S, c.index_topk) if c.index_topk else S
         indexer = c.index_n_heads * c.index_head_dim * S if c.index_topk else 0
@@ -1173,7 +1349,14 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     computed, ``moe_experts_touched`` (expert layers,) experts with at
     least one row, summed over the calls, and ``moe_layer_steps``
     calls.  They count what the kernel did: every row of a decode step
-    routes, also the rows the engine treats as inactive."""
+    routes, also the rows the engine treats as inactive.  With
+    ``zero_experts`` also ``moe_zero_choices`` (expert layers,): (token,
+    choice) pairs that fell on identity experts.
+
+    A shortcut-connected config (``block_form``) keeps a latent config's
+    one kind of state, TWO cache layers a layer: its attentions' ``ckv``
+    and ``mla_keys`` rows 2l and 2l + 1, and one row a layer of the
+    experts' counters."""
     c = config
     if c.latent and not c.index_topk:
         # no indexer: one kind of state, every visible key attended to;
@@ -1211,12 +1394,14 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             "gdn_counts": jnp.zeros((len(GDN_COUNTS), 2), jnp.int32),
         })
     if c.num_experts:
-        layers = c.cache_layers - c.first_dense_layers
+        layers = c.expert_layers
         cache["moe_expert_tokens"] = jnp.zeros(
             (layers, c.experts_here), jnp.int32
         )
         cache["moe_experts_touched"] = jnp.zeros((layers,), jnp.int32)
         cache["moe_layer_steps"] = jnp.zeros((layers,), jnp.int32)
+        if c.zero_experts:
+            cache["moe_zero_choices"] = jnp.zeros((layers,), jnp.int32)
     return cache
 
 
@@ -1232,6 +1417,8 @@ def _latent_row(config: LlamaConfig) -> int:
 
 #: the cache's entries that hold tokens' state (the rest are counters)
 _STATE = ("k", "v", "ckv", "ik", "gdn_state", "gdn_conv")
+#: what a latent attention hands the layer loop, an entry a cache layer
+_PER_CACHE_LAYER = ("mla_keys", "ckv_rows", "selected")
 #: ``cache["gdn_counts"]``'s rows, each an ``_add_wide`` pair, all over
 #: (row, linear layer): one-token updates of decode steps (every row
 #: steps, free slots too); prompt tokens the prefills' chunked rule ran
@@ -1274,7 +1461,8 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
                  first: int = 0) -> Params:
     """The cache after one call: the new state and the running totals
     plus this call's ``aux`` (the layer loop's stacked outputs): an
-    expert config's (expert layers, experts held) rows per expert, a
+    expert config's (expert layers, experts held) rows per expert (and
+    (expert layers,) choices of identity experts), a
     latent config's (L, 3) keys visible, selected and read (a single-token
     step: latent rows fetched; a run: (query, key) pairs its attention
     computed scores for); without an indexer (L, 2) keys visible and rows
@@ -1284,20 +1472,24 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
     out = dict(cache, **state)
     if "expert_rows" in aux:
         rows = aux["expert_rows"]
+        # the expert layers ``aux`` covers: all, or a part — the model's,
+        # or (behind them) the multi-token-prediction module's
+        at = cache["moe_layer_steps"].shape[0] - rows.shape[0] if first else 0
+        part = slice(at, at + rows.shape[0])
         if rows.shape[0] == cache["moe_layer_steps"].shape[0]:
             out["moe_expert_tokens"] = cache["moe_expert_tokens"] + rows
             out["moe_experts_touched"] = cache["moe_experts_touched"] + (
                 rows > 0
             ).sum(-1, dtype=jnp.int32)
             out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
-        else:  # a part of the expert layers: the model's, or (behind
-            # them) the multi-token-prediction module's
+        else:
             touched = (rows > 0).sum(-1, dtype=jnp.int32)
-            at = cache["moe_layer_steps"].shape[0] - rows.shape[0] if first else 0
-            part = slice(at, at + rows.shape[0])
             out["moe_expert_tokens"] = cache["moe_expert_tokens"].at[part].add(rows)
             out["moe_experts_touched"] = cache["moe_experts_touched"].at[part].add(touched)
             out["moe_layer_steps"] = cache["moe_layer_steps"].at[part].add(1)
+        if "zero_choices" in aux:
+            out["moe_zero_choices"] = cache["moe_zero_choices"].at[part].add(
+                aux["zero_choices"])
     if "dsa_keys" in aux:
         kind = int(step)  # runs at [:, :, 0], single-token steps at [:, :, 1]
         out["dsa_keys"] = cache["dsa_keys"].at[:, :, kind].set(
@@ -1584,7 +1776,9 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
 
     Per token: ``c_q = RMSNorm(h W_qa)``; ``q = c_q W_qb`` -> H heads of
     [nope | rope], the rope part rotated; ``[c_kv | k_rope] = h W_kva``,
-    ``c_kv`` RMS-normed, ``k_rope`` rotated, ONE for all heads.  The
+    ``c_kv`` RMS-normed, ``k_rope`` rotated, ONE for all heads (with
+    ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` the normed ``c_q`` /
+    ``c_kv`` times ``sqrt(E / rank)``: LongCat-Flash's).  The
     cache row (``ckv``) is ``[c_kv | k_rope]``.  Head h's key is ``[W_kb,h
     c_kv | k_rope]`` and its value ``W_vb,h c_kv``; scores over head
     size nope + rope.  Indexer: ``q^I = c_q W_iq`` (J heads of Di),
@@ -1641,15 +1835,24 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     scale = 1.0 / math.sqrt(Dn + Dr)
     dt = c.dtype
     with jax.named_scope("mla_proj"):
+        # a LoRA scale rides its norm's scale: applied in float32, before
+        # the latent is rounded to the compute type — and for the
+        # key-value latent before it is cached, so the stored row carries
+        # it (the rotary key beside it does not)
+        q_a_norm, kv_a_norm = p["q_a_norm"], p["kv_a_norm"]
+        if c.mla_scale_q_lora:
+            q_a_norm = q_a_norm.astype(jnp.float32) * math.sqrt(c.embed_dim / c.q_lora_rank)
+        if c.mla_scale_kv_lora:
+            kv_a_norm = kv_a_norm.astype(jnp.float32) * math.sqrt(c.embed_dim / C)
         c_q = _rmsnorm(
             jnp.einsum("rse,eq->rsq", h, p["w_qa"].astype(dt)),
-            p["q_a_norm"], c.rms_eps,
+            q_a_norm, c.rms_eps,
         )
         q = jnp.einsum("rsq,qhd->rshd", c_q, p["w_qb"].astype(dt))
         q_nope = q[..., :Dn]
         q_rope = _rope_pairs(q[..., Dn:], positions, c.rope_theta)
         kv = jnp.einsum("rse,ec->rsc", h, p["w_kva"].astype(dt))
-        c_kv = _rmsnorm(kv[..., :C], p["kv_a_norm"], c.rms_eps)
+        c_kv = _rmsnorm(kv[..., :C], kv_a_norm, c.rms_eps)
         k_rope = _rope_pairs(kv[:, :, None, C:], positions, c.rope_theta)[:, :, 0]
         fill = jnp.zeros((R, Sq, _latent_row(c) - C - Dr), dt)
         new_ckv = jnp.concatenate([c_kv.astype(dt), k_rope.astype(dt), fill], axis=-1)
@@ -1910,10 +2113,64 @@ def _ffn_in_chunks(h, p, config: LlamaConfig):
     chunks = h.reshape(B, S // _FFN_CHUNK, _FFN_CHUNK, E).swapaxes(0, 1)
     y, routing = lax.map(lambda hc: _ffn(hc, p, config), chunks)
     k = routing["experts"].shape[-1]
+    experts = routing.pop("experts").swapaxes(0, 1).reshape(B, S, k)
+    # the counts (``rows``, ``zero``) add up over the chunks
     return y.swapaxes(0, 1).reshape(B, S, E), {
-        "rows": routing["rows"].sum(0),
-        "experts": routing["experts"].swapaxes(0, 1).reshape(B, S, k),
+        **{name: count.sum(0) for name, count in routing.items()},
+        "experts": experts,
     }
+
+
+def _shortcut_block_step(x, p, state, slot, positions, config: LlamaConfig,
+                         collect: bool = False):
+    """``_block_step`` of a shortcut-connected double layer (LongCat-
+    Flash's; ``N`` an RMSNorm with its own scale)::
+
+        h0 = N(x);  x = x + MLA_0(h0)
+        g0 = N(x);  m = MoE(g0)               # kept aside: the shortcut
+                    x = x + SwiGLU_0(g0)
+        h1 = N(x);  x = x + MLA_1(h1)
+        g1 = N(x);  x = x + SwiGLU_1(g1) + m  # the expert layer lands here
+
+    The two attentions are ``_latent_attention`` on sub-layer i's own
+    weights (``_SUB_LAYER``: slice ``2 p["layer"] + i`` of the whole
+    stack, ``_layer_params``) and cache layer ``2 p["cache_layer"] + i``,
+    the expert layer the one ``_ffn``; nothing between ``m``'s making and
+    its landing needs it, so a schedule (or, across chips, an exchange)
+    may overlap it with the second attention and dense SwiGLU.  aux: the
+    attentions' entries stacked (2, ..) — a cache layer each,
+    ``_cached_step`` lays them out — beside the expert layer's
+    ``expert_rows`` and ``zero_choices``."""
+    c = config
+    kept = []
+    for i in range(2):
+        at = 2 * p["layer"] + i
+        sub = {k: lax.dynamic_index_in_dim(p[k], at, 0, keepdims=False) for k in _SUB_LAYER}
+        sub["cache_layer"] = 2 * p["cache_layer"] + i
+        with jax.named_scope(f"scmoe_attn{i}"):
+            h = _rmsnorm(x, sub["attn_norm"], c.rms_eps)
+            attn, state, aux = _latent_attention(h, sub, state, slot, positions, c, collect)
+            x = x + jnp.einsum("bshd,hde->bse", attn, sub["wo"].astype(c.dtype))
+            g = _rmsnorm(x, sub["mlp_norm"], c.rms_eps)
+        kept.append(aux)
+        if i == 0:
+            with jax.named_scope("scmoe_experts"):
+                m, routing = _ffn_in_chunks(g, p, c)
+        with jax.named_scope(f"scmoe_dense{i}"):
+            x = x + _swiglu(g, sub["wd_gate"], sub["wd_up"], sub["wd_down"], c)
+    aux = {k: jnp.stack([kept[0][k], kept[1][k]]) for k in kept[0]}
+    _note_routing(aux, routing, collect)
+    return x + m, state, aux
+
+
+def _note_routing(aux: Params, routing: Params, collect: bool) -> None:
+    """An expert layer's ``routing`` (``_ffn``) into its block's ``aux``:
+    the counts ``_with_counts`` adds up, with ``collect`` the choices."""
+    aux["expert_rows"] = routing["rows"]
+    if "zero" in routing:
+        aux["zero_choices"] = routing["zero"]
+    if collect:
+        aux["experts"] = routing["experts"]
 
 
 def _block_step(x, p, state, slot, positions, config: LlamaConfig,
@@ -1928,6 +2185,8 @@ def _block_step(x, p, state, slot, positions, config: LlamaConfig,
     block and ``dsa_keys`` for a latent config (``_with_counts``), with
     ``collect`` also the choices made (``selected``, ``experts``)."""
     c = config
+    if c.block_form == "shortcut":
+        return _shortcut_block_step(x, p, state, slot, positions, c, collect)
     with jax.named_scope("decode_attn"):
         h = _norm_in(x, p, "attn_norm", c)
         if "a_log" in p:
@@ -1945,9 +2204,7 @@ def _block_step(x, p, state, slot, positions, config: LlamaConfig,
         y, routing = _ffn_in_chunks(_norm_in(x, p, "mlp_norm", c), p, c)
         y = _norm_out(y, p, "mlp_norm", c)
     if routing:
-        aux["expert_rows"] = routing["rows"]
-        if collect:
-            aux["experts"] = routing["experts"]
+        _note_routing(aux, routing, collect)
     return x + y, state, aux
 
 
@@ -1999,6 +2256,10 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
         return (xx, st), aux
 
     (x, riding), aux = _layer_loop(params, c, (x, riding), block)
+    if c.mixers_per_layer > 1:
+        # (layers, attentions a layer, ..) -> (cache layers, ..)
+        aux = {k: v.reshape(-1, *v.shape[2:]) if k in _PER_CACHE_LAYER else v
+               for k, v in aux.items()}
     state = dict(state, **riding)
     for k in ("ckv", "ik"):
         if k + "_rows" in aux:  # (L, Sq, width) -> every layer, row ``slot``

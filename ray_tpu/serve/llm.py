@@ -408,7 +408,12 @@ class LLMEngine:
         between two steps), as one flat dict.  An expert config:
         ``moe_expert_tokens`` (expert layers, experts held) rows each
         expert of each layer computed, ``moe_experts_touched_total`` and
-        ``moe_layer_steps_total`` summed over the layers; every row of a
+        ``moe_layer_steps_total`` summed over the layers,
+        ``moe_routed_pairs_total`` (token, choice) pairs the router made
+        (token rows the model was given x expert layers x experts per
+        token), ``moe_held_pairs_total`` of them on an expert held here
+        and, where the router has identity experts (``zero_experts``),
+        ``moe_zero_choices_total`` of them on those; every row of a
         decode step routes, so rows of slots the engine holds no request
         in are counted too: these count what the kernel did, not what
         clients received.  A latent-attention config: keys the indexer
@@ -431,8 +436,10 @@ class LLMEngine:
         without an indexer: ``mla_keys_visible_step`` (keys a step's
         rows could see — a row's last query's, the others see prefixes —
         over every layer and row) and ``mla_keys_read_step`` (latent rows
-        fetched for them: a row's blocks once for all its queries).  Sets the
-        gauges of both.  A config with linear-attention layers:
+        fetched for them: a row's blocks once for all its queries) — over
+        both attentions of every layer where the block is the
+        shortcut-connected double layer.  Sets the gauges of both.  A
+        config with linear-attention layers:
         ``llama.GDN_COUNTS``, all over (row, linear layer) —
         ``gdn_rows_stepped`` one-token updates of decode steps,
         ``gdn_tokens_scanned`` / ``gdn_tokens_padded`` prompt tokens the
@@ -473,7 +480,11 @@ class LLMEngine:
                 "moe_expert_tokens": tokens.tolist(),
                 "moe_experts_touched_total": touched,
                 "moe_layer_steps_total": steps,
+                "moe_routed_pairs_total": int(routed),
+                "moe_held_pairs_total": int(tokens.sum()),
             })
+            if "moe_zero_choices" in host:
+                out["moe_zero_choices_total"] = int(host["moe_zero_choices"].sum())
         if "dsa_keys" in host:
             keys = host["dsa_keys"]       # (L, visible|selected|read, run|step, 2)
             dsa = {
@@ -963,7 +974,9 @@ class LlamaDeployment:
         were traced with (``grouped_matmul``) and the routing counters
         the cache carries (``LLMEngine.cache_counters``; one device-to-
         host copy here, none in any step): ``moe_expert_tokens``,
-        ``moe_experts_touched_total``, ``moe_layer_steps_total``.  The
+        ``moe_experts_touched_total``, ``moe_layer_steps_total``,
+        ``moe_routed_pairs_total``, ``moe_held_pairs_total`` and, with
+        identity experts, ``moe_zero_choices_total``.  The
         two gauges ``llm_moe_experts_touched_mean`` and
         ``llm_moe_expert_load_max_over_mean`` are set from them there
         (this class travels to its replica by value, so it names no
@@ -1052,6 +1065,8 @@ class LlamaDeployment:
             },
             "max_slots": self.engine.max_slots,
             "max_len": self.engine.max_len,
+            # "serial" | "shortcut": which block body the two programs run
+            "block_form": self.engine.config.block_form,
             "platform": dev.platform,
             "device_kind": dev.device_kind,
             "device_count": len(devices),

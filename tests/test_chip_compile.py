@@ -192,6 +192,59 @@ def test_gated_delta_step_kernel_compiles_for_v5e(
     assert 4 * 8 * dk * gd._heads_a_step(H, dk, dv) * dv * 4 < gd.VMEM_LIMIT_BYTES
 
 
+@pytest.mark.limit(300)
+def test_shortcut_decode_step_reads_every_weight_where_it_lies(
+    v5e_chip, compiled_not_interpreted, monkeypatch
+):
+    """LongCat-Flash's decode step at the cell's shape (4 double layers, 64
+    slots x 5,120): the sub-layers' matrices are sliced out of their stacks
+    where the matmuls read them (``llama._layer_params`` hands the block the
+    whole ``(2L, ..)`` leaves) — scanned a layer at a time and then indexed a
+    sub-layer, every matrix outside the experts was COPIED once a step, 13.4
+    of 27 ms on the chip (PR 49) — the donated cache is the output's buffer,
+    and the program fits beside 13.7 GB of weights and cache."""
+    import functools
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops import grouped_matmul, latent_decode_attention
+
+    for mod in (latent_decode_attention, grouped_matmul):
+        if hasattr(mod, "_interpret"):
+            monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cfg = llama.LlamaConfig.longcat_flash(
+        num_layers=4, vocab_size=16384, experts_held=16,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, max_len = 64, 5120
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(llama.init, config=cfg),
+                                    jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(functools.partial(llama.init_cache, cfg, slots, max_len)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    compiled = llama.decode_step_rowwise.lower(params, rows, cache, rows, cfg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8 * slots * max_len * 640 * 2, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_PROGRAM_LIMIT - MARGIN, mem
+    # one dense matrix is 151 MB: no temporary holds one
+    assert mem.temp_size_in_bytes < 256 * 2**20, mem
+    # and no instruction outside a fusion's body MAKES one (a bitcast of a
+    # parameter is no copy)
+    made, inside = [], None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"^\s+(?:ROOT )?%[\w.\-]+ = bf16\[(6144,12288|12288,6144|64,128,6144)\]\S* (\w[\w\-]*)\(", line)
+        if m and inside and not inside.startswith("fused_computation") and m.group(2) not in (
+                "bitcast", "parameter", "get-tuple-element"):
+            made.append(line.strip()[:120])
+    assert not made, made
+
+
 # ---- the training cells' step programs ----------------------------------
 
 BENCH = os.path.join(
