@@ -250,22 +250,24 @@ def _block(x, p, config: GPTConfig, mask=None):
     S = x.shape[1]
     h = _layernorm(x, p["ln1_scale"], p["ln1_bias"])
     if c.attention_impl == "flash" and S % 128 == 0:
-        # Kernel-native (B, H, S, D) layout: the qkv/proj einsums emit and
-        # consume it directly, so no transposes surround the pallas call.
-        # Non-128-multiple S falls through to the dense path below — the
-        # kernel requires block-divisible sequence lengths.
-        from ray_tpu.ops.flash_attention import sharded_flash_attention_bhsd
+        # The kernels' own layout, (B, S, H x D): the projections write and
+        # read it as plain matmuls (the weights' head axes folded), so no
+        # copy surrounds the kernels and a head pair is a 128-lane column
+        # block.  Non-128-multiple S goes the dense way below — the
+        # kernels want whole tiles.
+        from ray_tpu.ops.flash_attention import sharded_flash_attention
 
+        E = x.shape[-1]
         qkv = jnp.einsum(
-            "bse,ehd->bhsd", h, p["qkv_kernel"].astype(c.dtype)
-        ) + p["qkv_bias"].astype(c.dtype)[None, :, None, :]
-        q, k, v = jnp.split(qkv, 3, axis=1)
-        q = constrain(q, ("batch", "heads", "seq", None))
-        k = constrain(k, ("batch", "heads", "seq", None))
-        v = constrain(v, ("batch", "heads", "seq", None))
-        attn = sharded_flash_attention_bhsd(q, k, v)
+            "bse,ef->bsf", h, p["qkv_kernel"].astype(c.dtype).reshape(E, -1)
+        ) + p["qkv_bias"].astype(c.dtype).reshape(-1)
+        q, k, v = (
+            constrain(t, ("batch", "seq", "heads"))
+            for t in jnp.split(qkv, 3, axis=-1)
+        )
+        attn = sharded_flash_attention(q, k, v, c.head_dim)
         x = x + jnp.einsum(
-            "bhsd,hde->bse", attn, p["proj_kernel"].astype(c.dtype)
+            "bsf,fe->bse", attn, p["proj_kernel"].astype(c.dtype).reshape(-1, E)
         ) + p["proj_bias"].astype(c.dtype)
     else:
         qkv = (

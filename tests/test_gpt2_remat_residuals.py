@@ -4,11 +4,11 @@ with `flash_attention.RESIDUAL_NAMES`.
 
 Three things are held, single device and on a 4-device `fsdp=4` mesh of
 CPU devices (kernels in interpret mode): the gradient's structure (one
-forward, one dq, one dk/dv kernel a layer body), its values (the
+forward and one backward kernel a layer body), its values (the
 policy-less `jax.checkpoint`'s bit for bit) and what is kept (the
-stacks of `o`, in rows of 128 lanes, and of `lse`, and nothing else of
-the block).  The compiled programs at the cells' sizes are in
-tests/test_chip_compile.py.
+stacks of `o` as the kernel writes it, (B, S, H x D) in whole 128-lane
+rows, and of `lse`, and nothing else of the block).  The compiled
+programs at the cells' sizes are in tests/test_chip_compile.py.
 """
 
 import math
@@ -31,7 +31,7 @@ CONFIG = gpt2.GPTConfig(
     vocab_size=256, max_seq_len=S, num_layers=L, num_heads=H,
     embed_dim=H * D, attention_impl="flash", remat=True,
 )
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+KERNELS = ("flash_fwd", "flash_bwd")
 
 
 def _loss(params, batch):
@@ -89,9 +89,7 @@ def _residuals(params, batch):
 
 def test_the_gradient_runs_each_kernel_once_a_layer(placed, without_the_policy):
     # the layer scan is rolled: one body forward, one backward
-    assert _kernel_counts(*placed) == {
-        "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1
-    }
+    assert _kernel_counts(*placed) == {"flash_fwd": 1, "flash_bwd": 1}
     without_the_policy()
     assert _kernel_counts(*placed)["flash_fwd"] == 2, (
         "the comparison is no longer with a block that recomputes its "
@@ -121,10 +119,11 @@ def test_only_o_in_rows_of_128_lanes_and_lse_are_kept(placed, without_the_policy
     before = _residuals(*placed)
     added = kept - before
     assert not before - kept
-    # a 64-wide minor dimension is padded to the tile's 128 lanes where
-    # the scan stacks it, so `o` is kept as (S * D / 128, 128)
+    # `o` as the kernels read and write it: the heads folded into the
+    # lanes, so the scan's stack has no 64-wide minor dimension to pad
+    assert (H * D) % 128 == 0
     assert added == Counter({
-        ((L, B, H, S * D // 128, 128), "bfloat16"): 1,
+        ((L, B, S, H * D), "bfloat16"): 1,
         ((L, B, H, S), "float32"): 1,
     })
     assert sum(
