@@ -16,7 +16,7 @@ import functools
 import threading
 import time
 
-from chipbench import loadgen
+from chipbench import contract, loadgen
 from ray_tpu import serve
 from ray_tpu.serve.llm import LlamaDeployment
 
@@ -326,6 +326,9 @@ def run(ctx: dict) -> dict:
             "are left out of ttft_ms")
     if not summary["ttft_ms"] or not summary["itl_ms"]:
         raise RuntimeError("no request of the window produced a token")
+    log("itl_ms: " + ", ".join(
+        f"{loadgen.share_over_median(summary['itl_ms'], k):.3f}% of the gaps over "
+        f"{k:g} x their median" for k in (loadgen.STALLED_GAP_FACTOR, 3.0)))
     # A mix may state how long half of its requests may wait for their
     # first token.  It is a guard on ``correct``, not a judged metric:
     # a median holds when a process stops for seconds, a tail does not.
@@ -355,6 +358,15 @@ def run(ctx: dict) -> dict:
         "serve_tokens_per_s": summary["tokens_per_s"],
         "itl_p95_ms": loadgen.percentile(summary["itl_ms"], 95),
     }
+    # only where the cell is judged on it: it refuses a window of under
+    # 1,000 gaps, which the other cells need not have
+    if "itl_tail_mean_ms" in contract.declared_metrics(
+            contract.load_benchmark(), cell["name"], 0):
+        end_to_end["itl_tail_mean_ms"] = loadgen.interquantile_mean(
+            summary["itl_ms"], *loadgen.TAIL_MEAN_RANGE)
+        log(f"itl_tail_mean_ms: {end_to_end['itl_tail_mean_ms']:.4f}, the mean of the "
+            f"gaps from the {loadgen.TAIL_MEAN_RANGE[0]}th percentile to under the "
+            f"{loadgen.TAIL_MEAN_RANGE[1]}th")
     serve.delete("chipbench")
     serve.shutdown()
     return {

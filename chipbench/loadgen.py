@@ -40,6 +40,54 @@ def percentile(values: Sequence[float], p: float) -> float:
     return xs[lo] + (xs[hi] - xs[lo]) * (rank - lo)
 
 
+#: ``itl_tail_mean_ms`` is the mean of the window's token gaps between
+#: these two percentiles.  The range holds both edges of the mixed mix's
+#: gaps (4.9-5.4% of them lie behind a prefill, 1.1-1.5% behind a long
+#: one: twelve chip runs of PR 52) and leaves out the hundredth that a
+#: host standing still reaches.  Its lower end is the 80th and not the
+#: 90th percentile because the ORDER of a seed's arrivals moves the mean
+#: of the 90th-99th by 2.4% (one sigma): sets of three seeds each then
+#: lay 3.8% apart, the mean of the 80th-99th 2.0% (PERF.md section 2)
+TAIL_MEAN_RANGE = (80, 99)
+#: a gap over this many times the median gap has waited for more than a
+#: decode step (``itl_stalled_gap_share``): a step plus the shortest
+#: prefill reads 2.1 x (26 against 12.5 ms)
+STALLED_GAP_FACTOR = 1.5
+#: under this many values a mean between two quantiles is refused: the
+#: top hundredth it leaves out must hold ten samples, or it is no tail
+MIN_INTERQUANTILE = 1000
+
+
+def interquantile_mean(values: Sequence[float], lo: float, hi: float) -> float:
+    """The mean of the values at or above the ``lo``-th percentile and
+    below the ``hi``-th: of the n values sorted ascending, those at
+    ranks ceil(lo/100 n) .. ceil(hi/100 n) - 1, counted from 0.
+
+    What a cell is judged on where its values fall in separate modes (a
+    token gap that a prefill cut, and one that none did): a percentile
+    reads one mode or the other by which side of it the modes' shares
+    fall, so it jumps when a share crosses it, and a share that an
+    improving program moves walks toward the jump.  A mean over a range
+    that holds the shares' edges moves by one value's weight when one
+    value changes mode."""
+    n = len(values)
+    if n < MIN_INTERQUANTILE:
+        raise ValueError(
+            f"a mean between quantiles of {n} values, under {MIN_INTERQUANTILE}")
+    if not 0 <= lo < hi <= 100:
+        raise ValueError(f"want 0 <= lo < hi <= 100, got {lo}, {hi}")
+    xs = sorted(values)[math.ceil(n * lo / 100.0):math.ceil(n * hi / 100.0)]
+    return sum(xs) / len(xs)
+
+
+def share_over_median(values: Sequence[float], factor: float) -> float:
+    """Per cent of ``values`` over ``factor`` times their median: of a
+    window's token gaps, the share that something other than a plain
+    decode step lengthened."""
+    limit = factor * percentile(values, 50)
+    return 100.0 * sum(1 for v in values if v > limit) / len(values)
+
+
 @dataclass(frozen=True)
 class Request:
     """One request of the schedule.  ``due_s`` is its offset from the

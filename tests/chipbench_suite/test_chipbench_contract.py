@@ -104,6 +104,25 @@ def test_benchmark_json_keeps_the_limits_and_names_only_files_that_exist():
         assert os.path.isfile(os.path.join(contract.ROOT, "chipbench", "jobs", job + ".py"))
 
 
+def test_the_per_layer_list_holds_one_entry_for_each_quantity_under_a_judged_metric():
+    """The list's rule since PR 52: every entry names its cells, no two
+    entries share (reader file, ``moves``) — a quantity that cells under
+    different judged metrics report has one suffixed name for each and ONE
+    reader, and no quantity has a second name — and a quarter of the 128
+    places stays free for the readers of later PRs."""
+    assert len(BENCH["per_layer"]) <= 96
+    seen = {}
+    for m in BENCH["per_layer"]:
+        assert m.get("workloads"), m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+        key = (os.path.basename(contract.reader_path(m["name"])), m["moves"])
+        assert key not in seen, (m["name"], seen.get(key))
+        seen[key] = m["name"]
+    readers = {f[:-3] for f in os.listdir(
+        os.path.join(contract.ROOT, "chipbench", "layer_metrics")) if f.endswith(".py")}
+    assert readers == {reader[:-3] for reader, _ in seen}       # no reader without an entry
+
+
 def test_bad_names_and_units_are_found():
     bad = copy.deepcopy(BENCH)
     bad["end_to_end"][0]["unit"] = "tokens per second"
@@ -122,7 +141,7 @@ def _job(workload):
         "setup_s": 30.0, "attempted": 10, "failed": 0, "correct": True,
         "end_to_end": {"train_tokens_per_s_per_chip": 25000.0,
                        "serve_tokens_per_s": 1000.0, "ttft_p95_ms": 300.0,
-                       "itl_p95_ms": 50.0},
+                       "itl_p95_ms": 50.0, "itl_tail_mean_ms": 20.0},
         "facts": {},
     }
 

@@ -90,7 +90,23 @@ def test_the_program_gets_the_published_block():
         serve_dsa.dsa_config(dict(config_file(), n_group=8))
 
 
-def test_benchmark_json_gains_the_cell_and_nothing_else_changes():
+#: the cell's seventeen per-layer quantities by the entry that holds each
+#: since PR 52 (one entry for each quantity under a judged metric): three of
+#: its own, fourteen it shares with the other cells judged on tokens/s
+MINE = ("sparse_attn_time_share.glm", "sparse_attn_hbm_roofline_share.glm",
+        "dsa_selected_share_mean.glm")
+SHARED = ("decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
+          "decode_batch_occupancy.batch", "device_idle_share.batch",
+          "compiles_in_window.batch", "step_dispatch_ms_p50.batch",
+          "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch",
+          "idle_gap_attributed_share.batch", "gmm_time_share", "gmm_hbm_roofline_share",
+          "moe_experts_touched_mean", "moe_expert_load_max_over_mean",
+          "moe_held_assignment_share")
+
+
+def test_benchmark_json_holds_the_cell_and_its_entries():
+    """My entries are there, with these cells and this reader — by name,
+    never by position or by how many cells the benchmark has."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
     cell = contract.cell(bench, CELL)
@@ -98,18 +114,20 @@ def test_benchmark_json_gains_the_cell_and_nothing_else_changes():
     entry = contract.config_entry(bench, CONFIG)
     assert entry["file"] == "chipbench/configs/" + CONFIG + ".json"
     assert sorted(entry["reduced"]) == sorted(config_file()["reduced"])
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1]["name"] == CONFIG
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1 and len(bench["workloads"]) == 7
+    assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
     glm = contract.declared_metrics(bench, CELL, 1)
-    assert len(glm) == 17 and all(name.endswith(".glm") for name in glm)
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".glm")]
-    assert bench["per_layer"][-17:] == mine
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
-        assert contract.reader_path(m["name"]) is not None
-    for m in bench["per_layer"][:-17]:
-        assert CELL not in m.get("workloads", [CELL + "?"])
+    setup = {name for name in glm if name.startswith("setup_")}
+    assert set(glm) - setup == set(MINE) | set(SHARED) and len(MINE + SHARED) == 17
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in MINE:
+        assert by_name[name]["workloads"] == [CELL]
+    for name in MINE + SHARED:
+        m = by_name[name]
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
+        stem = name.rpartition(".")[0] or name
+        assert os.path.basename(contract.reader_path(name)) == stem + ".py"
     with open(os.path.join(contract.ROOT, "chipbench", "traffic", "dsa_long_closed64.json")) as f:
         mix = json.load(f)
     assert mix["job"] == "serve_dsa" and mix["loop"] == "closed" and mix["clients"] == 64
@@ -240,12 +258,12 @@ def test_the_four_new_readers():
     assert reader("sparse_attn_hbm_roofline_share.glm")(ctx) == pytest.approx(
         100 * 805_576_704 * 2 / 819e9 / 900e-9)
     assert reader("dsa_selected_share_mean.glm")(ctx) == 30.5
-    assert reader("moe_held_assignment_share.glm")(ctx) == 6.1
+    assert reader("moe_held_assignment_share")(ctx) == 6.1
     # a program without the path (the parent commit): nothing to read, no raise
     bare = {"facts": {"max_slots": 32}, "busy_s": 1.0, "window_s": 2.0,
             "peak": {"hbm_bytes_per_s": 819e9}}
     for name in ("sparse_attn_time_share.glm", "sparse_attn_hbm_roofline_share.glm",
-                 "dsa_selected_share_mean.glm", "moe_held_assignment_share.glm"):
+                 "dsa_selected_share_mean.glm", "moe_held_assignment_share"):
         assert reader(name)(bare) is None
 
 
@@ -370,7 +388,7 @@ def test_the_cell_walks_on_the_cpu_traced():
     assert out.returncode == 0, out.stderr[-3000:]
     line = contract.validate(contract.last_line(out.stdout), CELL, 1)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
-    assert line["metrics"]["compiles_in_window.glm"]["value"] == 0
+    assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
     assert 0 < line["metrics"]["dsa_selected_share_mean.glm"]["value"] < 100
-    assert 0 < line["metrics"]["moe_held_assignment_share.glm"]["value"] < 100
+    assert 0 < line["metrics"]["moe_held_assignment_share"]["value"] < 100
     assert "reference check at 24 + 2 tokens" in out.stderr

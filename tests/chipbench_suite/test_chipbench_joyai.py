@@ -24,11 +24,19 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size", "q_lora_rank",
           "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "qk_head_dim",
           "v_head_dim", "head_dim", "num_experts_per_tok", "num_attention_heads")
+#: the nine quantities PR 32 added, by the entry that holds each since PR 52
+#: (one entry for each quantity under a judged metric): four are this cell's
+#: alone, five it shares (SDAR runs the same stateful step, LongCat the same
+#: latent attention)
 NEW_METRICS = ("spec_acceptance_rate.joy", "spec_tokens_per_row_step_mean.joy",
-               "mtp_draft_time_share.joy", "mla_attn_time_share.joy",
-               "mla_attn_hbm_roofline_share.joy", "spec_step_hbm_roofline_share.joy",
-               "spec_step_dispatch_ms_p50.joy", "spec_step_deliver_ms_p50.joy",
-               "spec_step_serve_plane_ms_p50.joy")
+               "mtp_draft_time_share.joy", "mla_attn_time_share",
+               "mla_attn_hbm_roofline_share", "spec_step_hbm_roofline_share.joy",
+               "spec_step_dispatch_ms_p50", "spec_step_deliver_ms_p50",
+               "spec_step_serve_plane_ms_p50")
+#: and the seven accepted quantities the cell reports beside them
+SHARED = ("decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
+          "device_idle_share.batch", "compiles_in_window.batch", "gmm_time_share",
+          "gmm_hbm_roofline_share", "moe_held_assignment_share")
 
 
 def config_file():
@@ -112,25 +120,32 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
     assert 0.78 < (2 * n + ckv) / 16e9 < 0.81
 
 
-def test_the_benchmark_gains_one_configuration_one_cell_and_the_joy_metrics():
+def test_the_benchmark_holds_the_configuration_the_cell_and_the_joy_metrics():
+    """My entries are there, with these cells and this reader — by name,
+    never by position from the end or by how many cells there are."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
-    assert [c["name"] for c in bench["configs"]][-1] == CONFIG
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, CONFIG, "mtp_reason_closed64", 1)
-    assert len(bench["workloads"]) == 8
+    assert [c["name"] for c in bench["configs"]].count(CONFIG) == 1
+    cell = contract.cell(bench, CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        CONFIG, "mtp_reason_closed64", 1)
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert tokens["workloads"][-1] == CELL and tokens["bound"] == 0.03
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".joy")]
-    assert len(mine) == 16 and bench["per_layer"][-16:] == mine
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
-        assert contract.reader_path(m["name"]) is not None, m["name"]
-    assert set(NEW_METRICS) <= {m["name"] for m in mine}
-    for name in ("mla_attn_hbm_roofline_share.joy", "spec_step_hbm_roofline_share.joy"):
-        assert next(m for m in mine if m["name"] == name)["unit"] == "%"
+    assert CELL in tokens["workloads"] and tokens["bound"] == 0.09
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    declared = contract.declared_metrics(bench, CELL, 1)
+    setup = {name for name in declared if name.startswith("setup_")}
+    assert set(declared) - setup == set(NEW_METRICS) | set(SHARED)
+    assert len(NEW_METRICS + SHARED) == 16
+    for name in NEW_METRICS + SHARED:
+        m = by_name[name]
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
+        stem = name.rpartition(".")[0] or name
+        assert os.path.basename(contract.reader_path(name)) == stem + ".py", name
+        if name.endswith(".joy"):
+            assert m["workloads"] == [CELL]
+    for name in ("mla_attn_hbm_roofline_share", "spec_step_hbm_roofline_share.joy"):
+        assert by_name[name]["unit"] == "%"
 
 
 def test_the_traffic_is_the_issues():
@@ -223,16 +238,16 @@ def test_the_readers_on_recorded_facts():
     assert reader("spec_acceptance_rate.joy")(ctx) == 52.4
     assert reader("spec_tokens_per_row_step_mean.joy")(ctx) == 1.52
     assert reader("mtp_draft_time_share.joy")(ctx) == pytest.approx(3.0)
-    assert reader("mla_attn_time_share.joy")(ctx) == pytest.approx(20.0)
+    assert reader("mla_attn_time_share")(ctx) == pytest.approx(20.0)
     per_step = (41 * 32 * 2000 + 41 * 32 * 2) * 1280
-    assert reader("mla_attn_hbm_roofline_share.joy")(ctx) == pytest.approx(
+    assert reader("mla_attn_hbm_roofline_share")(ctx) == pytest.approx(
         100 * per_step * 160 / 819e9 / 0.5)
     cfg = ctx["facts"]["model"]
     touched = 6.5 * (2000 * 40 + 100 * 40) / 2000
     want = mla_cost.step_bytes(cfg, touched, 41 * 32 * 2000, 41 * 32 * 2)
     got = reader("spec_step_hbm_roofline_share.joy")(ctx)
     assert got == pytest.approx(100 * want / 819e9 / 0.018) and 55 < got < 80
-    assert reader("decode_step_device_ms_p50.joy")(ctx) == pytest.approx(18.0)
+    assert reader("decode_step_device_ms_p50.batch")(ctx) == pytest.approx(18.0)
 
 
 def test_host_step_times_come_from_the_spans_alone(monkeypatch):
@@ -257,11 +272,11 @@ def test_host_step_times_come_from_the_spans_alone(monkeypatch):
         2, 0, 0.1, 1.7, 0.4, 4.0)
     monkeypatch.setattr(span_reduce, "fetch", lambda ctx: {"spans": spans, "metrics": []})
     ctx = {}
-    assert reader("spec_step_dispatch_ms_p50.joy")(ctx) == pytest.approx(1.7)
-    assert reader("spec_step_deliver_ms_p50.joy")(ctx) == pytest.approx(0.3)
-    assert reader("spec_step_serve_plane_ms_p50.joy")(ctx) == pytest.approx(4.5)
+    assert reader("spec_step_dispatch_ms_p50")(ctx) == pytest.approx(1.7)
+    assert reader("spec_step_deliver_ms_p50")(ctx) == pytest.approx(0.3)
+    assert reader("spec_step_serve_plane_ms_p50")(ctx) == pytest.approx(4.5)
     monkeypatch.setattr(span_reduce, "fetch", lambda ctx: None)
-    assert reader("spec_step_dispatch_ms_p50.joy")({}) is None
+    assert reader("spec_step_dispatch_ms_p50")({}) is None
 
 
 def test_the_readers_find_nothing_on_a_program_without_the_module(monkeypatch):
@@ -337,10 +352,10 @@ def test_the_cell_walks_on_the_cpu():
     bench = contract.load_benchmark()
     want = contract.declared_metrics(bench, CELL, 1)
     assert set(line["metrics"]) == set(want)
-    assert line["metrics"]["compiles_in_window.joy"]["value"] == 0
+    assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
     assert line["metrics"]["spec_acceptance_rate.joy"]["value"] > 50
     assert 1.0 < line["metrics"]["spec_tokens_per_row_step_mean.joy"]["value"] <= 2.0
     assert "replay_mismatches': 0" in run.stderr
     # the drafting steps record their five parts: the host's share is read
-    assert line["metrics"]["spec_step_dispatch_ms_p50.joy"]["value"] > 0
-    assert line["metrics"]["spec_step_serve_plane_ms_p50.joy"]["value"] > 0
+    assert line["metrics"]["spec_step_dispatch_ms_p50"]["value"] > 0
+    assert line["metrics"]["spec_step_serve_plane_ms_p50"]["value"] > 0

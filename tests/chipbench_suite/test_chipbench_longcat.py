@@ -4,15 +4,13 @@ new entries (that mine are there, by name and in this order), ``scmoe_cost``
 by hand, the two new readers and the scope map on hand-made planes and
 facts, the job's window arithmetic, its refusal of a program without the
 fields, the comparison that decides ``correct`` on a toy cache, and the
-cell walked on the CPU — traced in a copy of the benchmark in which the cell
-has JOINED the generic readers' entries by a data edit, which is what a
-``benchmark`` PR will do once the list has room (PERF.md section 7 (0))."""
+cell walked on the CPU — traced with the generic readers it joined when the
+list got room (PR 52: one entry for each quantity under a judged metric)."""
 
 import importlib
 import json
 import math
 import os
-import shutil
 import subprocess
 import sys
 
@@ -32,21 +30,20 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = ["num_layers", "n_routed_experts", "vocab_size"]
 #: the cell's per-layer entries, in the order they were appended
 MINE = ("scmoe_step_hbm_roofline_share.lcat", "moe_zero_choice_share.lcat")
-#: the accepted entries whose readers the job's facts feed, so that the cell
-#: can join their lists by a data edit: the reader's file, and one accepted
-#: name it goes by
-GENERIC = {
-    "decode_step_device_ms_p50": ".joy", "prefill_device_ms_p50": ".joy",
-    "compiles_in_window": ".joy", "gmm_time_share": ".joy",
-    "gmm_hbm_roofline_share": ".joy", "mla_attn_time_share": ".joy",
-    "mla_attn_hbm_roofline_share": ".joy", "moe_held_assignment_share": ".joy",
-    "moe_experts_touched_mean": ".glm", "engine_step_dispatch_ms_p50": ".glm",
-    "engine_step_deliver_ms_p50": ".glm", "serve_plane_step_ms_p50": ".glm",
-}
+#: the accepted entries the cell joined at PR 52, by a data edit alone: their
+#: readers' facts are what the job has supplied since PR 49
+GENERIC = (
+    "decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
+    "decode_batch_occupancy.batch", "device_idle_share.batch", "compiles_in_window.batch",
+    "gmm_time_share", "gmm_hbm_roofline_share", "mla_attn_time_share",
+    "mla_attn_hbm_roofline_share", "moe_held_assignment_share",
+    "moe_experts_touched_mean", "step_dispatch_ms_p50.batch",
+    "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch",
+)
 #: of those, the ones a CPU walk can read (the others need a device plane)
-ON_THE_CPU = ("compiles_in_window", "moe_held_assignment_share", "moe_experts_touched_mean",
-              "engine_step_dispatch_ms_p50", "engine_step_deliver_ms_p50",
-              "serve_plane_step_ms_p50")
+ON_THE_CPU = ("compiles_in_window.batch", "moe_held_assignment_share",
+              "moe_experts_touched_mean", "step_dispatch_ms_p50.batch",
+              "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch")
 
 
 def config_file():
@@ -138,11 +135,11 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
 
 
 def test_my_benchmark_entries_are_there_by_name_and_in_this_order():
-    """By name and by order among themselves — never by position from the
-    end: a later PR appends behind them.  The list is FULL with them."""
+    """My entries are there, with these cells and this reader: by name and by
+    order among themselves — never by position from the end: a later PR
+    appends behind them."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
-    assert len(bench["per_layer"]) == 128
     entry = contract.config_entry(bench, CONFIG)
     assert entry["file"] == f"chipbench/configs/{CONFIG}.json"
     assert entry["reduced"] == REDUCED and entry["source"] == config_file()["source"]
@@ -167,11 +164,13 @@ def test_my_benchmark_entries_are_there_by_name_and_in_this_order():
     assert bench["per_layer"][at[1]]["source"] == "program_counter"
     setup = [m for m in bench["per_layer"] if m["name"].startswith("setup_")]
     assert len(setup) == 6 and all(CELL in m["workloads"] for m in setup)
-    assert set(contract.declared_metrics(bench, CELL, 1)) == set(MINE) | {m["name"] for m in setup}
+    assert set(contract.declared_metrics(bench, CELL, 1)) == (
+        set(MINE) | set(GENERIC) | {m["name"] for m in setup})
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
-    # no other cell's entry was given this cell
-    assert [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", ())] == (
-        [m["name"] for m in setup] + list(MINE))
+    for name in GENERIC:
+        m = bench["per_layer"][names.index(name)]
+        assert m["workloads"][-1] == CELL and len(m["workloads"]) > 1   # joined, last
+        assert m["moves"] == "serve_tokens_per_s"
 
 
 def test_the_traffic_is_the_issues():
@@ -310,22 +309,26 @@ def test_the_new_readers_find_nothing_on_a_program_without_the_block():
 
 
 def test_the_generic_device_readers_read_the_jobs_facts():
-    """The accepted readers the cell will join, on a hand-made device plane
+    """The accepted readers the cell joined, on a hand-made device plane
     and the facts the job supplies under the keys they read."""
     peak = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
     ops = [(GMM, 1000, 400_000, {}), (GMM, 500_000, 400_000, {}),
            ("fusion.2 = fusion", 1_000_000, 4_000_000, {})]
     modules = [("jit_decode_step_rowwise(1)", 0, 15_000_000, {}),
                ("jit_prefill_into_slot(2)", 20_000_000, 300_000_000, {})]
+    # ``tokens_while_traced`` / ``traced_client_s``: ``serve_moe.run``'s, which the job runs
     facts = window_facts(mla_attn_device_s=0.9, mla_attn_decode_device_s=0.6,
-                         decode_executions_traced=200)
+                         decode_executions_traced=200, tokens_while_traced=48,
+                         traced_client_s=3.0)
     ctx = {"facts": facts, "busy_s": 2.5, "window_s": 3.0, "peak": peak,
            "planes": [plane(ops, modules)]}
-    got = {name: reader(name + suffix)(ctx) for name, suffix in GENERIC.items()
-           if name not in ON_THE_CPU or name == "compiles_in_window"}
+    got = {name.removesuffix(".batch"): reader(name)(ctx) for name in GENERIC
+           if name not in ON_THE_CPU or name == "compiles_in_window.batch"}
     assert got["decode_step_device_ms_p50"] == pytest.approx(15.0)
     assert got["prefill_device_ms_p50"] == pytest.approx(300.0)
     assert got["compiles_in_window"] == 0
+    assert got["decode_batch_occupancy"] == pytest.approx(75.0)   # 48 tokens of 1 step x 64 rows
+    assert got["device_idle_share"] == pytest.approx(100 * 0.5 / 3.0)
     assert got["gmm_time_share"] == pytest.approx(100 * 800e-6 / 2.5)
     assert 0 < got["gmm_hbm_roofline_share"] < 100
     assert got["mla_attn_time_share"] == pytest.approx(36.0)
@@ -467,39 +470,26 @@ def test_the_cell_walks_on_the_cpu_untraced():
 
 
 @pytest.mark.limit(170)
-def test_the_traced_walk_reads_every_reader_the_cell_will_join(tmp_path):
-    """The traced walk in a COPY of the benchmark in which the cell has been
-    appended to the twelve generic readers' lists — the data edit a
-    ``benchmark`` PR makes when the list has room — and nothing else is
-    changed: the line carries all of them, and those a CPU walk can read
-    (counters and the engine's spans) read a number from the job's facts."""
-    root = tmp_path / "bench"
-    shutil.copytree(os.path.join(contract.ROOT, "chipbench"), root / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    bench = contract.load_benchmark()
-    joined = [name + suffix for name, suffix in GENERIC.items()]
-    for m in bench["per_layer"]:
-        if m["name"] in joined:
-            m["workloads"].append(CELL)
-    with open(root / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-    env = dict(os.environ, PYTHONPATH=f"{root}{os.pathsep}{contract.ROOT}")
+def test_the_traced_walk_reads_every_reader_the_cell_joined():
+    """The traced walk: the line carries the cell's own two entries and the
+    fourteen generic ones it joined, and those a CPU walk can read (counters
+    and the engine's spans) read a number from the job's facts."""
     out = subprocess.run(
         [sys.executable, "-m", "chipbench.run", "--workload", CELL, "--seed", "3000000018",
          "--seconds", "3", "--trace", "1", "--rehearse"],
-        cwd=root, env=env, capture_output=True, text=True, timeout=160,
+        cwd=contract.ROOT, capture_output=True, text=True, timeout=160,
     )
     assert out.returncode == 0, out.stderr[-3000:]
-    line = json.loads(contract.last_line(out.stdout))
+    line = contract.validate(contract.last_line(out.stdout), CELL, 1)
     assert line["correct"] and line["failed"] == 0
-    assert set(joined) | set(MINE) <= set(line["metrics"])
+    assert set(GENERIC) | set(MINE) <= set(line["metrics"])
     silent = [ln.split("rehearsal: ")[1].split(" found")[0]
               for ln in out.stderr.splitlines() if "found nothing to read" in ln]
     for name in ON_THE_CPU:
-        assert name + GENERIC[name] not in silent, name
+        assert name not in silent, name
     assert MINE[1] not in silent
     assert 5 < line["metrics"][MINE[1]]["value"] < 70           # 8 of a toy router's 24 outputs
-    assert line["metrics"]["compiles_in_window.joy"]["value"] == 0
+    assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
     facts = json.loads(next(ln for ln in out.stderr.splitlines()
                             if ln.startswith("[chipbench] facts: ")).split("facts: ", 1)[1])
     for key in ("moe_zero_choices", "moe_zero_choice_share", "moe_routed_assignments",

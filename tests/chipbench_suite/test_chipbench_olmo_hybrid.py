@@ -26,13 +26,16 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads", "num_key_value_heads",
           "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
           "linear_value_head_dim", "linear_conv_kernel_dim", "vocab_size")
-#: the cell's per-layer entries, in the order they were appended
-MINE = ("decode_step_device_ms_p50", "prefill_device_ms_p50", "device_idle_share",
-        "compiles_in_window", "decode_batch_occupancy", "engine_step_dispatch_ms_p50",
-        "engine_step_deliver_ms_p50", "serve_plane_step_ms_p50", "gdn_step_time_share",
-        "gdn_step_hbm_roofline_share", "gdn_scan_time_share", "gdn_scan_roofline_share",
-        "step_hbm_roofline_share")
-NEW_READERS = MINE[8:]
+#: the cell's thirteen per-layer quantities by the entry that holds each since
+#: PR 52 (one entry for each quantity under a judged metric): eight accepted
+#: ones it shares with the other cells judged on tokens/s, five of its own
+SHARED = ("decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
+          "device_idle_share.batch", "compiles_in_window.batch",
+          "decode_batch_occupancy.batch", "step_dispatch_ms_p50.batch",
+          "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch")
+NEW_READERS = ("gdn_step_time_share", "gdn_step_hbm_roofline_share", "gdn_scan_time_share",
+               "gdn_scan_roofline_share", "step_hbm_roofline_share")
+MINE = tuple(name + ".olmoh" for name in NEW_READERS)
 
 
 def config_file():
@@ -127,7 +130,7 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
 
 
 def test_my_benchmark_entries_are_there_in_this_order():
-    """By name and by order among themselves — never by position from the
+    """My entries are there, with these cells and this reader: by name and by order among themselves — never by position from the
     end: a later PR appends behind them."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
@@ -144,23 +147,24 @@ def test_my_benchmark_entries_are_there_in_this_order():
     assert CELL in tokens["workloads"]
     assert tokens["workloads"].index(CELL) > tokens["workloads"].index(
         "serve_sdar_diffusion_batch")
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".olmoh")]
-    assert [m["name"] for m in mine] == [name + ".olmoh" for name in MINE]
-    at = [bench["per_layer"].index(m) for m in mine]
-    assert at == list(range(at[0], at[0] + len(mine)))          # one run, unbroken
-    assert at[0] > max(i for i, m in enumerate(bench["per_layer"])
-                       if m["name"].endswith(".sdar"))          # behind PR 36's
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
-        assert contract.reader_path(m["name"]) is not None, m["name"]
-        if "roofline" in m["name"]:
+    names = [m["name"] for m in bench["per_layer"]]
+    at = [names.index(name) for name in MINE]
+    assert at == list(range(at[0], at[0] + len(MINE)))          # one run, unbroken
+    assert at[0] > max(i for i, n in enumerate(names) if n.endswith(".sdar"))  # behind PR 36's
+    for name in MINE + SHARED:
+        m = bench["per_layer"][names.index(name)]
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
+        assert contract.reader_path(name) is not None, name
+        if name in MINE:
+            assert m["workloads"] == [CELL]
+        if "roofline" in name:
             assert m["unit"] == "%" and m["better"] == "higher"
     for name in NEW_READERS:   # readers of their own, not a suffix's
         assert contract.reader_path(name + ".olmoh").endswith(name + ".py")
     setup = [m for m in bench["per_layer"] if m["name"].startswith("setup_")]
     assert len(setup) == 6 and all(CELL in m["workloads"] for m in setup)
     assert set(contract.declared_metrics(bench, CELL, 1)) == (
-        {m["name"] for m in mine} | {m["name"] for m in setup})
+        set(MINE) | set(SHARED) | {m["name"] for m in setup})
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
 
 
@@ -297,7 +301,7 @@ def test_the_readers_on_recorded_facts():
     step = gdn_cost.step_bytes(ctx["facts"]["model"], 4 * 32 * 1800, 32)
     got = reader("step_hbm_roofline_share.olmoh")(ctx)
     assert got == pytest.approx(100 * step / 819e9 / 0.020) and 70 < got < 85
-    assert reader("decode_step_device_ms_p50.olmoh")(ctx) == pytest.approx(20.0)
+    assert reader("decode_step_device_ms_p50.batch")(ctx) == pytest.approx(20.0)
 
 
 def test_the_new_readers_find_nothing_on_a_program_without_the_layers():
@@ -433,5 +437,5 @@ def test_the_cell_walks_on_the_cpu(trace):
     assert "reference check at [16, 32, 27] + 4 steps" in out.stderr
     assert '"gdn_rows_stepped": ' in out.stderr and '"decode_steps_in_window": ' in out.stderr
     if trace:
-        assert line["metrics"]["compiles_in_window.olmoh"]["value"] == 0
+        assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
         assert line["metrics"]["step_hbm_roofline_share.olmoh"]["value"] > 0

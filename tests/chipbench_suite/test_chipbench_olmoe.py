@@ -190,11 +190,11 @@ def test_gmm_readers_on_a_hand_made_trace():
              "moe_embed": 2048, "moe_expert_dim": 1024, "moe_itemsize": 2}
     ctx = {"planes": planes, "busy_s": busy_s, "window_s": window_s, "facts": facts,
            "peak": {"hbm_bytes_per_s": 819e9}}
-    assert _reader("gmm_time_share.moe")(ctx) == pytest.approx(100 * 400 / 710)
+    assert _reader("gmm_time_share")(ctx) == pytest.approx(100 * 400 / 710)
     per_call = moe_cost.mean_gmm_call_bytes(256.0, 2048, 1024, 50.0)
-    assert _reader("gmm_hbm_roofline_share.moe")(ctx) == pytest.approx(
+    assert _reader("gmm_hbm_roofline_share")(ctx) == pytest.approx(
         100 * 2 * per_call / 819e9 / 400e-6)
-    assert _reader("moe_experts_touched_mean.moe")(ctx) == 50.0
+    assert _reader("moe_experts_touched_mean")(ctx) == 50.0
 
 
 def test_gmm_readers_find_nothing_in_a_program_without_the_expert_layer():
@@ -203,8 +203,8 @@ def test_gmm_readers_find_nothing_in_a_program_without_the_expert_layer():
     busy_s, window_s = tr.busy(planes)
     ctx = {"planes": planes, "busy_s": busy_s, "window_s": window_s, "facts": {},
            "peak": {"hbm_bytes_per_s": 819e9}}
-    for name in ("gmm_time_share.moe", "gmm_hbm_roofline_share.moe",
-                 "moe_experts_touched_mean.moe", "moe_expert_load_max_over_mean.moe"):
+    for name in ("gmm_time_share", "gmm_hbm_roofline_share",
+                 "moe_experts_touched_mean", "moe_expert_load_max_over_mean"):
         assert _reader(name)(ctx) is None, name
 
 

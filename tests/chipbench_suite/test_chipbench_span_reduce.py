@@ -17,7 +17,6 @@ from ray_tpu.util import metrics
 DATA = os.path.join(contract.ROOT, "chipbench", "testdata", "serve_spans.json")
 NEW_METRICS = {
     "step_dispatch_ms_p50": "program_span",
-    "step_sync_overhead_ms_p50": "program_span",
     "step_deliver_ms_p50": "program_span",
     "step_serve_plane_ms_p50": "program_span",
     "idle_gap_attributed_share": "program_span",
@@ -127,13 +126,19 @@ class TestReduceRun:
         assert all(math.isfinite(v) and v > 0 for v in out.values()), out
         assert out["idle_gap_attributed_share"] <= 100.0
 
-    def test_the_four_parts_add_up_to_the_gap(self, rec):
+    def test_the_hosts_three_parts_fill_most_of_the_gap(self, rec):
+        """What the host does itself between two executions (build and
+        dispatch, deliver, yield) is most of the gap between them and
+        never more; the rest is ``llm.step.sync``'s wake-up, which has
+        had no reader since the engine keeps a step in flight (PR 29:
+        the span then runs SHORTER than the device's step, and the
+        difference PR 24 reported read negative)."""
         out = span_reduce.reduce_run(rec["planes"], rec["spans"], rec["metrics"])
         parts = sum(v for k, v in out.items() if k.startswith("step_"))
         events = span_reduce.decode_events(rec["planes"])
         between = sorted((b[0] - a[1]) / 1e6 for a, b in zip(events, events[1:]))
         gap = between[len(between) // 2]
-        assert abs(parts - gap) < 0.1 * gap, (parts, gap)
+        assert 0.8 * gap < parts < gap, (parts, gap)
 
     def test_a_run_without_histograms_is_an_error(self, rec):
         with pytest.raises(span_reduce.SpanError, match="llm_queue_wait_ms"):
@@ -141,20 +146,41 @@ class TestReduceRun:
 
     @pytest.mark.parametrize("name", sorted(NEW_METRICS))
     def test_declared_with_a_reader(self, name):
-        """Each new metric is in BENCHMARK.json for the serving cells it
-        names, and its reader file reads its own key."""
+        """My entries are there, with these cells and this reader: each
+        metric is in BENCHMARK.json for PR 24's cells (and whichever
+        joined since), one entry a judged metric, and its ONE reader
+        file reads its own key."""
         bench = contract.load_benchmark()
         entries = [m for m in bench["per_layer"]
                    if m["name"].rpartition(".")[0] == name]
         want = {"serve_ilm2_chat"} if name.startswith("engine_") else {
             "serve_ilm2_batch", "serve_ilm2_chat"}
-        assert {w for m in entries for w in m["workloads"]} == want
+        assert want <= {w for m in entries for w in m["workloads"]}
+        assert len({m["moves"] for m in entries}) == len(entries)
         for m in entries:
             assert m["source"] == NEW_METRICS[name]
             path = contract.reader_path(m["name"])
             assert path and os.path.basename(path) == name + ".py"
             with open(path) as f:
                 assert f'span_reduce.value(ctx, "{name}")' in f.read()
+
+    def test_the_sync_overhead_and_the_seven_aliases_are_gone(self):
+        """``step_sync_overhead_ms_p50`` measured nothing since PR 29, and
+        the aliases repeated a reader under a second name because this
+        file pinned the first to two cells: neither is declared, has a
+        reader, or is a key of the reduction."""
+        gone = ("sync_overhead", "engine_step_dispatch_ms_p50", "engine_step_deliver_ms_p50",
+                "serve_plane_step_ms_p50", "device_idle_gap_attributed_share",
+                "admitter_queue_wait_ms_p50", "engine_first_token_ms_p50")
+        bench = contract.load_benchmark()
+        readers = os.listdir(os.path.join(contract.ROOT, "chipbench", "layer_metrics"))
+        for name in gone:
+            assert not [m["name"] for m in bench["per_layer"] if name in m["name"]], name
+            assert not [f for f in readers if name in f], name
+        with open(DATA) as f:
+            rec = json.load(f)
+        out = span_reduce.reduce_run(rec["planes"], rec["spans"], rec["metrics"])
+        assert not [k for k in out if "sync" in k]
 
 
 class TestValue:
@@ -178,7 +204,7 @@ class TestValue:
         harness cannot leave a declared metric out, so 0 stands in."""
         monkeypatch.setattr(span_reduce, "fetch", lambda ctx: None)
         ctx = self.ctx(rec, "tpu")
-        assert [span_reduce.value(ctx, k) for k in NEW_METRICS] == [0.0] * 7
+        assert [span_reduce.value(ctx, k) for k in NEW_METRICS] == [0.0] * len(NEW_METRICS)
 
     def test_fetch_sees_a_program_without_a_span_table(self, monkeypatch):
         from ray_tpu.util import state, tracing  # noqa: F401 (state reads it)

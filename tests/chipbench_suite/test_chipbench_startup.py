@@ -141,14 +141,16 @@ def test_value_reduces_once_and_prints_the_time_line(monkeypatch, capsys):
 
 
 @pytest.mark.parametrize("name", METRICS)
-def test_declared_with_a_reader_in_all_eight_cells(name):
+def test_declared_with_a_reader_in_every_cell(name):
+    """My entries are there, with every cell the benchmark has (however
+    many) and this reader."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
     m = next(m for m in bench["per_layer"] if m["name"] == name)
     assert (m["unit"], m["better"], m["source"], m["moves"]) == (
         "s", "lower", "program_span", "setup_s")
     assert m["workloads"] == [w["name"] for w in bench["workloads"]]
-    assert len(m["workloads"]) == 8
+    assert len(m["workloads"]) >= 8
     path = contract.reader_path(name)
     assert path is not None and path.endswith(f"layer_metrics/{name}.py")
     for w in m["workloads"]:
