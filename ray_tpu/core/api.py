@@ -7,7 +7,9 @@ put:2655, wait:2720, remote:3212).
 from __future__ import annotations
 
 import atexit
+import logging
 import os
+import time
 from typing import Any, List, Optional, Sequence, Union
 
 from ray_tpu.common.config import cfg
@@ -20,7 +22,10 @@ from ray_tpu.core.remote_function import RemoteFunction
 from ray_tpu.core.runtime import Runtime, get_runtime, set_runtime
 from ray_tpu.util import tracing
 
+logger = logging.getLogger(__name__)
+
 _node_group: Optional[node_mod.NodeProcessGroup] = None
+_init_ns = 0  # time.time_ns() of this driver's init()
 
 
 def is_initialized() -> bool:
@@ -51,9 +56,10 @@ def init(
     stderr write inside tasks and actors of THIS job is streamed back and
     printed here with a ``(pid=..., node=...)`` prefix.
     """
-    global _node_group
+    global _node_group, _init_ns
     if is_initialized():
         raise RayTpuError("ray_tpu.init() called twice; call shutdown() first")
+    _init_ns = time.time_ns()
 
     # the root of this process's start-up time line; recorded when the
     # driver is attached (a failed init leaves no span)
@@ -166,6 +172,8 @@ def shutdown() -> None:
     from ray_tpu.core import runtime as rt_mod
 
     if rt_mod._global_runtime is not None:
+        if rt_mod._global_runtime.mode == "driver":
+            _say_stalls(rt_mod._global_runtime)
         rt_mod._global_runtime.shutdown()
     if _node_group is not None:
         _node_group.kill()
@@ -174,6 +182,26 @@ def shutdown() -> None:
         atexit.unregister(shutdown)
     except Exception:
         pass
+
+
+def _say_stalls(rt: Runtime) -> None:
+    """ONE line for the stops of the cluster's io loops since this
+    driver's init(), where they sum to 0.1 s or more: how many, the
+    seconds, the longest with its process, cause and place.  Best
+    effort and bounded: a cluster that no longer answers says nothing."""
+    from ray_tpu.core import stall
+
+    async def ask():
+        await rt.push_telemetry()
+        return await rt.gcs.call("list_spans", {
+            "since_ns": _init_ns, "name_prefix": stall.SPANS_PREFIX}, timeout=2.0)
+
+    try:
+        said = stall.summary(stall.join(rt._run(ask(), timeout=3.0)))
+    except Exception:  # noqa: BLE001 — shutdown goes on whatever the GCS does
+        return
+    if said:
+        logger.warning("%s", said)
 
 
 def remote(*args, **kwargs):

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import asyncio
 import copy
+import itertools
 import logging
 import os
 import time
@@ -47,7 +48,7 @@ from ray_tpu.common.constants import (
 )
 from ray_tpu.common.ids import ActorID, JobID, NodeID, PlacementGroupID, WorkerID
 from ray_tpu.common.resources import ResourceSet
-from ray_tpu.core import rpc
+from ray_tpu.core import rpc, stall
 from ray_tpu.core.errors import FencedError
 from ray_tpu.core.node import WORKER_STOP_GRACE_S, stop_processes
 
@@ -128,6 +129,10 @@ class LeaseEntry:
 
 #: spans the GCS keeps for the whole cluster (newest; util.tracing)
 SPAN_TABLE_SIZE = 65536
+#: the stops of io loops (rt.stall, core/stall.py) beside them, in a ring
+#: of their own: an hour of a replica whose every prefill is a stop (two a
+#: second), ten minutes of a process at its cap of 64 a push interval
+STALL_TABLE_SIZE = 8192
 
 RUNNING_JOB = "RUNNING"
 SUCCEEDED_JOB = "SUCCEEDED"
@@ -632,6 +637,7 @@ class GcsServer:
         # records them; a table of its own so that spans never push
         # node-death and lease events out of _events
         self.spans: deque = deque(maxlen=SPAN_TABLE_SIZE)
+        self.stall_spans: deque = deque(maxlen=STALL_TABLE_SIZE)
         # submitted driver jobs (job_submission.py): sub_id -> info
         self.submitted_jobs: Dict[str, dict] = {}
         self.session_dir = session_dir
@@ -825,11 +831,14 @@ class GcsServer:
         self._health_task = asyncio.get_running_loop().create_task(
             self._health_loop()
         )
+        self._stall_task = asyncio.get_running_loop().create_task(
+            stall.witness("gcs"))  # its spans: rpc_list_spans
         logger.info("GCS listening on %s", self.server.address)
 
     async def close(self):
         if self._health_task:
             self._health_task.cancel()
+            self._stall_task.cancel()
         if self.checkpoint is not None:
             self.checkpoint.flush()
         if self.checkpoint_objects is not None:
@@ -2164,13 +2173,20 @@ class GcsServer:
     async def rpc_metrics_push(self, conn, p):
         """A process pushes its metric snapshot (ray: stats exporter →
         dashboard agent; here straight into the GCS aggregate table)."""
-        self.metrics_by_reporter[p["reporter"]] = {
-            "ts": time.time(),
-            "metrics": p["metrics"],
-        }
-        pid = p.get("pid")
-        self.spans.extend((pid, row) for row in p.get("spans", ()))
+        if "metrics" in p:  # a push of spans alone leaves the last snapshot
+            self.metrics_by_reporter[p["reporter"]] = {
+                "ts": time.time(),
+                "metrics": p["metrics"],
+            }
+        self._keep_spans(p.get("pid"), p.get("spans", ()))
         return True
+
+    def _keep_spans(self, pid, rows) -> None:
+        """``rt.stall`` rows go to a ring of their own: a replica whose
+        every prefill is a stop records a few a second for as long as it
+        serves, and must not push the start-up spans out of the table."""
+        for row in rows:
+            (self.stall_spans if row[0] == stall.SPAN else self.spans).append((pid, row))
 
     async def rpc_list_spans(self, conn, p):
         """Spans the cluster's processes pushed (util.tracing), oldest
@@ -2178,15 +2194,19 @@ class GcsServer:
         its reporters, as metrics_by_reporter does."""
         from ray_tpu.util import tracing
 
+        # this process's own (its stall witness's): it pushes to nobody
+        self._keep_spans(os.getpid(), tracing.drain())
         trace_id, prefix = p.get("trace_id"), p.get("name_prefix")
         since, until = p.get("since_ns"), p.get("until_ns")
-        return [
-            tracing.as_dict(row, pid) for pid, row in list(self.spans)
+        rows = [
+            (pid, row) for pid, row in itertools.chain(self.spans, self.stall_spans)
             if (trace_id is None or row[1] == trace_id)
             and (prefix is None or row[0].startswith(prefix))
             and (since is None or row[5] >= since)
             and (until is None or row[4] <= until)
         ]
+        rows.sort(key=lambda r: r[1][5])  # two rings: oldest first by the span's end
+        return [tracing.as_dict(row, pid) for pid, row in rows]
 
     async def rpc_get_metrics(self, conn, p):
         """Aggregated metrics: counters/histogram buckets sum across

@@ -36,7 +36,7 @@ from ray_tpu._native.store import ShmStore, default_capacity
 from ray_tpu.common import faults
 from ray_tpu.common.config import cfg
 from ray_tpu.common.ids import NodeID, WorkerID
-from ray_tpu.core import rpc
+from ray_tpu.core import rpc, stall
 from ray_tpu.core.errors import FencedError, is_fenced
 from ray_tpu.core.node import WORKER_STOP_GRACE_S, stop_processes
 from ray_tpu.util import tracing
@@ -208,6 +208,8 @@ class Raylet:
         loop = asyncio.get_running_loop()
         self._tasks.append(loop.create_task(self._heartbeat_loop()))
         self._tasks.append(loop.create_task(self._reaper_loop()))
+        self._tasks.append(loop.create_task(
+            stall.witness("raylet", self._push_spans)))
         if cfg.preempt_poll_interval_s > 0:
             self._tasks.append(loop.create_task(self._preempt_watch_loop()))
         if cfg.memory_monitor_interval_s > 0:

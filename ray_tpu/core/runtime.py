@@ -47,7 +47,7 @@ from ray_tpu.common.ids import (
     task_return_binary,
 )
 from ray_tpu.common import serialization as ser
-from ray_tpu.core import rpc
+from ray_tpu.core import rpc, stall
 from ray_tpu.core.errors import (
     ActorDiedError,
     GetTimeoutError,
@@ -589,38 +589,11 @@ class Runtime:
             await self.push_telemetry()
 
     async def _stall_watch_loop(self):
-        """One line when this process stands still: a 100 ms ticker on
-        the io loop that, woken more than 1 s late, logs the wall time
-        lost and the CPU time the process and the loop thread used
-        meanwhile.  CPU time that did not advance means the process was
-        stopped from outside (another process opening a chip, a frozen
-        host); CPU time that did means Python held the loop."""
-        from ray_tpu.util import metrics as metrics_mod
-
-        lost = metrics_mod.Counter(
-            "loop_stall_seconds_total",
-            "wall time by which the io loop's 100 ms ticker woke late, "
-            "counted from 1 s", tag_keys=("role",),
-        )
-        tick_s = 0.1
-        was = (time.monotonic_ns(), time.process_time_ns(),
-               time.thread_time_ns())
-        while not self._closed:
-            await asyncio.sleep(tick_s)
-            now = (time.monotonic_ns(), time.process_time_ns(),
-                   time.thread_time_ns())
-            late_s = (now[0] - was[0]) / 1e9 - tick_s
-            if late_s > 1.0:
-                lost.inc(late_s, {"role": self.mode})
-                logger.warning(
-                    "%s pid %d stood still: its io loop woke %.2f s late; "
-                    "meanwhile the process used %.2f s of CPU and the loop "
-                    "thread %.2f s; open span: %s",
-                    self.mode, os.getpid(), late_s,
-                    (now[1] - was[1]) / 1e9, (now[2] - was[2]) / 1e9,
-                    tracing.open_span() or "none",
-                )
-            was = now
+        """This process's stall witness (core/stall.py): a 20 ms ticker
+        on the io loop that records every wake over 20 ms late as a span
+        ``rt.stall`` with its evidence and its cause, counts it, and says
+        so in one line from 1 s."""
+        await stall.witness(self.mode, self.push_spans)
 
     async def push_telemetry(self):
         """Ship this process's util.metrics registry and the spans it
@@ -640,6 +613,19 @@ class Runtime:
             await self.gcs.notify("metrics_push", payload)
         except Exception:
             pass  # best effort: the next push carries the metrics again
+
+    async def push_spans(self):
+        """The spans finished since the last push, alone: what the stall
+        witness sends after a stop of 0.1 s, which may be every prefill
+        of a long prompt; the registry waits for the push loop."""
+        spans = tracing.drain()
+        if spans:
+            try:
+                await self.gcs.notify("metrics_push", {
+                    "reporter": self.worker_id.hex(), "spans": spans,
+                    "pid": os.getpid()})
+            except Exception:
+                pass  # best effort, as push_telemetry
 
     async def push_last_telemetry(self):
         """On a graceful exit path: what the push loop has not sent yet,

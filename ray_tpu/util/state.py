@@ -145,3 +145,21 @@ from ray_tpu.util.events import list_events  # noqa: E402,F401
 # likewise the cluster's spans: util.tracing.collect(trace_id=None,
 # since_ns=None, until_ns=None, name_prefix=None)
 from ray_tpu.util.tracing import collect as list_spans  # noqa: E402,F401
+
+
+def stalls(since_ns: Optional[int] = None,
+           until_ns: Optional[int] = None) -> List[dict]:
+    """The stops of the cluster's io loops from 20 ms (the ``rt.stall``
+    spans of every process's stall witness, core/stall.py), oldest
+    first: ``pid``, ``role``, ``host``, ``start_ns`` / ``end_ns``
+    (``time.time_ns()``), ``late_ms``, the process's own ``cause`` (gc,
+    loop_held, interpreter_held, loop_waited, not_scheduled), ``where``,
+    ``profiling``, every attribute of the span, and the cluster's
+    ``reading``: ``chip_open`` / ``host`` for a stop of not_scheduled
+    beside another process's chip opening / stop of not_scheduled on its
+    host, ``process`` for the rest; and ``outside``: no change to the
+    program made this stop (``stall.join`` decides it)."""
+    from ray_tpu.core import stall
+
+    return stall.join(list_spans(
+        since_ns=since_ns, until_ns=until_ns, name_prefix=stall.SPANS_PREFIX))

@@ -29,7 +29,9 @@ builds (``xla.trace`` / ``xla.lower`` / ``xla.compile``,
 util/compile_cache.py).  Start-up happens before any profiler session;
 these number a dozen a process and three a compiled program (about 170
 in a 7B replica's start), and none sits on a per-task, per-step or
-per-token path.
+per-token path.  ``record()`` does the same for a span whose two ends
+are already known: ``rt.stall``, one for each stop of a process's io
+loop from 20 ms (core/stall.py; at most 64 a push interval a process).
 
 The recorder: ``start_ns`` / ``end_ns`` are integer nanoseconds of
 ``time.time_ns()`` (the processes of one host share that clock); a
@@ -313,6 +315,23 @@ def startup(name: str, carrier: Optional[Dict[str, str]] = None,
     if root:
         _start = (s.trace_id, s.span_id)
     return s
+
+
+def record(name: str, start_ns: int, end_ns: int, **attrs) -> None:
+    """Record a span whose two ends someone else's clock reads already
+    gave (``time.time_ns()`` both), whatever the switch says, as
+    ``startup`` spans are: the runtime's stall witness, which knows of a
+    stop only once it is over.  It hangs under this process's start-up
+    root.  While a profiler session runs it is also a TraceAnnotation,
+    which the profiler can place only at the moment it is made: the
+    stop's end."""
+    s = Span(name, _start, attrs)
+    s.start_ns = start_ns
+    if profiling():
+        with _annotation(name, start_ns=start_ns, end_ns=end_ns,
+                         **{k: str(v) for k, v in attrs.items()}):
+            pass
+    s.finish(end_ns=end_ns)
 
 
 def as_dict(row: tuple, pid: Optional[int] = None) -> dict:

@@ -172,44 +172,6 @@ def pytest_collection_modifyitems(config, items):
         raise pytest.UsageError(
             "slow-marked tests outside test_zz_* files: " + ", ".join(bad)
         )
-    for item in items:
-        if item.nodeid.endswith(_PINNED_TO_SEVEN_CELLS):
-            item.add_marker(pytest.mark.xfail(strict=True, reason=(
-                "PR 30's test holds its own cell to be the benchmark's LAST "
-                "(workloads[-1], configs[-1], per_layer[-17:], 7 cells); PR 32 "
-                "appended the eighth, and only a `benchmark` PR may edit a "
-                "file under BENCHMARK.json's paths (PERF.md section 7, harness "
-                "edit 11): strict, so the mark goes when the test is relaxed"
-            )))
-        if item.nodeid.endswith(_PINNED_TO_LAST_SIXTEEN):
-            item.add_marker(pytest.mark.xfail(strict=True, reason=(
-                "PR 32's test holds per_layer[-16:] to be JoyAI's; PR 34 appended "
-                "the six setup_* entries after them, because the driver refuses an "
-                "entry put anywhere but at the end of its list (it refused them at "
-                "the head: 'the PR changes the per-layer metric train_step_ms_p50'), "
-                "and only a `benchmark` PR may edit a file under BENCHMARK.json's "
-                "paths (PERF.md section 7, harness edit 12): strict, as above"
-            )))
-        if _PINNED_TO_EIGHT_CELLS in item.nodeid:
-            item.add_marker(pytest.mark.xfail(strict=True, reason=(
-                "PR 34's test holds each setup_* metric's cells to be the "
-                "benchmark's eight (len(workloads) == 8); PR 36 appended the ninth "
-                "cell to the benchmark and to those lists, and only a `benchmark` "
-                "PR may edit a file under BENCHMARK.json's paths (PERF.md section 7, "
-                "harness edit 13): strict, as above"
-            )))
-
-
-_PINNED_TO_SEVEN_CELLS = (
-    "test_chipbench_glm.py::test_benchmark_json_gains_the_cell_and_nothing_else_changes"
-)
-_PINNED_TO_EIGHT_CELLS = (
-    "test_chipbench_startup.py::test_declared_with_a_reader_in_all_eight_cells["
-)
-_PINNED_TO_LAST_SIXTEEN = (
-    "test_chipbench_joyai.py::"
-    "test_the_benchmark_gains_one_configuration_one_cell_and_the_joy_metrics"
-)
 
 
 @pytest.fixture

@@ -108,10 +108,12 @@ def _run_engine_traced(**kwargs):
 def _switched(rows):
     """Without the XLA build spans (``xla.*``): they are recorded
     whatever the switch says once a process listens to JAX's compile
-    events (util/compile_cache.py), as earlier tests of a run make it."""
+    events (util/compile_cache.py), as earlier tests of a run make it.
+    So are the stall witness's ``rt.stall``, whenever a loop of this
+    process wakes late (core/stall.py)."""
     name = (lambda r: r["name"]) if rows and isinstance(rows[0], dict) else (
         lambda r: r[0])
-    return [r for r in rows if not name(r).startswith("xla.")]
+    return [r for r in rows if not name(r).startswith(("xla.", "rt.stall"))]
 
 
 def _histogram_count(name: str, tags_key: str = "[]") -> float:
@@ -321,7 +323,8 @@ class TestTracing:
         assert found, [p.name for p in data.planes]
         plane, stats = found[0]
         assert plane.startswith("/host:")
-        assert stats.get("span_id") == sp.span_id
+        # a hex id without a letter comes back from the profiler as a number
+        assert str(stats.get("span_id")) in (sp.span_id, sp.span_id.lstrip("0"))
 
     def test_engine_spans_and_histograms(self):
         ttft0 = _histogram_count("llm_engine_ttft_ms")
@@ -435,7 +438,8 @@ class TestTracing:
 
     def test_a_stalled_loop_says_so_once(self, traced_cluster, caplog):
         """The io loop held for over a second: one log line with the CPU
-        time used meanwhile and the open span, and the counter moves."""
+        time used meanwhile, the open span and the cause, the counter
+        moves under the cause's tag, and the stop is a span."""
         from ray_tpu.core.runtime import get_runtime
 
         rt = get_runtime()
@@ -451,9 +455,15 @@ class TestTracing:
                  if "stood still" in r.getMessage()]
         assert len(lines) == 1, lines
         assert f"driver pid {os.getpid()}" in lines[0]
-        lost = [m for m in metrics.registry_snapshot()
-                if m["name"] == "loop_stall_seconds_total"]
-        assert lost and lost[0]["series"]['[["role", "driver"]]'] >= 1.0
+        assert "open span: holds.the.loop; cause: loop_waited; where: loop: hold (" in lines[0]
+        (lost,) = [m for m in metrics.registry_snapshot()
+                   if m["name"] == "loop_stall_seconds_total"]
+        assert lost["series"]['[["cause", "loop_waited"], ["role", "driver"]]'] >= 1.0
+        (stop,) = [s for s in tracing.spans() if s["name"] == "rt.stall"
+                   and s["attributes"]["late_ms"] >= 1000]
+        assert stop["attributes"]["open_span"] == "holds.the.loop"
+        assert stop["attributes"]["role"] == "driver"
+        assert 1400 <= stop["duration_ms"] <= 1700
 
 
 # ---- start-up spans: recorded whatever the switch says (PR 34) --------------
@@ -637,7 +647,8 @@ class TestStartUp:
         assert not [n for n in names if n.startswith(
             ("llm.step", "llm.prefill", "llm.request", "llm.idle", "serve.stream",
              "submit", "execute"))]
-        assert all(n.startswith(("rt.start.", "serve.start.", "llm.start.", "xla."))
+        assert all(n.startswith(("rt.start.", "serve.start.", "llm.start.", "xla.",
+                                 "rt.stall"))  # the always-on ones
                    for n in names), names
 
 
