@@ -843,13 +843,38 @@ def _route(x, p, config: LlamaConfig):
     return weight * c.router_scale, expert
 
 
+#: rows of the smallest block ``_ffn`` gathers where not every (token,
+#: choice) row has a group: the N * K rows of every decode step fit one
+_HELD_BLOCK_MIN = 1024
+#: a block's rows over what a uniform router sends the experts held here:
+#: room for an uneven one (a layer's fullest expert has 1.3-1.8 times the
+#: mean, PERF.md section 5; sixteen of them together far less)
+_HELD_BLOCK_ROOM = 1.5
+
+
+def _held_block(rows: int, config: LlamaConfig) -> int:
+    """R, the rows ``_ffn`` gathers at a time where only some of its
+    ``rows`` (token, choice) rows chose an expert held here:
+    ``_HELD_BLOCK_ROOM`` times this program's share of a uniform router's
+    rows (``experts_here`` / ``router_outputs``), at least
+    ``_HELD_BLOCK_MIN``, at most all of them, in whole row tiles of the
+    grouped matmul."""
+    from ray_tpu.ops.grouped_matmul import ROW_TILE
+
+    c = config
+    want = math.ceil(_HELD_BLOCK_ROOM * rows * c.experts_here / c.router_outputs)
+    return -(-min(rows, max(_HELD_BLOCK_MIN, want)) // ROW_TILE) * ROW_TILE
+
+
 def _ffn(h, p, config: LlamaConfig):
     """The ONE feed-forward body.  h: (B, S, E), already normed.
     Returns ``(y, routing)``: y (B, S, E) to add to the residual, and
     for an expert block ``{"rows": (experts held,) int32 rows each
     expert computed in this call, "experts": (B, S, k) the router
     outputs each token chose, with ``zero_experts`` also "zero": () int32
-    (token, choice) pairs that fell on identity experts}`` (None for a
+    (token, choice) pairs that fell on identity experts, where not every
+    row has a group also "tiles": () int32 row tiles (``grouped_matmul.
+    ROW_TILE`` rows each) gathered and handed the kernel}`` (None for a
     dense block: one without ``w_router``).
 
     Dense: SwiGLU, ``w_down(silu(w_gate h) * w_up h)``.
@@ -865,19 +890,36 @@ def _ffn(h, p, config: LlamaConfig):
 
     ``experts_held``: this program holds experts [``expert_offset``,
     ``expert_offset + experts_held``) of the ``num_experts`` the router
-    routes over.  Rows that chose another expert sort behind the held
-    groups, belong to no group — the grouped matmul computes nothing
-    for them — and count as zero: y is this chip's part of the layer
-    (its experts' terms and the shared expert), and a token whose k
-    experts all live elsewhere gets the shared expert alone.
+    routes over.  Rows that chose another expert belong to no group and
+    count as zero: y is this chip's part of the layer (its experts' terms
+    and the shared expert), and a token whose k experts all live
+    elsewhere gets the shared expert alone.
 
     ``zero_experts``: the router's outputs from ``num_experts`` on are
-    identity experts, whose term is ``weight x h``.  Their rows sort
-    behind the groups like rows held elsewhere — the grouped matmul
-    computes nothing for them, so what a token costs hangs on how many
-    REAL experts it chose, 0 to k — and their weights' sum times h is
-    added behind the combine, for every token, on whichever chip it
-    lives (like a shared expert, it belongs to no chip's share).
+    identity experts, whose term is ``weight x h``.  Their rows belong
+    to no group like rows held elsewhere — what a token costs hangs on
+    how many REAL experts it chose, 0 to k — and their weights' sum
+    times h is added behind the combine, for every token, on whichever
+    chip it lives (like a shared expert, it belongs to no chip's share).
+
+    With either, only the rows of a group are moved.  What is SORTED is
+    the (B*S*k,) int32 keys alone (a held row's expert, ``experts_here``
+    for the others, which sort last): the held rows' indices come first in
+    the order, by expert.  What is GATHERED is a block of R of them at a
+    time — R a static size read from the call's shape and the config's
+    share of a uniform router's rows (``_held_block``) — R rows of the
+    input, through the three grouped matmuls at (R, ·), each weighted by
+    its router weight in float32 and SCATTER-ADDED into y (B*S, E)
+    float32 at its token: as the product of the (B*S, R) matrix of ones
+    where a row is a token's with the weighted rows, in float32 on the
+    MXU (XLA's own scatter takes the TPU 0.1-0.6 us a row, more than
+    this product at every served shape but one: PERF.md section 6, PR
+    54).  R is a block and not a capacity: a call whose held rows fill
+    more than one block runs further blocks (a loop over the call's
+    ``ceil(B*S*k / R)`` blocks whose iterations past the held rows are
+    skipped, a static trip count, so it differentiates), and every held
+    row is computed for any routing.  A call of at most R rows (every
+    decode step) is one block without the loop.
 
     The three expert tensors arrive STACKED over the stack's layers,
     (L, X, ..), with ``p["layer"]`` saying which layer this is
@@ -888,29 +930,16 @@ def _ffn(h, p, config: LlamaConfig):
     c = config
     if "w_router" not in p:
         return _swiglu(h, p["w_gate"], p["w_up"], p["w_down"], c), None
-    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 
     B, S, E = h.shape
     X, K = c.experts_here, c.experts_per_token
     x = h.reshape(B * S, E)
-    with jax.named_scope("moe_route"):
-        weight, expert = _route(x, p, c)
-        flat = expert.reshape(-1)                      # (N*K,) row -> expert
-        some = bool(c.experts_held or c.zero_experts)  # not every row has a group
-        if c.zero_experts:
-            zero = expert >= c.num_experts             # (N, K) identity choices
-            zero_weight = jnp.where(zero, weight, 0.0).sum(-1)
-        if some:
-            flat = flat - c.expert_offset
-            here = (flat >= 0) & (flat < X)            # (N*K,) computed here?
-            flat = jnp.where(here, flat, X)            # the others sort last
-            weight = jnp.where(here.reshape(-1, K), weight, 0.0)
-        order = jnp.argsort(flat, stable=True)         # sorted row -> row
-        rows = (flat[:, None] == jnp.arange(X)[None, :]).sum(
-            0, dtype=jnp.int32
-        )                                              # bincount, (X,)
-        xs = x[order // K]                             # (N*K, E) by expert
-    with jax.named_scope("moe_experts"):
+
+    def experts(xs, rows):
+        """Sorted rows xs (R, E), of which expert g owns ``rows[g]``
+        behind those of the experts before it, through this layer's X
+        matrices: (R, E), undefined past the groups."""
         L = p["w_gate"].shape[0]
         sizes = lax.dynamic_update_slice(
             jnp.zeros((L * X,), jnp.int32), rows, (p["layer"] * X,)
@@ -920,14 +949,69 @@ def _ffn(h, p, config: LlamaConfig):
         )
         gate = grouped_matmul(xs, w_gate, sizes)
         up = grouped_matmul(xs, w_up, sizes)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
-    with jax.named_scope("moe_combine"):
-        back = jnp.argsort(order)                      # row -> sorted row
-        y = ys[back].reshape(B * S, K, E).astype(jnp.float32)
-        if some:  # rows of no group come back undefined
-            y = jnp.where(here.reshape(-1, K, 1), y, 0.0)
-        y = (y * weight[:, :, None]).sum(1)
+        return grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
+
+    with jax.named_scope("moe_route"):
+        weight, expert = _route(x, p, c)
+        flat = expert.reshape(-1)                      # (N*K,) row -> expert
+        some = bool(c.experts_held or c.zero_experts)  # not every row has a group
+        if c.zero_experts:
+            zero = expert >= c.num_experts             # (N, K) identity choices
+            zero_weight = jnp.where(zero, weight, 0.0).sum(-1)
+        if some:
+            flat = flat - c.expert_offset              # computed here? or sorts last
+            flat = jnp.where((flat >= 0) & (flat < X), flat, X)
+        order = jnp.argsort(flat, stable=True)         # sorted row -> row
+        rows = (flat[:, None] == jnp.arange(X)[None, :]).sum(
+            0, dtype=jnp.int32
+        )                                              # bincount, (X,)
     routing = {"rows": rows, "experts": expert.reshape(B, S, K)}
+    if not some:
+        with jax.named_scope("moe_route"):
+            xs = x[order // K]                         # (N*K, E) by expert
+        with jax.named_scope("moe_experts"):
+            ys = experts(xs, rows)
+        with jax.named_scope("moe_combine"):
+            back = jnp.argsort(order)                  # row -> sorted row
+            y = ys[back].reshape(B * S, K, E).astype(jnp.float32)
+            y = (y * weight[:, :, None]).sum(1)
+    else:
+        R = _held_block(B * S * K, c)
+        blocks = -(-B * S * K // R)
+        with jax.named_scope("moe_route"):
+            ends = jnp.cumsum(rows)                    # (X,) a group's end
+            held = ends[-1]                            # the rows of a group
+            order = jnp.pad(order, (0, blocks * R - B * S * K))
+            weight = weight.reshape(-1)
+
+        def block(b, y):
+            """Sorted rows [b R, (b + 1) R) into y (N, E) float32."""
+            with jax.named_scope("moe_route"):
+                at = lax.dynamic_slice(order, (b * R,), (R,))
+                live = b * R + jnp.arange(R) < held
+                token = at // K
+                xs = x[token]                          # (R, E) by expert
+                # a group's rows in the block, from where it ends there
+                sizes = jnp.diff(jnp.clip(ends - b * R, 0, R), prepend=0)
+            with jax.named_scope("moe_experts"):
+                ys = experts(xs, sizes)
+            with jax.named_scope("moe_combine"):
+                # rows of no group come back undefined
+                ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
+                lands = jnp.arange(B * S)[:, None] == token[None, :]
+                return y + jnp.dot(
+                    lands.astype(jnp.float32), ys * weight[at][:, None],
+                    precision=lax.Precision.HIGHEST,
+                )
+
+        y = jnp.zeros((B * S, E), jnp.float32)
+        if blocks == 1:
+            y, ran = block(0, y), 1
+        else:
+            y = lax.fori_loop(0, blocks, lambda b, y: lax.cond(
+                b * R < held, block, lambda b, y: y, b, y), y)
+            ran = -(-held // R)
+        routing["tiles"] = jnp.int32(ran * (R // ROW_TILE))
     if c.zero_experts:
         with jax.named_scope("moe_zero"):
             y = y + zero_weight[:, None] * x.astype(jnp.float32)
@@ -1351,7 +1435,12 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     calls.  They count what the kernel did: every row of a decode step
     routes, also the rows the engine treats as inactive.  With
     ``zero_experts`` also ``moe_zero_choices`` (expert layers,): (token,
-    choice) pairs that fell on identity experts.
+    choice) pairs that fell on identity experts.  Where not every row has
+    a group (``experts_held`` or ``zero_experts``) ``moe_layer_steps`` is
+    (expert layers, 2): the calls, and beside them the row tiles
+    (``grouped_matmul.ROW_TILE`` rows each) ``_ffn`` gathered and handed
+    the kernel, blocks x R (a column and not an entry of its own: three of
+    the benchmark's tests hold the set of a cache's entries).
 
     A shortcut-connected config (``block_form``) keeps a latent config's
     one kind of state, TWO cache layers a layer: its attentions' ``ckv``
@@ -1399,7 +1488,8 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             (layers, c.experts_here), jnp.int32
         )
         cache["moe_experts_touched"] = jnp.zeros((layers,), jnp.int32)
-        cache["moe_layer_steps"] = jnp.zeros((layers,), jnp.int32)
+        some = c.experts_held or c.zero_experts
+        cache["moe_layer_steps"] = jnp.zeros((layers, 2) if some else (layers,), jnp.int32)
         if c.zero_experts:
             cache["moe_zero_choices"] = jnp.zeros((layers,), jnp.int32)
     return cache
@@ -1462,7 +1552,7 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
     """The cache after one call: the new state and the running totals
     plus this call's ``aux`` (the layer loop's stacked outputs): an
     expert config's (expert layers, experts held) rows per expert (and
-    (expert layers,) choices of identity experts), a
+    (expert layers,) choices of identity experts, row tiles gathered), a
     latent config's (L, 3) keys visible, selected and read (a single-token
     step: latent rows fetched; a run: (query, key) pairs its attention
     computed scores for); without an indexer (L, 2) keys visible and rows
@@ -1476,17 +1566,20 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
         # or (behind them) the multi-token-prediction module's
         at = cache["moe_layer_steps"].shape[0] - rows.shape[0] if first else 0
         part = slice(at, at + rows.shape[0])
+        calls = 1
+        if "row_tiles" in aux:  # (calls, row tiles gathered) a layer
+            calls = jnp.stack([jnp.ones_like(aux["row_tiles"]), aux["row_tiles"]], -1)
         if rows.shape[0] == cache["moe_layer_steps"].shape[0]:
             out["moe_expert_tokens"] = cache["moe_expert_tokens"] + rows
             out["moe_experts_touched"] = cache["moe_experts_touched"] + (
                 rows > 0
             ).sum(-1, dtype=jnp.int32)
-            out["moe_layer_steps"] = cache["moe_layer_steps"] + 1
+            out["moe_layer_steps"] = cache["moe_layer_steps"] + calls
         else:
             touched = (rows > 0).sum(-1, dtype=jnp.int32)
             out["moe_expert_tokens"] = cache["moe_expert_tokens"].at[part].add(rows)
             out["moe_experts_touched"] = cache["moe_experts_touched"].at[part].add(touched)
-            out["moe_layer_steps"] = cache["moe_layer_steps"].at[part].add(1)
+            out["moe_layer_steps"] = cache["moe_layer_steps"].at[part].add(calls)
         if "zero_choices" in aux:
             out["moe_zero_choices"] = cache["moe_zero_choices"].at[part].add(
                 aux["zero_choices"])
@@ -2099,8 +2192,13 @@ def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
 
 
 #: token rows of a run above which its feed-forward goes through the
-#: expert layer in chunks: the sorted (token, choice) rows of 8,192
-#: tokens x 8 are 805 MB a copy at 6,144 wide
+#: expert layer in chunks.  Where every expert is held, the sorted (token,
+#: choice) rows of 8,192 tokens x 8 are 805 MB a copy at 6,144 wide, 201
+#: MB a chunk.  Where only some are (``_ffn`` gathers a block of the held
+#: rows: PR 54), a chunk's largest arrays are the (2,048, E) float32 sum
+#: and a block's rows, 50 MB + 25-38 MB at 6,144 wide, and what the
+#: chunk still bounds is the landing's (tokens, block) product, which
+#: grows with the square of the run
 _FFN_CHUNK = 2048
 
 
@@ -2169,6 +2267,8 @@ def _note_routing(aux: Params, routing: Params, collect: bool) -> None:
     aux["expert_rows"] = routing["rows"]
     if "zero" in routing:
         aux["zero_choices"] = routing["zero"]
+    if "tiles" in routing:
+        aux["row_tiles"] = routing["tiles"]
     if collect:
         aux["experts"] = routing["experts"]
 

@@ -112,7 +112,7 @@ def module_step(params: Params, hidden, tokens, state: Params, slot, positions,
 def _counted(cache: Params, state: Params, aux: Params, step: bool,
              config: LlamaConfig) -> Params:
     """``llama._with_counts`` of the module's one layer."""
-    aux = {k: v[None] for k, v in aux.items() if k in ("expert_rows", "mla_keys")}
+    aux = {k: v[None] for k, v in aux.items() if k in ("expert_rows", "row_tiles", "mla_keys")}
     return llama._with_counts(cache, state, aux, step, first=config.num_layers)
 
 

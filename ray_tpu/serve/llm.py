@@ -413,7 +413,12 @@ class LLMEngine:
         (token rows the model was given x expert layers x experts per
         token), ``moe_held_pairs_total`` of them on an expert held here
         and, where the router has identity experts (``zero_experts``),
-        ``moe_zero_choices_total`` of them on those; every row of a
+        ``moe_zero_choices_total`` of them on those,
+        ``moe_rows_gathered_total`` (token, choice) rows the expert layers
+        gathered and handed the kernel: every routed pair where every
+        expert is held, else blocks x R of ``llama._ffn`` — held over
+        gathered is how full the blocks ran, gathered over routed what
+        gathering the held rows alone saved; every row of a
         decode step routes, so rows of slots the engine holds no request
         in are counted too: these count what the kernel did, not what
         clients received.  A latent-attention config: keys the indexer
@@ -465,7 +470,10 @@ class LLMEngine:
         if "moe_expert_tokens" in host:
             tokens = host["moe_expert_tokens"]
             touched = int(host["moe_experts_touched"].sum())
-            steps = int(host["moe_layer_steps"].sum())
+            # (expert layers,) calls; where not every row has a group
+            # (expert layers, 2): the calls, the row tiles gathered
+            calls = host["moe_layer_steps"].astype(np.int64)
+            steps = int(calls.sum() if calls.ndim == 1 else calls[:, 0].sum())
             if steps:
                 _MOE_TOUCHED_MEAN.set(touched / steps)
                 _MOE_LOAD_MAX_OVER_MEAN.set(float(tokens.max() / tokens.mean()))
@@ -482,6 +490,8 @@ class LLMEngine:
                 "moe_layer_steps_total": steps,
                 "moe_routed_pairs_total": int(routed),
                 "moe_held_pairs_total": int(tokens.sum()),
+                "moe_rows_gathered_total": int(routed) if calls.ndim == 1 else int(
+                    calls[:, 1].sum()) * grouped_matmul.ROW_TILE,
             })
             if "moe_zero_choices" in host:
                 out["moe_zero_choices_total"] = int(host["moe_zero_choices"].sum())
@@ -975,8 +985,9 @@ class LlamaDeployment:
         the cache carries (``LLMEngine.cache_counters``; one device-to-
         host copy here, none in any step): ``moe_expert_tokens``,
         ``moe_experts_touched_total``, ``moe_layer_steps_total``,
-        ``moe_routed_pairs_total``, ``moe_held_pairs_total`` and, with
-        identity experts, ``moe_zero_choices_total``.  The
+        ``moe_routed_pairs_total``, ``moe_held_pairs_total``,
+        ``moe_rows_gathered_total`` and, with identity experts,
+        ``moe_zero_choices_total``.  The
         two gauges ``llm_moe_experts_touched_mean`` and
         ``llm_moe_expert_load_max_over_mean`` are set from them there
         (this class travels to its replica by value, so it names no

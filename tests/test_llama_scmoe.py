@@ -116,8 +116,9 @@ def test_the_cache_has_two_layers_a_layer_and_counts_what_was_routed(model, serv
     assert cache["moe_expert_tokens"].shape == (2, 8)
     assert cache["moe_zero_choices"].shape == (2,)
     rows = 20 + 9 + 6 * SLOTS                      # every row of a step routes
-    steps = np.asarray(cache["moe_layer_steps"])
-    assert steps.tolist() == [8, 8]
+    # the calls and, beside them, the row tiles the expert layer gathered:
+    # every call here is one block of one tile
+    assert np.asarray(cache["moe_layer_steps"]).tolist() == [[8, 8], [8, 8]]
     held = np.asarray(cache["moe_expert_tokens"]).sum(-1)
     zero = np.asarray(cache["moe_zero_choices"])
     assert (held + zero).tolist() == [rows * cfg.experts_per_token] * 2
@@ -314,15 +315,20 @@ _LATENT = dict(q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=12, qk_rope_hea
 _EXPERTS = dict(num_layers=3, first_dense_layers=1, num_experts=16, experts_per_token=4,
                 expert_dim=32, shared_expert_dim=32, router_scoring="sigmoid",
                 router_norm_topk=True, router_scale=2.5, experts_held=4)
-#: sha256 of the lowered text, taken on the parent commit (17810fd) with
-#: ``tests/test_llama_hybrid.py:lowered``
+#: sha256 of the lowered text with ``tests/test_llama_hybrid.py:lowered``.
+#: ``hybrid`` (every row of its expert layers has a group): taken on PR 49's
+#: parent commit (17810fd) and unchanged since.  The six held-experts
+#: entries: taken anew on PR 54's commit, whose ``_ffn`` gathers the held
+#: rows alone — they changed by design, and that ``hybrid``'s two and every
+#: pin of ``tests/test_llama_hybrid.py`` did not is the proof that no
+#: program whose rows all have a group was touched
 PARENT = {
-    ("latent_mtp", "decode_step_rowwise"): "ad00e82c6dab8ece",
-    ("latent_mtp", "prefill_into_slot"): "ae3e21730f9472c6",
-    ("latent_indexer", "decode_step_rowwise"): "11fbca7ad63694e1",
-    ("latent_indexer", "prefill_into_slot"): "c9cb5bbe9d42a1bd",
-    ("block_mask_held", "decode_step_rowwise"): "b57ef863eab555cc",
-    ("block_mask_held", "prefill_into_slot"): "53dbf280129a59cb",
+    ("latent_mtp", "decode_step_rowwise"): "a59eaa7aec60752b",
+    ("latent_mtp", "prefill_into_slot"): "0f13e312577d2067",
+    ("latent_indexer", "decode_step_rowwise"): "9512cfd5a6627c53",
+    ("latent_indexer", "prefill_into_slot"): "8465100f620cc448",
+    ("block_mask_held", "decode_step_rowwise"): "d85b28cb0aa22498",
+    ("block_mask_held", "prefill_into_slot"): "24331be150a84ed0",
     ("hybrid", "decode_step_rowwise"): "2fd4573b8865fdc3",
     ("hybrid", "prefill_into_slot"): "9a43e8a8ca3da42d",
 }
