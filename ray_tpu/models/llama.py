@@ -79,6 +79,23 @@ from ZERO, whatever the row held; a decode step updates it where it lies.
 ``post_norm`` is OLMo-2's block (the norm on each sub-layer's OUTPUT), and
 ``rope_theta`` None leaves q and k unrotated.
 
+A window may be the WHOLE model's or a layer KIND's.  ``sliding_window`` > 0
+is Mistral's: every layer's queries see the last ``sliding_window`` keys,
+over ONE rolling cache that the engine sizes (``rolling_cache_len``).
+``SLIDING`` in ``layer_types`` is MiMo-V2-Flash's: window layers BESIDE full
+ones, each kind with its own KV heads, rotary base, window and learned sink
+(``AttentionKind``; ``LlamaConfig.sliding``), its own parameter stack
+(``swa_blocks``) and its own K/V pair in the one cache tree — ``k`` / ``v``
+at every position for the full layers, ``swa_k`` / ``swa_v`` of ``window``
+rolling slots a row for the window layers (``init_cache``).  Keys may be
+wider than values (``v_head_dim``), only the leading ``rotary_dim`` of a head
+turned, the values scaled (``value_scale``); such a model goes with experts,
+leading dense blocks and ``experts_held``, runs ``_kind_attention`` under the
+one ``_block_step`` (``ops/kv_prefill_attention.py`` for a prompt,
+``ops/kv_decode_attention.py`` for a step), prefills whole prompts and steps
+one token a row, and runs on the cached paths only: ``forward`` /
+``loss_fn`` refuse it by name.
+
 ``block_form`` "shortcut" is LongCat-Flash's shortcut-connected DOUBLE
 layer (``_shortcut_block_step``): two latent attentions and two dense
 SwiGLUs in series — a layer owns TWO cache layers — and one expert layer
@@ -92,6 +109,7 @@ latents.  Cached paths only, like every latent config.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import math
@@ -106,6 +124,7 @@ from jax import lax
 from ray_tpu.ops import (
     gated_delta,
     kv_decode_attention,
+    kv_prefill_attention,
     latent_decode_attention,
     latent_prefill_attention,
     topk_mask,
@@ -115,7 +134,21 @@ from ray_tpu.parallel.sharding import constrain
 Params = Dict[str, Any]
 
 #: a layer's mixer kind (``LlamaConfig.layer_types``; the published names)
-LINEAR, FULL = "linear_attention", "full_attention"
+LINEAR, FULL, SLIDING = "linear_attention", "full_attention", "sliding_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What ONE kind of K/V attention layer has of its own (``LlamaConfig.
+    attention_kind``): its KV heads, its rotary base, and — a window kind —
+    the keys a query sees (its own and the ``window - 1`` before it, held in
+    ``window`` rolling slots a row) and whether every query head has a
+    learned SINK, a float that joins the softmax's denominator and carries no
+    value."""
+    num_kv_heads: int
+    rope_theta: Optional[float] = 10000.0
+    window: int = 0
+    sink: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,9 +165,13 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     attention_impl: str = "dense"  # "dense" | "ring"
-    # sliding-window attention (Mistral-style): > 0 limits every query
-    # to the last `sliding_window` keys, in training AND in the cached
-    # decode paths.  0 = full causal.
+    # a MODEL-WIDE sliding window (Mistral-style): > 0 limits every query of
+    # every layer to the last `sliding_window` keys, in training AND in the
+    # cached decode paths, over ONE rolling cache sized by the engine
+    # (``rolling_cache_len``).  0 = full causal.  A window that only SOME
+    # layers have is no number here but a layer KIND: ``SLIDING`` in
+    # ``layer_types``, whose window, KV heads, rotary base and sink are
+    # ``sliding``'s and whose cache is its own (``init_cache``)
     sliding_window: int = 0
     remat: bool = True
     xent_chunk: int = 0
@@ -158,6 +195,9 @@ class LlamaConfig:
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    # (on the K/V path ``v_head_dim`` > 0 is the width of a VALUE head where
+    # it is not ``head_dim``: MiMo-V2-Flash's 192-wide keys over 128-wide
+    # values)
     v_head_dim: int = 0
     # the sparse-attention indexer beside it (DeepSeek-V3.2, GLM-5):
     # index_n_heads heads of index_head_dim score every visible key and
@@ -200,9 +240,21 @@ class LlamaConfig:
     # own block of mask_block positions (block diffusion: the tokens of a
     # block see each other); 1 is causal
     mask_block: int = 1
-    # the mixer of every layer, in layer order: LINEAR | FULL, whole repeats
-    # of one period (Olmo-Hybrid: 3 linear, 1 full); () = all full attention
+    # the mixer of every layer, in layer order: LINEAR | FULL | SLIDING
+    # (Olmo-Hybrid: 3 linear, 1 full, repeated; MiMo-V2-Flash: 1 full, then
+    # runs of 4 or 5 window layers each closed by a full one); () = all
+    # full attention.  LINEAR and SLIDING do not go together
     layer_types: tuple = ()
+    # the SLIDING layers' kind (the FULL layers' is the model's own
+    # ``num_kv_heads`` and ``rope_theta``, no window, no sink)
+    sliding: Optional[AttentionKind] = None
+    # the leading values of every q and k head that are rotated (0: all
+    # ``head_dim``; MiMo-V2-Flash: int(192 x 0.334) = 64), half-split pairs
+    # (i, i + rotary_dim / 2) as ``_rope`` turns them
+    rotary_dim: int = 0
+    # a factor on every value head (MiMo-V2-Flash's attention_value_scale),
+    # applied to v before it is cached
+    value_scale: float = 1.0
     # the linear layers' gated delta rule: heads (keys' and values' alike),
     # a key's and a value's size, the short convolution's taps, write
     # strength in (0, 2) (``linear_allow_neg_eigval``) or (0, 1), and the
@@ -242,18 +294,42 @@ class LlamaConfig:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.layer_types:
             if (len(self.layer_types) != self.num_layers
-                    or set(self.layer_types) - {LINEAR, FULL}):
+                    or set(self.layer_types) - {LINEAR, FULL, SLIDING}):
                 raise ValueError(
                     f"layer_types names each of the {self.num_layers} layers "
-                    f"{LINEAR!r} or {FULL!r}; got {self.layer_types}"
+                    f"{LINEAR!r}, {FULL!r} or {SLIDING!r}; got {self.layer_types}"
                 )
-            if (self.latent or self.num_experts or self.first_dense_layers
-                    or self.mtp_layers or self.mask_block > 1):
+            if self.latent or self.mtp_layers or self.mask_block > 1:
                 raise NotImplementedError(
-                    "layer_types goes with K/V attention and a dense SwiGLU: no "
-                    "latent attention, experts, leading dense blocks, "
+                    "layer_types goes with K/V attention: no latent attention, "
                     "multi-token-prediction module or block mask"
                 )
+            if LINEAR in self.layer_types and (
+                    self.num_experts or self.first_dense_layers
+                    or SLIDING in self.layer_types):
+                raise NotImplementedError(
+                    "linear-attention layers go with full attention and a dense "
+                    "SwiGLU: no experts, leading dense blocks or window layers"
+                )
+        if (SLIDING in self.layer_types) != (self.sliding is not None):
+            raise ValueError(
+                f"``sliding`` is the kind of the {SLIDING!r} layers of "
+                "layer_types: one goes with the other"
+            )
+        if self.sliding is not None and (
+                self.sliding.window < 1 or self.sliding_window or self.qk_norm
+                or self.post_norm or self.attention_impl != "dense"):
+            raise NotImplementedError(
+                "window layers see a window of at least one key and go without a "
+                "model-wide sliding_window, qk_norm, post_norm or ring attention"
+            )
+        if (self.v_head_dim or self.rotary_dim or self.value_scale != 1.0) and (
+                self.sliding is None and not self.latent):
+            raise NotImplementedError(
+                "a value width, a rotary share or a value scale of its own is "
+                "written for the K/V path of a model with attention kinds "
+                "(layer_types with window layers)"
+            )
         if self.block_form not in ("serial", "shortcut"):
             raise ValueError(
                 f"block_form is 'serial' or 'shortcut'; got {self.block_form!r}"
@@ -276,11 +352,7 @@ class LlamaConfig:
     def period(self) -> tuple:
         """The shortest run of kinds that ``layer_types`` repeats (the
         whole list, where nothing shorter tiles it)."""
-        kinds = self.layer_types
-        for n in range(1, len(kinds) + 1):
-            if len(kinds) % n == 0 and kinds[:n] * (len(kinds) // n) == kinds:
-                return kinds[:n]
-        return ()
+        return _periodic(self.layer_types)
 
     @property
     def linear_layers(self) -> int:
@@ -288,9 +360,29 @@ class LlamaConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep K and V per token: all, or the full-attention
-        ones of a config with ``layer_types``."""
+        """Layers that keep K and V per token at every position (``k`` /
+        ``v``): all, or the full-attention ones of a config with
+        ``layer_types``."""
         return self.layer_types.count(FULL) if self.layer_types else self.num_layers
+
+    @property
+    def sliding_layers(self) -> int:
+        """Window layers: K and V of a row's last ``sliding.window``
+        positions, in rolling slots (``swa_k`` / ``swa_v``)."""
+        return self.layer_types.count(SLIDING)
+
+    @property
+    def value_dim(self) -> int:
+        """Values a K/V head's VALUE has: ``head_dim`` unless the config
+        says otherwise (``v_head_dim``)."""
+        return self.v_head_dim or self.head_dim
+
+    def attention_kind(self, kind: str) -> AttentionKind:
+        """The numbers of one K/V attention kind: ``sliding``'s own, or
+        (FULL) the model's."""
+        if kind == SLIDING:
+            return self.sliding
+        return AttentionKind(self.num_kv_heads, self.rope_theta)
 
     @property
     def latent(self) -> bool:
@@ -395,6 +487,46 @@ class LlamaConfig:
         return LlamaConfig.tiny(**defaults)
 
     @staticmethod
+    def mimo_v2_flash(**kw) -> "LlamaConfig":
+        """MiMo-V2-Flash's published shape (309B-A15B): 48 layers, layer 0
+        full attention over a dense SwiGLU, then runs of window layers (the
+        first four long, the other seven five) each closed by a full layer,
+        all 47 with 256 sigmoid-routed experts, top 8; 64 query heads of 192
+        (the first 64 rotated) over values of 128 scaled by 0.707; full
+        layers 4 KV heads at rotary base 5e6, window layers 8 KV heads at
+        1e4, 128 keys and a learned sink a head.  ``layer_types`` may be
+        given cut to fewer layers (with ``num_layers``)."""
+        layers = kw.setdefault("num_layers", 48)
+        pattern = (FULL,) + (SLIDING,) * 4 + ((FULL,) + (SLIDING,) * 5) * 7 + (FULL,)
+        defaults = dict(
+            vocab_size=152576, max_seq_len=262144, num_heads=64, num_kv_heads=4,
+            embed_dim=4096, mlp_dim=16384, rope_theta=5e6, rms_eps=1e-5,
+            head_dim=192, v_head_dim=128, rotary_dim=64, value_scale=0.707,
+            layer_types=pattern[:layers],
+            sliding=AttentionKind(num_kv_heads=8, rope_theta=1e4, window=128, sink=True),
+            first_dense_layers=1, num_experts=256, experts_per_token=8,
+            expert_dim=2048, router_scoring="sigmoid", router_norm_topk=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def tiny_swa(**kw) -> "LlamaConfig":
+        """``mimo_v2_flash`` at toy widths: layer 0 full and dense, then two
+        periods of (2 window, 1 full) with 8 experts, top 2; heads of 12 (8
+        rotated) over values of 8; a window of 8 keys."""
+        defaults = dict(
+            num_layers=7, num_heads=4, num_kv_heads=1, head_dim=12, v_head_dim=8,
+            rotary_dim=8, value_scale=0.707, rope_theta=5e6, mlp_dim=96,
+            layer_types=(FULL,) + (SLIDING, SLIDING, FULL) * 2,
+            sliding=AttentionKind(num_kv_heads=2, rope_theta=1e4, window=8, sink=True),
+            first_dense_layers=1, num_experts=8, experts_per_token=2,
+            expert_dim=32, router_scoring="sigmoid", router_norm_topk=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig.tiny(**defaults)
+
+    @staticmethod
     def longcat_flash(**kw) -> "LlamaConfig":
         """LongCat-Flash's published language model (LongCat-Flash-Omni's
         ``config.json``): 28 shortcut-connected double layers, latent
@@ -428,21 +560,48 @@ class LlamaConfig:
 
 
 #: the parameter stack of each mixer kind (``_stacks``)
-_STACK_OF = {LINEAR: "gdn_blocks", FULL: "blocks"}
+_STACK_OF = {LINEAR: "gdn_blocks", FULL: "blocks", SLIDING: "swa_blocks"}
+_KIND_OF = {stack: kind for kind, stack in _STACK_OF.items()}
+
+
+def _layer_order(config: LlamaConfig):
+    """Every layer of a config with ``layer_types``, in layer order:
+    ``[(its stack's name, its index in that stack, its kind, its index among
+    the layers that keep its kind of state, expert FFN?)]``.  A kind's layers
+    lie in its own stack (``_STACK_OF``), the ``first_dense_layers`` leading
+    ones in a stack of their kind's name behind ``dense_``."""
+    c = config
+    in_stack, in_cache, out = {}, {}, []
+    for i, kind in enumerate(c.layer_types):
+        lead = i < c.first_dense_layers
+        name = ("dense_" if lead else "") + _STACK_OF[kind]
+        out.append((name, in_stack.get(name, 0), kind, in_cache.get(kind, 0),
+                    bool(c.num_experts) and not lead))
+        in_stack[name] = in_stack.get(name, 0) + 1
+        in_cache[kind] = in_cache.get(kind, 0) + 1
+    return out
+
+
+def _stack_kind(name: str) -> str:
+    """The mixer kind of the layers of the stack ``name``."""
+    return _KIND_OF[name.removeprefix("dense_")]
 
 
 def _stacks(config: LlamaConfig):
     """The parameter stacks of the one layer loop, in layer order:
     ``[(name in the tree, layers, first layer's index, expert FFN?)]``.
     One stack, ``blocks``, unless dense blocks lead the expert blocks —
-    or the layers are of two mixer kinds (``layer_types``): then one stack
-    a kind, ``gdn_blocks`` the linear layers' and ``blocks`` the full
-    ones', each in its own layers' order, and the loop interleaves them
-    (``_layer_loop``)."""
+    or the layers are of several mixer kinds (``layer_types``): then one
+    stack a kind (``_STACK_OF``: ``gdn_blocks`` the linear layers',
+    ``swa_blocks`` the window layers', ``blocks`` the full ones'; leading
+    dense blocks apart, ``_layer_order``), each in its own layers' order, and
+    the loop interleaves them (``_layer_loop``)."""
     c = config
     if c.layer_types:
-        return [(_STACK_OF[LINEAR], c.linear_layers, 0, False),
-                (_STACK_OF[FULL], c.kv_layers, 0, False)]
+        stacks = {}
+        for name, _i, _kind, _cl, experts in _layer_order(c):
+            stacks[name] = (stacks.get(name, (0,))[0] + 1, experts)
+        return [(name, n, 0, experts) for name, (n, experts) in stacks.items()]
     if not c.first_dense_layers:
         return [("blocks", c.num_layers, 0, bool(c.num_experts))]
     return [
@@ -523,7 +682,9 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     out = {"tok_embed": ("vocab", "embed"), "final_norm": ("embed",)}
     for name, _n, _first, experts in _stacks(c):
         out[name] = expert if experts else dense
-    if c.layer_types:
+        if c.sliding is not None and c.sliding.sink and _stack_kind(name) == SLIDING:
+            out[name] = dict(out[name], sink=("layers", "heads"))
+    if LINEAR in c.layer_types:
         out[_STACK_OF[LINEAR]] = {
             **{k: v for k, v in dense.items() if k not in (*attn, "wo")},
             "gdn_wq": ("layers", "embed", "heads", None),
@@ -548,19 +709,24 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
 
 
 def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
-                 linear: bool = False) -> Params:
+                 kind: str = FULL) -> Params:
     """One stack of ``layers`` blocks: a leading layer axis on every
     leaf.  ``experts``: the feed-forward is the expert layer (router,
     ``experts_here`` SwiGLUs of width ``expert_dim``, the shared expert
-    if any), else one SwiGLU of width ``mlp_dim``.  ``linear``: the mixer
-    is the gated delta rule (``_gated_delta_mixer`` names its tensors;
+    if any), else one SwiGLU of width ``mlp_dim``.  ``kind`` LINEAR: the
+    mixer is the gated delta rule (``_gated_delta_mixer`` names its tensors;
     ``a_log`` = log U(0, 16) and ``dt_bias`` = 1 as ``fla``'s
-    ``GatedDeltaNet`` starts them).  A shortcut-connected stack
+    ``GatedDeltaNet`` starts them); FULL | SLIDING: K/V attention with the
+    kind's KV heads (``LlamaConfig.attention_kind``), values of
+    ``value_dim``, and where the kind has one a ``sink`` a query head,
+    which starts at zero.  A shortcut-connected stack
     (``block_form``): the attention, the norms and a dense SwiGLU
     (``wd_*``) twice a layer, (L, 2, ..), beside the one expert layer."""
     c = config
     dt = c.param_dtype
-    L, E, H, KV, D = layers, c.embed_dim, c.num_heads, c.num_kv_heads, c.head_dim
+    linear = kind == LINEAR
+    L, E, H, D = layers, c.embed_dim, c.num_heads, c.head_dim
+    KV = c.num_kv_heads if linear else c.attention_kind(kind).num_kv_heads
     A = (L, 2) if c.block_form == "shortcut" else (L,)  # a sub-layer's lead
     X = (c.experts_here,) if experts else ()
     M = c.expert_dim if experts else c.mlp_dim
@@ -617,9 +783,11 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
         blk = {
             "wq": norm(k[1], (L, E, H, D), std),
             "wk": norm(k[2], (L, E, KV, D), std),
-            "wv": norm(k[3], (L, E, KV, D), std),
-            "wo": norm(k[4], (L, H, D, E), resid_std),
+            "wv": norm(k[3], (L, E, KV, c.value_dim), std),
+            "wo": norm(k[4], (L, H, c.value_dim, E), resid_std),
         }
+        if c.attention_kind(kind).sink:
+            blk["sink"] = jnp.zeros((L, H), dt)
         if c.qk_norm:
             blk.update({
                 "q_norm": jnp.ones((L, D if c.qk_norm == "head" else H * D), dt),
@@ -681,10 +849,12 @@ def init(rng, config: LlamaConfig) -> Params:
         # the one stack of a config without leading dense blocks draws
         # from ``rng`` itself, as it always did
         key = jax.random.fold_in(rng, first) if first else rng
-        linear = name == _STACK_OF[LINEAR]
-        if linear:
-            key = jax.random.fold_in(rng, 1 << 21)
-        params[name] = _init_blocks(key, c, layers, experts, linear)
+        kind = _stack_kind(name)
+        if kind != FULL:
+            key = jax.random.fold_in(rng, (1 << 21) if kind == LINEAR else (1 << 22))
+        if name.startswith("dense_") and c.layer_types:
+            key = jax.random.fold_in(key, 1 << 23)
+        params[name] = _init_blocks(key, c, layers, experts, kind)
     if not c.tie_embeddings:
         params["lm_head"] = norm(
             jax.random.fold_in(k0, 1), (c.vocab_size, c.embed_dim), std
@@ -776,14 +946,26 @@ def _layer_params(blocks: Params, config: LlamaConfig):
     return (rest, layers), whole
 
 
-def _qkv(h, p, positions, config: LlamaConfig):
+def _rope_part(x, positions, theta, rotary_dim: int):
+    """``_rope`` over the first ``rotary_dim`` values of the last dim, the
+    others as they are (0: all of them turn)."""
+    if not rotary_dim or rotary_dim == x.shape[-1]:
+        return _rope(x, positions, theta)
+    turned = _rope(x[..., :rotary_dim], positions, theta)
+    return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
+
+
+def _qkv(h, p, positions, config: LlamaConfig, kind: Optional[AttentionKind] = None):
     """Projections of the normed input: q (B, S, H, D) and k (B, S, KV,
-    D) with rotary positions applied, v (B, S, KV, D).  With ``qk_norm``
+    D) with rotary positions applied (to their first ``rotary_dim`` values,
+    at ``kind``'s base where the layer is of an attention kind), v (B, S,
+    KV, value_dim) times ``value_scale``.  With ``qk_norm``
     q and k are RMS-normed before the rotation (scales ``q_norm`` /
     ``k_norm``): over their WHOLE projected width, all heads together, or
     (``"head"``) each head over its own D values under one (D,) scale."""
     c = config
     B, S = h.shape[:2]
+    theta = kind.rope_theta if kind is not None else c.rope_theta
 
     def normed(x, scale):
         if not c.qk_norm:
@@ -793,10 +975,12 @@ def _qkv(h, p, positions, config: LlamaConfig):
         return _rmsnorm(x.reshape(B, S, -1), p[scale], c.rms_eps).reshape(x.shape)
 
     q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(c.dtype))
-    q = _rope(normed(q, "q_norm"), positions, c.rope_theta)
+    q = _rope_part(normed(q, "q_norm"), positions, theta, c.rotary_dim)
     kk = jnp.einsum("bse,ekd->bskd", h, p["wk"].astype(c.dtype))
-    kk = _rope(normed(kk, "k_norm"), positions, c.rope_theta)
+    kk = _rope_part(normed(kk, "k_norm"), positions, theta, c.rotary_dim)
     vv = jnp.einsum("bse,ekd->bskd", h, p["wv"].astype(c.dtype))
+    if c.value_scale != 1.0:
+        vv = (vv.astype(jnp.float32) * c.value_scale).astype(vv.dtype)
     return q, kk, vv
 
 
@@ -1129,55 +1313,104 @@ def _block(x, p, positions, config: LlamaConfig):
     return x, routing and routing["experts"]
 
 
+def _periodic(seq: tuple) -> tuple:
+    """The shortest run that ``seq`` repeats (all of it, where nothing
+    shorter tiles it)."""
+    for n in range(1, len(seq) + 1):
+        if len(seq) % n == 0 and seq[:n] * (len(seq) // n) == seq:
+            return seq[:n]
+    return ()
+
+
+def _segments(seq: tuple):
+    """``seq`` as ``[(period, repeats)]``: an irregular head, once, and
+    behind it whole repeats of one period — the split with the fewest layers
+    in the two loop bodies together (MiMo-V2-Flash's 48: its first six
+    layers once, then seven times (5 window, 1 full); Olmo-Hybrid's: no
+    head, (3 linear, 1 full))."""
+    head = min(range(len(seq)), key=lambda h: (h + len(_periodic(seq[h:])), h))
+    period = _periodic(seq[head:])
+    rest = [(period, (len(seq) - head) // len(period))]
+    return ([(seq[:head], 1)] if head else []) + rest
+
+
 def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int = 1):
     """The ONE layer loop: ``carry`` through every block in layer order.
     ``block(carry, p, cache_layer) -> (carry, aux)``: p one layer's
-    parameters (and ``p["layer"]``, its index in its stack),
+    parameters (and ``p["layer"]``, its index in its stack; with
+    ``layer_types`` also ``p["kind"]``, its mixer kind's NAME),
     ``cache_layer`` its index among the layers that keep its kind of
     state.  Returns (carry, aux with a leading axis over the layers that
     gave it).
 
     One ``lax.scan`` a parameter stack, in turn (``_stacks``) — or, where
-    the layers are of two mixer kinds, ONE scan over the pattern's periods
-    whose body runs a period's layers in order, each on its own kind's
-    stack: a period of (3 linear, 1 full) is four blocks in the loop's
-    body and not ``num_layers`` unrolled."""
+    the layers are of several mixer kinds, ONE scan over the pattern's
+    periods whose body runs a period's layers in order, each on its own
+    kind's stack: a period of (3 linear, 1 full) is four blocks in the loop's
+    body and not ``num_layers`` unrolled.  A pattern with an irregular head
+    is two such scans (``_segments``)."""
     c = config
     if c.layer_types:
-        kinds = c.period
-        periods = c.num_layers // len(kinds)
-        per = {kind: kinds.count(kind) for kind in sorted(set(kinds))}
+        order = _layer_order(c)
+        experts = {name: x for name, _i, _kind, _cl, x in order}
+        kept, done = {}, 0
+        for kinds, periods in _segments(tuple((n, k) for n, _i, k, _cl, _x in order)):
+            # a period's layers of each stack and of each kind (two sets of
+            # names that do not meet), and where the segment's first of each lies
+            per = collections.Counter([n for n, _k in kinds] + [k for _n, k in kinds])
+            base = {}
+            for name, at, kind, cache_at, _x in order[done:done + len(kinds)]:
+                base.setdefault(name, at)
+                base.setdefault(kind, cache_at)
 
-        def body(carry, period):
-            seen, kept = dict.fromkeys(per, 0), {}
-            for kind in kinds:
-                l = period * per[kind] + seen[kind]
-                seen[kind] += 1
-                # the layer's index waits for the layers before it, and its
-                # weights' slices with the index.  Left free, XLA slices the
-                # three linear layers' weights at the top of the body in one
-                # fusion whose outputs do not all fit fast memory: 110 MB a
-                # period go out to HBM and come back (1.2 ms of a 20 ms
-                # decode step at Olmo-Hybrid's widths, PR 48; the program
-                # before it escaped only by being large enough for XLA's
-                # rematerialisation to start)
-                leaves, tree = jax.tree.flatten(carry)
-                leaves[0], l = lax.optimization_barrier((leaves[0], l))
-                carry = jax.tree.unflatten(tree, leaves)
-                # the layer's slice of each stacked leaf, taken where it is
-                # used: sliced a period at a time (the scan's ``xs``) and
-                # then a layer, every weight is copied once a call
-                p = jax.tree.map(
-                    lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
-                    params[_STACK_OF[kind]],
-                )
-                carry, aux = block(carry, dict(p, layer=l), l)
-                for key, v in aux.items():
-                    kept.setdefault(key, []).append(v)
-            return carry, {key: jnp.stack(v) for key, v in kept.items()}
+            def body(carry, period, kinds=kinds, per=per, base=base):
+                seen, kept = collections.Counter(), {}
 
-        carry, aux = lax.scan(body, carry, jnp.arange(periods), unroll=unroll)
-        return carry, {k: v.reshape(-1, *v.shape[2:]) for k, v in aux.items()}
+                def index(n, k, b):
+                    i = period * n + k
+                    return i + b if b else i
+
+                for name, kind in kinds:
+                    in_stack = (per[name], seen[name], base[name])
+                    in_cache = (per[kind], seen[kind], base[kind])
+                    seen.update((name, kind))
+                    l = index(*in_stack)
+                    # the layer's index waits for the layers before it, and its
+                    # weights' slices with the index.  Left free, XLA slices the
+                    # three linear layers' weights at the top of the body in one
+                    # fusion whose outputs do not all fit fast memory: 110 MB a
+                    # period go out to HBM and come back (1.2 ms of a 20 ms
+                    # decode step at Olmo-Hybrid's widths, PR 48; the program
+                    # before it escaped only by being large enough for XLA's
+                    # rematerialisation to start)
+                    leaves, tree = jax.tree.flatten(carry)
+                    leaves[0], l = lax.optimization_barrier((leaves[0], l))
+                    carry = jax.tree.unflatten(tree, leaves)
+                    # a kind with one stack: the index in it IS the cache layer
+                    cache_layer = l if in_cache == in_stack else index(*in_cache)
+                    # the layer's slice of each stacked leaf, taken where it is
+                    # used: sliced a period at a time (the scan's ``xs``) and
+                    # then a layer, every weight is copied once a call.  An
+                    # expert stack's three expert tensors go WHOLE (``_ffn``
+                    # hands the kernel all L * X matrices, ``_layer_params``)
+                    stack = params[name]
+                    whole = {k: stack[k].astype(c.dtype) for k in _EXPERT_TENSORS
+                             } if experts[name] else {}
+                    p = jax.tree.map(
+                        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+                        {k: v for k, v in stack.items() if k not in whole},
+                    )
+                    carry, aux = block(
+                        carry, dict(p, layer=l, kind=kind, **whole), cache_layer)
+                    for key, v in aux.items():
+                        kept.setdefault(key, []).append(v)
+                return carry, {key: jnp.stack(v) for key, v in kept.items()}
+
+            carry, aux = lax.scan(body, carry, jnp.arange(periods), unroll=unroll)
+            for k, v in aux.items():
+                kept.setdefault(k, []).append(v.reshape(-1, *v.shape[2:]))
+            done += len(kinds) * periods
+        return carry, {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
     kept = {}
     for name, _layers, first, _experts in _stacks(c):
         xs, whole = _layer_params(params[name], c)
@@ -1194,10 +1427,11 @@ def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int =
 
 def _features_and_choices(params: Params, tokens, config: LlamaConfig):
     c = config
-    if c.latent or c.first_dense_layers:
+    if c.latent or c.first_dense_layers or c.sliding is not None:
         raise NotImplementedError(
-            "a latent-attention or dense-leading config runs on the cached "
-            "paths only (prefill_into_slot / decode_step_rowwise)"
+            "a latent-attention or dense-leading config, and one with window "
+            "layers beside full ones (layer_types: sliding_attention), runs on "
+            "the cached paths only (prefill_into_slot / decode_step_rowwise)"
         )
     B, S = tokens.shape
     emb = constrain(params["tok_embed"], (None, None)).astype(c.dtype)
@@ -1209,6 +1443,7 @@ def _features_and_choices(params: Params, tokens, config: LlamaConfig):
         fn = _block
         if c.remat:
             fn = jax.checkpoint(_block, static_argnums=(3,))
+        p = {k: v for k, v in p.items() if k != "kind"}  # a name, no array
         x, experts = fn(carry, p, positions, c)
         return x, {} if experts is None else {"experts": experts}
 
@@ -1426,6 +1661,23 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     ``gdn_counts`` (``GDN_COUNTS``, 2) rides beside them as ``_add_wide``
     pairs.
 
+    A config with window layers (``SLIDING`` in ``layer_types``) keeps a
+    ``k`` / ``v`` pair A KIND in the one tree, each for its own layers and in
+    its own shape: ``k`` (full layers, B, max_len, KV x D) and ``v`` (.., KV x
+    D_v) as above for the FULL layers (2 of MiMo-V2-Flash's 7 here: 4 x 192 |
+    4 x 128), and for the window layers ``swa_k`` (window layers, B, window,
+    KV_w x D) and ``swa_v`` (.., KV_w x D_v): ``window`` ROLLING slots a row,
+    slot = position mod window, whatever ``max_len`` is (5 layers x 128 slots
+    x 8 x 192 | 8 x 128: 0.21 GB at 64 rows where ``max_len`` 13,312 would
+    take 21.8).  A prefill leaves a row's last ``window`` keys in their
+    slots, whatever the slot held; a step overwrites the one slot whose key
+    has just left the window.  Every row of either is whole 128-lane tiles.
+    Beside them ``attn_keys`` (``ATTENTION_KINDS``, 2, 2, 2) int32: per kind,
+    over its layers, keys VISIBLE / keys READ, for runs (prefills: (query,
+    key) pairs inside the mask / pairs scored) / one-token steps (keys a
+    row's query could see / keys fetched for it) apart, each an
+    ``_add_wide`` pair (``_kind_amounts``).
+
     An expert config adds int32 running totals that ride the donated
     cache like K and V, so no step pays a device-to-host copy for them
     (``serve/llm.py`` reads them in ``stats()``): ``moe_expert_tokens``
@@ -1467,12 +1719,19 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
             "dsa_keys": jnp.zeros((c.num_layers, 3, 2, 2), jnp.int32),
         }
     else:
-        shape = (c.kv_layers, batch_size, max_len, c.num_kv_heads * c.head_dim)
+        lead = (c.kv_layers, batch_size, max_len)
         cache = {
-            "k": jnp.zeros(shape, c.dtype),
-            "v": jnp.zeros(shape, c.dtype),
+            "k": jnp.zeros((*lead, c.num_kv_heads * c.head_dim), c.dtype),
+            "v": jnp.zeros((*lead, c.num_kv_heads * c.value_dim), c.dtype),
         }
-    if c.layer_types:
+    if c.sliding is not None:
+        lead = (c.sliding_layers, batch_size, c.sliding.window)
+        cache.update({
+            "swa_k": jnp.zeros((*lead, c.sliding.num_kv_heads * c.head_dim), c.dtype),
+            "swa_v": jnp.zeros((*lead, c.sliding.num_kv_heads * c.value_dim), c.dtype),
+            "attn_keys": jnp.zeros((len(ATTENTION_KINDS), 2, 2, 2), jnp.int32),
+        })
+    if LINEAR in c.layer_types:
         H, Dk, Dv = c.linear_num_heads, c.linear_key_head_dim, c.linear_value_head_dim
         cache.update({
             "gdn_state": jnp.zeros((c.linear_layers, batch_size, Dk, H * Dv), jnp.float32),
@@ -1506,7 +1765,7 @@ def _latent_row(config: LlamaConfig) -> int:
 
 
 #: the cache's entries that hold tokens' state (the rest are counters)
-_STATE = ("k", "v", "ckv", "ik", "gdn_state", "gdn_conv")
+_STATE = ("k", "v", "swa_k", "swa_v", "ckv", "ik", "gdn_state", "gdn_conv")
 #: what a latent attention hands the layer loop, an entry a cache layer
 _PER_CACHE_LAYER = ("mla_keys", "ckv_rows", "selected")
 #: ``cache["gdn_counts"]``'s rows, each an ``_add_wide`` pair, all over
@@ -1637,29 +1896,40 @@ def _last_visible(positions, block: int = 1):
     return positions
 
 
-def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig):
+def _grouped_attention(q, k_cache, v_cache, mask, config: LlamaConfig,
+                       kv_heads: int = 0, sink=None):
     """The ONE cached-attention body in plain XLA.  q: (B, Sq, H, D)
-    attends over the rows' slabs of the cache, (B, T, KV x D): the H = KV
+    attends over the rows' slabs of the cache, (B, T, KV x D) keys and (B,
+    T, KV x Dv) values: the H = KV
     * G query heads fold to (KV, G) and contract against their KV head
     directly, so K/V are never expanded (no ``jnp.repeat``) and every
     cached byte is read once, in the cache's dtype.  MHA is G = 1, the
     same code.  mask: (B, Sq, T), True where query q may see slot t.
     Scores and softmax in f32, probabilities and values in
-    ``config.dtype``.  (An every-row step over whole blocks runs the same
-    mathematics in flash order: ``ops/kv_decode_attention.py``.)"""
+    ``config.dtype``.  ``kv_heads``: the layer's kind's, where it is not
+    the model's.  ``sink`` (H,) float32: a learned number a query head that
+    joins the softmax's denominator and carries no value.  (An every-row
+    step over whole blocks runs the same mathematics in flash order:
+    ``ops/kv_decode_attention.py``.)"""
     c = config
     B, Sq, H, D = q.shape
-    KV = c.num_kv_heads
+    KV = kv_heads or c.num_kv_heads
     k_cache = k_cache.reshape(*k_cache.shape[:2], KV, D)
-    v_cache = v_cache.reshape(*v_cache.shape[:2], KV, D)
+    v_cache = v_cache.reshape(*v_cache.shape[:2], KV, -1)
     q = q.reshape(B, Sq, KV, H // KV, D)
     scores = jnp.einsum(
         "bqkgd,btkd->bkgqt", q, k_cache, preferred_element_type=jnp.float32
     ) / math.sqrt(D)
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    else:
+        sink = sink.reshape(1, KV, H // KV, 1, 1)
+        top = jnp.maximum(scores.max(-1, keepdims=True), sink)
+        probs = jnp.exp(scores - top)
+        probs = (probs / (probs.sum(-1, keepdims=True) + jnp.exp(sink - top))).astype(c.dtype)
     out = jnp.einsum("bkgqt,btkd->bqkgd", probs, v_cache)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, -1)
 
 
 #: tokens a row of an every-row step may bring and still be written into
@@ -1735,6 +2005,8 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
     shapes), else grouped attention over the rows' slabs.  Returns (the
     heads' outputs (R, Sq, H, D), state, no counters)."""
     c = config
+    if c.sliding is not None:
+        return _kind_attention(h, p, state, slot, positions, c)
     q, kk, vv = _qkv(h, p, positions, c)
     T = state["k"].shape[2]
     if c.layer_types and slot is not None:
@@ -1772,6 +2044,135 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
             mask = _cache_mask(positions, T, c.sliding_window, c.mask_block)
             attn = _grouped_attention(q, slab_k, slab_v, mask, c)
     return attn, dict(state, k=cache_k, v=cache_v), {}
+
+
+def _write_run(cache, new, layer, slot, rolling: bool):
+    """One row's run from position 0, ``new`` (S, width), into the carried
+    ``cache`` (L, B, T, width) at ``layer``, row ``slot``: positions 0..S-1
+    as they are, or (``rolling``: slot = position mod T) the run's last
+    ``min(S, T)`` positions, each in its slot — one block write either way
+    (S is static, so the rotation is two slices)."""
+    S, T = new.shape[0], cache.shape[2]
+    if rolling and S > T:
+        new = jnp.roll(new[S - T:], S % T, axis=0)
+    return lax.dynamic_update_slice(cache, new[None, None], (layer, slot, 0, 0))
+
+
+def _kind_step_streams(config: LlamaConfig, kind: AttentionKind, cache_len: int) -> bool:
+    """Does a one-token step of a layer of ``kind`` over ``cache_len`` slots
+    run the kernel of ``ops/kv_decode_attention.py`` (its rule, from static
+    shapes), or XLA's body over the slab?"""
+    return kv_decode_attention.implementation(
+        cache_len, config.head_dim, kv_heads=kind.num_kv_heads,
+        v_head_dim=config.value_dim) == "streamed"
+
+
+def _kind_attention(h, p, state, slot, positions, config: LlamaConfig):
+    """``_kv_attention`` of a layer of a model with attention KINDS
+    (``layer_types`` with window layers: MiMo-V2-Flash's).  The layer's
+    kind, ``p["kind"]``, says which K/V pair of the cache is its own
+    (``k`` / ``v``: every position; ``swa_k`` / ``swa_v``: ``window``
+    rolling slots), how many KV heads it has, its rotary base, and whether
+    a query sees every earlier key or its own and the ``window - 1`` before
+    it, with a learned sink a head in the softmax's denominator.
+
+    One row's run from position 0 (a prefill): the run's K/V are written —
+    a full layer's all, a window layer's last ``window`` positions into
+    their slots — and attention is over the run's OWN keys
+    (``ops/kv_prefill_attention.py``: a flash kernel with grouped queries
+    over the live tiles — those inside the band, for a window — or, toy
+    heads, XLA's dense body).  One token for every row (a decode step): the
+    token's K/V written where they belong, then attention over the carried
+    cache where it lies (``ops/kv_decode_attention.py``: a full layer's row
+    up to its own last key, a window layer's one block of slots), or, a
+    cache of no whole blocks, ``_grouped_attention`` over the layer's
+    slab.  Returns (the heads' outputs (R, Sq, H, value_dim), state, no
+    counters: what a call saw and read is known from its positions,
+    ``_kind_amounts``)."""
+    c = config
+    sliding = p["kind"] == SLIDING
+    kind = c.attention_kind(p["kind"])
+    names = ("swa_k", "swa_v") if sliding else ("k", "v")
+    R, Sq = positions.shape
+    layer = p["cache_layer"]
+    q, kk, vv = _qkv(h, p, positions, c, kind)
+    sink = p["sink"].astype(jnp.float32) if kind.sink else None
+    cache_k, cache_v = (state[n] for n in names)
+    T = cache_k.shape[2]
+    with jax.named_scope("swa_attn" if sliding else "full_attn"):
+        new_k = kk.reshape(R, Sq, -1).astype(c.dtype)
+        new_v = vv.reshape(R, Sq, -1).astype(c.dtype)
+        if slot is not None and R == 1:
+            cache_k = _write_run(cache_k, new_k[0], layer, slot, sliding)
+            cache_v = _write_run(cache_v, new_v[0], layer, slot, sliding)
+            attn = kv_prefill_attention.attention(
+                q[0], kk[0], vv[0], window=kind.window, sink=sink)[None]
+        elif slot is None and Sq == 1:
+            rows = jnp.arange(R)[:, None]
+            at = positions % T if sliding else positions
+            cache_k = cache_k.at[layer, rows, at].set(new_k)
+            cache_v = cache_v.at[layer, rows, at].set(new_v)
+            # in slots: a window layer's T slots are its window, all of them
+            # live once the row has T keys
+            visible = jnp.minimum(positions, T - 1)
+            if _kind_step_streams(c, kind, T):
+                attn = kv_decode_attention.kv_decode_attention(
+                    q, cache_k, cache_v, layer, visible, sink=sink)
+            else:
+                slab_k, slab_v = (lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+                                  for t in (cache_k, cache_v))
+                mask = jnp.arange(T) <= visible[:, :, None]
+                attn = _grouped_attention(
+                    q, slab_k, slab_v, mask, c, kind.num_kv_heads, sink)
+        else:
+            raise NotImplementedError(
+                "a model with window layers prefills whole prompts, one row at a "
+                "time, and steps one token a row (prefill_into_slot / "
+                "decode_step_rowwise): a run that continues a row, or a step of "
+                "several tokens a row, would need the keys a rolling cache has "
+                "already overwritten"
+            )
+    return attn.astype(c.dtype), dict(state, **dict(zip(names, (cache_k, cache_v)))), {}
+
+
+#: ``cache["attn_keys"]``'s first axis: the attention kinds that count
+ATTENTION_KINDS = (FULL, SLIDING)
+
+
+def _kind_amounts(config: LlamaConfig, positions, step: bool, cache_len: int):
+    """What one call adds to ``attn_keys``: (kinds, 2) = per kind
+    (``ATTENTION_KINDS``), over its layers and the call's rows, a head: keys
+    VISIBLE and keys READ.  A step (one token a row, ``positions`` (R, 1)):
+    keys a row's query could see, and keys fetched for it (whole blocks up
+    to its last visible key where the kernel streams them, the whole slab
+    otherwise; a window layer's slots are one block).  A run of S tokens
+    from position 0: (query, key) pairs inside the mask, and pairs its
+    attention computed scores for (``kv_prefill_attention.pairs_computed``).
+    A step's are traced int32 (64 rows x 13k keys x 9 layers are 8 M), a
+    run's numpy int64 from its static shape."""
+    c = config
+    layers = {FULL: c.kv_layers, SLIDING: c.sliding_layers}
+    out = []
+    for name in ATTENTION_KINDS:
+        kind = c.attention_kind(name)
+        T = kind.window if kind.window else cache_len
+        if step:
+            pos = positions[:, 0]
+            seen = jnp.minimum(pos + 1, T).sum(dtype=jnp.int32)
+            if _kind_step_streams(c, kind, T):
+                block = kv_decode_attention.BLOCK_KEYS
+                read = ((jnp.minimum(pos, T - 1) // block + 1) * block).sum(dtype=jnp.int32)
+            else:
+                read = jnp.int32(pos.shape[0] * T)
+            out.append(jnp.stack([seen, read]) * layers[name])
+        else:
+            S = positions.shape[1]
+            w = min(kind.window or S, S)
+            inside = S * w - w * (w - 1) // 2
+            computed = kv_prefill_attention.pairs_computed(
+                S, c.head_dim, c.value_dim, kind.window)
+            out.append(np.asarray([inside, computed], np.int64) * layers[name])
+    return jnp.stack(out) if step else np.stack(out)
 
 
 def _run_attention(q, kk, vv, config: LlamaConfig):
@@ -2377,10 +2778,16 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
     x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = x if hidden else _logits(params, x[:, -1, :], c)
     cache = _with_counts(cache, state, aux, step)
-    if c.layer_types:
+    if LINEAR in c.layer_types:
         cache["gdn_counts"] = _add_wide(
             cache["gdn_counts"], _gdn_amounts(c, *tokens.shape, step)
         )
+    if c.sliding is not None:
+        when = int(step)  # runs at [:, :, 0], one-token steps at [:, :, 1]
+        cache["attn_keys"] = cache["attn_keys"].at[:, :, when].set(_add_wide(
+            cache["attn_keys"][:, :, when],
+            _kind_amounts(c, positions, step, cache["k"].shape[2]),
+        ))
     return (logits, cache, aux) if collect else (logits, cache)
 
 

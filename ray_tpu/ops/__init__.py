@@ -2,7 +2,11 @@
 
 The serving path's attention kernels each say by shape, in ONE function
 ``implementation``, whether the kernel or XLA's body runs:
-``kv_decode_attention`` (a K/V config's every-row step),
+``kv_decode_attention`` (a K/V config's every-row step; for a layer KIND
+also key heads off a lane tile's edge, a sink, and a window kind's one block
+of rolling slots), ``kv_prefill_attention`` (a layer kind's prefill: grouped
+queries, keys wider than values, a window and a sink in one flash kernel
+over the live tiles; XLA's dense body for toy heads),
 ``latent_decode_attention`` (a latent config's decode step) and
 ``latent_prefill_attention`` (a latent config's prefill: the flash kernel
 for a run of whole 512-token tiles with heads of whole 128-lane tiles, XLA's

@@ -42,6 +42,16 @@ Two bodies, chosen from static shapes in ONE place (``implementation``):
   (a prefill, ``slot`` given): ``_kv_attention`` asks only for every-row
   steps.
 
+A layer KIND's step (``llama._kind_attention``: MiMo-V2-Flash's full layers, 4
+KV heads of 192 over values of 128, and its window layers, 8 KV heads in 128
+rolling slots with a learned sink a query head) runs the same kernel: values
+and the key ROW are whole lane tiles, key HEADS need not be — the queries then
+arrive block-diagonal over the whole row and one product gives every head's
+scores (``_accumulate``); ``sink`` starts each query row's running maximum
+and its sum at ``exp(0)``; a window layer's cache is ONE block, every slot a
+key of the row once it has 128.  ``implementation`` answers for a kind when
+given ``kv_heads`` and ``v_head_dim``.
+
 What the schedule's numbers were measured against (a v5e, PR 37; ms a
 call, the kernel alone in a loop over the layers whose own cost is 0.006 -
 0.024, at the three shapes that run it: SDAR 32 rows x 4 queries x 32 heads
@@ -119,11 +129,21 @@ _VMEM_BESIDE_THE_RINGS = 16 << 20
 _QUERY_ROWS = 8
 
 
-def implementation(cache_len: int, head_dim: int, window: int = 0) -> str:
+def implementation(cache_len: int, head_dim: int, window: int = 0, *,
+                   kv_heads: int = 0, v_head_dim: int = 0) -> str:
     """Which body an every-row step over a K/V cache of ``cache_len``
     positions with heads of ``head_dim`` values traces: ``"streamed"`` —
     the kernel — for a full-causal cache of whole blocks and whole-tile
-    heads, else ``"slab"``."""
+    heads, else ``"slab"``.  ``window``: a MODEL-WIDE window's rolling cache
+    (slab).  With ``kv_heads`` and ``v_head_dim`` the question is a layer
+    KIND's (``llama._kind_attention``): value heads of whole tiles and a
+    key ROW of whole tiles are enough — key heads may start off a tile's
+    edge (4 x 192), the kernel then takes the row whole — and a window
+    kind's cache of ``window`` rolling slots is one block, all of it a
+    row's keys."""
+    if kv_heads:
+        whole = v_head_dim % 128 == 0 and (kv_heads * head_dim) % 128 == 0
+        return "streamed" if whole and cache_len % BLOCK_KEYS == 0 else "slab"
     if not window and cache_len % BLOCK_KEYS == 0 and head_dim % 128 == 0:
         return "streamed"
     return "slab"
@@ -146,25 +166,36 @@ def item_blocks(cache_len: int, row_bytes: int) -> int:
     return min(MAX_ITEM_BLOCKS, fit, cache_len // BLOCK_KEYS)
 
 
-def _accumulate(q_ref, k_ref, v_ref, slot, keys, keep, m_ref, l_ref, acc_ref, scale):
+def _accumulate(q_ref, k_ref, v_ref, slot, keys, keep, m_ref, l_ref, acc_ref, scale, heads):
     """One item's ``keys`` keys in ring slot ``slot`` into the running max
     / sum / accumulator of every KV head at once: the heads' scores stand
     one under the other, (KV x query rows, keys), so the softmax's
     arithmetic is ONE pass over whole tiles and only the matmuls go head
     by head (a chain a head cost 0.14-0.2 us an item each: 16 heads of one
     query row were latency-bound at 53% of the bandwidth).  ``keep``: what
-    of the scores counts."""
-    heads, n, D = q_ref.shape
+    of the scores counts.  Key heads that start off a lane tile's edge (4 x
+    192): the queries arrive BLOCK-DIAGONAL, (KV x query rows, KV x Dk) with
+    head kv's rows zero outside its own lanes, and ONE product with the
+    whole key row gives every head's scores one under the other — no slice
+    of a key head, and no more passes of the MXU over the keys than the
+    heads' own products would take."""
+    n, Dv = acc_ref.shape[0] // heads, acc_ref.shape[1]
 
-    def of_head(h, ref):
+    def of_head(h, ref, D):
         return ref[slot, pl.ds(0, keys), pl.ds(h * D, D)]
 
-    s = jnp.concatenate([
-        lax.dot_general(
-            q_ref[h], of_head(h, k_ref), (((1,), (1,)), ((), ())),
+    if len(q_ref.shape) == 2:
+        s = lax.dot_general(
+            q_ref[...], k_ref[slot, pl.ds(0, keys), :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) for h in range(heads)
-    ], axis=0) * scale                                      # (KV x query rows, keys)
+        ) * scale
+    else:
+        s = jnp.concatenate([
+            lax.dot_general(
+                q_ref[h], of_head(h, k_ref, q_ref.shape[2]), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for h in range(heads)
+        ], axis=0) * scale                                  # (KV x query rows, keys)
     s = jnp.where(keep, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -174,26 +205,32 @@ def _accumulate(q_ref, k_ref, v_ref, slot, keys, keep, m_ref, l_ref, acc_ref, sc
     l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jnp.concatenate([
         lax.dot_general(
-            p[h * n:(h + 1) * n].astype(v_ref.dtype), of_head(h, v_ref),
+            p[h * n:(h + 1) * n].astype(v_ref.dtype), of_head(h, v_ref, Dv),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         ) for h in range(heads)
     ], axis=0)
 
 
-def _kernel(layer_ref, first_ref, last_ref, visible_ref, q_ref, k_hbm, v_hbm,
-            out_ref, ring_k, ring_v, arrived, head_ref, m_ref, l_ref, acc_ref,
-            *, block, scale, queries, group):
+def _kernel(layer_ref, first_ref, last_ref, visible_ref, q_ref, *rest,
+            block, scale, queries, group, heads, sunk):
     """Grid step r: row r's items ``first[r] .. first[r + 1]`` through the
     running softmax of each KV head.  q (KV, query rows, D): row j G + g of
     head kv is query j of query head kv G + g (zero rows behind them, to a
-    whole tile); ``k_hbm`` / ``v_hbm`` the whole caches where they lie; item
+    whole tile) — or block-diagonal, (KV x query rows, KV x D)
+    (``_accumulate``); ``k_hbm`` / ``v_hbm`` the whole caches where they lie; item
     i's blocks come by one copy each into slot ``i % depth`` of ``ring_k`` /
     ``ring_v`` (depth, span x block, KV x D), started ``depth - 1`` items
     ahead of the one computed on, whatever row it belongs to (``head_ref``:
     the row of the item last sent for).  A copy's and a matmul's length are
     static, so an item takes one of ``span`` branches by its number of
     blocks.  Query j of the row sees the keys t <= ``visible_ref[r queries
-    + j]``, non-decreasing in j."""
+    + j]``, non-decreasing in j.  ``sunk``: ``sink_ref`` (KV x query rows,
+    1) float32 stands in the operands behind q, a learned number a query
+    row that joins its softmax's denominator and carries no value: the
+    running maximum starts there and the running sum at ``exp(0)``."""
+    sink_ref = rest[0] if sunk else None
+    (k_hbm, v_hbm, out_ref, ring_k, ring_v, arrived, head_ref,
+     m_ref, l_ref, acc_ref) = rest[sunk:]
     r, rows = pl.program_id(0), pl.num_programs(0)
     depth, span = ring_k.shape[0], ring_k.shape[1] // block
     lo, hi, live = first_ref[r], first_ref[r + 1], first_ref[rows]
@@ -230,11 +267,15 @@ def _kernel(layer_ref, first_ref, last_ref, visible_ref, q_ref, k_hbm, v_hbm,
         for i in range(depth - 1):
             pl.when(i < live)(functools.partial(send_for, i))
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sunk:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    heads, n, D = q_ref.shape
+    n = m_ref.shape[0] // heads
     # score row h n + j G + g is query j of head (h, g); the tile's zero
     # rows behind them take the last query's limit
     row_of = lax.broadcasted_iota(jnp.int32, (heads * n, 1), 0) % n
@@ -253,7 +294,7 @@ def _kernel(layer_ref, first_ref, last_ref, visible_ref, q_ref, k_hbm, v_hbm,
                 jnp.int32, (heads * n, keys), 1
             )
             _accumulate(q_ref, ring_k, ring_v, i % depth, keys, t <= limit,
-                        m_ref, l_ref, acc_ref, scale)
+                        m_ref, l_ref, acc_ref, scale, heads)
 
         by_length(i, r, arrived_keys)
         return carry
@@ -264,23 +305,29 @@ def _kernel(layer_ref, first_ref, last_ref, visible_ref, q_ref, k_hbm, v_hbm,
         out_ref[h] = out[h * n:(h + 1) * n]
 
 
-def kv_decode_attention(q, k_cache, v_cache, layer, visible):
-    """The streamed body.  q (R, Sq, H, D), k_cache / v_cache (L, R, T,
-    KV x D) whole, layer () int32, visible (R, Sq) int32 >= 0, non-decreasing
+def kv_decode_attention(q, k_cache, v_cache, layer, visible, sink=None):
+    """The streamed body.  q (R, Sq, H, D), k_cache (L, R, T, KV x D) and
+    v_cache (L, R, T, KV x Dv) whole, layer () int32, visible (R, Sq) int32
+    >= 0, non-decreasing
     along Sq: query j of row r attends to the keys t <= visible[r, j] -> (R,
-    Sq, H, D) in the cache's dtype.  T is a whole number of ``BLOCK_KEYS``,
-    D of 128-lane tiles.  A row's blocks up to the one that holds
+    Sq, H, Dv) in the cache's dtype.  T is a whole number of ``BLOCK_KEYS``;
+    D and Dv are whole 128-lane tiles, or (``implementation``'s rule for a
+    layer kind) Dv and the key ROW are.  ``sink`` (H,) float32: a learned
+    number a query head that joins the softmax's denominator and carries no
+    value.  A row's blocks up to the one that holds
     ``visible[r, -1]`` are fetched once for all its queries and heads."""
     R, Sq, H, D = q.shape
     L, B, T, row = k_cache.shape
     KV = row // D
-    if (implementation(T, D) != "streamed" or B != R or KV * D != row or H % KV
-            or v_cache.shape != k_cache.shape or visible.shape != (R, Sq)):
+    Dv = v_cache.shape[-1] // KV
+    if (implementation(T, D, kv_heads=KV, v_head_dim=Dv) != "streamed" or B != R
+            or KV * D != row or H % KV or v_cache.shape != (L, B, T, KV * Dv)
+            or visible.shape != (R, Sq)):
         raise ValueError(
             f"streamed K/V attention wants one cache row a query row, whole "
-            f"blocks of {BLOCK_KEYS} keys and heads of whole 128-lane tiles: "
-            f"q {q.shape}, caches {k_cache.shape} / {v_cache.shape}, visible "
-            f"{visible.shape}"
+            f"blocks of {BLOCK_KEYS} keys, key rows and value heads of whole "
+            f"128-lane tiles: q {q.shape}, caches {k_cache.shape} / "
+            f"{v_cache.shape}, visible {visible.shape}"
         )
     G = H // KV
     block = BLOCK_KEYS
@@ -297,26 +344,39 @@ def kv_decode_attention(q, k_cache, v_cache, layer, visible):
     def a_row(*shape):
         return pl.BlockSpec((None, *shape), lambda r, *_: (r,) + (0,) * len(shape))
 
+    q_spec = a_row(KV, N, D)
+    if D % 128:  # block-diagonal: head kv's rows against its own lanes alone
+        qq = jnp.einsum("rknd,kj->rknjd", qq, jnp.eye(KV, dtype=qq.dtype))
+        qq, q_spec = qq.reshape(R, KV * N, row), a_row(KV * N, row)
+    operands, in_specs = [qq], [q_spec]
+    if sink is not None:
+        # (H,) -> a number a query row of every KV head, 0 on the tile's
+        # zero rows: (KV x N, 1), the same for every cache row
+        rows = jnp.tile(sink.astype(jnp.float32).reshape(KV, 1, G), (1, Sq, 1))
+        rows = jnp.pad(rows.reshape(KV, Sq * G), ((0, 0), (0, N - Sq * G)))
+        operands.append(rows.reshape(KV * N, 1))
+        in_specs.append(pl.BlockSpec((KV * N, 1), lambda r, *_: (0, 0)))
+
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, scale=1.0 / math.sqrt(D),
-                          queries=Sq, group=G),
+                          queries=Sq, group=G, heads=KV, sunk=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(R,),
-            in_specs=[a_row(KV, N, D), pl.BlockSpec(memory_space=pl.ANY),
+            in_specs=[*in_specs, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=a_row(KV, N, D),
+            out_specs=a_row(KV, N, Dv),
             scratch_shapes=[
                 pltpu.VMEM((depth, span * block, row), k_cache.dtype),
-                pltpu.VMEM((depth, span * block, row), v_cache.dtype),
+                pltpu.VMEM((depth, span * block, KV * Dv), v_cache.dtype),
                 pltpu.SemaphoreType.DMA((2, depth)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((KV * N, 1), jnp.float32),
                 pltpu.VMEM((KV * N, 1), jnp.float32),
-                pltpu.VMEM((KV * N, D), jnp.float32),
+                pltpu.VMEM((KV * N, Dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((R, KV, N, D), k_cache.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, KV, N, Dv), k_cache.dtype),
         compiler_params=pltpu.CompilerParams(
             # the ring of copies runs from one row into the next
             dimension_semantics=("arbitrary",),
@@ -327,7 +387,7 @@ def kv_decode_attention(q, k_cache, v_cache, layer, visible):
         name="kv_decode",
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32), _work_list(last, block, span),
-        last, visible.reshape(R * Sq), qq, k_cache, v_cache,
+        last, visible.reshape(R * Sq), *operands, k_cache, v_cache,
     )
-    out = out[:, :, :Sq * G].reshape(R, KV, Sq, G, D)
-    return out.transpose(0, 2, 1, 3, 4).reshape(R, Sq, H, D)
+    out = out[:, :, :Sq * G].reshape(R, KV, Sq, G, Dv)
+    return out.transpose(0, 2, 1, 3, 4).reshape(R, Sq, H, Dv)

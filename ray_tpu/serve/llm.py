@@ -193,7 +193,14 @@ class LLMEngine:
     next request overwrites position by position, and, where the config
     has linear-attention layers (``layer_types``), a recurrent state that
     no length describes — the admission's prefill writes it from zero,
-    whatever the slot held (``llama._gated_delta_state``)."""
+    whatever the slot held (``llama._gated_delta_state``) — or, where it has
+    window layers beside full ones (``sliding_attention``), a second K/V pair
+    of ``window`` rolling slots a row, which the prefill fills with the
+    prompt's last keys (``llama._kind_attention``).  Refused in words: with
+    linear layers ``speculative_tokens``, ``diffusion_block`` and a
+    model-wide ``sliding_window``; with window layers ``speculative_tokens``
+    and ``diffusion_block`` (a block mask with a sliding window is not
+    written: ``llama._cache_mask``)."""
 
     def __init__(self, params, config, *, max_slots: int = 4,
                  max_len: int = 256, max_prompt_len: Optional[int] = None,
@@ -214,10 +221,16 @@ class LLMEngine:
         self.temperature = float(temperature or 0.0)
         if self.speculative not in (0, 1):
             raise ValueError("speculative_tokens is 0 or 1: one drafted token a step")
-        if config.layer_types:
+        def refuse(beside: str, refused: dict) -> None:
+            for option, why in refused.items():
+                if why:
+                    raise ValueError(
+                        f"{option} does not go with a config that has {beside}: {why}")
+
+        if config.linear_layers:
             # a linear-attention layer's state is ONE matrix a row, not a
             # row a position: nothing of it can be taken back or rewritten
-            refused = {
+            refuse("linear-attention layers (layer_types)", {
                 "speculative_tokens": self.speculative and (
                     "a rejected draft has already moved the row's recurrent "
                     "state, and there is no row of it to overwrite"),
@@ -225,15 +238,22 @@ class LLMEngine:
                     "every refining pass runs the block's positions again, and "
                     "the recurrent state can take a position once"),
                 "sliding_window": config.sliding_window and (
-                    "its rolling cache re-addresses positions (slot = position "
-                    "mod length), and the two caches have no common allocator"),
-            }
-            for option, why in refused.items():
-                if why:
-                    raise ValueError(
-                        f"{option} does not go with a config that has "
-                        f"linear-attention layers (layer_types): {why}"
-                    )
+                    "a model-wide window's rolling cache re-addresses positions "
+                    "(slot = position mod length) for every layer, and a "
+                    "recurrent state has no positions to re-address"),
+            })
+        if config.sliding is not None:
+            # a window layer keeps a row's last ``window`` keys and no others
+            refuse("window layers (layer_types: sliding_attention), whose cache is "
+                   f"{config.sliding.window} rolling slots a row", {
+                "speculative_tokens": self.speculative and (
+                    "a rejected draft's key has already overwritten the key "
+                    "``window`` positions back, which the row still needs"),
+                "diffusion_block": self.diffusion_block and (
+                    "a block mask with a sliding window is not written "
+                    "(llama._cache_mask), and every refining pass would write "
+                    "the block's keys over keys the row still needs"),
+            })
         if self.speculative and not config.mtp_layers:
             raise ValueError(
                 "speculative_tokens=1 needs a model with a multi-token-prediction "
@@ -287,11 +307,14 @@ class LLMEngine:
             self._key = jax.random.key(seed)
         self.max_slots = max_slots
         self.max_len = max_len
-        # Sliding-window models with an explicit prompt cap get a
-        # ROLLING cache: window + max_prompt - 1 slots serve ANY decode
+        # Models with a MODEL-WIDE sliding window and an explicit prompt cap
+        # get a ROLLING cache: window + max_prompt - 1 slots serve ANY decode
         # length up to max_len positions (the Mistral KV-memory win;
-        # llama.rolling_cache_len).  Without the cap — or without a
-        # window — the cache holds every position, as before.
+        # llama.rolling_cache_len).  Without the cap — or without such a
+        # window — the cache holds every position, as before.  Where the
+        # window is a layer KIND's (``config.sliding``), ``cache_len`` is the
+        # FULL layers' and ``llama.init_cache`` sizes the window layers' own
+        # K/V pair from the config: ``window`` rolling slots a row
         self.max_prompt_len = max_prompt_len or max_len
         if config.sliding_window and max_prompt_len:
             self.cache_len = min(
@@ -454,7 +477,22 @@ class LLMEngine:
         body updates the state at the decode step's shape: ``in_place``
         (the kernel, one pass over the layer's rows where they lie) or
         ``xla``, as ``ops/gated_delta.py:implementation`` reads it from
-        (rows, heads, d_k, d_v)."""
+        (rows, heads, d_k, d_v).  A config with window layers beside full
+        ones (``layer_types``: ``sliding_attention``), per kind (``full_`` /
+        ``swa_``) and summed over the kind's layers, a head:
+        ``*_keys_visible_step`` keys a decode step's rows could see (a window
+        layer's at most ``window``) and ``*_keys_read_step`` keys fetched for
+        them (whole blocks of 128 up to each row's last key under the kernel,
+        the slab under XLA's body; a window layer's slots are one block);
+        ``*_pairs_visible_run`` (query, key) pairs inside the prefills' masks —
+        the causal triangle, the window's band — and ``*_pairs_read_run``
+        pairs their attention computed scores for (whole live tiles under
+        ``ops/kv_prefill_attention.py``'s kernel): read over visible is each
+        body's over-compute; and which bodies the shapes chose,
+        ``kv_prefill_attention`` (``flash`` | ``dense``) and
+        ``kv_decode_attention`` ({``full``, ``swa``}: ``streamed`` |
+        ``slab``).  These count on the device (``attn_keys`` rides the
+        cache): ``kv_keys_*_step`` are not reported for such a config."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
@@ -462,7 +500,7 @@ class LLMEngine:
 
         out = {}
         names = [k for k in self.cache
-                 if k.startswith(("moe_", "dsa_", "mla_", "gdn_counts"))]
+                 if k.startswith(("moe_", "dsa_", "mla_", "gdn_counts", "attn_keys"))]
         if not names:
             return out
         async with self._cache_lock:
@@ -516,6 +554,25 @@ class LLMEngine:
             keys = host["mla_keys"]       # (layers, visible|read, 2)
             out["mla_keys_visible_step"] = wide_total(keys[:, 0])
             out["mla_keys_read_step"] = wide_total(keys[:, 1])
+        if "attn_keys" in host:
+            from ray_tpu.ops import kv_decode_attention, kv_prefill_attention
+
+            c = self.config
+            keys = host["attn_keys"]      # (full|window, visible|read, run|step, 2)
+            for i, kind in enumerate(("full", "swa")):
+                for j, what in enumerate(("visible", "read")):
+                    out[f"{kind}_pairs_{what}_run"] = wide_total(keys[i, j, 0])
+                    out[f"{kind}_keys_{what}_step"] = wide_total(keys[i, j, 1])
+            # which bodies the two programs were traced with, by their shapes
+            out["kv_prefill_attention"] = kv_prefill_attention.implementation(
+                c.head_dim, c.value_dim)
+            out["kv_decode_attention"] = {
+                kind: kv_decode_attention.implementation(
+                    length, c.head_dim, kv_heads=heads, v_head_dim=c.value_dim)
+                for kind, length, heads in (
+                    ("full", self.cache_len, c.num_kv_heads),
+                    ("swa", c.sliding.window, c.sliding.num_kv_heads))
+            }
         if "gdn_counts" in host:
             from ray_tpu.models.llama import GDN_COUNTS
 
@@ -697,7 +754,7 @@ class LLMEngine:
             for i in active:
                 pos[i] = self.slots[i].pos
                 request[i] = self.slots[i].request
-            if not cfg.latent:
+            if not (cfg.latent or cfg.sliding):  # those count on the device
                 self._count_kv_keys(int(pos[active].sum()) + len(active), pos)
         with _part(life, "llm.step.dispatch") as dispatch:
 
@@ -1030,10 +1087,16 @@ class LlamaDeployment:
         FULL layers only where the config has ``layer_types``, which adds
         the recurrent layers' ``gdn_rows_stepped``, ``gdn_tokens_scanned``,
         ``gdn_tokens_padded`` and ``gdn_state_bytes_step``
-        (``LLMEngine.cache_counters``).
+        (``LLMEngine.cache_counters``).  A config with window layers beside
+        full ones reports, in their place, ``full_`` / ``swa_`` x
+        ``keys_visible_step`` / ``keys_read_step`` / ``pairs_visible_run`` /
+        ``pairs_read_run`` and the bodies chosen, ``kv_prefill_attention`` and
+        ``kv_decode_attention`` (``LLMEngine.cache_counters``).
         ``cache_bytes``
         is what the cache holds, by entry (the module's layer is one of
-        ``ckv``'s)."""
+        ``ckv``'s; a window-and-full config's ``k`` / ``v`` are its full
+        layers' at ``max_len``, ``swa_k`` / ``swa_v`` its window layers' at
+        ``window`` slots)."""
         import jax
 
         from ray_tpu.models import llama
@@ -1063,7 +1126,8 @@ class LlamaDeployment:
             counters["diffusion_blocks_committed_total"] = (
                 self.engine.diffusion_commit_forwards_total
             )
-        if not (self.engine.config.latent or self.engine.speculative):
+        if not (self.engine.config.latent or self.engine.speculative
+                or self.engine.config.sliding):
             counters["kv_keys_visible_step"] = self.engine.kv_keys_visible_step
             counters["kv_keys_read_step"] = self.engine.kv_keys_read_step
         return {

@@ -18,6 +18,7 @@ block shapes the cell sends.
 """
 
 import json
+import math
 import os
 import re
 from collections import Counter
@@ -247,6 +248,61 @@ def test_shortcut_decode_step_reads_every_weight_where_it_lies(
                 "bitcast", "parameter", "get-tuple-element"):
             made.append(line.strip()[:120])
     assert not made, made
+
+
+@pytest.mark.limit(300)
+def test_window_and_full_decode_step_fetches_no_full_layer_slab_whole(
+    v5e_chip, compiled_not_interpreted, monkeypatch
+):
+    """MiMo-V2-Flash's decode step at the cell's shape (7 layers, 64 slots x
+    13,312): both kinds' attention is the ``kv_decode`` kernel on the carried
+    cache — seven calls, the full layers' K with its heads off a lane tile's
+    edge (4 x 192) — the donated cache is the output's buffer, and nothing
+    makes a full layer's slab (64 x 13,312 x 768 bf16, 1.3 GB): the program's
+    temporaries stay under 64 MB.  The prefills at the cell's two prompt
+    lengths compile with the ``kv_prefill`` kernel and fit beside the weights
+    and the cache."""
+    import functools
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops import grouped_matmul, kv_decode_attention, kv_prefill_attention
+
+    for mod in (kv_decode_attention, kv_prefill_attention, grouped_matmul):
+        if hasattr(mod, "_interpret"):
+            monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cfg = llama.LlamaConfig.mimo_v2_flash(
+        num_layers=7, layer_types=(llama.FULL,) + (llama.SLIDING,) * 5 + (llama.FULL,),
+        vocab_size=19072, experts_held=16, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, max_len = 64, 13312
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(llama.init, config=cfg),
+                                    jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(functools.partial(llama.init_cache, cfg, slots, max_len)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=v5e_chip)
+    compiled = llama.decode_step_rowwise.lower(params, rows, cache, rows, cfg).compile()
+    mem = compiled.memory_analysis()
+    state = sum(math.prod(cache[k].shape) * 2 for k in ("k", "v", "swa_k", "swa_v"))
+    assert state == 4_362_076_160 + 209_715_200
+    assert mem.alias_size_in_bytes >= state, mem
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_PROGRAM_LIMIT - MARGIN, mem
+    text = compiled.as_text()
+    assert len(re.findall(r"%kv_decode[.\d]* = .*?custom-call\(", text)) == 7
+    # no instruction makes a full layer's slab of keys or of values
+    assert not re.search(r"= bf16\[64,13312,(768|512)\]", text)
+    for n in (2048, 12288):
+        prompt = jax.ShapeDtypeStruct((1, n), jnp.int32, sharding=v5e_chip)
+        slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip)
+        prefill = llama.prefill_into_slot.lower(params, prompt, cache, slot, cfg).compile()
+        mem = prefill.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_PROGRAM_LIMIT - MARGIN, mem
+        assert len(re.findall(r"%kv_prefill[.\d]* = .*?custom-call\(", prefill.as_text())) == 7
+        # no (S, S) score of any head count reaches memory
+        assert not re.search(rf"f32\[[\d,]*{n},{n}\]", prefill.as_text())
 
 
 # ---- the training cells' step programs ----------------------------------
