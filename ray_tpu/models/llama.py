@@ -2305,11 +2305,14 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     of no more than ``index_topk`` keys takes what is visible).
     ``ops/latent_prefill_attention.py`` says by the
     run's shape which body attends (``implementation``): ``flash`` — a run
-    of whole tiles with heads of whole 128-lane tiles: the blocks'
-    selections are laid into ONE (Sq, Sq) int8 mask and one Pallas kernel
+    of whole tiles, four or more, with value heads of whole 128-lane tiles:
+    the blocks' selections are laid into ONE (Sq, Sq) int8 mask and one kernel
     (online softmax over the live causal tiles) reads it, so no score ever
-    reaches HBM; ``blocked`` — everything else (tiny and ragged runs, a
-    192-wide key head): XLA's body, each block of queries' float32 scores
+    reaches HBM; a key head that is no whole lane tiles (LongCat's and
+    JoyAI's nope 128 | rope 64 = 192) is handed over with zeros behind it
+    up to one, written where q and k are put together; ``blocked`` —
+    everything else (tiny, short and ragged runs, a value head of half a
+    lane tile): XLA's body, each block of queries' float32 scores
     (heads x block x keys) written, masked, softmaxed and multiplied out
     inside the same loop as its selection.  The same selection and the
     same mathematics either way; ``collect`` takes the same body.
@@ -2456,19 +2459,25 @@ def _latent_attention(h, p, state, slot, positions, config: LlamaConfig,
     aux["ckv_rows"] = new_ckv[0]
     if K:
         aux["ik_rows"] = ki[0]
+    H = c.num_heads
+    flash = latent_prefill_attention.implementation(
+        Sq, Dn + Dr, c.v_head_dim
+    ) == "flash"
+    # the kernel reads key heads of whole lane tiles: the zeros behind a
+    # head that is none (192 -> 256) are written where the head is put
+    # together, not in a pass of their own; XLA's body is given none
+    behind = latent_prefill_attention.lanes_behind(Dn + Dr) if flash else 0
+    zeros = [jnp.zeros((Sq, H, behind), dt)] if behind else []
     with jax.named_scope("mla_proj"):
         lat = new_ckv[0, :, :C]
         k_nope = jnp.einsum("sc,chn->shn", lat, p["w_kb"].astype(dt))
         keys = jnp.concatenate([
             k_nope,
-            jnp.broadcast_to(new_ckv[0, :, None, C:C + Dr], (Sq, c.num_heads, Dr)),
-        ], axis=-1)                                                 # (Sq, H, Dn+Dr)
+            jnp.broadcast_to(new_ckv[0, :, None, C:C + Dr], (Sq, H, Dr)),
+            *zeros,
+        ], axis=-1)                                                 # (Sq, H, Dn+Dr[+behind])
         values = jnp.einsum("sc,chv->shv", lat, p["w_vb"].astype(dt))
-        qq = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)       # (Sq, H, Dn+Dr)
-    H = c.num_heads
-    flash = latent_prefill_attention.implementation(
-        Sq, Dn + Dr, c.v_head_dim
-    ) == "flash"
+        qq = jnp.concatenate([q_nope[0], q_rope[0], *zeros], axis=-1)
     blk = max(8, min(_QUERY_BLOCK, Sq, _SCORE_ELEMENTS // (H * Sq)))
     groups = _CAUSAL_GROUPS if Sq % (_CAUSAL_GROUPS * blk) == 0 else 1
     per = Sq // groups

@@ -9,8 +9,10 @@ queries, keys wider than values, a window and a sink in one flash kernel
 over the live tiles; XLA's dense body for toy heads),
 ``latent_decode_attention`` (a latent config's decode step) and
 ``latent_prefill_attention`` (a latent config's prefill: the flash kernel
-for a run of whole 512-token tiles with heads of whole 128-lane tiles, XLA's
-blocked body in ``models/llama.py:_latent_attention`` otherwise).
+for a run of four or more whole 512-token tiles with value heads of whole
+128-lane tiles — a key head of another width goes padded with zeros to whole
+lane tiles —
+XLA's blocked body in ``models/llama.py:_latent_attention`` otherwise).
 ``topk_mask`` is a sparse-attention indexer's exact top-k as a mask: by
 shape again (``implementation``), a Pallas kernel that counts its way to
 the k-th score and the tie rule, or ``lax.top_k`` and a running count.

@@ -16,12 +16,17 @@ Two bodies, chosen by the run's shape in ONE place (``implementation``):
   the trace's op line): a tile of ``TILE`` queries against the tiles of
   ``TILE`` keys at or before it, blocked online softmax with running max /
   sum / accumulator in float32 scratch, so nothing of size heads x queries x
-  keys is ever in HBM.  The grid is (heads / ``HEADS_A_STEP``, live tile
+  keys is ever in HBM.  The grid is (heads / heads a step, live tile
   pairs): the pairs are a list (two scalar-prefetch operands made at trace
   time), so a tile behind the diagonal costs neither a fetch nor a grid
   step.  q, k, v and the output stay (Sq, H x D) as the projections leave
-  them — a head is a 128-lane-aligned column block, which is why both head
-  sizes must be whole lane tiles — and no transpose is made.  The mask's
+  them — a head is a 128-lane-aligned column block, which is why the value
+  head must be whole lane tiles and a key head that is none (LongCat-Flash's
+  and JoyAI-LLM-Flash's nope 128 | rope 64 = 192) comes with zeros behind
+  it up to one (``lanes_behind``: 192 -> 256; zeros add nothing to a score,
+  and ``scale`` stays the published head's) — and no transpose is made.
+  The model writes those zeros in the ``concatenate`` that puts q and k
+  together, so no pass of its own is made over them.  The mask's
   (TILE, TILE) tile is read once a grid step for all its heads.  A query
   with NO selected key inside a tile adds ``exp(0)`` a key to its running
   sum; the first tile that holds one of its keys wipes that (``exp(NEG_INF -
@@ -30,8 +35,8 @@ Two bodies, chosen by the run's shape in ONE place (``implementation``):
   Without a mask operand visibility is causal, from an iota in the kernel.
 * ``blocked`` — XLA's body in ``_latent_attention`` (blocks of queries,
   float32 scores of a block in HBM, four causal groups): a run that is no
-  whole number of tiles, or heads that are no whole lane tiles (tier-1's
-  tiny runs, ragged lengths, JoyAI's 192-wide keys).
+  whole number of tiles, or a value head that is no whole lane tiles
+  (tier-1's tiny runs, ragged lengths).
 
 ``pairs_computed`` is what the kernel computes scores for — whole live
 tiles — and what ``dsa_keys``' run slot of keys read counts
@@ -67,6 +72,53 @@ D) blocks, the transposes left to XLA's layout assignment) make the same
 prefill 183.5 / 601.4 ms against 186.9 / 602.1: the copies of q and k into
 (Sq, H x D) cost 1-3 ms a prefill and are not worth a second layout.
 
+The same sweep at LongCat-Flash's and JoyAI-LLM-Flash's widths (a v5e, PR
+56: key heads of 192 with zeros up to 256 | values of 128, causal with no
+mask operand, bfloat16; ms ONE call, the kernel's own device time in a
+profiler trace; 64 heads at 4,096 / 2,048 tokens, then 32 heads at 1,536 /
+512; "-" where the tile does not divide the run, "vmem" where the compiler
+refuses the step's blocks):
+
+    128 x 128 x 4   17.17 / 4.44 / 1.302 / 0.176     512 x 512 x 1     5.05 / 1.448 / 0.451 / 0.082
+    256 x 256 x 2    8.04 / 2.16 / 0.649 / 0.100     512 x 512 x 2     4.84 / 1.393 / 0.432 / 0.079
+    256 x 256 x 8    7.22 / 1.96 / 0.596 / 0.091     512 x 512 x 4   **3.83 / 1.111 / 0.348 / 0.064**
+    512 x 256 x 4    7.47 / 2.12 / 0.652 / 0.115     512 x 512 x 8     vmem
+    256 x 512 x 2    4.39 / 1.26 / 0.401 / 0.073     512 x 1,024 x 2   3.49 / 1.091 / - / -
+    256 x 512 x 4    4.16 / 1.20 / 0.385 / 0.070     512 x 1,024 x 4   vmem
+    256 x 512 x 8    4.16 / 1.21 / 0.386 / 0.069     1,024 x 512 x 2   5.77 / 1.774 / - / -
+    256 x 1,024 x 4  3.71 / 1.16 / - / -             1,024 x 1,024 x 2 3.39 / 1.063 / - / -
+
+The key tile's width decides it here too, and the heads a step now matter:
+with the accumulator half as wide, FOUR heads a step are 21% faster than
+two (at 256 | 256 two and four were level), so a step computes
+``LANES_A_STEP`` = 512 value lanes whatever the head's width — 2 heads of
+256, 4 of 128; eight do not fit the fast memory.  Inside a traced prefill
+the call takes 3.85 / 1.11 ms a layer at 4,096 / 2,048 tokens (4.87 / 1.39
+at two heads a step): 0.344 / 0.086 TFLOP of causal work at the published
+widths at 89 / 77 TFLOP/s, 157 / 151 G (query, key) pairs a second as
+computed — the per-pair vector work bounds it, not the matmuls.  A
+1,024-wide key tile is 9-11% faster still at 4,096 tokens and 2-4% at
+2,048, and is NOT taken: it is 0.35 ms of a layer's 24 ms there, and
+``TILE`` is also the grain of the run lengths the kernel takes (1,536 and
+2,560 are no whole number of 1,024).
+
+Why ``MIN_TILES``: XLA's blocked body costs less a pair the shorter the run
+(its float32 score blocks are small), the kernel the same at every length.
+One whole prefill through XLA's body against the kernel (four heads a
+step), ms on the device, PR 56:
+
+    tokens            512     1,024   1,536   2,048   3,072   4,096
+    LongCat, XLA's    25.14   44.10   66.15   89.70           258.52
+    LongCat, kernel   26.06   43.80   65.17   87.35           193.94
+    JoyAI, XLA's      24.60   47.01   67.70   98.53   168.35
+    JoyAI, kernel     26.87   46.19   67.87   96.22   161.13
+
+(LongCat-Flash at 4 of 28 layers, 8 attentions of 64 heads; JoyAI-LLM-Flash's
+40 of 32.)  At one tile the kernel loses 4-9% of the prefill, at two and
+three the bodies are level (-1.7 ... +0.3%), from four tiles the kernel wins
+(2.3-2.6% at 2,048, 4.3% at 3,072, 25% at 4,096): the rule starts at four, so
+JoyAI's cell (512 and 1,536 tokens) keeps the programs it had.
+
 Off the chip the kernel runs in Pallas interpret mode (``_interpret`` of
 ``ops/flash_attention.py``, as its kernels do), so the tests run the very
 kernel.
@@ -86,23 +138,46 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops.flash_attention import _interpret
 
 NEG_INF = -1e30
+LANES = 128
 #: queries a tile, and keys a tile.  2,560 (the reference comparison's
 #: prompt), 4,096 and 8,192 are whole numbers of it; it computes 1.125 /
 #: 1.0625 times the visible pairs at 4,096 / 8,192 (the module's text has
 #: the sweep)
 TILE = 512
-#: heads a grid step computes, one after another, on one fetch of the
-#: mask's tile: 22.00 / 20.41 / 20.43 ms a call at 1 / 2 / 4
-HEADS_A_STEP = 2
+#: value lanes a grid step computes, one head after another, on one fetch of
+#: the mask's tile — a step's output block and accumulator are (TILE, 512)
+#: whatever the value head's width: 2 heads of 256 (22.00 / 20.41 / 20.43 ms a
+#: call at 1 / 2 / 4), 4 of 128 (the module's text, PR 56)
+LANES_A_STEP = 512
+#: the shortest run the kernel takes, in tiles: under it XLA's blocked body is
+#: as fast or faster (the module's text, PR 56)
+MIN_TILES = 4
+
+
+def _fits(run_len: int, v_head_dim: int) -> bool:
+    """What the kernel can compute: whole tiles of tokens, value heads of
+    whole lane tiles."""
+    return run_len % TILE == 0 and v_head_dim % LANES == 0
 
 
 def implementation(run_len: int, qk_head_dim: int, v_head_dim: int) -> str:
     """Which body a run of ``run_len`` tokens with heads of ``qk_head_dim``
     (keys) / ``v_head_dim`` (values) traces: ``"flash"`` — the kernel — for a
-    whole number of tiles and whole-lane-tile heads, else ``"blocked"``."""
-    if run_len % TILE == 0 and qk_head_dim % 128 == 0 and v_head_dim % 128 == 0:
+    whole number of tiles, at least ``MIN_TILES`` of them, and value heads of
+    whole lane tiles, else ``"blocked"``.  The key head may be any width: the
+    kernel is given it with zeros up to whole lane tiles (``lanes_behind``).
+    The rule reads the run's LENGTH because the kernel costs the same a
+    (query, key) pair at every length and XLA's body less the shorter the
+    run: under 2,048 tokens the blocked body wins (the module's text)."""
+    if _fits(run_len, v_head_dim) and run_len >= MIN_TILES * TILE:
         return "flash"
     return "blocked"
+
+
+def lanes_behind(qk_head_dim: int) -> int:
+    """Zeros the kernel wants behind each key (and query) head: up to the
+    next whole lane tile (192 -> 64, 256 -> 0)."""
+    return -qk_head_dim % LANES
 
 
 def _live_pairs(run_len: int):
@@ -169,18 +244,26 @@ def latent_prefill_attention(q, k, v, mask=None, *, scale: float):
     """The flash body.  q, k (Sq, H, Dqk), v (Sq, H, Dv), mask (Sq, Sq)
     int8 (non-zero: query t attends to key s; inside the causal triangle,
     at least one key a query) or None for causal -> (Sq, H, Dv) in ``v``'s
-    dtype.  Sq is a whole number of ``TILE``, Dqk and Dv of 128 lanes."""
+    dtype.  Sq is a whole number of ``TILE`` (any number: ``MIN_TILES`` is
+    ``implementation``'s to hold), Dv of 128 lanes; q and k that
+    are no whole lane tiles wide are padded here (``lanes_behind``; the model
+    hands them over padded, in the write that assembles them), and ``scale``
+    is the caller's, of the head as published."""
     Sq, H, Dqk = q.shape
     Dv = v.shape[-1]
-    if (implementation(Sq, Dqk, Dv) != "flash" or k.shape != q.shape
+    if (not _fits(Sq, Dv) or k.shape != q.shape
             or v.shape[:2] != (Sq, H)
             or (mask is not None and mask.shape != (Sq, Sq))):
         raise ValueError(
-            f"the prefill kernel wants whole tiles of {TILE} and heads of whole "
-            f"128-lane tiles: q {q.shape}, k {k.shape}, v {v.shape}, mask "
+            f"the prefill kernel wants whole tiles of {TILE} and value heads of "
+            f"whole 128-lane tiles: q {q.shape}, k {k.shape}, v {v.shape}, mask "
             f"{None if mask is None else mask.shape}"
         )
-    heads = HEADS_A_STEP if H % HEADS_A_STEP == 0 else 1
+    behind = lanes_behind(Dqk)
+    if behind:  # zeros add nothing to a score
+        q, k = (jnp.pad(x, ((0, 0), (0, 0), (0, behind))) for x in (q, k))
+        Dqk += behind
+    heads = max(n for n in range(1, max(1, LANES_A_STEP // Dv) + 1) if H % n == 0)
     pairs = _live_pairs(Sq)
 
     def spec(width, side):  # a (query: 0, key: 1) tile of an (Sq, H x width) array

@@ -460,7 +460,12 @@ class LLMEngine:
         ``counted`` (the kernel) or ``sorted`` — as
         ``ops/topk_mask.py:implementation`` reads it from (rows, cache
         length, ``index_topk``); a prefill's blocks ask it again by their
-        own shapes.  Latent attention
+        own shapes.  Every latent config: ``latent_prefill_attention``, the
+        body a run of at least ``MIN_TILES`` whole tiles takes at its head
+        widths (``flash`` — the kernel — or ``blocked``:
+        ``ops/latent_prefill_attention.py:implementation``; a shorter run
+        or one that is no whole tiles keeps ``blocked``).
+        Latent attention
         without an indexer: ``mla_keys_visible_step`` (keys a step's
         rows could see — a row's last query's, the others see prefixes —
         over every layer and row) and ``mla_keys_read_step`` (latent rows
@@ -496,7 +501,12 @@ class LLMEngine:
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
-        from ray_tpu.ops import gated_delta, grouped_matmul, topk_mask
+        from ray_tpu.ops import (
+            gated_delta,
+            grouped_matmul,
+            latent_prefill_attention,
+            topk_mask,
+        )
 
         out = {}
         names = [k for k in self.cache
@@ -554,6 +564,12 @@ class LLMEngine:
             keys = host["mla_keys"]       # (layers, visible|read, 2)
             out["mla_keys_visible_step"] = wide_total(keys[:, 0])
             out["mla_keys_read_step"] = wide_total(keys[:, 1])
+        if self.config.latent:
+            c = self.config
+            # the body a long enough run of whole tiles takes at these head widths
+            out["latent_prefill_attention"] = latent_prefill_attention.implementation(
+                latent_prefill_attention.MIN_TILES * latent_prefill_attention.TILE,
+                c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim)
         if "attn_keys" in host:
             from ray_tpu.ops import kv_decode_attention, kv_prefill_attention
 
@@ -1061,7 +1077,9 @@ class LlamaDeployment:
         step's shape: ``counted`` or ``sorted``), and the gauges
         ``llm_dsa_selected_share`` and (experts held here)
         ``llm_moe_held_assignment_share``.  Latent attention without an
-        indexer: ``mla_keys_visible_step`` / ``mla_keys_read_step``.  A
+        indexer: ``mla_keys_visible_step`` / ``mla_keys_read_step``; with or
+        without one, ``latent_prefill_attention`` (which body a prefill of
+        whole tiles, 2,048 tokens or more, takes: ``flash`` or ``blocked``).  A
         drafting deployment: ``spec_drafted_total`` (live rows its steps
         drafted for), ``spec_accepted_total``, ``spec_tokens_emitted_total``
         (tokens delivered from decode steps: 1 + accepted a live row-step,

@@ -12,9 +12,10 @@ width: GPT-2-124M at B=8 x S=1024 (12 heads x 64) and the Llama family
 at one 2048-token sequence (32 heads x 128); and, whole, the two
 training cells' step programs (GPT-2 medium on one chip, GPT-2 XL on
 the four of the 2x2 under `fsdp=4`): the forward kernel once a layer,
-and XL inside its chips' memory; and GLM-5's prefill attention kernel
-at 64 heads of 256 | 256, and its top-2,048 selection kernel at the six
-block shapes the cell sends.
+and XL inside its chips' memory; and the latent configs' prefill
+attention kernel at GLM-5's 64 heads of 256 | 256 and LongCat's (64) and
+JoyAI's (32) of 192 | 128, and GLM-5's top-2,048 selection kernel at the
+six block shapes the cell sends.
 """
 
 import json
@@ -115,32 +116,47 @@ def test_flash_kernel_compiles_for_v5e(
     assert f"bf16[{B},{H},{S},{D}]" not in text
 
 
-@pytest.mark.parametrize("run_len,masked", [
-    (8192, True), (2560, True),     # the cell's longest prompt, the comparison's
-    (4096, False),                  # no indexer: causal from an iota
+@pytest.mark.parametrize("run_len,heads,qk,v,masked", [
+    (8192, 64, 256, 256, True),     # GLM-5: the cell's longest prompt,
+    (2560, 64, 256, 256, True),     # the comparison's,
+    (4096, 64, 256, 256, False),    # and no indexer: causal from an iota
+    (4096, 64, 192, 128, False),    # LongCat's two prompt lengths: keys of
+    (2048, 64, 192, 128, False),    # 192 padded to 256, no indexer
+    (2048, 32, 192, 128, False),    # JoyAI's 32 heads at the shortest run the
+                                    # kernel takes (its cell's prompts, 512 and
+                                    # 1,536 tokens, keep XLA's body)
 ])
 def test_latent_prefill_kernel_compiles_for_v5e(
-    v5e_chip, compiled_not_interpreted, monkeypatch, run_len, masked
+    v5e_chip, compiled_not_interpreted, monkeypatch, run_len, heads, qk, v, masked
 ):
     """GLM-5's prefill attention at its published widths, 64 heads of 256 |
-    256 over a run of whole tiles, the selection an int8 mask operand
+    256 over a run of whole tiles, the selection an int8 mask operand, and
+    LongCat's (64 heads) and JoyAI's (32) of 192 | 128, causal, four heads a
+    grid step
     (``ops/latent_prefill_attention.py``): one kernel, inside its fast
     memory, and no float32 score array of heads x queries x keys."""
     monkeypatch.setattr(lpa, "_interpret", lambda: False)
-    assert lpa.implementation(run_len, 256, 256) == "flash"
-    x = jax.ShapeDtypeStruct((run_len, 64, 256), jnp.bfloat16, sharding=v5e_chip)
+    assert lpa.implementation(run_len, qk, v) == "flash"
+    # as the model hands them over: the zeros behind a head already written
+    padded = qk + lpa.lanes_behind(qk)
+    x = jax.ShapeDtypeStruct((run_len, heads, padded), jnp.bfloat16, sharding=v5e_chip)
+    values = jax.ShapeDtypeStruct((run_len, heads, v), jnp.bfloat16, sharding=v5e_chip)
     mask = jax.ShapeDtypeStruct((run_len, run_len), jnp.int8, sharding=v5e_chip)
 
     def attend(q, k, v, *mask):
-        return lpa.latent_prefill_attention(q, k, v, *mask, scale=0.0625)
+        return lpa.latent_prefill_attention(q, k, v, *mask, scale=qk ** -0.5)
 
-    args = (x, x, x, mask) if masked else (x, x, x)
+    args = (x, x, values, mask) if masked else (x, x, values)
     compiled = jax.jit(attend).lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
-    # at most a copy of q, k and v each (these are parameters in the default
-    # layout; inside the model their producers write the kernel's): the
-    # float32 scores would be 4 x 64 x run_len^2 bytes
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.1 * 64 * run_len * 256 * 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[{heads},{run_len},{run_len}]" not in text
+    # at most a copy of q, k and v each, at the padded width (these are
+    # parameters in the default layout; inside the model their producers
+    # write the kernel's): the float32 scores would be 4 x heads x run_len^2
+    # bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.035 * heads * run_len * (2 * padded + v) * 2)
 
 
 @pytest.mark.parametrize("rows,keys", [

@@ -1,7 +1,9 @@
 """A latent config's prefill attention (``ops/latent_prefill_attention.py``):
 the Pallas kernel, in interpret mode, against XLA's blocked body; which body
-a run takes; and ``models/llama.py``'s run through either — the same
-selection, the same logits with and without ``collect``, the counters."""
+a run takes (a key head of any width: zeros behind it up to whole lane
+tiles); and ``models/llama.py``'s run through either — the same selection,
+the same logits with and without ``collect``, the counters, the body's name
+in ``stats()``."""
 
 import jax
 import jax.numpy as jnp
@@ -48,12 +50,15 @@ def tile(monkeypatch):
 
 @pytest.mark.parametrize("tiles", [2, 3])
 @pytest.mark.parametrize("masked", [True, False], ids=["selection", "causal"])
-@pytest.mark.parametrize("heads", [2, 3], ids=["two_a_step", "one_a_step"])
-def test_the_kernel_is_xlas_body(tile, tiles, masked, heads):
+@pytest.mark.parametrize("heads", [2, 3, 5], ids=["two_a_step", "three_a_step", "one_a_step"])
+@pytest.mark.parametrize("Dqk", [256, 192], ids=["keys256", "keys192"])
+def test_the_kernel_is_xlas_body(tile, tiles, masked, heads, Dqk):
     """Tiles of 128 at 2 and 3 a side (3 and 6 live pairs), heads of 256 |
-    128, two a grid step or (an odd number of them) one: with the selection
-    as the mask operand, and causal without one."""
-    Dqk, Dv = 256, 128
+    128 and of 192 | 128 (a key head of no whole lane tiles: zeros behind it
+    up to 256), as many a grid step as divide the heads and make at most 512
+    value lanes (of 128: up to four): with the selection as the mask operand,
+    and causal without one."""
+    Dv = 128
     Sq = tiles * tile
     ks = jax.random.split(jax.random.key(tiles), 3)
     q = jax.random.normal(ks[0], (Sq, heads, Dqk), jnp.float32)
@@ -84,8 +89,10 @@ def test_the_kernel_refuses_what_implementation_would_not_send(tile):
     x = jnp.zeros((256, 2, 128), jnp.float32)
     with pytest.raises(ValueError, match="whole tiles"):
         lpa.latent_prefill_attention(x[:192], x[:192], x[:192], scale=1.0)
-    with pytest.raises(ValueError, match="128-lane"):
-        lpa.latent_prefill_attention(x[..., :96], x[..., :96], x, scale=1.0)
+    with pytest.raises(ValueError, match="128-lane"):     # a 64-wide value head
+        lpa.latent_prefill_attention(x, x, x[..., :64], scale=1.0)
+    with pytest.raises(ValueError, match="128-lane"):     # keys of another width than queries
+        lpa.latent_prefill_attention(x[..., :96], x, x, scale=1.0)
     with pytest.raises(ValueError, match="mask"):
         lpa.latent_prefill_attention(x, x, x, jnp.ones((256, 128), jnp.int8), scale=1.0)
 
@@ -94,9 +101,13 @@ def test_the_kernel_refuses_what_implementation_would_not_send(tile):
     (2560, 256, 256, "flash"),      # the reference comparison's prompt (GLM-5)
     (4096, 256, 256, "flash"),      # the cell's two prompt lengths
     (8192, 256, 256, "flash"),
-    (512, 128, 128, "flash"),       # one tile
-    (512, 192, 128, "blocked"),     # JoyAI: a key head of 192 is no whole lane tile
+    (2048, 256, 256, "flash"),      # four tiles: the shortest run it takes
+    (2048, 192, 128, "flash"),      # LongCat's two prompt lengths: a key head
+    (4096, 192, 128, "flash"),      # of 192 goes padded to 256
+    (512, 128, 128, "blocked"),     # one tile: under four XLA's body is faster
+    (512, 192, 128, "blocked"),     # JoyAI's two prompt lengths
     (1536, 192, 128, "blocked"),
+    (4000, 192, 128, "blocked"),
     (4000, 256, 256, "blocked"),    # a ragged length
     (24, 20, 16, "blocked"),        # tier-1's tiny runs
     (256, 256, 256, "blocked"),     # less than a tile
@@ -104,6 +115,11 @@ def test_the_kernel_refuses_what_implementation_would_not_send(tile):
 ])
 def test_the_body_goes_by_the_runs_shape(run_len, qk, v, want):
     assert lpa.implementation(run_len, qk, v) == want
+
+
+@pytest.mark.parametrize("qk,behind", [(192, 64), (256, 0), (128, 0), (96, 32), (20, 108)])
+def test_zeros_fill_a_key_head_up_to_whole_lane_tiles(qk, behind):
+    assert lpa.lanes_behind(qk) == behind
 
 
 @pytest.mark.parametrize("run_len,tiles", [(512, 1), (2560, 15), (4096, 36), (8192, 136)])
@@ -179,14 +195,24 @@ def test_a_run_with_its_choices_kept_is_the_served_run_bit_for_bit(body):
     assert (hit[:, 0].sum(-1) == np.minimum(np.arange(RUN) + 1, TOPK)).all()
 
 
-def test_both_bodies_select_the_same_keys_and_give_the_same_logits(monkeypatch):
-    cfg = wide()
+NO_INDEXER = dict(index_topk=0, index_n_heads=0, index_head_dim=0)
+
+
+@pytest.mark.parametrize("how", [
+    {}, dict(qk_nope_head_dim=64), dict(qk_nope_head_dim=64, **NO_INDEXER),
+], ids=["keys128", "keys96", "keys96_causal"])
+def test_both_bodies_select_the_same_keys_and_give_the_same_logits(how, monkeypatch):
+    """Also for a key head of nope 64 | rope 32 = 96 over values of 128
+    (LongCat's and JoyAI's 128 | 64 = 192 at toy width): the kernel on heads
+    padded to 128 lanes, XLA's body on the 96 as they are."""
+    cfg = wide(**how)
     outs = {}
     for which, tile in (("flash", 64), ("blocked", 1 << 20)):
         monkeypatch.setattr(lpa, "TILE", tile)
         for program in _RUN_PROGRAMS:
             program.clear_cache()
-        assert lpa.implementation(RUN, 128, cfg.v_head_dim) == which
+        assert lpa.implementation(
+            RUN, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim) == which
         logits, cache, chose = run_of(cfg, collect=True)
         outs[which] = (np.asarray(logits), np.asarray(cache["ckv"]), np.asarray(chose["selected"]))
     for program in _RUN_PROGRAMS:
@@ -214,19 +240,24 @@ def test_a_run_counts_keys_visible_selected_and_read(body):
 
 
 @both_bodies
-def test_a_run_without_an_indexer_attends_causally(body, monkeypatch):
+@pytest.mark.parametrize("nope", [96, 64], ids=["keys128", "keys96"])
+def test_a_run_without_an_indexer_attends_causally(body, nope, monkeypatch):
     """``index_topk`` unset: the kernel is given NO mask operand (an indexer's
     run hands it the (Sq, Sq) int8 selection), and ``collect`` hands back the
-    triangle with the served program's logits."""
-    masks = []
+    triangle with the served program's logits.  A key head of nope 64 | rope
+    32 = 96 (LongCat's and JoyAI's 128 | 64 = 192 at toy width) reaches the
+    kernel as 128 lanes, the zeros written by the model."""
+    masks, widths, scales = [], [], []
     kernel = lpa.latent_prefill_attention
 
     def spy(q, k, v, mask=None, **kw):
         masks.append(mask if mask is None else (mask.shape, mask.dtype))
+        widths.append((q.shape[-1], k.shape[-1], v.shape[-1]))
+        scales.append(kw["scale"])
         return kernel(q, k, v, mask, **kw)
 
     monkeypatch.setattr(lpa, "latent_prefill_attention", spy)
-    cfg = wide(index_topk=0, index_n_heads=0, index_head_dim=0)
+    cfg = wide(qk_nope_head_dim=nope, **NO_INDEXER)
     logits, cache = run_of(cfg, collect=False)
     twin, _, chose = run_of(cfg, collect=True)
     assert np.array_equal(np.asarray(logits), np.asarray(twin))
@@ -236,5 +267,31 @@ def test_a_run_without_an_indexer_attends_causally(body, monkeypatch):
     assert "dsa_keys" not in cache
     # traced once a parameter stack (dense blocks, expert blocks) a program
     assert masks == ([None] * 4 if body == "flash" else [])
+    assert widths == [(128, 128, 128)] * len(masks)
+    assert scales == [pytest.approx((nope + 32) ** -0.5)] * len(masks)   # the head's as published
     run_of(wide(), collect=False)
     assert masks[4:] == ([((RUN, RUN), jnp.int8)] * 2 if body == "flash" else [])
+
+
+@pytest.mark.parametrize("how,want", [
+    (dict(NO_INDEXER, qk_nope_head_dim=64), "flash"),   # keys of 96: padded to 128
+    (NO_INDEXER, "flash"),                              # keys of 128
+    ({}, "flash"),                                      # an indexer's config says it too
+    (dict(NO_INDEXER, v_head_dim=64), "blocked"),       # a value head of half a lane tile
+], ids=["keys96", "keys128", "indexer", "values64"])
+def test_stats_name_the_body_a_whole_tile_run_takes(how, want):
+    """``stats()["latent_prefill_attention"]`` of every latent config: what
+    ``implementation`` says for the shortest run the kernel takes at the
+    config's head widths."""
+    import asyncio
+
+    from ray_tpu.serve.llm import LlamaDeployment
+
+    cfg = wide(max_seq_len=64, **how)
+    params = llama.init(jax.random.key(3), cfg)
+    dep = LlamaDeployment.func_or_class(
+        config=cfg, weights_loader=lambda: params, max_slots=2, max_len=32)
+    stats = asyncio.run(dep.stats())
+    assert stats["latent_prefill_attention"] == want == lpa.implementation(
+        lpa.MIN_TILES * lpa.TILE, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+        cfg.v_head_dim)
