@@ -23,6 +23,10 @@ TRACED_DEVICE_KEYS = ("window_s", "busy_s")
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+#: the most entries ``per_layer`` may hold: the driver's limit for the list
+#: ("``per_layer``: 1 to 128 metrics of single layers", the contract every
+#: builder is handed; PR 38 was refused ``manifest_invalid`` at 130)
+PER_LAYER_LIMIT = 128
 
 
 class ContractError(Exception):
@@ -178,6 +182,16 @@ def validate(line: str, workload: str, trace: int,
                 if (not isinstance(row, list) or len(row) != 2
                         or not isinstance(row[0], str) or not _number(row[1])):
                     raise ContractError(f"breakdown.{k} row {row!r} is not [name, seconds]")
+    if "compared" in obj:
+        # the numbers the comparison with the reference read, each beside
+        # its limit: the driver ignores the key, a reader of a refused run
+        # does not; it comes last
+        if list(obj)[-1] != "compared" or not isinstance(obj["compared"], dict):
+            raise ContractError("'compared' is not the line's last key, an object")
+        for name, pair in obj["compared"].items():
+            if (not isinstance(pair, dict) or set(pair) != {"value", "limit"}
+                    or not all(_number(x) for x in pair.values())):
+                raise ContractError(f"compared.{name} is not {{value, limit}}: {pair!r}")
     return obj
 
 
@@ -245,6 +259,9 @@ def check_benchmark(bench: dict, root: str = ROOT) -> list:
     four = sum(w["chips"] == 4 for w in bench["workloads"])
     if four > max(1, len(bench["workloads"]) // 4):
         faults.append(f"{four} cells ask for 4 chips")
+    if not 1 <= len(bench["per_layer"]) <= PER_LAYER_LIMIT:
+        faults.append(f"per_layer holds {len(bench['per_layer'])} entries, "
+                      f"outside 1..{PER_LAYER_LIMIT}")
     e2e = {m["name"] for m in bench["end_to_end"]}
     if "setup_s" not in e2e:
         faults.append("no setup_s")

@@ -133,16 +133,18 @@ def _stop_cluster(ceiling_s: float = CHIPS_CEILING_S) -> None:
 _ENDING = threading.Lock()
 
 
-def _end(real_stdout: int, line, code: int, scratch) -> None:
+def _end(real_stdout: int, line, code: int, scratch, last_words=None) -> None:
     """Stop everything, then write the line (if any) as the process's
     last act and leave without running atexit hooks: nothing can print
-    after it.  Runs once: the watchdog and the main thread may both
-    arrive."""
+    after it.  ``last_words`` are standard error's last lines.  Runs
+    once: the watchdog and the main thread may both arrive."""
     _ENDING.acquire()
     t0 = time.monotonic()
     _stop_cluster()
     _say(f"end: the cluster stopped and the chips free {time.monotonic() - t0:.1f} s "
          "after the run's work was done")
+    if last_words:
+        _say(last_words)
     if scratch:
         shutil.rmtree(scratch, ignore_errors=True)
     sys.stderr.flush()
@@ -214,6 +216,25 @@ def build_line(bench: dict, workload: str, trace: int, job: dict,
             "idle_gaps": trace_reduce.idle_gaps(traced["planes"]),
         }
     return line
+
+
+def compared(facts: dict, tolerance: dict) -> dict:
+    """Each number the job's comparison with the plain reference handed
+    over (``reference_*`` in its facts) beside its limit (the
+    configuration's ``reference_tolerance``): ``{name: {"value", "limit"}}``
+    under the tolerance's own names.  It goes into the line as its LAST
+    key and onto standard error as its last lines, so that a run that is
+    not correct says by how much in what the driver keeps of it."""
+    out = {}
+    for key, limit in tolerance.items():
+        if not contract._number(limit):
+            continue
+        stem = key[:-4] if key.endswith(("_max", "_min")) else key
+        for fact in ("reference_" + key, "reference_err_" + key, "reference_" + stem):
+            if contract._number(facts.get(fact)):
+                out[key] = {"value": facts[fact], "limit": limit}
+                break
+    return out
 
 
 def reduce_trace(trace_dir: str, rehearse: bool, host_s=None) -> dict:
@@ -318,6 +339,9 @@ def main() -> None:
                  if not isinstance(v, (list, dict))}
         facts["chips_waited_s"] = waited
         _say(f"facts: {json.dumps(facts)}")
+        obj["compared"] = compared(facts, config.get("reference_tolerance", {}))
+        last_words = f"correct {obj['correct']}, failed {obj['failed']}; compared: " + "; ".join(
+            f"{k} {v['value']:.6g} (limit {v['limit']:g})" for k, v in obj["compared"].items())
         line = json.dumps(obj)
         contract.validate(line, args.workload, args.trace, bench)
         from jax._src import xla_bridge
@@ -329,7 +353,7 @@ def main() -> None:
         watchdog.cancel()
         _end(real_stdout, None, 1, scratch)
     watchdog.cancel()
-    _end(real_stdout, line, 0, scratch)
+    _end(real_stdout, line, 0, scratch, last_words)
 
 
 if __name__ == "__main__":

@@ -115,14 +115,12 @@ def test_benchmark_json_holds_the_cell_and_its_entries():
     assert entry["file"] == "chipbench/configs/" + CONFIG + ".json"
     assert sorted(entry["reduced"]) == sorted(config_file()["reduced"])
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
     glm = contract.declared_metrics(bench, CELL, 1)
     setup = {name for name in glm if name.startswith("setup_")}
-    assert set(glm) - setup == set(MINE) | set(SHARED) and len(MINE + SHARED) == 17
+    # mine are among them: a later PR declares further quantities in this cell
+    assert set(MINE) | set(SHARED) <= set(glm) - setup and len(MINE + SHARED) == 17
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in MINE:
-        assert by_name[name]["workloads"] == [CELL]
     for name in MINE + SHARED:
         m = by_name[name]
         assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
@@ -392,3 +390,8 @@ def test_the_cell_walks_on_the_cpu_traced():
     assert 0 < line["metrics"]["dsa_selected_share_mean.glm"]["value"] < 100
     assert 0 < line["metrics"]["moe_held_assignment_share"]["value"] < 100
     assert "reference check at 24 + 2 tokens" in out.stderr
+    # the two readings beside ``device_idle_share.batch`` the cell joined at
+    # PR 58: read from the witness's record on the CPU too
+    share = line["metrics"]["host_stall_share.batch"]["value"]
+    assert 0 <= line["metrics"]["host_stall_outside_share.batch"]["value"] <= share < 100
+    assert "keeps no record of its stops" not in out.stderr

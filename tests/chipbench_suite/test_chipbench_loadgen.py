@@ -314,7 +314,14 @@ def test_the_mixed_cell_is_judged_on_the_tail_mean_and_the_chat_cell_on_its_p95(
         "name": "itl_tail_mean_ms", "unit": "ms", "better": "lower", "bound": 0.06,
         "source": "host_clock", "workloads": ["serve_ilm2_mixed"]}
     assert e2e["itl_p95_ms"]["workloads"] == ["serve_ilm2_chat"]
-    assert e2e["itl_p95_ms"]["bound"] == 0.06
+    # every end-to-end bound, in this ONE test: the next change of a bound
+    # edits one file (PR 58 moved ``serve_tokens_per_s``'s here from the JoyAI
+    # and SDAR tests).  ``itl_p95_ms``: 0.06 until PR 58; the driver's note on
+    # PR 52's line asked for at most eight times its widest spread (0.42%:
+    # 3.36%), and 0.03 is 3.1 times PR 58's own widest (PERF.md section 2)
+    assert {"train_tokens_per_s_per_chip": 0.01, "itl_p95_ms": 0.03,
+            "itl_tail_mean_ms": 0.06, "serve_tokens_per_s": 0.09, "setup_s": 0.1}.items() <= {
+        m["name"]: m["bound"] for m in bench["end_to_end"]}.items()
     assert set(contract.declared_metrics(bench, "serve_ilm2_mixed", 0)) == {
         "itl_tail_mean_ms", "setup_s"}
     records = contract.declared_metrics(bench, "serve_ilm2_mixed", 1)

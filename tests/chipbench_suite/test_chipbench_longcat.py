@@ -30,8 +30,8 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = ["num_layers", "n_routed_experts", "vocab_size"]
 #: the cell's per-layer entries, in the order they were appended
 MINE = ("scmoe_step_hbm_roofline_share.lcat", "moe_zero_choice_share.lcat")
-#: the accepted entries the cell joined at PR 52, by a data edit alone: their
-#: readers' facts are what the job has supplied since PR 49
+#: the accepted entries the cell joined at PR 52 (the last two at PR 58), by a
+#: data edit alone: their readers' facts are what the job has supplied since PR 49
 GENERIC = (
     "decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
     "decode_batch_occupancy.batch", "device_idle_share.batch", "compiles_in_window.batch",
@@ -39,11 +39,14 @@ GENERIC = (
     "mla_attn_hbm_roofline_share", "moe_held_assignment_share",
     "moe_experts_touched_mean", "step_dispatch_ms_p50.batch",
     "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch",
+    # and at PR 58, beside ``device_idle_share.batch`` (their readers ask the GCS)
+    "host_stall_share.batch", "host_stall_outside_share.batch",
 )
 #: of those, the ones a CPU walk can read (the others need a device plane)
 ON_THE_CPU = ("compiles_in_window.batch", "moe_held_assignment_share",
               "moe_experts_touched_mean", "step_dispatch_ms_p50.batch",
-              "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch")
+              "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch",
+              "host_stall_share.batch", "host_stall_outside_share.batch")
 
 
 def config_file():
@@ -126,8 +129,8 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
                    "226,492,416", "4,719,360", "37,748,736", "10,240", "327,680"):
         assert number in cfg["changed"]["bytes"], number
     cache = jax.eval_shape(lambda: llama.init_cache(c, 64, 5120))
-    assert set(cache) == {"ckv", "mla_keys", "moe_expert_tokens", "moe_experts_touched",
-                          "moe_layer_steps", "moe_zero_choices"}
+    assert {"ckv", "mla_keys", "moe_expert_tokens", "moe_experts_touched",
+            "moe_layer_steps", "moe_zero_choices"} <= set(cache)   # a later PR may count more
     held = math.prod(cache["ckv"].shape) * cache["ckv"].dtype.itemsize
     assert held == 64 * 5120 * scmoe_cost.cache_bytes_per_token(cfg) == 3_355_443_200
     # 86% of the chip's 16 GB live
@@ -147,7 +150,6 @@ def test_my_benchmark_entries_are_there_by_name_and_in_this_order():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, TRAFFIC, 1)
     assert "1/32" in cell["why"] and "4/28 layers" in cell["why"] and len(cell["why"]) <= 200
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
     assert tokens["workloads"].index(CELL) > tokens["workloads"].index("serve_olmoh_doc_batch")
     names = [m["name"] for m in bench["per_layer"]]
@@ -156,7 +158,7 @@ def test_my_benchmark_entries_are_there_by_name_and_in_this_order():
     assert at[0] > max(i for i, n in enumerate(names) if n.endswith(".olmoh"))  # behind PR 46's
     for name in MINE:
         m = bench["per_layer"][names.index(name)]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         assert m["unit"] == "%" and m["better"] == "higher"
         assert m["layer"] == "model step (models/llama.py)"
         assert contract.reader_path(name).endswith(name.rpartition(".")[0] + ".py")
@@ -164,12 +166,13 @@ def test_my_benchmark_entries_are_there_by_name_and_in_this_order():
     assert bench["per_layer"][at[1]]["source"] == "program_counter"
     setup = [m for m in bench["per_layer"] if m["name"].startswith("setup_")]
     assert len(setup) == 6 and all(CELL in m["workloads"] for m in setup)
-    assert set(contract.declared_metrics(bench, CELL, 1)) == (
-        set(MINE) | set(GENERIC) | {m["name"] for m in setup})
+    # mine are among them: a later PR declares further quantities in this cell
+    assert set(MINE) | set(GENERIC) | {m["name"] for m in setup} <= set(
+        contract.declared_metrics(bench, CELL, 1))
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
     for name in GENERIC:
         m = bench["per_layer"][names.index(name)]
-        assert m["workloads"][-1] == CELL and len(m["workloads"]) > 1   # joined, last
+        assert CELL in m["workloads"] and len(m["workloads"]) > 1       # joined
         assert m["moves"] == "serve_tokens_per_s"
 
 
@@ -472,8 +475,8 @@ def test_the_cell_walks_on_the_cpu_untraced():
 @pytest.mark.limit(170)
 def test_the_traced_walk_reads_every_reader_the_cell_joined():
     """The traced walk: the line carries the cell's own two entries and the
-    fourteen generic ones it joined, and those a CPU walk can read (counters
-    and the engine's spans) read a number from the job's facts."""
+    sixteen generic ones it joined, and those a CPU walk can read (counters,
+    the engine's spans, the witness's stops) read a number."""
     out = subprocess.run(
         [sys.executable, "-m", "chipbench.run", "--workload", CELL, "--seed", "3000000018",
          "--seconds", "3", "--trace", "1", "--rehearse"],
@@ -497,3 +500,4 @@ def test_the_traced_walk_reads_every_reader_the_cell_joined():
                 "decode_steps_in_window", "prefills_in_window", "moe_rows_per_layer_step_mean"):
         assert facts[key] > 0, key
     assert facts["moe_dropped"] == 0
+    assert "keeps no record of its stops" not in out.stderr

@@ -30,6 +30,23 @@ REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size"]
 PEAK = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 #: the cell's per-layer entries, in the order they were appended
 MINE = tuple(name + ".mimo" for name in swa_trace.SHARES)
+#: the accepted entries the cell joined at PR 58, by a data edit alone (ISSUE 55
+#: asked for the first twelve; a test of another cell held each list's last
+#: cell to be its own): their readers' facts are what the job has supplied
+#: since PR 55, and the last two ask the GCS
+GENERIC = (
+    "decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
+    "decode_batch_occupancy.batch", "device_idle_share.batch", "compiles_in_window.batch",
+    "step_dispatch_ms_p50.batch", "step_deliver_ms_p50.batch",
+    "step_serve_plane_ms_p50.batch", "gmm_time_share", "gmm_hbm_roofline_share",
+    "moe_experts_touched_mean", "moe_held_assignment_share",
+    "host_stall_share.batch", "host_stall_outside_share.batch",
+)
+#: of those, the ones a CPU walk can read (the others need a device plane)
+ON_THE_CPU = ("compiles_in_window.batch", "moe_held_assignment_share",
+              "moe_experts_touched_mean", "step_dispatch_ms_p50.batch",
+              "step_deliver_ms_p50.batch", "step_serve_plane_ms_p50.batch",
+              "host_stall_share.batch", "host_stall_outside_share.batch")
 KERNELS = "kernels (ops/kv_decode_attention.py, ops/kv_prefill_attention.py)"
 
 
@@ -162,10 +179,12 @@ def test_my_benchmark_entries_are_there_by_name():
             ("%", "lower" if "time" in name else "higher") if share else ("x", "lower"))
         assert m["source"] == ("device_trace" if share else "program_counter")
         assert m["layer"] == ("model step (models/llama.py)" if "step" in name else KERNELS)
-    assert ({m["name"] for m in setup} | set(MINE)) <= set(
+    assert ({m["name"] for m in setup} | set(MINE) | set(GENERIC)) <= set(
         contract.declared_metrics(bench, CELL, 1))
+    for name in GENERIC:
+        m = bench["per_layer"][names.index(name)]
+        assert len(m["workloads"]) > 1 and m["moves"] == "serve_tokens_per_s"   # joined
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
-    assert len(bench["per_layer"]) <= 96
 
 
 def test_the_traffic_is_the_issues():
@@ -501,6 +520,12 @@ def test_the_cell_walks_on_the_cpu_untraced():
     line = contract.validate(contract.last_line(out.stdout), CELL, 0)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
     assert "reference check at [16, 32] + 4 steps" in out.stderr
+    # the numbers compared, each beside its limit: the line's last key and
+    # standard error's last line (PR 58)
+    assert list(line)[-1] == "compared" and {"rms", "max", "swap_rate_max"} <= set(line["compared"])
+    assert all(pair["value"] <= pair["limit"] for pair in line["compared"].values())
+    assert out.stderr.rstrip().splitlines()[-1].startswith(
+        "[chipbench] correct True, failed 0; compared: rms ")
     facts = facts_of(out.stderr)
     for key in ("full_keys_visible_step", "swa_keys_visible_step", "full_pairs_visible_run",
                 "swa_pairs_read_run", "moe_held_assignment_share", "decode_steps_in_window",
@@ -512,9 +537,10 @@ def test_the_cell_walks_on_the_cpu_untraced():
 
 @pytest.mark.limit(170)
 def test_the_traced_walk_ends_in_a_valid_line():
-    """The traced walk: the line carries the cell's own six entries and the six
-    set-up entries it joined, and the facts the generic readers of a later
-    join will take."""
+    """The traced walk: the line carries the cell's own six entries, the six
+    set-up entries and the fourteen generic ones it joined at PR 58, and those
+    a CPU walk can read (counters, the engine's spans, the witness's stops)
+    read a number."""
     out = walk(1, 3000000018)
     assert out.returncode == 0, out.stderr[-3000:]
     line = contract.validate(contract.last_line(out.stdout), CELL, 1)
@@ -522,12 +548,17 @@ def test_the_traced_walk_ends_in_a_valid_line():
     assert {name for name in line["metrics"] if name.startswith("setup_")} == {
         "setup_cluster_start_s", "setup_worker_ready_s", "setup_chip_open_s",
         "setup_state_init_s", "setup_xla_build_s", "setup_xla_cache_miss_s"}
-    assert set(MINE) <= set(line["metrics"])
+    assert set(MINE) | set(GENERIC) <= set(line["metrics"])
     # the counters' reader reads on the CPU too (the toy's dense body scores
     # the square: 3-4 times the band); the trace's have no device plane here
     silent = [ln.split("rehearsal: ")[1].split(" found")[0]
               for ln in out.stderr.splitlines() if "found nothing to read" in ln]
     assert "swa_prefill_pairs_over_band.mimo" not in silent
+    for name in ON_THE_CPU:
+        assert name not in silent, name
+    assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
+    assert 0 < line["metrics"]["moe_held_assignment_share"]["value"] < 100
+    assert "keeps no record of its stops" not in out.stderr
     assert 2 < line["metrics"]["swa_prefill_pairs_over_band.mimo"]["value"] < 6
     facts = facts_of(out.stderr)
     for key in ("moe_experts_touched_mean", "moe_rows_per_layer_step_mean",

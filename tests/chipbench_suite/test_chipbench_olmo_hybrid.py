@@ -117,7 +117,7 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
                    "61,440", "26,542,080"):
         assert number in cfg["changed"]["bytes"], number
     cache = jax.eval_shape(lambda: llama.init_cache(c, 32, 2560))
-    assert set(cache) == {"k", "v", "gdn_state", "gdn_conv", "gdn_counts"}
+    assert {"k", "v", "gdn_state", "gdn_conv", "gdn_counts"} <= set(cache)   # a later PR may count more
 
     def held(*names):
         return sum(math.prod(cache[k].shape) * cache[k].dtype.itemsize for k in names)
@@ -134,7 +134,6 @@ def test_my_benchmark_entries_are_there_in_this_order():
     end: a later PR appends behind them."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
-    assert len(bench["per_layer"]) <= 128
     entry = contract.config_entry(bench, CONFIG)
     assert entry["file"] == f"chipbench/configs/{CONFIG}.json"
     assert entry["reduced"] == ["num_hidden_layers"] and entry["source"] == config_file()["source"]
@@ -142,7 +141,6 @@ def test_my_benchmark_entries_are_there_in_this_order():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "hybrid_doc_closed64", 1)
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
     assert CELL in tokens["workloads"]
     assert tokens["workloads"].index(CELL) > tokens["workloads"].index(
@@ -155,16 +153,15 @@ def test_my_benchmark_entries_are_there_in_this_order():
         m = bench["per_layer"][names.index(name)]
         assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         assert contract.reader_path(name) is not None, name
-        if name in MINE:
-            assert m["workloads"] == [CELL]
         if "roofline" in name:
             assert m["unit"] == "%" and m["better"] == "higher"
     for name in NEW_READERS:   # readers of their own, not a suffix's
         assert contract.reader_path(name + ".olmoh").endswith(name + ".py")
     setup = [m for m in bench["per_layer"] if m["name"].startswith("setup_")]
     assert len(setup) == 6 and all(CELL in m["workloads"] for m in setup)
-    assert set(contract.declared_metrics(bench, CELL, 1)) == (
-        set(MINE) | set(SHARED) | {m["name"] for m in setup})
+    # mine are among them: a later PR declares further quantities in this cell
+    assert set(MINE) | set(SHARED) | {m["name"] for m in setup} <= set(
+        contract.declared_metrics(bench, CELL, 1))
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
 
 
@@ -439,3 +436,8 @@ def test_the_cell_walks_on_the_cpu(trace):
     if trace:
         assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
         assert line["metrics"]["step_hbm_roofline_share.olmoh"]["value"] > 0
+        # the two readings beside ``device_idle_share.batch`` the cell joined at
+        # PR 58: read from the witness's record on the CPU too
+        share = line["metrics"]["host_stall_share.batch"]["value"]
+        assert 0 <= line["metrics"]["host_stall_outside_share.batch"]["value"] <= share < 100
+        assert "keeps no record of its stops" not in out.stderr

@@ -24,16 +24,18 @@ CONFIG = "sdar-30b-a3b-ep8"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size", "head_dim",
           "num_experts_per_tok", "num_attention_heads", "num_key_value_heads")
-#: the cell's sixteen per-layer quantities by the entry that holds each since
+#: the cell's fourteen per-layer quantities by the entry that holds each since
 #: PR 52 (one entry for each quantity under a judged metric): ten accepted
-#: ones it shares with the other cells judged on tokens/s, six of its own
+#: ones it shares with the other cells judged on tokens/s, four of its own
+#: (PR 58 pruned ``diff_commit_forward_share.sdar`` and
+#: ``diff_threshold_transfer_share.sdar``: 0.2 and 0.0 on every line of the
+#: ledger, the block of four and the random weights; the job's facts keep both)
 SHARED = ("decode_step_device_ms_p50.batch", "prefill_device_ms_p50.batch",
           "device_idle_share.batch", "compiles_in_window.batch",
           "spec_step_dispatch_ms_p50", "spec_step_deliver_ms_p50",
           "spec_step_serve_plane_ms_p50", "gmm_time_share", "gmm_hbm_roofline_share",
           "moe_held_assignment_share")
-NEW_READERS = ("diff_tokens_per_row_forward_mean", "diff_commit_forward_share",
-               "diff_threshold_transfer_share", "block_attn_time_share",
+NEW_READERS = ("diff_tokens_per_row_forward_mean", "block_attn_time_share",
                "block_attn_hbm_roofline_share", "diff_step_hbm_roofline_share")
 MINE = tuple(name + ".sdar" for name in NEW_READERS)
 
@@ -119,8 +121,8 @@ def test_the_program_gets_the_published_block_and_the_bytes_add_up():
     kv = 2 * math.prod(cache["k"].shape) * 2
     assert kv == 32 * 1536 * gqa_cost.cache_bytes_per_token(cfg) == 4_831_838_208
     assert gqa_cost.cache_bytes_per_token(cfg) == 98_304
-    assert set(cache) == {"k", "v", "moe_expert_tokens", "moe_experts_touched",
-                          "moe_layer_steps"}
+    assert {"k", "v", "moe_expert_tokens", "moe_experts_touched",
+            "moe_layer_steps"} <= set(cache)            # a later PR may count more
     # 88% of the chip's 16 GB live
     assert 0.87 < (2 * n + kv) / 16e9 < 0.89
 
@@ -137,9 +139,8 @@ def test_my_benchmark_entries_are_there_in_this_order():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "diffusion_gen_closed64", 1)
     assert [w["config"] for w in bench["workloads"]].count(CONFIG) == 1
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     tokens = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert CELL in tokens["workloads"] and tokens["bound"] == 0.09
+    assert CELL in tokens["workloads"]
     assert tokens["workloads"].index(CELL) > tokens["workloads"].index(
         "serve_joyai_reason_mtp")
     names = [m["name"] for m in bench["per_layer"]]
@@ -150,15 +151,14 @@ def test_my_benchmark_entries_are_there_in_this_order():
         m = bench["per_layer"][names.index(name)]
         assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         assert contract.reader_path(name) is not None, name
-        if name in MINE:
-            assert m["workloads"] == [CELL]
         if "roofline" in name:
             assert m["unit"] == "%" and m["better"] == "higher"
     # and set-up from the inside, as in every cell (PR 34's six, the cell appended)
     setup = [m["name"] for m in bench["per_layer"] if m["name"].startswith("setup_")]
     assert len(setup) == 6
-    assert set(contract.declared_metrics(bench, CELL, 1)) == (
-        set(MINE) | set(SHARED) | set(setup))
+    # mine are among them: a later PR declares further quantities in this cell
+    assert set(MINE) | set(SHARED) | set(setup) <= set(
+        contract.declared_metrics(bench, CELL, 1))
     assert set(contract.declared_metrics(bench, CELL, 0)) == {"serve_tokens_per_s", "setup_s"}
 
 
@@ -256,8 +256,6 @@ def test_the_readers_on_recorded_facts():
                           decode_executions_traced=80),
            "busy_s": 2.0, "window_s": 2.1, "peak": peak, "planes": planes}
     assert reader("diff_tokens_per_row_forward_mean.sdar")(ctx) == 0.8
-    assert reader("diff_commit_forward_share.sdar")(ctx) == 0.2
-    assert reader("diff_threshold_transfer_share.sdar")(ctx) == 0.0
     assert reader("block_attn_time_share.sdar")(ctx) == pytest.approx(45.0)
     per_step = (48 * 32 * 900 + 48 * 32 * 4) * 2048
     got = reader("block_attn_hbm_roofline_share.sdar")(ctx)

@@ -1,8 +1,9 @@
 """The two quantities that stand beside ``device_idle_share`` (PR 53):
 ``chipbench/stall_reduce.py`` on a hand-made span file with hand-computed
-answers, the eight declarations at the end of ``per_layer`` (six of the
-eleven cells: ``NOT_YET`` says which five wait, and for what), and one
-cell walked on the CPU."""
+answers, the eight declarations in ``per_layer`` (found by name; every cell
+that reports ``device_idle_share.<suffix>`` reports both beside it, under the
+same suffix: all twelve since PR 58 joined the six batch cells that waited),
+and one cell walked on the CPU."""
 
 import json
 import os
@@ -107,48 +108,32 @@ def test_a_program_without_the_record_reads_zero_and_says_so(monkeypatch, capsys
     assert capsys.readouterr().err.count("keeps no record of its stops") == 2
 
 
-#: the batch cells whose own tests hold the SET of per-layer metrics their
-#: cell declares (tests/chipbench_suite/test_chipbench_{glm,joyai,sdar,
-#: olmo_hybrid,longcat}.py): the `benchmark` PR that relaxes those tests to
-#: "my entries are there" appends these to the two `.batch` lists (PERF.md
-#: section 7, harness edit (14)) and empties this tuple
-NOT_YET = ("serve_glm5_long_batch", "serve_joyai_reason_mtp",
-           "serve_sdar_diffusion_batch", "serve_olmoh_doc_batch",
-           "serve_longcat_agent_batch")
-
-
-def test_the_eight_entries_close_the_list_with_their_readers_and_cells():
+def test_the_eight_entries_stand_beside_device_idle_share_in_its_cells():
+    """By name — never by their place in the list or by how many cells the
+    benchmark has: a later PR appends entries behind them, and a cell it
+    adds joins ``device_idle_share.<suffix>`` and these two in one edit (the
+    readers ask the GCS, no job feeds them)."""
     bench = contract.load_benchmark()
     assert contract.check_benchmark(bench) == []
-    mine = bench["per_layer"][-8:]
-    assert [m["name"] for m in mine] == [f"{q}.{s}" for q in QUANTITIES
-                                         for s in SUFFIXES]
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    cells = set()
-    for m in mine:
-        quantity, suffix = m["name"].rsplit(".", 1)
-        idle = by_name[f"device_idle_share.{suffix}"]
-        assert (m["unit"], m["better"], m["source"], m["layer"]) == (
-            "%", "lower", "program_span", "device")
-        # beside device_idle_share under every suffix, in its cells and order
-        assert m["moves"] == idle["moves"]
-        assert m["workloads"] == [w for w in idle["workloads"] if w not in NOT_YET]
-        assert contract.reader_path(m["name"]).endswith(
-            f"layer_metrics/{quantity}.py")
-        for w in m["workloads"]:
-            assert m["name"] in contract.declared_metrics(bench, w, 1)
-            assert m["name"] not in contract.declared_metrics(bench, w, 0)
-        cells.update(m["workloads"])
-    # both quantities, under one suffix, in every cell but those five
-    assert cells == {w["name"] for w in bench["workloads"]} - set(NOT_YET)
-    assert len(cells) == 6
+    for quantity in QUANTITIES:
+        for suffix in SUFFIXES:
+            m, idle = by_name[f"{quantity}.{suffix}"], by_name[f"device_idle_share.{suffix}"]
+            assert (m["unit"], m["better"], m["source"], m["layer"]) == (
+                "%", "lower", "program_span", "device")
+            # beside device_idle_share under every suffix, in its cells and order
+            assert m["moves"] == idle["moves"] and m["workloads"] == idle["workloads"]
+            assert contract.reader_path(m["name"]).endswith(
+                f"layer_metrics/{quantity}.py")
+            for w in m["workloads"]:
+                assert m["name"] in contract.declared_metrics(bench, w, 1)
+                assert m["name"] not in contract.declared_metrics(bench, w, 0)
+    # both quantities under ONE suffix in a cell that has either
     for w in bench["workloads"]:
         got = [n for n in contract.declared_metrics(bench, w["name"], 1)
                if n.startswith("host_stall_")]
-        if w["name"] in NOT_YET:
-            assert got == []
-        else:
-            assert len(got) == 2 and got[0].rsplit(".", 1)[1] == got[1].rsplit(".", 1)[1]
+        assert not got or (len(got) == 2
+                           and got[0].rsplit(".", 1)[1] == got[1].rsplit(".", 1)[1])
 
 
 @pytest.mark.limit(170)
