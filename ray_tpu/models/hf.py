@@ -165,8 +165,51 @@ def llama_config_from_hf(hf_config, **overrides):
             expert_dim=hf_config.moe_intermediate_size,
             router_norm_topk=bool(hf_config.norm_topk_prob),
         )
+    if family == "solar_open2":
+        kwargs.update(solar_open2_fields(hf_config))
     kwargs.update(overrides)
     return LlamaConfig(**kwargs)
+
+
+def solar_open2_fields(hf_config) -> Dict[str, Any]:
+    """Solar-Open2's keys (``model_type`` ``solar_open2``) as LlamaConfig
+    fields: layer ``i`` is gated GQA without rotation where ``i in
+    gqa_layers``, else Kimi delta attention (``linear_attn_config``: heads,
+    one size for keys and values, the short convolution's taps;
+    ``kda_use_full_proj`` false: the decay's and the output gate's
+    projections are low-rank, ``head_dim`` wide as ``fla``'s; ``kda_allow_
+    neg_eigval``: write strength up to 2); every layer from
+    ``first_k_dense_replace`` on over ``n_routed_experts`` experts and
+    ``n_shared_experts`` shared ones of ``moe_intermediate_size``.  The
+    config names neither the router's score function nor the shared expert's
+    width: sigmoid scores, and one expert's width a shared expert, are
+    ASSUMED (the DeepSeek-V3 line's, whose key names these are)."""
+    from ray_tpu.models.llama import FULL, LINEAR
+
+    get = (hf_config.get if isinstance(hf_config, dict)
+           else lambda k, d=None: getattr(hf_config, k, d))
+    lin = get("linear_attn_config")
+    if get("kda_use_full_proj") or get("first_k_dense_replace"):
+        raise NotImplementedError(
+            "full-rank KDA gate projections and leading dense blocks are not written")
+    gqa = set(get("gqa_layers"))
+    return dict(
+        rope_theta=float(get("rope_theta")) if get("use_rope") else None,
+        head_dim=get("head_dim"), attn_output_gate=bool(get("use_gqa_gate")),
+        layer_types=tuple(FULL if i in gqa else LINEAR
+                          for i in range(get("num_hidden_layers"))),
+        linear_kind="kda", linear_num_heads=lin["num_heads"],
+        linear_key_head_dim=lin["head_dim"], linear_value_head_dim=lin["head_dim"],
+        linear_conv_kernel=lin["short_conv_kernel_size"],
+        linear_gate_rank=lin["head_dim"],
+        linear_neg_eigval=bool(get("kda_allow_neg_eigval")),
+        num_experts=get("n_routed_experts"),
+        experts_per_token=get("num_experts_per_tok"),
+        expert_dim=get("moe_intermediate_size"),
+        shared_expert_dim=get("n_shared_experts") * get("moe_intermediate_size"),
+        router_scoring="sigmoid", router_norm_topk=bool(get("norm_topk_prob")),
+        router_scale=float(get("routed_scaling_factor") or 1.0),
+    )
 
 
 def llama_params_from_hf(model, **config_overrides):

@@ -265,6 +265,21 @@ class LlamaConfig:
     linear_conv_kernel: int = 4
     linear_neg_eigval: bool = False
     linear_chunk: int = 64
+    # the linear layers' rule: "gated_delta" — one decay a head (Qwen3-Next's
+    # GatedDeltaNet, Olmo-Hybrid's) | "kda" — Kimi Delta Attention (Kimi
+    # Linear, Solar-Open2): a decay a KEY CHANNEL of every head, made by a
+    # low-rank projection of width ``linear_gate_rank`` (as is the output's
+    # sigmoid gate), ``dt_bias`` a channel (``_kda_mixer``).  A "kda" run is
+    # taken ``linear_segment`` tokens at a time, state and convolution tail
+    # carried from segment to segment, so that what a 16k-token prompt's
+    # projections and chunked rule hold at once is a segment's
+    linear_kind: str = "gated_delta"
+    linear_gate_rank: int = 0
+    linear_segment: int = 2048
+    # full-attention layers with an output gate (Solar-Open2's
+    # ``use_gqa_gate``, the gated attention of the Qwen3-Next line): the heads'
+    # outputs times sigmoid(h W_og), elementwise, before W_o
+    attn_output_gate: bool = False
     # the block's residual path: x + f(norm(x)) (False) | x + norm(f(x)),
     # OLMo-2's and OLMo-3's (True); the same two scales either way
     post_norm: bool = False
@@ -292,6 +307,9 @@ class LlamaConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.embed_dim // self.num_heads)
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.linear_kind not in ("gated_delta", "kda"):
+            raise ValueError(
+                f"linear_kind is 'gated_delta' or 'kda'; got {self.linear_kind!r}")
         if self.layer_types:
             if (len(self.layer_types) != self.num_layers
                     or set(self.layer_types) - {LINEAR, FULL, SLIDING}):
@@ -305,12 +323,24 @@ class LlamaConfig:
                     "multi-token-prediction module or block mask"
                 )
             if LINEAR in self.layer_types and (
-                    self.num_experts or self.first_dense_layers
-                    or SLIDING in self.layer_types):
+                    (self.num_experts and self.linear_kind != "kda")
+                    or self.first_dense_layers or SLIDING in self.layer_types):
                 raise NotImplementedError(
                     "linear-attention layers go with full attention and a dense "
-                    "SwiGLU: no experts, leading dense blocks or window layers"
+                    "SwiGLU (under linear_kind 'kda' also with an expert layer): "
+                    "no experts, leading dense blocks or window layers"
                 )
+        if self.linear_kind == "kda" and LINEAR in self.layer_types and (
+                self.linear_gate_rank < 1 or self.post_norm):
+            raise NotImplementedError(
+                "Kimi-delta layers make their decay and their output gate through "
+                "linear_gate_rank > 0 columns, in a pre-norm block"
+            )
+        if self.attn_output_gate and (
+                self.latent or self.sliding is not None or self.mask_block > 1):
+            raise NotImplementedError(
+                "attn_output_gate is written for plain K/V full-attention layers"
+            )
         if (SLIDING in self.layer_types) != (self.sliding is not None):
             raise ValueError(
                 f"``sliding`` is the kind of the {SLIDING!r} layers of "
@@ -487,6 +517,44 @@ class LlamaConfig:
         return LlamaConfig.tiny(**defaults)
 
     @staticmethod
+    def solar_open2(**kw) -> "LlamaConfig":
+        """Solar-Open2-250B's published shape: 48 layers, every fourth (0, 4,
+        ..) gated GQA without rotation (64 Q / 8 KV x 128), the others Kimi
+        delta attention (64 heads, keys and values of 128, low-rank gates of
+        128, write strength up to 2), every layer over 320 sigmoid-routed
+        experts of 1,280, top 8 renormalised, and one shared expert.
+        ``num_layers`` may be cut to fewer whole periods."""
+        layers = kw.setdefault("num_layers", 48)
+        defaults = dict(
+            vocab_size=196608, max_seq_len=1048576, num_heads=64, num_kv_heads=8,
+            head_dim=128, embed_dim=4096, mlp_dim=10240, rope_theta=None,
+            rms_eps=1e-6, attn_output_gate=True,
+            layer_types=((FULL, LINEAR, LINEAR, LINEAR) * -(-layers // 4))[:layers],
+            linear_kind="kda", linear_num_heads=64, linear_key_head_dim=128,
+            linear_value_head_dim=128, linear_gate_rank=128, linear_neg_eigval=True,
+            num_experts=320, experts_per_token=8, expert_dim=1280,
+            shared_expert_dim=1280, router_scoring="sigmoid", router_norm_topk=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def tiny_kda(**kw) -> "LlamaConfig":
+        """``solar_open2`` at toy widths: two periods, chunks of four, runs
+        in segments of eight, 8 experts top 2 and a shared one."""
+        defaults = dict(
+            num_layers=8, num_heads=4, num_kv_heads=2, rope_theta=None,
+            attn_output_gate=True, layer_types=(FULL, LINEAR, LINEAR, LINEAR) * 2,
+            linear_kind="kda", linear_num_heads=4, linear_key_head_dim=8,
+            linear_value_head_dim=16, linear_gate_rank=8, linear_neg_eigval=True,
+            linear_chunk=4, linear_segment=8, num_experts=8, experts_per_token=2,
+            expert_dim=32, shared_expert_dim=32, router_scoring="sigmoid",
+            router_norm_topk=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig.tiny(**defaults)
+
+    @staticmethod
     def mimo_v2_flash(**kw) -> "LlamaConfig":
         """MiMo-V2-Flash's published shape (309B-A15B): 48 layers, layer 0
         full attention over a dense SwiGLU, then runs of window layers (the
@@ -645,6 +713,8 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         }
         if c.qk_norm:
             attn.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
+        if c.attn_output_gate:
+            attn["w_og"] = ("layers", "embed", "heads", None)
     dense = {
         "attn_norm": ("layers", "embed"),
         **attn,
@@ -685,17 +755,28 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         if c.sliding is not None and c.sliding.sink and _stack_kind(name) == SLIDING:
             out[name] = dict(out[name], sink=("layers", "heads"))
     if LINEAR in c.layer_types:
-        out[_STACK_OF[LINEAR]] = {
-            **{k: v for k, v in dense.items() if k not in (*attn, "wo")},
+        name = _STACK_OF[LINEAR]
+        kda = c.linear_kind == "kda"
+        gates = {
+            "kda_wf_a": ("layers", "embed", None),
+            "kda_wf_b": ("layers", None, "heads", None),
+            "kda_wg_a": ("layers", "embed", None),
+            "kda_wg_b": ("layers", None, "heads", None),
+        } if kda else {
+            "gdn_wz": ("layers", "embed", "heads", None),
+            "gdn_wa": ("layers", "embed", "heads"),
+        }
+        out[name] = {
+            **{k: v for k, v in out[name].items() if k not in (*attn, "wo")},
             "gdn_wq": ("layers", "embed", "heads", None),
             "gdn_wk": ("layers", "embed", "heads", None),
             "gdn_wv": ("layers", "embed", "heads", None),
-            "gdn_wz": ("layers", "embed", "heads", None),
-            "gdn_wa": ("layers", "embed", "heads"),
+            **gates,
             "gdn_wb": ("layers", "embed", "heads"),
             "gdn_wo": ("layers", "heads", None, "embed"),
             "gdn_conv": ("layers", None, None), "gdn_norm": ("layers", None),
-            "a_log": ("layers", "heads"), "dt_bias": ("layers", "heads"),
+            "a_log": ("layers", "heads"),
+            "dt_bias": ("layers", "heads", None) if kda else ("layers", "heads"),
         }
     if not c.tie_embeddings:
         out["lm_head"] = ("vocab", "embed")
@@ -716,7 +797,10 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
     if any), else one SwiGLU of width ``mlp_dim``.  ``kind`` LINEAR: the
     mixer is the gated delta rule (``_gated_delta_mixer`` names its tensors;
     ``a_log`` = log U(0, 16) and ``dt_bias`` = 1 as ``fla``'s
-    ``GatedDeltaNet`` starts them); FULL | SLIDING: K/V attention with the
+    ``GatedDeltaNet`` starts them; under ``linear_kind`` "kda" ``_kda_mixer``
+    names them, ``a_log`` = log U(1, 16) a head and ``dt_bias`` a key channel
+    the inverse softplus of a step drawn log-uniformly in [0.001, 0.1], as
+    ``fla``'s ``KimiDeltaAttention`` starts them); FULL | SLIDING: K/V attention with the
     kind's KV heads (``LlamaConfig.attention_kind``), values of
     ``value_dim``, and where the kind has one a ``sink`` a query head,
     which starts at zero.  A shortcut-connected stack
@@ -757,6 +841,21 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
                 more(5), (L, Hl), jnp.float32, 1e-6, 16.0)).astype(dt),
             "dt_bias": jnp.ones((L, Hl), dt),
         }
+        if c.linear_kind == "kda":
+            r = c.linear_gate_rank
+            for gone in ("gdn_wz", "gdn_wa"):
+                del blk[gone]
+            step = jnp.exp(jax.random.uniform(
+                more(14), (L, Hl, Dk), jnp.float32, math.log(1e-3), math.log(0.1)))
+            blk.update({
+                "kda_wf_a": norm(more(1), (L, E, r), std),
+                "kda_wf_b": norm(more(2), (L, r, Hl, Dk), std),
+                "kda_wg_a": norm(more(15), (L, E, r), std),
+                "kda_wg_b": norm(more(16), (L, r, Hl, Dv), std),
+                "a_log": jnp.log(jax.random.uniform(
+                    more(5), (L, Hl), jnp.float32, 1.0, 16.0)).astype(dt),
+                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            })
     elif c.latent:
         Q, C = c.q_lora_rank, c.kv_lora_rank
         Dn, Dr, Dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
@@ -788,6 +887,8 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
         }
         if c.attention_kind(kind).sink:
             blk["sink"] = jnp.zeros((L, H), dt)
+        if c.attn_output_gate:
+            blk["w_og"] = norm(more(13), (L, E, H, c.value_dim), std)
         if c.qk_norm:
             blk.update({
                 "q_norm": jnp.ones((L, D if c.qk_norm == "head" else H * D), dt),
@@ -1290,11 +1391,118 @@ def _gated_delta_mixer(x, p, config: LlamaConfig, states=None, tail=None):
     return y, state, window[S:]
 
 
+def _output_gated(attn, h, p, config: LlamaConfig):
+    """A full-attention layer's heads' outputs (R, S, H, D_v) times the
+    sigmoid of the layer's gate projection of its normed input ``h``
+    (``attn_output_gate``), or as they are."""
+    if "w_og" not in p:
+        return attn
+    gate = jnp.einsum("bse,ehd->bshd", h, p["w_og"].astype(config.dtype),
+                      preferred_element_type=jnp.float32)
+    return (attn.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(attn.dtype)
+
+
+def _linear_mixer(config: LlamaConfig):
+    """The linear layers' mixer by the config's rule (``linear_kind``)."""
+    return _kda_mixer if config.linear_kind == "kda" else _gated_delta_mixer
+
+
+def _kda_mixer(x, p, config: LlamaConfig, states=None, tail=None):
+    """A Kimi-delta-attention layer's mixer (Kimi Linear, arXiv:2510.26692;
+    ``fla``'s ``KimiDeltaAttention``; Solar-Open2's ``kda_*`` keys): the
+    delta rule with a decay A KEY CHANNEL.  x: (R, S, E).  Per token ``q~,
+    k~`` (H d_k each) and ``v~`` (H d_v) plain projections of x, each through
+    a causal depthwise convolution of ``linear_conv_kernel`` taps and a silu
+    (one ``gdn_conv`` over the three side by side: depthwise, so the same);
+    per head ``q = l2norm(q) / sqrt(d_k)``, ``k = l2norm(k)``; ``beta =
+    sigmoid(x W_b)`` (doubled with ``linear_neg_eigval``); ``log alpha =
+    -exp(a_log_h) softplus(x W_f_a W_f_b + dt_bias)``, (H, d_k) float32, the
+    low-rank projection ``linear_gate_rank`` wide; the rule
+    (``ops/gated_delta.py``, ``log_alpha`` a channel); ``y = W_o
+    [RMSNorm_{d_v}(o) gdn_norm * sigmoid(x W_g_a W_g_b)]``.
+
+    ``states`` / ``tail`` as ``_gated_delta_mixer``'s: ONE token of every row
+    is a ``gated_delta.step_layer`` on layer ``p["cache_layer"]`` of the
+    leaf.  Without them the run starts from nothing and goes through the
+    chunked ``gated_delta.scan`` ``linear_segment`` tokens at a time, state
+    and tail carried (a run of no whole number of equal segments at most that
+    long goes in one).  Same returns."""
+    c = config
+    R, S, _ = x.shape
+    H, Dk, Dv, K = (c.linear_num_heads, c.linear_key_head_dim,
+                    c.linear_value_head_dim, c.linear_conv_kernel)
+    dt, f32 = c.dtype, jnp.float32
+
+    def inputs(x, tail):
+        """A run's (q, k, v, log_alpha, beta, gate, the new tail)."""
+        S = x.shape[1]
+        with jax.named_scope("kda_proj"):
+            def heads(name):
+                return jnp.einsum("rse,ehd->rshd", x, p[name].astype(dt)).reshape(R, S, -1)
+
+            def low_rank(a, b):  # (R, S, H, d) float32 through ``linear_gate_rank``
+                mid = jnp.einsum("rse,ec->rsc", x, p[a].astype(dt))
+                return jnp.einsum("rsc,chd->rshd", mid, p[b].astype(dt),
+                                  preferred_element_type=f32)
+
+            u = jnp.concatenate([heads("gdn_wq"), heads("gdn_wk"), heads("gdn_wv")], -1)
+            u = jnp.swapaxes(u, 0, 1)                               # (S, R, channels)
+            window = jnp.concatenate([tail, u])                     # (K - 1 + S, R, channels)
+            taps = p["gdn_conv"].astype(f32)
+            mixed = jax.nn.silu(sum(
+                window[j:j + S].astype(f32) * taps[j] for j in range(K)
+            ))
+            mixed = jnp.swapaxes(mixed, 0, 1)                       # (R, S, channels)
+            q, k, v = (part.reshape(R, S, H, -1) for part in jnp.split(
+                mixed, [H * Dk, 2 * H * Dk], axis=-1))
+
+            def l2norm(t):
+                return t * lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+            q, k = l2norm(q) / math.sqrt(Dk), l2norm(k)
+            b = jnp.einsum("rse,eh->rsh", x, p["gdn_wb"].astype(dt), preferred_element_type=f32)
+            beta = jax.nn.sigmoid(b) * (2.0 if c.linear_neg_eigval else 1.0)
+            log_alpha = -jnp.exp(p["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+                low_rank("kda_wf_a", "kda_wf_b") + p["dt_bias"].astype(f32))
+            gate = jax.nn.sigmoid(low_rank("kda_wg_a", "kda_wg_b"))
+        return q, k, v, log_alpha, beta, gate, window[S:]
+
+    def output(o, gate):
+        with jax.named_scope("kda_out"):
+            o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + c.rms_eps)
+            o = o * p["gdn_norm"].astype(f32) * gate
+            return jnp.einsum("rshv,hve->rse", o.astype(dt), p["gdn_wo"].astype(dt))
+
+    if states is not None:
+        q, k, v, log_alpha, beta, gate, tail = inputs(x, tail)
+        with jax.named_scope("kda_step"):
+            o, state = gated_delta.step_layer(
+                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0], states,
+                p["cache_layer"])
+        return output(o[:, None], gate), state, tail
+
+    def segment(carry, x):
+        state, tail = carry
+        q, k, v, log_alpha, beta, gate, tail = inputs(x, tail)
+        with jax.named_scope("kda_scan"):
+            o, state = gated_delta.scan(q, k, v, log_alpha, beta, state, chunk=c.linear_chunk)
+        return (state, tail), output(o, gate)
+
+    start = (jnp.zeros((R, H, Dk, Dv), f32), jnp.zeros((K - 1, R, H * (2 * Dk + Dv)), dt))
+    n = -(-S // c.linear_segment)
+    if n == 1 or S % n:
+        (state, tail), y = segment(start, x)
+        return y, state, tail
+    (state, tail), y = lax.scan(
+        segment, start, jnp.swapaxes(x.reshape(R, n, S // n, -1), 0, 1))
+    return jnp.swapaxes(y, 0, 1).reshape(R, S, -1), state, tail
+
+
 def _block(x, p, positions, config: LlamaConfig):
     c = config
     h = _norm_in(x, p, "attn_norm", c)
     if "a_log" in p:
-        y = _gated_delta_mixer(h, p, c)[0]
+        y = _linear_mixer(c)(h, p, c)[0]
     else:
         q, kk, vv = _qkv(h, p, positions, c)
         # GQA: repeat each KV head across its query group
@@ -1304,7 +1512,7 @@ def _block(x, p, positions, config: LlamaConfig):
         q = constrain(q, ("batch", "seq", "heads", None))
         kk = constrain(kk, ("batch", "seq", "heads", None))
         vv = constrain(vv, ("batch", "seq", "heads", None))
-        attn = _attention(q, kk, vv, c)
+        attn = _output_gated(_attention(q, kk, vv, c), h, p, c)
         y = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
     x = x + _norm_out(y, p, "attn_norm", c)
     x = constrain(x, ("batch", "seq", "embed"))
@@ -2017,7 +2225,12 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
                         positions=positions, config=c, slab=False)
         cache_k, _ = write(state["k"], kk.astype(c.dtype))
         cache_v, _ = write(state["v"], vv.astype(c.dtype))
-        return _run_attention(q, kk, vv, c), dict(state, k=cache_k, v=cache_v), {}
+        if c.q_per_kv > 1 and q.shape[0] == 1:
+            # grouped queries: the flash kernel that repeats no KV head
+            attn = kv_prefill_attention.attention(q[0], kk[0], vv[0])[None]
+        else:
+            attn = _run_attention(q, kk, vv, c)
+        return attn.astype(c.dtype), dict(state, k=cache_k, v=cache_v), {}
     streamed = (
         slot is None and positions.shape[1] <= _STEP_RUN
         and kv_decode_attention.implementation(T, c.head_dim, c.sliding_window)
@@ -2578,7 +2791,7 @@ def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
     layer = p["cache_layer"]
     rec, conv = state["gdn_state"], state["gdn_conv"]
     if slot is None and Sq == 1:
-        y, rec, tail = _gated_delta_mixer(
+        y, rec, tail = _linear_mixer(config)(
             h, p, config, rec, lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
         )
         conv = lax.dynamic_update_index_in_dim(conv, tail, layer, 0)
@@ -2589,7 +2802,7 @@ def _gated_delta_state(h, p, state, slot, positions, config: LlamaConfig):
         # the taps minor-most (the layout the projections' output has),
         # padded 3 -> 128, and copies it in and out every call (1.1 GB;
         # compile-only, PR 46)
-        y, new, tail = _gated_delta_mixer(h, p, config)
+        y, new, tail = _linear_mixer(config)(h, p, config)
         return y, state, {"gdn_state_rows": new, "gdn_conv_rows": tail}
     else:
         raise NotImplementedError(
@@ -2708,6 +2921,7 @@ def _block_step(x, p, state, slot, positions, config: LlamaConfig,
                 )
             else:
                 attn, state, aux = _kv_attention(h, p, state, slot, positions, c)
+                attn = _output_gated(attn, h, p, c)
             y = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(c.dtype))
         x = x + _norm_out(y, p, "attn_norm", c)
     with jax.named_scope("decode_mlp"):

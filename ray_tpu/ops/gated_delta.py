@@ -22,6 +22,27 @@ float32 (a head whose A is 16 decays by e^-20 a token).  Everything is
 float32 with the products at ``Precision.HIGHEST`` (the chip's default for
 float32 operands is one bfloat16 pass, which would round the state).
 
+THE DECAY A KEY CHANNEL (Kimi Delta Attention: Kimi Linear,
+arXiv:2510.26692; Solar-Open2's ``kda_*`` layers): every entry point takes
+``log_alpha`` with a trailing d_k axis — (B, H, d_k) a token, (B, S, H, d_k)
+a run — and then ``S' = Diag(alpha) S``: ``S'^T x = S^T (alpha * x)``, so
+``step`` contracts the old state with ``alpha k`` and ``alpha q``; the kernel
+``kda_step`` (``_kernel_channels``) transposes alpha with k and q and
+multiplies down the sublanes; ``scan`` becomes ``_scan_channels``, whose
+docstring has the algebra.  Its stability argument: the scalar gate's
+``e^(G_i - G_j)`` is one number a (token, token) pair and is formed from the
+DIFFERENCE of two sums; with a decay a channel the same factor sits inside
+the sum over d_k, and taken apart as ``(q e^G_i) . (k e^-G_j)`` its second
+factor is e^1280 at the end of a 64-token chunk of a channel that decays by
+e^-20 a token.  So no exponent above zero is formed: between sub-chunks of
+16 tokens both factors are taken against the cumulative decay at the last
+token before the later sub-chunk (both exponents <= 0, because the sum
+falls), inside a sub-chunk the difference is formed a channel before the
+exponential.  The scalar entry points keep their signatures and their
+bodies: a decay constant over the channels gives their results to 1e-5
+(``tests/test_solar_open2.py``), and nothing broadcasts a scalar gate to
+d_k channels.
+
 ``scan`` is plain ``jax.numpy``.  The one-token update of a cache's layer
 (``step_layer``) has two bodies, chosen from the operand's SHAPE in ONE place
 (``implementation``):
@@ -115,8 +136,20 @@ def step(q, k, v, log_alpha, beta, state):
     The state is read twice and written once: both contractions are
     taken of the OLD state in one pass (``S_t^T q = alpha S^T q + (k . q)
     delta``: the rule's own algebra, nothing approximated), the update is
-    the second."""
+    the second.
+
+    ``log_alpha`` (B, H, d_k): the decay is PER KEY CHANNEL (``S' = Diag(alpha)
+    S``, Kimi Delta Attention): ``S'^T x = S^T (alpha * x)``, so the two
+    contractions are taken of the old state against ``alpha k`` and ``alpha
+    q``, still in one pass."""
     q, k, v, beta = (x.astype(jnp.float32) for x in (q, k, v, beta))
+    if log_alpha.ndim == k.ndim:
+        alpha = jnp.exp(log_alpha.astype(jnp.float32))              # (B, H, d_k)
+        seen_k = (state * (alpha * k)[..., None]).sum(-2)            # S'^T k
+        seen_q = (state * (alpha * q)[..., None]).sum(-2)            # S'^T q
+        delta = beta[..., None] * (v - seen_k)
+        o = seen_q + (k * q).sum(-1, keepdims=True) * delta
+        return o, alpha[..., None] * state + k[..., None] * delta[..., None, :]
     alpha = jnp.exp(log_alpha.astype(jnp.float32))[..., None]       # (B, H, 1)
     seen_k = (state * k[..., None]).sum(-2)                          # S^T k
     seen_q = (state * q[..., None]).sum(-2)                          # S^T q
@@ -263,8 +296,9 @@ def step_in_place(q, k, v, log_alpha, beta, states, layer):
     """The kernel: ``step`` on layer ``layer`` () int32 of ``states`` (L, B,
     d_k, H d_v) float32, which is the output's buffer
     (``input_output_aliases``): the layer's blocks move, nothing else.  q,
-    k: (B, H, d_k); v: (B, H, d_v); log_alpha, beta: (B, H).  Returns (o (B,
-    H, d_v) float32, ``states``)."""
+    k: (B, H, d_k); v: (B, H, d_v); log_alpha, beta: (B, H) — or log_alpha
+    (B, H, d_k), a decay a key channel: the kernel ``kda_step``
+    (``_kernel_channels``).  Returns (o (B, H, d_v) float32, ``states``)."""
     B, H, d_k = k.shape
     d_v = v.shape[-1]
     if implementation(B, H, d_k, d_v) != "in_place" or states.shape[1:] != (B, d_k, H * d_v):
@@ -273,6 +307,8 @@ def step_in_place(q, k, v, log_alpha, beta, states, layer):
             f"packed (layers, rows, d_k, heads x d_v): q {q.shape}, v {v.shape}, "
             f"states {states.shape}"
         )
+    if log_alpha.ndim == k.ndim:
+        return _step_in_place_channels(q, k, v, log_alpha, beta, states, layer)
     hb = _heads_a_step(H, d_k, d_v)
     nb, C = H // hb, hb * d_v
     rb, gr = (ROWS_A_STEP, 8) if B % 8 == 0 else (1, B)
@@ -324,6 +360,138 @@ def step_in_place(q, k, v, log_alpha, beta, states, layer):
     return o.reshape(B, H, d_v), states
 
 
+def _head_lanes(kt_ref, i, sl, p, d_v):
+    """Operand ``i`` of ``kt_ref`` (d_k down the sublanes, a head a lane),
+    sublanes ``sl``, at the columns of group ``p``'s heads: (8, W)."""
+    g = _group(d_v)
+    W = g * d_v
+    lane = lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    heads = [jnp.broadcast_to(kt_ref[i, sl, p * g + h:p * g + h + 1], (8, 128))
+             for h in range(g)]
+    tiles = []
+    for t in range(W // 128):  # the heads that own lanes of tile t
+        first, last = t * 128 // d_v, (t * 128 + 127) // d_v
+        tile = heads[first]
+        for h in range(first + 1, last + 1):
+            tile = jnp.where(lane < h * d_v - t * 128, tile, heads[h])
+        tiles.append(tile)
+    return jnp.concatenate(tiles, 1)
+
+
+def _kernel_channels(layer_ref, kqa_ref, gates_ref, s_ref, o_ref, out_ref,
+                     kt_ref, kb_ref, ab_ref, *, d_v):
+    """``_kernel`` with a decay a key channel.  kqa (rows, 3, heads padded,
+    128): k, q and alpha (heads, d_k) as the layer makes them; gates (3, 8
+    rows, C): beta, v and k . q at every column of their head; scratch kt (3,
+    128, 128) the three transposed, kb / ab (d_k, W) a group's k and alpha at
+    the lanes of its heads.  The first pass takes ``S^T (alpha k)`` and ``S^T
+    (alpha q)`` of the old state, the second writes ``alpha S + k (x) delta``
+    with alpha down the sublanes: the block still comes in once and goes out
+    once."""
+    del layer_ref
+    rows, d_k, C = s_ref.shape
+    base = (pl.program_id(1) * rows) % o_ref.shape[0]
+    W = _group(d_v) * d_v
+    f32 = jnp.float32
+
+    def a_row(r, carry):
+        for i in range(3):
+            x = kqa_ref[r, i]
+            x = jnp.concatenate([x, jnp.zeros((128 - x.shape[0], 128), f32)], 0)
+            kt_ref[i] = x.T
+        for p in range(C // W):
+            cols = pl.ds(p * W, W)
+            # the row's gates out of their whole (8, W) tile by a mask: at one
+            # lane tile a head (d_v 128) a one-sublane load at a traced index
+            # does not lower ("dynamic load with unaligned indices")
+            mine = lax.broadcasted_iota(jnp.int32, (o_ref.shape[0], W), 0) == base + r
+            beta, v, kq = (
+                jnp.sum(jnp.where(mine, gates_ref[i, :, cols], 0.0), axis=0, keepdims=True)
+                for i in range(3))
+
+            def contract(n, acc):  # sublanes n 8 .. of both contractions
+                sl = pl.ds(pl.multiple_of(n * 8, 8), 8)
+                K, A = _head_lanes(kt_ref, 0, sl, p, d_v), _head_lanes(kt_ref, 2, sl, p, d_v)
+                kb_ref[sl, :] = K
+                ab_ref[sl, :] = A
+                decayed = s_ref[r, sl, cols] * A
+                return (acc[0] + decayed * K,
+                        acc[1] + decayed * _head_lanes(kt_ref, 1, sl, p, d_v))
+
+            acc_k, acc_q = lax.fori_loop(
+                0, d_k // 8, contract, (jnp.zeros((8, W), f32),) * 2, unroll=True)
+            seen_k = jnp.sum(acc_k, axis=0, keepdims=True)           # S'^T k
+            seen_q = jnp.sum(acc_q, axis=0, keepdims=True)           # S'^T q
+            delta = beta * (v - seen_k)                              # (1, W)
+            o_ref[:, cols] = jnp.where(mine, seen_q + kq * delta, o_ref[:, cols])
+            delta8 = jnp.broadcast_to(delta, (8, W))
+
+            def write(n, carry):
+                sl = pl.ds(pl.multiple_of(n * 8, 8), 8)
+                out_ref[r, sl, cols] = (
+                    ab_ref[sl, :] * s_ref[r, sl, cols] + kb_ref[sl, :] * delta8)
+                return carry
+
+            lax.fori_loop(0, d_k // 8, write, 0, unroll=True)
+        return carry
+
+    lax.fori_loop(0, rows, a_row, 0)
+
+
+def _step_in_place_channels(q, k, v, log_alpha, beta, states, layer):
+    """``step_in_place`` for ``log_alpha`` (B, H, d_k): the same grid and the
+    same blocks of the state under the kernel ``kda_step``; alpha rides with
+    k and q (a head a sublane, d_k along the lanes) and is transposed with
+    them."""
+    B, H, d_k = k.shape
+    d_v = v.shape[-1]
+    hb = _heads_a_step(H, d_k, d_v)
+    nb, C = H // hb, hb * d_v
+    rb, gr = (ROWS_A_STEP, 8) if B % 8 == 0 else (1, B)
+    f32 = jnp.float32
+    q, k, v, beta = (x.astype(f32) for x in (q, k, v, beta))
+    alpha = jnp.exp(log_alpha.astype(f32))
+    gates = jnp.stack([
+        jnp.repeat(beta, d_v, axis=-1), v.reshape(B, H * d_v),
+        jnp.repeat((k * q).sum(-1), d_v, axis=-1),
+    ])                                                               # (3, B, H d_v)
+    hbp = -(-hb // 8) * 8
+    kqa = jnp.stack([k, q, alpha], 1).reshape(B, 3, nb, hb, d_k).swapaxes(1, 2)
+    kqa = jnp.pad(kqa, ((0, 0),) * 3 + ((0, hbp - hb), (0, 128 - d_k)))  # (B, nb, 3, hbp, 128)
+    state = pl.BlockSpec((None, rb, d_k, C), lambda j, b, layer: (layer[0], b, 0, j))
+    W = _group(d_v) * d_v
+    o, states = pl.pallas_call(
+        functools.partial(_kernel_channels, d_v=d_v),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb, B // rb),
+            in_specs=[
+                pl.BlockSpec((rb, None, 3, hbp, 128), lambda j, b, layer: (b, j, 0, 0, 0)),
+                pl.BlockSpec((3, gr, C), lambda j, b, layer: (0, b * rb // gr, j)),
+                state,
+            ],
+            out_specs=[pl.BlockSpec((gr, C), lambda j, b, layer: (b * rb // gr, j)), state],
+            scratch_shapes=[
+                pltpu.VMEM((3, 128, 128), f32),
+                pltpu.VMEM((d_k, W), f32),
+                pltpu.VMEM((d_k, W), f32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H * d_v), f32),
+            jax.ShapeDtypeStruct(states.shape, f32),
+        ],
+        input_output_aliases={3: 1},  # operands count the scalar-prefetch one
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=_interpret(),
+        name="kda_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), kqa, gates, states)
+    return o.reshape(B, H, d_v), states
+
+
 def scan(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):
     """A run of S tokens of every row, from ``state0``.  q, k: (B, S, H,
     d_k); v: (B, S, H, d_v); log_alpha, beta: (B, S, H); state0: (B, H,
@@ -333,7 +501,11 @@ def scan(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):
     A position that is not ``valid`` is the identity (alpha 1, beta 0):
     it leaves the state as it found it, and its own output is not to be
     read.  S is padded up to whole chunks with such positions, so the
-    final state is that of the real tokens whatever S is."""
+    final state is that of the real tokens whatever S is.
+
+    ``log_alpha`` (B, S, H, d_k), a decay a key channel: ``_scan_channels``."""
+    if log_alpha.ndim == k.ndim:
+        return _scan_channels(q, k, v, log_alpha, beta, state0, valid, chunk)
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     pad = -S % chunk
@@ -374,6 +546,108 @@ def scan(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):
     q_in = q * jnp.exp(g)[..., None]                                 # against the chunk's S0
     k_out = k * jnp.exp(g[..., -1:] - g)[..., None]                  # into the chunk's end
     total = jnp.exp(g[..., -1])[..., None, None]                     # (B, H, N, 1, 1)
+
+    def one(state, c):
+        u_c, w_c, within_c, q_c, k_c, total_c = c
+        new = u_c - mm("bhid,bhdv->bhiv", w_c, state)
+        o = mm("bhid,bhdv->bhiv", q_c, state) + mm("bhij,bhjv->bhiv", within_c, new)
+        return total_c * state + mm("bhid,bhiv->bhdv", k_c, new), o
+
+    over_chunks = tuple(jnp.moveaxis(x, 2, 0) for x in (u, w, within, q_in, k_out, total))
+    state, o = lax.scan(one, state0.astype(jnp.float32), over_chunks)
+    o = jnp.moveaxis(o, 0, 2).reshape(B, H, -1, dv)[:, :, :S]        # (B, H, S, d_v)
+    return jnp.moveaxis(o, 1, 2), state
+
+
+#: tokens of a sub-chunk of the per-channel rule (``fla``'s ``chunk_kda``: 16)
+SUB_CHUNK = 16
+
+
+def _scan_channels(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):
+    """``scan`` with a decay a key channel (Kimi Delta Attention,
+    arXiv:2510.26692): log_alpha (B, S, H, d_k), the rest as ``scan``'s.
+
+    The algebra is ``scan``'s with ``Diag(alpha)`` in ``alpha``'s place: with
+    G_i the decays' running sum inside a chunk (a vector over d_k), ``(I + A)
+    new = beta v - (beta k * e^G) S0``, ``A_ij = beta_i sum_d k_id k_jd
+    e^(G_id - G_jd)`` (i > j), ``o_i = (q_i * e^G_i) S0 + sum_{j<=i} (sum_d
+    q_id k_jd e^(G_id - G_jd)) new_j``, ``S_C = Diag(e^G_C) S0 + sum_j (k_j *
+    e^(G_C - G_j)) new_j^T``.  What the scalar gate never needed: ``e^(G_i -
+    G_j)`` no longer leaves the sum over d, and its two factors ``e^G_i`` and
+    ``e^-G_j`` cannot be taken apart — a channel that decays by e^-20 a token
+    has ``e^-G`` = e^1280 at a chunk's end, past float32.  So every exponent
+    that is formed is <= 0:
+
+    * between SUB-CHUNKS of ``SUB_CHUNK`` tokens (i in sub-chunk I, j in an
+      earlier one) both factors are taken against a reference point between
+      them, R_I = G at the last token before I: ``e^(G_i - R_I)`` and ``e^(R_I
+      - G_j)``, each in (0, 1] because G falls.  One matrix product a row of
+      sub-chunks, the same operations as the scalar form.  A factor that
+      underflows to 0 stands for a product smaller still;
+    * inside a sub-chunk the difference ``G_i - G_j`` is formed per channel
+      BEFORE the exponential (masked to -inf above the diagonal) and summed
+      over d: (SUB_CHUNK, SUB_CHUNK, d_k) a sub-chunk, a quarter of a chunk's
+      pairs.
+
+    The triangular system is solved as in ``scan`` (the nilpotent series);
+    beta up to 2 (negative eigenvalues) changes nothing of it.  Everything
+    float32, products at HIGHEST."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    pad = -S % chunk
+    sub = SUB_CHUNK if chunk % SUB_CHUNK == 0 else chunk
+    nI = chunk // sub
+    real = jnp.ones((B, S), bool) if valid is None else valid
+    real = jnp.pad(real, ((0, 0), (0, pad)))                         # (B, S')
+
+    def chunked(x):  # (B, S or S', H, ...) -> (B, H, N, chunk, ...) float32
+        x = x.astype(jnp.float32)
+        x = jnp.pad(x, ((0, 0), (0, S + pad - x.shape[1])) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape(B, -1, chunk, *x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    g = jnp.pad(log_alpha.astype(jnp.float32), ((0, 0), (0, pad), (0, 0), (0, 0)))
+    g = chunked(jnp.where(real[..., None, None], g, 0.0))             # (B, H, N, C, d_k)
+    b = chunked(_masked(beta, real[..., None], pad))                  # (B, H, N, C)
+    q, k, v = chunked(q), chunked(k), chunked(v)
+    g = jnp.cumsum(g, axis=-2)
+    N = g.shape[2]
+
+    def mm(eq, x, y):
+        return jnp.einsum(eq, x, y, precision=_EXACT)
+
+    qk = jnp.stack([q, k])                                           # (2, B, H, N, C, d_k)
+    lead = (B, H, N, nI)
+    g_sub = g.reshape(*lead, sub, dk)
+    # inside a sub-chunk: the difference first, then the exponential
+    inside = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    decay = jnp.exp(jnp.where(inside, g_sub[..., :, None, :] - g_sub[..., None, :, :], -jnp.inf))
+    k_sub = k.reshape(*lead, sub, dk)
+    diag = (qk.reshape(2, *lead, sub, 1, dk) * (k_sub[..., None, :, :] * decay)).sum(-1)
+    pairs = jnp.einsum("xbhnIij,IJ->xbhnIiJj", diag, jnp.eye(nI, dtype=jnp.float32))
+    if nI > 1:
+        # between sub-chunks: both sides against R_I, G at the last token before I
+        ref = jnp.concatenate(
+            [jnp.zeros((B, H, N, 1, dk), jnp.float32), g_sub[..., :-1, -1, :]], axis=-2)
+        left = qk.reshape(2, *lead, sub, dk) * jnp.exp(g_sub - ref[..., None, :])
+        before = (jnp.arange(chunk)[None, :] < (jnp.arange(nI) * sub)[:, None])[..., None]
+        right = k[..., None, :, :] * jnp.exp(
+            jnp.where(before, ref[..., None, :] - g[..., None, :, :], -jnp.inf))
+        pairs = pairs + mm("xbhnIid,bhnIjd->xbhnIij", left, right).reshape(pairs.shape)
+    within, kk = pairs.reshape(2, B, H, N, chunk, chunk)             # i >= j, decayed
+    k_beta, v_beta = k * b[..., None], v * b[..., None]
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = -jnp.where(strict, kk * b[..., None], 0.0)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+    solve, power = eye + a, a
+    for _ in range(max(0, (chunk - 1).bit_length() - 1)):
+        power = mm("bhnij,bhnjk->bhnik", power, power)
+        solve = mm("bhnij,bhnjk->bhnik", solve, eye + power)
+    u = mm("bhnij,bhnjd->bhnid", solve, v_beta)                      # (B, H, N, C, d_v)
+    w = mm("bhnij,bhnjd->bhnid", solve, k_beta * jnp.exp(g))
+    q_in = q * jnp.exp(g)                                            # against the chunk's S0
+    k_out = k * jnp.exp(g[..., -1:, :] - g)                          # into the chunk's end
+    total = jnp.exp(g[..., -1, :])[..., None]                        # (B, H, N, d_k, 1)
 
     def one(state, c):
         u_c, w_c, within_c, q_c, k_c, total_c = c

@@ -213,6 +213,34 @@ def test_gated_delta_step_kernel_compiles_for_v5e(
     assert 4 * 8 * dk * gd._heads_a_step(H, dk, dv) * dv * 4 < gd.VMEM_LIMIT_BYTES
 
 
+def test_kda_step_kernel_compiles_for_v5e(v5e_chip, compiled_not_interpreted, monkeypatch):
+    """Solar-Open2's one-token update at the cell's shape — 64 rows x 64 heads
+    x (128, 128), a decay a key channel, the 3 KDA layers' states stacked in
+    the cache's leaf: the kernel ``kda_step``, the donated leaf its output's
+    buffer and not a byte of it copied.  At one lane tile a head a
+    one-sublane load at a traced index does not lower (``dynamic load with
+    unaligned indices``: found here, before a chip, PR 59); the kernel takes
+    the row's gates out of their tile by a mask."""
+    monkeypatch.setattr(gd, "_interpret", lambda: False)
+    L, B, H, dk, dv = 3, 64, 64, 128, 128
+    assert gd.implementation(B, H, dk, dv) == "in_place"
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+
+    args = (f32(B, H, dk), f32(B, H, dk), f32(B, H, dv), f32(B, H, dk), f32(B, H),
+            f32(L, B, dk, H * dv),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip))
+    compiled = jax.jit(gd.step_layer, donate_argnums=(5,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1 and "kda_step" in text
+    assert "gated_delta_step" not in text
+    leaf = L * B * dk * H * dv * 4
+    assert leaf == 805_306_368 and mem.alias_size_in_bytes == leaf
+    assert mem.temp_size_in_bytes < 2**22, mem
+    assert 4 * gd.ROWS_A_STEP * dk * gd._heads_a_step(H, dk, dv) * dv * 4 < gd.VMEM_LIMIT_BYTES
+
+
 @pytest.mark.limit(300)
 def test_shortcut_decode_step_reads_every_weight_where_it_lies(
     v5e_chip, compiled_not_interpreted, monkeypatch

@@ -204,42 +204,58 @@ class TestTracing:
         ), names
 
     def test_disabled_tracing_adds_nothing(self, pushes, tracing_off):
+        """What the SWITCH governs.  The stall witness records its ``rt.stall``
+        spans whatever the switch says (``tracing.record``, PR 53), and
+        beside five other xdist workers this process's io loop does stop
+        for 20 ms now and then: those spans are the witness's, not a span
+        site's, and are left out of what is held to be empty (this test
+        failed on the driver's tier-1 runs since PR 53 for want of that)."""
+        from ray_tpu.core import stall
         from ray_tpu.core.runtime import get_runtime
         from ray_tpu.serve import llm
+
+        def switched(spans):  # as dicts (``spans``) or rows (``drain``, pushes)
+            return [s for s in spans
+                    if (s["name"] if isinstance(s, dict) else s[0]) != stall.SPAN]
 
         @ray_tpu.remote
         def untraced():
             return 1
 
         ray_tpu.get(untraced.remote(), timeout=60)
-        assert tracing.spans() == [] and tracing.drain() == []
+        assert switched(tracing.spans()) == [] and switched(tracing.drain()) == []
         assert tracing.current() is None  # no context-variable write
-        # a span site that is off allocates nothing in the recorder
-        tracemalloc.start()
-        try:
-            before = tracemalloc.take_snapshot()
-            for _ in range(1000):
-                if tracing.enabled():
-                    tracing.span("never")
-                # the engine asks once per decode step, then hands its
-                # span sites the step's span: None while tracing is off
-                life = tracing.root("never") if tracing.enabled() else None
-                with llm._part(life, "never"):
-                    pass
-            after = tracemalloc.take_snapshot()
-        finally:
-            tracemalloc.stop()
+        # a span site that is off allocates nothing in the recorder; a stop
+        # the witness records meanwhile does, so such a round is taken again
+        for _ in range(5):
+            stops = len(tracing.spans())
+            tracemalloc.start()
+            try:
+                before = tracemalloc.take_snapshot()
+                for _ in range(1000):
+                    if tracing.enabled():
+                        tracing.span("never")
+                    # the engine asks once per decode step, then hands its
+                    # span sites the step's span: None while tracing is off
+                    life = tracing.root("never") if tracing.enabled() else None
+                    with llm._part(life, "never"):
+                        pass
+                after = tracemalloc.take_snapshot()
+            finally:
+                tracemalloc.stop()
+            if len(tracing.spans()) == stops:
+                break
         grown = [
             d for d in after.compare_to(before, "filename")
             if d.size_diff > 0 and d.traceback[0].filename in (
                 tracing.__file__, llm.__file__)
         ]
         assert not grown, grown
-        # and nothing is sent: a push now carries no spans
+        # and nothing is sent: a push now carries no span of a span site
         rt = get_runtime()
         rt._run(rt.push_telemetry())
         time.sleep(2 * PUSH_INTERVAL_S)
-        assert not [p for _t, p in pushes if p.get("spans")]
+        assert not [p for _t, p in pushes if switched(p.get("spans") or [])]
 
     def test_span_records_error_attribute(self):
         with pytest.raises(ValueError):
