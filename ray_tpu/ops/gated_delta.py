@@ -43,7 +43,65 @@ bodies: a decay constant over the channels gives their results to 1e-5
 (``tests/test_solar_open2.py``), and nothing broadcasts a scalar gate to
 d_k channels.
 
-``scan`` is plain ``jax.numpy``.  The one-token update of a cache's layer
+``scan`` has two bodies, chosen from the operands' SHAPE in ONE place
+(``scan_implementation``): the Pallas kernel ``kda_chunk`` (``_scan_kernel``)
+where the decay is a key channel's, a head's d_k and d_v are whole 128-lane
+tiles, the chunk is 64 tokens and the heads are whole groups of eight —
+Solar-Open2's served prefills — and plain ``jax.numpy`` everywhere else (the
+scalar gate at any width: Olmo-Hybrid's 96 x 192 heads are no whole tile;
+tier-1's toy widths; the kernel's reference in the tests).  THE KERNEL keeps a
+chunk in fast memory: grid (row, group of 8 heads, chunk), the chunk axis last
+and sequential; a step takes the chunk's q, k, v, decay (64 tokens x 8 heads x
+128, as the mixer makes them: (R, S, H, d) blocks, a head's 64 rows picked out
+of the (token, head) sublanes by strided loads) and beta, and for a head at a
+time — two heads' chains side by side in a loop's trip, for the scheduler to
+interleave — forms the running sum of the decays (six shifted adds down the
+sublanes), the pair scores (``_scan_channels``'s algebra and stability argument
+unchanged: between sub-chunks of 16 both factors against the reference point on
+the MXU, only for the sub-chunk rows that have something before them; inside a
+sub-chunk the difference a channel before the exponential on the VPU / EUP, a
+column of pairs at a time, the sum over d_k along the lanes, the rows of a
+sublane tile at or under the diagonal only), solves the unit-lower-triangular
+system by forward substitution (``_substitute``: the earlier sub-chunks' rows
+through one product, inside a sub-chunk a row at a time on the VPU: no series,
+no inverse is formed), takes the three (d_k, d_v) products against the carried
+state and writes o.  The state (128 x 128 float32 a head) is the kernel's second
+output, whose block does not move along the chunk axis: set from ``state0`` at a
+group's first chunk, carried in fast memory, written out after its last.  HBM
+sees q, k, v, the decay and beta in and o out, once.  Every product is float32
+at ``Precision.HIGHEST`` (Mosaic's ``contract_precision<fp32>``), every
+exponent <= 0, no floor, no clamp.  ``_chunk_state_step`` and ``_substitute``
+know nothing of the channels: the scalar gate's pair scores (one product and
+one decay a pair) can be put under them as they are.
+
+What was swept for ``kda_chunk`` (a v5e, PR 60; the served segment alone, 1 row
+x 1,024 tokens x 64 heads x (128, 128), microseconds a (token, layer) — the
+parent's ``jax.numpy`` body 6.58 alone, 5.67 in the prefill program):
+
+  the solve (8 heads a step, 2 a trip)                                us
+    nilpotent series, ten 64 x 64 x 64 products at six passes         2.54
+      (the same with the series alone taken out: 1.20 — more than half the
+      kernel; with the sub-chunks' exponentials taken out as well: 1.19)
+    forward substitution by 16-row blocks, the diagonal blocks a row at a
+      time on the VPU (this body; 1.42 in the prefill program)        1.53
+  where the exponentials went: the 64 columns of pairs a (head, chunk)
+    inside the sub-chunks (VPU / EUP / the lane sums) cost nothing that
+    shows: 1.53 with them, 1.56 without — they run under the MXU's products
+  heads a trip of the loop, 1 / 2 / 4                                1.68 1.53 1.44
+    (1.2 / 2.6 / 4.0 s of Mosaic compile a kernel, six kernels a set-up:
+    2 is kept, ``setup_s`` is judged)
+  heads a grid step, 8 / 16 (both without the strided loads)         1.41 1.42
+  operand layout: (R, S, H, d) blocks as the mixer makes q, k, v and the
+    decay, a head's rows by strided loads: 0.11 of the 1.53 (1.41 with the
+    loads left out); (R, H, S, d) would take four transposes of 33.5 MB a
+    segment first, 0.33 at the chip's 819 GB/s at the least: not built.
+    In the prefill program nothing stands beside the kernel under
+    ``kda_scan`` but beta's (1024, 64) regrouping: no copy of an operand.
+  what is left (by taking parts out): the three state products 0.53, the
+  operands' DMA, the strided loads, the running sum and the factors 0.42, the
+  cross-sub-chunk products 0.10, the substitution ~0.4.
+
+The one-token update of a cache's layer
 (``step_layer``) has two bodies, chosen from the operand's SHAPE in ONE place
 (``implementation``):
 
@@ -503,11 +561,16 @@ def scan(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CHUNK):
     read.  S is padded up to whole chunks with such positions, so the
     final state is that of the real tokens whatever S is.
 
-    ``log_alpha`` (B, S, H, d_k), a decay a key channel: ``_scan_channels``."""
-    if log_alpha.ndim == k.ndim:
-        return _scan_channels(q, k, v, log_alpha, beta, state0, valid, chunk)
+    ``log_alpha`` (B, S, H, d_k), a decay a key channel: the kernel
+    ``kda_chunk`` or ``_scan_channels``, as ``scan_implementation`` reads the
+    shapes."""
     B, S, H, dk = q.shape
     dv = v.shape[-1]
+    channels = log_alpha.ndim == k.ndim
+    if scan_implementation(H, dk, dv, chunk, channels) == "kernel":
+        return _scan_kernel(q, k, v, log_alpha, beta, state0, valid)
+    if channels:
+        return _scan_channels(q, k, v, log_alpha, beta, state0, valid, chunk)
     pad = -S % chunk
     real = jnp.ones((B, S), bool) if valid is None else valid
     real = jnp.pad(real, ((0, 0), (0, pad)))[..., None]              # (B, S', 1)
@@ -659,6 +722,233 @@ def _scan_channels(q, k, v, log_alpha, beta, state0, valid=None, chunk: int = CH
     state, o = lax.scan(one, state0.astype(jnp.float32), over_chunks)
     o = jnp.moveaxis(o, 0, 2).reshape(B, H, -1, dv)[:, :, :S]        # (B, H, S, d_v)
     return jnp.moveaxis(o, 1, 2), state
+
+
+#: heads of one grid step of ``kda_chunk``: a sublane tile of the (tokens,
+#: heads, d) blocks the operands come in as
+HEADS_A_STEP = 8
+#: heads whose chains one trip of the kernel's loop over a step's heads holds
+#: side by side (the scheduler's to interleave)
+HEADS_A_TRIP = 2
+SCAN_VMEM_LIMIT_BYTES = 32 << 20
+
+
+def scan_implementation(heads: int, d_k: int, d_v: int, chunk: int, channels: bool) -> str:
+    """Which body a run of the chunked rule traces: ``"kernel"`` — ``kda_chunk``
+    — where the decay is a key channel's (``channels``), a head's d_k and d_v
+    are whole 128-lane tiles, the chunk is the kernel's 64 tokens and the
+    heads are whole groups of ``HEADS_A_STEP``; else ``"xla"``, the
+    ``jax.numpy`` bodies."""
+    if (channels and d_k % 128 == 0 and d_v % 128 == 0 and chunk == CHUNK
+            and heads % HEADS_A_STEP == 0):
+        return "kernel"
+    return "xla"
+
+
+def _mm(x, y, contract=((1,), (0,))):
+    """A float32 product on the MXU, exact (six bfloat16 passes)."""
+    return lax.dot_general(x, y, (contract, ((), ())), precision=_EXACT,
+                           preferred_element_type=jnp.float32)
+
+
+def _substitute(a, rhs, sub):
+    """``(I + A)^-1 rhs`` for ``a = -A`` strictly lower triangular (C, C), rhs
+    (C, W): forward substitution by blocks of ``sub`` rows.  What the earlier
+    blocks' rows add to a block's right side is one product on the MXU; inside
+    a block a row is final once the rows before it are, and is then taken off
+    the rows under it — its column of ``a`` along the lanes (a one-hot sum), the
+    row along the sublanes, float32 multiplies and adds on the VPU.  Exact: no
+    series, no inverse is formed."""
+    C, W = rhs.shape
+    lane = lax.broadcasted_iota(jnp.int32, (8, C), 1)
+    solved = []
+    for r0 in range(0, C, sub):
+        a_I, x = a[r0:r0 + sub], rhs[r0:r0 + sub]
+        if r0:
+            done = jnp.concatenate(solved + [jnp.zeros((C - r0, W), jnp.float32)], axis=0)
+            x = x + _mm(a_I, done)
+        tiles = [x[t:t + 8] for t in range(0, sub, 8)]
+        for j in range(sub - 1):
+            t0, at = divmod(j, 8)
+            x_j = jnp.broadcast_to(tiles[t0][at:at + 1], (8, W))
+            for t in range(t0 + (at == 7), len(tiles)):
+                column = jnp.sum(jnp.where(lane == r0 + j, a_I[8 * t:8 * t + 8], 0.0),
+                                 axis=1, keepdims=True)
+                tiles[t] = tiles[t] + column * x_j
+        solved += tiles
+    return jnp.concatenate(solved, axis=0)
+
+
+def _chunk_state_step(within, a, v_beta, k_in, q_in, k_out, total, state, sub):
+    """What every chunked delta rule does once its pair scores stand, one head
+    and one chunk of C tokens (the scalar gate's scores can be put under it as
+    they are): ``within`` (C, C) the decayed q k^T, i >= j; ``a`` (C, C) minus
+    the decayed beta k k^T, i > j; ``v_beta`` (C, d_v); ``k_in`` (C, d_k) the
+    decayed beta k against the chunk's first state, ``q_in`` q likewise;
+    ``k_out`` k decayed to the chunk's end; ``total`` (d_k, 1) the chunk's
+    whole decay; ``state`` (d_k, d_v).  Returns (o (C, d_v), the next
+    state)."""
+    C, d_v = v_beta.shape
+    rhs = jnp.concatenate([v_beta, k_in], axis=1)                    # (C, d_v + d_k)
+    uw = _substitute(a, rhs, sub)
+    seen = _mm(jnp.concatenate([uw[:, d_v:], q_in], axis=0), state)  # (2 C, d_v)
+    new = uw[:, :d_v] - seen[:C]
+    o = seen[C:] + _mm(within, new)
+    return o, total * state + _mm(k_out, new, ((0,), (0,)))
+
+
+def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref,
+                      qs, ks, vs, gs, bs, os_, *, sub):
+    """One grid step of ``kda_chunk``: one chunk of C tokens of ``hb`` heads
+    of one row.  q, k, g: (C, hb, d_k) and v, o: (C, hb, d_v) as the mixer
+    makes them; b (C, hb) beta; s0, s: (hb, d_k, d_v), the group's state —
+    ``s`` is the output's block, which stays in fast memory over the chunk
+    axis (its index does not move): set from ``s0`` at the first chunk,
+    carried, written out after the last.  Scratch (hb, C, d): the operands a
+    head in front, beta along the lanes, and o."""
+    C, hb, d_k = q_ref.shape
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    # a head's 64 rows out of the (token, head) sublanes: strided loads, static
+    for h in range(hb):
+        for src, dst in ((q_ref, qs), (k_ref, ks), (v_ref, vs), (g_ref, gs)):
+            dst[h] = src[:, h, :]
+        bs[h] = jnp.broadcast_to(b_ref[:, h:h + 1], (C, 128))
+
+    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    token = lax.broadcasted_iota(jnp.int32, (C, d_k), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (8, C), 1)
+    diag = (lax.broadcasted_iota(jnp.int32, (d_k, d_k), 0)
+            == lax.broadcasted_iota(jnp.int32, (d_k, d_k), 1))
+
+    def a_head(h):
+        # the decays' running sum down the chunk: log2(C) shifted adds
+        G = gs[h]
+        s = 1
+        while s < C:
+            G = G + jnp.where(token >= s, pltpu.roll(G, s, 0), 0.0)
+            s *= 2
+        gs[h] = G
+        q, k, beta = qs[h], ks[h], bs[h]
+        blocks = []
+        for I in range(C // sub):
+            r0 = I * sub
+            rows = slice(r0, r0 + sub)
+            G_I, q_I, k_I = G[rows], q[rows], k[rows]
+            # inside the sub-chunk: the difference a channel BEFORE the
+            # exponential, a pair's sum over d_k along the lanes; 8 rows (a
+            # sublane tile) at a time, those at or under the diagonal
+            tiles = sub // 8
+            acc = [[jnp.zeros((8, C), f32) for _ in range(tiles)] for _ in range(2)]
+            for j in range(sub):
+                g_j = gs[h, pl.ds(r0 + j, 1), :]
+                k_j = ks[h, pl.ds(r0 + j, 1), :]
+                for t in range(j // 8, tiles):
+                    part = slice(8 * t, 8 * t + 8)
+                    # above the diagonal (i < j, masked below) the difference
+                    # is positive: held at 0, no exponent above zero
+                    decayed = k_j * jnp.exp(jnp.minimum(G_I[part] - g_j, 0.0))
+                    for x, x_I in enumerate((q_I, k_I)):
+                        pair = jnp.sum(x_I[part] * decayed, axis=1, keepdims=True)
+                        acc[x][t] = jnp.where(lane == r0 + j, pair, acc[x][t])
+            inside = jnp.concatenate(
+                [jnp.concatenate(acc[x], axis=0) for x in range(2)], axis=0)  # (2 sub, C)
+            if I:
+                # between sub-chunks: both factors against R_I, G at the last
+                # token before I (both exponents <= 0, because G falls)
+                ref = gs[h, pl.ds(r0 - 1, 1), :]
+                left = jnp.exp(G_I - ref)
+                right = k[:r0] * jnp.exp(ref - G[:r0])
+                right = jnp.concatenate([right, jnp.zeros((C - r0, d_k), f32)], axis=0)
+                inside = inside + _mm(
+                    jnp.concatenate([q_I * left, k_I * left], axis=0), right, ((1,), (1,)))
+            blocks.append(inside)
+        within = jnp.concatenate([b[:sub] for b in blocks], axis=0)          # (C, C)
+        kk = jnp.concatenate([b[sub:] for b in blocks], axis=0)
+        within = jnp.where(row >= col, within, 0.0)
+        a = -jnp.where(row > col, kk * beta[:, :C], 0.0)
+        decay = jnp.exp(G)
+        last = gs[h, pl.ds(C - 1, 1), :]                                      # (1, d_k)
+        # the chunk's whole decay down the sublanes: the row, on a diagonal,
+        # summed along the lanes
+        total = jnp.sum(jnp.where(diag, jnp.exp(last), 0.0), axis=1, keepdims=True)
+        o, state = _chunk_state_step(
+            within, a, vs[h] * _lanes(beta, vs.shape[-1]),
+            k * _lanes(beta, d_k) * decay, q * decay, k * jnp.exp(last - G), total, s_ref[h], sub)
+        os_[h] = o
+        s_ref[h] = state
+
+    def trip(n, carry):
+        for i in range(HEADS_A_TRIP):
+            a_head(n * HEADS_A_TRIP + i)
+        return carry
+
+    lax.fori_loop(0, hb // HEADS_A_TRIP, trip, 0)
+    for h in range(hb):
+        o_ref[:, h, :] = os_[h]
+
+
+def _lanes(beta, width):
+    """beta (C, 128), a token's along all its lanes, as wide as ``width``."""
+    return beta if width == 128 else jnp.concatenate([beta] * (width // 128), axis=1)
+
+
+@jax.jit  # traced once a process, not once a layer and program: set-up is judged
+def _scan_kernel(q, k, v, log_alpha, beta, state0, valid=None):
+    """``_scan_channels`` as the kernel ``kda_chunk`` (module docstring): q,
+    k, log_alpha (B, S, H, d_k), v (B, S, H, d_v), beta (B, S, H), state0 (B,
+    H, d_k, d_v), as the mixer makes them.  The grid is (row, group of
+    ``HEADS_A_STEP`` heads, chunk), the chunk axis last and sequential."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    if scan_implementation(H, dk, dv, CHUNK, log_alpha.ndim == 4) != "kernel":
+        raise ValueError(
+            f"kda_chunk wants a decay a channel, whole lane tiles a head and whole "
+            f"groups of {HEADS_A_STEP} heads: q {q.shape}, v {v.shape}, "
+            f"log_alpha {log_alpha.shape}")
+    f32 = jnp.float32
+    C, hb = CHUNK, HEADS_A_STEP
+    pad = -S % C
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, log_alpha, beta))
+    if valid is not None:
+        # a position that is not valid is the identity: alpha 1, beta 0
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
+    N, groups = (S + pad) // C, H // hb
+    # beta a group's heads side by side: (C, hb) blocks whose last axis is whole
+    beta = jnp.moveaxis(beta.reshape(B, S + pad, groups, hb), 2, 1)
+
+    def tokens(d):
+        return pl.BlockSpec((None, C, hb, d), lambda b, j, c: (b, c, j, 0))
+
+    state = pl.BlockSpec((None, hb, dk, dv), lambda b, j, c: (b, j, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, sub=SUB_CHUNK),
+        grid=(B, groups, N),
+        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk),
+                  pl.BlockSpec((None, None, C, hb), lambda b, j, c: (b, j, c, 0)), state],
+        out_specs=[tokens(dv), state],
+        scratch_shapes=[pltpu.VMEM((hb, C, d), f32) for d in (dk, dk, dv, dk, 128, dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S + pad, H, dv), f32),
+            jax.ShapeDtypeStruct((B, H, dk, dv), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=SCAN_VMEM_LIMIT_BYTES,
+        ),
+        interpret=_interpret(),
+        name="kda_chunk",
+    )(q, k, v, g, beta, state0.astype(f32))
+    return o[:, :S], state
 
 
 def _masked(x, real, pad):

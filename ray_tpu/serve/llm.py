@@ -482,7 +482,11 @@ class LLMEngine:
         body updates the state at the decode step's shape: ``in_place``
         (the kernel, one pass over the layer's rows where they lie) or
         ``xla``, as ``ops/gated_delta.py:implementation`` reads it from
-        (rows, heads, d_k, d_v).  A config with window layers beside full
+        (rows, heads, d_k, d_v) — and ``gated_delta_scan``, which body a
+        prefill's chunked rule traces: ``kernel`` (``kda_chunk``, a chunk in
+        fast memory) or ``xla``, as ``ops/gated_delta.py:scan_implementation``
+        reads it from (heads, d_k, d_v, chunk, a decay a channel or
+        not).  A config with window layers beside full
         ones (``layer_types``: ``sliding_attention``), per kind (``full_`` /
         ``swa_``) and summed over the kind's layers, a head:
         ``*_keys_visible_step`` keys a decode step's rows could see (a window
@@ -601,6 +605,10 @@ class LLMEngine:
             out["gated_delta_step"] = gated_delta.implementation(
                 self.max_slots, c.linear_num_heads, c.linear_key_head_dim,
                 c.linear_value_head_dim,
+            )
+            out["gated_delta_scan"] = gated_delta.scan_implementation(
+                c.linear_num_heads, c.linear_key_head_dim, c.linear_value_head_dim,
+                c.linear_chunk, c.linear_kind == "kda",
             )
         return out
 

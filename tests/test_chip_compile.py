@@ -241,6 +241,52 @@ def test_kda_step_kernel_compiles_for_v5e(v5e_chip, compiled_not_interpreted, mo
     assert 4 * gd.ROWS_A_STEP * dk * gd._heads_a_step(H, dk, dv) * dv * 4 < gd.VMEM_LIMIT_BYTES
 
 
+@pytest.mark.parametrize("dk, dv", [(128, 256), (256, 128)])
+def test_kda_scan_kernel_takes_heads_of_several_lane_tiles(
+    v5e_chip, compiled_not_interpreted, monkeypatch, dk, dv
+):
+    """What ``scan_implementation`` promises beyond the served shape: d_k or
+    d_v of two lane tiles (one group of heads, two chunks) compiles too."""
+    monkeypatch.setattr(gd, "_interpret", lambda: False)
+    B, S, H = 1, 128, 8
+    assert gd.scan_implementation(H, dk, dv, gd.CHUNK, True) == "kernel"
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+
+    args = (f32(B, S, H, dk), f32(B, S, H, dk), f32(B, S, H, dv), f32(B, S, H, dk),
+            f32(B, S, H), f32(B, H, dk, dv))
+    text = jax.jit(gd.scan).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_chunk" in text
+
+
+def test_kda_scan_kernel_compiles_for_v5e(v5e_chip, compiled_not_interpreted, monkeypatch):
+    """Solar-Open2's chunked rule at the served shape — one row x a segment of
+    1,024 tokens x 64 heads x (128, 128), chunk 64, float32, a decay a key
+    channel: ONE kernel, ``kda_chunk``, whose operands are the mixer's own (no
+    transposed copy of q, k, v or the decay beside it: the program's
+    temporaries stay under one operand's size), and a grid step's blocks and
+    scratch inside the kernel's stated VMEM limit."""
+    monkeypatch.setattr(gd, "_interpret", lambda: False)
+    B, S, H, dk, dv = 1, 1024, 64, 128, 128
+    assert gd.scan_implementation(H, dk, dv, gd.CHUNK, True) == "kernel"
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+
+    args = (f32(B, S, H, dk), f32(B, S, H, dk), f32(B, S, H, dv), f32(B, S, H, dk),
+            f32(B, S, H), f32(B, H, dk, dv))
+    compiled = jax.jit(gd.scan).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1 and "kda_chunk" in text
+    assert mem.temp_size_in_bytes < B * S * H * dk * 4, mem
+    # a step's blocks (q, k, v, the decay in, o out; the state in and out), two
+    # buffers each, and the scratch: the same six a head in front
+    hb, C = gd.HEADS_A_STEP, gd.CHUNK
+    tokens, state = C * hb * 128 * 4, hb * dk * dv * 4
+    assert 2 * (5 * tokens + 2 * state) + 6 * tokens < gd.SCAN_VMEM_LIMIT_BYTES
+
+
 @pytest.mark.limit(300)
 def test_shortcut_decode_step_reads_every_weight_where_it_lies(
     v5e_chip, compiled_not_interpreted, monkeypatch

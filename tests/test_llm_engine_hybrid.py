@@ -15,6 +15,7 @@ import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import gated_delta
 from ray_tpu.serve.llm import LlamaDeployment, LLMEngine
 
 SLOTS, MAX_LEN = 3, 48
@@ -80,6 +81,10 @@ def test_stats_carry_the_recurrent_layers_counters(replica, streamed):
     assert stats["cache_bytes"]["gdn_state"] == lin * SLOTS * state
     # four heads of 16 are half a lane tile: the toy's update is XLA's body
     assert stats["gated_delta_step"] == "xla"
+    # and a decay a head takes the chunked rule's ``jax.numpy`` body whatever
+    # the widths: Olmo-Hybrid's served shape by its numbers, no engine built
+    assert stats["gated_delta_scan"] == "xla"
+    assert gated_delta.scan_implementation(30, 96, 192, 64, False) == "xla"
     assert eng.slots == [None] * SLOTS and eng.steps_launched_ahead_total > 0
     assert stats["programs"]["prefill_into_slot"] >= len(set(admitted))
 

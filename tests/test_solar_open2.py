@@ -135,6 +135,108 @@ def test_the_kernel_is_the_step_and_moves_one_layer(B, H, dk, dv):
     np.testing.assert_array_equal(out[2], leaf[2])
 
 
+def _whole_tile_inputs(seed, S=128, fast=True):
+    """The shape ``kda_chunk`` takes, small: 1 row x S tokens x 8 heads x (128,
+    128): one group of heads, interpret mode."""
+    return rule_inputs(seed, 1, S, 8, 128, 128, fast=fast)
+
+
+# one trace and one compile a shape: the cases share two (128 tokens; 100 with
+# ``valid``), interpret mode takes seconds for each
+_scan, _scan_xla, _recurrent = (jax.jit(f) for f in (gd.scan, gd._scan_channels, gd.recurrent))
+
+
+@pytest.mark.parametrize("case", [
+    "state0", "ragged", "fast_channel", "continued", "constant_decay", "two_tiles_a_value"])
+def test_the_kernel_is_the_chunked_rule(case):
+    """``kda_chunk`` in interpret mode — the very kernel — against the rule
+    token by token and against the ``jax.numpy`` body, at the tolerances that
+    body is held to."""
+    assert gd.scan_implementation(8, 128, 128, 64, True) == "kernel"
+
+    def close(got, want, atol=2e-5):
+        for g, w in zip(got, want):
+            assert np.isfinite(np.asarray(g)).all()
+            np.testing.assert_allclose(g, w, atol=atol)
+
+    def first(n, *xs):
+        return tuple(x[:, :n] for x in xs)
+
+    if case == "state0":
+        # a non-zero first state, beta up to 2, two chunks
+        q, k, v, g, beta, s0 = _whole_tile_inputs(11)
+        assert float(beta.max()) > 1.98 and float(jnp.abs(s0).max()) > 1
+        got = _scan(q, k, v, g, beta, s0)
+        close(got, _recurrent(q, k, v, g, beta, s0))
+        close(got, _scan_xla(q, k, v, g, beta, s0))
+    elif case == "ragged":
+        # S no whole number of chunks, ``valid`` with an end inside a chunk
+        q, k, v, g, beta, s0 = _whole_tile_inputs(12, 100)
+        valid = jnp.arange(100)[None, :] < 70
+        o, s = _scan(q, k, v, g, beta, s0, valid)
+        want_o, want_s = gd.recurrent(*first(70, q, k, v, g, beta), s0)
+        close((o[:, :70], s), (want_o, want_s))
+        xla_o, xla_s = _scan_xla(q, k, v, g, beta, s0, valid)
+        close((o[:, :70], s), (xla_o[:, :70], xla_s))
+    elif case == "fast_channel":
+        # a channel that falls by e^-20 a token (e^-1280 a chunk: its inverse is
+        # past float32) beside one that does not decay: no inf, no nan, and
+        # what the fast channel's row of the first state held is gone at once
+        q, k, v, g, beta, s0 = _whole_tile_inputs(13)
+        g = g.at[..., 1].set(0.0)
+        o, s = _scan(q, k, v, g, beta, s0)
+        close((o, s), _recurrent(q, k, v, g, beta, s0))
+        close(_scan(q, k, v, g, beta, s0.at[:, :, 0].add(100.0)), (o, s), atol=1e-5)
+    elif case == "continued":
+        # two runs that continue each other are one run: 70 tokens (the cut in
+        # the middle of a chunk), then the other 58 from the state they left
+        run = _whole_tile_inputs(14)
+        *tokens, s0 = run
+        want_o, want_s = _scan(*run)
+        o1, s1 = _scan(*first(100, *tokens), s0, jnp.arange(100)[None, :] < 70)
+        rest = (jnp.pad(x[:, 70:], ((0, 0), (0, 42)) + ((0, 0),) * (x.ndim - 2)) for x in tokens)
+        o2, s2 = _scan(*rest, s1, jnp.arange(100)[None, :] < 58)
+        close((jnp.concatenate([o1[:, :70], o2[:, :58]], 1), s2), (want_o, want_s))
+    elif case == "two_tiles_a_value":
+        # d_v of two lane tiles beside d_k of one
+        run = rule_inputs(16, 1, 64, 8, 128, 256)
+        assert gd.scan_implementation(8, 128, 256, 64, True) == "kernel"
+        close(gd.scan(*run), gd.recurrent(*run))
+    else:
+        # a decay constant over the channels is the scalar gate's ``scan``
+        q, k, v, g, beta, s0 = _whole_tile_inputs(15, fast=False)
+        scalar = g[..., 1]
+        wide = jnp.broadcast_to(scalar[..., None], g.shape)
+        close(_scan(q, k, v, wide, beta, s0), _scan(q, k, v, scalar, beta, s0), atol=1e-5)
+
+
+def test_the_chunked_rules_body_is_chosen_by_shape_in_one_place(monkeypatch):
+    """The toy widths take XLA's body, the served shape the kernel, a decay a
+    head (B, S, H) never the kernel; ``scan`` asks ``scan_implementation`` and
+    nothing else."""
+    solar = (64, 128, 128, 64)
+    assert gd.scan_implementation(*solar, True) == "kernel"
+    assert gd.scan_implementation(*solar, False) == "xla"
+    assert gd.scan_implementation(30, 96, 192, 64, False) == "xla"      # Olmo-Hybrid's
+    assert gd.scan_implementation(4, 16, 16, 4, True) == "xla"          # tier-1's toy
+    assert gd.scan_implementation(64, 128, 128, 32, True) == "xla"      # another chunk
+    assert gd.scan_implementation(4, 128, 128, 64, True) == "xla"       # half a group of heads
+    called = []
+    monkeypatch.setattr(gd, "_scan_kernel", lambda *a: called.append("kernel") or (None, None))
+    monkeypatch.setattr(gd, "_scan_channels", lambda *a: called.append("xla") or (None, None))
+    toy = rule_inputs(1, 1, 8, 4, 16, 16)
+    gd.scan(*toy, chunk=4)
+    served = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (1, 64, 8, 128), (1, 64, 8, 128), (1, 64, 8, 128), (1, 64, 8, 128), (1, 64, 8),
+        (1, 8, 128, 128))]
+    gd.scan(*served)
+    assert called == ["xla", "kernel"]
+    # the scalar gate's body is ``scan``'s own, whatever the widths
+    q, k, v, g, beta, s0 = rule_inputs(2, 1, 64, 8, 128, 128)
+    o, s = gd.scan(q, k, v, g[..., 0], beta, s0)
+    assert called == ["xla", "kernel"] and o.shape == v.shape
+
+
 def test_toy_states_take_xlas_body_with_the_channels_too():
     q, k, v, g, beta, s0 = rule_inputs(9, 3, 1, 4, 8, 16)
     assert gd.implementation(3, 4, 8, 16) == "xla"
@@ -287,6 +389,10 @@ def test_the_engine_serves_it_greedily(toy):
     assert a == greedy[20:26].tolist()
     assert counters["gdn_tokens_scanned"] == (21 + 10) * 6
     assert counters["moe_held_pairs_total"] > 0 and counters["gated_delta_step"] == "xla"
+    # four heads of 16, chunk 4: the toy's prefills trace XLA's body; the served
+    # shape (64 heads of 128 x 128, chunk 64, a decay a channel) the kernel
+    assert counters["gated_delta_scan"] == "xla"
+    assert gd.scan_implementation(64, 128, 128, 64, True) == "kernel"
 
 
 def test_speculation_and_diffusion_are_refused_beside_the_state(toy):
