@@ -2864,6 +2864,7 @@ class GcsServer:
         else:
             node.resources_available = node.resources_available.subtract(demand)
         node.inflight_grants += 1
+        asked_at = time.monotonic()
         try:
             reply = await node.conn.call(
                 "lease_worker",
@@ -2905,7 +2906,7 @@ class GcsServer:
                         "placement group was removed while the lease was "
                         "being granted"
                     )
-        except Exception:
+        except Exception as e:
             if pg_ref is not None:
                 pg = self.placement_groups[pg_ref[0]]
                 # refund only if the bundle still lives on this node — it
@@ -2922,6 +2923,15 @@ class GcsServer:
             else:
                 node.resources_available = node.resources_available.add(demand)
             self._kick_pending()
+            if isinstance(e, asyncio.TimeoutError):
+                # lease_worker's: it says nothing of its own, and the
+                # client fails its queued tasks with these words
+                raise rpc.RpcError(
+                    f"node {node.node_id.hex()[:12]} handed lease "
+                    f"{lease_id} no worker in "
+                    f"{time.monotonic() - asked_at:.0f} s "
+                    f"(worker_start_timeout_s)"
+                ) from None
             raise
         finally:
             # success continues to the LeaseEntry registration below with

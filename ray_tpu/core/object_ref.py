@@ -74,8 +74,6 @@ class ObjectRef:
             m = _rt_mod
             if m is None:
                 return  # no runtime ever existed: nothing to release
-            rt = m._global_runtime
-            if rt is not None:
-                rt.on_ref_deleted(self.object_id)
+            m.finalized("ref", self.object_id)
         except Exception:
             pass  # interpreter teardown

@@ -81,8 +81,9 @@ class WorkerEntry:
     rtenv_key: str = ""  # runtime-env binding (core/runtime_env.py)
     venv_key: str = ""   # pip-env interpreter this worker was spawned with
     lease_id: Optional[int] = None
-    # spawned for a lease that is waiting for it (rpc_lease_worker):
-    # never pooled, so no other lease can take it meanwhile
+    # a lease (rpc_lease_worker) is waiting for it to start or is
+    # binding it: never pooled, so no other lease can take it
+    # meanwhile, and not idle
     spoken_for: bool = False
     tpu_chips: tuple = ()
     started_at: float = field(default_factory=time.monotonic)
@@ -97,7 +98,10 @@ class WorkerEntry:
 
     @property
     def idle(self) -> bool:
-        return self.lease_id is None and self.conn is not None
+        return (
+            self.lease_id is None and self.conn is not None
+            and not self.spoken_for
+        )
 
 
 class Raylet:
@@ -1315,13 +1319,15 @@ class Raylet:
             try:
                 await self._wait_for_worker(w)
             except BaseException:
-                # nobody holds the chips picked above
+                # nobody holds the chips picked above, and the worker
+                # is anyone's should it still come up
                 self._release_accel_env(accel_env)
-                raise
-            finally:
-                # handed out below; or, after a failed start, anyone's
-                # should it still come up
                 w.spoken_for = False
+                raise
+        # this lease's until it is bound below: in no pool, and not the
+        # memory monitor's idle worker either (killed as one between
+        # its worker_ready and its lease, it took the grant with it)
+        w.spoken_for = True
         if w.bound_env is None:
             try:
                 await w.conn.call(
@@ -1347,6 +1353,7 @@ class Raylet:
             self._release_accel_env(accel_env)
         w.lease_id = p["lease_id"]
         w.leased_at = time.monotonic()
+        w.spoken_for = False
         if faults.ACTIVE is not None:
             self._chaos_on_lease_grant(w)
         return {

@@ -442,9 +442,12 @@ class Connection:
     async def _handle_request(self, msg_id, method, payload):
         try:
             result = await self.handler(self, method, payload)
-        except ConnectionLost:
-            return
         except Exception as e:
+            if isinstance(e, ConnectionLost) and self._closed:
+                return  # the caller is gone
+            # a ConnectionLost from a call the handler made to ANOTHER
+            # peer is an answer like any other: unanswered, this caller
+            # waited out its whole timeout for a worker that had died
             logger.debug("handler %s raised: %r", method, e)
             result = _safe_exc(e)
             try:

@@ -76,6 +76,21 @@ def set_runtime(rt: Optional["Runtime"]):
     _global_runtime = rt
 
 
+def finalized(kind: str, what) -> None:
+    """The whole of a finaliser (a ``__del__``, a ``weakref.finalize``
+    callback) that concerns the runtime.  The collector runs it on
+    whatever thread allocated last, inside whatever that thread holds —
+    the io loop under ``_ref_lock`` included — so it may not take a
+    lock, wait for the loop or log: it says what died and the io loop
+    does the work (``Runtime._drain_finalized``).  ``kind`` is "ref"
+    (an ObjectID), "stream" (a streaming task's id) or "call" (a
+    callable that may wait for the loop: it runs on the executor)."""
+    rt = _global_runtime
+    if rt is not None:
+        rt._finalized.append((kind, what))
+        rt._schedule_ref_flush()
+
+
 # --------------------------------------------------------------------------
 # Lease management (client side of scheduling)
 # --------------------------------------------------------------------------
@@ -404,7 +419,11 @@ class Runtime:
         # holder set per object; this process reports itself as a holder
         # while any local ObjectRef instance or in-flight task arg needs
         # the object, with events batched per flush window) ----
+        # a plain Lock on purpose: no finaliser asks for it (they only
+        # enqueue, below), so whoever waits for it chose to
         self._ref_lock = threading.Lock()
+        self._finalized: deque = deque()  # (kind, what): see finalized()
+        self._finaliser_calls: set = set()  # "call"s the executor runs now
         self._local_refs: Dict[bytes, int] = {}   # live ObjectRef instances
         self._task_holds: Dict[bytes, int] = {}   # held as in-flight task deps
         self._ref_registered: set = set()         # ref_add sent (or pending)
@@ -736,6 +755,19 @@ class Runtime:
     def shutdown(self):
         if self._closed:
             return
+
+        async def _owed():
+            # what finalisers asked for in the last window is still
+            # owed: a compiled DAG dropped just now has channels to
+            # unlink, and nobody flushes once _closed is set
+            self._drain_finalized()
+            if self._finaliser_calls:
+                await asyncio.wait(self._finaliser_calls)
+
+        try:
+            self._run(_owed(), timeout=5)
+        except Exception:
+            pass
         self._closed = True
 
         async def _close():
@@ -2095,6 +2127,7 @@ class Runtime:
     async def _acquire_lease(self, class_key, resources, strategy):
         st = self._classes[class_key]
         pending_backoff = None  # built on first LEASE_PENDING only
+        asked_at = time.monotonic()
         try:
             while True:
                 try:
@@ -2160,10 +2193,16 @@ class Runtime:
         except Exception as e:
             # fail queued tasks if the demand is infeasible
             if st.queue and isinstance(e, rpc.RemoteCallError):
+                remote = e.remote_exception
+                why = (
+                    f"the request for {resources} failed after "
+                    f"{time.monotonic() - asked_at:.1f} s: "
+                    f"{str(remote) or type(remote).__name__}"
+                )
                 for task in st.queue:
-                    self._fail_task(task, TaskError(
-                        "SchedulingError", str(e.remote_exception), "", "lease"
-                    ))
+                    self._fail_task(
+                        task, TaskError("SchedulingError", why, "", "lease")
+                    )
                 st.queue.clear()
             return
         finally:
@@ -3107,9 +3146,11 @@ class Runtime:
                     self._schedule_ref_flush()
 
     def _schedule_ref_flush(self):
-        # caller holds _ref_lock (every call site takes it; the flush
-        # callback clears the flag under it too) — locked, just not
-        # lexically here, which is past what rtrace can see
+        # One wake a window, from any thread, with or without _ref_lock
+        # (a finaliser has none).  GIL-ordered like _submit_to_loop: the
+        # flush clears the flag and THEN looks at the inbox, so an
+        # enqueue that read a stale True is seen by that look, and a
+        # stale False costs one empty flush.
         if self._ref_flush_scheduled or self._closed:
             return
         # rtlint: disable-next=RT301
@@ -3120,11 +3161,39 @@ class Runtime:
                 self._flush_ref_events,
             )
         except RuntimeError:
-            # loop closing; same caller-held _ref_lock as the set above
-            # rtlint: disable-next=RT301
-            self._ref_flush_scheduled = False
+            pass  # loop closing: nothing is left to flush to
+
+    def _drain_finalized(self):
+        """What the finalisers since the last window had to do, in their
+        order.  On the io loop and outside every lock: the explicit
+        forms (``on_ref_deleted``, ``stream_abandon``) take theirs
+        freely here, and what may wait for this loop goes to the
+        executor (``_finaliser_calls`` while it runs: ``shutdown`` waits
+        for those before it stops the loop under them)."""
+        q = self._finalized  # one consumer: this loop
+        while q:
+            kind, what = q.popleft()
+            try:
+                if kind == "ref":
+                    self.on_ref_deleted(what)
+                elif kind == "stream":
+                    self.stream_abandon(what)
+                else:
+                    call = self._loop.run_in_executor(None, what)
+                    self._finaliser_calls.add(call)
+                    call.add_done_callback(self._finaliser_call_done)
+            except Exception:
+                logger.exception("finaliser work (%s) failed", kind)
+
+    def _finaliser_call_done(self, call) -> None:
+        self._finaliser_calls.discard(call)
+        # as the ``__del__`` that asked for it did: a failure is
+        # nobody's to handle
+        if not call.cancelled() and call.exception() is not None:
+            logger.debug("finaliser's call failed: %r", call.exception())
 
     def _flush_ref_events(self):
+        self._drain_finalized()
         with self._ref_lock:
             add = []
             revisit = []
@@ -3171,7 +3240,7 @@ class Runtime:
             self._pending_ref_del.clear()
             self._pending_ref_del.update(held_dels)
             self._ref_flush_scheduled = False
-            if revisit:
+            if revisit or self._finalized:
                 self._schedule_ref_flush()
         if (add or dels) and self.gcs and not self.gcs.closed:
             # rides the object-notify coalescer: a ref window that
@@ -3375,9 +3444,9 @@ class ObjectRefGenerator:
     def __del__(self):
         if not self._exhausted:
             try:
-                get_runtime().stream_abandon(self._task_id)
+                finalized("stream", self._task_id)
             except Exception:
-                pass
+                pass  # interpreter teardown
 
     def __repr__(self):
         return f"ObjectRefGenerator({self._task_id.hex()[:16]})"

@@ -22,10 +22,12 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.common import serialization
+from ray_tpu.core.runtime import finalized
 from ray_tpu.dag.channel import (
     Channel,
     ChannelClosedError,
@@ -415,6 +417,6 @@ class CompiledDAG:
     def __del__(self):
         try:
             if not self._torn_down:
-                self.teardown(timeout=1.0)
+                finalized("call", functools.partial(self.teardown, 1.0))
         except Exception:
-            pass
+            pass  # interpreter teardown

@@ -27,6 +27,7 @@ overlaps the caller's step N compute.
 from __future__ import annotations
 
 import builtins
+import functools
 import os
 from typing import Any, Callable, Dict, Iterator, List, Optional, Union
 
@@ -34,6 +35,7 @@ import numpy as np
 import pyarrow as pa
 
 import ray_tpu
+from ray_tpu.core.runtime import finalized
 from ray_tpu.data import block as block_mod
 from ray_tpu.data.block import Block, BlockAccessor, concat_blocks
 
@@ -309,7 +311,8 @@ class Dataset:
         def _one_ref_dead():
             remaining["n"] -= 1
             if remaining["n"] == 0:
-                _kill_actor_pool(pool)
+                # in the collector: only say so (core/runtime.finalized)
+                finalized("call", functools.partial(_kill_actor_pool, pool))
 
         for r in out:
             weakref.finalize(r, _one_ref_dead)

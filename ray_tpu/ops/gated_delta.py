@@ -40,7 +40,7 @@ token before the later sub-chunk (both exponents <= 0, because the sum
 falls), inside a sub-chunk the difference is formed a channel before the
 exponential.  The scalar entry points keep their signatures and their
 bodies: a decay constant over the channels gives their results to 1e-5
-(``tests/test_solar_open2.py``), and nothing broadcasts a scalar gate to
+(``tests/test_solar_open2_ops.py``), and nothing broadcasts a scalar gate to
 d_k channels.
 
 ``scan`` has two bodies, chosen from the operands' SHAPE in ONE place

@@ -15,6 +15,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.core.runtime import finalized
 
 ROUTE_REFRESH_S = 1.0
 
@@ -437,12 +438,10 @@ class DeploymentResponseGenerator:
         # replica's stream state and ongoing-count, or the autoscaling
         # signal counts a phantom in-flight request forever
         try:
-            if not self._done:
-                self.cancel()
-            else:
-                self._settle()
+            if not self._settled:
+                finalized("call", self.cancel)
         except Exception:
-            pass
+            pass  # interpreter teardown
 
 
 class DeploymentHandle:

@@ -48,6 +48,15 @@ import pytest  # noqa: E402
 #: with ``@pytest.mark.limit(seconds)``.
 TEST_LIMIT_S = 180
 
+#: Seconds a test gets once a test of its module has run past its
+#: limit: the module's shared cluster (a module- or session-scoped
+#: fixture) is suspect from then on, and an honest test of a sound one
+#: does not need more.  A hung module costs one limit, not one a test.
+SUSPECT_MODULE_LIMIT_S = 30
+
+#: The modules of this process in which a test ran past its limit.
+_suspect_modules = set()
+
 
 def pytest_configure(config):
     config.addinivalue_line(
@@ -65,7 +74,8 @@ def pytest_configure(config):
 @contextlib.contextmanager
 def time_limit(seconds: float, name: str):
     """Fail the body, by name, once it has run ``seconds``: a hang
-    costs one named failure and not the run.  SIGALRM's handler runs in
+    costs one named failure and not the run, and the rest of its module
+    the short limit (``limit_for``).  SIGALRM's handler runs in
     the main thread between two bytecodes (a blocking lock or sleep is
     interrupted for it): it dumps every thread's stack into the failure
     message and raises ``pytest.fail``.  A hang inside a C call never
@@ -74,6 +84,7 @@ def time_limit(seconds: float, name: str):
     entry are put back on exit, whatever the outcome."""
 
     def on_alarm(signum, frame):
+        _suspect_modules.add(name.partition("::")[0])
         with tempfile.TemporaryFile() as f:
             faulthandler.dump_traceback(file=f, all_threads=True)
             f.seek(0)
@@ -97,11 +108,21 @@ def time_limit(seconds: float, name: str):
         signal.signal(signal.SIGALRM, old_handler)
 
 
+def limit_for(nodeid: str, own=None) -> float:
+    """The seconds ``nodeid`` gets: its marker's (``own``) or
+    ``TEST_LIMIT_S``, and no more than ``SUSPECT_MODULE_LIMIT_S`` once
+    a test of its module has run past its limit."""
+    seconds = TEST_LIMIT_S if own is None else own
+    if nodeid.partition("::")[0] in _suspect_modules:
+        seconds = min(seconds, SUSPECT_MODULE_LIMIT_S)
+    return seconds
+
+
 @pytest.fixture(autouse=True)
 def _test_limit(request):
     nodeid = request.node.nodeid
     marker = request.node.get_closest_marker("limit")
-    seconds = marker.args[0] if marker else TEST_LIMIT_S
+    seconds = limit_for(nodeid, marker.args[0] if marker else None)
     # Under ``--dist loadfile`` xdist hands a file whose worker died
     # back to the next worker WITH the test that killed it, up to nine
     # times over.  A file per running test, in the run's own name, lets

@@ -262,6 +262,28 @@ class TestTimeLimit:
         assert handler_after is handler
         assert remaining - 5 < remaining_after <= remaining
 
+    def test_the_rest_of_a_module_that_hung_gets_the_short_limit(self):
+        """A module-scoped cluster that hung one test hangs the next:
+        they wait 30 s each for it, not 180."""
+        import conftest
+
+        hung = "tests/test_hung_module.py"
+        assert conftest.limit_for(f"{hung}::test_b") == conftest.TEST_LIMIT_S
+        with pytest.raises(pytest.fail.Exception):
+            with conftest.time_limit(0.2, f"{hung}::TestA::test_a[case]"):
+                threading.Event().wait(30)
+        try:
+            short = conftest.SUSPECT_MODULE_LIMIT_S
+            assert conftest.limit_for(f"{hung}::test_b") == short
+            assert conftest.limit_for(f"{hung}::test_c", 300) == short
+            assert conftest.limit_for(f"{hung}::test_d", 20) == 20
+            assert (
+                conftest.limit_for("tests/test_sound_module.py::test_a")
+                == conftest.TEST_LIMIT_S
+            )
+        finally:
+            conftest._suspect_modules.discard(hung)
+
     def test_body_inside_its_limit_leaves_no_trace(self):
         from conftest import time_limit
 

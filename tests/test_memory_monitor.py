@@ -34,6 +34,68 @@ class TestMeasurement:
         assert 0.0 <= frac <= 1.5  # cgroup current can briefly exceed max
 
 
+def test_a_worker_being_handed_to_a_lease_is_not_the_idle_victim():
+    """Under pressure the monitor kills idle workers first.  A worker
+    that had said ``worker_ready`` and was not yet bound to the lease
+    that spawned it counted as one: killed there, it took the grant
+    with it, and the retry of the very task the kill was to make room
+    for failed (`lease failed with SchedulingError:`, 60 s later)."""
+    import asyncio
+
+    from ray_tpu.common.ids import WorkerID
+    from ray_tpu.core import raylet as raylet_mod
+    from ray_tpu.core.memory_monitor import MemoryMonitor
+
+    class Proc:
+        poll = staticmethod(lambda: None)
+
+    class Conn:
+        closed = False
+
+        def __init__(self):
+            self.peer_info = {}
+            self.binding = asyncio.Event()
+            self.bound = asyncio.Event()
+
+        async def call(self, method, payload):
+            assert method == "bind_env"
+            self.binding.set()
+            await self.bound.wait()
+            return True
+
+    r = raylet_mod.Raylet.__new__(raylet_mod.Raylet)
+    r.draining = r._fencing = r._closing = False
+    r._idle_by_env, r.workers = {}, {}
+
+    def spawn(**kw):
+        w = raylet_mod.WorkerEntry(worker_id=WorkerID.random(), proc=Proc())
+        r.workers[w.worker_id] = w
+        return w
+
+    r._spawn_worker = spawn
+    monitor = MemoryMonitor(r)
+
+    async def scenario():
+        lease = asyncio.ensure_future(r.rpc_lease_worker(
+            None, {"lease_id": 1, "resources": {"CPU": 1}}))
+        await asyncio.sleep(0)
+        (w,) = r.workers.values()
+        assert monitor.pick_victim() == (None, "")  # still starting
+        conn = Conn()
+        await r.rpc_worker_ready(
+            conn, {"worker_id": w.worker_id.binary(), "address": "w:1"})
+        await asyncio.wait_for(conn.binding.wait(), 5)
+        # ready, its lease waits for bind_env: nobody's idle worker
+        assert not w.idle and monitor.pick_victim() == (None, "")
+        conn.bound.set()
+        await asyncio.wait_for(lease, 5)
+        assert monitor.pick_victim() == (w, "busy")
+        await r.rpc_release_worker(None, {"worker_id": w.worker_id.binary()})
+        assert monitor.pick_victim() == (w, "idle")
+
+    asyncio.run(scenario())
+
+
 @pytest.fixture(scope="module")
 def oom_cluster(tmp_path_factory):
     fake = tmp_path_factory.mktemp("oom") / "usage"

@@ -10,6 +10,7 @@ re-execute their producing task from owner-held lineage
 """
 
 import gc
+import threading
 import time
 
 import numpy as np
@@ -142,3 +143,167 @@ class TestLineageReconstruction:
             rt.store.delete(oid)
             rt._run(rt.gcs.call("free_objects", {"object_ids": [oid]}))
         assert ray_tpu.get(r2, timeout=120)[0] == 10
+
+
+def _abandoned_generator(rt):
+    """A streaming task's generator dropped mid-stream; gone means the
+    runtime forgot the stream."""
+
+    @ray_tpu.remote
+    def count():
+        for i in range(10_000):
+            yield i
+
+    gen = count.remote()
+    assert ray_tpu.get(next(gen), timeout=60) == 0
+    tid = gen.task_id
+    return gen, lambda: tid not in rt._streams, 1.0
+
+
+def _dropped_serve_stream(rt):
+    """A serve stream dropped mid-iteration; gone means the router no
+    longer counts a request in flight on the replica."""
+    from ray_tpu import serve
+
+    @serve.deployment
+    class Inf:
+        def forever(self):
+            i = 0
+            while True:
+                yield i
+                i += 1
+
+    h = serve.run(Inf.bind(), name="dropped_stream", route_prefix=None)
+    gen = h.options(method_name="forever", stream=True).remote()
+    assert next(gen) == 0
+    router = gen._router
+    assert sum(router._inflight.values()) == 1
+    return gen, lambda: sum(router._inflight.values()) == 0, 1.0
+
+
+def _unfinished_dag(rt):
+    """A compiled DAG nobody tore down; gone means its channels left
+    /dev/shm, which ``teardown(1.0)`` does after waiting up to its
+    second for the actors' loops."""
+    import os
+
+    from ray_tpu.dag import InputNode
+
+    @ray_tpu.remote
+    class Adder:
+        def add(self, x):
+            return x + 1
+
+    a = Adder.remote()
+    with InputNode() as inp:
+        out = a.add.bind(inp)
+    dag = out.experimental_compile()
+    assert dag.execute(1).get(timeout=60) == 2
+    names = list(dag._all_channel_names)
+    assert names and all(os.path.exists(f"/dev/shm/{n}") for n in names)
+    return dag, lambda: not any(
+        os.path.exists(f"/dev/shm/{n}") for n in names
+    ), 3.0
+
+
+class TestFinalisersOnlyEnqueue:
+    """The collector runs a finaliser on whatever thread allocated last,
+    inside whatever that thread holds — the driver's io loop, under
+    ``_ref_lock``, included.  So a finaliser says what died
+    (``core/runtime.finalized``) and the loop does the work later."""
+
+    @pytest.mark.limit(20)
+    def test_a_ref_collected_inside_the_flush_leaves_the_loop_running(
+        self, cluster, monkeypatch
+    ):
+        @ray_tpu.remote
+        def one():
+            return 1
+
+        @ray_tpu.remote
+        def slow():
+            time.sleep(1.0)
+            return 2
+
+        rt = get_runtime()
+        gc.collect()
+        gc.disable()
+        try:
+            ref = one.remote()
+            assert ray_tpu.get(ref, timeout=10) == 1
+            oid = ref.object_id.binary()
+            assert oid in rt.memory_store
+            cycle = [ref]
+            cycle.append(cycle)
+            del ref, cycle  # only the cyclic collector finds it now
+
+            # the flush re-arms itself under _ref_lock when an add has to
+            # be looked at again (an in-flight task's return): make THAT
+            # allocation the one the collector runs in
+            real = rt._loop.call_soon_threadsafe
+            collected = []
+
+            def collecting(*args):
+                if (
+                    not collected
+                    and threading.current_thread() is rt._thread
+                    and rt._ref_lock.locked()
+                ):
+                    collected.append(True)
+                    gc.collect()
+                return real(*args)
+
+            monkeypatch.setattr(
+                rt._loop, "call_soon_threadsafe", collecting
+            )
+            pending = slow.remote()
+            _wait_for(lambda: collected, timeout=5,
+                      msg="a collection inside the flush")
+            assert ray_tpu.get(one.remote(), timeout=10) == 1
+            _wait_for(lambda: oid not in rt.memory_store, timeout=5,
+                      msg="the collected ref's value released")
+            assert ray_tpu.get(pending, timeout=10) == 2
+        finally:
+            gc.enable()
+
+    @pytest.mark.limit(60)
+    @pytest.mark.parametrize(
+        "make",
+        [_abandoned_generator, _dropped_serve_stream, _unfinished_dag],
+    )
+    def test_collected_on_the_io_loop_itself(self, cluster, make):
+        """The work a finaliser asks for waits for the io loop (a
+        cancel, a get, a wait): collected ON the loop it must neither
+        wait for itself nor hold the loop while it runs."""
+        rt = get_runtime()
+        gc.collect()
+        gc.freeze()  # the loop's collection walks what this test made, no more
+        try:
+            obj, gone, within = make(rt)
+            assert not gone()
+            cycle = [obj]
+            cycle.append(cycle)
+            del obj, cycle
+            took = []
+
+            def collect():
+                t0 = time.monotonic()
+                gc.collect()
+                took.append(time.monotonic() - t0)
+
+            rt._loop.call_soon_threadsafe(collect)
+            _wait_for(lambda: took, timeout=10, msg="the collection")
+            assert ray_tpu.get(ray_tpu.put(7), timeout=10) == 7
+            deadline = time.monotonic() + within
+            while not gone() and time.monotonic() < deadline:
+                time.sleep(0.02)
+            assert gone(), f"the finaliser's work was not done in {within} s"
+            assert took[0] < 0.5, (
+                f"the finaliser held the io loop for {took[0]:.2f} s"
+            )
+        finally:
+            gc.unfreeze()
+            if make is _dropped_serve_stream:
+                from ray_tpu import serve
+
+                serve.shutdown()

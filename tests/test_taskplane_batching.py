@@ -173,6 +173,33 @@ def test_single_call_soon_flushes_same_tick():
     asyncio.run(main())
 
 
+def test_a_handler_that_lost_another_peer_still_answers_its_caller():
+    """A handler that calls a third process and finds it gone raises
+    ConnectionLost, which says nothing about ITS caller's connection.
+    Taken for that, the request went unanswered: the GCS waited out
+    ``worker_start_timeout_s`` (60 s, the CPU debited meanwhile) for a
+    raylet whose worker had died between ``worker_ready`` and
+    ``bind_env``."""
+
+    async def main():
+        async def handler(conn, method, payload):
+            raise rpc.ConnectionLost("connection worker@1 is closed")
+
+        srv = rpc.Server(handler)
+        await srv.start()
+        conn = await rpc.connect(srv.address, name="t")
+        try:
+            with pytest.raises(rpc.RemoteCallError) as err:
+                await conn.call("lease_worker", {}, timeout=5.0)
+            assert isinstance(err.value.remote_exception, rpc.ConnectionLost)
+            assert "worker@1" in str(err.value.remote_exception)
+        finally:
+            await conn.close()
+            await srv.close()
+
+    asyncio.run(main())
+
+
 def test_burst_coalesces_into_one_frame():
     """A burst of call_soon requests issued within one tick must leave
     the client as ONE wire frame (the push_task_batch behavior), and
