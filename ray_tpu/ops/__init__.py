@@ -5,8 +5,9 @@ The serving path's attention kernels each say by shape, in ONE function
 ``kv_decode_attention`` (a K/V config's every-row step; for a layer KIND
 also key heads off a lane tile's edge, a sink, and a window kind's one block
 of rolling slots), ``kv_prefill_attention`` (a layer kind's prefill: grouped
-queries, keys wider than values, a window and a sink in one flash kernel
-over the live tiles; XLA's dense body for toy heads),
+queries, keys wider than values, a window and a sink in one Pallas kernel
+— a full layer's live tiles under the online softmax, a window layer's
+whole band a grid step; XLA's dense body for toy heads),
 ``latent_decode_attention`` (a latent config's decode step) and
 ``latent_prefill_attention`` (a latent config's prefill: the flash kernel
 for a run of four or more whole 512-token tiles with value heads of whole

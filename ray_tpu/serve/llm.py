@@ -498,7 +498,11 @@ class LLMEngine:
         pairs their attention computed scores for (whole live tiles under
         ``ops/kv_prefill_attention.py``'s kernel): read over visible is each
         body's over-compute; and which bodies the shapes chose,
-        ``kv_prefill_attention`` (``flash`` | ``dense``) and
+        ``kv_prefill_attention`` (``flash`` | ``dense``), ``kv_prefill_tiles``
+        ({``full``, ``swa``}: (queries, keys, query heads) a grid step of the
+        prefill kernel takes at these heads: a tile pair of the online
+        softmax without a window, a step's queries and the band a sub-tile
+        of them meets with one, ``ops/kv_prefill_attention.py:tiles``) and
         ``kv_decode_attention`` ({``full``, ``swa``}: ``streamed`` |
         ``slab``).  These count on the device (``attn_keys`` rides the
         cache): ``kv_keys_*_step`` are not reported for such a config."""
@@ -586,6 +590,12 @@ class LLMEngine:
             # which bodies the two programs were traced with, by their shapes
             out["kv_prefill_attention"] = kv_prefill_attention.implementation(
                 c.head_dim, c.value_dim)
+            out["kv_prefill_tiles"] = {
+                kind: kv_prefill_attention.tiles(c.num_heads // heads, window)
+                for kind, heads, window in (
+                    ("full", c.num_kv_heads, 0),
+                    ("swa", c.sliding.num_kv_heads, c.sliding.window))
+            }
             out["kv_decode_attention"] = {
                 kind: kv_decode_attention.implementation(
                     length, c.head_dim, kv_heads=heads, v_head_dim=c.value_dim)

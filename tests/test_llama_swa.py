@@ -232,24 +232,92 @@ def test_what_does_not_go_together_is_refused_by_name():
 
 # ---- the kernels, interpreted, against XLA's bodies --------------------------
 
+def prefill_operands(run, heads, kv_heads, sunk, seed):
+    """One run's q, k (192-wide), v (128-wide) and, where ``sunk``, a sink a
+    query head that holds a fair share of a query's mass."""
+    k = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(k[0], (run, heads, 192))
+    kk = jax.random.normal(k[1], (run, kv_heads, 192))
+    v = jax.random.normal(k[2], (run, kv_heads, 128))
+    return q, kk, v, (jax.random.normal(k[3], (heads,)) + 3.0 if sunk else None)
+
+
+@pytest.fixture
+def scored(monkeypatch):
+    """(rows x keys) of every product of scores the interpreted kernel RUNS
+    (a body under a ``pl.when`` that does not hold adds nothing)."""
+    seen = []
+    scores = kpa._scores
+
+    def counted(q, k, scale):
+        jax.debug.callback(lambda: seen.append(q.shape[0] * k.shape[0]))
+        return scores(q, k, scale)
+
+    monkeypatch.setattr(kpa, "_scores", counted)
+    return seen
+
+
 @pytest.mark.limit(170)
 @pytest.mark.parametrize("window, sunk", [(0, False), (128, True), (100, True), (128, False)])
 def test_the_prefill_kernel_is_the_dense_body(window, sunk):
     """Grouped queries (4 heads on 2), 192 | 128, a ragged run of 300."""
-    k = jax.random.split(jax.random.key(window + sunk), 4)
-    q = jax.random.normal(k[0], (300, 4, 192))
-    kk = jax.random.normal(k[1], (300, 2, 192))
-    v = jax.random.normal(k[2], (300, 2, 128))
-    sink = jax.random.normal(k[3], (4,)) + 3.0 if sunk else None
+    q, kk, v, sink = prefill_operands(300, 4, 2, sunk, window + sunk)
     assert kpa.implementation(192, 128) == "flash" and kpa.implementation(12, 8) == "dense"
     got = kpa.attention(q, kk, v, window=window, sink=sink)
     want = kpa._dense(q, kk, v, window, sink)
     assert got.shape == (300, 4, 128)
     assert float(jnp.abs(got - want).max()) < 5e-6
-    tiles = 3 if window else 1                                   # of 128 | of 512
-    pairs = (2 * tiles - 1) * 128 * 128 if window else 512 * 512
+    # a window: 3 sub-tiles of 128 queries, two blocks of 128 keys each but
+    # the first; none: one tile of 512 queries against one of 1,024 keys
+    pairs = (2 * 3 - 1) * 128 * 128 if window else 512 * 1024
     assert kpa.pairs_computed(300, 192, 128, window) == pairs
     assert kpa.pairs_computed(300, 12, 8, window) == 300 * 300
+
+
+@pytest.mark.limit(60)
+@pytest.mark.parametrize("heads, kv_heads", [(4, 2), (8, 1)])
+@pytest.mark.parametrize("run, tile", [(90, 512), (256, 128), (300, 256)])
+@pytest.mark.parametrize("window, sunk", [(128, True), (100, False), (1, True), (300, False)])
+def test_a_window_layers_band_in_one_step_is_the_dense_body(
+        monkeypatch, scored, window, sunk, run, tile, heads, kv_heads):
+    """A run under one block, one of whole tiles and a ragged one whose last
+    step holds a sub-tile without a token; a step's queries one sub-tile (the
+    blocks before it another step's), two, and the whole run; windows of a
+    block, under one, of one key and of three blocks; every head of a KV
+    group stacked in a step's rows."""
+    monkeypatch.setattr(kpa, "WINDOW_TILE", tile)
+    q, kk, v, sink = prefill_operands(run, heads, kv_heads, sunk, window + run + heads)
+    got = kpa.attention(q, kk, v, window=window, sink=sink)
+    jax.effects_barrier()
+    assert got.shape == (run, heads, 128)
+    assert float(jnp.abs(got - kpa._dense(q, kk, v, window, sink)).max()) < 5e-6
+    # a sub-tile that holds a token meets its own block and the blocks of
+    # its band before it that there are: a block for a window of 1
+    blocks, back = -(-run // 128), -(-(window - 1) // 128)
+    pairs = sum(min(i, back) + 1 for i in range(blocks)) * 128 * 128
+    assert kpa.pairs_computed(run, 192, 128, window) == pairs
+    assert sum(scored) == pairs * heads
+    assert kpa.tiles(heads // kv_heads, window, run) == (
+        min(tile, blocks * 128), (back + 1) * 128, heads // kv_heads)
+
+
+@pytest.mark.limit(60)
+@pytest.mark.parametrize("heads, kv_heads, sunk", [(4, 2, False), (8, 1, True), (4, 2, True)])
+def test_a_full_layers_tiles_carry_the_softmax_up_to_the_diagonal(
+        monkeypatch, scored, heads, kv_heads, sunk):
+    """Tiles of 128 queries against 256 keys over a run of 600: a tile
+    wholly under the diagonal, one the diagonal crosses in its first half,
+    one in its second, and a padded tail of queries and of keys."""
+    monkeypatch.setattr(kpa, "TILE", 128)
+    monkeypatch.setattr(kpa, "TILE_KEYS", 256)
+    q, kk, v, sink = prefill_operands(600, heads, kv_heads, sunk, heads + sunk)
+    got = kpa.attention(q, kk, v, sink=sink)
+    jax.effects_barrier()
+    assert float(jnp.abs(got - kpa._dense(q, kk, v, 0, sink)).max()) < 5e-6
+    pairs = sum(i // 2 + 1 for i in range(5)) * 128 * 256
+    assert kpa.pairs_computed(600, 192, 128) == pairs
+    assert sum(scored) == pairs * heads
+    assert kpa.tiles(heads // kv_heads) == (128, 256, 2)
 
 
 @pytest.mark.limit(170)
@@ -333,6 +401,9 @@ def test_stats_carry_the_kinds_counters(replica, streamed, cfg):
     assert stats["swa_pairs_read_run"] == cfg.sliding_layers * sum(n * n for n in admitted)
     assert stats["swa_pairs_visible_run"] < stats["full_pairs_visible_run"]
     assert stats["kv_prefill_attention"] == "dense"
+    # what the kernel would take at these heads: 4 on 1 a tile pair of two, 4
+    # on 2 both of a group against a band of two blocks
+    assert stats["kv_prefill_tiles"] == {"full": (512, 1024, 2), "swa": (512, 256, 2)}
     assert stats["kv_decode_attention"] == {"full": "slab", "swa": "slab"}
     assert stats["cache_bytes"]["swa_k"] == cfg.sliding_layers * SLOTS * WINDOW * 2 * 12 * 4
     assert stats["cache_bytes"]["k"] == cfg.kv_layers * SLOTS * MAX_LEN * 12 * 4
