@@ -129,8 +129,9 @@ _PER_HEAD_NORM = ("qwen3", "qwen3_moe", "sdar", "sdar_moe")
 
 def llama_config_from_hf(hf_config, **overrides):
     """Map a transformers LlamaConfig — or a Qwen3-MoE / SDAR one: ``head_dim``,
-    the per-head ``q_norm`` / ``k_norm``, the experts and ``norm_topk_prob`` —
-    onto LlamaConfig."""
+    the per-head ``q_norm`` / ``k_norm``, the experts and ``norm_topk_prob``;
+    or an Ouro one (``model_type`` ``ouro``): ``ouro_fields`` — onto
+    LlamaConfig."""
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import LlamaConfig
@@ -167,8 +168,36 @@ def llama_config_from_hf(hf_config, **overrides):
         )
     if family == "solar_open2":
         kwargs.update(solar_open2_fields(hf_config))
+    if family == "ouro":
+        kwargs.update(ouro_fields(hf_config))
     kwargs.update(overrides)
     return LlamaConfig(**kwargs)
+
+
+def ouro_fields(hf_config) -> Dict[str, Any]:
+    """Ouro's keys (``model_type`` ``ouro``, a LoopLM) as LlamaConfig fields:
+    the stack run ``total_ut_steps`` times a token with shared weights, the
+    final norm behind every pass, K and V of every (pass, layer), the
+    four-norm sandwich block, the exit gate and its ``early_exit_threshold``
+    (1 as published; anything else LlamaConfig refuses)."""
+    get = _getter(hf_config)
+    if get("use_sliding_window") or get("rope_scaling"):
+        raise NotImplementedError(
+            "an Ouro config with a sliding window or scaled rotary positions is "
+            "not written")
+    return dict(
+        loop_passes=int(get("total_ut_steps")), sandwich_norm=True,
+        early_exit_threshold=float(get("early_exit_threshold", 1.0)),
+        rope_theta=float(get("rope_theta")),
+    )
+
+
+def _getter(hf_config):
+    """``get(key, default=None)`` of a published config, be it a dict (a
+    benchmark configuration's file) or a transformers config object."""
+    if isinstance(hf_config, dict):
+        return hf_config.get
+    return lambda k, d=None: getattr(hf_config, k, d)
 
 
 def solar_open2_fields(hf_config) -> Dict[str, Any]:
@@ -186,8 +215,7 @@ def solar_open2_fields(hf_config) -> Dict[str, Any]:
     ASSUMED (the DeepSeek-V3 line's, whose key names these are)."""
     from ray_tpu.models.llama import FULL, LINEAR
 
-    get = (hf_config.get if isinstance(hf_config, dict)
-           else lambda k, d=None: getattr(hf_config, k, d))
+    get = _getter(hf_config)
     lin = get("linear_attn_config")
     if get("kda_use_full_proj") or get("first_k_dense_replace"):
         raise NotImplementedError(
@@ -258,6 +286,16 @@ def llama_params_from_hf(model, **config_overrides):
         "final_norm": j(sd["model.norm.weight"]),
     }
     blocks = params["blocks"]
+    if config.sandwich_norm:
+        # Ouro's block: ``x += N2(Attn(N1(x)))``; ``x += N4(SwiGLU(N3(x)))``
+        blocks["attn_norm_out"] = j(stacked("model.layers.{i}.input_layernorm_2.weight"))
+        blocks["mlp_norm_out"] = j(
+            stacked("model.layers.{i}.post_attention_layernorm_2.weight"))
+    if config.loop_passes > 1:
+        params["exit_gate"] = {
+            "w": j(sd["model.early_exit_gate.weight"].reshape(E)),
+            "b": j(sd["model.early_exit_gate.bias"].reshape(1)),
+        }
     if config.qk_norm == "head":
         blocks["q_norm"] = j(stacked("model.layers.{i}.self_attn.q_norm.weight"))
         blocks["k_norm"] = j(stacked("model.layers.{i}.self_attn.k_norm.weight"))

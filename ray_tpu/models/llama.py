@@ -105,6 +105,23 @@ selection-only bias among ``num_experts`` experts and ``zero_experts``
 identity ("zero-compute") experts, whose term is weight x input and costs
 no matrix; ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` scale the two
 latents.  Cached paths only, like every latent config.
+
+``loop_passes`` T > 1 is a LOOPED decoder (Ouro's LoopLM): the whole stack of
+``num_layers`` blocks is run T times a token with the SAME weights, the final
+norm behind every pass (pass t + 1 reads normed states), logits from pass T.
+The passes ride the ONE layer loop — one ``lax.scan`` over (pass, layer),
+``_looped`` — so a program holds one block and not T x L unrolled; every
+(pass, layer) pair keeps K and V of its own — cache layer ``pass * num_layers
++ l``, T x ``num_layers`` of them for ``num_layers`` parameter layers
+(``kv_layers``) — because block l in pass t attends over what block l wrote IN
+PASS t at the earlier positions.
+An exit gate (``params["exit_gate"]``, embed -> 1 with bias) reads each pass's
+output, ``lambda_t = sigmoid(x_t w + b)``; the exit distribution is
+``exit_distribution``'s.  At the published ``early_exit_threshold`` 1 only the
+last pass reaches it: every token runs every pass, the gate changes no logit,
+and the cache counts what a lower threshold could save (``loop_exit_mass``).
+``sandwich_norm`` is such a model's block: a norm before AND behind each
+sub-layer, four scales a block.
 """
 
 from __future__ import annotations
@@ -302,6 +319,19 @@ class LlamaConfig:
     # head's nope key and its value carry the factor, the rotary key not
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # a LOOPED decoder (Ouro's ``total_ut_steps``): the stack of num_layers
+    # blocks run this many times a token with the same weights, final_norm
+    # behind every pass, a K/V cache layer a (pass, layer), an exit gate
+    # that reads every pass's output (1: each layer once, no gate)
+    loop_passes: int = 1
+    # the cumulative exit probability at which a token's logits are taken
+    # (Ouro's key of this name).  1 — only the last pass reaches it, every
+    # token runs every pass — is what is written
+    early_exit_threshold: float = 1.0
+    # the block's residual path, a third form beside ``post_norm``'s two:
+    # x + norm_out(f(norm_in(x))), a norm on BOTH sides of each sub-layer
+    # (``attn_norm`` / ``attn_norm_out``, ``mlp_norm`` / ``mlp_norm_out``)
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if not self.head_dim:
@@ -377,6 +407,30 @@ class LlamaConfig:
             )
         if self.zero_experts and not self.num_experts:
             raise ValueError("zero_experts stand behind num_experts routed experts")
+        if self.loop_passes < 1:
+            raise ValueError(f"loop_passes counts the passes, 1 or more; got {self.loop_passes}")
+        if self.early_exit_threshold != 1:
+            raise NotImplementedError(
+                "early_exit_threshold under 1 lets rows leave the loop at different "
+                "passes, which is a change to the scheduler and not to the model: "
+                "every token runs all loop_passes passes (threshold 1) in what is written"
+            )
+        if self.loop_passes > 1 and (
+                self.latent or self.layer_types or self.block_form != "serial"
+                or self.mtp_layers or self.mask_block > 1 or self.num_experts
+                or self.first_dense_layers):
+            raise NotImplementedError(
+                "loop_passes > 1 is written for plain K/V attention over a dense "
+                "SwiGLU: no latent attention, layer_types, shortcut block, multi-"
+                "token-prediction module, block mask, experts or leading dense blocks"
+            )
+        if self.sandwich_norm and (
+                self.post_norm or self.latent or self.layer_types
+                or self.block_form != "serial"):
+            raise NotImplementedError(
+                "sandwich_norm is the serial K/V block's third residual form: not "
+                "with post_norm, latent attention, layer_types or the shortcut block"
+            )
 
     @property
     def period(self) -> tuple:
@@ -392,8 +446,10 @@ class LlamaConfig:
     def kv_layers(self) -> int:
         """Layers that keep K and V per token at every position (``k`` /
         ``v``): all, or the full-attention ones of a config with
-        ``layer_types``."""
-        return self.layer_types.count(FULL) if self.layer_types else self.num_layers
+        ``layer_types`` — one a (pass, layer) of a looped config."""
+        if self.layer_types:
+            return self.layer_types.count(FULL)
+        return self.num_layers * self.loop_passes
 
     @property
     def sliding_layers(self) -> int:
@@ -430,8 +486,10 @@ class LlamaConfig:
     def cache_layers(self) -> int:
         """Layers of token state in the cache: one an attention of the
         model's (layer l of a shortcut-connected config owns 2l and 2l +
-        1) and the multi-token-prediction module's behind them."""
-        return self.num_layers * self.mixers_per_layer + self.mtp_layers
+        1; layer l of a looped config owns ``t * num_layers + l`` in pass t)
+        and the multi-token-prediction module's behind them."""
+        return (self.num_layers * self.mixers_per_layer * self.loop_passes
+                + self.mtp_layers)
 
     @property
     def expert_layers(self) -> int:
@@ -595,6 +653,30 @@ class LlamaConfig:
         return LlamaConfig.tiny(**defaults)
 
     @staticmethod
+    def ouro_2_6b(**kw) -> "LlamaConfig":
+        """Ouro-2.6B's published shape, a LoopLM: 48 sandwich-norm blocks of
+        plain multi-head attention (16 x 128, rotary base 1e6) over a SwiGLU
+        of 5,632, run 4 times a token with shared weights, 192 K/V cache
+        layers, the exit gate at threshold 1."""
+        defaults = dict(
+            vocab_size=49152, max_seq_len=65536, num_layers=48, num_heads=16,
+            num_kv_heads=16, head_dim=128, embed_dim=2048, mlp_dim=5632,
+            rope_theta=1e6, rms_eps=1e-6, loop_passes=4, sandwich_norm=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def tiny_loop(**kw) -> "LlamaConfig":
+        """``ouro_2_6b`` at toy widths: 3 blocks of 4 heads of 16, 3 passes."""
+        defaults = dict(
+            num_layers=3, num_kv_heads=4, rope_theta=1e6, rms_eps=1e-6,
+            loop_passes=3, sandwich_norm=True,
+        )
+        defaults.update(kw)
+        return LlamaConfig.tiny(**defaults)
+
+    @staticmethod
     def longcat_flash(**kw) -> "LlamaConfig":
         """LongCat-Flash's published language model (LongCat-Flash-Omni's
         ``config.json``): 28 shortcut-connected double layers, latent
@@ -724,6 +806,9 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if c.sandwich_norm:
+        dense.update({"attn_norm_out": ("layers", "embed"),
+                      "mlp_norm_out": ("layers", "embed")})
     expert = dict(dense, **{
         "w_router": ("layers", "embed", None),
         "w_gate": ("layers", "expert", "embed", "mlp"),
@@ -780,6 +865,8 @@ def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
         }
     if not c.tie_embeddings:
         out["lm_head"] = ("vocab", "embed")
+    if c.loop_passes > 1:
+        out["exit_gate"] = {"w": ("embed",), "b": (None,)}
     if c.mtp_layers:
         out["mtp"] = {
             "enorm": ("embed",), "hnorm": ("embed",), "head_norm": ("embed",),
@@ -794,8 +881,9 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
     """One stack of ``layers`` blocks: a leading layer axis on every
     leaf.  ``experts``: the feed-forward is the expert layer (router,
     ``experts_here`` SwiGLUs of width ``expert_dim``, the shared expert
-    if any), else one SwiGLU of width ``mlp_dim``.  ``kind`` LINEAR: the
-    mixer is the gated delta rule (``_gated_delta_mixer`` names its tensors;
+    if any), else one SwiGLU of width ``mlp_dim``.  With ``sandwich_norm``
+    two more scales a block, ``attn_norm_out`` and ``mlp_norm_out``.  ``kind``
+    LINEAR: the mixer is the gated delta rule (``_gated_delta_mixer`` names its tensors;
     ``a_log`` = log U(0, 16) and ``dt_bias`` = 1 as ``fla``'s
     ``GatedDeltaNet`` starts them; under ``linear_kind`` "kda" ``_kda_mixer``
     names them, ``a_log`` = log U(1, 16) a head and ``dt_bias`` a key channel
@@ -901,6 +989,9 @@ def _init_blocks(rng, config: LlamaConfig, layers: int, experts: bool,
         "w_up": norm(k[6], (L, *X, E, M), std),
         "w_down": norm(k[7], (L, *X, M, E), resid_std),
     })
+    if c.sandwich_norm:
+        blk.update({"attn_norm_out": jnp.ones((L, E), dt),
+                    "mlp_norm_out": jnp.ones((L, E), dt)})
     if c.block_form == "shortcut":
         blk.update({
             "wd_gate": norm(more(10), (*A, E, c.mlp_dim), std),
@@ -960,6 +1051,12 @@ def init(rng, config: LlamaConfig) -> Params:
         params["lm_head"] = norm(
             jax.random.fold_in(k0, 1), (c.vocab_size, c.embed_dim), std
         )
+    if c.loop_passes > 1:
+        # the exit gate: embed -> 1 with bias, read off every pass's output
+        params["exit_gate"] = {
+            "w": norm(jax.random.fold_in(rng, 1 << 24), (c.embed_dim,), std),
+            "b": jnp.zeros((1,), dt),
+        }
     if c.mtp_layers:
         if c.mtp_layers != 1 or not c.latent or c.index_topk:
             raise NotImplementedError(
@@ -1315,7 +1412,10 @@ def _norm_in(x, p, name: str, config: LlamaConfig):
 
 def _norm_out(y, p, name: str, config: LlamaConfig):
     """A sub-layer's output as it is, or (``post_norm``) normed: the same
-    scale on the other side of the sub-layer."""
+    scale on the other side of the sub-layer — or (``sandwich_norm``) normed
+    by a scale of its own, ``<name>_out``, the input's norm staying too."""
+    if config.sandwich_norm:
+        return _rmsnorm(y, p[name + "_out"], config.rms_eps)
     return _rmsnorm(y, p[name], config.rms_eps) if config.post_norm else y
 
 
@@ -1542,7 +1642,8 @@ def _segments(seq: tuple):
     return ([(seq[:head], 1)] if head else []) + rest
 
 
-def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int = 1):
+def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int = 1,
+                close=None):
     """The ONE layer loop: ``carry`` through every block in layer order.
     ``block(carry, p, cache_layer) -> (carry, aux)``: p one layer's
     parameters (and ``p["layer"]``, its index in its stack; with
@@ -1556,7 +1657,9 @@ def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int =
     periods whose body runs a period's layers in order, each on its own
     kind's stack: a period of (3 linear, 1 full) is four blocks in the loop's
     body and not ``num_layers`` unrolled.  A pattern with an irregular head
-    is two such scans (``_segments``)."""
+    is two such scans (``_segments``).  A looped config (``loop_passes``) runs
+    its one stack that many times in ONE scan (``_looped``), ``close`` behind
+    every pass."""
     c = config
     if c.layer_types:
         order = _layer_order(c)
@@ -1619,6 +1722,8 @@ def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int =
                 kept.setdefault(k, []).append(v.reshape(-1, *v.shape[2:]))
             done += len(kinds) * periods
         return carry, {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
+    if c.loop_passes > 1:
+        return _looped(params, c, carry, block, close, unroll)
     kept = {}
     for name, _layers, first, _experts in _stacks(c):
         xs, whole = _layer_params(params[name], c)
@@ -1631,6 +1736,52 @@ def _layer_loop(params: Params, config: LlamaConfig, carry, block, unroll: int =
         for k, v in aux.items():
             kept.setdefault(k, []).append(v)
     return carry, {k: v[0] if len(v) == 1 else jnp.concatenate(v) for k, v in kept.items()}
+
+
+def _looped(params: Params, config: LlamaConfig, carry, block, close, unroll: int = 1):
+    """``_layer_loop`` of a looped config: the one stack ``loop_passes`` times
+    over, the same weights every pass, as ONE ``lax.scan`` over (pass, layer)
+    — the program holds one block and not T x L unrolled — whose body takes
+    layer l's slice of each stacked leaf where it uses it, hands ``block`` the
+    cache layer ``t num_layers + l``, and behind a pass's last layer runs
+    ``close(x) -> (x, y)`` on the carry's FIRST leaf (the residual: the final
+    norm, and the exit gate's reading ``y``) under a ``lax.cond`` that the
+    rest of the carry — a cache of gigabytes — stays out of.  Returns (carry,
+    {"closed": y stacked over the passes}).
+
+    One scan and not a scan over passes around the layers' scan: through two
+    nested loops XLA carried the weights and the cache in layouts of its own
+    and copied them at the program's start — two of wq / wk / wv (2 x 384 MB
+    a decode step) and, in a prefill, K and V whole (2 x 3 GB: Ouro-2.6B's
+    prefill did not compile for a 16 GB chip); compiled for a described v5e,
+    PR 63."""
+    c = config
+    (name, layers, _first, _experts), = _stacks(c)
+    stack = params[name]
+    leaves, tree = jax.tree.flatten(carry)
+    shape = jax.eval_shape(lambda x: close(x)[1], leaves[0])
+    closed = jnp.zeros((c.loop_passes, *shape.shape), shape.dtype)
+
+    def body(state, i):
+        carry, closed = state
+        t, l = i // layers, i % layers
+        with jax.named_scope("loop_pass"):
+            p = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stack)
+            carry, _aux = block(carry, dict(p, layer=l), i)
+            leaves, tree = jax.tree.flatten(carry)
+
+            def end(x, closed):
+                x, y = close(x)
+                return x, lax.dynamic_update_index_in_dim(closed, y, t, 0)
+
+            leaves[0], closed = lax.cond(
+                l == layers - 1, end, lambda x, closed: (x, closed), leaves[0], closed)
+        return (jax.tree.unflatten(tree, leaves), closed), None
+
+    (carry, closed), _ = lax.scan(
+        body, (carry, closed), jnp.arange(c.loop_passes * layers), unroll=unroll)
+    return carry, {"closed": closed}
 
 
 def _features_and_choices(params: Params, tokens, config: LlamaConfig):
@@ -1655,12 +1806,39 @@ def _features_and_choices(params: Params, tokens, config: LlamaConfig):
         x, experts = fn(carry, p, positions, c)
         return x, {} if experts is None else {"experts": experts}
 
-    x, aux = _layer_loop(params, c, x, block, unroll=max(1, c.scan_unroll))
-    return _rmsnorm(x, params["final_norm"], c.rms_eps), aux.get("experts")
+    def close(x):  # behind a pass of a looped config: the norm, the gate
+        x = _rmsnorm(x, params["final_norm"], c.rms_eps)
+        return x, _exit_lambda(params, x, c)
+
+    x, aux = _layer_loop(params, c, x, block, unroll=max(1, c.scan_unroll), close=close)
+    if c.loop_passes > 1:  # the last pass has normed it
+        return x, None, aux["closed"]
+    return _rmsnorm(x, params["final_norm"], c.rms_eps), aux.get("experts"), None
+
+
+def _exit_lambda(params: Params, x, config: LlamaConfig):
+    """A pass's final-normed output (..., E) -> the exit gate's ``lambda``
+    (...,) float32: sigmoid(x w + b)."""
+    gate = params["exit_gate"]
+    z = jnp.einsum("...e,e->...", x, gate["w"].astype(config.dtype),
+                   preferred_element_type=jnp.float32)
+    return jax.nn.sigmoid(z + gate["b"].astype(jnp.float32)[0])
+
+
+def exit_distribution(lam):
+    """(T, ...) ``lambda_t`` of a looped model's T passes -> (T, ...) ``p_t``,
+    the probability of leaving after pass t: ``lambda_t prod_{s<t} (1 -
+    lambda_s)`` for t < T, and what is left, ``prod_{s<T} (1 - lambda_s)``,
+    for the last.  A token's logits come from the first pass whose cumulative
+    sum reaches ``early_exit_threshold``: at 1, the last."""
+    stayed = jnp.cumprod(1.0 - lam[:-1], axis=0)
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), stayed], axis=0)
+    return jnp.concatenate([lam[:-1] * before[:-1], before[-1:]], axis=0)
 
 
 def features(params: Params, tokens, config: LlamaConfig):
-    """tokens (B, S) int32 → final-RMSNorm features (B, S, E)."""
+    """tokens (B, S) int32 → final-RMSNorm features (B, S, E): of a looped
+    config, the last pass's."""
     return _features_and_choices(params, tokens, config)[0]
 
 
@@ -1670,6 +1848,15 @@ def expert_choices(params: Params, tokens, config: LlamaConfig):
     router probability.  For comparisons with a reference's routing
     (which pairs swap under rounding); no serving path calls it."""
     return _features_and_choices(params, tokens, config)[1]
+
+
+def exit_gates(params: Params, tokens, config: LlamaConfig):
+    """tokens (B, S) int32 → (passes, B, S) float32: the exit gate's
+    ``lambda_t`` behind every pass of a looped config's no-cache forward
+    (``exit_distribution`` makes ``p_t`` of them)."""
+    if config.loop_passes == 1:
+        raise ValueError("a config with one pass has no exit gate")
+    return _features_and_choices(params, tokens, config)[2]
 
 
 def _head_weight(params: Params, config: LlamaConfig):
@@ -1738,12 +1925,20 @@ def flops_per_token(config: LlamaConfig, seq_len: Optional[int] = None) -> float
     ``experts_per_token``: under an even router the share ``experts_here /
     router_outputs`` of its k choices falls on an expert held here (8 of
     LongCat-Flash's 12 on a real expert where all 512 are held), and N
-    holds that many experts a layer in place of all the held ones."""
+    holds that many experts a layer in place of all the held ones.  A
+    looped config (``loop_passes``) counts a block's weights once a PASS
+    (``num_params`` counts them once) and its attention over every (pass,
+    layer)'s keys (``kv_layers``)."""
     c = config
     S = seq_len or c.max_seq_len
     n = num_params(c) - c.vocab_size * c.embed_dim * (
         0 if c.tie_embeddings else 1
     )
+    if c.loop_passes > 1:
+        # a weight is held once and used once a pass: the blocks', the final
+        # norm's and the gate's ``loop_passes`` times, the head's once
+        head = c.vocab_size * c.embed_dim
+        n = (n - head) * c.loop_passes + head
     if c.zero_experts:
         chosen_here = c.experts_per_token * c.experts_here / c.router_outputs
         n -= c.expert_layers * 3 * c.embed_dim * c.expert_dim * (
@@ -1905,7 +2100,16 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
     A shortcut-connected config (``block_form``) keeps a latent config's
     one kind of state, TWO cache layers a layer: its attentions' ``ckv``
     and ``mla_keys`` rows 2l and 2l + 1, and one row a layer of the
-    experts' counters."""
+    experts' counters.
+
+    A looped config (``loop_passes`` T) keeps ``k`` / ``v`` of T x
+    ``num_layers`` layers, layer l's of pass t at ``t num_layers + l``, and
+    two running totals: ``loop_passes`` (2,) int32, the passes run summed
+    over every (row, call) — T a row-step while every token runs every pass,
+    the number an early exit would move — and beside it the (row, call)s
+    themselves; ``loop_exit_mass`` () float32, the sum over (row, call) of
+    the exit mass BEFORE the last pass, ``1 - p_T`` of each row's last new
+    token (``exit_distribution``): what a threshold under 1 could save."""
     c = config
     if c.latent and not c.index_topk:
         # no indexer: one kind of state, every visible key attended to;
@@ -1959,6 +2163,9 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> Params:
         cache["moe_layer_steps"] = jnp.zeros((layers, 2) if some else (layers,), jnp.int32)
         if c.zero_experts:
             cache["moe_zero_choices"] = jnp.zeros((layers,), jnp.int32)
+    if c.loop_passes > 1:
+        cache["loop_passes"] = jnp.zeros((2,), jnp.int32)
+        cache["loop_exit_mass"] = jnp.zeros((), jnp.float32)
     return cache
 
 
@@ -2023,7 +2230,8 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
     latent config's (L, 3) keys visible, selected and read (a single-token
     step: latent rows fetched; a run: (query, key) pairs its attention
     computed scores for); without an indexer (L, 2) keys visible and rows
-    read, of steps only.  ``first``: the cache layer
+    read, of steps only; a looped config's ``exit_lambda`` (passes, rows)
+    into ``loop_passes`` and ``loop_exit_mass``.  ``first``: the cache layer
     ``aux`` starts at, where it covers a part of the layers (the model
     without its multi-token-prediction module, or that module alone)."""
     out = dict(cache, **state)
@@ -2060,6 +2268,13 @@ def _with_counts(cache: Params, state: Params, aux: Params, step: bool,
         out["mla_keys"] = cache["mla_keys"].at[part].set(
             _add_wide(cache["mla_keys"][part], aux["mla_keys"])
         )
+    if "exit_lambda" in aux:
+        lam = aux["exit_lambda"]  # (passes, rows): each row's last new token
+        passes, rows = lam.shape
+        out["loop_passes"] = cache["loop_passes"] + jnp.asarray(
+            [passes * rows, rows], jnp.int32)
+        out["loop_exit_mass"] = cache["loop_exit_mass"] + (
+            1.0 - exit_distribution(lam)[-1]).sum()
     return out
 
 
@@ -2217,28 +2432,35 @@ def _kv_attention(h, p, state, slot, positions, config: LlamaConfig):
         return _kind_attention(h, p, state, slot, positions, c)
     q, kk, vv = _qkv(h, p, positions, c)
     T = state["k"].shape[2]
-    if c.layer_types and slot is not None:
+    # named where it is the block attention, so that a trace finds its
+    # operations: the block's rows written, the keys read, scores and mix
+    # (and where the model is looped, ``loop_attn``: 192 calls a step at Ouro's)
+    scope = contextlib.nullcontext()
+    if c.mask_block > 1 or c.loop_passes > 1:
+        scope = jax.named_scope("block_attn" if c.mask_block > 1 else "loop_attn")
+    if (c.layer_types or c.loop_passes > 1) and slot is not None:
         # beside linear layers a run starts at position 0 (they can do
         # nothing else): its own tokens are all the keys there are, and
-        # the slab's other max_len - Sq rows need not be read or scored
+        # the slab's other max_len - Sq rows need not be read or scored.
+        # A looped config's run into a slot starts at 0 as well (``slot``
+        # given: ``prefill_into_slot``) and takes this path: 192 slabs a
+        # prompt would be read for nothing at Ouro-2.6B's cache layers
         write = partial(_write_and_read, layer=p["cache_layer"], slot=slot,
                         positions=positions, config=c, slab=False)
-        cache_k, _ = write(state["k"], kk.astype(c.dtype))
-        cache_v, _ = write(state["v"], vv.astype(c.dtype))
-        if c.q_per_kv > 1 and q.shape[0] == 1:
-            # grouped queries: the flash kernel that repeats no KV head
-            attn = kv_prefill_attention.attention(q[0], kk[0], vv[0])[None]
-        else:
-            attn = _run_attention(q, kk, vv, c)
+        with scope:
+            cache_k, _ = write(state["k"], kk.astype(c.dtype))
+            cache_v, _ = write(state["v"], vv.astype(c.dtype))
+            if c.q_per_kv > 1 and q.shape[0] == 1:
+                # grouped queries: the flash kernel that repeats no KV head
+                attn = kv_prefill_attention.attention(q[0], kk[0], vv[0])[None]
+            else:
+                attn = _run_attention(q, kk, vv, c)
         return attn.astype(c.dtype), dict(state, k=cache_k, v=cache_v), {}
     streamed = (
         slot is None and positions.shape[1] <= _STEP_RUN
         and kv_decode_attention.implementation(T, c.head_dim, c.sliding_window)
         == "streamed"
     )
-    # named where it is the block attention, so that a trace finds its
-    # operations: the block's rows written, the keys read, scores and mix
-    scope = jax.named_scope("block_attn") if c.mask_block > 1 else contextlib.nullcontext()
     with scope:
         cache_k, slab_k = _write_and_read(
             state["k"], kk.astype(c.dtype), p["cache_layer"], slot, positions, c,
@@ -2979,7 +3201,15 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
         )
         return (xx, st), aux
 
-    (x, riding), aux = _layer_loop(params, c, (x, riding), block)
+    def close(xx):
+        # behind a pass of a looped config: the final norm (the next pass
+        # reads normed states), and the exit gate on each row's last new token
+        xx = _rmsnorm(xx, params["final_norm"], c.rms_eps)
+        return xx, _exit_lambda(params, xx[:, -1, :], c)
+
+    (x, riding), aux = _layer_loop(params, c, (x, riding), block, close=close)
+    if c.loop_passes > 1:
+        aux = {"exit_lambda": aux["closed"]}  # (passes, R)
     if c.mixers_per_layer > 1:
         # (layers, attentions a layer, ..) -> (cache layers, ..)
         aux = {k: v.reshape(-1, *v.shape[2:]) if k in _PER_CACHE_LAYER else v
@@ -2998,7 +3228,8 @@ def _cached_step(params: Params, tokens, cache: Params, slot, start,
         state["gdn_conv"] = lax.dynamic_update_slice(
             state["gdn_conv"], aux.pop("gdn_conv_rows"), (0, 0, slot, 0)
         )
-    x = _rmsnorm(x, params["final_norm"], c.rms_eps)
+    if c.loop_passes == 1:  # a looped config's last pass has normed it
+        x = _rmsnorm(x, params["final_norm"], c.rms_eps)
     logits = x if hidden else _logits(params, x[:, -1, :], c)
     cache = _with_counts(cache, state, aux, step)
     if LINEAR in c.layer_types:

@@ -148,9 +148,9 @@ class _Slot:
 class _Step:
     """A decode step from its launch to the delivery of its tokens."""
 
-    __slots__ = ("tokens", "rows", "span")
+    __slots__ = ("tokens", "rows", "span", "loop")
 
-    def __init__(self, tokens, rows, span):
+    def __init__(self, tokens, rows, span, loop=None):
         # (max_slots,) int32 on the device, the step's choice a row; of a
         # stateful step (max_slots, k + extras): up to k ids, how many of
         # them count, and what the step's kind tallies (``_hand_out``)
@@ -159,6 +159,10 @@ class _Step:
         # [(row, its _Slot)]: which token is the last is not known yet
         self.rows = rows
         self.span = span      # its llm.step span, if traced
+        # a traced step of a looped config: the cache's loop totals as this
+        # step left them, copied on the device (the next step takes the
+        # cache itself), for the span (``LLMEngine._loop_totals``)
+        self.loop = loop
 
 
 _END = object()
@@ -196,7 +200,10 @@ class LLMEngine:
     whatever the slot held (``llama._gated_delta_state``) — or, where it has
     window layers beside full ones (``sliding_attention``), a second K/V pair
     of ``window`` rolling slots a row, which the prefill fills with the
-    prompt's last keys (``llama._kind_attention``).  Refused in words: with
+    prompt's last keys (``llama._kind_attention``) — or, where the model is
+    LOOPED (``loop_passes``), K/V rows of every (pass, layer): a row-step still
+    yields one token, the scheduler knows nothing of the passes, and a token
+    costs ``cache_layers`` cache layers, not ``num_layers``.  Refused in words: with
     linear layers ``speculative_tokens``, ``diffusion_block`` and a
     model-wide ``sliding_window``; with window layers ``speculative_tokens``
     and ``diffusion_block`` (a block mask with a sliding window is not
@@ -323,6 +330,14 @@ class LLMEngine:
         else:
             self.cache_len = max_len
         self.cache = llama.init_cache(config, max_slots, self.cache_len)
+        # a looped config's traced steps copy the cache's two loop totals for
+        # their spans (``_launch``): the copy is compiled here, at set-up
+        self._loop_copy = None
+        if config.loop_passes > 1:
+            import jax
+
+            self._loop_copy = jax.jit(lambda totals: jax.tree.map(jnp.copy, totals))
+            self._loop_copy({k: self.cache[k] for k in ("loop_passes", "loop_exit_mass")})
         # held while a prefill or a decode step is launched on the
         # (donated) cache: whoever else wants to read it (cache_counters)
         # waits its turn, and then for the steps launched so far
@@ -505,7 +520,8 @@ class LLMEngine:
         of them meets with one, ``ops/kv_prefill_attention.py:tiles``) and
         ``kv_decode_attention`` ({``full``, ``swa``}: ``streamed`` |
         ``slab``).  These count on the device (``attn_keys`` rides the
-        cache): ``kv_keys_*_step`` are not reported for such a config."""
+        cache): ``kv_keys_*_step`` are not reported for such a config.  A
+        looped config (``loop_passes`` > 1): ``_loop_totals``."""
         import numpy as np
 
         from ray_tpu.models.llama import wide_total
@@ -518,7 +534,8 @@ class LLMEngine:
 
         out = {}
         names = [k for k in self.cache
-                 if k.startswith(("moe_", "dsa_", "mla_", "gdn_counts", "attn_keys"))]
+                 if k.startswith(("moe_", "dsa_", "mla_", "gdn_counts", "attn_keys",
+                                  "loop_"))]
         if not names:
             return out
         async with self._cache_lock:
@@ -603,6 +620,8 @@ class LLMEngine:
                     ("full", self.cache_len, c.num_kv_heads),
                     ("swa", c.sliding.window, c.sliding.num_kv_heads))
             }
+        if "loop_passes" in host:
+            out.update(self._loop_totals(host))
         if "gdn_counts" in host:
             from ray_tpu.models.llama import GDN_COUNTS
 
@@ -621,6 +640,21 @@ class LLMEngine:
                 c.linear_chunk, c.linear_kind == "kda",
             )
         return out
+
+    @staticmethod
+    def _loop_totals(cache) -> dict:
+        """A looped config's running totals as the cache carries them
+        (``llama.init_cache``): ``loop_passes`` passes run, summed over every
+        (row, call) of the two programs — ``loop_passes`` a row-step while
+        every token runs every pass — ``loop_row_steps`` those (row, call)s,
+        and ``loop_exit_mass`` the exit distribution's mass before the last
+        pass, summed over them: the passes' share a threshold under 1 could
+        save with these weights."""
+        import numpy as np
+
+        passes, row_steps = (int(x) for x in np.asarray(cache["loop_passes"]))
+        return {"loop_passes": passes, "loop_row_steps": row_steps,
+                "loop_exit_mass": float(np.asarray(cache["loop_exit_mass"]))}
 
     # -- engine loop -----------------------------------------------------
     async def _run(self):
@@ -807,9 +841,13 @@ class LLMEngine:
                         ), cache
                     return jnp.argmax(logits, axis=-1), cache
 
+            loop = None
             async with self._cache_lock:
                 self._tokens, self.cache = await asyncio.to_thread(_step)
                 self.rows_stepped_total += self.max_slots
+                if life is not None and self._loop_copy is not None:
+                    loop = self._loop_copy(
+                        {k: self.cache[k] for k in ("loop_passes", "loop_exit_mass")})
             self.decode_steps_total += 1
             if self._flying:
                 self.steps_launched_ahead_total += 1
@@ -825,7 +863,7 @@ class LLMEngine:
                 rows.append((i, s, last))
                 if last:
                     self.slots[i] = None
-            self._flying.append(_Step(self._tokens, rows, life))
+            self._flying.append(_Step(self._tokens, rows, life, loop))
 
     def _count_kv_keys(self, visible: int, last) -> None:
         """One every-row step into ``kv_keys_visible_step`` (``visible``:
@@ -963,6 +1001,8 @@ class LLMEngine:
         with _part(step.span, "llm.step.yield"):
             await asyncio.sleep(0)
         if step.span is not None:
+            if step.loop is not None:
+                step.span.attrs.update(self._loop_totals(step.loop))
             step.span.finish()
 
     async def _run_inner(self):
@@ -1119,7 +1159,12 @@ class LlamaDeployment:
         ``kv_keys_read_step`` (keys the steps' attention fetched, for every
         row, free slots too: whole blocks up to each row's last visible key
         where ``ops/kv_decode_attention.py``'s kernel runs, the whole slab
-        where XLA's body does; read / visible is the over-read); over the
+        where XLA's body does; read / visible is the over-read); over every
+        (pass, layer) of a looped config (``loop_passes``: ``kv_layers`` is
+        the cache's, 192 for Ouro-2.6B's 48), which adds ``loop_passes``,
+        ``loop_row_steps`` and ``loop_exit_mass`` (``LLMEngine._loop_totals``;
+        a traced step's ``llm.step`` span carries the three as that step
+        left them); over the
         FULL layers only where the config has ``layer_types``, which adds
         the recurrent layers' ``gdn_rows_stepped``, ``gdn_tokens_scanned``,
         ``gdn_tokens_padded`` and ``gdn_state_bytes_step``
